@@ -4,7 +4,7 @@ The dense placement path has exactly one expensive shared dependency:
 the batched device dispatch (scheduler/batcher.py -> ops/binpack.py).
 PR 3 gave it a *per-eval* recovery — a failed ``place()`` falls back to
 the host iterators for that eval — but a persistently sick device path
-(runtime wedged, tunnel congested, device OOM-looping) then pays the
+(runtime wedged, device OOM-looping) then pays the
 failure latency on EVERY eval before falling back: the cluster limps at
 fault-detection speed instead of host speed. The breaker turns N
 consecutive per-eval failures into one routing decision.

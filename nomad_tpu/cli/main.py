@@ -594,6 +594,7 @@ def cmd_agent(args) -> int:
         pass  # not on the main thread (tests)
 
     scheduler_factories = {}
+    device_banner = ""
     if cfg.server.scheduler_factories:
         scheduler_factories = dict(cfg.server.scheduler_factories)
     if args.tpu:
@@ -605,26 +606,25 @@ def cmd_agent(args) -> int:
                                     "system": "system-tpu"})
     if cfg.server.enabled and any(
             f.endswith("-tpu") for f in scheduler_factories.values()):
-        # Eager jax import at agent boot: with dense factories
-        # configured this SERVER will need the device backend, and a
-        # broken device environment should fail loudly here — at
-        # startup, on the operator's console — rather than as per-eval
-        # scheduler errors in the middle of the first placement storm.
-        # Client-only agents never schedule and skip the cost.
+        # Backend start-up at agent boot: with dense factories
+        # configured this SERVER needs the device backend, and a broken
+        # device environment fails here — at startup, on the operator's
+        # console — rather than as per-eval scheduler errors (or a
+        # silent host fallback) in the middle of the first placement
+        # storm. It also takes backend start-up (seconds on a TPU) off
+        # the first eval's dispatcher thread. JAX_PLATFORMS is the only
+        # way to name a backend. Client-only agents never schedule and
+        # skip the cost.
         import jax
 
-        # Operator backend override: dense factories are correct on any
-        # XLA backend (CPU/TPU parity is a test invariant), so agents
-        # on TPU-less hosts can still run them — and some environments
-        # pin jax_platforms in site config where JAX_PLATFORMS can't
-        # override it.
-        plat = os.environ.get("NOMAD_TPU_PLATFORM")
-        if plat:
-            try:
-                jax.config.update("jax_platforms", plat)
-            except Exception as e:  # noqa: BLE001 - backend already up
-                print(f"warning: NOMAD_TPU_PLATFORM={plat!r} ignored: {e}",
-                      file=sys.stderr)
+        try:
+            devices = jax.devices()
+        except RuntimeError as e:
+            print(f"error initializing the JAX backend for the dense "
+                  f"scheduler factories: {e}", file=sys.stderr)
+            return 1
+        device_banner = (f"{devices[0].platform} "
+                         f"({devices[0].device_kind}) x{len(devices)}")
 
     # Unique gossip identity per agent: two same-region agents with the
     # same member name would clobber each other in the serf pool.
@@ -822,6 +822,8 @@ def cmd_agent(args) -> int:
         print(f"==> nomad-tpu agent started ({mode})! HTTP: {http.addr}")
         print(f"    Gossip: {serf_addr} (region {cfg.region})")
         print(f"    Scheduler factories: {scheduler_factories or 'cpu defaults'}")
+        if device_banner:
+            print(f"    Placement device: {device_banner}")
 
     client_agent = None
     if cfg.client.enabled:

@@ -270,10 +270,7 @@ def solve_cache_size() -> int:
     exactly 2 per live (K bucket, N) shape — cold + warm."""
     if _SOLVE_JIT is None:
         return 0
-    try:
-        return _SOLVE_JIT._cache_size()
-    except Exception:  # noqa: BLE001 - accounting must never raise
-        return 0
+    return _SOLVE_JIT._cache_size()
 
 
 def _k_bucket(k: int) -> int:

@@ -219,6 +219,7 @@ class DispatchPipeline:
         self.inline_retries = 0  # guarded-by: _lock (classic retries)
         self.prefetches = 0  # guarded-by: _lock (base prefetch calls)
         self.prefetch_bytes = 0  # guarded-by: _lock (host->device bytes)
+        self.prefetch_failures = 0  # guarded-by: _lock (upload raised)
         self.t_drain = 0.0  # guarded-by: _lock (time in accumulator)
         self.t_process = 0.0  # guarded-by: _lock (scheduler invoke)
         self.t_submit = 0.0  # guarded-by: _lock (plan queue + commit)
@@ -636,6 +637,8 @@ class DispatchPipeline:
                 self.logger.warning(
                     "base prefetch failed; place() will upload "
                     "synchronously", exc_info=True)
+                with self._lock:
+                    self.prefetch_failures += 1
                 continue
             with self._lock:
                 self.prefetches += 1
@@ -838,6 +841,7 @@ class DispatchPipeline:
                 "breaker_routed": self.breaker_routed,
                 "prefetches": self.prefetches,
                 "prefetch_bytes": self.prefetch_bytes,
+                "prefetch_failures": self.prefetch_failures,
                 "retries_per_eval": round(retries / done, 4) if done else 0.0,
                 # Cumulative stage latencies (divide by the matching
                 # counters for per-unit): microseconds, like the

@@ -42,7 +42,6 @@ from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .mesh import NODE_AXIS
@@ -95,7 +94,7 @@ def sharded_base_delta(mesh):
                     ports_free.at[safe].set(ports_rows, mode="drop"),
                     node_ok.at[safe].set(ok_rows, mode="drop"))
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(NODE_AXIS, None), P(NODE_AXIS), P(NODE_AXIS),
                       P(NODE_AXIS), P(), P(None, None), P(), P(), P()),
@@ -120,7 +119,7 @@ def sharded_group_capacity(mesh, g_pad: int):
             partial = _group_capacity(units, topo_ids, g_pad)
             return jax.lax.psum(partial, NODE_AXIS)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(NODE_AXIS), P(NODE_AXIS)),
             out_specs=P())
@@ -130,10 +129,11 @@ def sharded_group_capacity(mesh, g_pad: int):
 
 
 def per_shard_occupancy(arrays) -> List[dict]:
-    """[{device, rows, bytes}] per shard of a device-resident base
-    tuple (or a single array) — the bench's per-shard occupancy and
-    device-memory columns. Pure metadata: reads shard layouts, moves
-    no data. Single-device arrays report one row."""
+    """[{device, platform, rows, bytes}] per shard of a
+    device-resident base tuple (or a single array) — the bench's
+    per-shard occupancy and device-memory columns, and chip_smoke.py's
+    proof of WHERE the base sits. Pure metadata: reads shard layouts,
+    moves no data. Single-device arrays report one row."""
     if not isinstance(arrays, (tuple, list)):
         arrays = (arrays,)
     per: Dict[str, dict] = {}
@@ -143,18 +143,13 @@ def per_shard_occupancy(arrays) -> List[dict]:
             continue
         for s in shards:
             d = str(s.device)
-            ent = per.setdefault(d, {"device": d, "rows": 0, "bytes": 0})
+            ent = per.setdefault(d, {
+                "device": d, "platform": s.device.platform,
+                "rows": 0, "bytes": 0})
             ent["bytes"] += int(s.data.nbytes)
             if j == 0:
                 ent["rows"] += int(s.data.shape[0])
     return [per[d] for d in sorted(per)]
-
-
-def _one_cache_size(fn) -> int:
-    try:
-        return fn._cache_size()
-    except Exception:  # noqa: BLE001 - accounting must never raise
-        return 0
 
 
 def shard_cache_size() -> int:
@@ -163,4 +158,4 @@ def shard_cache_size() -> int:
     jit_recompiles gate covers the sharded paths too."""
     with _PROGRAM_LOCK:
         fns = list(_PROGRAMS.values())
-    return sum(_one_cache_size(fn) for fn in fns)
+    return sum(fn._cache_size() for fn in fns)

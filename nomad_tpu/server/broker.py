@@ -151,6 +151,11 @@ class EvalBroker:
         # deadline expired before a dequeuer reached them.
         self.shed = 0  # guarded-by: _lock
         self.expired = 0  # guarded-by: _lock
+        # Redeliveries: every nack, and the subset the nack TIMER
+        # fired (a scheduler still busy — e.g. compiling — when
+        # nack_timeout ran out, as opposed to one that gave up).
+        self.nacked = 0  # guarded-by: _lock
+        self.nack_timeouts = 0  # guarded-by: _lock
 
     # ------------------------------------------------------------------
 
@@ -446,7 +451,9 @@ class EvalBroker:
         try:
             self.nack(eval_id, token)
         except ValueError:
-            pass  # already acked/nacked
+            return  # already acked/nacked
+        with self._lock:
+            self.nack_timeouts += 1
 
     # ------------------------------------------------------------------
 
@@ -497,6 +504,7 @@ class EvalBroker:
             unack.timer.cancel()
             del self._unack[eval_id]
             self._requeue.pop(token, None)
+            self.nacked += 1
             ev = unack.eval
             # The job claim stays with this eval; redeliver it, or
             # dead-letter it past the delivery limit: the failed-queue
@@ -588,6 +596,8 @@ class EvalBroker:
             dead = self.dead_lettered
             shed = self.shed
             expired = self.expired
+            nacked = self.nacked
+            nack_timeouts = self.nack_timeouts
         return {
             "ready_by_queue": self.ready_by_queue(),
             "total_ready": self.ready_count(),
@@ -597,4 +607,6 @@ class EvalBroker:
             "dead_lettered": dead,
             "shed": shed,
             "expired": expired,
+            "nacked": nacked,
+            "nack_timeouts": nack_timeouts,
         }

@@ -53,6 +53,9 @@ def test_single_request_matches_direct_program():
     direct_c, direct_s, _ = placement_program_jit(state, asks, key, CONFIG)
     np.testing.assert_array_equal(choices, np.asarray(direct_c))
     np.testing.assert_allclose(scores, np.asarray(direct_s), rtol=1e-5)
+    # The recompile gates read this counter; after a real dispatch it
+    # must count a real program (jax's private _cache_size still works).
+    assert batcher.stats()["jit_cache_size"] > 0
 
 
 def test_concurrent_requests_share_one_dispatch():
