@@ -28,7 +28,10 @@ backend JAX finds (for debugging the script on a CPU); its summary says
 so and is never a chip result. The timings printed are observations
 with the device beside them, not claims.
 
-The last line of stdout is one JSON object.
+The line before last, `summary: {...}`, carries the sizes, counters,
+timings and failed checks. The last line of stdout is the verdict and
+nothing else: `{"ok": ..., "device": {"platform", "kind", "count"}}`,
+the device as JAX reports it.
 """
 
 from __future__ import annotations
@@ -559,7 +562,8 @@ def main(argv=None) -> int:
         if http is not None:
             http.stop()
         server.shutdown()
-    print(json.dumps(summary), flush=True)
+    say(f"summary: {json.dumps(summary)}")
+    say(json.dumps({"ok": summary["ok"], "device": device}))
     return 0 if summary["ok"] else 1
 
 

@@ -52,8 +52,16 @@ def test_chip_smoke_rehearsal_runs_every_phase_on_the_cpu():
     between chip runs: same phases, same counters, same checks."""
     out = run(["chip_smoke.py", "--rehearse"], timeout=300)
     assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-3000:]
-    summary = json.loads(out.stdout.splitlines()[-1])
+    *_, summary_line, verdict_line = out.stdout.splitlines()
+    # The last line is the verdict and nothing else, key for key.
+    verdict = json.loads(verdict_line)
+    assert set(verdict) == {"ok", "device"} and verdict["ok"] is True
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert isinstance(verdict["device"]["count"], int)
+    assert summary_line.startswith("summary: ")
+    summary = json.loads(summary_line[len("summary: "):])
     assert summary["ok"] and summary["rehearsal"]
+    assert summary["device"] == verdict["device"]
     assert summary["device"]["platform"] == "cpu"
     assert summary["differential"]["violations"] == 0
     assert summary["waves"][-1]["jit_cache_size"] > 0
