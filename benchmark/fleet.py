@@ -1,0 +1,163 @@
+"""One generic builder for every configuration file: the fleet (classes of
+node shape with counts and a filler rule), loaded in bulk through the
+raft log, and the job template the load generator registers.
+
+Everything comes from the configuration's JSON and `--seed`; a new
+deployment is a new file, not new code here.
+"""
+
+from __future__ import annotations
+
+import random
+import uuid
+
+# Allocations to a raft entry when the fillers are loaded: few large
+# entries, so the store's per-apply work (index bump, watch stamps,
+# notify) is paid some tens of times and not tens of thousands.
+FILLER_ENTRY = 5000
+
+
+def seeded_uuid(rng: random.Random) -> str:
+    return str(uuid.UUID(int=rng.getrandbits(128), version=4))
+
+
+def scaled(config: dict, rehearse: bool) -> dict:
+    """The configuration as run. A rehearsal shrinks the fleet and the
+    job by the factors the file itself states; a real run changes
+    nothing."""
+    if not rehearse:
+        return config
+    small = dict(config)
+    spec = config["rehearsal"]
+    fleet = dict(config["fleet"])
+    fleet["classes"] = [
+        dict(c, count=max(8, int(c["count"] * spec["fleet_scale"])))
+        for c in fleet["classes"]]
+    small["fleet"] = fleet
+    small["job"] = dict(config["job"], count=spec["job_count"])
+    return small
+
+
+def _node_template(shape: dict, datacenter: str):
+    from nomad_tpu.structs import NetworkResource, Node, Port, Resources
+
+    res = shape["reserved"]
+    return Node(
+        datacenter=datacenter, name="bench",
+        attributes=dict(shape["attributes"]), meta=dict(shape["meta"]),
+        node_class=shape["node_class"], status="ready",
+        resources=Resources(
+            cpu=shape["cpu"], memory_mb=shape["memory_mb"],
+            disk_mb=shape["disk_mb"], iops=shape["iops"],
+            networks=[NetworkResource(
+                device=shape["device"], cidr=shape["cidr"], ip=shape["ip"],
+                mbits=shape["mbits"])]),
+        reserved=Resources(
+            cpu=res["cpu"], memory_mb=res["memory_mb"],
+            disk_mb=res["disk_mb"], iops=res["iops"],
+            networks=[NetworkResource(
+                device=shape["device"], ip=shape["ip"], mbits=res["mbits"],
+                reserved_ports=[Port(f"r{p}", p) for p in res["ports"]])]))
+
+
+def _filler_job(datacenter: str):
+    from nomad_tpu.structs import (EphemeralDisk, Job, Resources, Task,
+                                   TaskGroup)
+
+    job = Job(id="filler", name="filler", type="service", priority=50,
+              datacenters=[datacenter],
+              task_groups=[TaskGroup(
+                  name="web", count=1, ephemeral_disk=EphemeralDisk(),
+                  tasks=[Task(name="web", driver="exec",
+                              resources=Resources(cpu=100, memory_mb=64))])])
+    job.canonicalize()
+    return job
+
+
+def load_fleet(server, config: dict, seed: int) -> dict:
+    """Nodes one raft entry each (the program has no bulk node entry),
+    fillers FILLER_ENTRY to an entry; every object a copy of one
+    template. Goes through `server.log.apply`, so the FSM's tables and
+    indexes are the program's own."""
+    from nomad_tpu.structs import Allocation, Resources
+
+    rng = random.Random(seed)
+    fleet = config["fleet"]
+    dc = fleet["datacenter"]
+    filler_job = _filler_job(dc)
+    n_nodes = n_allocs = 0
+    pending = []
+    for cls in fleet["classes"]:
+        template = _node_template(cls["node"], dc)
+        template.compute_class()
+        rule = cls["filler"]
+        per_node = rule.get("per_node", 0)
+        for _ in range(cls["count"]):
+            node = template.copy()
+            node.id = seeded_uuid(rng)
+            node.secret_id = seeded_uuid(rng)
+            server.log.apply("node_register", {"node": node})
+            n_nodes += 1
+            for k in range(per_node):
+                pending.append(Allocation(
+                    id=seeded_uuid(rng), eval_id="filler", node_id=node.id,
+                    name=f"filler.web[{k}]", job_id=filler_job.id,
+                    job=filler_job, task_group="web",
+                    shared_resources=Resources(disk_mb=rule["disk_mb"]),
+                    task_resources={"web": Resources(
+                        cpu=rng.choice(rule["cpu"]),
+                        memory_mb=rng.choice(rule["memory_mb"]))},
+                    desired_status="run", client_status="running"))
+            if len(pending) >= FILLER_ENTRY:
+                server.log.apply("alloc_update", {"allocs": pending})
+                n_allocs += len(pending)
+                pending = []
+    if pending:
+        server.log.apply("alloc_update", {"allocs": pending})
+        n_allocs += len(pending)
+    return {"nodes": n_nodes, "filler_allocs": n_allocs}
+
+
+def job_template(config: dict) -> dict:
+    """The job every client registers, as the JSON body's `job`; the
+    generator fills in `id` and `name`."""
+    from nomad_tpu.structs import (Constraint, EphemeralDisk, Job,
+                                   NetworkResource, Port, Resources,
+                                   RestartPolicy, Task, TaskGroup)
+    from nomad_tpu.utils.codec import to_dict
+
+    spec = config["job"]
+    task = spec["task"]
+    networks = []
+    if task["mbits"] or task["dynamic_ports"]:
+        networks = [NetworkResource(
+            mbits=task["mbits"],
+            dynamic_ports=[Port(label, 0) for label in task["dynamic_ports"]])]
+    group_constraints = []
+    if spec["distinct_hosts"]:
+        group_constraints.append(Constraint(operand="distinct_hosts"))
+    if spec["type"] == "batch":
+        restart = RestartPolicy(attempts=0, interval=0.0, delay=0.0,
+                                mode="fail")
+    else:
+        restart = RestartPolicy(attempts=3, interval=600.0, delay=60.0,
+                                mode="delay")
+    job = Job(
+        region="global", id="template", name="template", type=spec["type"],
+        priority=spec["priority"], datacenters=list(spec["datacenters"]),
+        constraints=[Constraint(**c) for c in spec["constraints"]],
+        task_groups=[TaskGroup(
+            name=spec["group"], count=spec["count"],
+            constraints=group_constraints, restart_policy=restart,
+            ephemeral_disk=EphemeralDisk(size_mb=spec["ephemeral_disk_mb"]),
+            tasks=[Task(
+                name=task["name"], driver=task["driver"],
+                config={"command": "/bin/date"},
+                resources=Resources(cpu=task["cpu"],
+                                    memory_mb=task["memory_mb"],
+                                    networks=networks))])])
+    job.canonicalize()
+    errors = job.validate()
+    if errors:
+        raise ValueError(f"job template does not validate: {errors}")
+    return to_dict(job)
