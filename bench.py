@@ -1177,18 +1177,18 @@ def _trivial_rtt_us() -> float:
 
 
 def _breakdown_str(dstats) -> str:
-    """Per-dispatch cost breakdown: host stacking / h->d payload /
-    issue / device round-trip, plus the transport floor."""
+    """Per-dispatch cost breakdown: h->d payload / issue / device
+    round-trip, plus the transport floor. (Host stacking and base
+    upload are the `device.idle.stack` stage of the trace table.)"""
     n = max(dstats.get("dispatches", 0), 1)
     return (
-        f"per-dispatch: stack {dstats.get('stack_us', 0) / n:.0f}us, "
+        f"per-dispatch: "
         f"payload {dstats.get('payload_bytes', 0) / n / 1024:.0f}KB, "
         f"issue {dstats.get('issue_us', 0) / n:.0f}us, "
         f"sync {dstats.get('sync_us', 0) / n:.0f}us; "
         f"uploads {dstats.get('base_uploads', 0)} full "
         f"({dstats.get('upload_bytes', 0) / 1024:.0f}KB total) + "
-        f"{dstats.get('base_delta_updates', 0)} delta, "
-        f"{dstats.get('upload_us', 0) / 1000:.0f}ms; "
+        f"{dstats.get('base_delta_updates', 0)} delta; "
         f"trivial-RTT floor {_trivial_rtt_us():.0f}us"
     )
 

@@ -20,6 +20,7 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, List, Optional, Tuple
 
+from .. import trace
 from ..admission import AdmissionRejected
 from ..state import watch
 from ..structs import Allocation, Evaluation, Job, Node, Plan
@@ -711,15 +712,29 @@ class HTTPServer:
 
     # ------------------------------------------------------------- jobs
 
+    def _register(self, body, job_id=None, **gate):
+        """Job.Register behind both register routes. The `api.register`
+        span runs from here to the response being ready, recorded at the
+        end: the eval's trace was opened by the broker's mark inside the
+        raft apply (create=False: an eval that already finished keeps
+        its published trace), and the recorder moves the trace's origin
+        back to the span's start, so e2e starts at the request."""
+        t0 = time.monotonic()
+        job = from_dict(Job, body.get("job", body))
+        if job_id is not None and job.id != job_id:
+            raise HTTPError(400, "job ID does not match URL")
+        eval_id, index = self.server.job_register(job, **gate)
+        trace.record_span(eval_id, trace.STAGE_API_REGISTER, t0,
+                          create=False)
+        return {"eval_id": eval_id, "index": index}
+
     def _jobs(self, method, query, body):
         if method in ("PUT", "POST"):
-            job = from_dict(Job, body.get("job", body))
-            eval_id, index = self.server.job_register(
-                job,
+            return self._register(
+                body,
                 enforce_index=bool(body.get("enforce_index")),
                 job_modify_index=int(body.get("job_modify_index") or 0),
             )
-            return {"eval_id": eval_id, "index": index}
         state = self.server.fsm.state
         prefix = query.get("prefix", [""])[0]
         return self._blocking(
@@ -737,11 +752,7 @@ class HTTPServer:
             eval_id = self.server.job_deregister(job_id)
             return {"eval_id": eval_id or ""}
         if method in ("PUT", "POST"):
-            job = from_dict(Job, body.get("job", body))
-            if job.id != job_id:
-                raise HTTPError(400, "job ID does not match URL")
-            eval_id, index = self.server.job_register(job)
-            return {"eval_id": eval_id, "index": index}
+            return self._register(body, job_id)
         state = self.server.fsm.state
 
         def run():

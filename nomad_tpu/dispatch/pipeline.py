@@ -220,9 +220,6 @@ class DispatchPipeline:
         self.prefetches = 0  # guarded-by: _lock (base prefetch calls)
         self.prefetch_bytes = 0  # guarded-by: _lock (host->device bytes)
         self.prefetch_failures = 0  # guarded-by: _lock (upload raised)
-        self.t_drain = 0.0  # guarded-by: _lock (time in accumulator)
-        self.t_process = 0.0  # guarded-by: _lock (scheduler invoke)
-        self.t_submit = 0.0  # guarded-by: _lock (plan queue + commit)
 
     # ------------------------------------------------------- lifecycle
 
@@ -351,8 +348,8 @@ class DispatchPipeline:
             # Run-queue delay at the broker-drain point: notify-while-
             # parked -> this thread actually running — the dispatcher's
             # wake latency under GIL pressure, nothing else (the top-up
-            # window and slot waits are deliberate batching time and
-            # are measured by t_drain, not here).
+            # window and slot waits are deliberate batching time: the
+            # dispatch.accumulate span, not this).
             if self._notified_at:
                 profile.record_runq(
                     "broker_drain",
@@ -401,11 +398,9 @@ class DispatchPipeline:
             self.batches += 1
             self.dispatched_evals += len(batch)
             self.largest_batch = max(self.largest_batch, len(batch))
-            now = time.monotonic()
-            for entry in batch:
-                self.t_drain += now - entry.enqueued_at
-                if entry.requeues and len(batch) > 1:
-                    self.requeues_batched += 1
+            if len(batch) > 1:
+                self.requeues_batched += sum(
+                    1 for entry in batch if entry.requeues)
             profile.event("accumulate_close", "dispatcher",
                           a=len(batch), b=self.batches)
         metrics.add_sample(("dispatch", "batch_size"), len(batch))
@@ -438,14 +433,22 @@ class DispatchPipeline:
         # and the slot _accumulate took would leak, wedging the
         # accumulator once max_inflight failed launches pile up.
         try:
-            prologue = self._launch_prologue(batch)
+            with trace.annotation("nomad.launch_prologue",
+                                  evals=len(batch)):
+                prologue = self._launch_prologue(batch)
         except Exception:
             self.logger.exception(
                 "batch launch failed; nacking %d evals", len(batch))
             prologue = None
+        # One instant ends every member's dispatch.launch and starts its
+        # dispatch.pool_wait: the fan-out below hands the entries to the
+        # pool one after another, and an entry's wait for its turn in
+        # that loop is part of its wait for a stage thread.
+        t_fan = time.monotonic()
         for entry in batch:
             trace.record_span(
                 entry.eval.id, trace.STAGE_DISPATCH_LAUNCH, t_launch,
+                t_fan,
                 ann=({"failed": True} if prologue is None else None),
                 trace_id=entry.eval.trace_id)
         # Single abort call site: an abort raising INSIDE the try must
@@ -465,7 +468,7 @@ class DispatchPipeline:
         for entry in batch:
             self.server.eval_pool.submit(
                 self._process_entry, entry, snapshot, route_host,
-                remaining)
+                remaining, t_fan)
 
     def _drop_expired(self, batch: List[_Pending],
                       t_launch: float) -> List[_Pending]:
@@ -657,9 +660,11 @@ class DispatchPipeline:
     # ---------------------------------------------------------- stages
 
     def _process_entry(self, entry: _Pending, snapshot, route_host: bool,
-                       remaining: List[int]) -> None:
+                       remaining: List[int], t_fan: float) -> None:
         ev, token = entry.eval, entry.token
         start = time.monotonic()
+        trace.record_span(ev.id, trace.STAGE_DISPATCH_POOL_WAIT, t_fan,
+                          start, trace_id=ev.trace_id)
         # Lock-wait attribution for this stage: the profiler keeps a
         # per-thread contended-wait total; the delta across the
         # scheduler invoke lands on the span so a slow scheduler.process
@@ -692,7 +697,6 @@ class DispatchPipeline:
         except _RequeueConflict:
             with self._lock:
                 self.requeues += 1
-                self.t_process += time.monotonic() - start
             trace.record_span(ev.id, trace.STAGE_SCHED_PROCESS, start,
                               ann={"path": "pipeline", "requeued": True},
                               trace_id=ev.trace_id)
@@ -706,8 +710,6 @@ class DispatchPipeline:
             return
         except Exception:
             self.logger.exception("pipeline eval %s failed", ev.id)
-            with self._lock:
-                self.t_process += time.monotonic() - start
             trace.record_span(ev.id, trace.STAGE_SCHED_PROCESS, start,
                               ann={"path": "pipeline", "failed": True},
                               trace_id=ev.trace_id)
@@ -715,8 +717,6 @@ class DispatchPipeline:
             self._finish(entry, acked=False)
             self._release_slot(remaining)
             return
-        with self._lock:
-            self.t_process += time.monotonic() - start
         trace.record_span(
             ev.id, trace.STAGE_SCHED_PROCESS, start,
             ann={"path": "pipeline", "route_host": route_host,
@@ -790,11 +790,9 @@ class DispatchPipeline:
             timeout, stop=self._stop, base=0.001, max_delay=0.1)
 
     def _note_submit(self, start: float) -> None:
-        dt = time.monotonic() - start
-        with self._lock:
-            self.t_submit += dt
         metrics.measure_since(("dispatch", "submit_plan"), start)
-        profile.event("submit", a=round(dt * 1000.0, 3))
+        profile.event(
+            "submit", a=round((time.monotonic() - start) * 1000.0, 3))
 
     def _note_conflict(self) -> None:
         with self._lock:
@@ -843,10 +841,4 @@ class DispatchPipeline:
                 "prefetch_bytes": self.prefetch_bytes,
                 "prefetch_failures": self.prefetch_failures,
                 "retries_per_eval": round(retries / done, 4) if done else 0.0,
-                # Cumulative stage latencies (divide by the matching
-                # counters for per-unit): microseconds, like the
-                # batcher's breakdown.
-                "drain_us": int(self.t_drain * 1e6),
-                "process_us": int(self.t_process * 1e6),
-                "submit_us": int(self.t_submit * 1e6),
             }

@@ -211,6 +211,12 @@ def apply_base_delta(util, bw_used, ports_free, node_ok, rows,
     )
 
 
+# The phases below carry jax.named_scope names (score_and_mask, topk,
+# claim, claim_scan, expand_overlay): metadata on the HLO ops, so a
+# profiler trace's `Framework Name Scope` line separates the time of one
+# program by phase (tools/traceconv.py --xplane). The compiled code does
+# not change; the jitted entry points already differ by name.
+@jax.named_scope("score_and_mask")
 def _score_and_mask(state: NodeState, ask_res, ask_bw, ask_ports, feas_row,
                     tg_onehot, job_dh, tg_dh, config: PlacementConfig,
                     noise):
@@ -286,14 +292,16 @@ def placement_step(state: NodeState, ask, config: PlacementConfig, noise):
 
     # Row n is out of range: mode="drop" makes the invalid case a no-op.
     safe = jnp.where(valid, choice, n)
-    new_state = state._replace(
-        util=state.util.at[safe].add(ask_res, mode="drop"),
-        bw_used=state.bw_used.at[safe].add(ask_bw, mode="drop"),
-        ports_free=state.ports_free.at[safe].add(-ask_ports, mode="drop"),
-        job_count=state.job_count.at[safe].add(1, mode="drop"),
-        tg_count=state.tg_count.at[safe].add(
-            tg_onehot.astype(jnp.int32), mode="drop"),
-    )
+    with jax.named_scope("claim"):
+        new_state = state._replace(
+            util=state.util.at[safe].add(ask_res, mode="drop"),
+            bw_used=state.bw_used.at[safe].add(ask_bw, mode="drop"),
+            ports_free=state.ports_free.at[safe].add(
+                -ask_ports, mode="drop"),
+            job_count=state.job_count.at[safe].add(1, mode="drop"),
+            tg_count=state.tg_count.at[safe].add(
+                tg_onehot.astype(jnp.int32), mode="drop"),
+        )
     out_choice = jnp.where(valid, choice, -1).astype(jnp.int32)
     out_score = jnp.where(valid, clean_score, 0.0)
     return new_state, (out_choice, out_score)
@@ -328,7 +336,8 @@ def _uniform_topk_program(state: NodeState, asks: Asks, key,
     # back as unplaceable — the same choice=-1 the sequential scan
     # yields once every node carries the job.
     k_eff = min(k_count, n)
-    top_scores, top_idx = jax.lax.top_k(score, k_eff)
+    with jax.named_scope("topk"):
+        top_scores, top_idx = jax.lax.top_k(score, k_eff)
     if k_eff < k_count:
         pad = k_count - k_eff
         top_scores = jnp.concatenate(
@@ -342,18 +351,20 @@ def _uniform_topk_program(state: NodeState, asks: Asks, key,
     # invalid rows scatter to row n and drop.
     safe = jnp.where(valid, top_idx, n)
     vi = valid.astype(jnp.int32)
-    new_state = state._replace(
-        util=state.util.at[safe].add(
-            jnp.where(valid[:, None], ask_res[None, :], 0.0), mode="drop"),
-        bw_used=state.bw_used.at[safe].add(
-            jnp.where(valid, ask_bw, 0.0), mode="drop"),
-        ports_free=state.ports_free.at[safe].add(
-            jnp.where(valid, -ask_ports, 0.0), mode="drop"),
-        job_count=state.job_count.at[safe].add(vi, mode="drop"),
-        tg_count=state.tg_count.at[safe].add(
-            vi[:, None] * tg_onehot[None, :].astype(jnp.int32),
-            mode="drop"),
-    )
+    with jax.named_scope("claim"):
+        new_state = state._replace(
+            util=state.util.at[safe].add(
+                jnp.where(valid[:, None], ask_res[None, :], 0.0),
+                mode="drop"),
+            bw_used=state.bw_used.at[safe].add(
+                jnp.where(valid, ask_bw, 0.0), mode="drop"),
+            ports_free=state.ports_free.at[safe].add(
+                jnp.where(valid, -ask_ports, 0.0), mode="drop"),
+            job_count=state.job_count.at[safe].add(vi, mode="drop"),
+            tg_count=state.tg_count.at[safe].add(
+                vi[:, None] * tg_onehot[None, :].astype(jnp.int32),
+                mode="drop"),
+        )
     return choices, scores_out, new_state
 
 
@@ -406,12 +417,13 @@ def placement_program(
         )
         return new_state, out
 
-    final_state, (choices, scores) = jax.lax.scan(
-        body,
-        state,
-        (asks.resources, asks.bw, asks.ports, feas_rows, tg_onehots,
-         tg_dhs, asks.active, noise),
-    )
+    with jax.named_scope("claim_scan"):
+        final_state, (choices, scores) = jax.lax.scan(
+            body,
+            state,
+            (asks.resources, asks.bw, asks.ports, feas_rows, tg_onehots,
+             tg_dhs, asks.active, noise),
+        )
     return choices, scores, final_state
 
 
@@ -530,6 +542,7 @@ class CompactOverlay(NamedTuple):
     job_tgs: jnp.ndarray  # [J] int32 their task-group indices
 
 
+@jax.named_scope("expand_overlay")
 def _expand_overlay(class_ids, ov: CompactOverlay, n: int, g: int):
     """Device-side overlay reconstruction (one eval)."""
     classed = class_ids >= 0

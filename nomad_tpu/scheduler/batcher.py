@@ -38,7 +38,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .. import profile
+from .. import profile, trace
 from ..profile import ProfiledCondition, ProfiledLock
 
 MAX_BATCH = 64
@@ -95,7 +95,7 @@ NTA_REBUILD_ENTRYPOINTS = ("PlacementBatcher._build_device_base",)
 class _Request:
     __slots__ = ("token", "base", "overlay", "compact", "asks", "key",
                  "delta", "event", "choices", "scores", "error", "span",
-                 "ready_at")
+                 "ready_at", "arrived_at")
 
     def __init__(self, token, base, overlay, asks, key, delta=None,
                  compact=None, span=None):
@@ -112,6 +112,10 @@ class _Request:
         self.key = key
         self.delta = delta  # (parent_token, changed_rows) or None
         self.span = span  # (eval_id, trace_id) for the device.solve span
+        # When the request reached the batcher: the earliest of a
+        # batch's is where the device's idle gap stops being "no work"
+        # and starts being "batch wait" (_idle_parts).
+        self.arrived_at = time.monotonic()
         self.event = threading.Event()
         self.choices = None
         self.scores = None
@@ -170,6 +174,24 @@ def _pad_batch(n: int, max_batch: int) -> int:
     return max_batch
 
 
+def _idle_parts(last_end: float, first_arrival: float, closed: float,
+                issue: float) -> Tuple[float, float, float]:
+    """The device's idle gap before one dispatch, [last_end, issue] on
+    time.monotonic(), split by cause into seconds of (no_work,
+    batch_wait, stack): up to the arrival of the batch's first request
+    nothing was waiting for the chip; from there to the batch's close
+    it was the window and the cohort wait; from close to issue, host
+    stacking and base upload. Each boundary is clipped into the gap, so
+    the three sum to it whatever the order of the instants (a request
+    that arrived while the last program was still in flight has no
+    no_work part)."""
+    if issue <= last_end:
+        return 0.0, 0.0, 0.0
+    arrived = min(max(first_arrival, last_end), issue)
+    closed = min(max(closed, arrived), issue)
+    return arrived - last_end, closed - arrived, issue - closed
+
+
 class PlacementBatcher:
     """Coalesces placement_program calls across scheduler threads."""
 
@@ -209,16 +231,13 @@ class PlacementBatcher:
         self.base_delta_updates = 0  # guarded-by: _lock (derived bases)
         self.overlay_dispatches = 0  # guarded-by: _lock (shared-base)
         self.compact_dispatches = 0  # guarded-by: _lock (device expand)
-        self.pre_resolve_dispatches = 0  # guarded-by: _lock
-        # (PlacementConfig.pre_resolve: in-batch conflict pre-resolution)
-        # Per-dispatch cost breakdown (seconds/bytes, cumulative): the
-        # judge-facing proof of where a storm's wall-clock goes —
-        # host-side stacking, host->device payload size, dispatch
-        # issue, and the device round-trip (sync = transport + compute).
-        self.t_stack = 0.0  # guarded-by: _lock (np.stack of payloads)
+        # Per-dispatch cost breakdown (seconds/bytes, cumulative):
+        # host->device payload size, dispatch issue, and the device
+        # round-trip (sync = transport + compute). Host stacking and
+        # base upload are the device.idle.stack histogram and the
+        # nomad.stack / nomad.base_upload annotations.
         self.t_issue = 0.0  # guarded-by: _lock (jitted-call issue)
         self.t_sync = 0.0  # guarded-by: _lock (result fetch RTT)
-        self.t_upload = 0.0  # guarded-by: _lock (base uploads)
         self.bytes_overlay = 0.0  # guarded-by: _lock (dispatch payload)
         self.bytes_upload = 0.0  # guarded-by: _lock (upload payload)
         # EMA of the dispatch round-trip, drives the adaptive window.
@@ -234,6 +253,15 @@ class PlacementBatcher:
         # announcement and re-fragment its dispatch.
         self._cohort = 0  # guarded-by: _lock
         self._cohort_gen = 0  # guarded-by: _lock
+        # The device's idle time by cause (_issue): programs between
+        # issue and results on the host, over every shape key, and the
+        # end of the last such interval (0.0: none yet). A gap is only
+        # counted by the dispatch that ends it, and only when nothing
+        # else was in flight, so concurrent dispatchers never count one
+        # stretch twice.
+        self._in_flight = 0  # guarded-by: _lock
+        self._busy_until = 0.0  # guarded-by: _lock
+        self._issued = 0  # guarded-by: _lock (nomad.dispatch ordinal)
 
     def add_cohort(self, n: int) -> None:
         """Announce that `n` place() calls are on their way (the
@@ -464,7 +492,9 @@ class PlacementBatcher:
             # cache insert instead of paying a duplicate transfer.
             pending.wait(30.0)
         try:
-            dev, nbytes = self._build_device_base(token, base, delta)
+            with trace.annotation("nomad.base_upload",
+                                  delta=delta is not None):
+                dev, nbytes = self._build_device_base(token, base, delta)
         finally:
             with self._lock:
                 self._base_pending.pop(token, None)
@@ -539,11 +569,8 @@ class PlacementBatcher:
         return mesh
 
     def _build_device_base(self, token, base, delta):
-        import time as _time
-
         import jax
 
-        t0 = _time.perf_counter()
         nbytes = 0
         dev = None
         if delta is not None:
@@ -625,7 +652,6 @@ class PlacementBatcher:
         if not delta_derived:
             nbytes = sum(np.asarray(x).nbytes for x in base)
         with self._lock:
-            self.t_upload += _time.perf_counter() - t0
             self.bytes_upload += nbytes
             # Counters under the lock: builders of DIFFERENT tokens run
             # concurrently (the pending guard is per token) and += is
@@ -679,8 +705,6 @@ class PlacementBatcher:
         return parent, rows, done
 
     def _run_batch(self, batch: List[_Request], config) -> None:
-        import time as _time
-
         import jax
 
         from ..chaos import chaos
@@ -693,6 +717,9 @@ class PlacementBatcher:
             placement_program_jit,
         )
 
+        # The batch is closed: from here to the issue is host stacking
+        # and base upload (device.idle.stack).
+        closed = time.monotonic()
         if chaos.enabled:
             # 'delay' = a slow device for this dispatch; the adaptive
             # window sees the inflated RTT.
@@ -710,13 +737,9 @@ class PlacementBatcher:
             # against a stable snapshot — is exactly where re-uploading
             # the full [N,4] base every dispatch hurt most.
             req = batch[0]
-            t_solo = _time.perf_counter()
-            choices, scores, _ = placement_program_jit(
+            req.choices, req.scores, _ = self._issue(
+                batch, config, closed, placement_program_jit,
                 req.full_state(), req.asks, req.key, config)
-            req.choices = np.asarray(choices)
-            req.scores = np.asarray(scores)
-            self._record_solve(batch, config,
-                               _time.perf_counter() - t_solo, 1)
             return
 
         # Pad the batch axis up a ladder bucket (see BATCH_BUCKETS):
@@ -726,143 +749,178 @@ class PlacementBatcher:
         n_live = len(batch)
         pad_to = _pad_batch(n_live, self.max_batch)
         padded = batch + [batch[-1]] * (pad_to - n_live)
-
-        t0 = _time.perf_counter()
-        keys = np.stack([r.key for r in padded])
-        asks = jax.tree.map(lambda *xs: np.stack(xs), *[r.asks for r in padded])
         token = batch[0].token
-        payload = sum(x.nbytes for x in asks) + keys.nbytes
-        compact_dispatch = overlay_dispatch = False
-        if token is not None and all(r.token == token for r in batch):
-            # Shared-base fast path: base cached on device, only the
-            # per-eval payloads cross host->device this dispatch.
-            if batch[0].compact is not None:
-                # Compact overlays: class verdicts + sparse patches +
-                # job positions, expanded to the dense [B,N,G] masks ON
-                # DEVICE — a few KB per eval instead of ~100KB x G.
+        # Shared-base fast path: base cached on device, only the
+        # per-eval payloads cross host->device this dispatch.
+        shared = token is not None and all(r.token == token for r in batch)
+        # Compact overlays: class verdicts + sparse patches + job
+        # positions, expanded to the dense [B,N,G] masks ON DEVICE — a
+        # few KB per eval instead of ~100KB x G.
+        compact = shared and batch[0].compact is not None
+
+        def stacked(*xs):
+            return np.stack(xs)
+
+        with trace.annotation("nomad.stack", lanes=n_live):
+            keys = np.stack([r.key for r in padded])
+            asks = jax.tree.map(stacked, *[r.asks for r in padded])
+            if compact:
+                per_eval = jax.tree.map(
+                    stacked, *[r.compact for r in padded])
+            elif shared:
+                per_eval = tuple(np.stack([r.overlay[i] for r in padded])
+                                 for i in range(3))
+            else:
+                per_eval = jax.tree.map(
+                    stacked, *[r.full_state() for r in padded])
+        payload = (sum(x.nbytes for x in asks) + keys.nbytes
+                   + sum(x.nbytes for x in per_eval))
+        if compact:
+            fused = self._claim_fused_delta(token, batch[0].delta)
+            if fused is not None:
+                # Base delta FUSED into this dispatch: the changed rows
+                # ride the call, the derived base comes back as device
+                # residents — zero extra round-trips.
                 from ..ops.binpack import (
                     batched_placement_program_compact_delta,
                 )
 
-                overlays = jax.tree.map(
-                    lambda *xs: np.stack(xs),
-                    *[r.compact for r in padded])
-                payload += sum(x.nbytes for x in overlays)
-                fused = self._claim_fused_delta(token, batch[0].delta)
-                if fused is not None:
-                    # Base delta FUSED into this dispatch: the changed
-                    # rows ride the call, the derived base comes back
-                    # as device residents — zero extra round-trips.
-                    parent, rows, done = fused
-                    try:
-                        rows_p = _pad_rows(rows)
-                        hb = batch[0].base
-                        util_rows = np.asarray(hb[2])[rows_p]
-                        bw_rows = np.asarray(hb[4])[rows_p]
-                        ports_rows = np.asarray(hb[5])[rows_p]
-                        ok_rows = np.asarray(hb[6])[rows_p]
-                        payload += (rows_p.nbytes + util_rows.nbytes
-                                    + bw_rows.nbytes + ports_rows.nbytes
-                                    + ok_rows.nbytes)
-                        t1 = _time.perf_counter()
-                        (choices, scores, util2, bw2, ports2, ok2) = \
-                            batched_placement_program_compact_delta(
-                                parent[0], parent[1], parent[2],
-                                parent[3], parent[4], parent[5],
-                                parent[6], parent[7], rows_p, util_rows,
-                                bw_rows, ports_rows, ok_rows, overlays,
-                                asks, keys, config)
-                        dev = (parent[0], parent[1], util2, parent[3],
-                               bw2, ports2, ok2, parent[7])
-                        with self._lock:
-                            self.base_delta_updates += 1
-                            while len(self._device_bases) >= DEVICE_BASE_CACHE:
-                                self._device_bases.popitem(last=False)
-                            self._device_bases[token] = dev
-                    finally:
-                        with self._lock:
-                            self._base_pending.pop(token, None)
-                        done.set()
-                else:
-                    dev, _ = self._device_base(
-                        token, batch[0].base, batch[0].delta)
-                    t1 = _time.perf_counter()
-                    choices, scores, _ = batched_placement_program_compact(
-                        dev[0], dev[1], dev[2], dev[3], dev[4], dev[5],
-                        dev[6], dev[7], overlays, asks, keys, config)
-                compact_dispatch = True
+                parent, rows, done = fused
+
+                def publish() -> None:
+                    with self._lock:
+                        self._base_pending.pop(token, None)
+                    done.set()
+
+                def cache_derived(out) -> None:
+                    # As soon as the program is issued, before its
+                    # results are pulled: dispatchers waiting on this
+                    # token need the derived base, not the placements.
+                    dev = (parent[0], parent[1], out[2], parent[3],
+                           out[3], out[4], out[5], parent[7])
+                    with self._lock:
+                        self.base_delta_updates += 1
+                        while len(self._device_bases) >= DEVICE_BASE_CACHE:
+                            self._device_bases.popitem(last=False)
+                        self._device_bases[token] = dev
+                    publish()
+
+                try:
+                    rows_p = _pad_rows(rows)
+                    hb = batch[0].base
+                    row_payload = tuple(np.asarray(hb[i])[rows_p]
+                                        for i in (2, 4, 5, 6))
+                    payload += rows_p.nbytes + sum(
+                        x.nbytes for x in row_payload)
+                    choices, scores, times = self._issue(
+                        batch, config, closed,
+                        batched_placement_program_compact_delta,
+                        *parent[:8], rows_p, *row_payload, per_eval,
+                        asks, keys, config, on_issued=cache_derived)
+                finally:
+                    publish()
             else:
                 dev, _ = self._device_base(
                     token, batch[0].base, batch[0].delta)
-                state = NodeState(
-                    capacity=dev[0], sched_capacity=dev[1], util=dev[2],
-                    bw_avail=dev[3], bw_used=dev[4], ports_free=dev[5],
-                    job_count=np.stack([r.overlay[0] for r in padded]),
-                    tg_count=np.stack([r.overlay[1] for r in padded]),
-                    feasible=np.stack([r.overlay[2] for r in padded]),
-                    node_ok=dev[6],
-                )
-                payload += (state.job_count.nbytes + state.tg_count.nbytes
-                            + state.feasible.nbytes)
-                t1 = _time.perf_counter()
-                choices, scores, _ = batched_placement_program_overlay(
-                    state, asks, keys, config)
-            overlay_dispatch = True
+                choices, scores, times = self._issue(
+                    batch, config, closed,
+                    batched_placement_program_compact, *dev[:8],
+                    per_eval, asks, keys, config)
+        elif shared:
+            dev, _ = self._device_base(
+                token, batch[0].base, batch[0].delta)
+            state = NodeState(
+                capacity=dev[0], sched_capacity=dev[1], util=dev[2],
+                bw_avail=dev[3], bw_used=dev[4], ports_free=dev[5],
+                job_count=per_eval[0], tg_count=per_eval[1],
+                feasible=per_eval[2], node_ok=dev[6],
+            )
+            choices, scores, times = self._issue(
+                batch, config, closed, batched_placement_program_overlay,
+                state, asks, keys, config)
         else:
-            states = jax.tree.map(
-                lambda *xs: np.stack(xs), *[r.full_state() for r in padded])
-            payload += sum(x.nbytes for x in states)
-            t1 = _time.perf_counter()
-            choices, scores, _ = batched_placement_program(
-                states, asks, keys, config)
-        t2 = _time.perf_counter()
-        choices = np.asarray(choices)
-        scores = np.asarray(scores)
-        t3 = _time.perf_counter()
+            choices, scores, times = self._issue(
+                batch, config, closed, batched_placement_program,
+                per_eval, asks, keys, config)
+        t1, t2, t3 = times
         with self._lock:
-            self.t_stack += t1 - t0
             self.t_issue += t2 - t1
             self.t_sync += t3 - t2
             self.bytes_overlay += payload
             # Path counters under the lock: dispatchers of different
             # shape keys run concurrently and += is not atomic across a
             # GIL switch.
-            self.compact_dispatches += compact_dispatch
-            self.overlay_dispatches += overlay_dispatch
-            self.pre_resolve_dispatches += (
-                overlay_dispatch and bool(getattr(config, "pre_resolve",
-                                                  False)))
+            self.compact_dispatches += compact
+            self.overlay_dispatches += shared
             sync = t3 - t2
             self._sync_ema = (sync if self._sync_ema == 0.0
                               else 0.7 * self._sync_ema + 0.3 * sync)
         for i, req in enumerate(batch):
             req.choices = choices[i]
             req.scores = scores[i]
-        self._record_solve(batch, config, t3 - t1, n_live)
 
-    def _record_solve(self, batch, config, dur: float,
-                      n_live: int) -> None:
+    def _issue(self, batch: List[_Request], config, closed: float, program,
+               *args, on_issued=None):
+        """Issue one placement program and pull its placements back to
+        the host: (choices, scores, (t_issue, t_issued, t_results)), all
+        on time.monotonic(). `on_issued(outputs)` runs between the issue
+        and the pull.
+
+        The interval from issue to results is when the device has this
+        batch's work; everything the measurement knows of the device
+        from the host's side hangs on it: the `device.solve` span, the
+        `nomad.dispatch` annotation, and the three `device.idle.*`
+        parts of the gap since the last such interval ended
+        (_idle_parts; nothing while another program is in flight)."""
+        first_arrival = min(r.arrived_at for r in batch)
+        with self._lock:
+            t1 = time.monotonic()
+            self._issued += 1
+            ordinal = self._issued
+            idle = None
+            if self._in_flight == 0 and self._busy_until:
+                idle = _idle_parts(self._busy_until, first_arrival,
+                                   closed, t1)
+            self._in_flight += 1
+        try:
+            with trace.annotation(
+                    "nomad.dispatch", ordinal=ordinal, lanes=len(batch),
+                    program=getattr(program, "__name__", "program")):
+                out = program(*args)
+                t2 = time.monotonic()
+                if on_issued is not None:
+                    on_issued(out)
+                choices = np.asarray(out[0])
+                scores = np.asarray(out[1])
+        finally:
+            with self._lock:
+                t3 = time.monotonic()
+                self._in_flight -= 1
+                self._busy_until = t3
+        if idle is not None:
+            recorder = trace.get_recorder()
+            for stage, seconds in zip(trace.DEVICE_IDLE_STAGES, idle):
+                recorder.observe_stage(stage, seconds * 1000.0)
+        self._record_solve(batch, config, t1, t3)
+        return choices, scores, (t1, t2, t3)
+
+    def _record_solve(self, batch, config, t1: float, t3: float) -> None:
         """device.solve spans for the requests that carry a trace
         identity: the jitted solve's issue + device sync window,
         kernel-annotated — the slice of device.dispatch that IS the
-        placement kernel (batch-wait and host stacking excluded). The
-        duration was measured on perf_counter; the span is anchored to
-        the monotonic clock the recorder shares by subtracting it from
-        'now' (both clocks tick at the same rate)."""
+        placement kernel (batch-wait and host stacking excluded). Both
+        ends were taken on time.monotonic(), the recorder's clock, where
+        the issue happened and where the results arrived: the same
+        instants the device.idle.* parts and nomad.dispatch use."""
         if not any(r.span for r in batch):
             return
-        import time as _time
-
-        from .. import trace
-
-        end = _time.monotonic()
         ann = {"kernel": getattr(config, "kernel", "greedy"),
-               "batch": n_live}
+               "batch": len(batch)}
         for req in batch:
             if req.span:
                 trace.record_span(
-                    req.span[0], trace.STAGE_DEVICE_SOLVE, end - dur,
-                    end, ann=ann, trace_id=req.span[1])
+                    req.span[0], trace.STAGE_DEVICE_SOLVE, t1, t3,
+                    ann=ann, trace_id=req.span[1])
 
     def _accumulate(self, shape_key, window: float) -> None:
         """Wait up to `window` for requests to pile on — but a FULL
@@ -1055,16 +1113,13 @@ class PlacementBatcher:
                 "base_delta_updates": self.base_delta_updates,
                 "overlay_dispatches": self.overlay_dispatches,
                 "compact_dispatches": self.compact_dispatches,
-                "pre_resolve_dispatches": self.pre_resolve_dispatches,
                 "sharded_bases": self.sharded_bases,
                 "unsharded_fallbacks": self.unsharded_fallbacks,
                 # Cost breakdown (cumulative; divide by `dispatches`
                 # for per-dispatch): microseconds so the config-6
                 # delta print stays integral.
-                "stack_us": int(self.t_stack * 1e6),
                 "issue_us": int(self.t_issue * 1e6),
                 "sync_us": int(self.t_sync * 1e6),
-                "upload_us": int(self.t_upload * 1e6),
                 "payload_bytes": int(self.bytes_overlay),
                 "upload_bytes": int(self.bytes_upload),
                 # Compiled XLA programs this process holds (all the

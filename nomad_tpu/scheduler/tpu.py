@@ -202,8 +202,11 @@ class BatchedTPUScheduler(GenericScheduler):
             # 1-3 ask replan that still needs the eviction leg.
             # Counted: every route off the device is visible from
             # outside (/v1/metrics), like the fault and breaker routes.
+            # In allocations and in evals: a rate per eval needs the
+            # second.
             metrics.incr_counter(
                 ("scheduler", "small_route_host"), len(bulk))
+            metrics.incr_counter(("scheduler", "small_route_host_evals"))
             self._repay_cohort()
             super()._compute_placements(bulk)
             return

@@ -232,6 +232,11 @@ class PlanApplier:
                     inflight = None
                 optimistic = None  # queue drained: next gets fresh state
                 continue
+            # create=False, as the applier's other spans: a remote
+            # (follower-worker) plan's trace lives in its own process.
+            trace.record_span(pending.plan.eval_id,
+                              trace.STAGE_PLAN_QUEUE_WAIT,
+                              pending.enqueue_time, create=False)
             if inflight is None:
                 # Nothing outstanding: every plan verifies against
                 # fresh state (the pre-pipelining invariant). The
@@ -244,7 +249,9 @@ class PlanApplier:
                 # Verified against the optimistic view WHILE the
                 # previous plan's raft commit is still in flight — the
                 # reference's verify-(N+1)-during-commit-(N) overlap.
-                result = self._evaluate_plan(optimistic, pending.plan)
+                with trace.annotation("nomad.plan_apply",
+                                      phase="evaluate"):
+                    result = self._evaluate_plan(optimistic, pending.plan)
                 metrics.measure_since(("plan", "evaluate"), start)
             except Exception as e:  # noqa: BLE001 - fail the one plan
                 self.logger.exception("plan evaluate failed")
@@ -481,9 +488,11 @@ class PlanApplier:
             n_preempted += len(victim_list)
         for alloc_list in result.node_allocation.values():
             allocs.extend(alloc_list)
-        index = self.log.apply(
-            ALLOC_UPDATE, {"allocs": allocs, "job": plan.job}
-        )
+        with trace.annotation("nomad.plan_apply", phase="commit",
+                              allocs=len(allocs)):
+            index = self.log.apply(
+                ALLOC_UPDATE, {"allocs": allocs, "job": plan.job}
+            )
         if n_preempted:
             from ..migrate import note_preemption_committed
 

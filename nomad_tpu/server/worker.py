@@ -119,7 +119,11 @@ class EvalSession:
         return result, None
 
     def update_eval(self, ev: Evaluation) -> None:
+        t0 = time.monotonic()
         self.server.eval_update([ev])
+        trace.record_span(self.eval.id, trace.STAGE_EVAL_UPDATE, t0,
+                          ann={"status": ev.status},
+                          trace_id=self.eval.trace_id)
 
     def create_eval(self, ev: Evaluation) -> None:
         ev.snapshot_index = self.server.fsm.state.latest_index()

@@ -14,11 +14,17 @@ recorder; all of them are no-ops when the recorder is disabled and
 never raise into the instrumented path.
 """
 
+import contextlib
+import sys
+
 from .recorder import FlightRecorder  # noqa: F401
 from .span import (  # noqa: F401
     ALL_STAGES,
+    DEVICE_IDLE_STAGES,
     LIFECYCLE_CORE_STAGES,
+    SELF_SUFFIX,
     STAGE_ALLOC_UPSERT,
+    STAGE_API_REGISTER,
     STAGE_BROKER_WAIT,
     STAGE_DEFRAG_SOLVE,
     STAGE_DEVICE_DISPATCH,
@@ -26,13 +32,20 @@ from .span import (  # noqa: F401
     STAGE_DEVICE_TRANSFER,
     STAGE_DISPATCH_ACCUMULATE,
     STAGE_DISPATCH_LAUNCH,
+    STAGE_DISPATCH_POOL_WAIT,
+    STAGE_EVAL_UNCOVERED,
+    STAGE_EVAL_UPDATE,
     STAGE_GANG_SELECT,
+    STAGE_IDLE_BATCH_WAIT,
+    STAGE_IDLE_NO_WORK,
+    STAGE_IDLE_STACK,
     STAGE_MATRIX_BUILD,
     STAGE_MATRIX_COMPRESS,
     STAGE_MATRIX_UPDATE,
     STAGE_MIGRATE_PLACE,
     STAGE_PLAN_COMMIT,
     STAGE_PLAN_EVALUATE,
+    STAGE_PLAN_QUEUE_WAIT,
     STAGE_PLAN_SUBMIT,
     STAGE_PREEMPT_SELECT,
     STAGE_SCHED_PROCESS,
@@ -46,6 +59,24 @@ _recorder = FlightRecorder()
 
 def get_recorder() -> FlightRecorder:
     return _recorder
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **kwargs):
+    """A `jax.profiler.TraceAnnotation`: a named region of this host
+    thread on the profiler's clock, the same one the device's events
+    are on, so that an idle gap of the device can be named by what the
+    host did across it (tools/traceconv.py --xplane). For batch-level
+    regions only (the `nomad.*` names in README.md), never per eval.
+    While no profile is being taken it costs one TraceMe construction
+    (~0.6 us); in a process that has not loaded JAX (an agent without
+    -tpu) it is nothing, and this module does not load it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    return jax.profiler.TraceAnnotation(name, **kwargs)
 
 
 def mark(eval_id: str, trace_id: str = "") -> None:

@@ -19,7 +19,19 @@ grow without bound:
   spans past the cap are counted, not stored.
 - per-stage latency histograms are fixed log-bucket arrays
   (utils/metrics.py bucket math) so p50/p95/p99 are computable at any
-  time from O(buckets) memory.
+  time from O(buckets) memory. They live IN the stripes, under the
+  stripe's lock, and are merged on read: a span's sample lands in the
+  critical section that stores the span, so recording takes one lock,
+  not two. (One global histogram lock was taken by every record call of
+  every thread; in the storm cell on the chip's host its contended
+  waits were 16-25 ms at the median, PERF.md section 6, PR 25.)
+- the account of a finished trace (`<stage>.self`, `eval.uncovered`;
+  _account) is computed at complete() from the tree that is built there
+  anyway: bounded by SPAN_CAP, and fed to the stripe's stage table in
+  the critical section that publishes the trace.
+- the end-to-end histogram is one (tail-keep compares every trace with
+  the p99 of all of them), under the tail ring's lock, which complete()
+  takes once anyway.
 
 The discipline is machine-enforced: ``NTA_RECORD_PATH`` names the
 record-path entrypoints, and ntalint's ``record-path-blocking`` rule
@@ -42,16 +54,26 @@ from ..utils.metrics import (
     LatencyHist,
     hist_percentile,
 )
-from .span import make_span, span_to_dict
+from .span import (
+    SELF_SUFFIX,
+    STAGE_EVAL_UNCOVERED,
+    make_span,
+    span_to_dict,
+)
 
 N_STRIPES = 8
 RING_PER_STRIPE = 64     # completed traces kept per stripe (recent)
 TAIL_KEEP = 32           # slow traces kept in the tail ring
-SPAN_CAP = 32            # spans stored per trace (excess counted)
+SPAN_CAP = 96            # spans stored per trace (excess counted): an
+#   attempt through the dense pipeline is 16 spans, and the evals that
+#   define the tail are those a plan conflict sends round again (32 cut
+#   every tail-kept trace of the storm cell short, on the chip)
 FAULT_CAP = 8            # chaos fault annotations stored per trace
 ACTIVE_PER_STRIPE = 256  # in-flight traces per stripe before eviction
 TAIL_MIN_SAMPLES = 64    # e2e samples before tail-keep engages
-MAX_STAGES = 64          # distinct stage histograms (instrumentation-bounded)
+MAX_STAGES = 64          # distinct stage histograms (instrumentation-
+#   bounded: 22 eval stages, each with a `.self` twin at worst, plus
+#   eval.uncovered, device.idle.* and read.* is 50)
 
 # ntalint record-path manifest (analysis/robustness.py
 # record-path-blocking): every function reachable from these — the
@@ -69,6 +91,57 @@ NTA_RECORD_PATH = (
 # The shared fixed-size log-bucket histogram (utils/metrics.py
 # LatencyHist; one implementation for the recorder AND the profiler).
 _Hist = LatencyHist
+
+
+def _observe(hists: Dict[str, "_Hist"], stage: str, ms: float) -> None:
+    """One sample into a stripe's stage table, whose lock the caller
+    holds. The table is bounded by MAX_STAGES: a stage past it is not
+    counted."""
+    h = hists.get(stage)
+    if h is None:
+        if len(hists) >= MAX_STAGES:
+            return
+        h = _Hist()
+        hists[stage] = h
+    h.observe(ms)
+
+
+def _union_ms(intervals, lo: float, hi: float) -> float:
+    """Length in ms of the union of `intervals` (sorted by start),
+    clipped to [lo, hi]. One pass; overlapping children count once."""
+    total = 0.0
+    cur = lo
+    for t0, t1 in intervals:
+        if t0 < cur:
+            t0 = cur
+        if t1 > hi:
+            t1 = hi
+        if t1 > t0:
+            total += t1 - t0
+            cur = t1
+    return total * 1000.0
+
+
+def _account(spans, parents, origin: float, end: float):
+    """The account of one finished trace: `(rows, uncovered_ms)`, a row
+    `(stage, self_ms, has_children)` for each span (in the order of
+    `spans`, which is sorted by start). Self time is the span's duration
+    minus the UNION of its direct children's intervals (children run in
+    parallel: device.transfer beside the prologue, the applier's spans
+    beside the waiting worker); uncovered is e2e minus the union of
+    every span. Bounded by SPAN_CAP."""
+    kids: List[list] = [[] for _ in spans]
+    for j, parent in enumerate(parents):
+        if parent is not None:
+            kids[parent].append((spans[j][1], spans[j][2]))
+    rows = []
+    for s, children in zip(spans, kids):
+        self_ms = (s[2] - s[1]) * 1000.0
+        if children:
+            self_ms = max(0.0, self_ms - _union_ms(children, s[1], s[2]))
+        rows.append((s[0], self_ms, bool(children)))
+    covered = _union_ms([(s[1], s[2]) for s in spans], origin, end)
+    return rows, max(0.0, (end - origin) * 1000.0 - covered)
 
 
 class _Trace:
@@ -94,7 +167,7 @@ class _Trace:
 
 class _Stripe:
     __slots__ = ("lock", "active", "ring", "ring_idx", "evicted",
-                 "dropped_spans")
+                 "dropped_spans", "hists")
 
     def __init__(self):
         # Profiled (nomad_tpu/profile): the stripes are taken under the
@@ -106,6 +179,8 @@ class _Stripe:
         self.ring_idx = 0  # guarded-by: lock (monotonic; slot = idx % K)
         self.evicted = 0  # guarded-by: lock (active-cap evictions)
         self.dropped_spans = 0  # guarded-by: lock
+        # This stripe's share of the stage table (merged on read).
+        self.hists: Dict[str, _Hist] = {}  # guarded-by: lock
 
 
 class FlightRecorder:
@@ -115,10 +190,11 @@ class FlightRecorder:
         # not, either is fine.
         self.enabled = True
         self._stripes = [_Stripe() for _ in range(N_STRIPES)]
-        self._hist_lock = ProfiledLock("trace.recorder.hist")
-        self._hists: Dict[str, _Hist] = {}  # guarded-by: _hist_lock
-        self._e2e = _Hist()  # guarded-by: _hist_lock
         self._tail_lock = ProfiledLock("trace.recorder.tail")
+        self._e2e = _Hist()  # guarded-by: _tail_lock
+        # Stages that have had a child span (each has a `.self` row).
+        # Replaced, never mutated: complete() reads it outside the lock.
+        self._parent_stages: frozenset = frozenset()  # guarded-by: _tail_lock
         self._tail: List[Optional[dict]] = [None] * TAIL_KEEP
         self._tail_idx = 0  # guarded-by: _tail_lock
         self._completed = 0  # guarded-by: _tail_lock (lifetime count)
@@ -164,7 +240,6 @@ class FlightRecorder:
             return
         now = time.monotonic()
         stripe = self._stripe_for(eval_id)
-        dur_ms = None
         with stripe.lock:
             entry = stripe.active.get(eval_id)
             if entry is None or entry.enqueued_at is None:
@@ -172,8 +247,6 @@ class FlightRecorder:
             t0 = entry.enqueued_at
             entry.enqueued_at = None
             self._store_span_locked(stripe, entry, stage, t0, now, ann)
-            dur_ms = (now - t0) * 1000.0
-        self._hist_add(stage, dur_ms)
 
     def record_span(self, eval_id: str, stage: str, t0: float,
                     t1: Optional[float] = None,
@@ -201,7 +274,6 @@ class FlightRecorder:
                 if entry is None:
                     return
             self._store_span_locked(stripe, entry, stage, t0, t1, ann)
-        self._hist_add(stage, (t1 - t0) * 1000.0)
 
     def _store_span_locked(self, stripe: _Stripe, entry: _Trace,
                            stage: str, t0: float, t1: float,
@@ -220,6 +292,9 @@ class FlightRecorder:
         else:
             entry.dropped_spans += 1
             stripe.dropped_spans += 1
+        # The stage table takes the sample either way: a span past the
+        # cap is lost to the tree, not to the percentiles.
+        _observe(stripe.hists, stage, (max(t1, t0) - t0) * 1000.0)
 
     def annotate_fault(self, eval_id: str, site: str, seq: int,
                        kind: str) -> None:
@@ -247,19 +322,9 @@ class FlightRecorder:
         and must keep measuring the eval lifecycle only."""
         if not self.enabled:
             return
-        self._hist_add(stage, ms)
-
-    def _hist_add(self, stage: str, ms: Optional[float]) -> None:
-        if ms is None:
-            return
-        with self._hist_lock:
-            h = self._hists.get(stage)
-            if h is None:
-                if len(self._hists) >= MAX_STAGES:
-                    return
-                h = _Hist()
-                self._hists[stage] = h
-            h.observe(ms)
+        stripe = self._stripes[hash(stage) % N_STRIPES]
+        with stripe.lock:
+            _observe(stripe.hists, stage, ms)
 
     def complete(self, eval_id: str, status: str = "complete") -> None:
         """Close the eval's trace: finalize the span tree, fold its e2e
@@ -277,35 +342,48 @@ class FlightRecorder:
             entry = stripe.active.pop(eval_id, None)
             if entry is None:
                 return
-            done = self._finalize_locked(entry, now, status)
+            done, (rows, uncovered_ms) = self._finalize_locked(
+                entry, now, status)
         dur_ms = done["duration_ms"]
-        keep_tail = False
-        with self._hist_lock:
+        with self._tail_lock:
+            # Which spans feed a `.self` row: those with children, and
+            # the childless instances of a stage that has had children,
+            # with their duration, so that the row holds every instance
+            # of the stage and its mass is the stage's exclusive time.
+            for stage, _self_ms, has_children in rows:
+                if has_children and stage not in self._parent_stages \
+                        and len(self._parent_stages) < MAX_STAGES:
+                    self._parent_stages = self._parent_stages | {stage}
+            parent_stages = self._parent_stages
             # p99 against the distribution SO FAR (excluding this
             # sample): an outlier compared against a p99 that already
             # contains it would sit inside its own bucket's bound and
             # never qualify.
-            if self._e2e.count >= TAIL_MIN_SAMPLES:
-                p99 = hist_percentile(
-                    self._e2e.buckets, self._e2e.count, 0.99)
-                keep_tail = dur_ms >= p99
+            keep_tail = (
+                self._e2e.count >= TAIL_MIN_SAMPLES
+                and dur_ms >= hist_percentile(
+                    self._e2e.buckets, self._e2e.count, 0.99))
             self._e2e.observe(dur_ms)
-        if keep_tail:
-            done["tail_kept"] = True
-        with stripe.lock:
-            stripe.ring[stripe.ring_idx % RING_PER_STRIPE] = done
-            stripe.ring_idx += 1
-        with self._tail_lock:
             self._completed += 1
             if keep_tail:
+                done["tail_kept"] = True
                 self._tail[self._tail_idx % TAIL_KEEP] = done
                 self._tail_idx += 1
+        with stripe.lock:
+            # The trace's account goes to the stage table in the
+            # critical section that publishes it.
+            hists = stripe.hists
+            for stage, self_ms, _has_children in rows:
+                if stage in parent_stages:
+                    _observe(hists, stage + SELF_SUFFIX, self_ms)
+            _observe(hists, STAGE_EVAL_UNCOVERED, uncovered_ms)
+            stripe.ring[stripe.ring_idx % RING_PER_STRIPE] = done
+            stripe.ring_idx += 1
 
-    def _finalize_locked(self, entry: _Trace, now: float,
-                         status: str) -> dict:
-        """Materialize one immutable dict for the completed trace. Runs
-        under the stripe lock but does bounded work only (SPAN_CAP x
-        FAULT_CAP)."""
+    def _finalize_locked(self, entry: _Trace, now: float, status: str):
+        """Materialize one immutable dict for the completed trace, and
+        its account (_account). Runs under the stripe lock but does
+        bounded work only (SPAN_CAP x FAULT_CAP, SPAN_CAP squared)."""
         spans = [entry.spans[i] for i in range(entry.n_spans)]
         spans.sort(key=lambda s: (s[1], -s[2]))
         faults = [entry.faults[i] for i in range(entry.n_faults)]
@@ -338,6 +416,7 @@ class FlightRecorder:
         # reads back as a tree (scheduler.process contains
         # matrix.build / device.dispatch / plan.submit, which contains
         # plan.evaluate / plan.commit / fsm.alloc_upsert).
+        parents: List[Optional[int]] = [None] * len(spans)
         for i, s in enumerate(spans):
             parent = None
             parent_len = None
@@ -349,8 +428,14 @@ class FlightRecorder:
                     if (parent is None or plen < parent_len
                             or (plen == parent_len and j < i)):
                         parent, parent_len = j, plen
+            parents[i] = parent
             dicts[i]["parent"] = (spans[parent][0]
                                   if parent is not None else None)
+        account = _account(spans, parents, origin, end)
+        for span_dict, (_stage, self_ms, has_children) in zip(
+                dicts, account[0]):
+            if has_children:
+                span_dict["self_ms"] = round(self_ms, 3)
         uncovered = [f for fi, f in enumerate(faults)
                      if not covered_flags[fi]]
         out = {
@@ -359,6 +444,7 @@ class FlightRecorder:
             "status": status,
             "start_unix": round(entry.wall_start, 6),
             "duration_ms": round((end - origin) * 1000.0, 3),
+            "uncovered_ms": round(account[1], 3),
             "spans": dicts,
             "dropped_spans": entry.dropped_spans,
         }
@@ -367,7 +453,7 @@ class FlightRecorder:
                 {"site": site, "ordinal": seq, "kind": kind}
                 for (_t, site, seq, kind) in uncovered
             ]
-        return out
+        return out, account
 
     # ------------------------------------------------------ read side
 
@@ -407,35 +493,61 @@ class FlightRecorder:
         """Rolling end-to-end p99 in ms (0.0 before any completions).
         Cheap single-histogram read for the pressure monitor
         (nomad_tpu/admission) — stage_stats() walks every stage."""
-        with self._hist_lock:
+        with self._tail_lock:
             if not self._e2e.count:
                 return 0.0
             return hist_percentile(
                 self._e2e.buckets, self._e2e.count, 0.99)
 
     def stage_buckets(self, stage: str):
-        """(count, bucket-list copy) of one stage's lifetime histogram,
-        or None before any sample. The rolling-window consumers
+        """(count, bucket-list copy) of one stage's lifetime histogram
+        (`e2e` names the end-to-end one, as in stage_stats()), or None
+        before any sample. The rolling-window consumers
         (kernels/quality.py's per-interval queueing gauge) snapshot
         this at window reset and percentile over the bucket DELTA —
         lifetime exposition stays monotonic for Prometheus while the
         window reads only what landed since the reset."""
-        with self._hist_lock:
-            h = self._hists.get(stage)
-            if h is None or not h.count:
-                return None
-            return h.count, list(h.buckets)
+        merged = self._merged(stage).get(stage)
+        if merged is None or not merged[0]:
+            return None
+        return merged[0], merged[3]
+
+    def _merged(self, only: Optional[str] = None) -> Dict[str, list]:
+        """{stage: [count, total, max, buckets]} summed over the stripes
+        (`only`: that one stage), with the `e2e` row. Each stripe is
+        read under its own lock, so the table is a consistent cut per
+        stripe, not across them: good for percentiles and for window
+        differences, which is all it is read for."""
+        out: Dict[str, list] = {}
+        for stripe in self._stripes:
+            with stripe.lock:
+                if only is None:
+                    items = list(stripe.hists.items())
+                else:
+                    h = stripe.hists.get(only)
+                    items = [] if h is None else [(only, h)]
+                items = [(name, h.count, h.total, h.max, list(h.buckets))
+                         for name, h in items]
+            for name, count, total, mx, buckets in items:
+                row = out.get(name)
+                if row is None:
+                    out[name] = [count, total, mx, buckets]
+                else:
+                    row[0] += count
+                    row[1] += total
+                    row[2] = max(row[2], mx)
+                    row[3] = [a + b for a, b in zip(row[3], buckets)]
+        if only is None or only == "e2e":
+            with self._tail_lock:
+                out["e2e"] = [self._e2e.count, self._e2e.total,
+                              self._e2e.max, list(self._e2e.buckets)]
+        return out
 
     def stage_stats(self) -> Dict[str, dict]:
         """Per-stage latency table: count/mean/max and log-bucket
         p50/p95/p99, all in milliseconds."""
-        with self._hist_lock:
-            items = [(name, h.count, h.total, h.max, list(h.buckets))
-                     for name, h in self._hists.items()]
-            items.append(("e2e", self._e2e.count, self._e2e.total,
-                          self._e2e.max, list(self._e2e.buckets)))
         out: Dict[str, dict] = {}
-        for name, count, total, mx, buckets in items:
+        for name, (count, total, mx, buckets) in self._merged().items():
             if not count:
                 continue
             out[name] = {
@@ -483,10 +595,10 @@ class FlightRecorder:
                 stripe.ring_idx = 0
                 stripe.evicted = 0
                 stripe.dropped_spans = 0
-        with self._hist_lock:
-            self._hists = {}
-            self._e2e = _Hist()
+                stripe.hists = {}
         with self._tail_lock:
+            self._e2e = _Hist()
+            self._parent_stages = frozenset()
             self._tail = [None] * TAIL_KEEP
             self._tail_idx = 0
             self._completed = 0

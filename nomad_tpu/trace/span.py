@@ -21,9 +21,15 @@ from typing import Optional, Tuple
 # (job stop) skip fsm.alloc_upsert, and dispatch.* only appear when the
 # central pipeline handles the eval.
 
+STAGE_API_REGISTER = "api.register"        # HTTP register handler entered
+#   -> response ready (api/http.py; recorded at the handler's end onto
+#   the trace the broker's mark opened inside the raft apply, so e2e
+#   starts at the request)
 STAGE_BROKER_WAIT = "broker.wait"          # enqueue -> dequeue
 STAGE_DISPATCH_ACCUMULATE = "dispatch.accumulate"  # pipeline admit -> batch cut
 STAGE_DISPATCH_LAUNCH = "dispatch.launch"  # launch prologue (catch-up + snapshot)
+STAGE_DISPATCH_POOL_WAIT = "dispatch.pool_wait"  # launch fan-out ->
+#   the eval's stage thread running (the eval_pool hand-off)
 STAGE_SCHED_PROCESS = "scheduler.process"  # scheduler invoke, end to end
 STAGE_MATRIX_BUILD = "matrix.build"        # ClusterMatrix + ask construction
 STAGE_MATRIX_UPDATE = "matrix.update"      # incremental delta vs full rebuild
@@ -48,14 +54,41 @@ STAGE_DEFRAG_SOLVE = "defrag.solve"        # one defrag-loop round's
 #   (nomad_tpu/defrag; ann: movable, moves, gain, warm, solve_ms) —
 #   recorded on its own per-round trace, not an eval's
 STAGE_PLAN_SUBMIT = "plan.submit"          # plan queue wait + commit (worker view)
+STAGE_PLAN_QUEUE_WAIT = "plan.queue_wait"  # PlanQueue.enqueue -> the
+#   applier taking the plan
 STAGE_PLAN_EVALUATE = "plan.evaluate"      # applier per-node verification
 STAGE_PLAN_COMMIT = "plan.commit"          # raft apply of the accepted plan
 STAGE_ALLOC_UPSERT = "fsm.alloc_upsert"    # state-store alloc write
+STAGE_EVAL_UPDATE = "eval.update"          # the eval's terminal status
+#   write through raft (inside scheduler.process)
+
+# Derived at complete() from the finished tree, never recorded by a call
+# site (recorder.py _account): `<stage>.self` is a span's duration minus
+# the union of its children's intervals, fed for every stage that has
+# had children; `eval.uncovered` is the part of e2e no span covers.
+SELF_SUFFIX = ".self"
+STAGE_EVAL_UNCOVERED = "eval.uncovered"
+
+# The device's idle time on the host clock, split by cause per dispatch
+# (scheduler/batcher.py): fed through observe_stage, no eval's tree.
+STAGE_IDLE_NO_WORK = "device.idle.no_work"        # last results ->
+#   this batch's first request arrives (nothing was waiting for the chip)
+STAGE_IDLE_BATCH_WAIT = "device.idle.batch_wait"  # first arrival ->
+#   batch close (window and cohort wait)
+STAGE_IDLE_STACK = "device.idle.stack"            # batch close -> issue
+#   (host stacking and base upload)
+DEVICE_IDLE_STAGES = (
+    STAGE_IDLE_NO_WORK,
+    STAGE_IDLE_BATCH_WAIT,
+    STAGE_IDLE_STACK,
+)
 
 ALL_STAGES = (
+    STAGE_API_REGISTER,
     STAGE_BROKER_WAIT,
     STAGE_DISPATCH_ACCUMULATE,
     STAGE_DISPATCH_LAUNCH,
+    STAGE_DISPATCH_POOL_WAIT,
     STAGE_SCHED_PROCESS,
     STAGE_MATRIX_BUILD,
     STAGE_MATRIX_UPDATE,
@@ -68,9 +101,11 @@ ALL_STAGES = (
     STAGE_GANG_SELECT,
     STAGE_DEFRAG_SOLVE,
     STAGE_PLAN_SUBMIT,
+    STAGE_PLAN_QUEUE_WAIT,
     STAGE_PLAN_EVALUATE,
     STAGE_PLAN_COMMIT,
     STAGE_ALLOC_UPSERT,
+    STAGE_EVAL_UPDATE,
 )
 
 # The stages every PLACING eval must produce regardless of path (the

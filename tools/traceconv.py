@@ -14,11 +14,21 @@ Input: a JSON file holding either
 Output: ``{"traceEvents": [...]}`` — load it at chrome://tracing or
 https://ui.perfetto.dev.
 
+With ``--xplane`` the input is instead a device profile: a directory
+as ``jax.profiler.start_trace`` leaves it, or one ``.xplane.pb``. The
+output, one JSON object on standard output, is the busiest device
+plane's time by program (``modules``) and by ``jax.named_scope``
+(``scopes``), and its idle gaps, longest first, each named by the
+``nomad.*`` host annotation that covers most of it or ``no host
+annotation`` (``idle_gaps``: ``[[name, seconds]]``; see
+``nomad_tpu/profile/xplane.py``).
+
 Usage:
     python tools/traceconv.py dump.json -o trace.chrome.json
     python tools/traceconv.py dump.json --tail-only
     python tools/traceconv.py --validate trace.chrome.json
     curl -s localhost:4646/v1/agent/trace | python tools/traceconv.py -
+    python tools/traceconv.py --xplane /tmp/profile-dir
 
 Exit codes: 0 = converted (or validated clean), 1 = validation
 failures, 2 = usage/input error.
@@ -73,7 +83,9 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="traceconv", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
-    parser.add_argument("input", help="trace dump JSON file, or - for stdin")
+    parser.add_argument("input", help="trace dump JSON file, or - for "
+                        "stdin; with --xplane a profile directory or "
+                        "an .xplane.pb file")
     parser.add_argument("-o", "--output", default="trace.chrome.json",
                         help="output file (default trace.chrome.json)")
     parser.add_argument("--tail-only", action="store_true",
@@ -81,7 +93,23 @@ def main(argv=None) -> int:
     parser.add_argument("--validate", action="store_true",
                         help="treat INPUT as a chrome trace file and "
                              "schema-check it instead of converting")
+    parser.add_argument("--xplane", action="store_true",
+                        help="treat INPUT as a device profile and print "
+                             "device time by program and named scope, "
+                             "and the idle gaps named by nomad.* host "
+                             "annotations")
     args = parser.parse_args(argv)
+
+    if args.xplane:
+        from nomad_tpu.profile.xplane import reduce_xplane
+
+        try:
+            print(json.dumps(reduce_xplane(args.input), indent=1))
+        except (OSError, ValueError) as e:
+            print(f"traceconv: cannot read {args.input!r}: {e}",
+                  file=sys.stderr)
+            return 2
+        return 0
 
     try:
         doc = _load(args.input)
