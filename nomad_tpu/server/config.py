@@ -256,9 +256,6 @@ class ServerConfig:
     node_gc_interval: float = 300.0
     node_gc_threshold: float = 24 * 3600.0
 
-    # Plan verification pool size (plan_apply.go:48: NumCPU/2).
-    plan_verify_workers: int = 2
-
     # Blocked-evals failed-eval unblock cadence (leader.go:441).
     failed_eval_unblock_interval: float = 60.0
 

@@ -213,21 +213,29 @@ class FSM:
     # ----------------------------------------------------------- allocs
 
     def _apply_alloc_update(self, index: int, payload: dict):
-        allocs: List[Allocation] = payload["allocs"]
-        job = payload.get("job")
-        for alloc in allocs:
-            if alloc.job is None:
-                if job is not None and alloc.job_id == job.id:
-                    alloc.job = job
-                else:
-                    # A plan may carry OTHER jobs' allocs (preemption
-                    # victims): re-denormalize from the stored record,
-                    # never from the submitting plan's job — a victim
-                    # stamped with the preemptor's job would lie about
-                    # its own priority to every later scheduler pass.
-                    stored = self.state.alloc_by_id(alloc.id)
-                    if stored is not None:
-                        alloc.job = stored.job
+        # Two forms. {"allocs", "job"}: one plan's allocations (or bare
+        # allocations with their jobs attached). {"plans": [{"allocs",
+        # "job"}, ...]}: the applier's group, each part denormalized
+        # against its OWN plan's job, then all of them written by one
+        # upsert at this one index, in the group's order.
+        allocs: List[Allocation] = []
+        for part in payload.get("plans") or (payload,):
+            job = part.get("job")
+            for alloc in part["allocs"]:
+                if alloc.job is None:
+                    if job is not None and alloc.job_id == job.id:
+                        alloc.job = job
+                    else:
+                        # A plan may carry OTHER jobs' allocs
+                        # (preemption victims): re-denormalize from the
+                        # stored record, never from the submitting
+                        # plan's job — a victim stamped with the
+                        # preemptor's job would lie about its own
+                        # priority to every later scheduler pass.
+                        stored = self.state.alloc_by_id(alloc.id)
+                        if stored is not None:
+                            alloc.job = stored.job
+            allocs.extend(part["allocs"])
         t0 = time.monotonic()
         self.state.upsert_allocs(index, allocs)
         # Trace: the state-store write is the lifecycle's last

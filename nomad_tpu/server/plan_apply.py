@@ -3,11 +3,18 @@ concurrency.
 
 Reference: nomad/plan_apply.go:41 — a long-lived leader loop that
 dequeues plans by priority, verifies each node's placements against the
-latest state (fanned out over a worker pool, plan_apply_pool.go:18),
-partially commits what fits, and hands workers a RefreshIndex when
-their snapshot went stale. Pipelining: plan N+1 is evaluated against an
-optimistic snapshot while plan N's commit is in flight
-(plan_apply.go:19-39).
+latest state, partially commits what fits, and hands workers a
+RefreshIndex when their snapshot went stale. Pipelining: the next plans
+are evaluated against an optimistic snapshot while a commit is in
+flight (plan_apply.go:19-39).
+
+The unit of work is the GROUP of plans that are in the queue when the
+loop looks (PlanQueue.dequeue_group): verified plan by plan, in queue
+order, on this thread against one overlay, and committed in one raft
+entry. The reference fans the per-node checks out over a pool
+(plan_apply_pool.go:18); under one GIL nothing of that runs in
+parallel and every hand-off waits for the lock, so the checks run
+inline.
 """
 
 from __future__ import annotations
@@ -75,18 +82,25 @@ def evaluate_node_plan(snapshot, plan: Plan, node_id: str) -> bool:
 
 
 class OptimisticSnapshot:
-    """Base snapshot + accepted allocations of in-flight plans — the
-    read view for verifying plan N+1 while plan N's commit is still in
-    flight (plan_apply.go:155-161 optimistic snap.UpsertAllocs).
-    Exposes exactly what evaluate_node_plan reads."""
+    """Base snapshot + accepted allocations of plans that have not
+    landed — the read view for verifying a plan behind its group-mates
+    and behind the group whose commit is still in flight
+    (plan_apply.go:155-161 optimistic snap.UpsertAllocs). Exposes
+    exactly what evaluate_node_plan reads."""
 
     def __init__(self, base):
         self.base = base
         self._extra_by_node = {}  # node_id -> {alloc_id: alloc}
         self._evicted = set()  # alloc ids stopped by in-flight plans
-        self._dirty = False
+        # Raft entries the view runs ahead of its base by: those sealed
+        # and handed to the commit thread, and the one the group being
+        # verified will make once it holds an accepted result.
+        self._sealed = 0
+        self._open = False
 
     def add_result(self, result: PlanResult) -> None:
+        if result.is_no_op():
+            return
         for node_id, allocs in result.node_allocation.items():
             d = self._extra_by_node.setdefault(node_id, {})
             for alloc in allocs:
@@ -99,18 +113,25 @@ class OptimisticSnapshot:
         for allocs in result.node_preemptions.values():
             for alloc in allocs:
                 self._evicted.add(alloc.id)
-        self._dirty = True
+        self._open = True
+
+    def seal_entry(self) -> None:
+        """The results added so far go into one raft entry, now."""
+        if self._open:
+            self._sealed += 1
+            self._open = False
 
     def node_by_id(self, node_id):
         return self.base.node_by_id(node_id)
 
     def latest_index(self) -> int:
-        # With a commit in flight, a plan rejected off this view must
-        # refresh PAST the in-flight commit — otherwise the worker's
-        # "refresh" is a no-op against pre-commit state and it spins
-        # resubmitting the same plan (the reference advances its
-        # optimistic snapshot's index the same way).
-        return self.base.latest_index() + (1 if self._dirty else 0)
+        # A plan rejected off this view must refresh PAST every entry
+        # the view holds — the in-flight group's and its own group's —
+        # otherwise the worker's "refresh" is a no-op against
+        # pre-commit state and it spins resubmitting the same plan (the
+        # reference advances its optimistic snapshot's index the same
+        # way).
+        return self.base.latest_index() + self._sealed + self._open
 
     def allocs_by_node_terminal(self, node_id, terminal):
         live = {
@@ -123,24 +144,40 @@ class OptimisticSnapshot:
         return list(live.values())
 
 
+# The most allocations one group carries into its one raft entry, and
+# so into one upsert_allocs under the store's lock. Grouping pays where
+# a plan's fixed costs (a snapshot, a copy of each table on the first
+# write after it, two thread hand-offs) are of the size of its own
+# work: plans of 8 allocations, of which 1,024 is every plan of the
+# pipeline's two in-flight dispatches (2 x 64 lanes x 8). A plan of a
+# thousand is its own work five hundred times over, and in a group it
+# only waits for its mates' verification: at 2,048 and 4,096 the C1M
+# cell's plan.commit and plan.submit.self medians rose two- and
+# many-fold for no gain, so such a plan goes alone and the store's lock
+# is held no longer than one of them already held it (PERF.md, PR 26).
+# A constant, not a setting: no two deployments need different values.
+MAX_GROUP_ALLOCS = 1024
+
+# One verified plan of a group: the waiter and what it will be told.
+Verified = Tuple[PendingPlan, PlanResult]
+
+
 class PlanApplier:
     """Consumes the plan queue; runs as a leader-only thread.
 
     Pipelined like the reference (plan_apply.go:41-118): one raft
-    commit is in flight at a time while the NEXT plan is verified
-    against an optimistic snapshot that includes the in-flight plan's
-    accepted allocations. A failed commit forces the following plan to
-    re-verify on a fresh snapshot."""
+    commit is in flight at a time while the NEXT group is verified
+    against an optimistic snapshot that includes the in-flight group's
+    accepted allocations. A failed commit fails every plan of its
+    group and forces the following group to re-verify on a fresh
+    snapshot."""
 
-    def __init__(self, plan_queue: PlanQueue, fsm, log, pool_size: int = 2,
+    def __init__(self, plan_queue: PlanQueue, fsm, log,
                  logger: Optional[logging.Logger] = None):
         self.plan_queue = plan_queue
         self.fsm = fsm
         self.log = log
         self.logger = logger or logging.getLogger("nomad_tpu.plan_apply")
-        self.pool = ThreadPoolExecutor(
-            max_workers=max(pool_size, 1), thread_name_prefix="plan-eval"
-        )
         # Dedicated single-thread executor: commits stay ordered.
         self._commit_pool = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="plan-commit"
@@ -162,6 +199,12 @@ class PlanApplier:
         # a member's node failed verification — every one of these is a
         # proven nothing-partial-committed event.
         self.gangs_rejected = 0
+        # That groups form: raft entries applied, the plans they held,
+        # and the most plans one of them held. Touched on the commit
+        # thread only.
+        self.commits = 0
+        self.plans_committed = 0
+        self.largest_group = 0
 
     def start(self) -> None:
         with self._lifecycle:
@@ -221,97 +264,101 @@ class PlanApplier:
 
     def _run(self, stop: Optional[threading.Event] = None) -> None:
         stop = stop if stop is not None else self._stop
-        inflight = None  # (future, pending) of the in-flight commit
-        optimistic: Optional[OptimisticSnapshot] = None
+        inflight = None  # future of the in-flight group commit
+        overlay: Optional[OptimisticSnapshot] = None
         while not stop.is_set():
-            pending = self.plan_queue.dequeue(
-                timeout=0.02 if inflight else 0.25)
-            if pending is None:
+            group = self.plan_queue.dequeue_group(
+                MAX_GROUP_ALLOCS, timeout=0.02 if inflight else 0.25)
+            if not group:
                 if inflight is not None:
                     self._wait_commit(inflight)
                     inflight = None
-                optimistic = None  # queue drained: next gets fresh state
+                overlay = None  # queue drained: next gets fresh state
                 continue
-            # create=False, as the applier's other spans: a remote
-            # (follower-worker) plan's trace lives in its own process.
-            trace.record_span(pending.plan.eval_id,
-                              trace.STAGE_PLAN_QUEUE_WAIT,
-                              pending.enqueue_time, create=False)
             if inflight is None:
-                # Nothing outstanding: every plan verifies against
-                # fresh state (the pre-pipelining invariant). The
-                # optimistic overlay only ever spans ONE in-flight
-                # commit — a rejected or no-op plan must not pin the
-                # next one to a stale base.
-                optimistic = OptimisticSnapshot(self.fsm.state.snapshot())
-            try:
-                start = time.monotonic()
-                # Verified against the optimistic view WHILE the
-                # previous plan's raft commit is still in flight — the
-                # reference's verify-(N+1)-during-commit-(N) overlap.
-                with trace.annotation("nomad.plan_apply",
-                                      phase="evaluate"):
-                    result = self._evaluate_plan(optimistic, pending.plan)
-                metrics.measure_since(("plan", "evaluate"), start)
-            except Exception as e:  # noqa: BLE001 - fail the one plan
-                self.logger.exception("plan evaluate failed")
-                pending.respond(None, e)
-                continue
+                # Nothing outstanding: the group verifies against fresh
+                # state. The overlay only ever spans ONE in-flight
+                # commit and the group being verified — a rejected or
+                # no-op group must not pin the next one to a stale base.
+                overlay = OptimisticSnapshot(self.fsm.state.snapshot())
+            # Verified against the optimistic view WHILE the previous
+            # group's raft commit is still in flight — the reference's
+            # verify-(N+1)-during-commit-(N) overlap.
+            verified = self._verify_group(overlay, group, queued=True)
             if inflight is not None:
                 ok = self._wait_commit(inflight)
                 inflight = None
                 # Rebase on committed state either way: staleness is
-                # bounded to one commit's duration (the old per-plan
-                # fresh snapshot invariant, now per-commit), and node
+                # bounded to one commit's duration, and node
                 # drains/client updates applied meanwhile are seen.
-                optimistic = OptimisticSnapshot(self.fsm.state.snapshot())
-                if not ok:
+                overlay = OptimisticSnapshot(self.fsm.state.snapshot())
+                if ok:
+                    for _pending, result in verified:
+                        overlay.add_result(result)
+                else:
                     # The old view contained allocs that never landed:
-                    # this plan's verification must be redone.
-                    try:
-                        result = self._evaluate_plan(optimistic,
-                                                     pending.plan)
-                    except Exception as e:  # noqa: BLE001
-                        pending.respond(None, e)
-                        continue
-            if result.is_no_op():
-                pending.respond(result, None)
-                continue
-            fut = self._commit_pool.submit(self._commit, pending.plan, result)
-            # The waiter is answered the INSTANT the commit lands, not
-            # when this loop next wakes: a worker ping-ponging plans
-            # with an idle-queue applier would otherwise pay the full
-            # dequeue timeout per plan in response latency (~20 ms,
-            # which capped the whole control plane near 50 plans/s).
-            fut.add_done_callback(self._make_responder(pending, result))
-            optimistic.add_result(result)
-            inflight = (fut, pending)
+                    # this group's verification must be redone.
+                    verified = self._verify_group(
+                        overlay, [pending for pending, _ in verified])
+            accepted = []
+            for pending, result in verified:
+                if result.is_no_op():
+                    pending.respond(result, None)
+                else:
+                    accepted.append((pending, result))
+            if accepted:
+                overlay.seal_entry()
+                # The waiters are answered by the commit thread the
+                # INSTANT the entry has applied, not when this loop
+                # next wakes.
+                inflight = self._commit_pool.submit(self._commit, accepted)
         if inflight is not None:
             self._wait_commit(inflight)
 
-    @staticmethod
-    def _make_responder(pending, result: PlanResult):
-        def _respond(fut) -> None:
-            try:
-                result.alloc_index = fut.result()
-                pending.respond(result, None)
-            except Exception as e:  # noqa: BLE001 - fail the one plan
-                pending.respond(None, e)
-
-        return _respond
+    def _verify_group(self, overlay: "OptimisticSnapshot",
+                      group: List[PendingPlan],
+                      queued: bool = False) -> List[Verified]:
+        """Each plan in queue order against the overlay, its accepted
+        part added before the next plan is checked: a plan sees
+        committed state plus everything accepted ahead of it that has
+        not landed. A plan whose verification raises is answered with
+        the error and leaves nothing in the overlay."""
+        verified: List[Verified] = []
+        with trace.annotation("nomad.plan_apply", phase="evaluate",
+                              plans=len(group)):
+            for pending in group:
+                if queued:
+                    # Ends where the plan's OWN verification starts:
+                    # waiting for the group-mates ahead is queue wait.
+                    # create=False, as the applier's other spans: a
+                    # remote (follower-worker) plan's trace lives in
+                    # its own process.
+                    trace.record_span(pending.plan.eval_id,
+                                      trace.STAGE_PLAN_QUEUE_WAIT,
+                                      pending.enqueue_time, create=False)
+                start = time.monotonic()
+                try:
+                    result = self._evaluate_plan(overlay, pending.plan)
+                except Exception as e:  # noqa: BLE001 - fail the one plan
+                    self.logger.exception("plan evaluate failed")
+                    pending.respond(None, e)
+                    continue
+                metrics.measure_since(("plan", "evaluate"), start)
+                overlay.add_result(result)
+                verified.append((pending, result))
+        return verified
 
     def _wait_commit(self, inflight) -> bool:
         """Wait out an in-flight raft commit; False when it failed
-        (asyncPlanWait, plan_apply.go:166). The waiter was already
-        answered by the commit future's done-callback. No extra timeout
-        here: log.apply has its own bounded timeouts, and abandoning a
+        (asyncPlanWait, plan_apply.go:166). The waiters were already
+        answered on the commit thread. No extra timeout here:
+        log.apply has its own bounded timeouts, and abandoning a
         still-running commit would let it land after the pipeline moved
         on (double-commit on retry)."""
-        fut, _pending = inflight
         try:
-            fut.result()
+            inflight.result()
             return True
-        except Exception:  # noqa: BLE001 - logged; waiter already told
+        except Exception:  # noqa: BLE001 - logged; waiters already told
             self.logger.exception("plan commit failed")
             return False
 
@@ -360,16 +407,12 @@ class PlanApplier:
 
         node_ids = (set(plan.node_update) | set(plan.node_allocation)
                     | set(plan.node_preemptions))
-        futures = {
-            node_id: self.pool.submit(evaluate_node_plan, snapshot, plan, node_id)
-            for node_id in node_ids
-        }
         self.plans_evaluated += 1
         rejected = 0
         suspect = False
         rejected_nodes = set()
-        for node_id, fut in futures.items():
-            if fut.result():
+        for node_id in node_ids:
+            if evaluate_node_plan(snapshot, plan, node_id):
                 continue
             # This node's changes don't fit anymore.
             rejected += 1
@@ -466,47 +509,77 @@ class PlanApplier:
     def stats(self) -> dict:
         """Conflict counters: how often optimistic plans lost node
         verifications (each rejection is a replan round-trip somewhere
-        upstream — the dispatch pipeline's A/B measures these)."""
+        upstream — the dispatch pipeline's A/B measures these); and
+        whether groups form: plans_committed over commits is the plans
+        a raft entry carries."""
         return {
             "plans_evaluated": self.plans_evaluated,
             "plans_rejected": self.plans_rejected,
             "nodes_rejected": self.nodes_rejected,
             "gangs_rejected": self.gangs_rejected,
+            "commits": self.commits,
+            "plans_committed": self.plans_committed,
+            "largest_group": self.largest_group,
         }
 
-    def _commit(self, plan: Plan, result: PlanResult) -> int:
+    def _commit(self, accepted: List[Verified]) -> int:
+        """One raft entry for the group's accepted results; every plan
+        is answered with its own result once that entry has applied,
+        or with the error if it did not."""
         start = time.monotonic()
-        allocs: List[Allocation] = []
-        for update_list in result.node_update.values():
-            allocs.extend(update_list)
-        n_preempted = 0
-        for victim_list in result.node_preemptions.values():
-            # Victims ride the SAME raft apply as the placements they
-            # make room for: one log entry, one terminal stamp — the
-            # exactly-once contract the preemption soak asserts.
-            allocs.extend(victim_list)
-            n_preempted += len(victim_list)
-        for alloc_list in result.node_allocation.values():
-            allocs.extend(alloc_list)
-        with trace.annotation("nomad.plan_apply", phase="commit",
-                              allocs=len(allocs)):
-            index = self.log.apply(
-                ALLOC_UPDATE, {"allocs": allocs, "job": plan.job}
-            )
+        parts = []
+        n_allocs = n_preempted = 0
+        for pending, result in accepted:
+            allocs: List[Allocation] = []
+            for update_list in result.node_update.values():
+                allocs.extend(update_list)
+            for victim_list in result.node_preemptions.values():
+                # Victims ride the SAME raft apply as the placements
+                # they make room for: one log entry, one terminal stamp
+                # — the exactly-once contract the preemption soak
+                # asserts.
+                allocs.extend(victim_list)
+                n_preempted += len(victim_list)
+            for alloc_list in result.node_allocation.values():
+                allocs.extend(alloc_list)
+            n_allocs += len(allocs)
+            # One part per plan: the handler re-attaches each plan's
+            # job to that plan's allocations alone.
+            parts.append({"allocs": allocs, "job": pending.plan.job})
+        try:
+            with trace.annotation("nomad.plan_apply", phase="commit",
+                                  allocs=n_allocs, plans=len(accepted)):
+                index = self.log.apply(ALLOC_UPDATE, {"plans": parts})
+        except Exception as e:  # noqa: BLE001 - fail the whole group
+            for pending, _result in accepted:
+                pending.respond(None, e)
+            raise
+        applied = time.monotonic()
+        self.commits += 1
+        self.plans_committed += len(accepted)
+        self.largest_group = max(self.largest_group, len(accepted))
         if n_preempted:
             from ..migrate import note_preemption_committed
 
             note_preemption_committed(n_preempted)
-        trace.record_span(plan.eval_id, trace.STAGE_PLAN_COMMIT, start,
-                          ann={"allocs": len(allocs)}, create=False)
         # Stamp indexes onto the result's alloc objects the way the Go
-        # store mutates shared pointers — workers count fresh placements
-        # by create_index == alloc_index (scheduler/util.py).
-        for alloc_list in result.node_allocation.values():
-            for alloc in alloc_list:
-                stored = self.fsm.state.alloc_by_id(alloc.id)
-                if stored is not None:
-                    alloc.create_index = stored.create_index
-                    alloc.modify_index = stored.modify_index
+        # store mutates shared pointers — workers count fresh
+        # placements by create_index == alloc_index (scheduler/util.py).
+        # One view of the store for the group: each read through the
+        # store itself would take a snapshot of its own.
+        snapshot = self.fsm.state.snapshot()
+        for (pending, result), part in zip(accepted, parts):
+            trace.record_span(
+                pending.plan.eval_id, trace.STAGE_PLAN_COMMIT, start, applied,
+                ann={"allocs": len(part["allocs"]), "plans": len(accepted)},
+                create=False)
+            for alloc_list in result.node_allocation.values():
+                for alloc in alloc_list:
+                    stored = snapshot.alloc_by_id(alloc.id)
+                    if stored is not None:
+                        alloc.create_index = stored.create_index
+                        alloc.modify_index = stored.modify_index
+            result.alloc_index = index
+            pending.respond(result, None)
         metrics.measure_since(("plan", "submit"), start)
         return index

@@ -18,10 +18,18 @@ from ..structs import Plan, PlanResult
 class PendingPlan:
     """A queued plan and its response future."""
 
-    __slots__ = ("plan", "enqueue_time", "_event", "_result", "_error")
+    __slots__ = ("plan", "n_allocs", "enqueue_time", "_event", "_result",
+                 "_error")
 
     def __init__(self, plan: Plan):
         self.plan = plan
+        # What the plan would put into a raft entry if all of it were
+        # accepted: the measure the applier's group bound is in.
+        self.n_allocs = sum(
+            len(allocs)
+            for leg in (plan.node_update, plan.node_allocation,
+                        plan.node_preemptions)
+            for allocs in leg.values())
         self.enqueue_time = time.monotonic()
         self._event = threading.Event()
         self._result: Optional[PlanResult] = None
@@ -62,30 +70,45 @@ class PlanQueue:
             return self._enabled
 
     def enqueue(self, plan: Plan) -> PendingPlan:
+        pending = PendingPlan(plan)
         with self._lock:
             if not self._enabled:
                 raise RuntimeError("plan queue is disabled")
-            pending = PendingPlan(plan)
             heapq.heappush(
                 self._heap, (-plan.priority, next(self._counter), pending)
             )
             self._cond.notify()
             return pending
 
-    def dequeue(self, timeout: Optional[float] = None) -> Optional[PendingPlan]:
+    def dequeue_group(self, max_allocs: int,
+                      timeout: Optional[float] = None) -> List[PendingPlan]:
+        """Every pending plan, in heap order (priority, then arrival),
+        while their summed allocations stay within `max_allocs`. Waits
+        up to `timeout` for the first plan and never for a second: one
+        plan queued is a group of one. The first plan is always taken,
+        so a plan larger than the bound is a group of its own. Empty
+        on timeout or when disabled."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._lock:
             while True:
                 if not self._enabled:
-                    return None
+                    return []
                 if self._heap:
-                    return heapq.heappop(self._heap)[2]
+                    break
                 remaining = None
                 if deadline is not None:
                     remaining = deadline - time.monotonic()
                     if remaining <= 0:
-                        return None
+                        return []
                 self._cond.wait(remaining if remaining is not None else 1.0)
+            group = [heapq.heappop(self._heap)[2]]
+            total = group[0].n_allocs
+            while (self._heap
+                   and total + self._heap[0][2].n_allocs <= max_allocs):
+                pending = heapq.heappop(self._heap)[2]
+                total += pending.n_allocs
+                group.append(pending)
+            return group
 
     def depth(self) -> int:
         with self._lock:

@@ -68,10 +68,7 @@ class Server:
         )
         self.blocked_evals = BlockedEvals(self.broker.enqueue_all)
         self.plan_queue = PlanQueue()
-        self.plan_applier = PlanApplier(
-            self.plan_queue, self.fsm, self.log,
-            pool_size=self.config.plan_verify_workers,
-        )
+        self.plan_applier = PlanApplier(self.plan_queue, self.fsm, self.log)
         self.heartbeats = HeartbeatTimers(self)
         self.periodic = PeriodicDispatch(self)
         self.workers: List[Worker] = []

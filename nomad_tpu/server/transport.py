@@ -378,10 +378,16 @@ def fsm_payload_decoder(msg_type: str, payload: Any) -> Any:
     elif msg_type == m.EVAL_UPDATE and "evals" in out:
         out["evals"] = [from_dict(Evaluation, e) for e in out["evals"]]
     elif msg_type in (m.ALLOC_UPDATE, m.ALLOC_CLIENT_UPDATE):
-        if out.get("allocs"):
-            out["allocs"] = [from_dict(Allocation, a) for a in out["allocs"]]
-        if out.get("job"):
-            out["job"] = from_dict(Job, out["job"])
+        # A plan applier's group carries one {"allocs", "job"} a plan.
+        parts = [dict(p) for p in out.get("plans") or ()]
+        if parts:
+            out["plans"] = parts
+        for part in parts or (out,):
+            if part.get("allocs"):
+                part["allocs"] = [from_dict(Allocation, a)
+                                  for a in part["allocs"]]
+            if part.get("job"):
+                part["job"] = from_dict(Job, part["job"])
     elif msg_type == m.VAULT_ACCESSOR_REGISTER and out.get("accessors"):
         from .vault import VaultAccessor
 

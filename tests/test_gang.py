@@ -623,7 +623,7 @@ def _run_real_applier(fsm, log, plans):
 
     queue = PlanQueue()
     queue.set_enabled(True)
-    applier = PlanApplier(queue, fsm, log, pool_size=2)
+    applier = PlanApplier(queue, fsm, log)
     applier.start()
     pendings = [queue.enqueue(p) for p in plans]
     results = [p.wait(timeout=20.0) for p in pendings]

@@ -540,8 +540,11 @@ def test_server_preemption_soak_with_victim_lost_chaos():
         # the high-prio evals all completed; the victims' replacement
         # evals exist (blocked or pending — the cluster is full, which
         # is the correct PR 5 outcome for prio-20 work on a red box)
-        for e in state.evals_by_job(high.id):
-            assert e.terminal_status(), e
+        # (the allocations are readable when the plan's entry applies,
+        # the eval's terminal status an instant later)
+        assert wait_until(lambda: all(
+            e.terminal_status() for e in state.evals_by_job(high.id)),
+            10.0), state.evals_by_job(high.id)
         assert [e for e in state.evals_by_job(low.id)
                 if e.triggered_by == consts.EVAL_TRIGGER_PREEMPTION]
     finally:

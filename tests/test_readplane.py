@@ -445,8 +445,12 @@ def test_red_pressure_degrades_reads_to_stale(api):
     client.jobs.register(mock.job())
     ctl = server.admission
     ctl.force_level("red")
+    rate = ctl._read.rate
     try:
-        # Exhaust the read bucket so the next read is over budget.
+        # Exhaust the read bucket so the next read is over budget, and
+        # stop its refill: at 200 tokens a second a request that takes
+        # 5 ms to arrive (a cold socket, a loaded machine) found one.
+        ctl._read.rate = 0.0
         while ctl._read.try_acquire()[0]:
             pass
         status, headers, _body = _raw_request(client.address, "/v1/jobs")
@@ -455,6 +459,7 @@ def test_red_pressure_degrades_reads_to_stale(api):
         assert headers.get("X-Nomad-KnownLeader") == "true"
         assert "X-Nomad-LastContact" in headers
     finally:
+        ctl._read.rate = rate
         ctl.force_level(None)
 
 

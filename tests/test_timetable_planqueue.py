@@ -66,9 +66,9 @@ class TestPlanQueue:
         lo = q.enqueue(self.make_plan(10))
         hi = q.enqueue(self.make_plan(90))
         assert q.depth() == 2
-        first = q.dequeue(timeout=1.0)
-        assert first.plan.priority == 90
-        assert q.dequeue(timeout=1.0).plan.priority == 10
+        first, second = q.dequeue_group(8, timeout=1.0)
+        assert first is hi and first.plan.priority == 90
+        assert second is lo and q.depth() == 0
 
     def test_future_resolves_waiter(self):
         q = PlanQueue()
@@ -81,7 +81,7 @@ class TestPlanQueue:
 
         t = threading.Thread(target=waiter)
         t.start()
-        applier_side = q.dequeue(timeout=1.0)
+        (applier_side,) = q.dequeue_group(8, timeout=1.0)
         result = PlanResult()
         applier_side.respond(result, None)
         t.join(timeout=5.0)
@@ -91,7 +91,7 @@ class TestPlanQueue:
         q = PlanQueue()
         q.set_enabled(True)
         pending = q.enqueue(self.make_plan())
-        q.dequeue(timeout=1.0).respond(None, RuntimeError("boom"))
+        q.dequeue_group(8, timeout=1.0)[0].respond(None, RuntimeError("boom"))
         with pytest.raises(RuntimeError, match="boom"):
             pending.wait(timeout=5.0)
 
@@ -105,10 +105,24 @@ class TestPlanQueue:
             pending.wait(timeout=5.0)
         assert q.depth() == 0
 
-    def test_dequeue_timeout_returns_none(self):
+    def test_dequeue_timeout_returns_nothing(self):
         q = PlanQueue()
         q.set_enabled(True)
-        assert q.dequeue(timeout=0.05) is None
+        assert q.dequeue_group(8, timeout=0.05) == []
+
+    def test_group_stops_at_the_allocation_bound(self):
+        """Plans are taken while their summed allocations fit the
+        bound; the rest stay queued, in order, for the next group."""
+        q = PlanQueue()
+        q.set_enabled(True)
+        pendings = []
+        for n_allocs in (2, 2, 2):
+            plan = self.make_plan()
+            plan.node_allocation = {"n": [object()] * n_allocs}
+            pendings.append(q.enqueue(plan))
+        assert [p.n_allocs for p in pendings] == [2, 2, 2]
+        assert q.dequeue_group(5, timeout=1.0) == pendings[:2]
+        assert q.dequeue_group(1, timeout=1.0) == pendings[2:]
 
 
 def test_generate_uuid_fork_safe():

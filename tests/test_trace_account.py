@@ -206,6 +206,85 @@ def test_new_stage_names_are_stages():
 
 
 # ---------------------------------------------------------------------
+# plans that ride in one group of the applier
+
+
+def test_account_of_an_eval_whose_plan_rode_in_a_group():
+    """Four plans are queued when the applier looks: one group, one
+    raft entry. Each eval's trace holds the applier's four spans once,
+    `plan.queue_wait` ends where its own `plan.evaluate` starts (so a
+    plan's wait covers the verification of those ahead), `plan.commit` and
+    `fsm.alloc_upsert` are the group's one apply in every trace, and
+    self times plus uncovered still come to the trace's duration."""
+    from nomad_tpu.server.fsm import FSM, DevLog
+    from nomad_tpu.server.plan_apply import PlanApplier
+    from nomad_tpu.server.plan_queue import PlanQueue
+    from nomad_tpu.structs import Allocation, Plan, Resources, consts
+    from nomad_tpu.utils.ids import generate_uuid
+
+    recorder = trace.get_recorder()
+    recorder.reset()
+    fsm = FSM()
+    log = DevLog(fsm)
+    nodes = [mock.node() for _ in range(4)]
+    for node in nodes:
+        log.apply("node_register", {"node": node})
+    queue = PlanQueue()
+    queue.set_enabled(True)
+    applier = PlanApplier(queue, fsm, log)
+    evals, pendings, starts = [], [], []
+    for node in nodes:
+        job = mock.job()
+        eval_id = generate_uuid()
+        plan = Plan(job=job, eval_id=eval_id)
+        plan.append_alloc(Allocation(
+            id=generate_uuid(), eval_id=eval_id, job_id=job.id, job=job,
+            node_id=node.id, task_group="web",
+            task_resources={"web": Resources(cpu=100, memory_mb=64)},
+            desired_status=consts.ALLOC_DESIRED_RUN))
+        starts.append(time.monotonic())
+        trace.record_span(eval_id, trace.STAGE_BROKER_WAIT, starts[-1])
+        evals.append(eval_id)
+        pendings.append(queue.enqueue(plan))
+    applier.start()
+    try:
+        for eval_id, pending, start in zip(evals, pendings, starts):
+            assert pending.wait(timeout=20.0).alloc_index > 0
+            # the worker's side of the submit, as server/worker.py has it
+            trace.record_span(eval_id, trace.STAGE_PLAN_SUBMIT, start)
+            trace.record_span(eval_id, trace.STAGE_SCHED_PROCESS, start)
+            trace.complete(eval_id)
+    finally:
+        applier.stop()
+    assert applier.stats()["largest_group"] == 4
+    traces = [recorder.trace_for(e) for e in evals]
+    wait_ends = []
+    for done, pending in zip(traces, pendings):
+        by_name = {}
+        for span in done["spans"]:
+            by_name.setdefault(span["name"], []).append(span)
+        for stage in (trace.STAGE_PLAN_QUEUE_WAIT, trace.STAGE_PLAN_EVALUATE,
+                      trace.STAGE_PLAN_COMMIT, trace.STAGE_ALLOC_UPSERT):
+            assert len(by_name[stage]) == 1, (stage, sorted(by_name))
+        wait = by_name[trace.STAGE_PLAN_QUEUE_WAIT][0]
+        assert wait["parent"] == trace.STAGE_PLAN_SUBMIT
+        assert wait["end_ms"] <= by_name[
+            trace.STAGE_PLAN_EVALUATE][0]["start_ms"] + 0.002
+        commit = by_name[trace.STAGE_PLAN_COMMIT][0]
+        assert commit["annotations"] == {"allocs": 1, "plans": 4}
+        assert by_name[trace.STAGE_ALLOC_UPSERT][0]["parent"] == \
+            trace.STAGE_PLAN_COMMIT
+        wait_ends.append(pending.enqueue_time + wait["duration_ms"] / 1e3)
+        accounted = done["uncovered_ms"] + sum(
+            s.get("self_ms", s["duration_ms"]) for s in done["spans"])
+        assert done["duration_ms"] <= accounted + 0.01
+        assert accounted <= done["duration_ms"] * 1.0003 + 0.01, done
+    # queue order: each plan's wait ends after that of the plan ahead
+    assert wait_ends == sorted(wait_ends)
+    recorder.reset()
+
+
+# ---------------------------------------------------------------------
 # a placing eval through HTTP and the pipeline
 
 
