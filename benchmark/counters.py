@@ -25,9 +25,11 @@ PICK = {
     "executive": ("routed_host", "host_fallbacks"),
     "broker": ("dead_lettered", "shed", "expired", "nacked", "nack_timeouts"),
     "breaker": ("trips", "failures", "rejected"),
+    "plan_applier": ("commits", "plans_committed"),
 }
 PROM = ("host_fallback", "gang_host_fallback", "breaker_rejected",
-        "gang_breaker_rejected", "small_route_host")
+        "gang_breaker_rejected", "small_route_host",
+        "small_route_host_evals")
 
 
 def prom_counter(text: str, suffix: str) -> float:
@@ -52,6 +54,7 @@ def read_counters(conn) -> dict:
         "executive": stats["scheduler_executive"],
         "broker": stats["broker"],
         "breaker": stats["admission"]["breaker"],
+        "plan_applier": stats.get("plan_applier") or {},
     }
     flat = {}
     for group, keys in PICK.items():

@@ -1,6 +1,6 @@
 """One generic builder for every configuration file: the fleet (classes of
-node shape with counts and a filler rule), loaded in bulk through the
-raft log, and the job template the load generator registers.
+node shape with counts and filler rules), loaded in bulk through the
+raft log, and the job templates the load generator registers.
 
 Everything comes from the configuration's JSON and `--seed`; a new
 deployment is a new file, not new code here.
@@ -34,8 +34,26 @@ def scaled(config: dict, rehearse: bool) -> dict:
         dict(c, count=max(8, int(c["count"] * spec["fleet_scale"])))
         for c in fleet["classes"]]
     small["fleet"] = fleet
-    small["job"] = dict(config["job"], count=spec["job_count"])
+    counts = spec["job_count"]      # a number (all) or a map by name
+    small_jobs = [dict(job, count=counts[job["name"]]
+                       if isinstance(counts, dict) else counts)
+                  for job in job_specs(config)]
+    if isinstance(config.get("jobs"), list):
+        small["jobs"] = small_jobs
+    else:
+        small["job"] = small_jobs[0]
     return small
+
+
+def job_specs(config: dict) -> list:
+    """The configuration's job shapes: `jobs`, a list of templates each
+    with a `name` and a `share` of the arrivals, or `job` alone, which is
+    a list of one. (`jobs` as a line of prose, as `c1m-5k.json` has it,
+    is a note and not a list.)"""
+    if isinstance(config.get("jobs"), list):
+        return config["jobs"]
+    return [dict(config["job"], name=config["job"].get("name", "job"),
+                 share=1.0)]
 
 
 def _node_template(shape: dict, datacenter: str):
@@ -60,12 +78,16 @@ def _node_template(shape: dict, datacenter: str):
                 reserved_ports=[Port(f"r{p}", p) for p in res["ports"]])]))
 
 
-def _filler_job(datacenter: str):
+def _filler_job(datacenter: str, rule: dict):
+    """A rule that states a priority gets a filler job of its own
+    (`filler-p<priority>`), so that a fleet can hold several tiers."""
     from nomad_tpu.structs import (EphemeralDisk, Job, Resources, Task,
                                    TaskGroup)
 
-    job = Job(id="filler", name="filler", type="service", priority=50,
-              datacenters=[datacenter],
+    priority = rule.get("priority", 50)
+    job_id = f"filler-p{priority}" if "priority" in rule else "filler"
+    job = Job(id=job_id, name=job_id, type=rule.get("type", "service"),
+              priority=priority, datacenters=[datacenter],
               task_groups=[TaskGroup(
                   name="web", count=1, ephemeral_disk=EphemeralDisk(),
                   tasks=[Task(name="web", driver="exec",
@@ -84,30 +106,37 @@ def load_fleet(server, config: dict, seed: int) -> dict:
     rng = random.Random(seed)
     fleet = config["fleet"]
     dc = fleet["datacenter"]
-    filler_job = _filler_job(dc)
+    filler_jobs: dict = {}
     n_nodes = n_allocs = 0
     pending = []
     for cls in fleet["classes"]:
         template = _node_template(cls["node"], dc)
         template.compute_class()
-        rule = cls["filler"]
-        per_node = rule.get("per_node", 0)
+        # one rule, or a list of them: one tier of fillers each
+        rules = cls["filler"]
+        rules = rules if isinstance(rules, list) else [rules]
+        tiers = []
+        for rule in rules:
+            job = _filler_job(dc, rule)
+            tiers.append((rule, filler_jobs.setdefault(job.id, job)))
         for _ in range(cls["count"]):
             node = template.copy()
             node.id = seeded_uuid(rng)
             node.secret_id = seeded_uuid(rng)
             server.log.apply("node_register", {"node": node})
             n_nodes += 1
-            for k in range(per_node):
-                pending.append(Allocation(
-                    id=seeded_uuid(rng), eval_id="filler", node_id=node.id,
-                    name=f"filler.web[{k}]", job_id=filler_job.id,
-                    job=filler_job, task_group="web",
-                    shared_resources=Resources(disk_mb=rule["disk_mb"]),
-                    task_resources={"web": Resources(
-                        cpu=rng.choice(rule["cpu"]),
-                        memory_mb=rng.choice(rule["memory_mb"]))},
-                    desired_status="run", client_status="running"))
+            for rule, filler_job in tiers:
+                for k in range(rule.get("per_node", 0)):
+                    pending.append(Allocation(
+                        id=seeded_uuid(rng), eval_id="filler",
+                        node_id=node.id, name=f"{filler_job.id}.web[{k}]",
+                        job_id=filler_job.id, job=filler_job,
+                        task_group="web",
+                        shared_resources=Resources(disk_mb=rule["disk_mb"]),
+                        task_resources={"web": Resources(
+                            cpu=rng.choice(rule["cpu"]),
+                            memory_mb=rng.choice(rule["memory_mb"]))},
+                        desired_status="run", client_status="running"))
             if len(pending) >= FILLER_ENTRY:
                 server.log.apply("alloc_update", {"allocs": pending})
                 n_allocs += len(pending)
@@ -118,15 +147,14 @@ def load_fleet(server, config: dict, seed: int) -> dict:
     return {"nodes": n_nodes, "filler_allocs": n_allocs}
 
 
-def job_template(config: dict) -> dict:
-    """The job every client registers, as the JSON body's `job`; the
-    generator fills in `id` and `name`."""
+def job_template(spec: dict) -> dict:
+    """One job shape (an entry of `job_specs`) as the JSON body's `job`;
+    the generator fills in `id` and `name`."""
     from nomad_tpu.structs import (Constraint, EphemeralDisk, Job,
                                    NetworkResource, Port, Resources,
                                    RestartPolicy, Task, TaskGroup)
     from nomad_tpu.utils.codec import to_dict
 
-    spec = config["job"]
     task = spec["task"]
     networks = []
     if task["mbits"] or task["dynamic_ports"]:
@@ -159,5 +187,6 @@ def job_template(config: dict) -> dict:
     job.canonicalize()
     errors = job.validate()
     if errors:
-        raise ValueError(f"job template does not validate: {errors}")
+        raise ValueError(
+            f"job template {spec['name']!r} does not validate: {errors}")
     return to_dict(job)

@@ -23,6 +23,7 @@ sys.path.insert(0, HERE)
 
 import httpc  # noqa: E402
 import plugins  # noqa: E402
+import stats  # noqa: E402
 
 READBACK_THREADS = 8
 
@@ -36,6 +37,7 @@ class Control:
         self.max_life_s, self.drain_s = max_life_s, drain_s
         self.window_start = None
         self.stop_at = None
+        self.told = threading.Event()   # set once the window is named
 
     def stopped(self) -> bool:
         now = time.monotonic()
@@ -54,6 +56,7 @@ class Control:
             msg = json.loads(line)
             self.window_start = msg["window_start"]
             self.stop_at = msg["stop_at"]
+            self.told.set()
 
 
 def read_back(samples: list, make_conn) -> None:
@@ -99,8 +102,12 @@ def read_back(samples: list, make_conn) -> None:
 
 def main() -> int:
     spec = json.loads(sys.stdin.readline())
-    spec["job_body"] = json.dumps(
-        {"job": dict(spec["job"], id="@@JOB@@", name="@@JOB@@")}).encode()
+    # One body for each job shape; `job_body` is the first shape's, which
+    # is all a generator of one shape reads.
+    for shape in spec["jobs"]:
+        shape["body"] = json.dumps({"job": dict(
+            shape["job"], id="@@JOB@@", name="@@JOB@@")}).encode()
+    spec["job_body"] = spec["jobs"][0]["body"]
     traffic = spec["traffic"]
     control = Control(spec["max_life_s"], traffic["drain_s"])
     threading.Thread(target=control.listen, args=(sys.stdin,),
@@ -115,7 +122,7 @@ def main() -> int:
     # throughput population (seen terminal in it).
     t0, t1 = control.window_start, control.stop_at
     window = [s for s in samples if t0 is not None and (
-        t0 <= s["t_register"] < t1
+        t0 <= stats.due(s) < t1
         or (s["t_terminal"] is not None and t0 <= s["t_terminal"] < t1))]
     read_back(window, make_conn)
     if "jax" in sys.modules or "nomad_tpu" in sys.modules:

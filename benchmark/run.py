@@ -14,7 +14,8 @@ object as the last line of its standard output.
 A cell is data: `BENCHMARK.json` names a configuration
 (`configs/<name>.json`), a traffic mix (`traffic/<name>.json`, whose
 `kind` names `generators/<kind>.py`) and the per-layer metrics
-(`metrics/<name>.json`, whose `reader` names `readers/<reader>.py`).
+(`metrics/<name>.json`, whose `reader` names `readers/<reader>.py`); a
+configuration may name checks of its own (`checks/<name>.py`).
 
 `--rehearse` runs the same phases at a tiny size on whatever backend JAX
 finds; every line it prints starts with `REHEARSAL`, so the last line is
@@ -175,12 +176,28 @@ class Run:
         print(self.prefix + msg, flush=True)
 
     def check(self, name: str, value, limit, ok: bool) -> None:
-        self.checks.append((name, ok))
+        self.checks.append((name, value, limit, ok))
         self.say(f"check {name}: value={value} limit={limit} "
                  f"{'ok' if ok else 'FAIL'}")
 
     def correct(self) -> bool:
-        return all(ok for _, ok in self.checks)
+        return all(ok for *_, ok in self.checks)
+
+    def compared(self) -> dict:
+        """Each number compared beside its limit, the failed ones first:
+        the driver's record keeps only the end of what a run printed."""
+        rows = sorted(self.checks, key=lambda row: row[3])
+        return {name: {"value": value, "limit": limit, "ok": ok}
+                for name, value, limit, ok in rows}
+
+    def say_compared(self) -> None:
+        """The same as the last lines of standard error, the failed ones
+        last."""
+        for name, row in reversed(list(self.compared().items())):
+            print(f"{self.prefix}{name} {row['value']} limit "
+                  f"{row['limit']}{'' if row['ok'] else ' FAIL'}",
+                  file=sys.stderr)
+        sys.stderr.flush()
 
 
 def warm_up(run: Run, conn, rule: dict, child) -> dict:
@@ -222,7 +239,9 @@ def end_to_end_value(name: str, ctx: dict):
         return ctx["setup_s"]
     if name == "placed_allocs_per_s":
         return ctx["placed_allocs"] / ctx["seconds"]
-    m = re.fullmatch(r"place_p(\d+)_ms", name)
+    # `place_due_*`: the same arithmetic under the name the open-loop
+    # cells report it by (a bound is a metric's, and theirs is wider)
+    m = re.fullmatch(r"place(?:_due)?_p(\d+)_ms", name)
     if m and ctx["client"]["place_ms"]:
         return stats.percentile(ctx["client"]["place_ms"], int(m.group(1)) / 100)
     return None
@@ -246,13 +265,19 @@ def per_layer_value(entry: dict, ctx: dict):
 
 
 def start_generator(addr: str, cell: dict, config: dict, traffic: dict,
-                    seed: int):
+                    seed: int, seconds: float):
+    """The child gets every job shape of the configuration with its
+    share, and the seed and the window's length, so that a generator can
+    draw its whole schedule before the window."""
     child = subprocess.Popen(
         [sys.executable, os.path.join(HERE, "loadgen.py")],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
-    tell(child, {"addr": addr, "job": fleet.job_template(config),
-                 "traffic": traffic,
+    jobs = [{"name": spec["name"], "share": spec["share"],
+             "job": fleet.job_template(spec)}
+            for spec in fleet.job_specs(config)]
+    tell(child, {"addr": addr, "jobs": jobs, "traffic": traffic,
                  "prefix": f"{cell['traffic']}-{seed}",
+                 "seed": seed, "seconds": seconds,
                  "max_life_s": GENERATOR_MAX_LIFE_S})
     return child
 
@@ -278,7 +303,7 @@ def collect(child, traffic: dict) -> dict:
 
 
 def trace_stretch(spec: dict, seconds: float, trace_dir: str,
-                  rehearse: bool, dispatches, out: dict) -> None:
+                  rehearse: bool, dispatches, drained, out: dict) -> None:
     """Profile a steady stretch inside the window; its (start, end) on
     time.monotonic() go into `out`. Device events only: the Python
     tracer would slow the host, which is what the window measures. Runs
@@ -292,12 +317,22 @@ def trace_stretch(spec: dict, seconds: float, trace_dir: str,
     stretch in which nothing ran on the device says nothing (and one
     that ends on a dispatch reads a little busier than the window was).
     The trace is kept short because writing it out takes some four
-    minutes for each second the device was busy in it."""
+    minutes for each second the device was busy in it.
+
+    Where that is long (thousand-allocation programs), the traffic file
+    says `"write_out": "after_drain"`: the stretch is then the window's
+    last `spec["seconds"]` and goes on through the drain (`drained` is
+    set when the generator has handed its samples over), so that the
+    write-out runs when the closing readings are taken and nothing of the
+    window is in flight any more. A write-out inside the window loads
+    the host that the window measures, and evaluations that wait it out
+    can fail for no fault of the program."""
     import jax
 
     lead = min(spec["start_s"], seconds / 4)
     length = max(0.5, min(spec["seconds"], seconds - 2 * lead))
-    time.sleep(lead)
+    after_drain = spec.get("write_out") == "after_drain"
+    time.sleep(seconds - length if after_drain else lead)
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 2 if rehearse else 1
@@ -305,9 +340,13 @@ def trace_stretch(spec: dict, seconds: float, trace_dir: str,
     t_a = time.monotonic()
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     seen = dispatches()
-    time.sleep(length)
-    while dispatches() == seen and time.monotonic() - t_a < seconds - 2 * lead:
-        time.sleep(1.0)
+    if after_drain:
+        drained.wait()
+    else:
+        time.sleep(length)
+        while (dispatches() == seen
+               and time.monotonic() - t_a < seconds - 2 * lead):
+            time.sleep(1.0)
     t_b = time.monotonic()
     jax.profiler.stop_trace()
     out["stretch"] = (t_a, t_b)
@@ -316,14 +355,19 @@ def trace_stretch(spec: dict, seconds: float, trace_dir: str,
 
 def judge(run: Run, config: dict, registered: list, snapshot, before: dict,
           after: dict, seed: int, platform: str) -> int:
-    """The five comparisons of `correct`, each number printed beside its
-    limit. Returns `failed`."""
-    count = config["job"]["count"]
+    """The five comparisons of `correct` and the configuration's own
+    checks, each number printed beside its limit. Returns `failed`."""
+    shapes = {spec["name"]: spec for spec in fleet.job_specs(config)}
+    first = next(iter(shapes))
+
+    def shape_of(s):
+        # a generator of one shape does not stamp its samples
+        return shapes[s.get("template", first)]
 
     def short(s):
         return (s["status"] != "complete"
                 or s.get("read_status") != "complete"
-                or s.get("read_allocs") != count)
+                or s.get("read_allocs") != shape_of(s)["count"])
 
     # 1. every acknowledged evaluation reads back complete, with all of
     # its allocations in `desired run`
@@ -335,9 +379,11 @@ def judge(run: Run, config: dict, registered: list, snapshot, before: dict,
     # 2. the plain reference judges the final store
     port_range = config["fleet"]["dynamic_port_range"]
     store = store_dump.dump_store(snapshot)
-    window_jobs = {s["job_id"]: {
-        "count": count, "distinct_hosts": config["job"]["distinct_hosts"]}
-        for s in registered}
+    window_jobs = {
+        s["job_id"]: {"count": shape["count"],
+                      "distinct_hosts": shape["distinct_hosts"],
+                      "template": shape["name"], "priority": shape["priority"]}
+        for s, shape in ((s, shape_of(s)) for s in registered)}
     verdict = reference.judge(store, window_jobs, port_range)
     for name, value in sorted(verdict["counts"].items()):
         run.check(f"reference.{name}", value, 0, value == 0)
@@ -366,6 +412,13 @@ def judge(run: Run, config: dict, registered: list, snapshot, before: dict,
         run.check("resident_rows_differing", wrong, 0, wrong == 0)
         run.check("resident_base_platform", readback["platform"], platform,
                   readback["platform"] == platform)
+
+    # the guarantees only this deployment states: its own checks, each a
+    # file that imports nothing of the program, every count held to 0
+    for name in config.get("checks", []):
+        counts = plugins.load("checks", name).check(store, window_jobs, config)
+        for count_name, value in sorted(counts.items()):
+            run.check(f"{name}.{count_name}", value, 0, value == 0)
     return failed
 
 
@@ -415,6 +468,7 @@ def run_cell(args, run: Run) -> int:
 
     server = Server(ServerConfig(**config["server"]))
     http = child = conn = None
+    drained = threading.Event()
     trace_dir = tempfile.TemporaryDirectory(prefix="bench-trace-")
     try:
         t0 = time.monotonic()
@@ -436,7 +490,8 @@ def run_cell(args, run: Run) -> int:
         run.say(f"fleet: {loaded} through the raft log in {fleet_s:.1f} s; "
                 f"server at {http.addr}")
 
-        child = start_generator(http.addr, cell, config, traffic, args.seed)
+        child = start_generator(http.addr, cell, config, traffic, args.seed,
+                                args.seconds)
         t0 = time.monotonic()
         warm = warm_up(run, conn, traffic["warmup"], child)
         warmup_s = time.monotonic() - t0
@@ -458,7 +513,7 @@ def run_cell(args, run: Run) -> int:
                 target=trace_stretch, name="trace-stretch",
                 args=(traffic["trace"], args.seconds, trace_dir.name,
                       args.rehearse, lambda: get_batcher().dispatches,
-                      traced))
+                      drained, traced))
             tracer.start()
         time.sleep(max(0.0, stop_at - time.monotonic()))
         after = counters.read_counters(conn)
@@ -467,9 +522,12 @@ def run_cell(args, run: Run) -> int:
                 f"the window's end")
 
         gen = collect(child, traffic)
+        drained.set()
         if tracer is not None:
             tracer.join()
-            run.say(f"trace written out in {traced['written_s']:.1f} s")
+            run.say(f"trace written out in {traced['written_s']:.1f} s, "
+                    f"{time.monotonic() - stop_at:.1f} s after the "
+                    f"window's end")
         samples = gen["samples"]
         run.say(f"drain {gen['t_drained'] - stop_at:.2f} s, read-back "
                 f"{gen['t_read_back'] - gen['t_drained']:.2f} s, "
@@ -482,7 +540,7 @@ def run_cell(args, run: Run) -> int:
 
         # ---- metrics
         place_ms = [stats.latency_ms(s) if s["t_terminal"] is not None
-                    else (stop_at + traffic["drain_s"] - s["t_register"]) * 1e3
+                    else (stop_at + traffic["drain_s"] - stats.due(s)) * 1e3
                     for s in registered]
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
                  for d in devices]
@@ -526,8 +584,10 @@ def run_cell(args, run: Run) -> int:
                            trace_dir.name, "/host:CPU", "tf_XLA"))
             ctx["profile"] = {
                 "busy_s": reduced["busy_s"],
-                "evals_completed": sum(
-                    1 for s in completed if t_a <= s["t_terminal"] < t_b)}
+                # completions over the whole stretch, the part of it
+                # that lies in the drain included
+                "evals_completed": len(stats.window_samples(
+                    samples, t_a, t_b)["completed"])}
             device["busy_s"] = reduced["busy_s"]
             device["window_s"] = t_b - t_a
             result["breakdown"] = {"device_ops": reduced["device_ops"],
@@ -539,9 +599,11 @@ def run_cell(args, run: Run) -> int:
             result["metrics"] = reported(
                 bench["end_to_end"], cell["name"],
                 lambda entry: end_to_end_value(entry["name"], ctx))
+        result["compared"] = run.compared()
         run.say(json.dumps(result))
         return 0
     finally:
+        drained.set()
         if child is not None and child.poll() is None:
             child.kill()
             child.wait()
@@ -566,7 +628,11 @@ def main(argv=None, mark: str = "") -> int:
                     help="tiny size on whatever backend JAX finds; every "
                          "line is marked and none is a result")
     args = ap.parse_args(argv)
-    return run_cell(args, Run(args.rehearse, mark))
+    run = Run(args.rehearse, mark)
+    try:
+        return run_cell(args, run)
+    finally:
+        run.say_compared()      # after the server's own last words
 
 
 if __name__ == "__main__":

@@ -69,14 +69,23 @@ def bucket_percentile_ms(buckets, count: int, q: float):
     return bucket_upper_ms(len(buckets) - 1)
 
 
+def due(sample) -> float:
+    """When the request was due: an open loop's schedule says
+    (`t_due`); a closed loop's request is due when its client sends it,
+    so a sample without `t_due` counts from `t_register`."""
+    t_due = sample.get("t_due")
+    return sample["t_register"] if t_due is None else t_due
+
+
 def window_samples(samples, start: float, end: float) -> dict:
     """Split the generator's samples by the window [start, end).
 
-    `registered`: evals whose register call started inside the window
-    (the latency population and `attempted`). `completed`: evals seen
-    complete inside the window, whenever they were registered (the
-    throughput population)."""
-    registered = [s for s in samples if start <= s["t_register"] < end]
+    `registered`: evals that were due inside the window: whose register
+    call started there or, in an open loop, was scheduled there, however
+    late it was sent (the latency population and `attempted`).
+    `completed`: evals seen complete inside the window, whenever they
+    were registered (the throughput population)."""
+    registered = [s for s in samples if start <= due(s) < end]
     completed = [s for s in samples
                  if s["status"] == "complete" and s["t_terminal"] is not None
                  and start <= s["t_terminal"] < end]
@@ -84,4 +93,6 @@ def window_samples(samples, start: float, end: float) -> dict:
 
 
 def latency_ms(sample) -> float:
-    return (sample["t_terminal"] - sample["t_register"]) * 1000.0
+    """From when the request was due, so that a stall is charged to
+    every request it held up (no coordinated omission)."""
+    return (sample["t_terminal"] - due(sample)) * 1000.0

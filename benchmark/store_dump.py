@@ -31,6 +31,23 @@ def _alloc_total(alloc):
     return cpu, mem, disk, iops
 
 
+def _priority(alloc) -> int:
+    """The priority of the allocation's job as the store holds it, -1
+    where the allocation carries no job."""
+    return alloc.job.priority if alloc.job is not None else -1
+
+
+def _evals(state) -> dict:
+    """Every evaluation's job, status, trigger and predecessor: a check
+    can then see that an evicted job got its follow-up evaluation."""
+    evals = list(state.evals())
+    return {"eval_ids": [ev.id for ev in evals],
+            "eval_job": [ev.job_id for ev in evals],
+            "eval_status": [ev.status for ev in evals],
+            "eval_trigger": [ev.triggered_by for ev in evals],
+            "eval_previous": [ev.previous_eval for ev in evals]}
+
+
 def dump_store(state) -> dict:
     """`state` is the server's StateStore (or a snapshot of it)."""
     nodes = list(state.nodes())
@@ -61,10 +78,20 @@ def dump_store(state) -> dict:
     job_ids, job_row = [], {}
     alloc_ids, alloc_eval = [], []
     alloc_node, alloc_job, usage, alloc_mbits = [], [], [], []
+    alloc_priority = []
     port_alloc, port_value = [], []
+    gone = {"ids": [], "node": [], "job": [], "priority": [], "desired": []}
     for alloc in state.allocs():
-        if alloc.desired_status != LIVE_DESIRED \
-                or alloc.client_status in DEAD_CLIENT:
+        if alloc.desired_status != LIVE_DESIRED:
+            # stopped or evicted: kept apart for a deployment's own
+            # checks (who was evicted, from where, at what priority)
+            gone["ids"].append(alloc.id)
+            gone["node"].append(row.get(alloc.node_id, -1))
+            gone["job"].append(alloc.job_id)
+            gone["priority"].append(_priority(alloc))
+            gone["desired"].append(alloc.desired_status)
+            continue
+        if alloc.client_status in DEAD_CLIENT:
             continue
         a = len(alloc_ids)
         alloc_ids.append(alloc.id)
@@ -76,6 +103,7 @@ def dump_store(state) -> dict:
             job_ids.append(alloc.job_id)
         alloc_job.append(j)
         usage.append(_alloc_total(alloc))
+        alloc_priority.append(_priority(alloc))
         bw = 0
         # The first network of each task is the one the reference's
         # NetworkIndex counts (network.go AddAllocs).
@@ -104,4 +132,11 @@ def dump_store(state) -> dict:
         "port_alloc": np.asarray(port_alloc, np.int64),
         "port_value": np.asarray(port_value, np.int64),
         "job_ids": job_ids,
+        "alloc_priority": np.asarray(alloc_priority, np.int64),
+        "gone_ids": gone["ids"],
+        "gone_node": np.asarray(gone["node"], np.int64),
+        "gone_job": gone["job"],
+        "gone_priority": np.asarray(gone["priority"], np.int64),
+        "gone_desired": gone["desired"],
+        **_evals(state),
     }

@@ -12,12 +12,13 @@ import pytest
 import control
 import run
 
-ARGS = ["--workload", "northstar-10k.storm", "--seconds", "4",
-        "--trace", "0", "--rehearse"]
+ARGS = ["--seconds", "4", "--trace", "0", "--rehearse"]
+STORM, STEADY = "northstar-10k.storm", "northstar-10k.steady"
 
 
-def result_of(capsys, seed, mark=""):
-    assert run.main(ARGS + ["--seed", str(seed)], mark=mark) == 0
+def result_of(capsys, seed, mark="", cell=STORM):
+    assert run.main(ARGS + ["--workload", cell, "--seed", str(seed)],
+                    mark=mark) == 0
     lines = capsys.readouterr().out.strip().splitlines()
     prefix = "REHEARSAL " + mark
     assert all(line.startswith(prefix) for line in lines)
@@ -31,15 +32,20 @@ def test_sound_run_is_correct(capsys):
     assert result["attempted"] > 0 and result["failed"] == 0
 
 
-@pytest.mark.parametrize("seed", [102, 2**31 + 11])
-def test_bfloat16_sums_are_not_correct(capsys, seed):
+@pytest.mark.parametrize("cell,seed", [
+    (STORM, 102), (STORM, 2**31 + 11), (STEADY, 2**31 + 13)])
+def test_bfloat16_sums_are_not_correct(capsys, cell, seed):
     with control.sums_in_bfloat16():
-        result, failed = result_of(capsys, seed, mark="CONTROL bf16 ")
+        result, failed = result_of(capsys, seed, mark="CONTROL bf16 ",
+                                   cell=cell)
     assert result["correct"] is False
     assert any("resident_rows_differing" in line for line in failed), failed
+    assert result["compared"]["resident_rows_differing"]["ok"] is False
+    assert list(result["compared"])[0] == "resident_rows_differing"
 
 
-def test_a_dropped_allocation_is_not_correct(capsys, monkeypatch):
+@pytest.mark.parametrize("cell", [STORM, STEADY])
+def test_a_dropped_allocation_is_not_correct(capsys, monkeypatch, cell):
     from nomad_tpu.state.store import StateStore
 
     upsert = StateStore.upsert_allocs
@@ -50,7 +56,7 @@ def test_a_dropped_allocation_is_not_correct(capsys, monkeypatch):
         return upsert(self, index, allocs)
 
     monkeypatch.setattr(StateStore, "upsert_allocs", lossy)
-    result, failed = result_of(capsys, 103)
+    result, failed = result_of(capsys, 103, cell=cell)
     assert result["correct"] is False
     assert result["failed"] > 0
     assert any("evals_not_complete_with_all_allocs" in line

@@ -15,8 +15,11 @@ PORTS = (20000, 60000)
 
 
 class FakeState:
-    def __init__(self, nodes, allocs):
-        self._nodes, self._allocs = nodes, allocs
+    def __init__(self, nodes, allocs, evals=()):
+        self._nodes, self._allocs, self._evals = nodes, allocs, evals
+
+    def evals(self):
+        return self._evals
 
     def nodes(self):
         return self._nodes
@@ -114,6 +117,43 @@ def test_an_untouched_node_is_not_judged_and_dead_allocs_do_not_count():
     allocs = [make_alloc("n1", "j1", ports=(20001,)), dead, lost,
               make_alloc("n2", "filler", cpu=3900)]
     assert not any(judge(nodes, allocs).values())
+
+
+def test_what_went_is_kept_apart_for_a_deployments_own_checks():
+    """An evicted or stopped allocation is no part of any sum, and is
+    copied out with its node, job and priority; evaluations come with
+    their job, status, trigger and predecessor. Keys `reference.judge`
+    reads are the live ones, as before."""
+    from nomad_tpu import mock
+
+    nodes = [make_node("n1"), make_node("n2")]
+    live = make_alloc("n1", "j1")
+    live.job.priority = 80
+    evicted = make_alloc("n2", "low", ports=(20002,))
+    evicted.desired_status = "evict"
+    evicted.job.priority = 20
+    stopped = make_alloc("n1", "old", ports=())
+    stopped.desired_status = "stop"
+    stopped.job = None
+    follow_up = mock.eval()
+    follow_up.job_id, follow_up.triggered_by = "low", "preemption"
+    store = store_dump.dump_store(
+        FakeState(nodes, [live, evicted, stopped], [follow_up]))
+    assert store["alloc_ids"] == [live.id]
+    assert list(store["alloc_priority"]) == [80]
+    assert store["gone_ids"] == [evicted.id, stopped.id]
+    assert list(store["gone_node"]) == [1, 0]
+    assert store["gone_job"] == ["low", "old"]
+    assert list(store["gone_priority"]) == [20, -1]
+    assert store["gone_desired"] == ["evict", "stop"]
+    assert store["eval_job"] == ["low"]
+    assert store["eval_trigger"] == ["preemption"]
+    assert store["eval_ids"] == [follow_up.id]
+    assert store["eval_status"] == [follow_up.status]
+    assert store["eval_previous"] == [follow_up.previous_eval]
+    assert not any(reference.judge(
+        store, {"j1": {"count": 1, "distinct_hosts": True}},
+        PORTS)["counts"].values())
 
 
 @pytest.mark.parametrize("seed", range(8))

@@ -13,7 +13,50 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in BENCH["workloads"]]
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
+
+# What the two cells accepted with PR 24 printed before the harness took
+# an open loop, several job shapes and a deployment's own checks: the
+# names of the numbers compared, in order, and of the metrics. A
+# generalisation of the harness may not move them; a metric a later PR
+# adds for every cell is named in ADDED_SINCE.
+PINNED_CHECKS = [
+    "evals_not_complete_with_all_allocs", "evals_registered_in_window",
+    "reference.allocs_on_unknown_node", "reference.distinct_hosts_shared",
+    "reference.nodes_not_ready_or_draining", "reference.nodes_over_bandwidth",
+    "reference.nodes_over_cpu", "reference.nodes_over_disk",
+    "reference.nodes_over_iops", "reference.nodes_over_memory",
+    "reference.ports_held_twice", "reference.ports_out_of_range",
+    "device_requests_in_window", "scheduler.host_fallback",
+    "scheduler.gang_host_fallback", "scheduler.breaker_rejected",
+    "scheduler.gang_breaker_rejected", "executive.host_fallbacks",
+    "pipeline.breaker_routed", "pipeline.prefetch_failures",
+    "batcher.unsharded_fallbacks", "broker.dead_lettered",
+    "broker.nack_timeouts", "breaker.trips", "breaker.failures",
+    "breaker.rejected", "breaker.state", "fit_score_placed_mean_vs_uniform",
+    "resident_rows_differing", "resident_base_platform"]
+PINNED_PER_LAYER = {
+    "alloc_upsert_p50_ms", "api_register_p50_ms", "batch_wait_p50_ms",
+    "batch_wait_p95_ms", "broker_wait_p50_ms", "device_busy_ms_per_eval",
+    "device_idle_batch_wait_share", "device_idle_no_work_share",
+    "device_idle_stack_share", "device_requests_per_eval",
+    "dispatch_accumulate_p50_ms", "dispatch_launch_p50_ms",
+    "dispatch_wait_p50_ms", "eval_e2e_p50_ms", "eval_e2e_p95_ms",
+    "eval_uncovered_share", "eval_update_p50_ms", "gen_late_p95_ms",
+    "http_register_p50_ms", "lanes_per_dispatch", "matrix_build_p50_ms",
+    "plan_commit_p50_ms", "plan_conflicts_per_eval", "plan_queue_wait_p50_ms",
+    "plan_queue_wait_p95_ms", "plan_submit_p50_ms", "plan_verify_p50_ms",
+    "pool_wait_p50_ms", "routed_host_per_eval", "sched_self_p50_ms",
+    "solve_p50_ms", "trivial_rtt_us", "window_compiles"}
+PINNED_METRICS = {
+    ("northstar-10k.storm", 0): {"placed_allocs_per_s", "place_p50_ms",
+                                 "setup_s"},
+    ("northstar-10k.storm", 1): PINNED_PER_LAYER | {"place_tail_p95_ms"},
+    ("c1m-5k.ramp", 0): {"placed_allocs_per_s", "setup_s"},
+    ("c1m-5k.ramp", 1): PINNED_PER_LAYER,
+}
+ADDED_SINCE = {"plans_per_commit", "small_route_host_evals_per_eval"}
 
 
 def start(args, cwd=ROOT, script=None):
@@ -54,7 +97,17 @@ def test_cell_rehearsal(cell, trace):
     assert set(result["device"]) == device_keys
     for metric in result["metrics"].values():
         assert set(metric) == {"value", "unit"}
-    assert any(line.startswith("REHEARSAL check ") for line in lines)
+    checks = [line.split()[2].rstrip(":") for line in lines
+              if line.startswith("REHEARSAL check ")]
+    assert checks == list(result["compared"]) and checks
+    assert list(result)[-1] == "compared"
+    for name, row in result["compared"].items():
+        assert f"REHEARSAL {name} {row['value']} limit {row['limit']}" \
+            in proc.stderr
+    if (cell, trace) in PINNED_METRICS:
+        assert checks == PINNED_CHECKS
+        assert set(result["metrics"]) - (ADDED_SINCE if trace else set()) \
+            == PINNED_METRICS[cell, trace]
 
 
 def test_no_accelerator_no_result():
