@@ -2432,8 +2432,7 @@ def _preempt_storm(preemption_on, seed, n_nodes=16, storm_x=3):
     from nomad_tpu.structs.eval import new_eval
 
     migrate_configure(preemption_enabled=preemption_on,
-                      preempt_priority_threshold=50,
-                      pressure_probe=lambda: "red")
+                      preempt_priority_threshold=50)
     get_governor().reset_stats()
     h = Harness(seed=seed)
     for _ in range(n_nodes):
@@ -2518,8 +2517,7 @@ def run_preempt_ab(reps=3, check=False):
             arms["on"].append(_preempt_storm(True, seed=9000 + rep))
             arms["off"].append(_preempt_storm(False, seed=9500 + rep))
     finally:
-        migrate_configure(preemption_enabled=False,
-                          pressure_probe=lambda: "green")
+        migrate_configure(preemption_enabled=False)
 
     if check:
         for rep, r in enumerate(arms["on"]):

@@ -998,7 +998,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="in-flight migration budget for drain storms "
                         "(0 = unbounded)")
     p.add_argument("-preemption", dest="preemption", action="store_true",
-                   help="allow red-pressure priority preemption")
+                   help="allow priority preemption on a full cluster")
     p.add_argument("-defrag", dest="defrag", action="store_true",
                    help="enable the leader-side continuous "
                         "defragmentation loop (nomad_tpu/defrag)")

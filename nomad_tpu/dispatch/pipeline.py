@@ -45,7 +45,7 @@ from .. import profile, trace
 from ..chaos import chaos
 from ..profile import ProfiledCondition, ProfiledLock
 from ..scheduler import new_scheduler
-from ..server.worker import EvalSession
+from ..server.worker import EvalSession, routes_host
 from ..structs import Evaluation, Plan, PlanResult, consts
 from ..utils import metrics
 from ..utils.backoff import poll_until
@@ -548,7 +548,8 @@ class DispatchPipeline:
         # Latency-aware routing, centralized: a batch too small to
         # amortize the device dispatch runs on the host factories with
         # identical placement semantics (parity-tested).
-        route_host = len(batch) < cfg.dense_min_batch
+        route_host = routes_host((e.eval.priority for e in batch),
+                                 cfg.dense_min_batch)
         if not route_host:
             # Device-path circuit breaker (admission/breaker.py): an
             # OPEN breaker inside its cool-down routes the whole batch

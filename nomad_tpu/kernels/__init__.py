@@ -41,9 +41,9 @@ delay.
 
 Preemption interplay (ops/preempt.py): the dense priority-preemption
 pass is NOT part of the kernel contract — kernels place into free
-capacity only. When a red-pressure, outranking eval's kernel solve
-leaves asks unplaced, the scheduler runs the separate preemption
-program over a fresh matrix (its own compiled entry point, greedy
+capacity only. When an outranking eval's kernel solve leaves asks
+unplaced (the machines are full), the scheduler runs the separate
+preemption program over the cached base (its own compiled entry point, greedy
 scoring) regardless of which kernel failed first; evictions commit
 through the plan's verified node_preemptions leg either way. A kernel
 therefore never needs victim-awareness to stay correct under

@@ -17,10 +17,12 @@ Two churn workflows live behind this module:
   bound) while the storm still drains in waves instead of stalling.
 
 - **Preemption policy** — the host-side half of the dense preemption
-  pass (ops/preempt.py): eligibility (enabled + red pressure + eval
-  priority above the threshold), the victim-selection oracle the
-  differential rig judges the kernel against, and the commit counters
-  bench --preempt-ab reads.
+  pass (ops/preempt.py): eligibility (enabled + eval priority above
+  the threshold; the scheduler asks only after the normal dense pass
+  left asks unplaced, so it is decided by the MACHINES' capacity and
+  never by the control plane's queue depth), the victim-selection
+  oracle the differential rig judges the kernel against, and the
+  pass/commit/failure counters ``stats.churn`` carries.
 
 Both are process-global and lock-guarded, like the breaker and the
 resident-state tracker (one device path / one leader per process);
@@ -41,7 +43,7 @@ Chaos sites (nomad_tpu/chaos):
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 # Default in-flight migration budget (ServerConfig.migrate_max_parallel
 # overrides; 0 = unbounded). 32 keeps a 100-node drain storm to a few
@@ -150,55 +152,51 @@ class _PreemptPolicy:
         self._lock = threading.Lock()
         self.enabled = False  # guarded-by: _lock
         self.priority_threshold = DEFAULT_PREEMPT_PRIORITY  # guarded-by: _lock
-        # Pressure probe: () -> "green"|"yellow"|"red". Server init
-        # points this at its admission controller; tests force it.
-        # None = no signal = never preempt (preemption is an overload
-        # valve, not a default placement strategy).
-        self.pressure_probe: Optional[Callable[[], str]] = None  # guarded-by: _lock
+        self.passes = 0  # guarded-by: _lock (dense preemption passes run)
         self.evictions_staged = 0  # guarded-by: _lock
         self.evictions_committed = 0  # guarded-by: _lock
         self.placements = 0  # guarded-by: _lock
+        # Asks a pass left unplaced because its dispatch failed or the
+        # device-path breaker refused it (the same events as the
+        # scheduler's preempt_dispatch_failed / preempt_breaker_rejected
+        # counters on /v1/metrics, here beside what they cost).
+        self.dispatch_failed = 0  # guarded-by: _lock
+        self.breaker_rejected = 0  # guarded-by: _lock
 
     def configure(self, enabled: Optional[bool] = None,
-                  priority_threshold: Optional[int] = None,
-                  pressure_probe: Optional[Callable[[], str]] = None) -> None:
+                  priority_threshold: Optional[int] = None) -> None:
         with self._lock:
             if enabled is not None:
                 self.enabled = bool(enabled)
             if priority_threshold is not None:
                 self.priority_threshold = int(priority_threshold)
-            if pressure_probe is not None:
-                self.pressure_probe = pressure_probe
 
     def eligible(self, eval_priority: int) -> bool:
         with self._lock:
-            if not self.enabled:
-                return False
-            if eval_priority <= self.priority_threshold:
-                return False
-            probe = self.pressure_probe
-        if probe is None:
-            return False
-        try:
-            return probe() == "red"
-        except Exception:  # noqa: BLE001 - a broken probe must not fail evals
-            return False
+            return self.enabled and eval_priority > self.priority_threshold
 
     def note(self, staged: int = 0, committed: int = 0,
-             placements: int = 0) -> None:
+             placements: int = 0, passes: int = 0,
+             dispatch_failed: int = 0, breaker_rejected: int = 0) -> None:
         with self._lock:
             self.evictions_staged += staged
             self.evictions_committed += committed
             self.placements += placements
+            self.passes += passes
+            self.dispatch_failed += dispatch_failed
+            self.breaker_rejected += breaker_rejected
 
     def stats(self) -> Dict[str, object]:
         with self._lock:
             return {
                 "enabled": self.enabled,
                 "priority_threshold": self.priority_threshold,
+                "passes": self.passes,
                 "evictions_staged": self.evictions_staged,
                 "evictions_committed": self.evictions_committed,
                 "placements": self.placements,
+                "preempt_dispatch_failed": self.dispatch_failed,
+                "preempt_breaker_rejected": self.breaker_rejected,
             }
 
 
@@ -212,28 +210,45 @@ def get_governor() -> MigrationGovernor:
 
 def configure(migrate_max_parallel: Optional[int] = None,
               preemption_enabled: Optional[bool] = None,
-              preempt_priority_threshold: Optional[int] = None,
-              pressure_probe: Optional[Callable[[], str]] = None) -> None:
+              preempt_priority_threshold: Optional[int] = None) -> None:
     """Server-init configuration funnel (mirrors breaker/resident/
     kernels: last explicit configuration wins, counters survive)."""
     _governor.configure(max_parallel=migrate_max_parallel)
     _policy.configure(enabled=preemption_enabled,
-                      priority_threshold=preempt_priority_threshold,
-                      pressure_probe=pressure_probe)
+                      priority_threshold=preempt_priority_threshold)
 
 
 def preemption_eligible(eval_priority: int) -> bool:
     """Whether this eval may run the dense preemption pass: preemption
-    is on, the cluster reads red (the PR 5 admission signal), and the
-    eval outranks the threshold. Checked AFTER normal placement failed
-    — preemption is the last resort, never the first choice."""
+    is on and the eval strictly outranks the threshold. The scheduler
+    runs the pass only for asks the normal dense pass left unplaced
+    (scheduler/tpu.py), so what decides is the machines' capacity: a
+    cluster with headroom never evicts, whatever the control plane's
+    pressure reads (upstream Nomad's own rule)."""
     return _policy.eligible(eval_priority)
 
 
 def note_preemption(staged: int, placements: int = 0) -> None:
-    """Scheduler-side accounting: victims staged into a plan and the
-    placements they enabled."""
-    _policy.note(staged=staged, placements=placements)
+    """Scheduler-side accounting: one pass, the victims it staged into
+    a plan and the placements they enabled."""
+    _policy.note(staged=staged, placements=placements, passes=1)
+
+
+def note_preemption_failure(dispatch_failed: int = 0,
+                            breaker_rejected: int = 0) -> None:
+    """Asks a pass left unplaced because its dispatch failed or the
+    breaker refused it: counted here (``stats.churn``) and on
+    /v1/metrics, like every other route off the device."""
+    from ..utils import metrics
+
+    if dispatch_failed:
+        metrics.incr_counter(
+            ("scheduler", "preempt_dispatch_failed"), dispatch_failed)
+    if breaker_rejected:
+        metrics.incr_counter(
+            ("scheduler", "preempt_breaker_rejected"), breaker_rejected)
+    _policy.note(dispatch_failed=dispatch_failed,
+                 breaker_rejected=breaker_rejected)
 
 
 def note_preemption_committed(n: int) -> None:
@@ -323,6 +338,7 @@ __all__ = [
     "get_governor",
     "note_preemption",
     "note_preemption_committed",
+    "note_preemption_failure",
     "preempt_stats",
     "preemption_eligible",
     "select_victims_host",

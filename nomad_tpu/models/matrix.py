@@ -85,7 +85,8 @@ class _ClusterBase:
                  "alloc_groups", "token", "allocs_index", "table_len",
                  "nodes_index", "delta_parent", "class_ids", "class_reps",
                  "class_index", "topology", "_positions",
-                 "_positions_lock")
+                 "_positions_lock", "_victims", "_victims_lock",
+                 "_row_of")
 
     def __init__(self, nodes, proposed_fn, allocs_index: int = -1,
                  table_len: int = -1, nodes_index: int = -1):
@@ -126,6 +127,11 @@ class _ClusterBase:
 
         self._positions = None  # guarded-by: _positions_lock
         self._positions_lock = ProfiledLock("models.matrix.positions")
+        # Preemption candidates by node (_VictimTable), built lazily by
+        # the first preempting eval and carried along the delta chain.
+        self._victims = None  # guarded-by: _victims_lock
+        self._victims_lock = ProfiledLock("models.matrix.victims")
+        self._row_of = None  # node id -> row, lazy; shared by delta clones
         self._fill_all(nodes, proposed_fn)
 
     def _init_class_index(self, nodes) -> None:
@@ -174,6 +180,43 @@ class _ClusterBase:
                     for jid, per in positions.items()
                 }
             return self._positions.get(job_id, {})
+
+    def row_of(self, nodes) -> Dict[str, int]:
+        """{node id: row} of this base's node set. Built once per
+        family: delta clones keep the node set and share the dict."""
+        rows = self._row_of
+        if rows is None:
+            rows = self._row_of = {
+                node.id: i for i, node in enumerate(nodes)}
+        return rows
+
+    def victim_table(self, nodes, state) -> "_VictimTable":
+        """The preemption candidates of every node of this base's
+        snapshot `state` (ops/preempt.py), built once per base: the
+        first preempting eval pays the walk over the store's live
+        allocations, its batch-mates wait for it here, and newer bases
+        re-derive only the rows their delta touched (_patch_victims)."""
+        with self._victims_lock:
+            if self._victims is None:
+                self._victims = _VictimTable.build(self.n, nodes, state)
+            return self._victims
+
+    def _patch_victims(self, parent: "_ClusterBase", rows, nodes,
+                       state) -> None:
+        """Carry the parent's victim table forward (see
+        _patch_positions): only the changed rows are re-derived. A
+        parent that never built one leaves this base lazy too, so a
+        server that never preempts never pays for the table."""
+        with parent._victims_lock:
+            table = parent._victims
+        if table is None:
+            return
+        patched = table.with_rows({
+            i: _victim_candidates(
+                state.allocs_by_node_terminal(nodes[i].id, False))
+            for i in rows})
+        with self._victims_lock:
+            self._victims = patched
 
     def _fill_static(self, i, node) -> Tuple[float, float, int]:
         """Node-only (alloc-independent) fields of one row. Returns
@@ -417,6 +460,9 @@ class _ClusterBase:
 
         new._positions_lock = ProfiledLock("models.matrix.positions")
         new._positions = None  # patched below when the parent built one
+        new._victims_lock = ProfiledLock("models.matrix.victims")
+        new._victims = None  # likewise
+        new._row_of = self._row_of
         new.capacity = self.capacity.copy()
         new.sched_capacity = self.sched_capacity.copy()
         new.util = self.util.copy()
@@ -462,6 +508,7 @@ class _ClusterBase:
                     new.alloc_groups[i] = list(self.alloc_groups[i])
                 new.alloc_groups[i].append((a.job_id, a.task_group))
         new._patch_positions(self, rows, old_groups)
+        new._patch_victims(self, rows, nodes, state)
         return new
 
     def _patch_positions(self, parent: "_ClusterBase", rows,
@@ -513,6 +560,97 @@ class _ClusterBase:
         # LIVE base would otherwise race job_positions' lazy build.
         with self._positions_lock:
             self._positions = patched
+
+
+def _victim_candidates(allocs, job_id=None, max_priority=None):
+    """A node's preemption candidates: its live allocations, lowest
+    priority first (nomad_tpu/migrate victim_sort_key: the host list
+    and the device tensor MUST agree on the order, because the kernel
+    returns only a victim COUNT per placement), at most
+    PREEMPT_MAX_VICTIMS of them. With a `job_id` that job's own are
+    left out, with a `max_priority` those at or above it."""
+    from ..migrate import victim_priority, victim_sort_key
+    from ..ops.preempt import PREEMPT_MAX_VICTIMS
+
+    cands = [a for a in allocs
+             if not a.terminal_status()
+             and (job_id is None or a.job_id != job_id)
+             and (max_priority is None
+                  or victim_priority(a) < max_priority)]
+    cands.sort(key=victim_sort_key)
+    return cands[:PREEMPT_MAX_VICTIMS]
+
+
+class _VictimTable:
+    """Per node, the V lowest-priority live allocations in victim
+    order with their footprints: what ops/preempt.py's VictimState
+    holds, for every job and every priority at once. Which of a row's
+    entries a given eval may evict is a prefix of it (priorities
+    ascend), so an eval masks `ok` by its own priority and patches only
+    the rows its own job or plan touches (ClusterMatrix.build_victims).
+    Immutable once built: evals of one batch share it."""
+
+    __slots__ = ("res", "bw", "ports", "prio", "ok", "lists")
+
+    def __init__(self, res, bw, ports, prio, ok, lists: List):
+        self.res, self.bw, self.ports = res, bw, ports  # [N,V,4], [N,V] x2
+        self.prio, self.ok = prio, ok  # [N,V]; padding: +inf, False
+        self.lists = lists  # row -> ordered Allocations, or None
+
+    @classmethod
+    def build(cls, n: int, nodes, state) -> "_VictimTable":
+        from ..ops.preempt import PREEMPT_MAX_VICTIMS as V
+
+        table = cls(np.zeros((n, V, 4), np.float32),
+                    np.zeros((n, V), np.float32),
+                    np.zeros((n, V), np.float32),
+                    np.full((n, V), np.inf, np.float32),
+                    np.zeros((n, V), bool), [None] * len(nodes))
+        table._set_rows({
+            i: _victim_candidates(
+                state.allocs_by_node_terminal(node.id, False))
+            for i, node in enumerate(nodes)})
+        return table
+
+    def with_rows(self, cands_by_row: Dict[int, List[Allocation]],
+                  ok: Optional[np.ndarray] = None) -> "_VictimTable":
+        """A copy in which the given rows hold the given candidates
+        (and `ok` is the caller's own mask, which it may write to)."""
+        new = _VictimTable(
+            self.res.copy(), self.bw.copy(), self.ports.copy(),
+            self.prio.copy(), self.ok.copy() if ok is None else ok,
+            list(self.lists))
+        new._set_rows(cands_by_row)
+        return new
+
+    def _set_rows(self, cands_by_row: Dict[int, List[Allocation]]) -> None:
+        """Write the given rows: one bulk assignment of every
+        candidate's memoized usage."""
+        from ..migrate import victim_priority
+
+        rows: List[int] = []
+        slots: List[int] = []
+        flat: List[Allocation] = []
+        for i, cands in cands_by_row.items():
+            self.lists[i] = cands or None
+            rows.extend([i] * len(cands))
+            slots.extend(range(len(cands)))
+            flat.extend(cands)
+        touched = np.fromiter(cands_by_row, np.intp, len(cands_by_row))
+        self.res[touched] = 0.0
+        self.bw[touched] = 0.0
+        self.ports[touched] = 0.0
+        self.prio[touched] = np.inf
+        self.ok[touched] = False
+        if not flat:
+            return
+        r, v = np.asarray(rows, np.intp), np.asarray(slots, np.intp)
+        usage = np.asarray([_alloc_usage(a) for a in flat], np.float32)
+        self.res[r, v] = usage[:, :4]
+        self.bw[r, v] = usage[:, 4]
+        self.ports[r, v] = usage[:, 5]
+        self.prio[r, v] = [victim_priority(a) for a in flat]
+        self.ok[r, v] = True
 
 
 def compute_class_index(nodes) -> Tuple[np.ndarray, List[int]]:
@@ -989,10 +1127,26 @@ class ClusterMatrix:
     """Dense view of the schedulable cluster for one job's placements."""
 
     def __init__(self, state, job: Job, plan: Optional[Plan] = None,
-                 nodes: Optional[List[Node]] = None):
+                 nodes: Optional[List[Node]] = None,
+                 plan_overlay: bool = False, ask_floor: int = 0):
+        """`plan_overlay`: where the plan already holds placements or
+        stops, take the node state from the CACHED base of the snapshot
+        and re-derive only the rows the plan touches, instead of a
+        fresh uncacheable base over every node (the preemption pass:
+        a handful of rows against 12k). Such a matrix carries no base
+        token: what it holds is no cached base's content.
+
+        `ask_floor`: pad the asks and the job's alloc positions as for
+        that many asks at least. An eval that retries on the dense path
+        gives its first attempt's count, so that the retry (fewer asks,
+        more positions) runs the programs the first attempt compiled."""
         self.state = state
         self.job = job
         self.plan = plan
+        self._plan_overlay = plan_overlay
+        self._ask_floor = ask_floor
+        self._job_rows_floor = bucket_size(
+            min(ask_floor, JOBPOS_BUCKETS[-1]), JOBPOS_BUCKETS)
         self._explicit_nodes = nodes is not None
         if nodes is None:
             from .resident import get_tracker
@@ -1015,6 +1169,8 @@ class ClusterMatrix:
         self.groups = job.task_groups
         self.g = len(self.groups)
         self._build()
+        if plan_overlay:
+            self._overlay_plan()
 
     # ------------------------------------------------------------------
 
@@ -1024,11 +1180,14 @@ class ClusterMatrix:
         return proposed_allocs_for_node(self.state, self.plan, node_id)
 
     def _cached_base(self) -> "_ClusterBase":
-        cacheable = self.plan is None or self.plan.is_no_op()
+        cacheable = (self._plan_overlay or self.plan is None
+                     or self.plan.is_no_op())
         base, self.build_kind = resolve_cluster_base(
             self.state, self.job.datacenters, nodes=self.nodes,
             explicit=self._explicit_nodes,
-            proposed_fn=self._proposed_allocs, cacheable=cacheable)
+            proposed_fn=(None if self._plan_overlay
+                         else self._proposed_allocs),
+            cacheable=cacheable)
         self.delta_rows = (len(base.delta_parent[1])
                            if self.build_kind == "delta"
                            and base.delta_parent else 0)
@@ -1036,7 +1195,7 @@ class ClusterMatrix:
 
     def _build(self) -> None:
         n, g = self.n, self.g
-        base = self._cached_base()
+        base = self._base = self._cached_base()
         if self.plan is not None and hasattr(self.state, "index"):
             # Any nodes/allocs change the matrix could have seen has
             # modify_index <= this watermark; anything later is an
@@ -1078,7 +1237,8 @@ class ClusterMatrix:
             # of (base, constraint signature); share one memo across
             # the batch instead of re-materializing ~N-sized arrays
             # per eval under the GIL.
-            okey = (base.token, feasibility_signature(self.job))
+            okey = (base.token, feasibility_signature(self.job),
+                    self._job_rows_floor)
             with _BASE_CACHE_LOCK:
                 hit = _OVERLAY_CACHE.get(okey)
             if hit is not None:
@@ -1108,6 +1268,58 @@ class ClusterMatrix:
         self.tg_count = tg_count
         self.feasible, verdicts = self._build_feasibility(base)
         self._build_compact_overlay(base, verdicts)
+
+    def _plan_rows(self) -> Dict[int, str]:
+        """{row: node id} of the nodes this matrix's plan stops,
+        evicts or places something on."""
+        plan = self.plan
+        if plan is None or plan.is_no_op():
+            return {}
+        row_of = self._base.row_of(self.nodes)
+        return {row_of[nid]: nid
+                for nid in (set(plan.node_update) | set(plan.node_allocation)
+                            | set(plan.node_preemptions))
+                if nid in row_of}
+
+    def _overlay_plan(self) -> None:
+        """Bring the rows this plan touches from the snapshot's state
+        to the proposed one (live allocations less the plan's stops and
+        victims plus its placements, scheduler/util.py
+        proposed_allocs_for_node): each row moves by the difference of
+        the two sums, as a base's delta adds a new allocation's usage.
+        Every other row IS the cached base's. The arrays touched are
+        copied first: the base's and the overlay memo's are shared."""
+        rows = self._plan_rows()
+        if not rows:
+            return
+        self.base_token = self.base_delta = self.compact_overlay = None
+        self.util = self.util.copy()
+        self.bw_used = self.bw_used.copy()
+        self.ports_free = self.ports_free.copy()
+        self.job_count = self.job_count.copy()
+        self.tg_count = self.tg_count.copy()
+        gi_by_name = {tg.name: gi for gi, tg in enumerate(self.groups)}
+
+        def total(allocs) -> np.ndarray:
+            if not allocs:
+                return np.zeros(6, np.float32)
+            return np.asarray([_alloc_usage(a) for a in allocs],
+                              np.float32).sum(axis=0)
+
+        for i, nid in rows.items():
+            proposed = self._proposed_allocs(nid)
+            moved = total(proposed) - total(
+                self.state.allocs_by_node_terminal(nid, False))
+            self.util[i] += moved[:4]
+            self.bw_used[i] += moved[4]
+            self.ports_free[i] -= moved[5]
+            mine = [a for a in proposed if a.job_id == self.job.id]
+            self.job_count[i] = len(mine)
+            self.tg_count[i] = 0
+            for a in mine:
+                gi = gi_by_name.get(a.task_group)
+                if gi is not None:
+                    self.tg_count[i, gi] += 1
 
     def _build_compact_overlay(self, base, verdicts) -> None:
         """The pre-expansion overlay (ops/binpack.py CompactOverlay):
@@ -1149,8 +1361,8 @@ class ClusterMatrix:
         c_pad = bucket_size(max(len(base.class_reps), 1), CLASS_BUCKETS)
         p_pad = bucket_size(len(patch_rows), PATCH_BUCKETS) \
             if len(patch_rows) else PATCH_BUCKETS[0]
-        j_pad = bucket_size(n_pos, JOBPOS_BUCKETS) \
-            if n_pos else JOBPOS_BUCKETS[0]
+        j_pad = max(bucket_size(n_pos, JOBPOS_BUCKETS),
+                    self._job_rows_floor)
         verd = np.zeros((c_pad, g), bool)
         verd[: len(verdicts)] = verdicts
         # Pad with self.n: out of range, dropped by the device scatter.
@@ -1201,7 +1413,7 @@ class ClusterMatrix:
         """Convert an ordered list of (tg_index) placements into padded
         ask arrays. placements: list of task-group indices."""
         k_real = len(placements)
-        k = bucket_size(k_real, ASK_BUCKETS)
+        k = bucket_size(max(k_real, self._ask_floor), ASK_BUCKETS)
         resources = np.zeros((k, 4), np.float32)
         bw = np.zeros(k, np.float32)
         ports = np.zeros(k, np.float32)
@@ -1250,45 +1462,38 @@ class ClusterMatrix:
     def build_victims(self, max_priority: int):
         """Per-node preemption candidates for ops/preempt.py: the V
         lowest-priority live allocations on each real node, sorted
-        priority-ascending (nomad_tpu/migrate victim_sort_key — the
-        host list and the device tensor MUST agree on order, because
-        the kernel returns only a victim COUNT per placement and the
-        commit loop maps it back to the first n unconsumed entries).
+        priority-ascending, strictly below ``max_priority`` (the
+        preempting eval's) and never this job's own.
 
-        Only allocs strictly below ``max_priority`` (the preempting
-        eval's) are candidates, and never this job's own. Returns
-        (victim_arrays, victim_lists) where victim_arrays feed
-        make_victim_state and victim_lists[row] is the ordered
-        Allocation list; rows beyond n_real are padding."""
-        from ..migrate import victim_priority, victim_sort_key
-        from ..ops.preempt import PREEMPT_MAX_VICTIMS as V
+        Looked up in the table the snapshot's cached base keeps
+        (_VictimTable: every job's and priority's candidates, built
+        once per base): this eval masks it by its priority, and only
+        the rows where its own job runs or its plan stops, evicts or
+        places something are derived again from the store, exactly.
 
-        n = self.n
-        res = np.zeros((n, V, 4), np.float32)
-        bw = np.zeros((n, V), np.float32)
-        ports = np.zeros((n, V), np.float32)
-        prio = np.full((n, V), np.inf, np.float32)
-        ok = np.zeros((n, V), bool)
-        victim_lists: Dict[int, List[Allocation]] = {}
-        total = 0
-        for i, node in enumerate(self.nodes):
-            cands = [
-                a for a in self._proposed_allocs(node.id)
-                if not a.terminal_status()
-                and a.job_id != self.job.id
-                and victim_priority(a) < max_priority
-            ]
-            if not cands:
-                continue
-            cands.sort(key=victim_sort_key)
-            cands = cands[:V]
-            victim_lists[i] = cands
-            total += len(cands)
-            for v, alloc in enumerate(cands):
-                cpu, mem, disk, iops, mbits, nports = _alloc_usage(alloc)
-                res[i, v] = (cpu, mem, disk, iops)
-                bw[i, v] = mbits
-                ports[i, v] = nports
-                prio[i, v] = victim_priority(alloc)
-                ok[i, v] = True
-        return (res, bw, ports, prio, ok), victim_lists, total
+        Returns (victim_arrays, victims_of, total): victim_arrays feed
+        make_victim_state, victims_of(row) is the row's ordered
+        Allocation list (the commit loop maps the kernel's victim COUNT
+        back to its next unconsumed entries), total the number of
+        candidates; rows beyond n_real are padding."""
+        base = self._base
+        table = base.victim_table(self.nodes, self.state)
+        res, bw, ports, prio = table.res, table.bw, table.ports, table.prio
+        ok = table.ok & (prio < max_priority)
+        lists = table.lists
+
+        patch = {int(i) for rows in base.job_positions(self.job.id).values()
+                 for i in rows} | set(self._plan_rows())
+        if patch:
+            own = table.with_rows({
+                i: _victim_candidates(
+                    self._proposed_allocs(self.nodes[i].id),
+                    self.job.id, max_priority)
+                for i in patch}, ok)
+            res, bw, ports, prio = own.res, own.bw, own.ports, own.prio
+            lists = own.lists
+
+        def victims_of(row: int) -> List[Allocation]:
+            return lists[row] or []
+
+        return (res, bw, ports, prio, ok), victims_of, int(ok.sum())

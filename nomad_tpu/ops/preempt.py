@@ -1,15 +1,21 @@
 """Dense priority preemption: victim selection and placement in one
 masked pass (ROADMAP item 3; SURVEY.md build-plan stage 7).
 
-A red-pressure cluster (admission/pressure.py) has no headroom for a
-high-priority eval, so the normal feasibility mask is all-false and
-the eval would block. The reference handles this with per-node
-iterator walks over candidate allocs; here the whole decision runs as
-ONE compiled program over the cluster:
+A cluster whose MACHINES are full has no headroom for a
+high-priority eval, whatever the control plane's pressure reads: the
+normal dense pass leaves its asks unplaced and the eval would block.
+An eval above the priority threshold then runs this pass over the
+unplaced asks (migrate.preemption_eligible; a cluster with headroom
+never gets here). The reference handles this with per-node iterator
+walks over candidate allocs; here the whole decision runs as ONE
+compiled program over the cluster:
 
-- the host builds a ``VictimState``: per node, the V lowest-priority
-  live allocations sorted priority-ascending (models/matrix.py
-  ``build_victims``), with their resource/bandwidth/port footprints;
+- the host hands over a ``VictimState``: per node, the V
+  lowest-priority live allocations sorted priority-ascending, with
+  their resource/bandwidth/port footprints (models/matrix.py: a table
+  kept beside the cached cluster base and carried along its delta
+  chain, ``ClusterMatrix.build_victims`` patches the few rows this
+  eval's own job or plan touches);
 - for each ask the kernel computes, per node, the cumulative capacity
   freed by evicting the first k victims (a prefix cumsum over the
   sorted axis) and the smallest k that makes the ask fit — *victim
@@ -211,10 +217,11 @@ def preempt_placement_program(state, victims: VictimState, asks, key,
             eval_priority, config, noise_row)
         return (new_st, new_vok), out
 
-    (_, _), (choices, scores, n_victims) = jax.lax.scan(
-        body, (state, victims.ok),
-        (asks.resources, asks.bw, asks.ports, feas_rows, tg_onehots,
-         tg_dhs, asks.active, noise))
+    with jax.named_scope("preempt_scan"):
+        (_, _), (choices, scores, n_victims) = jax.lax.scan(
+            body, (state, victims.ok),
+            (asks.resources, asks.bw, asks.ports, feas_rows, tg_onehots,
+             tg_dhs, asks.active, noise))
     return choices, scores, n_victims
 
 

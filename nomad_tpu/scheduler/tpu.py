@@ -40,6 +40,12 @@ from .generic import GenericScheduler
 from .system import SystemScheduler
 from .util import AllocTuple
 
+# A preemption-eligible eval whose task groups ask for at most this
+# many allocations in all pads its asks to that count (see
+# BatchedTPUScheduler._compute_placements): the top of the ask ladder's
+# small steps (models/matrix.py ASK_BUCKETS 8/16/32/64).
+REPLAN_PAD_MAX_ASKS = 64
+
 
 def _offer_networks(rng, missing: AllocTuple, node, net_indexes, matrix):
     """Exact per-task network offers on a dense-path-chosen node.
@@ -237,8 +243,25 @@ class BatchedTPUScheduler(GenericScheduler):
             super()._compute_placements(bulk)
             return
 
+        ask_floor = 0
+        if may_preempt:
+            # Such an eval is replanned HERE after a partially
+            # committed plan (requeued, so by a new scheduler), with
+            # fewer asks and more positions of its own. It pads both as
+            # if none of its task groups' allocations were placed yet:
+            # the replan then runs the programs the first attempt
+            # compiled and never mints one (on a full cell every
+            # production eval is such an eval, and a compile is seconds
+            # of its latency). Small task groups only: that is where a
+            # replan changes bucket, and a large one scaling up by a
+            # few must not scan its whole count.
+            counts = {m.task_group.name: m.task_group.count for m in bulk}
+            ask_floor = sum(counts.values())
+            if ask_floor > REPLAN_PAD_MAX_ASKS:
+                ask_floor = 0
         _t0 = time.monotonic()
-        matrix = ClusterMatrix(self.state, self.job, self.plan)
+        matrix = ClusterMatrix(self.state, self.job, self.plan,
+                               ask_floor=ask_floor)
         _t_base = time.monotonic()
         tg_indices = {tg.name: i for i, tg in enumerate(self.job.task_groups)}
         placements = [tg_indices[m.task_group.name] for m in bulk]
@@ -310,6 +333,14 @@ class BatchedTPUScheduler(GenericScheduler):
                 # soak drives trip -> half-open -> reclose through
                 # this site).
                 chaos.fire("device.breaker_trip", eval_id=self.eval.id)
+            if may_preempt:
+                # The other half of the padding above: an inline replan
+                # plans on a snapshot no launch prologue prefetched.
+                # Its base is made resident first (a no-op wherever it
+                # already is), so the dispatch takes the plain program
+                # and not the one that fuses the base's delta in: one
+                # program a shape for such an eval.
+                get_batcher().prefetch_base(matrix)
             choices, scores = get_batcher().place(
                 matrix, asks, key, config,
                 span=(self.eval.id, self.eval.trace_id))
@@ -347,8 +378,8 @@ class BatchedTPUScheduler(GenericScheduler):
         # never commit through this loop).
         committed: List[Tuple[int, int]] = []
         # Asks the kernel could not place: candidates for the dense
-        # preemption pass (red pressure + outranking eval only) before
-        # they become recorded failures.
+        # preemption pass (an outranking eval only) before they become
+        # recorded failures.
         unplaced: List[AllocTuple] = []
 
         for j, missing in enumerate(bulk):
@@ -397,7 +428,13 @@ class BatchedTPUScheduler(GenericScheduler):
         self._note_quality(kernel, matrix, ask_arrays[0], committed)
 
         if unplaced:
-            self._preempt_placements(unplaced, tg_indices)
+            _t0 = time.monotonic()
+            candidates = self._preempt_placements(unplaced, tg_indices,
+                                                  ask_floor)
+            trace.record_span(
+                self.eval.id, trace.STAGE_PREEMPT_SELECT, _t0,
+                ann={"asks": len(unplaced), "candidates": candidates},
+                trace_id=self.eval.trace_id)
 
     def _place_gang_dense(self, tg, tuples: List[AllocTuple]) -> None:
         """One gang's all-K dispatch (ops/gang.py): per-node fit mask
@@ -523,18 +560,23 @@ class BatchedTPUScheduler(GenericScheduler):
             committed)
 
     def _preempt_placements(self, pending: List[AllocTuple],
-                            tg_indices: Dict[str, int]) -> None:
+                            tg_indices: Dict[str, int],
+                            ask_floor: int) -> int:
         """The dense preemption pass (ops/preempt.py): place the asks
         the normal kernel could not, by selecting lowest-priority
         victims and the placement in the same masked program. Runs
         only when migrate.preemption_eligible said yes (preemption on,
-        cluster red, eval outranks the threshold). Victim evictions
+        eval outranks the threshold) and the normal pass left these
+        asks unplaced: the machines are full. Victim evictions
         are staged on the plan's node_preemptions leg and re-verified
         per victim by the plan applier before committing with the
         placements in one raft apply — chaos site preempt.victim_lost
-        drops a staged victim here to prove that verification."""
+        drops a staged victim here to prove that verification.
+        `ask_floor` is the normal pass's (see _compute_placements).
+        Returns the number of victim candidates the pass had to choose
+        from."""
         from ..chaos import chaos
-        from ..migrate import note_preemption
+        from ..migrate import note_preemption, note_preemption_failure
         from ..models.matrix import ClusterMatrix
         from ..ops.binpack import (
             PlacementConfig,
@@ -564,18 +606,22 @@ class BatchedTPUScheduler(GenericScheduler):
                 self._record_placement_failure(missing, pm, metrics,
                                                tg_indices)
 
-        _t0 = time.monotonic()
-        # A FRESH matrix including this very plan's placements and
-        # staged stops (the plan is non-no-op by now, so this build is
-        # uncacheable by design): the preemption pass must not claim
+        # The node state is the snapshot's CACHED base (the one the
+        # normal pass just used) with only the rows this very plan
+        # touches derived again: the preemption pass must not claim
         # headroom an earlier ask of this same eval just took, and its
         # victim lists must exclude allocs the plan already stops.
-        pm = ClusterMatrix(self.state, self.job, self.plan)
-        varrays, victim_lists, n_candidates = pm.build_victims(
+        pm = ClusterMatrix(self.state, self.job, self.plan,
+                           plan_overlay=True, ask_floor=ask_floor)
+        _t_victims = time.monotonic()
+        varrays, victims_of, n_candidates = pm.build_victims(
             self.eval.priority)
+        trace.record_span(
+            self.eval.id, trace.STAGE_PREEMPT_VICTIMS, _t_victims,
+            ann={"candidates": n_candidates}, trace_id=self.eval.trace_id)
         if n_candidates == 0:
             fail_all(pending, pm)
-            return
+            return 0
         placements = [tg_indices[m.task_group.name] for m in pending]
         ask_arrays = pm.build_asks(placements)
         asks = make_asks(*ask_arrays)
@@ -593,41 +639,37 @@ class BatchedTPUScheduler(GenericScheduler):
         # The preemption dispatch shares the device-path breaker: a
         # persistently failing preempt program (e.g. device OOM from
         # the extra victim tensors) must become one routing decision,
-        # not a fresh dispatch-failure latency per red-pressure eval.
+        # not a fresh dispatch-failure latency per preempting eval.
         from ..admission import get_breaker
-        from ..utils import metrics as _metrics
 
         breaker = get_breaker()
         if not breaker.acquire():
-            _metrics.incr_counter(
-                ("scheduler", "preempt_breaker_rejected"), len(pending))
+            note_preemption_failure(breaker_rejected=len(pending))
             fail_all(pending, pm)
-            return
+            return n_candidates
         _t_solve = time.monotonic()
         try:
-            choices, scores, counts = preempt_placement_program_jit(
-                state, victims, asks, key,
-                np.float32(self.eval.priority), config)
+            with trace.annotation("nomad.preempt", asks=len(pending)):
+                choices, scores, counts = preempt_placement_program_jit(
+                    state, victims, asks, key,
+                    np.float32(self.eval.priority), config)
+                choices = np.asarray(choices)
+                scores = np.asarray(scores)
+                counts = np.asarray(counts)
         except Exception:  # noqa: BLE001 - degrade to plain failure
-            # The device path is sick (the cluster may be red for that
-            # very reason): these asks simply stay failed/blocked — the
+            # The device path is sick: these asks simply stay failed/blocked — the
             # no-preemption outcome, never a half-staged eviction. The
             # breaker counts the failure like any dense dispatch.
             breaker.record_failure()
             self.logger.warning(
                 "preemption dispatch failed; %d placements stay "
                 "unplaced", len(pending), exc_info=True)
-            _metrics.incr_counter(
-                ("scheduler", "preempt_dispatch_failed"), len(pending))
+            note_preemption_failure(dispatch_failed=len(pending))
             fail_all(pending, pm)
-            return
+            return n_candidates
         breaker.record_success((time.monotonic() - _t_solve) * 1000.0)
-        choices = np.asarray(choices)
-        scores = np.asarray(scores)
-        counts = np.asarray(counts)
         trace.record_span(
-            self.eval.id, trace.STAGE_PREEMPT_SELECT, _t0,
-            ann={"asks": len(pending), "candidates": n_candidates},
+            self.eval.id, trace.STAGE_PREEMPT_SOLVE, _t_solve,
             trace_id=self.eval.trace_id)
 
         net_indexes: Dict[str, NetworkIndex] = {}
@@ -651,7 +693,7 @@ class BatchedTPUScheduler(GenericScheduler):
             cnt = int(counts[j])
             taken = []
             if cnt > 0:
-                lst = victim_lists.get(choice, [])
+                lst = victims_of(choice)
                 start = consumed.get(choice, 0)
                 taken = lst[start:start + cnt]
                 consumed[choice] = start + len(taken)
@@ -684,6 +726,7 @@ class BatchedTPUScheduler(GenericScheduler):
             staged_total += staged
             placed_total += 1
         note_preemption(staged_total, placed_total)
+        return n_candidates
 
     def _note_quality(self, kernel, matrix, ask_res, committed) -> None:
         note_quality(self.logger, self.job, kernel, matrix, ask_res,

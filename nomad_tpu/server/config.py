@@ -131,11 +131,13 @@ class ServerConfig:
     # storm re-places in bounded waves instead of thundering-herding
     # the plan queue. 0 = unbounded.
     migrate_max_parallel: int = 32
-    # Priority preemption (ops/preempt.py): allow a red-pressure,
-    # above-threshold-priority eval whose placements found no room to
-    # evict lowest-priority allocs in the same dense pass. Off by
-    # default: with it off, a red cluster sheds exactly per the PR 5
-    # admission policy.
+    # Priority preemption (ops/preempt.py): allow an eval whose
+    # priority is above the threshold, and whose asks the normal dense
+    # pass found no room for, to place them by evicting the
+    # lowest-priority allocations. Decided by the machines' capacity,
+    # not by the control plane's pressure: a cluster with headroom
+    # never evicts. Off by default: with it off such an eval blocks
+    # until capacity returns.
     preemption_enabled: bool = False
     # Evals must STRICTLY outrank this to preempt (50 = the default
     # job priority, so only above-normal work may evict).

@@ -69,6 +69,7 @@ from .worker import (
     factory_kernel,
     host_factory,
     is_dense_factory,
+    routes_host,
 )
 
 DEQUEUE_TOPUP_SLICE = 0.002  # cond-wait granularity while accumulating
@@ -475,7 +476,8 @@ class SchedulerExecutive:
             return []
         snapshot = self.server.fsm.state.snapshot()
 
-        route_host = len(batch) < cfg.dense_min_batch
+        route_host = routes_host((e.eval.priority for e in batch),
+                                 cfg.dense_min_batch)
         if not route_host:
             from ..admission import get_breaker
 
@@ -506,8 +508,8 @@ class SchedulerExecutive:
         for entry, m in zip(batch, members):
             if m.fast and preemption_eligible(m.eval.priority):
                 # The eviction leg belongs to the per-eval dense
-                # scheduler (ops/preempt.py); rare by construction
-                # (red pressure + outranking priority only).
+                # scheduler (ops/preempt.py): with preemption on, every
+                # eval above the threshold takes the legacy lane.
                 m.fast = False
                 m.reason = "preemption-eligible"
             if not m.fast:

@@ -152,17 +152,13 @@ class Server:
 
         configure_kernels(self.config.placement_kernel)
         # Churn control (nomad_tpu/migrate): the migration budget and
-        # the preemption policy are process-global like the breaker;
-        # the pressure probe points preemption eligibility at THIS
-        # server's admission signal (PR 5) — preemption only ever
-        # fires on a red cluster.
+        # the preemption policy are process-global like the breaker.
         from ..migrate import configure as configure_migrate
 
         configure_migrate(
             migrate_max_parallel=self.config.migrate_max_parallel,
             preemption_enabled=self.config.preemption_enabled,
             preempt_priority_threshold=self.config.preempt_priority_threshold,
-            pressure_probe=self.admission.level,
         )
         # Continuous defragmentation (nomad_tpu/defrag): the leader-
         # side optimizer loop. Constructed unconditionally (stats

@@ -25,6 +25,7 @@ import time
 from typing import List, Optional, Tuple
 
 from ..scheduler import new_scheduler
+from ..migrate import preemption_eligible
 from ..utils import metrics
 from ..utils.backoff import poll_until
 from ..structs import Evaluation, Plan, PlanResult, consts
@@ -42,6 +43,19 @@ BACKPRESSURE_NAP = 0.01
 def is_dense_factory(name: str) -> bool:
     """Dense/TPU factories benefit from drain-to-batch processing."""
     return name.endswith("-tpu")
+
+
+def routes_host(priorities, dense_min_batch: int) -> bool:
+    """Latency-aware routing, the one rule of the worker's drain, the
+    dispatch pipeline and the scheduler executive: a batch too small to
+    amortize a device dispatch runs on the host factories (identical
+    placement semantics, parity-tested), unless one of its evals may
+    preempt. The host iterators cannot evict, so such an eval stays
+    dense at any batch size (scheduler/tpu.py keeps its few-ask retries
+    on the dense path for the same reason)."""
+    priorities = list(priorities)
+    return len(priorities) < dense_min_batch and not any(
+        preemption_eligible(p) for p in priorities)
 
 
 def factory_kernel(name: str) -> Optional[str]:
@@ -251,9 +265,9 @@ class Worker:
                 group.extend(
                     self.server.eval_dequeue_many([ev.type], batch_max - 1)
                 )
-            if batch_max > 1 and is_dense_factory(factory) and (
-                len(group) < self.server.config.dense_min_batch
-            ):
+            if batch_max > 1 and is_dense_factory(factory) and routes_host(
+                    (e.priority for e, _ in group),
+                    self.server.config.dense_min_batch):
                 # (batch_max == 1 disables batching AND routing — an
                 # operator who turned draining off still gets the dense
                 # factory they configured, one eval per dispatch.)

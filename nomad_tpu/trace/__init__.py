@@ -48,6 +48,8 @@ from .span import (  # noqa: F401
     STAGE_PLAN_QUEUE_WAIT,
     STAGE_PLAN_SUBMIT,
     STAGE_PREEMPT_SELECT,
+    STAGE_PREEMPT_SOLVE,
+    STAGE_PREEMPT_VICTIMS,
     STAGE_SCHED_PROCESS,
 )
 

@@ -44,7 +44,13 @@ STAGE_MIGRATE_PLACE = "migrate.place"      # drain-displaced allocs staged
 #   for re-placement under the migration budget (ann: migrations
 #   claimed this wave, deferred to the follow-up eval)
 STAGE_PREEMPT_SELECT = "preempt.select"    # dense victim-selection +
-#   placement pass (ops/preempt.py; ann: asks, candidate victims)
+#   placement pass, whole (ops/preempt.py; ann: asks, candidate
+#   victims); what its two children leave is `preempt.select.self`:
+#   the node state from the cached base, the asks, staging the plan
+STAGE_PREEMPT_VICTIMS = "preempt.victims"  # inside preempt.select: the
+#   victim candidates looked up in the base's table (or built)
+STAGE_PREEMPT_SOLVE = "preempt.solve"      # inside preempt.select: the
+#   preemption program, issue to results on the host (ends in a sync)
 STAGE_GANG_SELECT = "gang.select"          # all-K gang slice selection
 #   + member assignment (ops/gang.py; ann: members, mode,
 #   slice group, host_fallback) — one span per gang dispatch
@@ -98,6 +104,8 @@ ALL_STAGES = (
     STAGE_DEVICE_SOLVE,
     STAGE_MIGRATE_PLACE,
     STAGE_PREEMPT_SELECT,
+    STAGE_PREEMPT_VICTIMS,
+    STAGE_PREEMPT_SOLVE,
     STAGE_GANG_SELECT,
     STAGE_DEFRAG_SOLVE,
     STAGE_PLAN_SUBMIT,

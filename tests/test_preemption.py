@@ -1,8 +1,10 @@
 """Priority preemption as a dense kernel pass (ops/preempt.py +
 scheduler/tpu.py + the Plan.node_preemptions leg): kernel-level victim
 selection invariants, the plan applier's per-victim verification, the
-CPU-oracle differential judgment, the red-pressure priority-storm soak
-with preemption ON vs OFF, victim-lost chaos, and jit-cache stability
+CPU-oracle differential judgment, the full-cluster priority-storm soak
+with preemption ON vs OFF (eligibility comes from capacity: a cluster
+with headroom never evicts, a full one does whatever the control
+plane's pressure reads), victim-lost chaos, and jit-cache stability
 with the preemption leg compiled in."""
 
 import random
@@ -44,11 +46,6 @@ def _restore_globals():
     chaos.disarm()
     configure(migrate_max_parallel=32, preemption_enabled=False,
               preempt_priority_threshold=50)
-    # Drop the test probe so a later default-configured Server rewires
-    # its own.
-    from nomad_tpu.migrate import _policy
-
-    _policy.configure(pressure_probe=lambda: "green")
 
 
 def wait_until(fn, timeout=60.0, interval=0.02):
@@ -291,12 +288,12 @@ def test_applier_rejects_outranked_preemption():
 # scheduler end-to-end (harness): the priority storm, ON vs OFF
 
 
-def _storm_harness(seed, n_nodes=4):
+def _storm_harness(seed, n_nodes=4, node_cpu=1000):
     h = Harness(seed=seed)
     nodes = []
     for _ in range(n_nodes):
         n = mock.node()
-        n.resources.cpu = 1000
+        n.resources.cpu = node_cpu
         n.resources.memory_mb = 4096
         n.compute_class()
         h.state.upsert_node(h.next_index(), n)
@@ -328,8 +325,7 @@ def _storm_harness(seed, n_nodes=4):
 
 
 def test_priority_storm_preemption_on_places_all():
-    configure(preemption_enabled=True, preempt_priority_threshold=50,
-              pressure_probe=lambda: "red")
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
     h, low, high = _storm_harness(seed=31)
     h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
                                       consts.EVAL_TRIGGER_JOB_REGISTER))
@@ -357,7 +353,7 @@ def test_priority_storm_preemption_on_places_all():
 
 
 def test_priority_storm_preemption_off_sheds_unchanged():
-    configure(preemption_enabled=False, pressure_probe=lambda: "red")
+    configure(preemption_enabled=False)
     h, low, high = _storm_harness(seed=32)
     h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
                                       consts.EVAL_TRIGGER_JOB_REGISTER))
@@ -372,13 +368,33 @@ def test_priority_storm_preemption_off_sheds_unchanged():
 
 
 def test_priority_storm_green_cluster_never_preempts():
-    configure(preemption_enabled=True, preempt_priority_threshold=50,
-              pressure_probe=lambda: "green")
-    h, low, high = _storm_harness(seed=33)
+    """Eligibility comes from capacity: with preemption on and an
+    outranking eval, a cluster that has headroom for every ask places
+    them all without an eviction (nodes that fit without eviction
+    always win, PREEMPT_VICTIM_PENALTY), and no pass runs."""
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
+    passes = preempt_stats()["passes"]
+    # 600 of 2,000 MHz used a node: the 500 MHz asks fit beside it
+    h, low, high = _storm_harness(seed=33, node_cpu=2000)
     h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
                                       consts.EVAL_TRIGGER_JOB_REGISTER))
+    assert len([a for a in h.state.allocs_by_job(high.id)
+                if not a.terminal_status()]) == 4
     assert [a for a in h.state.allocs_by_job(low.id)
             if a.desired_status == consts.ALLOC_DESIRED_EVICT] == []
+    assert preempt_stats()["passes"] == passes
+
+
+def test_eligibility_is_priority_and_switch_alone():
+    """`preemption_eligible` reads no pressure level: on, and strictly
+    above the threshold."""
+    from nomad_tpu.migrate import preemption_eligible
+
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
+    assert preemption_eligible(51) and preemption_eligible(100)
+    assert not preemption_eligible(50) and not preemption_eligible(10)
+    configure(preemption_enabled=False)
+    assert not preemption_eligible(100)
 
 
 def test_preemption_leg_jit_cache_is_stable():
@@ -386,8 +402,7 @@ def test_preemption_leg_jit_cache_is_stable():
     compiled in: a second storm of identical shape adds no programs."""
     from nomad_tpu.ops.binpack import jit_cache_size
 
-    configure(preemption_enabled=True, preempt_priority_threshold=50,
-              pressure_probe=lambda: "red")
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
     h, low, high = _storm_harness(seed=34)
     h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
                                       consts.EVAL_TRIGGER_JOB_REGISTER))
@@ -395,6 +410,76 @@ def test_preemption_leg_jit_cache_is_stable():
     h2, low2, high2 = _storm_harness(seed=35)
     h2.process("service-tpu", new_eval(h2.state.job_by_id(high2.id),
                                        consts.EVAL_TRIGGER_JOB_REGISTER))
+    assert jit_cache_size() == warm
+
+
+@pytest.mark.parametrize("ask_floor,asks,k,job_rows", [
+    (0, 3, 8, 16), (12, 3, 16, 16), (24, 7, 32, 64), (64, 1, 64, 64)])
+def test_ask_floor_pads_asks_and_job_rows(ask_floor, asks, k, job_rows):
+    """ClusterMatrix(ask_floor=n) pads the asks and the compact
+    overlay's job rows as for n asks at least; padding asks are
+    inactive."""
+    from nomad_tpu.models.matrix import ClusterMatrix
+
+    state, _nodes, jobs, _index = _banded_store(2830)
+    matrix = ClusterMatrix(state.snapshot(), jobs[3], None,
+                           ask_floor=ask_floor)
+    arrays = matrix.build_asks([0] * asks)
+    assert arrays[0].shape == (k, 4)
+    assert int(arrays[4].sum()) == asks  # `active`
+    assert matrix.compact_overlay.job_rows.shape == (job_rows,)
+
+
+@pytest.mark.parametrize("priority,count,floor", [
+    (60, 4, 4), (40, 4, 0), (60, 70, 0)])
+def test_replan_padding_is_for_small_eligible_evals(monkeypatch, priority,
+                                                    count, floor):
+    """The dense scheduler asks for the padding only where an eval may
+    preempt (it is replanned on the dense path) and its task groups ask
+    for at most REPLAN_PAD_MAX_ASKS allocations in all."""
+    from nomad_tpu.models import matrix as matrix_mod
+
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
+    h, _low, high = _storm_harness(seed=37)
+    high.priority = priority
+    high.task_groups[0].count = count
+    h.state.upsert_job(h.next_index(), high)
+    seen = []
+    init = matrix_mod.ClusterMatrix.__init__
+
+    def spy(self, *args, **kwargs):
+        seen.append(kwargs.get("ask_floor", 0))
+        init(self, *args, **kwargs)
+
+    monkeypatch.setattr(matrix_mod.ClusterMatrix, "__init__", spy)
+    h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
+                                      consts.EVAL_TRIGGER_JOB_REGISTER))
+    assert seen and set(seen) == {floor}
+
+
+def test_a_replan_of_a_preempting_eval_mints_no_program():
+    """An eval that places the last 3 of its job's 12 allocations (the
+    replan after a partially committed plan) runs the programs the
+    12-ask attempt compiled: normal pass and preemption pass alike."""
+    from nomad_tpu.ops.binpack import jit_cache_size
+
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
+    h, _low, high = _storm_harness(seed=38, n_nodes=12)
+    high.task_groups[0].count = 9
+    h.state.upsert_job(h.next_index(), high)
+    h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
+                                      consts.EVAL_TRIGGER_JOB_REGISTER))
+    warm = jit_cache_size()
+    passes = preempt_stats()["passes"]
+    high = h.state.job_by_id(high.id).copy()
+    high.task_groups[0].count = 12
+    h.state.upsert_job(h.next_index(), high)
+    h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
+                                      consts.EVAL_TRIGGER_JOB_REGISTER))
+    live = [a for a in h.state.allocs_by_job(high.id)
+            if not a.terminal_status()]
+    assert len(live) == 12
+    assert preempt_stats()["passes"] == passes + 1
     assert jit_cache_size() == warm
 
 
@@ -411,8 +496,7 @@ def test_preemption_differential_validity(seed):
     from nomad_tpu.structs import allocs_fit
 
     rng = random.Random(seed)
-    configure(preemption_enabled=True, preempt_priority_threshold=50,
-              pressure_probe=lambda: "red")
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
     h = Harness(seed=seed)
     n_nodes = rng.choice([4, 6])
     nodes = []
@@ -504,8 +588,9 @@ def test_server_preemption_soak_with_victim_lost_chaos():
 
         assert wait_until(lambda: len(live(low.id)) == 4, 60.0)
 
-        # red pressure + a victim lost between selection and commit
-        server.admission.force_level("red")
+        # a full cluster under a healthy (green) control plane, and a
+        # victim lost between selection and commit
+        assert server.admission.level() == "green"
         chaos.arm(99, [FaultSpec("preempt.victim_lost", "drop", count=1)])
         high = mock.job()
         high.id = "high-prio"
@@ -539,7 +624,7 @@ def test_server_preemption_soak_with_victim_lost_chaos():
             assert fit, node.id
         # the high-prio evals all completed; the victims' replacement
         # evals exist (blocked or pending — the cluster is full, which
-        # is the correct PR 5 outcome for prio-20 work on a red box)
+        # is the correct outcome for prio-20 work on a full cluster)
         # (the allocations are readable when the plan's entry applies,
         # the eval's terminal status an instant later)
         assert wait_until(lambda: all(
@@ -549,5 +634,227 @@ def test_server_preemption_soak_with_victim_lost_chaos():
                 if e.triggered_by == consts.EVAL_TRIGGER_PREEMPTION]
     finally:
         chaos.disarm()
-        server.admission.force_level(None)
         server.shutdown()
+
+
+# ---------------------------------------------------------------------
+# the victim table beside the cached base, and the plan overlay (PR 28)
+
+
+def _banded_store(seed, n_nodes=9):
+    """A store of three node shapes holding allocations of four jobs in
+    three priority bands, loaded through the store as the FSM does."""
+    from nomad_tpu.state import StateStore
+    from nomad_tpu.structs import Allocation, Resources
+
+    rng = random.Random(seed)
+    state = StateStore()
+    index = 10
+    nodes = []
+    for i in range(n_nodes):
+        node = mock.node()
+        node.resources.cpu = (4000, 8000, 16000)[i % 3]
+        node.resources.memory_mb = (8192, 16384, 32768)[i % 3]
+        node.compute_class()
+        index += 1
+        state.upsert_node(index, node)
+        nodes.append(node)
+    jobs = []
+    for prio in (10, 30, 70, 70):
+        job = mock.job()
+        job.id = f"band-{prio}-{len(jobs)}"
+        job.priority = prio
+        jobs.append(job)
+    allocs = []
+    for node in nodes:
+        for k in range(rng.randint(0, 11)):
+            job = rng.choice(jobs)
+            allocs.append(Allocation(
+                id=mock.alloc().id, eval_id="load", node_id=node.id,
+                name=f"{job.id}.web[{k}]", job_id=job.id, job=job,
+                task_group="web",
+                shared_resources=Resources(disk_mb=rng.choice([10, 150])),
+                task_resources={"web": Resources(
+                    cpu=rng.choice([50, 100, 250]),
+                    memory_mb=rng.choice([256, 300, 512]))},
+                desired_status="run", client_status="running"))
+    index += 1
+    state.upsert_allocs(index, allocs)
+    return state, nodes, jobs, index
+
+
+def _victims_by_walk(matrix, max_priority):
+    """What build_victims was before the table: every node's live
+    allocations through the plan, filtered, sorted, the first V."""
+    from nomad_tpu.migrate import victim_sort_key
+
+    out = {}
+    for i, node in enumerate(matrix.nodes):
+        cands = sorted(
+            (a for a in matrix._proposed_allocs(node.id)
+             if not a.terminal_status() and a.job_id != matrix.job.id
+             and victim_priority(a) < max_priority),
+            key=victim_sort_key)[:V]
+        if cands:
+            out[i] = cands
+    return out
+
+
+def _assert_victims_equal_walk(matrix, max_priority):
+    from nomad_tpu.models.matrix import _alloc_usage
+
+    (res, bw, ports, prio, ok), victims_of, total = matrix.build_victims(
+        max_priority)
+    want = _victims_by_walk(matrix, max_priority)
+    assert total == sum(len(c) for c in want.values())
+    for i in range(matrix.n):
+        cands = want.get(i, [])
+        assert int(ok[i].sum()) == len(cands), i
+        # the eligible candidates are a prefix of the row, in order
+        if i < matrix.n_real:    # rows beyond are padding
+            assert [a.id for a in victims_of(i)[:len(cands)]] \
+                == [a.id for a in cands], i
+        for v, alloc in enumerate(cands):
+            usage = _alloc_usage(alloc)
+            assert ok[i, v] and prio[i, v] == victim_priority(alloc)
+            assert tuple(res[i, v]) == usage[:4]
+            assert bw[i, v] == usage[4] and ports[i, v] == usage[5]
+        assert not ok[i, len(cands):].any()
+
+
+@pytest.mark.parametrize("seed", range(2810, 2816))
+def test_victim_table_equals_the_per_node_walk(seed):
+    from nomad_tpu.models.matrix import ClusterMatrix
+
+    state, _nodes, jobs, _index = _banded_store(seed)
+    snap = state.snapshot()
+    for job, priority in ((jobs[2], 70), (jobs[1], 30), (jobs[0], 100)):
+        matrix = ClusterMatrix(snap, job, None, plan_overlay=True)
+        _assert_victims_equal_walk(matrix, priority)
+
+
+@pytest.mark.parametrize("seed", range(2816, 2822))
+def test_victim_table_follows_the_delta_chain(seed):
+    """A newer snapshot's base is a delta of the older one's; its table
+    is the older table with the touched rows derived again, and reads
+    as a walk over the newer snapshot would."""
+    from nomad_tpu.models.matrix import ClusterMatrix
+
+    state, nodes, jobs, index = _banded_store(seed)
+    rng = random.Random(seed)
+    first = ClusterMatrix(state.snapshot(), jobs[3], None, plan_overlay=True)
+    _assert_victims_equal_walk(first, 70)      # builds the table
+    table = first._base._victims
+    assert table is not None
+    listed = [[a.id for a in lst or []] for lst in table.lists]
+    ok_then = table.ok.copy()
+    # evict two allocations and add one: what a preempting plan commits
+    live = [a for a in state.allocs() if not a.terminal_status()]
+    gone = rng.sample(live, 2)
+    changed = []
+    for a in gone:
+        c = a.copy()
+        c.desired_status = consts.ALLOC_DESIRED_EVICT
+        changed.append(c)
+    new = live[0].copy()
+    new.id = mock.alloc().id
+    new.node_id = nodes[-1].id
+    new.job, new.job_id = jobs[0], jobs[0].id
+    changed.append(new)
+    state.upsert_allocs(index + 1, changed)
+    second = ClusterMatrix(state.snapshot(), jobs[3], None, plan_overlay=True)
+    assert second.build_kind == "delta"
+    assert second._base._victims is not None
+    assert second._base._victims is not table   # carried, not shared
+    _assert_victims_equal_walk(second, 70)
+    # the older base's table still reads as its own snapshot did
+    assert [[a.id for a in lst or []] for lst in table.lists] == listed
+    assert np.array_equal(table.ok, ok_then)
+
+
+@pytest.mark.parametrize("seed", range(2822, 2830))
+def test_plan_overlay_equals_a_fresh_matrix(seed):
+    """The node state of the preemption pass: the cached base with the
+    rows the plan touches derived again equals a base built from
+    scratch through the plan, array for array, and so do the victims."""
+    from nomad_tpu.models.matrix import ClusterMatrix
+    from nomad_tpu.structs import Plan
+
+    state, nodes, jobs, _index = _banded_store(seed)
+    rng = random.Random(seed)
+    snap = state.snapshot()
+    job = jobs[3]
+    live = [a for a in snap.allocs() if not a.terminal_status()]
+    plan = Plan(eval_id="e", priority=70, job=job)
+    for a in rng.sample(live, 3):
+        plan.append_update(a, consts.ALLOC_DESIRED_STOP, "test")
+    for a in rng.sample(live, 2):
+        plan.append_preemption(a, consts.ALLOC_DESIRED_EVICT, "test")
+    for k in range(3):
+        placed = live[0].copy()
+        placed.id = mock.alloc().id
+        placed.node_id = rng.choice(nodes).id
+        placed.job, placed.job_id = job, job.id
+        placed.task_group = job.task_groups[0].name
+        plan.append_alloc(placed)
+    fresh = ClusterMatrix(snap, job, plan)
+    over = ClusterMatrix(snap, job, plan, plan_overlay=True)
+    assert over.build_kind in ("hit", "delta", "full", "rekey")
+    assert over.base_token is None and over.compact_overlay is None
+    for name in ("capacity", "sched_capacity", "util", "bw_avail", "bw_used",
+                 "ports_free", "node_ok", "job_count", "tg_count", "feasible"):
+        np.testing.assert_array_equal(
+            getattr(over, name), getattr(fresh, name), err_msg=name)
+    _assert_victims_equal_walk(over, 70)
+    # the cached base itself was not written to
+    again = ClusterMatrix(snap, job, None)
+    assert again.base_token is not None
+    clean = ClusterMatrix(snap, jobs[0], None, plan_overlay=True)
+    np.testing.assert_array_equal(again.util, clean.util)
+
+
+def test_churn_stats_carry_passes_and_failure_counters():
+    from nomad_tpu.migrate import churn_stats, note_preemption_failure
+
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
+    before = churn_stats()["preemption"]
+    assert {"passes", "evictions_staged", "evictions_committed",
+            "placements", "preempt_dispatch_failed",
+            "preempt_breaker_rejected"} <= set(before)
+    h, low, high = _storm_harness(seed=36)
+    h.process("service-tpu", new_eval(h.state.job_by_id(high.id),
+                                      consts.EVAL_TRIGGER_JOB_REGISTER))
+    note_preemption_failure(dispatch_failed=2, breaker_rejected=3)
+    after = churn_stats()["preemption"]
+    assert after["passes"] == before["passes"] + 1
+    assert after["evictions_staged"] == before["evictions_staged"] + 4
+    assert after["preempt_dispatch_failed"] \
+        == before["preempt_dispatch_failed"] + 2
+    assert after["preempt_breaker_rejected"] \
+        == before["preempt_breaker_rejected"] + 3
+
+
+def test_preempt_spans_nest_and_the_account_closes():
+    """preempt.victims and preempt.solve lie inside preempt.select, and
+    what they leave is the pass's self time."""
+    from nomad_tpu import trace
+    from nomad_tpu.trace import (
+        ALL_STAGES,
+        STAGE_PREEMPT_SELECT,
+        STAGE_PREEMPT_SOLVE,
+        STAGE_PREEMPT_VICTIMS,
+    )
+
+    assert {STAGE_PREEMPT_VICTIMS, STAGE_PREEMPT_SOLVE} <= set(ALL_STAGES)
+    configure(preemption_enabled=True, preempt_priority_threshold=50)
+    recorder = trace.get_recorder()
+    before = {s: (recorder.stage_buckets(s) or (0, None))[0]
+              for s in (STAGE_PREEMPT_SELECT, STAGE_PREEMPT_VICTIMS,
+                        STAGE_PREEMPT_SOLVE)}
+    h, low, high = _storm_harness(seed=37)
+    ev = new_eval(h.state.job_by_id(high.id),
+                  consts.EVAL_TRIGGER_JOB_REGISTER)
+    trace.mark(ev.id, ev.trace_id)
+    h.process("service-tpu", ev)
+    for stage, count in before.items():
+        assert recorder.stage_buckets(stage)[0] == count + 1, stage
