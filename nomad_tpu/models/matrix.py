@@ -312,10 +312,13 @@ class _ClusterBase:
         NODES table advanced too, rows whose node object changed
         (up/down/drain flips) are refilled with node_ok re-derived, so
         a node transition is a delta record like a plan commit instead
-        of a node-axis rebuild. Returns None when a full rebuild is the
+        of a node-axis rebuild. What changed is what the store's journal
+        of allocation writes says (`state.allocs_changed_since`), never
+        a walk over the table. Returns None when a full rebuild is the
         better deal (too many touched rows) or required for correctness
-        (allocs were DELETED — GC removals leave no modify_index trace,
-        so their usage would stay baked in; or a changed node's
+        (the journal does not reach back to this base; allocs were
+        DELETED — GC removals leave no trace in it, so their usage
+        would stay baked in; or a changed node's
         capacity/class moved, which the device-shared immutable arrays
         cannot express), or self unchanged-but-rekeyed when no relevant
         alloc moved (same token -> the device-cached upload is reused
@@ -365,9 +368,26 @@ class _ClusterBase:
                         != node_signature(node)):
                     return None
                 node_rows.append(i)
-        allocs = state.allocs()
-        created = sum(1 for a in allocs if a.create_index > base_allocs_index)
-        if len(allocs) != base_table_len + created:
+        # What changed comes from the store's journal of allocation
+        # writes (state/store.py allocs_changed_since): O(a commit's
+        # few), where walking the table is O(all allocations) under the
+        # GIL on the stage thread every eval of the batch waits for.
+        # A state without the journal, or one whose journal does not
+        # reach back to this base (a restored store), gets a full build.
+        from .resident import get_tracker
+
+        changed_since = getattr(state, "allocs_changed_since", None)
+        changed = (changed_since(base_allocs_index)
+                   if changed_since is not None else None)
+        get_tracker().count_journal(changed)
+        if changed is None:
+            return None
+        table_len = state.alloc_count()
+        # An alloc created after the base was written after it, so the
+        # created are all among the changed.
+        created = sum(1 for a in changed
+                      if a.create_index > base_allocs_index)
+        if table_len != base_table_len + created:
             return None  # deletions happened; they are untraceable
         # Split the changes: an alloc CREATED after our watermark was
         # never in this base, so its usage can be scatter-ADDED to its
@@ -380,9 +400,7 @@ class _ClusterBase:
         # the storm quadratic in total allocs (VERDICT r4 ask #8).
         refill_nids = set()
         adds = []
-        for a in allocs:
-            if a.modify_index <= base_allocs_index:
-                continue
+        for a in changed:
             if a.create_index > base_allocs_index:
                 if not a.terminal_status():
                     adds.append(a)
@@ -390,7 +408,7 @@ class _ClusterBase:
                 # consumes nothing now — nothing to do.
             else:
                 refill_nids.add(a.node_id)
-        row_of = {node.id: i for i, node in enumerate(nodes)}
+        row_of = self.row_of(nodes)
         adds = [a for a in adds
                 if a.node_id not in refill_nids and a.node_id in row_of]
         node_row_set = set(node_rows)
@@ -411,12 +429,10 @@ class _ClusterBase:
             with _BASE_CACHE_LOCK:
                 if new_allocs_index > self.allocs_index:
                     self.allocs_index = new_allocs_index
-                    self.table_len = len(allocs)
+                    self.table_len = table_len
                 if 0 <= self.nodes_index < new_nodes_index:
                     self.nodes_index = new_nodes_index
             return self
-        from .resident import get_tracker
-
         if len(refill_rows) > get_tracker().max_refill_rows(self.n_real):
             return None  # full rebuild is cheaper (refills only: the
             #              additive rows cost O(1) per new alloc)
@@ -438,7 +454,7 @@ class _ClusterBase:
         new = _ClusterBase.__new__(_ClusterBase)
         new.token = next(_BASE_TOKENS)
         new.allocs_index = new_allocs_index
-        new.table_len = len(allocs)
+        new.table_len = table_len
         new.nodes_index = max(base_nodes_index, new_nodes_index)
         new.delta_parent = (self.token, tuple(rows))
         new.n_real, new.n = self.n_real, self.n

@@ -67,6 +67,14 @@ class ResidentStateTracker:
         # toward the rebuild threshold.
         self.alloc_delta_rows = 0  # guarded-by: _lock
         self.node_delta_rows = 0  # guarded-by: _lock
+        # How the delta learnt what changed (state/store.py's journal
+        # of allocation writes): deltas it served, deltas it could not
+        # (trimmed past the base, a restored store, a state without
+        # one: a full build follows), and the changed allocations it
+        # handed over. allocs / deltas is the allocations a commit.
+        self.journal_deltas = 0  # guarded-by: _lock
+        self.journal_misses = 0  # guarded-by: _lock
+        self.journal_allocs = 0  # guarded-by: _lock
         self.stale_rebuilds = 0  # guarded-by: _lock (post-rejection)
         self.universe_rebuilds = 0  # guarded-by: _lock (node set changed)
         # Plan-apply rejection marked the resident chain suspect; the
@@ -130,6 +138,16 @@ class ResidentStateTracker:
                 self.node_delta_updates += 1
                 self.node_delta_rows += node_rows
 
+    def count_journal(self, changed: Optional[list]) -> None:
+        """One delta asked the store's journal: `changed` is its answer
+        (None = it could not serve)."""
+        with self._lock:
+            if changed is None:
+                self.journal_misses += 1
+            else:
+                self.journal_deltas += 1
+                self.journal_allocs += len(changed)
+
     def stats(self) -> Dict[str, object]:
         with self._lock:
             return {
@@ -139,6 +157,9 @@ class ResidentStateTracker:
                 "node_delta_updates": self.node_delta_updates,
                 "alloc_delta_rows": self.alloc_delta_rows,
                 "node_delta_rows": self.node_delta_rows,
+                "journal_deltas": self.journal_deltas,
+                "journal_misses": self.journal_misses,
+                "journal_allocs": self.journal_allocs,
                 "stale_rebuilds": self.stale_rebuilds,
                 "universe_rebuilds": self.universe_rebuilds,
             }

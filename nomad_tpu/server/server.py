@@ -320,6 +320,9 @@ class Server:
                     metrics.set_gauge(
                         ("device_state", "upload_bytes"),
                         ds["upload_bytes"])
+                    for key in ("journal_deltas", "journal_misses",
+                                "journal_allocs"):
+                        metrics.set_gauge(("device_state", key), ds[key])
                     # Placement-quality gauges (kernels/quality.py):
                     # the active kernel's committed-plan medians plus
                     # the queueing p99, scrapeable at /v1/metrics so a

@@ -15,10 +15,11 @@ reference's lock-free MVCC property, SURVEY.md section 2.3).
 
 from __future__ import annotations
 
+import bisect
 import copy as _copy
 import threading
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Set
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from ..structs import (
     Allocation,
@@ -116,6 +117,51 @@ class _Index:
         return self.data
 
 
+# Allocation writes the journal keeps before it drops its older half.
+# The cluster base's delta asks only for what was written since the
+# newest base, a commit or a few; the cap is what the list may cost
+# (two words an entry), not a tuning knob.
+_ALLOC_JOURNAL_CAP = 1 << 18
+
+
+class _AllocJournal:
+    """Append-only record of allocation writes, `(index, alloc id)` in
+    index order, so that a reader of a snapshot finds what changed
+    since an earlier index without walking the table
+    (StateSnapshot.allocs_changed_since; models/matrix.py delta_update).
+    Derived state: no part of the raft snapshot, nothing replicated.
+
+    Snapshots take `view()`, the lists by reference with their length
+    at that moment, so they never see a later entry; a trim REPLACES
+    the lists rather than mutating what a snapshot holds. `floor` is
+    the highest index below which entries may be missing."""
+
+    __slots__ = ("indexes", "ids", "floor")
+
+    def __init__(self, floor: int = 0):
+        self.indexes: List[int] = []
+        self.ids: List[str] = []
+        self.floor = floor
+
+    def record(self, index: int, ids: List[str]) -> None:
+        if self.indexes and index < self.indexes[-1]:
+            # Out of index order (raft never does this): the bisect
+            # below would lie, so forget what came before.
+            self.floor = self.indexes[-1]
+            self.indexes, self.ids = [], []
+        self.indexes.extend([index] * len(ids))
+        self.ids.extend(ids)
+        if len(self.ids) > _ALLOC_JOURNAL_CAP:
+            # Drop the older half: O(cap) once in cap/2 appends.
+            cut = len(self.ids) // 2
+            self.floor = self.indexes[cut - 1]
+            self.indexes = self.indexes[cut:]
+            self.ids = self.ids[cut:]
+
+    def view(self) -> Tuple[List[int], List[str], int, int]:
+        return self.indexes, self.ids, len(self.ids), self.floor
+
+
 TABLES = (
     "nodes",
     "jobs",
@@ -132,11 +178,12 @@ class StateSnapshot:
     (scheduler.State, reference scheduler/scheduler.go:55)."""
 
     def __init__(self, tables, indexes, table_indexes, latest,
-                 store_id: str = ""):
+                 alloc_journal, store_id: str = ""):
         self._t = tables
         self._i = indexes
         self._table_indexes = table_indexes
         self._latest = latest
+        self._alloc_journal = alloc_journal  # _AllocJournal.view()
         # Identity of the owning store: table indexes alone are not
         # unique across stores in one process (tests, multi-server),
         # so caches keyed on indexes must include this.
@@ -203,6 +250,21 @@ class StateSnapshot:
         point)."""
         return len(self._t["allocs"])
 
+    def allocs_changed_since(self, index: int) -> Optional[List[Allocation]]:
+        """The distinct allocations, as this snapshot holds them, whose
+        modify_index is above `index`, in the order they were first
+        written since; None where the journal does not reach back to
+        `index` (trimmed, or a restored store). One that was written
+        and then collected is skipped. A bisect plus O(entries since
+        `index`), where allocs() is O(table)."""
+        indexes, ids, length, floor = self._alloc_journal
+        if index < floor:
+            return None
+        table = self._t["allocs"]
+        start = bisect.bisect_right(indexes, index, 0, length)
+        changed = (table.get(i) for i in dict.fromkeys(ids[start:length]))
+        return [a for a in changed if a is not None]
+
     def allocs_by_job(self, job_id: str) -> List[Allocation]:
         ids = self._i["allocs_by_job"].get(job_id, ())
         return [self._t["allocs"][i] for i in ids]
@@ -256,6 +318,7 @@ class StateStore:
         # instead of missed ones.
         self._scope_indexes: Dict[watch.Item, int] = {}
         self._scope_floor = 0
+        self._alloc_journal = _AllocJournal()
         self.notify = watch.NotifyGroup()
         from ..utils.ids import generate_uuid
 
@@ -271,7 +334,8 @@ class StateStore:
             indexes = {name: i.share() for name, i in self._indexes.items()}
             return StateSnapshot(
                 tables, indexes, dict(self._table_indexes),
-                self._latest_index, store_id=self.store_id,
+                self._latest_index, self._alloc_journal.view(),
+                store_id=self.store_id,
             )
 
     def latest_index(self) -> int:
@@ -329,6 +393,7 @@ class StateStore:
             "alloc_by_id",
             "allocs",
             "alloc_count",
+            "allocs_changed_since",
             "allocs_by_job",
             "allocs_by_node",
             "allocs_by_node_terminal",
@@ -619,6 +684,7 @@ class StateStore:
                         watch.job_summary(alloc.job_id),
                     ]
                 )
+            self._alloc_journal.record(index, [a.id for a in allocs])
             # Derived job status recomputes once per touched job, not
             # once per alloc (a system job upserts one alloc per node).
             for job_id in {a.job_id for a in allocs}:
@@ -636,10 +702,12 @@ class StateStore:
         items = [watch.table("allocs")]
         with self._lock:
             table = self._tables["allocs"].for_write()
+            written: List[str] = []
             for update in allocs:
                 existing = table.get(update.id)
                 if existing is None:
                     continue
+                written.append(update.id)
                 alloc = existing.copy()
                 alloc.client_status = update.client_status
                 alloc.client_description = update.client_description
@@ -663,6 +731,7 @@ class StateStore:
                         watch.job_summary(alloc.job_id),
                     ]
                 )
+            self._alloc_journal.record(index, written)
             self._bump(index, "allocs", "job_summary")
             self._stamp(index, items)
         self.notify.notify(items)
@@ -834,6 +903,10 @@ class StateStore:
                 store._tables["vault_accessors"].data[v.accessor] = v
             store._table_indexes = dict(data.get("table_indexes", {}))
             store._latest_index = data.get("latest_index", 0)
+            # The journal is derived state and was not persisted: what
+            # was written up to the restored allocs index is unknown.
+            store._alloc_journal = _AllocJournal(
+                floor=store._table_indexes.get("allocs", 0))
             scopes = data.get("scope_indexes")
             if scopes is None:
                 # Snapshot predates scope persistence: every scope's

@@ -331,3 +331,211 @@ def test_addition_on_refilled_node_not_double_counted(cluster):
     oracle = _ClusterBase(
         m2.nodes, lambda nid: snap.allocs_by_node_terminal(nid, False))
     assert_bases_equal(m2._cached_base(), oracle)
+
+
+# ---------------------------------------------------------------------
+# The delta reads the store's journal of allocation writes
+# (StateSnapshot.allocs_changed_since), never the whole table.
+# ---------------------------------------------------------------------
+
+
+class _NoWalk:
+    """A snapshot whose allocs() raises: the O(all allocations) walk
+    cannot come back to the base's path unnoticed. Everything else is
+    the snapshot's own."""
+
+    def __init__(self, snap):
+        self._snap = snap
+
+    def __getattr__(self, name):
+        return getattr(self._snap, name)
+
+    def allocs(self):
+        raise AssertionError("the cluster base walked every allocation")
+
+
+def assert_same_base(base, snap, nodes):
+    """`base` equals a fresh build of `snap`, array for array, with an
+    equal positions index and (where the chain carries one) an equal
+    victim table."""
+    from nomad_tpu.models.matrix import _VictimTable
+
+    oracle = _ClusterBase(
+        nodes, lambda nid: snap.allocs_by_node_terminal(nid, False))
+    for f in ("capacity", "sched_capacity", "util", "bw_avail",
+              "bw_used", "ports_free", "node_ok"):
+        np.testing.assert_array_equal(
+            getattr(base, f), getattr(oracle, f), err_msg=f)
+    assert [sorted(g) for g in base.alloc_groups] \
+        == [sorted(g) for g in oracle.alloc_groups]
+    jobs = {jid for g in oracle.alloc_groups for jid, _tg in g}
+    for jid in jobs | {"no-such-job"}:
+        got = {tg: sorted(rows.tolist())
+               for tg, rows in base.job_positions(jid).items()}
+        want = {tg: sorted(rows.tolist())
+                for tg, rows in oracle.job_positions(jid).items()}
+        assert got == want, jid
+    if base._victims is not None:
+        want = _VictimTable.build(base.n, nodes, snap)
+        for f in ("res", "bw", "ports", "prio", "ok"):
+            np.testing.assert_array_equal(
+                getattr(base._victims, f), getattr(want, f), err_msg=f)
+        assert [[a.id for a in lst or []] for lst in base._victims.lists] \
+            == [[a.id for a in lst or []] for lst in want.lists]
+
+
+@pytest.mark.parametrize("seed", range(2900, 2910))
+def test_journal_deltas_equal_a_fresh_build_over_random_histories(seed):
+    """Creations, in-place updates, terminal transitions, evictions,
+    client status updates, GC deletions and node ready/drain flips in a
+    seeded order: at every index the base reached through the journal's
+    deltas (or, after a deletion, the full build) is the fresh build of
+    the same snapshot, and no step walks the table."""
+    import random
+
+    from nomad_tpu.models import resident
+
+    rng = random.Random(seed)
+    store = StateStore()
+    jobs = []
+    for i, priority in enumerate((10, 30, 70)):
+        job = mock.job()
+        job.id = f"job-{i}"
+        job.priority = priority
+        job.task_groups[0].tasks[0].resources.networks = []
+        jobs.append(job)
+    nodes = []
+    index = 0
+    for _ in range(20):
+        node = mock.node()
+        node.compute_class()
+        nodes.append(node)
+        index += 1
+        store.upsert_node(index, node)
+    live = [make_alloc(nodes[i % 20], jobs[i % 3], cpu=40 + i)
+            for i in range(30)]
+    index += 1
+    store.upsert_allocs(index, live)
+    dead = []
+
+    tracker = resident.get_tracker()
+    first = ClusterMatrix(_NoWalk(store.snapshot()), jobs[2])
+    first._base.job_positions(jobs[0].id)   # the chain carries
+    first._base.victim_table(                # both along
+        first.nodes, store.snapshot())
+    before = tracker.stats()
+    kinds = []
+    ops = ("create", "update", "stop", "evict", "client", "flip") * 3 \
+        + ("gc",)
+    for step in range(40):
+        op = rng.choice(ops)
+        index += 1
+        if op == "create":
+            fresh = [make_alloc(rng.choice(nodes), rng.choice(jobs),
+                                cpu=10 + rng.randrange(40))
+                     for _ in range(rng.randrange(1, 5))]
+            live.extend(fresh)
+            store.upsert_allocs(index, fresh)
+        elif op == "update":
+            a = rng.choice(live)
+            for tr in a.task_resources.values():
+                tr.cpu = 10 + rng.randrange(60)
+            a.__dict__.pop("_dense_usage", None)
+            store.upsert_allocs(index, [a])
+        elif op in ("stop", "evict") and len(live) > 5:
+            a = live.pop(rng.randrange(len(live)))
+            a.desired_status = (consts.ALLOC_DESIRED_STOP if op == "stop"
+                                else consts.ALLOC_DESIRED_EVICT)
+            dead.append(a)
+            store.upsert_allocs(index, [a])
+        elif op == "client" and len(live) > 5:
+            a = live.pop(rng.randrange(len(live)))
+            a.client_status = consts.ALLOC_CLIENT_FAILED
+            dead.append(a)
+            store.update_allocs_from_client(index, [a])
+        elif op == "gc" and dead:
+            gone = [dead.pop() for _ in range(min(2, len(dead)))]
+            store.delete_evals(index, [], [a.id for a in gone])
+        else:
+            node = rng.choice(nodes)
+            if rng.random() < 0.5:
+                node.drain = not node.drain
+            else:
+                node.status = (consts.NODE_STATUS_DOWN
+                               if node.status == consts.NODE_STATUS_READY
+                               else consts.NODE_STATUS_READY)
+            store.upsert_node(index, node)
+            op = "flip"
+        snap = store.snapshot()
+        m = ClusterMatrix(_NoWalk(snap), rng.choice(jobs))
+        kinds.append((op, m.build_kind))
+        assert_same_base(m._base, snap, m.nodes)
+        if m.build_kind == "full":
+            # a full build starts a chain without the lazy tables
+            m._base.job_positions(jobs[0].id)
+            m._base.victim_table(m.nodes, snap)
+    # a deletion ends in a full build; the rest ride the journal's
+    # deltas, but for a flip of a node that stands for its class
+    assert {kind for op, kind in kinds if op == "gc"} <= {"full"}, kinds
+    assert {kind for op, kind in kinds if op != "flip" and op != "gc"} \
+        <= {"delta", "rekey"}, kinds
+    served = sum(kind != "full" for _op, kind in kinds)
+    assert served >= 25, kinds
+    after = tracker.stats()
+    assert after["journal_misses"] == before["journal_misses"]
+    assert after["journal_deltas"] - before["journal_deltas"] >= served
+
+
+def test_a_journal_that_does_not_reach_back_ends_in_a_full_build(
+        cluster, monkeypatch):
+    """Trimmed past the family's base, the store answers None and the
+    base is built in full (as after a restore): never a delta from a
+    partial answer."""
+    from nomad_tpu.models import resident
+    from nomad_tpu.state import store as store_mod
+
+    store, job, nodes, allocs, index = cluster
+    monkeypatch.setattr(store_mod, "_ALLOC_JOURNAL_CAP", 8)
+    ClusterMatrix(_NoWalk(store.snapshot()), job)
+    before = resident.get_tracker().stats()
+    for i in range(12):
+        index += 1
+        store.upsert_allocs(index, [make_alloc(nodes[i], job, cpu=11 + i)])
+    snap = store.snapshot()
+    assert snap.allocs_changed_since(index - 12) is None
+    m = ClusterMatrix(_NoWalk(snap), job)
+    assert m.build_kind == "full"
+    assert_same_base(m._base, snap, m.nodes)
+    after = resident.get_tracker().stats()
+    assert after["journal_misses"] == before["journal_misses"] + 1
+    assert after["full_rebuilds"] == before["full_rebuilds"] + 1
+    # the chain goes on from the full build
+    index += 1
+    store.upsert_allocs(index, [make_alloc(nodes[0], job, cpu=7)])
+    snap = store.snapshot()
+    m = ClusterMatrix(_NoWalk(snap), job)
+    assert m.build_kind == "delta"
+    assert_same_base(m._base, snap, m.nodes)
+
+
+def test_a_state_without_a_journal_gets_a_full_build(cluster):
+    """A state object that cannot say what changed (no
+    allocs_changed_since) is never walked: its base is built in full."""
+    store, job, nodes, allocs, index = cluster
+
+    class Bare:
+        def __init__(self, snap):
+            self._snap = snap
+            for name in ("store_id", "index", "nodes", "node_by_id",
+                         "alloc_count", "allocs_by_node",
+                         "allocs_by_node_terminal", "allocs_by_job",
+                         "job_by_id"):
+                setattr(self, name, getattr(snap, name))
+
+    ClusterMatrix(Bare(store.snapshot()), job)
+    index += 1
+    store.upsert_allocs(index, [make_alloc(nodes[1], job, cpu=9)])
+    snap = store.snapshot()
+    m = ClusterMatrix(Bare(snap), job)
+    assert m.build_kind == "full"
+    assert_same_base(m._base, snap, m.nodes)

@@ -265,9 +265,64 @@ def test_device_state_stats_surface():
     for key in ("enabled", "full_rebuilds", "delta_updates",
                 "node_delta_updates", "stale_rebuilds",
                 "universe_rebuilds", "jit_cache_size", "base_uploads",
-                "base_delta_updates", "upload_bytes"):
+                "base_delta_updates", "upload_bytes", "journal_deltas",
+                "journal_misses", "journal_allocs"):
         assert key in st, key
     assert st["enabled"] is True
+
+
+def test_journal_counters_say_how_the_delta_learnt_what_changed(
+        resident_on, monkeypatch):
+    """journal_deltas: deltas the store's journal of allocation writes
+    served; journal_allocs: the changed allocations it handed over (so
+    allocs / deltas is the allocations a commit); journal_misses: deltas
+    it could not serve, each followed by a full build."""
+    from nomad_tpu.state import store as store_mod
+
+    monkeypatch.setattr(store_mod, "_ALLOC_JOURNAL_CAP", 16)
+    store = StateStore()
+    job = mock.job()
+    job.task_groups[0].tasks[0].resources.networks = []
+    nodes = []
+    index = 0
+    for _ in range(8):
+        node = mock.node()
+        node.compute_class()
+        nodes.append(node)
+        index += 1
+        store.upsert_node(index, node)
+    index += 1
+    store.upsert_allocs(index, [make_alloc(nodes[0], job)])
+    ClusterMatrix(store.snapshot(), job)
+
+    def moved(then):
+        now = resident_on.stats()
+        return tuple(now[k] - then[k] for k in (
+            "journal_deltas", "journal_allocs", "journal_misses",
+            "full_rebuilds"))
+
+    then = resident_on.stats()
+    for commit in (3, 5):     # two commits, two deltas
+        index += 1
+        store.upsert_allocs(
+            index, [make_alloc(nodes[i], job) for i in range(commit)])
+        assert ClusterMatrix(store.snapshot(), job).build_kind == "delta"
+    assert moved(then) == (2, 8, 0, 0)
+    # the same snapshot again is a cache hit: nobody asks the journal
+    assert ClusterMatrix(store.snapshot(), job).build_kind == "hit"
+    assert moved(then) == (2, 8, 0, 0)
+    # two commits between two bases: one delta, both commits' allocs
+    for _ in range(2):
+        index += 1
+        store.upsert_allocs(index, [make_alloc(nodes[1], job)])
+    assert ClusterMatrix(store.snapshot(), job).build_kind == "delta"
+    assert moved(then) == (3, 10, 0, 0)
+    # more writes than the journal keeps: a miss, and a full build
+    for _ in range(20):
+        index += 1
+        store.upsert_allocs(index, [make_alloc(nodes[2], job)])
+    assert ClusterMatrix(store.snapshot(), job).build_kind == "full"
+    assert moved(then) == (3, 10, 1, 1)
 
 
 # --------------------------------------------------------- staleness

@@ -2,6 +2,8 @@
 
 import threading
 
+import pytest
+
 from nomad_tpu import mock
 from nomad_tpu.state import StateStore, watch
 from nomad_tpu.structs import consts
@@ -364,3 +366,170 @@ def test_persist_restore_every_table_via_json():
     assert summary.summary["web"].running == 1
     # client-side fields preserved
     assert s2.alloc_by_id(a.id).client_status == "running"
+
+
+# ---------------------------------------------------------------------
+# The journal of allocation writes (allocs_changed_since): what the
+# cluster base's delta reads instead of walking the table.
+# ---------------------------------------------------------------------
+
+
+def _journal_alloc(node_id="n1"):
+    a = mock.alloc()
+    a.node_id = node_id
+    return a
+
+
+def _walk(snap, index):
+    """The reference: the walk over the whole table that the journal
+    replaces."""
+    return {a.id for a in snap.allocs() if a.modify_index > index}
+
+
+def _ids(allocs):
+    return [a.id for a in allocs]
+
+
+def _journal_exact_set(s, _monkeypatch):
+    """Upserts, an in-place update, an eviction and a client status
+    update: at every earlier index the journal's answer is the walk's."""
+    a, b, c, d = (_journal_alloc() for _ in range(4))
+    s.upsert_allocs(10, [a, b])
+    s.upsert_allocs(11, [c, d])
+    s.upsert_allocs(12, [a.copy()])  # in place
+    evicted = b.copy()
+    evicted.desired_status = consts.ALLOC_DESIRED_EVICT
+    s.upsert_allocs(13, [evicted])
+    done = c.copy()
+    done.client_status = consts.ALLOC_CLIENT_COMPLETE
+    s.update_allocs_from_client(14, [done])
+    snap = s.snapshot()
+    for index in range(9, 16):
+        got = snap.allocs_changed_since(index)
+        assert len(got) == len(set(_ids(got)))
+        assert set(_ids(got)) == _walk(snap, index), index
+    assert set(_ids(snap.allocs_changed_since(11))) == {a.id, b.id, c.id}
+    # as THIS snapshot holds them, not as they were written
+    by_id = {x.id: x for x in snap.allocs_changed_since(9)}
+    assert by_id[b.id].desired_status == consts.ALLOC_DESIRED_EVICT
+    assert by_id[c.id].client_status == consts.ALLOC_CLIENT_COMPLETE
+    assert by_id[c.id].modify_index == 14
+    assert snap.allocs_changed_since(14) == []
+    # a client update of an id the table does not hold writes nothing
+    s.update_allocs_from_client(15, [_journal_alloc()])
+    assert s.snapshot().allocs_changed_since(14) == []
+
+
+def _journal_snapshot_isolation(s, _monkeypatch):
+    a, b = _journal_alloc(), _journal_alloc()
+    s.upsert_allocs(10, [a])
+    before = s.snapshot()
+    s.upsert_allocs(11, [b])
+    s.upsert_allocs(12, [a])
+    assert _ids(before.allocs_changed_since(0)) == [a.id]
+    assert before.allocs_changed_since(10) == []
+    assert before.allocs_changed_since(0)[0].modify_index == 10
+    assert _ids(s.snapshot().allocs_changed_since(10)) == [b.id, a.id]
+
+
+def _journal_trim(s, monkeypatch):
+    """Past the cap the older half goes: below the new floor the answer
+    is None, at and above it exact; a snapshot from before the trim
+    keeps the journal it took."""
+    from nomad_tpu.state import store as store_mod
+
+    monkeypatch.setattr(store_mod, "_ALLOC_JOURNAL_CAP", 8)
+    allocs = [_journal_alloc() for _ in range(12)]
+    for i, a in enumerate(allocs[:8]):
+        s.upsert_allocs(10 + i, [a])
+    early = s.snapshot()
+    for i, a in enumerate(allocs[8:], start=8):
+        s.upsert_allocs(10 + i, [a])
+    snap = s.snapshot()
+    _indexes, _ids_, length, floor = snap._alloc_journal
+    assert length <= 8 and floor > 10
+    assert snap.allocs_changed_since(floor - 1) is None
+    assert snap.allocs_changed_since(0) is None
+    for index in range(floor, 23):
+        assert set(_ids(snap.allocs_changed_since(index))) \
+            == _walk(snap, index), index
+    assert _ids(early.allocs_changed_since(0)) == _ids(allocs[:8])
+
+
+def _journal_restore(s, _monkeypatch):
+    a, b = _journal_alloc(), _journal_alloc()
+    s.upsert_allocs(10, [a])
+    s.upsert_allocs(11, [b])
+    r = StateStore.restore(s.persist())
+    assert "alloc_journal" not in s.persist()  # derived, not replicated
+    assert r.allocs_changed_since(10) is None
+    assert r.allocs_changed_since(0) is None
+    assert r.allocs_changed_since(11) == []
+    c = _journal_alloc()
+    r.upsert_allocs(12, [c, b])
+    assert _ids(r.allocs_changed_since(11)) == [c.id, b.id]
+    assert r.allocs_changed_since(10) is None
+
+
+def _journal_distinct_in_index_order(s, _monkeypatch):
+    a, b, c = (_journal_alloc() for _ in range(3))
+    s.upsert_allocs(10, [a, b])
+    s.upsert_allocs(11, [c])
+    s.upsert_allocs(12, [a])
+    s.upsert_allocs(13, [b, a, b])
+    got = s.snapshot().allocs_changed_since(9)
+    # each once, in the order of its first write since the index
+    assert _ids(got) == [a.id, b.id, c.id]
+    assert _ids(s.snapshot().allocs_changed_since(10)) == [c.id, a.id, b.id]
+    journal_indexes = s.snapshot()._alloc_journal[0]
+    assert journal_indexes == sorted(journal_indexes)
+    # the store answers through a fresh snapshot, as its other reads do
+    assert _ids(s.allocs_changed_since(12)) == [b.id, a.id]
+
+
+def _journal_collected_is_skipped(s, _monkeypatch):
+    a, b = _journal_alloc(), _journal_alloc()
+    s.upsert_allocs(10, [a, b])
+    s.delete_evals(11, [], [a.id])
+    assert _ids(s.snapshot().allocs_changed_since(0)) == [b.id]
+    assert s.snapshot().alloc_count() == 1
+
+
+def _journal_out_of_order_write(s, _monkeypatch):
+    """Raft never writes out of index order; a test that does must get
+    None (a full build), not a bisect over an unsorted list."""
+    a, b = _journal_alloc(), _journal_alloc()
+    s.upsert_allocs(20, [a])
+    s.upsert_allocs(15, [b])
+    assert s.snapshot().allocs_changed_since(10) is None
+    assert _ids(s.snapshot().allocs_changed_since(20)) == []
+    s.upsert_allocs(21, [a])
+    assert _ids(s.snapshot().allocs_changed_since(20)) == [a.id]
+
+
+@pytest.mark.parametrize("case", [
+    _journal_exact_set, _journal_snapshot_isolation, _journal_trim,
+    _journal_restore, _journal_distinct_in_index_order,
+    _journal_collected_is_skipped, _journal_out_of_order_write,
+], ids=lambda f: f.__name__.removeprefix("_journal_"))
+def test_alloc_journal_contract(case, monkeypatch):
+    case(StateStore(), monkeypatch)
+
+
+def test_alloc_journal_appends_are_amortised():
+    """A bulk load (set-up writes 177k fillers through upsert_allocs)
+    must not feel the bound: trims are one copy of half the cap per
+    half-cap appends, so the journal never holds more than the cap and
+    the copies made are O(appends)."""
+    from nomad_tpu.state.store import _ALLOC_JOURNAL_CAP, _AllocJournal
+
+    j = _AllocJournal()
+    replaced = 0
+    batch = [str(i) for i in range(1000)]
+    for index in range(1, 3 * _ALLOC_JOURNAL_CAP // 1000 + 2):
+        held = j.ids
+        j.record(index, batch)
+        replaced += j.ids is not held
+        assert len(j.ids) == len(j.indexes) <= _ALLOC_JOURNAL_CAP
+    assert 4 <= replaced <= 6
+    assert j.floor == j.indexes[0] - 1 or j.floor == j.indexes[0]
