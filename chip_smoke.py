@@ -120,8 +120,9 @@ def seeded_uuid(rng: random.Random) -> str:
 
 
 def load_fleet(server, rng: random.Random, size: dict) -> None:
-    """bench.py config 8's fleet, through the raft log: identical
-    mock nodes, each carrying running filler allocations."""
+    """The north-star fleet (BASELINE.json configs[3]), through the
+    raft log: identical mock nodes, each carrying running filler
+    allocations."""
     from nomad_tpu import mock
     from nomad_tpu.structs import consts
 
@@ -155,7 +156,7 @@ def load_fleet(server, rng: random.Random, size: dict) -> None:
 
 def make_job(job_id: str, count: int):
     """One task group, `count` allocations, two dynamic ports each,
-    distinct_hosts (bench.py config 8's job)."""
+    distinct_hosts (the north-star job, BASELINE.json configs[3])."""
     from nomad_tpu import mock
     from nomad_tpu.structs import Constraint, consts
 
@@ -228,9 +229,6 @@ def read_counters(client) -> dict:
             stats["dispatch_pipeline"], "enabled", "batches",
             "largest_batch", "routed_host", "breaker_routed",
             "prefetch_failures", "plan_conflicts", "nacked"),
-        "executive": pick(
-            stats["scheduler_executive"], "enabled", "routed_host",
-            "host_fallbacks"),
         "broker": pick(
             stats["broker"], "dead_lettered", "shed", "expired", "nacked",
             "nack_timeouts"),
@@ -245,12 +243,10 @@ def read_counters(client) -> dict:
 
 def check_device_did_the_work(smoke: Smoke, c: dict, where: str) -> None:
     chk = smoke.check
-    sched, pipe, exe = c["scheduler"], c["pipeline"], c["executive"]
+    sched, pipe = c["scheduler"], c["pipeline"]
     for name in ("host_fallback", "gang_host_fallback",
                  "breaker_rejected", "gang_breaker_rejected"):
         chk(sched[name] == 0, f"{where}: scheduler.{name} = {sched[name]}")
-    chk(exe["host_fallbacks"] == 0,
-        f"{where}: executive host_fallbacks = {exe['host_fallbacks']}")
     chk(pipe["breaker_routed"] == 0,
         f"{where}: pipeline breaker_routed = {pipe['breaker_routed']}")
     chk(pipe["prefetch_failures"] == 0,
@@ -310,8 +306,7 @@ def run_wave(smoke: Smoke, addr: str, client, number: int, size: dict,
         "compact_dispatches": delta("batcher", "compact_dispatches"),
         "base_uploads": delta("batcher", "base_uploads"),
         "base_delta_updates": delta("batcher", "base_delta_updates"),
-        "routed_host": delta("pipeline", "routed_host")
-        + delta("executive", "routed_host"),
+        "routed_host": delta("pipeline", "routed_host"),
         "small_route_host": delta("scheduler", "small_route_host"),
         "plan_conflicts": delta("pipeline", "plan_conflicts"),
         "nacked": delta("broker", "nacked"),

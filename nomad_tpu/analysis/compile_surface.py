@@ -1,10 +1,10 @@
-"""Compile-surface prover: the static side of bench.py's
-``jit_recompiles == 0`` gate.
+"""Compile-surface prover: the static side of the benchmark's
+``window_compiles == 0``.
 
 Every headline number since PR 6 assumes the jit cache is BOUNDED: a
 fixed set of entry points, each compiled once per (shape bucket,
 static key). That invariant was only enforced at runtime — a recompile
-bug shipped silently until someone ran the right bench arm. These four
+bug shipped silently until someone ran the right workload. These four
 whole-program rules prove the bound statically, riding the
 `core.Program` call graph (the same graph every manifest rule uses):
 
@@ -36,7 +36,7 @@ whole-program rules prove the bound statically, riding the
   ``ops//kernels//models//parallel/`` must appear in the
   ``NTA_JIT_ACCOUNTED`` manifest (ops/binpack.py), which mirrors the
   runtime ``jit_cache_size()`` accounting — an unaccounted entry
-  point blinds the bench recompile gate exactly the way the PR 7
+  point blinds the recompile count exactly the way the PR 7
   SARIF rule-list omission blinded CI. Inert when no analyzed module
   declares the manifest (fixture subsets). The manifest<->runtime
   agreement is itself tested (tests/test_compile_surface.py).
@@ -311,7 +311,7 @@ def _check_unregistered(program: Program,
                 RULE_UNREGISTERED, mod.rel, ep.line, 0,
                 f"{what} '{ep.name}' is absent from the "
                 f"{JIT_MANIFEST} manifest — jit_cache_size() cannot "
-                f"account it and the bench recompile gate is blind to "
+                f"account it and the recompile count is blind to "
                 f"it; register it (and its runtime accounting) in "
                 f"ops/binpack.py", ep.name,
                 related=list(manifest_sites)))

@@ -899,7 +899,7 @@ def analyze_paths(paths: List[str],
         findings.extend(cached)
 
     # ---- program pass (tree-digest cache). Skipped outright when the
-    # rules filter excludes every program rule (bench's purity gate):
+    # rules filter excludes every program rule:
     # building the cross-module graph to discard its findings is the
     # most expensive no-op in the suite.
     program_rules = {"dispatcher-blocking-call", "record-path-blocking",

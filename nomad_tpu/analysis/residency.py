@@ -3,8 +3,7 @@ back.
 
 The device-resident design (models/resident.py) exists because every
 dense batch used to re-ship the whole ``[N, R]`` node matrix to the
-device before placing — BENCH_r08 measured that round-trip at 84% of
-the e2e p99. The fix keeps the matrix resident and scatters small row
+device before placing. The fix keeps the matrix resident and scatters small row
 deltas; the regression mode is silent: a ``jax.device_put`` (or a
 ``device_resident()`` upload) creeping into a steady-state dispatch or
 scheduler path still *works*, it just ships 10-100x the bytes per
@@ -107,5 +106,5 @@ def check(mod: Module) -> List[Finding]:
             f"({REBUILD_MANIFEST}) — steady-state dispatch/scheduler "
             f"paths must ride the delta/cached resident-base paths; a "
             f"full re-ship here regresses silently (10-100x bytes/"
-            f"batch, BENCH_r08's 524ms tail)", qual))
+            f"batch)", qual))
     return findings

@@ -579,9 +579,9 @@ class HTTPServer:
     def _blocking_threadpark(self, items, min_index: int, deadline: float,
                              run, headers, scoped: bool) -> "JSONResponse":
         """The pre-mux blocking loop: park THIS handler thread on the
-        watch until satisfied or expired. Baseline arm for the bench
-        A/B (`read_mux_enabled=false` / `read_scoped_index=false`) and
-        the overflow path when the mux is full."""
+        watch until satisfied or expired. What runs with
+        `read_mux_enabled=false` / `read_scoped_index=false`, and the
+        overflow path when the mux is full."""
         state = self.server.fsm.state
 
         def cur_index() -> int:
@@ -1175,9 +1175,8 @@ class HTTPServer:
         if url.startswith("https://") and self.forward_ssl_context is None:
             # Without a local tls block, urlopen would fall back to
             # system-CA verification, fail against the cluster CA, and
-            # surface as an opaque generic forward error — the exact
-            # rolling-TLS-rollout trap ADVICE r5 flagged. Name the
-            # misconfiguration instead.
+            # surface as an opaque generic forward error during a
+            # rolling TLS rollout. Name the misconfiguration instead.
             raise HTTPError(
                 502,
                 f"region {region!r} peer {peer!r} requires TLS but "

@@ -324,9 +324,8 @@ class ChaosRegistry:
             return sorted(self._log)
 
     def unfired(self) -> List[FaultSpec]:
-        """Scheduled specs that never fired — the bench --chaos typo
-        guard refuses to report numbers while this is non-empty (a
-        schedule that never exercised its path measured nothing)."""
+        """Scheduled specs that never fired — the soaks' typo guard:
+        a schedule that never exercised its path measured nothing."""
         with self._lock:
             return [s for specs in self._specs.values()
                     for s in specs if s.fired == 0]

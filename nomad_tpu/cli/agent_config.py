@@ -32,24 +32,16 @@ class ServerBlock:
     # Per-type factory overrides, e.g. { "service" = "service-tpu" } —
     # finer-grained than the all-or-nothing -tpu flag.
     scheduler_factories: Dict[str, str] = field(default_factory=dict)
-    # Drain-to-batch tuning (server/config.py): max evals drained per
-    # broker visit for dense factories, and the group size below which
+    # Batch tuning (server/config.py): max dense-factory evals the
+    # dispatch pipeline packs into one batch, and the size below which
     # latency-aware routing sends evals to the host pipeline.
     eval_batch_size: Optional[int] = None
     dense_min_batch: Optional[int] = None
     # Central dispatch pipeline knobs (server/config.py dispatch_*):
-    # enable/disable, batches in flight, and the device-side in-batch
-    # conflict pre-resolution toggle.
-    dispatch_pipeline: Optional[bool] = None
+    # batches in flight, and the device-side in-batch conflict
+    # pre-resolution toggle.
     dispatch_max_inflight: Optional[int] = None
     dense_pre_resolve: Optional[bool] = None
-    # Scheduler executive (server/executive.py): the batched
-    # event-loop dense scheduler. When on, `executive_threads` (not
-    # num_schedulers) is the dense path's parallelism knob —
-    # num_schedulers then only sizes the host/system worker pool (see
-    # README "Scheduler executive" migration note).
-    scheduler_executive: Optional[bool] = None
-    executive_threads: Optional[int] = None
     # Device-resident node state (models/resident.py): enable knob +
     # the delta-vs-rebuild row threshold (0 = auto).
     device_resident: Optional[bool] = None
@@ -240,9 +232,7 @@ _SCHEMA: Dict[str, Any] = {
     "server.node_gc_threshold": str, "server.heartbeat_grace": str,
     "server.retry_join": _str_list, "server.start_join": _str_list,
     "server.eval_batch_size": int, "server.dense_min_batch": int,
-    "server.dispatch_pipeline": bool, "server.dispatch_max_inflight": int,
-    "server.dense_pre_resolve": bool,
-    "server.scheduler_executive": bool, "server.executive_threads": int,
+    "server.dispatch_max_inflight": int, "server.dense_pre_resolve": bool,
     "server.device_resident": bool, "server.resident_rebuild_rows": int,
     "server.placement_kernel": str,
     "server.migrate_max_parallel": int,

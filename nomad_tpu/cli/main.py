@@ -660,21 +660,11 @@ def cmd_agent(args) -> int:
             server_cfg.eval_batch_size = cfg.server.eval_batch_size
         if cfg.server.dense_min_batch is not None:
             server_cfg.dense_min_batch = cfg.server.dense_min_batch
-        if cfg.server.dispatch_pipeline is not None:
-            server_cfg.dispatch_pipeline = cfg.server.dispatch_pipeline
         if cfg.server.dispatch_max_inflight is not None:
             server_cfg.dispatch_max_inflight = (
                 cfg.server.dispatch_max_inflight)
         if cfg.server.dense_pre_resolve is not None:
             server_cfg.dense_pre_resolve = cfg.server.dense_pre_resolve
-        # Scheduler executive (server/executive.py): batched cohort
-        # scheduling instead of thread-per-eval workers. See the README
-        # migration note — num_schedulers keeps sizing the host/system
-        # worker pool; executive_threads is the dense knob here.
-        if cfg.server.scheduler_executive is not None:
-            server_cfg.scheduler_executive = cfg.server.scheduler_executive
-        if cfg.server.executive_threads is not None:
-            server_cfg.executive_threads = cfg.server.executive_threads
         # Device-resident node state (models/resident.py).
         if cfg.server.device_resident is not None:
             server_cfg.device_resident = cfg.server.device_resident

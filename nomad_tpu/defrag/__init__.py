@@ -205,8 +205,8 @@ class DefragLoop:
     def tick(self, now: Optional[float] = None) -> None:
         """One scheduling decision: watch the in-flight wave, then run
         a round if the interval elapsed on a green, led cluster.
-        Public (and monotonic-clock injectable) so tests and the bench
-        rig can drive the loop synchronously."""
+        Public (and monotonic-clock injectable) so tests can drive
+        the loop synchronously."""
         now = time.monotonic() if now is None else now
         with self._lock:
             enabled = self.enabled
@@ -256,7 +256,7 @@ class DefragLoop:
     def run_round(self) -> Optional[DefragPlan]:
         """One solve->diff->wave round against the current snapshot.
         Returns the solver plan (None only if the server has no state
-        yet). Public for the bench rig and tests."""
+        yet). Public for tests."""
         from .. import trace
         from ..chaos import chaos
         from ..models.matrix import base_epoch
@@ -422,7 +422,7 @@ class DefragLoop:
     # ----------------------------------------------------------- stats
 
     def reset_stats(self) -> None:
-        """Re-baseline counters (bench windows) without touching the
+        """Re-baseline counters (a measured window) without touching the
         in-flight wave or the warm carry."""
         with self._lock:
             self.rounds = self.waves = self.waves_lost = 0

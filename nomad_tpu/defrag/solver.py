@@ -1,7 +1,7 @@
 """The defrag loop's relaxed global re-placement solve.
 
-BENCH_r11's verdict on the convex kernel was "better placements,
-too slow for the latency path" — so this module runs the SAME
+The convex kernel places better and is too slow for the latency
+path — so this module runs the SAME
 mirror-descent program (kernels/convex.py mirror_descent) off the hot
 path, over the WHOLE cluster instead of one eval's asks: every movable
 allocation becomes a row of the relaxed assignment x [K, N], solved
@@ -156,8 +156,8 @@ def reference_asks(ask_res) -> List[Tuple[np.ndarray, float]]:
 def frag_score(util, capacity, node_ok, refs) -> float:
     """The defrag objective: frequency-weighted mean of the quality
     scoreboard's fragmentation over the workload's reference asks.
-    One number both the solver's move acceptance and the bench
-    trajectory read (cluster_fragmentation), so the loop can never
+    One number both the solver's move acceptance and
+    cluster_fragmentation read, so the loop can never
     'improve' a score nobody measures."""
     from ..kernels.quality import quality_from_arrays
 
@@ -172,8 +172,7 @@ def frag_score(util, capacity, node_ok, refs) -> float:
 def cluster_fragmentation(state, datacenters) -> float:
     """Measure the current cluster's defrag objective from a snapshot:
     the same resolve + movable-set + frag_score path the solver runs,
-    without solving. The bench --defrag-ab trajectory samples THIS for
-    both arms."""
+    without solving."""
     from ..models.matrix import (
         _alloc_usage,
         resolve_cluster_base,

@@ -176,14 +176,9 @@ class DispatchPipeline:
             t for t in cfg.enabled_schedulers
             if is_dense_factory(cfg.factory_for(t))
         ]
-        # The scheduler executive (server/executive.py) supersedes the
-        # pipeline when enabled: both own the central dense drain, and
-        # two drains racing the broker would split every storm into
-        # half-filled cohorts.
-        self.enabled = bool(
-            cfg.dispatch_pipeline and self.types
-            and cfg.eval_batch_size > 1 and not cfg.scheduler_executive
-        )
+        # eval_batch_size <= 1 is the operator turning batching off:
+        # the worker then runs each dense eval itself.
+        self.enabled = bool(self.types and cfg.eval_batch_size > 1)
 
         # Profiled (nomad_tpu/profile): the accumulator lock every
         # worker handoff and batch cut crosses.
@@ -573,8 +568,8 @@ class DispatchPipeline:
         # One MVCC snapshot for the whole batch: every member plans
         # against the same cluster state so their ClusterMatrix bases
         # share one token, one device upload, and (pre_resolve) one
-        # serialized claim scan. Same invariant as the worker drain
-        # path; optimistic concurrency keeps it safe.
+        # serialized claim scan. Optimistic concurrency keeps it
+        # safe: the applier re-verifies every node.
         max_index = max(max(e.eval.modify_index, e.min_index)
                         for e in batch)
         if not self._wait_for_index(max_index, WAIT_INDEX_TIMEOUT):

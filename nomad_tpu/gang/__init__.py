@@ -22,9 +22,8 @@ places its ``count`` members ATOMICALLY — all K or none:
   (scheduler/generic.py ``promote_gang_replacements``).
 
 This module holds the shared spec/routing helpers both scheduler
-paths, the executive's cohort fast path, the applier, and the rig
-import — it never touches the state store (gang terminals only ever
-stamp through the raft funnel).
+paths, the applier, and the rig import — it never touches the state
+store (gang terminals only ever stamp through the raft funnel).
 """
 
 from __future__ import annotations
@@ -186,7 +185,7 @@ _stats: Dict[str, int] = {}
 
 def note_gang_result(placed: bool, members: int, path: str) -> None:
     """Count one gang attempt's outcome (leaf lock, constant work).
-    ``path`` is "device" | "host" | "executive"."""
+    ``path`` is "device" | "host"."""
     with _stats_lock:
         _stats["gangs_placed" if placed else "gangs_rejected"] = (
             _stats.get("gangs_placed" if placed else "gangs_rejected", 0)

@@ -100,8 +100,7 @@ def register_kernel(name: str, loader: Callable[[], Callable]) -> None:
     zero-arg callable returning the kernel program (resolved lazily on
     first dispatch so registration never imports jax). Third-party
     kernels register here and become selectable through every surface
-    (placement_kernel knob, `service-<name>-tpu` factories, bench
-    --kernel-ab)."""
+    (placement_kernel knob, `service-<name>-tpu` factories)."""
     if not name or "-" in name:
         # Kernel names embed into factory names ("service-<k>-tpu") and
         # host_factory() strips them back out; a dash would make that

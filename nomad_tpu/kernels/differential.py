@@ -31,9 +31,7 @@ The oracle's own run on an identical cluster is recorded alongside
 parity is deliberately NOT asserted — that is the quality axis the
 scoreboard measures, not the validity axis this rig enforces.
 
-bench.py --check consumes ``run_differential`` and refuses to report
-kernel numbers whose rig is red; tests/test_kernels.py sweeps it
-property-style.
+tests/test_kernels.py sweeps ``run_differential`` property-style.
 """
 
 from __future__ import annotations
@@ -625,9 +623,7 @@ def _defrag_scenario(seed: int):
 
     rng = _random.Random(seed)
     h = Harness(seed=seed)
-    # The SHARED fragmentation fixture (scheduler/testing.py): the
-    # bench --defrag-ab arm builds the same workload, so the rig and
-    # the trajectory always judge one shape.
+    # The SHARED fragmentation fixture (scheduler/testing.py).
     seed_consolidation_cluster(h, rng.choice([24, 32]))
     churn_stop_small_allocs(h, rng, 0.35)
     return h

@@ -1,7 +1,6 @@
 """Kernel-agnostic placement-quality scoreboard.
 
-Throughput (bench.py's evals/s columns) says how FAST a kernel
-places; nothing measured how WELL. This module scores committed
+Throughput says how FAST a kernel places; nothing measured how WELL. This module scores committed
 placement decisions on the two axes Tesserae (PAPERS.md) evaluates
 placement policies on, plus the queueing axis the admission layer
 cares about:
@@ -14,23 +13,19 @@ cares about:
   OCCUPIED schedulable nodes: how tightly the used part of the
   cluster is packed. Higher = tighter (BestFit's goal, measured).
 - **queueing_delay_ms** — p99 time placement work spent QUEUED
-  rather than computed/committed, measured at whichever queue the
-  harness has: on a live server that is the broker (the flight
-  recorder's ``broker.wait`` p99, what ``snapshot()`` reports); the
-  broker-less bench e2e harness measures its queue, the batcher
-  (``device.dispatch`` p99 minus ``device.solve`` p99).
+  rather than computed/committed: the broker's queue (the flight
+  recorder's ``broker.wait`` p99, what ``snapshot()`` reports).
 
 All three are computed from COMMITTED state — the dense schedulers
 feed the board from the post-placement claimed arrays right after
 appending to the plan (the applier re-verifies, so emitted == applied
 modulo the conflict retries the pipeline stats already count), and
 ``quality_from_store`` recomputes from a live/oracle state store for
-bench columns and tests. The board never touches the state store and
+tests. The board never touches the state store and
 never blocks: bounded ring of samples under one leaf lock.
 
-Surfaces: ``server.stats()["placement_quality"]``, ``/v1/metrics``
-gauges (``placement_quality.*``), and bench.py's
-fragmentation/binpack_score/queueing_delay_ms columns + --kernel-ab.
+Surfaces: ``server.stats()["placement_quality"]`` and ``/v1/metrics``
+gauges (``placement_quality.*``).
 """
 
 from __future__ import annotations
@@ -47,8 +42,7 @@ SAMPLE_CAP = 512
 # copy + a few full-array passes), which at 10k nodes x 64 concurrent
 # evals is real GIL time on the scheduler hot path — and a 512-sample
 # median needs nowhere near every eval. The first WARM_SAMPLES evals
-# per kernel always score (fast feedback on fresh servers / bench
-# arms); after that, 1 in SAMPLE_EVERY.
+# per kernel always score (fast feedback on fresh servers); after that, 1 in SAMPLE_EVERY.
 WARM_SAMPLES = 64
 SAMPLE_EVERY = 8
 
@@ -94,7 +88,7 @@ def quality_from_arrays(util, capacity, node_ok, ask_res) -> Dict[str, float]:
 
 def quality_from_store(state, job) -> Dict[str, float]:
     """Recompute the scoreboard metrics from a state store snapshot
-    (bench columns for host-path configs; differential-rig checks).
+    (differential-rig checks and tests).
     `job`'s first task group is the reference ask."""
     from ..structs import allocs_fit
 
@@ -161,8 +155,8 @@ def slice_fragmentation(util, capacity, node_ok, topo_ids, ask_res,
 
 
 def slice_frag_from_store(state, job, tg, level: str = "rack") -> float:
-    """slice_fragmentation recomputed from a state-store snapshot (the
-    bench --gang-ab column and rig checks). ``tg`` is the gang task
+    """slice_fragmentation recomputed from a state-store snapshot (rig
+    checks). ``tg`` is the gang task
     group whose member ask and count parameterize the axis."""
     from ..models.topology import TOPOLOGY_META_KEYS
     from ..structs import allocs_fit

@@ -115,7 +115,7 @@ class MigrationGovernor:
     def reset_stats(self) -> None:
         """Re-baseline the observability counters (high-water mark,
         grant/defer/release totals) WITHOUT touching in-flight claims —
-        tests and bench arms measure a window, and a lifetime max would
+        tests measure a window, and a lifetime max would
         smear earlier windows into it."""
         with self._lock:
             self.high_water = self.in_flight
@@ -253,8 +253,8 @@ def note_preemption_failure(dispatch_failed: int = 0,
 
 def note_preemption_committed(n: int) -> None:
     """Plan-applier-side accounting: victims whose eviction actually
-    committed through the raft funnel (bench --check compares this to
-    the staged count to refuse numbers with lost evictions)."""
+    committed through the raft funnel (against the staged count: a
+    lost victim cost a replan)."""
     if n > 0:
         _policy.note(committed=n)
 

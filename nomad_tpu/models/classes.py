@@ -147,7 +147,7 @@ class ClassIndex:
     def members(self, ci: int) -> np.ndarray:
         """Member rows of one class (ascending). The per-class lists
         build lazily in one vectorized pass — expansion-side consumers
-        (defrag rounding, bench audits) want them, the hot build path
+        (defrag rounding, audits) want them, the hot build path
         does not."""
         if self._members is None:
             order = np.argsort(self.ids[: self.n_real], kind="stable")
@@ -161,7 +161,7 @@ class ClassIndex:
         return self._members[ci]
 
     def compression_ratio(self) -> float:
-        """N / C — the bench's ``class_compression_ratio`` column; 1.0
+        """N / C (the ``matrix.compress`` span's ``ratio``); 1.0
         means the plane compresses nothing (all-singleton fleet)."""
         return self.n_real / max(1, self.n_classes)
 

@@ -733,7 +733,7 @@ def ready_nodes_cached(state, datacenters):
 # [N, G] mask depends only on the node set (pinned by the base token)
 # and the job's constraint/driver STRUCTURE — not its id. A placement
 # storm is N structurally identical jobs with distinct ids (one
-# service scaled out, the bench's e2e-0..e2e-119 shape), so every eval
+# service scaled out, the benchmark's storm), so every eval
 # of a drained batch was recomputing an identical mask under the GIL
 # while the batcher's cohort window ticked — the mask memo is to
 # node_feasibility what the base cache is to the [N, 4] build.

@@ -185,7 +185,7 @@ def device_state_stats() -> Dict[str, object]:
     """The ``server.stats()["device_state"]`` payload: resident-chain
     counters plus the batcher's upload/delta tallies and the jit
     compile-cache size (a CLIMBING cache under steady load is a
-    recompile storm — bench.py's jit_recompiles column gates on it)."""
+    recompile storm — the benchmark's window_compiles reads it)."""
     from ..scheduler.batcher import get_batcher
 
     out = _tracker.stats()

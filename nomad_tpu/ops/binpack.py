@@ -442,18 +442,6 @@ def batched_placement_program(states: NodeState, asks: Asks, keys, config: Place
     )(states, asks, keys)
 
 
-@functools.partial(jax.jit, static_argnames=("config",))
-def batched_placement_program_shared(
-    state: NodeState, asks: Asks, keys, config: PlacementConfig
-):
-    """Batched evals against ONE shared snapshot/ask: only the PRNG keys
-    carry the batch axis, so the cluster matrix is transferred and held
-    on device once — the broker drain-to-batch fast path."""
-    return jax.vmap(
-        lambda k: placement_program(state, asks, k, config)
-    )(keys)
-
-
 # vmap axes for the overlay path: the job-independent cluster base
 # (capacity/util/bandwidth/ports/node_ok) is SHARED across the eval
 # batch (in_axes=None — one device copy, no per-eval transfer), while
@@ -671,9 +659,9 @@ def uniform_dh_flag(placements, job_dh, tg_dh) -> bool:
 # FLAT — a growing count under load is a recompile storm (a shape
 # bucket leak, an unhashable static arg, a drifting ladder) silently
 # eating multi-second trace+compile stalls. Exposed via
-# server.stats()["device_state"], /v1/metrics, and bench.py's
-# jit_recompiles column (whose --check gate refuses dense numbers when
-# it moves after warmup).
+# server.stats()["device_state"] and /v1/metrics (the benchmark's
+# window_compiles reads it and chip_smoke.py holds it flat across a
+# steady wave).
 
 # The static mirror of _jit_entry_points() + the parallel/shard.py
 # factory caches, enforced two ways: ntalint's `unregistered-jit` rule
@@ -684,7 +672,6 @@ def uniform_dh_flag(placements, job_dh, tg_dh) -> bool:
 NTA_JIT_ACCOUNTED = (
     "placement_program_jit",
     "batched_placement_program",
-    "batched_placement_program_shared",
     "batched_placement_program_overlay",
     "batched_placement_program_compact",
     "batched_placement_program_compact_delta",
@@ -706,15 +693,14 @@ def _jit_entry_points():
     if not _JIT_ENTRY_POINTS:
         # The preemption leg (ops/preempt.py) and the gang leg
         # (ops/gang.py) are part of the placement path's compile
-        # budget: bench.py's jit_recompiles gate must see their caches
-        # too, or a preemption/gang shape leak would hide.
+        # budget: jit_cache_size() must see their caches too, or a
+        # preemption/gang shape leak would hide.
         from .gang import gang_placement_program_jit
         from .preempt import preempt_placement_program_jit
 
         _JIT_ENTRY_POINTS = (
             placement_program_jit,
             batched_placement_program,
-            batched_placement_program_shared,
             batched_placement_program_overlay,
             batched_placement_program_compact,
             batched_placement_program_compact_delta,

@@ -130,8 +130,7 @@ def sharded_group_capacity(mesh, g_pad: int):
 
 def per_shard_occupancy(arrays) -> List[dict]:
     """[{device, platform, rows, bytes}] per shard of a
-    device-resident base tuple (or a single array) — the bench's
-    per-shard occupancy and device-memory columns, and chip_smoke.py's
+    device-resident base tuple (or a single array) — chip_smoke.py's
     proof of WHERE the base sits. Pure metadata: reads shard layouts,
     moves no data. Single-device arrays report one row."""
     if not isinstance(arrays, (tuple, list)):
@@ -154,8 +153,8 @@ def per_shard_occupancy(arrays) -> List[dict]:
 
 def shard_cache_size() -> int:
     """Compiled-program count across the cached shard_map programs —
-    an input to ops/binpack.py jit_cache_size, so the bench's
-    jit_recompiles gate covers the sharded paths too."""
+    an input to ops/binpack.py jit_cache_size, so the recompile count
+    covers the sharded paths too."""
     with _PROGRAM_LOCK:
         fns = list(_PROGRAMS.values())
     return sum(fn._cache_size() for fn in fns)

@@ -29,9 +29,8 @@ Overhead discipline: the uncontended lock path pays one counter bump
 and one clock read; everything on the record path is arithmetic +
 preallocated-slot writes under leaf locks (machine-enforced: ntalint's
 ``record-path-blocking`` walks the ``NTA_RECORD_PATH`` manifests here
-and in locks.py/timeline.py). bench.py's ``--profile-ab`` arm proves
-the whole observatory costs < 5% paired e2e (the --check gate refuses
-numbers otherwise).
+and in locks.py/timeline.py). What recorder plus observatory cost on
+the chip is in PERF.md section 6 (PR 25).
 """
 
 from __future__ import annotations
@@ -107,8 +106,8 @@ class _ThreadStats:
 
 class Profiler:
     def __init__(self):
-        # Plain attribute read on every record call (the bench
-        # --profile-off arm and tests flip it); no lock — a racing
+        # Plain attribute read on every record call (profile_enabled
+        # and tests flip it); no lock — a racing
         # record lands or not, either is fine.
         self.enabled = True
         self._reg_lock = threading.Lock()
@@ -342,8 +341,7 @@ class Profiler:
 
     def lock_site_buckets(self, field: str = "wait"):
         """(site -> (count, dense buckets)) for one histogram family —
-        the Prometheus exposition and the bench aggregation read this
-        so their percentiles come off the same ladder as snapshot()."""
+        the Prometheus exposition reads this so its percentiles come off the same ladder as snapshot()."""
         out = {}
         for site, instances in self._site_stats_lists().items():
             count, total, mx = 0, 0.0, 0.0
@@ -474,8 +472,8 @@ class Profiler:
             self.gil.stop()
 
     def reset(self) -> None:
-        """Drop accumulated stats (bench A/B arms and test isolation;
-        not on the record path). Racing writers may lose a sample into
+        """Drop accumulated stats (test isolation; not on the
+        record path). Racing writers may lose a sample into
         a just-replaced histogram — benign for an A/B reset."""
         self._drain_retired()
         with self._reg_lock:

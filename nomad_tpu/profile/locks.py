@@ -163,7 +163,7 @@ class ProfiledLock:
         # Cleared UNCONDITIONALLY: a stamp surviving a
         # disabled-profiler release would be read by a later
         # enabled-again release as one giant hold spanning the whole
-        # disabled window (the bench A/B flips exactly this way).
+        # disabled window (flipping profile_enabled does exactly this).
         self._acquired_at = 0.0
         self._lock.release()
 

@@ -5,10 +5,9 @@ A daemon thread repeatedly requests a short sleep and measures the
 the overshoot is the OS timer slack (tens of microseconds); when N
 runnable threads contend for the GIL the sleeper must wait for a
 GIL handoff after its timer fires, so the overshoot distribution IS
-the interpreter scheduling delay every other thread experiences. This
-is the measurement BENCH_r10 inferred from a percentile gap: "GIL
-queuing of 64 eval threads around the batch boundary" becomes a
-histogram, not a guess.
+the interpreter scheduling delay every other thread experiences: "GIL
+queuing of 64 eval threads around the batch boundary", once inferred
+from a percentile gap, becomes a histogram, not a guess.
 
 The sampler owns its histogram (single writer — the sampler thread;
 readers snapshot monotonic counters, benign mid-update reads). The

@@ -4,8 +4,8 @@ parked HTTP threads.
 The thread-parking blocking query (api/http.py `_blocking`) holds one
 HTTP handler thread per watcher for up to MAX_BLOCKING_WAIT — N
 watchers cost N OS threads, and before scoped indexes every commit
-woke all of them. The mux applies the executive's event-loop
-discipline to the read side:
+woke all of them. The mux applies an event-loop discipline to the
+read side:
 
 - A blocking query whose scope has not yet passed ``?index=N``
   registers a **continuation** — scope set, min index, deadline, and a
@@ -15,7 +15,7 @@ discipline to the read side:
   then exits; the socket stays open, owned by the continuation.
 - One **wake-owner thread** (`_wake_loop`, registered in
   ``NTA_DISPATCHER_ENTRYPOINTS`` — it is a never-blocking clock like
-  the executive drain) drains scope notifications fed by the store's
+  the dispatch pipeline's drain) drains scope notifications fed by the store's
   NotifyGroup sink, re-checks each candidate's scope index, and hands
   satisfied or expired continuations to a small bounded WorkPool that
   re-runs the query and streams the response.

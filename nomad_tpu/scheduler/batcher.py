@@ -223,10 +223,6 @@ class PlacementBatcher:
         self.unsharded_fallbacks = 0  # guarded-by: _lock
         self.dispatches = 0  # guarded-by: _lock (device calls issued)
         self.batched_requests = 0  # guarded-by: _lock (requests served)
-        # Dispatches issued inline by a cohort driver (place_cohort —
-        # the scheduler executive's no-park path) vs. the parked
-        # place() path; a subset of `dispatches`.
-        self.cohort_dispatches = 0  # guarded-by: _lock
         self.base_uploads = 0  # guarded-by: _lock (host->device bases)
         self.base_delta_updates = 0  # guarded-by: _lock (derived bases)
         self.overlay_dispatches = 0  # guarded-by: _lock (shared-base)
@@ -298,7 +294,7 @@ class PlacementBatcher:
         kernel, separated from batch-wait and stacking."""
         class_ids = getattr(state, "class_ids", None)
         if class_ids is None:
-            # Plain NodeState callers (bench harness): no class index —
+            # Plain NodeState callers (tests): no class index —
             # the compact path is off for them anyway.
             class_ids = np.full(np.shape(state.node_ok), -1, np.int32)
         base = (state.capacity, state.sched_capacity, state.util,
@@ -403,67 +399,6 @@ class PlacementBatcher:
         if req.error is not None:
             raise req.error
         return req.choices, req.scores
-
-    def place_cohort(self, requests):
-        """Dispatch a pre-formed cohort synchronously on the CALLING
-        thread — the scheduler executive's entry point
-        (server/executive.py). Where place() makes an eval's identity a
-        parked thread (join a queue, wait on an event, wake under GIL
-        pressure — the measured batch-boundary convoy, BENCH_r13), here
-        the cohort driver IS the batch: requests are grouped by the
-        same shape key place() computes, chunked to max_batch, and each
-        group runs _run_batch inline. No queues, no events, no
-        dispatcher threads, nothing parks.
-
-        `requests` is a list of (state, asks, rng_key, config, span)
-        tuples (place()'s argument shapes). Returns a list of
-        (choices, scores) aligned with the input order. A device fault
-        raises out of the whole call — the executive's host fallback
-        owns the blast radius, exactly like the per-eval except path in
-        scheduler/tpu.py."""
-        built: List[Tuple[Tuple, object, _Request]] = []
-        for state, asks, rng_key, config, span in requests:
-            class_ids = getattr(state, "class_ids", None)
-            if class_ids is None:
-                class_ids = np.full(np.shape(state.node_ok), -1, np.int32)
-            base = (state.capacity, state.sched_capacity, state.util,
-                    state.bw_avail, state.bw_used, state.ports_free,
-                    state.node_ok, class_ids)
-            overlay = (state.job_count, state.tg_count, state.feasible)
-            compact = getattr(state, "compact_overlay", None)
-            token = getattr(state, "base_token", None)
-            compact_key = None if compact is None else (
-                np.shape(compact.verdicts)[0],
-                np.shape(compact.patch_rows)[0],
-                np.shape(compact.job_rows)[0],
-            )
-            shape_key = (
-                np.shape(state.capacity), np.shape(asks.resources),
-                np.shape(state.feasible)[-1], config, token, compact_key,
-            )
-            built.append((shape_key, config, _Request(
-                token, base, overlay, asks, rng_key,
-                delta=getattr(state, "base_delta", None),
-                compact=compact, span=span)))
-        groups: "OrderedDict[Tuple, List[_Request]]" = OrderedDict()
-        configs: Dict[Tuple, object] = {}
-        for shape_key, config, req in built:
-            groups.setdefault(shape_key, []).append(req)
-            configs[shape_key] = config
-        for shape_key, reqs in groups.items():
-            for at in range(0, len(reqs), self.max_batch):
-                chunk = reqs[at:at + self.max_batch]
-                self._run_batch(chunk, configs[shape_key])
-                with self._lock:
-                    self.dispatches += 1
-                    self.batched_requests += len(chunk)
-                    self.cohort_dispatches += 1
-        out = []
-        for _key, _config, req in built:
-            if req.error is not None:
-                raise req.error
-            out.append((req.choices, req.scores))
-        return out
 
     # ------------------------------------------------------------------
 
@@ -1082,8 +1017,7 @@ class PlacementBatcher:
 
     def shard_occupancy(self) -> list:
         """Per-shard [{device, rows, bytes}] of the newest resident
-        base (parallel/shard.py per_shard_occupancy) — the bench's
-        per-shard occupancy / device-memory columns. Snapshot under
+        base (parallel/shard.py per_shard_occupancy). Snapshot under
         the lock, read layouts outside it (pure metadata)."""
         with self._lock:
             dev = next(reversed(self._device_bases.values()), None) \
@@ -1108,7 +1042,6 @@ class PlacementBatcher:
             return {
                 "dispatches": self.dispatches,
                 "batched_requests": self.batched_requests,
-                "cohort_dispatches": self.cohort_dispatches,
                 "base_uploads": self.base_uploads,
                 "base_delta_updates": self.base_delta_updates,
                 "overlay_dispatches": self.overlay_dispatches,
@@ -1124,8 +1057,8 @@ class PlacementBatcher:
                 "upload_bytes": int(self.bytes_upload),
                 # Compiled XLA programs this process holds (all the
                 # placement entry points): steady state is FLAT — a
-                # climb under load is a recompile storm (bench.py's
-                # jit_recompiles column gates on it).
+                # climb under load is a recompile storm (the benchmark's
+                # window_compiles reads it).
                 "jit_cache_size": jit_programs,
             }
 

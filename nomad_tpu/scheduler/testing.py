@@ -49,13 +49,13 @@ def seed_consolidation_cluster(harness: "Harness", n_nodes: int,
                                factory: str = "service",
                                big_prefix: str = "cbig",
                                small_prefix: str = "csmall"):
-    """The shared fragmentation fixture (defrag rig + bench arm): a
+    """The shared fragmentation fixture (defrag rig and tests): a
     fleet of 1000/1000-capacity nodes running a mixed service workload
     — 600/600 'big' jobs and 300/300 'small' jobs, placed through the
     real scheduler — whose churn-stopped smalls leave the sub-ask
-    remainders consolidation exists for. One builder, so the bench
-    trajectory and the differential rig can never silently judge
-    different workloads. Returns (nodes, jobs); store writes route
+    remainders consolidation exists for. One builder, so the tests and
+    the differential rig can never silently judge different
+    workloads. Returns (nodes, jobs); store writes route
     through seed_harness_cluster (the fixture funnel)."""
     from .. import mock
     from ..structs import consts

@@ -76,13 +76,11 @@ def _offer_networks(rng, missing: AllocTuple, node, net_indexes, matrix):
 
 def build_placement_config(batch: bool, pre_resolve: bool, kernel,
                            placements, ask_arrays):
-    """The PlacementConfig both dense drivers — BatchedTPUScheduler's
-    per-eval place() and the scheduler executive's cohort dispatch
-    (server/executive.py) — hand the batcher. Factored so the STATIC
-    fields that key compiled device programs (penalty, pre_resolve,
-    uniform_dh, kernel) can never drift between the two paths: a drift
-    would mint a second program per shape bucket (a recompile storm)
-    and break executive-vs-worker placement parity."""
+    """The PlacementConfig BatchedTPUScheduler hands the batcher. One
+    factory for the STATIC fields that key compiled device programs
+    (penalty, pre_resolve, uniform_dh, kernel): a second way to build
+    them would mint a second program per shape bucket (a recompile
+    storm; analysis/compile_surface.py sanctions this factory)."""
     from ..kernels import active_kernel
     from ..ops.binpack import PlacementConfig, uniform_dh_flag
     from .stack import (
@@ -302,9 +300,7 @@ class BatchedTPUScheduler(GenericScheduler):
         # factory variant, else the process-global active kernel inside
         # build_placement_config. The name is a static PlacementConfig
         # field — it joins the batcher's shape key, so kernels never
-        # share a dispatch. The config literal is shared with the
-        # scheduler executive (build_placement_config) so the two dense
-        # drivers can never compile divergent programs.
+        # share a dispatch.
         config = build_placement_config(
             self.batch,
             bool(getattr(self.planner, "pre_resolve", False)),
@@ -422,8 +418,8 @@ class BatchedTPUScheduler(GenericScheduler):
         # Quality scoreboard (kernels/quality.py): score the cluster
         # state this plan commits to — base utilization plus the
         # claims this loop actually appended — on the fragmentation/
-        # bin-pack axes, labeled by kernel so --kernel-ab and stats()
-        # can compare. Cheap ([N,4] copy + vector ops) next to the
+        # bin-pack axes, labeled by kernel so stats() can compare
+        # kernels. Cheap ([N,4] copy + vector ops) next to the
         # dispatch it follows.
         self._note_quality(kernel, matrix, ask_arrays[0], committed)
 
@@ -767,9 +763,7 @@ class BatchedTPUScheduler(GenericScheduler):
 
 def note_quality(logger, job, kernel, matrix, ask_res, committed) -> None:
     """Quality scoreboard entry (kernels/quality.py) for one dense
-    plan's committed claims — shared by the per-eval scheduler and the
-    scheduler executive so --kernel-ab and stats() score both drivers
-    on the same axes. Scoring must never fail an eval."""
+    plan's committed claims. Scoring must never fail an eval."""
     from ..kernels.quality import (
         get_board,
         quality_from_arrays,

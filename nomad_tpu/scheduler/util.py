@@ -13,8 +13,6 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
-import numpy as np
-
 from ..structs import (
     Allocation,
     DesiredUpdates,
@@ -547,190 +545,6 @@ def desired_updates(
     for tup in destructive_updates:
         get(tup.task_group.name).destructive_update += 1
     return out
-
-
-# ---------------------------------------------------------------- cohort
-
-# Per-alloc classification codes for the stacked cohort table
-# (cohort_reconcile). IGNORE and PLACE_PREV keep an eval on the
-# executive's array path; LEGACY routes the whole eval to the per-eval
-# scheduler (its diff has buckets — stop/update/migrate/lost — the
-# batched path does not reproduce).
-_COHORT_IGNORE = 0
-_COHORT_PLACE_PREV = 1
-_COHORT_LEGACY = 2
-
-# Triggers the executive's array path may take end to end; everything
-# else carries semantics (deregister stops, migration budget claims,
-# rolling follow-ups) the per-eval scheduler owns.
-COHORT_FAST_TRIGGERS = (
-    consts.EVAL_TRIGGER_JOB_REGISTER,
-    consts.EVAL_TRIGGER_NODE_UPDATE,
-    consts.EVAL_TRIGGER_PERIODIC_JOB,
-    consts.EVAL_TRIGGER_MAX_PLANS,
-)
-
-
-@dataclass
-class CohortMember:
-    """One eval's reconcile verdict inside an executive cohort: either
-    `fast` with its pure-placement diff attached (the array path owns
-    it end to end), or legacy with the routing reason (the per-eval
-    scheduler runs it unchanged)."""
-
-    eval: Evaluation
-    job: Optional[Job] = None
-    fast: bool = False
-    reason: str = ""
-    place: List[AllocTuple] = field(default_factory=list)
-    queued: Dict[str, int] = field(default_factory=dict)
-
-
-def cohort_reconcile(state, evals: List[Evaluation]) -> List[CohortMember]:
-    """Reconcile a whole cohort of evaluations in one pass over a
-    stacked existing-allocs table (the scheduler executive's batched
-    replacement for N GIL-interleaved diff_allocs loops).
-
-    The cohort's existing allocations stack into parallel arrays —
-    owning-eval index, job-modify index, terminal/tainted/required
-    membership flags — classified with vectorized compares instead of
-    per-eval Python branching, and the per-eval verdict is an
-    aggregation (np.bincount over the eval axis). An eval is `fast`
-    exactly when its diff would contain ONLY place/ignore buckets:
-    stops, destructive/in-place updates, migrations (budget claims),
-    lost allocs, tainted nodes, batch-job terminal semantics and
-    sticky disks all route to the per-eval scheduler, whose code paths
-    stay the single source of truth for those semantics. Parity with
-    diff_allocs on the fast subset is a test invariant
-    (tests/test_scheduler_util.py)."""
-    members = [CohortMember(eval=ev) for ev in evals]
-    node_tainted: Dict[str, bool] = {}  # cohort-level memo, one lookup/node
-
-    def tainted(node_id: str) -> bool:
-        hit = node_tainted.get(node_id)
-        if hit is None:
-            node = state.node_by_id(node_id)
-            hit = (node is None or node.status == consts.NODE_STATUS_DOWN
-                   or node.drain)
-            node_tainted[node_id] = hit
-        return hit
-
-    # ---- gather: one pass stacking every member's existing allocs.
-    per_eval_allocs: List[List[Allocation]] = []
-    requireds: List[Dict[str, TaskGroup]] = []
-    e_idx: List[int] = []
-    a_jmi: List[int] = []  # alloc's job-modify index
-    e_jmi: List[int] = []  # owning eval's job-modify index (repeated)
-    a_term: List[bool] = []
-    a_taint: List[bool] = []
-    a_req: List[bool] = []
-    a_lostable: List[bool] = []  # client running/pending (lost-markable)
-    for i, m in enumerate(members):
-        ev = m.eval
-        m.job = state.job_by_id(ev.job_id)
-        allocs = state.allocs_by_job(ev.job_id)
-        per_eval_allocs.append(allocs)
-        if ev.triggered_by not in COHORT_FAST_TRIGGERS:
-            m.reason = f"trigger {ev.triggered_by!r}"
-        elif ev.status != consts.EVAL_STATUS_PENDING:
-            m.reason = f"status {ev.status!r}"
-        elif ev.annotate_plan:
-            m.reason = "annotated plan"
-        elif m.job is None or getattr(m.job, "stop", False):
-            m.reason = "job stopped/deregistered"
-        elif m.job.type not in (consts.JOB_TYPE_SERVICE,
-                                consts.JOB_TYPE_BATCH):
-            m.reason = f"job type {m.job.type!r}"
-        elif m.job.type == consts.JOB_TYPE_BATCH and allocs:
-            # ran_successfully()/newest-per-slot filtering is batch-only
-            # reconcile state the per-eval path owns.
-            m.reason = "batch job with history"
-        elif allocs and any(
-                tg.ephemeral_disk is not None and tg.ephemeral_disk.sticky
-                for tg in m.job.task_groups):
-            m.reason = "sticky ephemeral disk"
-        elif any(getattr(tg, "gang", None) is not None
-                 for tg in m.job.task_groups):
-            # Gang task groups (nomad_tpu/gang) carry all-or-nothing
-            # semantics the array materialize path does not reproduce
-            # (atomic gang-leg staging, pop_gang unwind, whole-gang
-            # replacement). The per-eval DENSE scheduler is their
-            # single source of truth; routing there keeps the gang ONE
-            # eval with K asks — one dispatch of the all-K program —
-            # never K batch rows.
-            m.reason = "gang task group"
-        required = materialize_task_groups(m.job) if not m.reason else {}
-        requireds.append(required)
-        if m.reason:
-            continue
-        jmi = m.job.job_modify_index
-        for a in allocs:
-            e_idx.append(i)
-            a_jmi.append(a.job.job_modify_index if a.job else 0)
-            e_jmi.append(jmi)
-            a_term.append(a.terminal_status())
-            a_taint.append(tainted(a.node_id))
-            a_req.append(a.name in required)
-            a_lostable.append(a.client_status in (
-                consts.ALLOC_CLIENT_RUNNING, consts.ALLOC_CLIENT_PENDING))
-
-    # ---- classify: vectorized over the stacked table.
-    if e_idx:
-        eidx = np.asarray(e_idx, np.int64)
-        term = np.asarray(a_term, bool)
-        taint = np.asarray(a_taint, bool)
-        req = np.asarray(a_req, bool)
-        updated = np.asarray(a_jmi, np.int64) != np.asarray(e_jmi, np.int64)
-        lostable = np.asarray(a_lostable, bool)
-        # Live alloc on a tainted node -> migrate/lost; name outside the
-        # required set -> stop; stale job version -> update: all legacy.
-        # A terminal-by-desired-status alloc whose client still runs on
-        # a tainted node needs the lost-marking pass
-        # (update_non_terminal_allocs_to_lost) — legacy too.
-        legacy = (~term & (taint | ~req | updated)) | (taint & lostable)
-        codes = np.where(legacy, _COHORT_LEGACY,
-                         np.where(term & req, _COHORT_PLACE_PREV,
-                                  _COHORT_IGNORE))
-        legacy_counts = np.bincount(eidx[codes == _COHORT_LEGACY],
-                                    minlength=len(members))
-    else:
-        codes = np.zeros(0, np.int64)
-        legacy_counts = np.zeros(len(members), np.int64)
-
-    # ---- assemble: place = required minus live names, prev-alloc from
-    # the newest terminal holder of the slot (previous_allocation).
-    flat = 0
-    for i, m in enumerate(members):
-        allocs = per_eval_allocs[i]
-        n = len(allocs) if not m.reason else 0
-        if m.reason:
-            continue
-        if legacy_counts[i]:
-            m.reason = "diff has stop/update/migrate/lost buckets"
-            flat += n
-            continue
-        live_names = set()
-        terminal_prev: Dict[str, Allocation] = {}
-        for k, a in enumerate(allocs):
-            code = codes[flat + k]
-            if code == _COHORT_PLACE_PREV:
-                prev = terminal_prev.get(a.name)
-                if prev is None or prev.create_index < a.create_index:
-                    terminal_prev[a.name] = a
-            elif not a.terminal_status():
-                live_names.add(a.name)
-        flat += n
-        m.fast = True
-        required = requireds[i]
-        for name, tg in required.items():
-            if name in live_names:
-                continue
-            m.place.append(AllocTuple(name, tg, terminal_prev.get(name)))
-            m.queued[tg.name] = m.queued.get(tg.name, 0) + 1
-        if not m.place:
-            for tg in m.job.task_groups:
-                m.queued.setdefault(tg.name, 0)
-    return members
 
 
 def adjust_queued_allocations(

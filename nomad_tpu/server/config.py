@@ -32,11 +32,12 @@ class ServerConfig:
     eval_nack_timeout: float = 60.0
     eval_delivery_limit: int = 3
 
-    # Max evals a worker drains per broker visit when the eval's
-    # factory is a dense (TPU) one, so their placement programs share
-    # one batched device dispatch (extension over the reference's
-    # single dequeue, eval_broker.go:259). 1 disables batching.
-    # Default = the batcher's MAX_BATCH: a drain can fill one device
+    # Max evals the dispatch pipeline packs into one batch of dense
+    # (TPU) factory evals, so their placement programs share one
+    # batched device dispatch (extension over the reference's single
+    # dequeue, eval_broker.go:259). 1 disables batching: each worker
+    # then runs a dense eval itself on the dense factory.
+    # Default = the batcher's MAX_BATCH: a batch can fill one device
     # dispatch and no more. The value has not been re-measured on an
     # attached chip. Lone/interactive evals never see this (the
     # dense_min_batch router sends them to the host pipeline).
@@ -55,8 +56,6 @@ class ServerConfig:
     # packs full device batches, launches them pipelined (next batch
     # accumulates during the in-flight device sync + plan submits),
     # and requeues plan-conflict retries into the ACCUMULATING batch.
-    # False reverts to the per-worker drain-then-place loop.
-    dispatch_pipeline: bool = True
     # Batches allowed in flight at once: overlap hides the device
     # round-trip + plan-submit tail behind the next accumulation.
     dispatch_max_inflight: int = 2
@@ -70,28 +69,6 @@ class ServerConfig:
     # this many times before falling back to the scheduler's own
     # inline retry loop (bounded like MAX_SERVICE_SCHEDULE_ATTEMPTS).
     dispatch_max_requeues: int = 3
-
-    # ---- Scheduler executive (nomad_tpu/server/executive.py) ----
-    # Replace the thread-per-eval dense worker model with a batched
-    # event-loop executive: one drain-owner thread pulls whole cohorts
-    # from the broker, reconciles them as arrays host-side
-    # (scheduler/util.py cohort_reconcile), hands complete batches
-    # straight to the device via the batcher's no-park cohort dispatch
-    # (place_cohort), and fans results back out through per-eval
-    # plan-submit + ack — an evaluation's identity is a batch row, not
-    # a parked thread (the BENCH_r13 convoy). False (the default, for
-    # A/B and until the rollout flips) keeps the dispatch-pipeline +
-    # worker fan-out path; the Worker pool always remains the host/
-    # system/fallback scheduler either way.
-    scheduler_executive: bool = False
-    # Host-side helper threads the executive uses for per-eval matrix
-    # builds and plan-submit/ack fan-out WITHIN a cohort (numpy releases
-    # the GIL, so a few help; 64 was the convoy). The drain itself is
-    # always one thread. Replaces num_schedulers as the dense path's
-    # parallelism knob when the executive is on (num_schedulers then
-    # only sizes the host/system worker pool — see README migration
-    # note).
-    executive_threads: int = 4
 
     # In-batch conflict pre-resolution: serialize the eval axis of a
     # shared-base device dispatch so batch members see each other's
@@ -116,8 +93,7 @@ class ServerConfig:
     # The dense path's [N, R] node matrix lives on device; plan commits
     # and node up/down/drain transitions apply as small scatter deltas
     # keyed on raft index instead of re-shipping the full matrix per
-    # batch. False reverts to per-snapshot rebuild + re-upload (the
-    # bench A/B arm).
+    # batch. False reverts to per-snapshot rebuild + re-upload.
     device_resident: bool = True
     # Max delta-refilled rows before a full rebuild is the better deal;
     # 0 = auto (max(64, N/4)).
@@ -168,7 +144,7 @@ class ServerConfig:
     # register a continuation with the mux and free their HTTP handler
     # thread; one wake-owner thread + a small serve pool re-run them
     # on scope notifications. False reverts to thread-parking long
-    # polls (the bench --read-storm baseline arm).
+    # polls.
     read_mux_enabled: bool = True
     # Serve-pool threads re-running satisfied/expired queries.
     read_mux_workers: int = 4
@@ -225,9 +201,8 @@ class ServerConfig:
     # Always-on lock/GIL/pipeline profiler, like the flight recorder:
     # ProfiledLock wait/hold histograms on the hot locks, the
     # GIL-pressure sampler thread, and the batch-boundary convoy
-    # detector. False disables recording (the bench --profile-off arm)
-    # and stops the sampler; the lock wrappers stay in place either
-    # way.
+    # detector. False disables recording and stops the sampler; the
+    # lock wrappers stay in place either way.
     profile_enabled: bool = True
     # GIL sampler sleep-request interval in seconds (~200 wakes/s at
     # the default; the overshoot distribution is the measurement).

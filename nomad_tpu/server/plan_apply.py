@@ -190,7 +190,8 @@ class PlanApplier:
         self._lifecycle = threading.Lock()  # start/stop can race on
         # leadership flaps (raft elections)
         # Conflict observability (feeds the dispatch pipeline's
-        # retries-per-eval accounting and the bench's A/B column):
+        # retries-per-eval accounting and the benchmark's conflict
+        # metrics):
         # counters only ever touched on the applier thread.
         self.plans_evaluated = 0
         self.plans_rejected = 0  # plans that lost >= 1 node (refresh)

@@ -72,27 +72,20 @@ class Server:
         self.heartbeats = HeartbeatTimers(self)
         self.periodic = PeriodicDispatch(self)
         self.workers: List[Worker] = []
-        # Shared pool for drain-to-batch eval processing: batch members
-        # must run concurrently (the batcher coalesces their blocked
-        # place() calls into one device dispatch) but thread-per-eval at
-        # storm rates is churn — a fixed ceiling of persistent daemon
-        # workers serves every Worker's batches. Sized so every worker's
-        # full drain fits at once: a dequeued eval queued behind other
-        # workers' batches would hold its broker lease past the nack
-        # clock and miss its batch's dispatch window.
+        # Shared pool for the dispatch pipeline's stage threads: batch
+        # members must run concurrently (the batcher coalesces their
+        # blocked place() calls into one device dispatch) but
+        # thread-per-eval at storm rates is churn — a fixed ceiling of
+        # persistent daemon workers serves every batch. The pipeline
+        # fans a full batch out per in-flight slot; a pool smaller than
+        # that would strand batch members behind their own batch's
+        # dispatch. +1 per slot for the launch prologue itself — it
+        # runs on this pool too (the dispatcher thread must never
+        # block), and its FSM catch-up may stall the full
+        # wait-for-index timeout.
         self.eval_pool = WorkPool(
-            max(2, min(192, max(
-                self.config.num_schedulers
-                * max(1, self.config.eval_batch_size - 1),
-                # The dispatch pipeline fans a full batch out per
-                # in-flight slot; a pool smaller than that would strand
-                # batch members behind their own batch's dispatch. +1
-                # per slot for the launch prologue itself — it runs on
-                # this pool too (the dispatcher thread must never
-                # block), and its FSM catch-up may stall the full
-                # wait-for-index timeout.
-                (self.config.eval_batch_size + 1)
-                * max(1, self.config.dispatch_max_inflight)))),
+            max(2, min(192, (self.config.eval_batch_size + 1)
+                       * max(1, self.config.dispatch_max_inflight))),
             name="eval-batch")
         # Central dispatch pipeline for dense-path evals (dispatch/):
         # workers hand dense evals here; the pipeline drains the rest
@@ -101,14 +94,6 @@ class Server:
         from ..dispatch import DispatchPipeline
 
         self.dispatch = DispatchPipeline(self)
-        # Scheduler executive (server/executive.py): the batched
-        # event-loop replacement for thread-per-eval dense scheduling —
-        # behind `scheduler_executive` (the pipeline+worker fan-out
-        # stays the default for A/B). Constructed unconditionally so
-        # stats()/endpoints always have the surface.
-        from .executive import SchedulerExecutive
-
-        self.executive = SchedulerExecutive(self)
         # Overload protection (nomad_tpu/admission): pressure monitor +
         # token-bucket intake control; the HTTP layer and the TCP
         # transport consult it per request. The device-path breaker is
@@ -171,7 +156,7 @@ class Server:
         # multiplexer. Constructed unconditionally (stats surface); the
         # HTTP layer only parks continuations here while
         # read_mux_enabled — otherwise blocking queries fall back to
-        # the thread-parking loop (the bench baseline arm). The store
+        # the thread-parking loop. The store
         # accessor is a callable because FSM snapshot-restore swaps the
         # StateStore instance.
         from ..readplane import ReadMux
@@ -260,7 +245,6 @@ class Server:
             self.workers.append(worker)
             worker.start()
         self.dispatch.start()
-        self.executive.start()
         self.defrag.start()
         if self.config.read_mux_enabled:
             self.read_mux.start()
@@ -303,7 +287,7 @@ class Server:
                     # this process): recompile storms (jit_cache_size
                     # climbing under steady load) and staleness
                     # rebuilds must be visible on a live agent, not
-                    # just in bench.
+                    # just in a benchmark run.
                     ds = _device_state_stats()
                     metrics.set_gauge(
                         ("device_state", "jit_cache_size"),
@@ -327,7 +311,7 @@ class Server:
                     # the active kernel's committed-plan medians plus
                     # the queueing p99, scrapeable at /v1/metrics so a
                     # kernel rollout's quality shift shows up on a
-                    # dashboard, not just in bench.
+                    # dashboard, not just in a benchmark run.
                     pq = _quality_board().snapshot()
                     metrics.set_gauge(
                         ("placement_quality", "queueing_delay_ms"),
@@ -437,7 +421,6 @@ class Server:
             self.workers.append(worker)
             worker.start()
         self.dispatch.start()
-        self.executive.start()
         self.defrag.start()
         if self.config.read_mux_enabled:
             self.read_mux.start()
@@ -557,7 +540,6 @@ class Server:
         if self.raft is not None:
             self.raft.stop()
         self.dispatch.stop()
-        self.executive.stop()
         self.defrag.stop()
         self.read_mux.stop()
         for w in self.workers:
@@ -739,12 +721,11 @@ class Server:
     def revoke_leadership(self) -> None:
         self._leader = False
         # Drain FIRST, while the broker still accepts nacks: the
-        # pipeline's/executive's accumulated evals go back to the ready
+        # pipeline's accumulated evals go back to the ready
         # queue (or, on a real flap where the broker flushes anyway,
         # fail cleanly and re-seed from raft state via the new leader's
         # _restore_evals) — either way no eval is lost with the batch.
         self.dispatch.drain()
-        self.executive.drain()
         # The defrag loop pauses itself on is_leader() per tick; the
         # explicit abandon here returns its wave's governor slots NOW
         # instead of on the next tick (the new leader's drain storms
@@ -1422,10 +1403,11 @@ class Server:
             "heartbeat_timers": self.heartbeats.count(),
             "num_workers": len(self.workers),
             "dispatch_pipeline": self.dispatch.stats(),
-            # Scheduler executive (server/executive.py): cohort sizes,
-            # fast-vs-legacy lane split (with routing reasons), and
-            # the drain/build/dispatch/finalize time breakdown.
-            "scheduler_executive": self.executive.stats(),
+            # A constant, for one reader: benchmark/counters.py
+            # indexes stats["scheduler_executive"] and holds
+            # executive.host_fallbacks to 0 by absence; the key goes
+            # when that reader drops it (ROADMAP R0).
+            "scheduler_executive": {"enabled": False},
             "plan_applier": self.plan_applier.stats(),
             # Overload-protection surface (nomad_tpu/admission):
             # pressure level + reasons, intake-bucket stats, and the

@@ -6,13 +6,11 @@ Planner interface (worker.go:285-483): plans go through the leader's
 plan queue; a RefreshIndex response makes the worker catch its local
 state up and hand the scheduler a fresh snapshot.
 
-Extension over the reference (VERDICT round 1 / BASELINE north star):
-when an eval routes to a dense (TPU) factory, the worker drains more
-ready evals of the same type in one broker visit (dequeue_many) and
-processes them concurrently, so their placement programs coalesce into
-one batched device dispatch (scheduler/batcher.py) even with a single
-active worker. The reference's single-dequeue loop cannot form device
-batches; this is the drain-to-batch shim the dense backend needs.
+Extension over the reference: an eval that routes to a dense (TPU)
+factory is handed to the central dispatch pipeline (nomad_tpu/dispatch),
+which packs evals from every worker into full device batches. The
+reference's single-dequeue loop cannot form device batches; the worker
+stays the broker's long-poll seed and the host/system/fallback engine.
 """
 
 from __future__ import annotations
@@ -22,13 +20,13 @@ import os
 import random
 import threading
 import time
-from typing import List, Optional, Tuple
+from typing import Optional, Tuple
 
 from ..scheduler import new_scheduler
 from ..migrate import preemption_eligible
 from ..utils import metrics
 from ..utils.backoff import poll_until
-from ..structs import Evaluation, Plan, PlanResult, consts
+from ..structs import Evaluation, Plan, PlanResult
 from .. import trace
 
 DEQUEUE_TIMEOUT = 0.5
@@ -41,18 +39,17 @@ BACKPRESSURE_NAP = 0.01
 
 
 def is_dense_factory(name: str) -> bool:
-    """Dense/TPU factories benefit from drain-to-batch processing."""
+    """Dense/TPU factories place through batched device dispatches."""
     return name.endswith("-tpu")
 
 
 def routes_host(priorities, dense_min_batch: int) -> bool:
-    """Latency-aware routing, the one rule of the worker's drain, the
-    dispatch pipeline and the scheduler executive: a batch too small to
-    amortize a device dispatch runs on the host factories (identical
-    placement semantics, parity-tested), unless one of its evals may
-    preempt. The host iterators cannot evict, so such an eval stays
-    dense at any batch size (scheduler/tpu.py keeps its few-ask retries
-    on the dense path for the same reason)."""
+    """Latency-aware routing, the dispatch pipeline's one rule: a
+    batch too small to amortize a device dispatch runs on the host
+    factories (identical placement semantics, parity-tested), unless
+    one of its evals may preempt. The host iterators cannot evict, so
+    such an eval stays dense at any batch size (scheduler/tpu.py keeps
+    its few-ask retries on the dense path for the same reason)."""
     priorities = list(priorities)
     return len(priorities) < dense_min_batch and not any(
         preemption_eligible(p) for p in priorities)
@@ -61,11 +58,7 @@ def routes_host(priorities, dense_min_batch: int) -> bool:
 def factory_kernel(name: str) -> Optional[str]:
     """The kernel a dense factory variant pins ("service-convex-tpu"
     -> "convex"; nomad_tpu/kernels lazy registry), None for plain
-    dense factories and host factories. The scheduler executive's
-    fast path reads the pin from here so its cohort dispatches run
-    the SAME kernel the per-eval scheduler (and the conflict re-run)
-    would — a drift would compile a second program per shape bucket
-    and break executive-vs-worker parity."""
+    dense factories and host factories."""
     if not is_dense_factory(name):
         return None
     base = name[: -len("-tpu")]
@@ -94,8 +87,8 @@ def host_factory(name: str) -> str:
 
 class EvalSession:
     """Per-eval Planner (worker.go:285-483). One session per in-flight
-    eval so a worker can process a drained batch concurrently — the
-    Planner callbacks need the eval's own token, not worker state."""
+    eval so a pipeline batch's members run concurrently — the Planner
+    callbacks need the eval's own token, not worker state."""
 
     def __init__(self, worker: "Worker", ev: Evaluation, token: str):
         self.worker = worker
@@ -103,8 +96,8 @@ class EvalSession:
         self.eval = ev
         self.token = token
         # The dense kernel's in-batch conflict pre-resolution flag
-        # (scheduler/tpu.py reads it off its Planner): worker-drained
-        # batches share a snapshot exactly like pipeline batches do.
+        # (scheduler/tpu.py reads it off its Planner): a pipeline
+        # batch's members share one snapshot.
         self.pre_resolve = worker.server.config.dense_pre_resolve
 
     def submit_plan(self, plan: Plan) -> Tuple[PlanResult, Optional[object]]:
@@ -210,13 +203,8 @@ class Worker:
     def run(self) -> None:
         while not self._stop.is_set():
             self._check_paused()
-            executive = getattr(self.server, "executive", None)
-            if executive is not None and not executive.enabled:
-                executive = None
-            pipeline = getattr(self.server, "dispatch", None)
-            if (executive is not None and executive.saturated()) or (
-                    pipeline is not None and pipeline.enabled
-                    and pipeline.saturated()):
+            pipeline = self.server.dispatch
+            if pipeline.enabled and pipeline.saturated():
                 # Intake backpressure (nomad_tpu/admission): the
                 # central accumulator already holds two full batches.
                 # Draining more would only move backlog out of the
@@ -234,97 +222,34 @@ class Worker:
             if ev is None:
                 continue
             metrics.measure_since(("worker", "dequeue_eval"), start)
-            group = [(ev, token)]
             factory = self.server.config.factory_for(ev.type)
-            batch_max = self.server.config.eval_batch_size
-            if executive is not None and is_dense_factory(factory):
-                # Scheduler executive (server/executive.py): the worker
-                # is only the broker's long-poll seed — the executive
-                # owns the drain from here (bulk top-ups, array-side
-                # reconcile, one no-park cohort dispatch). The worker
-                # immediately returns to the broker for host-path work.
-                executive.submit(ev, token)
-                metrics.incr_counter(("worker", "executive_handoff"))
-                continue
-            pipeline = getattr(self.server, "dispatch", None)
-            if (pipeline is not None and pipeline.enabled
-                    and is_dense_factory(factory)):
+            if pipeline.enabled and is_dense_factory(factory):
                 # Central dispatch pipeline (nomad_tpu/dispatch): hand
-                # the eval to the leader-side accumulator instead of
-                # draining a per-worker slice — ONE drain packs full
-                # batches across all workers, submits run pipelined,
-                # and conflict retries rejoin the accumulating batch.
-                # This worker immediately returns to the broker for
-                # more (host-path evals keep flowing meanwhile).
+                # the eval to the leader-side accumulator — ONE drain
+                # packs full batches across all workers, submits run
+                # pipelined, and conflict retries rejoin the
+                # accumulating batch. This worker immediately returns
+                # to the broker for more (host-path evals keep flowing
+                # meanwhile).
                 pipeline.submit(ev, token)
                 metrics.incr_counter(("worker", "pipeline_handoff"))
                 continue
-            if batch_max > 1 and is_dense_factory(factory):
-                # Drain-to-batch: siblings of the same type ride one
-                # device dispatch. Non-blocking — whatever is ready now.
-                group.extend(
-                    self.server.eval_dequeue_many([ev.type], batch_max - 1)
-                )
-            if batch_max > 1 and is_dense_factory(factory) and routes_host(
-                    (e.priority for e, _ in group),
-                    self.server.config.dense_min_batch):
-                # (batch_max == 1 disables batching AND routing — an
-                # operator who turned draining off still gets the dense
-                # factory they configured, one eval per dispatch.)
-                # Latency-aware routing: too few evals to amortize the
-                # device dispatch — a lone interactive eval must not pay
-                # the batch-window + device RTT. The host factory has
-                # identical placement semantics (parity-tested).
-                factory = host_factory(factory)
-                metrics.incr_counter(("worker", "route_host"))
-            if len(group) == 1:
-                self._process_eval(ev, token, factory)
-            else:
-                metrics.add_sample(("worker", "eval_batch"), len(group))
-                # One MVCC snapshot for the whole drained batch: every
-                # member plans against the same cluster state, so their
-                # ClusterMatrix bases share one cache entry and one
-                # device upload (the batcher's overlay fast path needs
-                # matching base tokens). Per-eval snapshots would
-                # interleave with plan applies and fracture the batch
-                # into mixed-token dispatches. Optimistic concurrency
-                # makes this safe: the plan applier re-verifies every
-                # node and hands back RefreshIndex when stale
-                # (plan_apply.go:122-166).
-                snapshot = None
-                max_index = max(e.modify_index for e, _ in group)
-                if self._wait_for_index(max_index, timeout=5.0):
-                    snapshot = self.server.fsm.state.snapshot()
-                # Batch members run concurrently on the server's shared
-                # bounded pool (their place() calls coalesce in the
-                # batcher); the worker thread takes the first itself.
-                futures = [
-                    self.server.eval_pool.submit(
-                        self._process_eval, e, t, factory, snapshot)
-                    for e, t in group[1:]
-                ]
-                self._process_eval(ev, token, factory, snapshot)
-                for f in futures:
-                    # Bounded with a shutdown re-check: an unbounded
-                    # wait here pinned the worker thread to a wedged
-                    # batch member forever (ntalint unbounded-wait).
-                    while not f.wait(1.0) and not self._stop.is_set():
-                        pass
-                    if self._stop.is_set():
-                        break
+            # Host and system evals; and, with eval_batch_size <= 1, a
+            # dense eval too: an operator who turned batching off still
+            # gets the dense factory they configured, one eval per
+            # dispatch, no routing.
+            self._process_eval(ev, token, factory)
 
     def _process_eval(self, ev: Evaluation, token: str,
-                      factory: Optional[str] = None,
-                      snapshot=None) -> None:
+                      factory: str) -> None:
         start = time.monotonic()
-        if snapshot is None:
-            if not self._wait_for_index(ev.modify_index, timeout=5.0):
-                self._safe_nack(ev.id, token)
-                return
+        if not self._wait_for_index(ev.modify_index, timeout=5.0):
+            self._safe_nack(ev.id, token)
+            return
         metrics.measure_since(("worker", "wait_for_index"), start)
         start = time.monotonic()
         try:
-            self._invoke_scheduler(ev, token, factory, snapshot)
+            self._invoke_scheduler(ev, token, factory)
         except Exception:
             self.logger.exception("eval %s failed", ev.id)
             self._safe_nack(ev.id, token)
@@ -354,17 +279,13 @@ class Worker:
             base=BACKOFF_BASE, max_delay=BACKOFF_LIMIT)
 
     def _invoke_scheduler(self, ev: Evaluation, token: str,
-                          factory: Optional[str] = None,
-                          snapshot=None) -> None:
-        if snapshot is None:
-            snapshot = self.server.fsm.state.snapshot()
-        if factory is None:
-            factory = self.server.config.factory_for(ev.type)
+                          factory: str) -> None:
+        snapshot = self.server.fsm.state.snapshot()
         session = EvalSession(self, ev, token)
-        # Independent PRNG per eval: concurrent batch members must not
-        # share tie-break streams (duplicate streams would correlate
-        # their placements, spiking plan conflicts); seeding from the OS
-        # keeps this race-free across the batch threads.
+        # Independent PRNG per eval: concurrent evals must not share
+        # tie-break streams (duplicate streams would correlate their
+        # placements, spiking plan conflicts); seeding from the OS
+        # keeps this race-free across threads.
         rng = random.Random(int.from_bytes(os.urandom(8), "little"))
         sched = new_scheduler(factory, self.logger, snapshot, session, rng=rng)
         sched.process_eval(ev)

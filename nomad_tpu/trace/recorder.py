@@ -185,8 +185,8 @@ class _Stripe:
 
 class FlightRecorder:
     def __init__(self):
-        # Plain attribute read on every record call (the bench --no-trace
-        # arm and tests flip it); no lock — a racing record lands or
+        # Plain attribute read on every record call (tests flip it);
+        # no lock — a racing record lands or
         # not, either is fine.
         self.enabled = True
         self._stripes = [_Stripe() for _ in range(N_STRIPES)]
@@ -586,8 +586,8 @@ class FlightRecorder:
         self.enabled = bool(enabled)
 
     def reset(self) -> None:
-        """Drop all stored traces and histograms (bench A/B arms and
-        test isolation; not part of the record path)."""
+        """Drop all stored traces and histograms (test isolation;
+        not part of the record path)."""
         for stripe in self._stripes:
             with stripe.lock:
                 stripe.active.clear()

@@ -29,7 +29,8 @@ STAGE_BROKER_WAIT = "broker.wait"          # enqueue -> dequeue
 STAGE_DISPATCH_ACCUMULATE = "dispatch.accumulate"  # pipeline admit -> batch cut
 STAGE_DISPATCH_LAUNCH = "dispatch.launch"  # launch prologue (catch-up + snapshot)
 STAGE_DISPATCH_POOL_WAIT = "dispatch.pool_wait"  # launch fan-out ->
-#   the eval's stage thread running (the eval_pool hand-off)
+#   the eval's stage thread running (the pipeline's hand-off to
+#   its stage pool)
 STAGE_SCHED_PROCESS = "scheduler.process"  # scheduler invoke, end to end
 STAGE_MATRIX_BUILD = "matrix.build"        # ClusterMatrix + ask construction
 STAGE_MATRIX_UPDATE = "matrix.update"      # incremental delta vs full rebuild

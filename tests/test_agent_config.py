@@ -268,26 +268,19 @@ server {
         "service": "service-tpu", "batch": "batch-tpu"}
 
 
-def test_scheduler_executive_knobs(tmp_path):
-    """The scheduler-executive knobs parse from HCL and carry the
-    num_schedulers -> executive_threads split: with the executive on,
-    num_schedulers only sizes the host/system worker pool (README
-    'Scheduler executive' migration note)."""
-    from nomad_tpu.cli.agent_config import load_config
-
+@pytest.mark.parametrize("line", [
+    "scheduler_executive = true",
+    "executive_threads = 6",
+    "dispatch_pipeline = false",
+])
+def test_removed_dense_driver_keys_are_refused(tmp_path, line):
+    """There is one dense driver: the switches of the other two are
+    unknown keys like any typo, not silently accepted."""
     p = tmp_path / "a.hcl"
-    p.write_text('''
-server {
-  enabled = true
-  num_schedulers = 2
-  scheduler_executive = true
-  executive_threads = 6
-}
-''')
-    cfg = load_config(str(p))
-    assert cfg.server.scheduler_executive is True
-    assert cfg.server.executive_threads == 6
-    assert cfg.server.num_schedulers == 2
+    p.write_text("server {\n  enabled = true\n  %s\n}\n" % line)
+    key = "server." + line.split()[0]
+    with pytest.raises(ValueError, match="unknown config keys: " + key):
+        load_config(str(p))
 
 
 def test_overload_protection_knobs(tmp_path):

@@ -88,53 +88,6 @@ def test_concurrent_requests_share_one_dispatch():
         np.testing.assert_array_equal(results[i][0], np.asarray(direct_c))
 
 
-def test_place_cohort_matches_direct_program_and_never_parks():
-    """The scheduler executive's no-park entry point: a pre-formed
-    cohort dispatches inline on the calling thread — results match the
-    unbatched program per row, the cohort counter ticks, and no
-    dispatcher thread is ever spawned (thread count is flat across the
-    call: nothing to park, nothing to convoy)."""
-    batcher = PlacementBatcher(window=0.25)
-    reqs = []
-    for i in range(6):
-        state, asks, key = tiny_inputs(seed=100 + i)
-        reqs.append((state, asks, key, CONFIG, None))
-    before_threads = threading.active_count()
-    results = batcher.place_cohort(reqs)
-    assert threading.active_count() <= before_threads
-    assert len(results) == 6
-    for (state, asks, key, _c, _s), (choices, scores) in zip(reqs, results):
-        direct_c, direct_s, _ = placement_program_jit(
-            state, asks, key, CONFIG)
-        np.testing.assert_array_equal(np.asarray(choices),
-                                      np.asarray(direct_c))
-        np.testing.assert_allclose(np.asarray(scores),
-                                   np.asarray(direct_s), rtol=1e-5)
-    stats = batcher.stats()
-    assert stats["cohort_dispatches"] >= 1
-    assert stats["batched_requests"] == 6
-    # One shape -> one dispatch for the whole cohort.
-    assert stats["dispatches"] == 1
-
-
-def test_place_cohort_groups_mixed_shapes():
-    """Mixed ask shapes cannot share one program: the cohort splits by
-    the same shape key place() computes, one inline dispatch each."""
-    batcher = PlacementBatcher(window=0.25)
-    s1, a1, k1 = tiny_inputs(seed=1)
-    s2, a2, k2 = tiny_inputs(n=64, k=4, seed=2)
-    results = batcher.place_cohort([
-        (s1, a1, k1, CONFIG, None), (s2, a2, k2, CONFIG, None),
-        (s1, a1, k1, CONFIG, None)])
-    assert len(results) == 3
-    assert batcher.stats()["dispatches"] == 2
-    for (state, asks, key), (choices, _sc) in zip(
-            ((s1, a1, k1), (s2, a2, k2), (s1, a1, k1)), results):
-        direct_c, _ds, _ = placement_program_jit(state, asks, key, CONFIG)
-        np.testing.assert_array_equal(np.asarray(choices),
-                                      np.asarray(direct_c))
-
-
 def test_mixed_shapes_do_not_batch_together():
     batcher = PlacementBatcher(window=0.05)
     out = {}

@@ -264,129 +264,6 @@ def test_chaos_soak_randomized_wide():
     _run_soak(seed=20260803, n_jobs=24, schedule=schedule, flaps=2)
 
 
-def test_chaos_soak_executive_fixed_seed():
-    """The chaos soak rerun with the scheduler executive on (PR 12):
-    the same fault families — delivery drops, crash-held unacked evals,
-    a forced device fault (cohort host fallback), submit failure, plus
-    a leader flap that drains the executive's accumulated leases —
-    against the cohort drain instead of the worker/pipeline fan-out.
-    Invariants unchanged: exactly-once terminals, no duplicate
-    placements, and the drain thread stays live (the liveness roster
-    read from the EXECUTIVE module's extended ntalint manifest)."""
-    schedule = [
-        FaultSpec("broker.deliver", "drop", prob=0.3, count=8),
-        FaultSpec("dispatch.finish", "drop", count=2),
-        FaultSpec("dispatch.submit", "error", count=1),
-        FaultSpec("binpack.device", "error", count=1),
-    ]
-    # Nobody heartbeats mock nodes: on a slow host the default ~20s
-    # TTL+grace marks the whole cluster down mid-soak and the
-    # resulting node-update eval flood unplaces everything — that
-    # failure mode belongs to the heartbeat tests, not this one.
-    server = make_server(scheduler_executive=True,
-                         min_heartbeat_ttl=600.0)
-    try:
-        seed_nodes(server)
-
-        # Phase A (clean): warm the cohort programs.
-        jobs_a = run_storm(server, 12, "xclean")
-        settle_executive(server, jobs_a)
-
-        # Phase B (faulted) + a leader flap mid-storm. The flap waits
-        # for the storm's first DEVICE dispatch (the seeded device
-        # fault firing proves it): flapping earlier can drain the
-        # cohort before it ever reaches the device, and the restore's
-        # straggler redeliveries then trickle through the host router
-        # — the schedule's device spec would deterministically never
-        # fire and the soak would prove nothing about that site.
-        chaos.arm(4242, schedule)
-        jobs_b = run_storm(server, 12, "xchaos")
-        from nomad_tpu.scheduler.batcher import get_batcher
-
-        if not wait_until(
-                lambda: any(s == "binpack.device"
-                            for s, _n, _k, _d in chaos.firing_log()),
-                60.0):
-            import sys as _sys
-
-            from nomad_tpu.admission import get_breaker
-
-            print("FIRING:", chaos.firing_log(), file=_sys.stderr)
-            print("EXEC:", server.executive.stats(), file=_sys.stderr)
-            print("BATCHER:", get_batcher().stats(), file=_sys.stderr)
-            print("BREAKER:", get_breaker().state(),
-                  get_breaker().stats(), get_breaker().transitions(),
-                  file=_sys.stderr)
-            raise AssertionError("binpack.device never fired")
-        server.revoke_leadership()  # drains the executive's pending
-        time.sleep(0.15)
-        server.establish_leadership()  # re-seeds from raft state
-        settle_executive(server, jobs_b)
-        fired = chaos.firing_log()
-        unfired = chaos.unfired()
-        chaos.disarm()
-        assert fired, "no faults fired"
-        assert not unfired, [s.to_dict() for s in unfired]
-        sites = {s for s, _n, _k, _d in fired}
-        assert "binpack.device" in sites  # cohort host fallback forced
-        ex = server.executive.stats()
-        assert ex["host_fallbacks"] >= 1 or ex["legacy_evals"] >= 1, ex
-
-        # Phase C (probe): cohorts still pack post-fault.
-        mid = server.executive.stats()
-        jobs_c = run_storm(server, 12, "xprobe")
-        settle_executive(server, jobs_c)
-        post = server.executive.stats()
-        probe_cohorts = post["cohorts"] - mid["cohorts"]
-        probe_evals = post["cohort_evals"] - mid["cohort_evals"]
-        assert probe_cohorts <= 4, (mid, post)
-        assert probe_evals / max(probe_cohorts, 1) >= 4.0, (mid, post)
-
-        assert_invariants(server, jobs_a + jobs_b + jobs_c)
-        # Liveness roster from the executive's extended manifest.
-        from nomad_tpu.server.executive import (
-            NTA_DISPATCHER_ENTRYPOINTS as EXEC_ENTRYPOINTS,
-        )
-
-        assert EXEC_ENTRYPOINTS
-        for entry in EXEC_ENTRYPOINTS:
-            cls_name, _meth = entry.split(".")
-            assert cls_name == "SchedulerExecutive", entry
-            thread = server.executive._thread
-            assert thread is not None and thread.is_alive(), (
-                f"executive drain thread for {entry} stalled/died")
-    finally:
-        chaos.disarm()
-        server.shutdown()
-
-
-def settle_executive(server, jobs, count=5, timeout=120.0):
-    """settle() for the executive server: broker drained, executive
-    pending empty, placements whole."""
-    assert wait_until(
-        lambda: all(
-            len([a for a in server.fsm.state.allocs_by_job(j.id)
-                 if not a.terminal_status()]) == count
-            for j in jobs),
-        timeout), (
-            {j.id: Counter(
-                (a.name, a.desired_status, a.client_status)
-                for a in server.fsm.state.allocs_by_job(j.id)
-                if not a.terminal_status())
-             for j in jobs
-             if len([a for a in server.fsm.state.allocs_by_job(j.id)
-                     if not a.terminal_status()]) != count},
-            Counter((e.status, e.triggered_by)
-                    for e in server.fsm.state.evals()),
-            server.broker.stats(),
-            server.executive.stats())
-    assert wait_until(
-        lambda: (server.broker.ready_count() == 0
-                 and server.broker.unacked_count() == 0
-                 and server.executive.pending_count() == 0),
-        timeout), (server.broker.stats(), server.executive.stats())
-
-
 # ---------------------------------------------------------------------
 # registry determinism + guards
 

@@ -4,7 +4,7 @@ jit-registry introspection test (the static NTA_JIT_ACCOUNTED manifest,
 the AST scan of ops//kernels//models//parallel/, and the runtime
 jit_cache_size() registry must agree), the real-tree self-checks (all
 four rules clean with an EMPTY baseline — findings there are fixed,
-never baselined), and the bench.py --check gate wiring.
+never baselined).
 
 Fixture sets per rule are analyzed in separate directories: an
 NTA_JIT_ACCOUNTED manifest anywhere in an analyzed set arms
@@ -504,31 +504,6 @@ def test_jit_manifest_matches_runtime_cache_accounting():
     assert callable(shard.shard_cache_size)
     # and jit_cache_size() composes both accountings without devices.
     assert binpack.jit_cache_size() >= 0
-
-
-# ---------------------------------------------------------------------
-# bench --check wiring: the compile-surface gate runs FIRST.
-
-
-def test_bench_compile_surface_gate_wired_and_clean():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_cs_gate_probe", os.path.join(REPO, "bench.py"))
-    bench_mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_mod)
-    assert bench_mod.COMPILE_SURFACE_GATE_DIRS == (
-        "nomad_tpu/ops/", "nomad_tpu/kernels/",
-        "nomad_tpu/models/", "nomad_tpu/parallel/")
-    assert bench_mod.ntalint_compile_surface_gate() == []
-    # The gate must run before device warmup: its invocation precedes
-    # the purity gate's inside the --check block.
-    with open(os.path.join(REPO, "bench.py"), "r",
-              encoding="utf-8") as fh:
-        src = fh.read()
-    assert src.index("ntalint_compile_surface_gate()",
-                     src.index("if args.check:")) < src.index(
-        "ntalint_purity_gate()", src.index("if args.check:"))
 
 
 # ---------------------------------------------------------------------

@@ -195,7 +195,7 @@ def test_steady_state_solver_compiles_stay_flat():
                             warm=warm)
     assert solve_cache_size() == programs  # steady state: FLAT
     # ... and the placement path's jit accounting sees the defrag
-    # programs (a shape leak here must move the bench recompile gate).
+    # programs (a shape leak here must move jit_cache_size).
     from nomad_tpu.ops.binpack import jit_cache_size
 
     assert jit_cache_size() >= programs
@@ -297,15 +297,6 @@ def test_defrag_eval_is_budget_exempt_but_drains_still_claim():
             assert stored.desired_status == consts.ALLOC_DESIRED_STOP
     finally:
         migrate_configure(migrate_max_parallel=32)
-
-
-def test_wave_eval_routes_to_legacy_lane_under_executive():
-    """defrag-migration is NOT a cohort-fast trigger: the executive's
-    array path must route it to the per-eval scheduler whose migrate
-    leg owns the semantics."""
-    from nomad_tpu.scheduler.util import COHORT_FAST_TRIGGERS
-
-    assert consts.EVAL_TRIGGER_DEFRAG not in COHORT_FAST_TRIGGERS
 
 
 def test_defrag_eval_fields_survive_wire_roundtrip():
@@ -689,6 +680,98 @@ def test_live_server_defrag_loop_end_to_end():
         # warm solves measurably cheaper than the cold first solve
         assert st["warm_solves"] >= 1 and st["cold_solves"] >= 1
         assert st["min_warm_solve_ms"] < st["first_cold_solve_ms"]
+    finally:
+        server.shutdown()
+
+
+def test_wave_evals_ride_the_dispatch_pipeline_on_a_live_dense_server():
+    """A wave's evals on a dense server: handed to the pipeline as one
+    batch, each stops exactly the allocation its move marks and places
+    the replacement; nothing goes to the host route. The moves are
+    made by hand, so the layout the scheduler happened to choose does
+    not decide whether a wave exists."""
+    from nomad_tpu.defrag.solver import Move
+    from nomad_tpu.scheduler.util import ALLOC_MIGRATING
+    from nomad_tpu.server import Server
+    from nomad_tpu.server.worker import DEQUEUE_TIMEOUT
+
+    def wait_until(fn, timeout=90.0):
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            if fn():
+                return True
+            time.sleep(0.02)
+        return False
+
+    def storm(register):
+        """Run `register` against parked workers, release them at once."""
+        for w in server.workers:
+            w.set_pause(True)
+        time.sleep(DEQUEUE_TIMEOUT + 0.3)
+        ready = server.broker.ready_count()
+        eval_ids = register()
+        assert wait_until(
+            lambda: server.broker.ready_count() >= ready + len(eval_ids),
+            15.0)
+        for w in server.workers:
+            w.set_pause(False)
+        state = server.fsm.state
+        assert wait_until(lambda: all(
+            (ev := state.eval_by_id(e)) is not None
+            and ev.terminal_status() for e in eval_ids)), [
+            getattr(state.eval_by_id(e), "status", None) for e in eval_ids]
+
+    server = Server(ServerConfig(
+        num_schedulers=2, scheduler_factories={"service": "service-tpu"}))
+    server.start()
+    try:
+        nodes = []
+        for _ in range(12):
+            node = mock.node()
+            node.compute_class()
+            server.node_register(node)
+            nodes.append(node)
+        jobs = [_mkjob(f"wave{j}", 5, 100, 64) for j in range(3)]
+        for job in jobs:
+            job.type = "service"
+        storm(lambda: [server.job_register(job)[0] for job in jobs])
+        state = server.fsm.state
+
+        def live(job):
+            return [a for a in state.allocs_by_job(job.id)
+                    if not a.terminal_status()]
+
+        assert all(len(live(job)) == 5 for job in jobs)
+        moves = []
+        for job in jobs[:2]:
+            victim = live(job)[0]
+            taken = {a.node_id for a in live(job)}
+            target = next(n.id for n in nodes if n.id not in taken)
+            moves.append(Move(victim.id, job.id, victim.node_id, target,
+                              0.01))
+        routed = server.dispatch.stats()["routed_host"]
+        batches = server.dispatch.stats()["batches"]
+        wave = build_wave_evals(state.snapshot(), moves)
+        assert len(wave) == 2
+
+        def submit():
+            server.eval_update(wave)
+            return [ev.id for ev in wave]
+
+        storm(submit)
+        stats = server.dispatch.stats()
+        assert stats["routed_host"] == routed, stats
+        assert stats["batches"] > batches, stats
+        for mv in moves:
+            moved = state.alloc_by_id(mv.alloc_id)
+            assert moved.desired_status == consts.ALLOC_DESIRED_STOP
+            assert moved.desired_description == ALLOC_MIGRATING
+        for job in jobs:
+            assert len(live(job)) == 5
+        marked = {mv.alloc_id for mv in moves}
+        stopped = {a.id for job in jobs for a in state.allocs_by_job(job.id)
+                   if a.desired_status == consts.ALLOC_DESIRED_STOP}
+        assert stopped == marked  # exactly the wave's, exactly once
     finally:
         server.shutdown()
 

@@ -210,8 +210,8 @@ def test_exec_driver_rejects_task_config_chroot_env():
 
 
 def test_disk_used_ignores_task_written_manifest(tmp_path):
-    """ADVICE r5 (medium): the disk watcher must not trust ANY file the
-    task can write. A task forging an embed manifest inside its own dir
+    """An advisor finding of round 5: the disk watcher must not trust
+    ANY file the task can write. A task forging an embed manifest inside its own dir
     (the pre-fix mechanism) gets charged anyway — only the agent's own
     embed_chroot registration prunes."""
     import json

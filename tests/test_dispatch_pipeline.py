@@ -142,7 +142,9 @@ def test_lone_eval_routes_host_and_pipeline_counts_it():
 # in-batch conflict pre-resolution (device-side eval-axis scan)
 
 
-def _shared_batch_inputs(n, k, g, b, node_cpu=1000.0, ask_cpu=400.0):
+def _shared_batch_inputs(n, k, g, b, node_cpu=1000.0, ask_cpu=400.0,
+                         ask_mem=64.0, bw=10.0, ports=1.0,
+                         distinct_hosts=False):
     from nomad_tpu.ops.binpack import make_asks, make_node_state
 
     state = make_node_state(
@@ -158,18 +160,37 @@ def _shared_batch_inputs(n, k, g, b, node_cpu=1000.0, ask_cpu=400.0):
         node_ok=np.ones(n, bool),
     )
     asks = make_asks(
-        resources=np.tile([ask_cpu, 64, 100, 0], (b, k, 1)),
-        bw=np.full((b, k), 10.0),
-        ports=np.full((b, k), 1.0),
+        resources=np.tile([ask_cpu, ask_mem, 100, 0], (b, k, 1)),
+        bw=np.full((b, k), bw),
+        ports=np.full((b, k), ports),
         tg_index=np.zeros((b, k), np.int32),
         active=np.ones((b, k), bool),
-        job_distinct_hosts=np.zeros(b, bool),
+        job_distinct_hosts=np.full(b, distinct_hosts, bool),
         tg_distinct_hosts=np.zeros((b, g), bool),
     )
     return state, asks
 
 
-def test_pre_resolve_parity_vs_serial_placement():
+# The default mock shape, then the job shapes the non-preempting cells
+# send (benchmark/configs/*.json), each as the dense scheduler builds
+# its config (penalty by job type, uniform_dh where one task group is
+# under distinct_hosts): northstar's count=8 with two dynamic ports,
+# 50 Mbit and distinct_hosts; c1m's batch container at a count tier-1
+# can afford; borg's `fill`.
+PARITY_SHAPES = {
+    "mock": dict(b=6, n=16, k=4, penalty=10.0),
+    "northstar": dict(b=6, n=32, k=8, penalty=10.0, node_cpu=4000.0,
+                      ask_cpu=20.0, ask_mem=16.0, bw=50.0, ports=2.0,
+                      distinct_hosts=True),
+    "c1m": dict(b=3, n=16, k=64, penalty=5.0, node_cpu=4000.0,
+                ask_cpu=19.0, ask_mem=32.0, bw=0.0, ports=0.0),
+    "borg-fill": dict(b=4, n=16, k=24, penalty=5.0, node_cpu=4000.0,
+                      ask_cpu=100.0, ask_mem=128.0, bw=0.0, ports=0.0),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(PARITY_SHAPES))
+def test_pre_resolve_parity_vs_serial_placement(shape):
     """The device-side eval-axis scan must equal placing the evals one
     at a time while carrying the shared capacity state host-side — the
     exact serialization the plan applier would impose."""
@@ -181,16 +202,23 @@ def test_pre_resolve_parity_vs_serial_placement():
         batched_placement_program_overlay,
         host_prng_key,
         placement_program_jit,
+        uniform_dh_flag,
     )
 
-    b, n, k, g = 6, 16, 4, 1
-    state, asks = _shared_batch_inputs(n, k, g, b)
+    spec = dict(PARITY_SHAPES[shape])
+    b, n, k, g = spec.pop("b"), spec.pop("n"), spec.pop("k"), 1
+    penalty = spec.pop("penalty")
+    state, asks = _shared_batch_inputs(n, k, g, b, **spec)
     keys = np.stack([host_prng_key(i) for i in range(b)])
-    cfg = PlacementConfig(anti_affinity_penalty=10.0, pre_resolve=True)
+    cfg = PlacementConfig(
+        anti_affinity_penalty=penalty, pre_resolve=True,
+        uniform_dh=uniform_dh_flag(
+            [0] * k, spec.get("distinct_hosts", False), [False]))
 
     choices, scores, _ = batched_placement_program_overlay(
         state, asks, keys, cfg)
     choices, scores = np.asarray(choices), np.asarray(scores)
+    assert (choices >= 0).all()  # every shape fits its cluster
 
     util, bw, pf = state.util, state.bw_used, state.ports_free
     serial_choices, serial_scores = [], []
@@ -210,6 +238,9 @@ def test_pre_resolve_parity_vs_serial_placement():
         serial_scores.append(np.asarray(sc))
     assert (choices == np.stack(serial_choices)).all()
     assert np.allclose(scores, np.stack(serial_scores))
+    if spec.get("distinct_hosts"):
+        for row in choices:
+            assert len(set(row.tolist())) == k
 
 
 def test_pre_resolve_eliminates_in_batch_overcommit():
@@ -628,5 +659,163 @@ def test_pipeline_drops_expired_evals_before_matrix_build():
         assert state.allocs_by_job("live-job")
         # Leases released: nothing left unacked, nothing re-delivers.
         assert wait_until(lambda: server.broker.unacked_count() == 0, 5.0)
+    finally:
+        server.shutdown()
+
+
+# ---------------------------------------------------------------------
+# the one dense driver: what a dense BATCH must do beyond pure placement
+# (a destructive update, exhaustion into a blocked eval, a device fault)
+# and what is left for the worker when the operator turns batching off
+
+
+def _sized_job(jid, count=5, cpu=20, mem=16):
+    job = mock.job()
+    job.id = jid
+    job.task_groups[0].count = count
+    res = job.task_groups[0].tasks[0].resources
+    res.cpu, res.memory_mb, res.networks = cpu, mem, []
+    return job
+
+
+def _storm(server, jobs):
+    """Register `jobs` against parked workers and release them at once,
+    so they reach the pipeline as ONE batch (at least dense_min_batch:
+    the dense path, not the host route). Returns the eval ids."""
+    quiesce(server)
+    evals = [server.job_register(job)[0] for job in jobs]
+    assert wait_until(
+        lambda: server.broker.ready_count() >= len(jobs), 15.0)
+    for w in server.workers:
+        w.set_pause(False)
+    return evals
+
+
+def _settle(server, evals, timeout=120.0):
+    state = server.fsm.state
+
+    def done():
+        got = [state.eval_by_id(e) for e in evals]
+        return all(e is not None and e.terminal_status() for e in got)
+
+    assert wait_until(done, timeout), {
+        e: getattr(state.eval_by_id(e), "status", None) for e in evals}
+
+
+def _live(server, job_id):
+    return [a for a in server.fsm.state.allocs_by_job(job_id)
+            if not a.terminal_status()]
+
+
+def _host_fallbacks() -> float:
+    from nomad_tpu.utils.metrics import format_prometheus
+
+    for line in format_prometheus().splitlines():
+        name, _, value = line.partition(" ")
+        if name.endswith("_scheduler_host_fallback_total"):
+            return float(value)
+    return 0.0
+
+
+def test_dense_batch_commits_destructive_update_and_stops_old_allocs():
+    from nomad_tpu.scheduler.batcher import get_batcher
+
+    server = make_server(num_schedulers=2)
+    try:
+        seed_nodes(server, 8)
+        # count > 3: fewer asks and the dense scheduler itself hands
+        # them to the host iterators
+        jobs = [_sized_job(f"upd-{i}", count=5) for i in range(4)]
+        _settle(server, _storm(server, jobs))
+        old = {j.id: {a.id for a in _live(server, j.id)} for j in jobs}
+        assert all(len(ids) == 5 for ids in old.values())
+        routed = server.dispatch.stats()["routed_host"]
+        served = get_batcher().batched_requests
+        # More cpu a task: the diff has an update bucket, every old
+        # allocation must stop and a new one take its place.
+        updates = [_sized_job(j.id, count=5, cpu=30) for j in jobs]
+        _settle(server, _storm(server, updates))
+        assert server.dispatch.stats()["routed_host"] == routed
+        assert get_batcher().batched_requests > served
+        for job in jobs:
+            live = _live(server, job.id)
+            assert len(live) == 5
+            assert not {a.id for a in live} & old[job.id]
+            assert all(res.cpu == 30 for a in live
+                       for res in a.task_resources.values())
+            for alloc_id in old[job.id]:
+                gone = server.fsm.state.alloc_by_id(alloc_id)
+                assert gone.desired_status == consts.ALLOC_DESIRED_STOP
+    finally:
+        server.shutdown()
+
+
+def test_dense_batch_exhaustion_blocks_then_unblocks_on_new_nodes():
+    server = make_server(num_schedulers=2)
+    try:
+        seed_nodes(server, 2, cpu=100, mem=256)
+        # 2 jobs x 8 allocations x 30 cpu do not fit two tiny nodes.
+        jobs = [_sized_job(f"blk-{i}", count=8, cpu=30) for i in range(2)]
+        _settle(server, _storm(server, jobs))
+        blocked = [e for e in server.fsm.state.evals()
+                   if e.status == consts.EVAL_STATUS_BLOCKED]
+        assert {e.job_id for e in blocked} == {j.id for j in jobs}, [
+            (e.job_id, e.status, e.triggered_by)
+            for e in server.fsm.state.evals()]
+        seed_nodes(server, 6)  # capacity arrives
+        assert wait_until(
+            lambda: all(len(_live(server, j.id)) == 8 for j in jobs),
+            90.0), {j.id: len(_live(server, j.id)) for j in jobs}
+    finally:
+        server.shutdown()
+
+
+def test_device_fault_in_a_dense_batch_falls_back_to_the_host_path():
+    from nomad_tpu.admission import get_breaker
+    from nomad_tpu.chaos import FaultSpec, chaos
+
+    server = make_server(num_schedulers=2)
+    try:
+        seed_nodes(server, 8)
+        warm = [_sized_job(f"warm-{i}") for i in range(4)]
+        _settle(server, _storm(server, warm))
+        before = _host_fallbacks()
+        chaos.arm(7, [FaultSpec("binpack.device", "error", count=1)])
+        jobs = [_sized_job(f"faulted-{i}") for i in range(6)]
+        _settle(server, _storm(server, jobs))
+        fired = chaos.firing_log()
+        chaos.disarm()
+        assert any(site == "binpack.device" for site, _n, _k, _d in fired)
+        assert _host_fallbacks() - before >= 1
+        for job in jobs:
+            assert len(_live(server, job.id)) == 5
+    finally:
+        chaos.disarm()
+        breaker = get_breaker()
+        breaker.reset()
+        breaker.configure_defaults()
+        server.shutdown()
+
+
+def test_batching_off_worker_runs_dense_eval_on_its_dense_factory():
+    """eval_batch_size=1 is the operator turning batching off: the
+    pipeline stands down and the worker runs each dense eval itself on
+    the dense factory configured, one eval a dispatch, no host
+    routing."""
+    from nomad_tpu.scheduler.batcher import get_batcher
+
+    server = make_server(num_schedulers=1, eval_batch_size=1)
+    try:
+        assert server.dispatch.enabled is False
+        assert server.dispatch.stats()["enabled"] is False
+        seed_nodes(server, 8)
+        served = get_batcher().batched_requests
+        job = _sized_job("alone", count=5)
+        ev, _ = server.job_register(job)
+        _settle(server, [ev])
+        assert len(_live(server, job.id)) == 5
+        assert get_batcher().batched_requests == served + 1
+        stats = server.dispatch.stats()
+        assert stats["batches"] == 0 and stats["routed_host"] == 0, stats
     finally:
         server.shutdown()

@@ -11,7 +11,11 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.scheduler.batcher import get_batcher
 from nomad_tpu.server import Server, ServerConfig
-from nomad_tpu.server.worker import host_factory, is_dense_factory
+from nomad_tpu.server.worker import (
+    host_factory,
+    is_dense_factory,
+    routes_host,
+)
 
 
 def wait_until(fn, timeout=30.0, interval=0.02):
@@ -53,6 +57,40 @@ def test_host_factory_mapping():
     assert host_factory("service-convex-tpu") == "service"
     assert host_factory("batch-greedy-tpu") == "batch"
     assert is_dense_factory("service-convex-tpu")
+
+
+# (priorities of the batch, dense_min_batch, preemption on) -> host?
+# Threshold 50: priority 70 may preempt, 50 may not (strictly above).
+ROUTES_HOST_TABLE = {
+    "below_min_batch": ([50], 2, False, True),
+    "at_min_batch": ([50, 50], 2, False, False),
+    "min_batch_one_forces_dense": ([50], 1, False, False),
+    "empty_batch_below_min": ([], 2, False, True),
+    "none_eligible": ([50], 2, True, True),
+    "one_eligible_stays_dense": ([50, 70, 50], 4, True, False),
+    "all_eligible_stay_dense": ([70], 2, True, False),
+    "eligible_priority_but_preemption_off": ([70], 2, False, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTES_HOST_TABLE))
+def test_routes_host_truth_table(case):
+    """The dispatch pipeline's one routing rule: a batch under
+    dense_min_batch goes to the host factories unless one of its evals
+    may preempt (the host iterators cannot evict)."""
+    from nomad_tpu import migrate
+
+    priorities, min_batch, preempt_on, want = ROUTES_HOST_TABLE[case]
+    before = migrate.preempt_stats()
+    migrate.configure(preemption_enabled=preempt_on,
+                      preempt_priority_threshold=50)
+    try:
+        # a generator, as the pipeline passes it
+        assert routes_host((p for p in priorities), min_batch) is want
+    finally:
+        migrate.configure(
+            preemption_enabled=before["enabled"],
+            preempt_priority_threshold=before["priority_threshold"])
 
 
 def test_tpu_suffix_fallback_registers_lazily():

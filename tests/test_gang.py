@@ -13,9 +13,8 @@ The contract under test, end to end:
   the host iterator path, with parity on hand-built topologies;
 - losing any member replaces the WHOLE gang (survivors stopped, all K
   re-placed), a gang that cannot place blocks as ONE eval and
-  unblocks when capacity arrives, the executive routes gang evals to
-  the per-eval scheduler (one cohort row with K asks, never K rows),
-  and the gang leg joins the placement path's jit-cache accounting
+  unblocks when capacity arrives, a gang in a pipeline batch places
+  on the device as one eval with K asks, and the gang leg joins the placement path's jit-cache accounting
   (steady-state recompiles 0);
 - chaos sites ``gang.partial_commit`` / ``gang.member_lost`` are
   registered, deterministic, documented, and drive the invariants
@@ -844,57 +843,36 @@ def test_blocked_gang_unblocks_and_places_when_capacity_arrives():
 
 
 # ---------------------------------------------------------------------
-# executive cohort routing: a gang is ONE row with K asks
+# dispatch pipeline: a gang in a dense batch is ONE eval with K asks
 
 
-def test_cohort_reconcile_routes_gang_to_legacy_lane():
-    from nomad_tpu.scheduler.util import cohort_reconcile
-
-    nodes = topo_nodes(n=4)
-    job = gang_job(k=4, slice="rack")
-    h = seeded_harness(nodes, job)
-    ev = new_eval(h.state.job_by_id(job.id),
-                  consts.EVAL_TRIGGER_JOB_REGISTER)
-    (member,) = cohort_reconcile(h.state.snapshot(), [ev])
-    assert member.reason == "gang task group"
-    plain = mock.job()
-    h.state.upsert_job(h.next_index(), plain)
-    (m2,) = cohort_reconcile(
-        h.state.snapshot(),
-        [new_eval(plain, consts.EVAL_TRIGGER_JOB_REGISTER)])
-    assert not m2.reason  # plain jobs stay on the cohort fast path
-
-
-def test_executive_places_gang_atomically():
+def test_pipeline_places_gang_atomically():
     import time as _time
 
+    from nomad_tpu.gang import gang_stats, reset_gang_stats
     from nomad_tpu.server import Server, ServerConfig
     from nomad_tpu.server.worker import DEQUEUE_TIMEOUT
 
     server = Server(ServerConfig(
         num_schedulers=2,
         scheduler_factories={"service": "service-tpu"},
-        scheduler_executive=True,
-        executive_threads=2,
         eval_nack_timeout=5.0))
     server.start()
     try:
         nodes = topo_nodes(n=8, rack_size=4)
         for node in nodes:
             server.node_register(node)
-        # quiesce so the eval drains through the EXECUTIVE's cohort
-        # path (not a worker's direct handoff window)
+        # quiesce so the evals reach the pipeline as ONE batch
         for w in server.workers:
             w.set_pause(True)
-        server.executive.set_pause(True)
         deadline = _time.monotonic() + 4 * DEQUEUE_TIMEOUT + 30.0
-        while _time.monotonic() < deadline and not (
-                all(w.parked() for w in server.workers)
-                and server.executive.parked()):
+        while _time.monotonic() < deadline and not all(
+                w.parked() for w in server.workers):
             _time.sleep(0.02)
-        # a gang job AND plain jobs: the cohort clears dense_min_batch
-        # so the executive's array-reconcile actually classifies it
-        # (a singleton batch short-circuits to the host route)
+        reset_gang_stats()
+        # a gang job AND plain jobs: the batch clears dense_min_batch,
+        # so the gang rides the dense path (a singleton batch
+        # short-circuits to the host route)
         job = gang_job(k=4, slice="rack")
         ev, _ = server.job_register(job)
         evals = [ev]
@@ -912,7 +890,6 @@ def test_executive_places_gang_atomically():
             _time.sleep(0.02)
         for w in server.workers:
             w.set_pause(False)
-        server.executive.set_pause(False)
 
         def done():
             evs = [state.eval_by_id(e) for e in evals]
@@ -928,8 +905,10 @@ def test_executive_places_gang_atomically():
         assert len(live) == 4
         by_id = {n.id: n for n in nodes}
         assert len({by_id[a.node_id].meta["rack"] for a in live}) == 1
-        st = server.executive.stats()
-        assert st["legacy_reasons"].get("gang task group", 0) >= 1
+        stats = gang_stats()
+        assert stats.get("path_device", 0) >= 1, stats
+        assert stats.get("members_placed", 0) == 4, stats
+        assert server.dispatch.stats()["routed_host"] == 0
     finally:
         server.shutdown()
 

@@ -23,9 +23,6 @@ stamping + device-path breaker):
 Protection OFF: the same storm grows the broker monotonically past the
 ON arm's bound with zero sheds — the unbounded behaviour this PR
 removes by default-config choice, kept reachable for the A/B.
-
-`bench.py --overload` reports the same A/B quantitatively
-(BENCH_r09.json: shed_rate, goodput, accepted-eval p99).
 """
 
 import random
@@ -298,103 +295,6 @@ def test_overload_soak_protection_on():
         assert ("half-open", "closed") in arcs[i_half:]
 
         assert_dispatcher_live(server)
-    finally:
-        chaos.disarm()
-        server.shutdown()
-
-
-def test_overload_soak_executive_on():
-    """The overload soak rerun with the scheduler executive on
-    (PR 12): the bounded service queue + priority-aware shedding +
-    deadline stamping protect the cohort drain exactly as they did the
-    worker fan-out — every shed eval reaches its structured terminal
-    exactly once, accepted work completes, the seeded device fault
-    trips the breaker through the COHORT host-fallback leg
-    (record_failure on place_cohort), and the drain thread stays live
-    (roster read from the executive's extended manifest)."""
-    rng = random.Random(SOAK_SEED + 1)
-    server = make_server(
-        scheduler_executive=True,
-        eval_ready_cap=0,
-        eval_ready_caps={"service": CAP},
-        eval_deadline_ttl=60.0,
-        breaker_failure_threshold=1,
-        breaker_cooldown=0.6,
-        # Mock nodes never heartbeat; a slow host must not let the
-        # ~20s TTL+grace mark the cluster down mid-soak.
-        min_heartbeat_ttl=600.0,
-    )
-    try:
-        seed_nodes(server)
-
-        warm = submit_storm(server, CAP, "xwarm")
-        run_to_terminal(server, warm)
-
-        # Overload: 3x-capacity burst against the parked drain.
-        storm = submit_storm(server, STORM, "xstorm", rng=rng)
-        bstats = server.broker.stats()
-        assert bstats["total_ready"] <= CAP
-        assert bstats["shed"] == STORM - CAP
-        snap = server.admission.pressure.snapshot(refresh=True)
-        assert snap["level"] == "red", snap
-
-        run_to_terminal(server, storm)
-        state = server.fsm.state
-        evs = [state.eval_by_id(e) for e in storm]
-        assert all(e is not None and e.terminal_status() for e in evs)
-        statuses = Counter(e.id for e in state.evals())
-        assert all(c == 1 for c in statuses.values())
-        shed = [e for e in evs
-                if e.triggered_by == consts.EVAL_TRIGGER_SHED]
-        accepted = [e for e in evs
-                    if e.triggered_by != consts.EVAL_TRIGGER_SHED]
-        assert len(shed) == STORM - CAP and len(accepted) == CAP
-        for e in shed:
-            assert e.status == consts.EVAL_STATUS_FAILED
-            assert "shed" in e.status_description
-        for e in accepted:
-            assert e.status == consts.EVAL_STATUS_COMPLETE, (
-                e.id, e.status, e.status_description)
-        assert (min(storm[e.id] for e in accepted)
-                >= max(storm[e.id] for e in shed))
-
-        # Breaker leg: the injected device fault fails the COHORT
-        # dispatch; the executive falls the whole cohort back to the
-        # host path and the breaker counts one failure (K=1 trips).
-        breaker = get_breaker()
-        assert breaker.state() == "closed"
-        chaos.arm(SOAK_SEED, [
-            FaultSpec("binpack.device", "error", count=1),
-            FaultSpec("admission.slow_consumer", "delay", delay=0.05,
-                      count=2),
-        ])
-        trip_storm = submit_storm(server, CAP, "xtrip")
-        run_to_terminal(server, trip_storm)
-        assert not chaos.unfired(), [
-            s.to_dict() for s in chaos.unfired()]
-        chaos.disarm()
-        assert breaker.stats()["trips"] >= 1
-        assert server.executive.stats()["host_fallbacks"] >= 1
-
-        # Cool-down passes: next dense storm half-opens and recloses.
-        time.sleep(0.7)
-        probe_storm = submit_storm(server, CAP, "xprobe")
-        run_to_terminal(server, probe_storm)
-        st = breaker.stats()
-        assert st["half_opens"] >= 1 and st["recloses"] >= 1, st
-        assert breaker.state() == "closed"
-
-        from nomad_tpu.server.executive import (
-            NTA_DISPATCHER_ENTRYPOINTS as EXEC_ENTRYPOINTS,
-        )
-
-        assert EXEC_ENTRYPOINTS
-        for entry in EXEC_ENTRYPOINTS:
-            cls_name, _meth = entry.split(".")
-            assert cls_name == "SchedulerExecutive", entry
-            thread = server.executive._thread
-            assert thread is not None and thread.is_alive(), (
-                f"executive drain thread for {entry} stalled/died")
     finally:
         chaos.disarm()
         server.shutdown()

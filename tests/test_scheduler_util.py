@@ -171,203 +171,3 @@ def test_retry_max():
     with pytest.raises(SetStatusError):
         retry_max(2, fails2, lambda: next(resets))
     assert len(calls) == 4  # 2 attempts, reset twice, then exhausted
-
-
-# ---------------------------------------------------------------------
-# cohort_reconcile: the scheduler executive's stacked-table diff
-# (PR 12). The invariant: `fast` exactly when diff_allocs would
-# produce ONLY place/ignore buckets, and the fast place set matches
-# diff_allocs' placement-for-placement.
-
-
-def _cohort_store(n_nodes=4):
-    from nomad_tpu.state import StateStore
-
-    store = StateStore()
-    idx = 0
-    for _ in range(n_nodes):
-        node = mock.node()
-        node.compute_class()
-        idx += 1
-        store.upsert_node(idx, node)
-    return store, idx
-
-
-def _register(store, idx, job_id, count=3):
-    job = mock.job()
-    job.id = job_id
-    job.task_groups[0].count = count
-    idx += 1
-    store.upsert_job(idx, job)
-    return store.job_by_id(job_id), idx
-
-
-def _pending_eval(job):
-    from nomad_tpu.structs.eval import new_eval
-
-    return new_eval(job, consts.EVAL_TRIGGER_JOB_REGISTER)
-
-
-def _diff_parity(snapshot, ev):
-    """The per-eval path's place names for one eval (the oracle)."""
-    from nomad_tpu.scheduler.util import tainted_nodes
-
-    job = snapshot.job_by_id(ev.job_id)
-    groups = materialize_task_groups(job)
-    allocs = snapshot.allocs_by_job(ev.job_id)
-    tainted = tainted_nodes(snapshot, allocs)
-    live = [a for a in allocs if not a.terminal_status()]
-    terminal = {a.name: a for a in allocs if a.terminal_status()}
-    diff = diff_allocs(job, tainted, groups, live, terminal)
-    return diff
-
-
-def test_cohort_reconcile_fresh_jobs_fast_with_full_place():
-    from nomad_tpu.scheduler.util import cohort_reconcile
-
-    store, idx = _cohort_store()
-    job_a, idx = _register(store, idx, "ca", count=3)
-    job_b, idx = _register(store, idx, "cb", count=2)
-    snap = store.snapshot()
-    evs = [_pending_eval(job_a), _pending_eval(job_b)]
-    members = cohort_reconcile(snap, evs)
-    assert all(m.fast for m in members), [m.reason for m in members]
-    for m, ev in zip(members, evs):
-        oracle = _diff_parity(snap, ev)
-        assert sorted(t.name for t in m.place) == sorted(
-            t.name for t in oracle.place)
-    assert members[0].queued == {"web": 3}
-    assert members[1].queued == {"web": 2}
-
-
-def test_cohort_reconcile_current_allocs_fast_noop():
-    from nomad_tpu.scheduler.util import cohort_reconcile
-
-    store, idx = _cohort_store()
-    job, idx = _register(store, idx, "cur", count=2)
-    node = store.nodes()[0]
-    allocs = make_allocs(job, [f"{job.name}.web[0]", f"{job.name}.web[1]"],
-                         node=node.id)
-    idx += 1
-    store.upsert_allocs(idx, allocs)
-    snap = store.snapshot()
-    [m] = cohort_reconcile(snap, [_pending_eval(job)])
-    assert m.fast
-    assert m.place == []
-    assert m.queued == {"web": 0}
-
-
-def test_cohort_reconcile_legacy_routing_matches_diff_buckets():
-    """Every non-pure-placement diff shape routes legacy: stop (name
-    outside required), update (stale job version), tainted (migrate/
-    lost), batch history, sticky disk, wrong trigger/status."""
-    from nomad_tpu.scheduler.util import cohort_reconcile
-
-    store, idx = _cohort_store()
-
-    # stop: an alloc whose name is no longer required
-    job_s, idx = _register(store, idx, "stopj", count=1)
-    node = store.nodes()[0]
-    stray = make_allocs(job_s, ["stopj-old.web[9]"], node=node.id)
-    idx += 1
-    store.upsert_allocs(idx, stray)
-
-    # update: alloc carries an older job_modify_index
-    job_u, idx = _register(store, idx, "updj", count=1)
-    old = job_u.copy()
-    old.job_modify_index = job_u.job_modify_index - 1
-    upd = make_allocs(old, [f"{job_u.name}.web[0]"], node=node.id)
-    idx += 1
-    store.upsert_allocs(idx, upd)
-
-    # tainted: alloc on a draining node
-    job_t, idx = _register(store, idx, "taintj", count=1)
-    drain_node = store.nodes()[1]
-    ta = make_allocs(job_t, [f"{job_t.name}.web[0]"], node=drain_node.id)
-    idx += 1
-    store.upsert_allocs(idx, ta)
-    idx += 1
-    store.update_node_drain(idx, drain_node.id, True)
-
-    # fresh control rides the same cohort and stays fast
-    job_f, idx = _register(store, idx, "freshj", count=1)
-
-    snap = store.snapshot()
-    evs = [_pending_eval(j) for j in (
-        snap.job_by_id("stopj"), snap.job_by_id("updj"),
-        snap.job_by_id("taintj"), snap.job_by_id("freshj"))]
-    members = cohort_reconcile(snap, evs)
-    verdicts = {m.eval.job_id: m.fast for m in members}
-    assert verdicts == {"stopj": False, "updj": False,
-                        "taintj": False, "freshj": True}
-    # and the legacy verdicts agree with the oracle's buckets
-    for m in members:
-        oracle = _diff_parity(snap, m.eval)
-        pure = not (oracle.stop or oracle.update or oracle.migrate
-                    or oracle.lost)
-        assert m.fast == pure, (m.eval.job_id, m.reason, str(oracle))
-
-
-def test_cohort_reconcile_terminal_prev_alloc_attached():
-    """A terminal holder of a required slot re-places with
-    previous_allocation continuity (the diff_allocs terminal_allocs
-    lookup), still on the fast path."""
-    from nomad_tpu.scheduler.util import cohort_reconcile
-
-    store, idx = _cohort_store()
-    job, idx = _register(store, idx, "prevj", count=1)
-    node = store.nodes()[0]
-    [dead] = make_allocs(job, [f"{job.name}.web[0]"], node=node.id)
-    dead.client_status = consts.ALLOC_CLIENT_FAILED
-    idx += 1
-    store.upsert_allocs(idx, [dead])
-    snap = store.snapshot()
-    [m] = cohort_reconcile(snap, [_pending_eval(job)])
-    assert m.fast, m.reason
-    assert [t.name for t in m.place] == [f"{job.name}.web[0]"]
-    assert m.place[0].alloc is not None
-    assert m.place[0].alloc.id == dead.id
-
-
-def test_cohort_reconcile_guards():
-    """Batch history, sticky disks, stopped jobs, wrong status/trigger
-    all refuse the fast path with an attributed reason."""
-    from nomad_tpu.scheduler.util import cohort_reconcile
-
-    store, idx = _cohort_store()
-    node = store.nodes()[0]
-
-    job_b, idx = _register(store, idx, "batchy", count=1)
-    job_b.type = consts.JOB_TYPE_BATCH
-    idx += 1
-    store.upsert_job(idx, job_b)
-    job_b = store.job_by_id("batchy")
-    ba = make_allocs(job_b, [f"{job_b.name}.web[0]"], node=node.id)
-    idx += 1
-    store.upsert_allocs(idx, ba)
-
-    job_k, idx = _register(store, idx, "sticky", count=1)
-    job_k.task_groups[0].ephemeral_disk.sticky = True
-    idx += 1
-    store.upsert_job(idx, job_k)
-    job_k = store.job_by_id("sticky")
-    ka = make_allocs(job_k, [f"{job_k.name}.web[0]"], node=node.id)
-    idx += 1
-    store.upsert_allocs(idx, ka)
-
-    job_d, idx = _register(store, idx, "dereg", count=1)
-
-    snap = store.snapshot()
-    ev_b = _pending_eval(job_b)
-    ev_k = _pending_eval(job_k)
-    ev_d = _pending_eval(job_d)
-    ev_d.triggered_by = consts.EVAL_TRIGGER_JOB_DEREGISTER
-    ev_blocked = _pending_eval(job_d)
-    ev_blocked.status = consts.EVAL_STATUS_BLOCKED
-    members = cohort_reconcile(snap, [ev_b, ev_k, ev_d, ev_blocked])
-    assert [m.fast for m in members] == [False, False, False, False]
-    reasons = [m.reason for m in members]
-    assert "batch job with history" in reasons[0]
-    assert "sticky" in reasons[1]
-    assert "trigger" in reasons[2]
-    assert "status" in reasons[3]

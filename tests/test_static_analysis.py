@@ -1621,24 +1621,16 @@ def test_new_rules_raw_clean_in_baseline_free_dirs():
 def test_kernels_subsystem_raw_clean_and_in_every_scope():
     """The placement-kernel subsystem's self-check (the PR-8 analog of
     the dispatch/admission acceptance below): nomad_tpu/kernels/ is
-    inside the baseline-free core set, the residency scope, and both
-    of bench --check's gate sweeps; the tree shows ZERO findings of
+    inside the baseline-free core set and the residency scope; the
+    tree shows ZERO findings of
     ANY rule there (not even baselined ones) — in particular no
     raft-funnel findings: kernels never touch the state store, they
     only return plans (the differential rig's store seeding routes
     through scheduler/testing.py's sanctioned fixture funnel)."""
-    import importlib.util
-
     assert "nomad_tpu/kernels/" in CORE_DIRS
     from nomad_tpu.analysis.residency import SCOPE_MARKERS
 
     assert "/kernels/" in SCOPE_MARKERS
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate_probe", os.path.join(REPO, "bench.py"))
-    bench_mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_mod)
-    assert "kernels" in bench_mod.PURITY_GATE_DIRS
-    assert "nomad_tpu/kernels/" in bench_mod.CONCURRENCY_GATE_DIRS
 
     offenders = [f for f in _tree_findings()
                  if f.path.startswith("nomad_tpu/kernels/")]
@@ -1920,9 +1912,9 @@ def test_migrate_module_raw_clean_and_in_every_scope():
 def test_defrag_module_raw_clean_and_in_every_scope():
     """Defrag-PR acceptance (the ISSUE's ntalint satellite):
     nomad_tpu/defrag/ (the background optimizer) is in the
-    baseline-free core set, the unbounded-wait / swallowed-exception
-    scopes, and both bench gates' dir sets, with ZERO findings of ANY
-    rule and ZERO baseline entries or inline suppressions — the loop
+    baseline-free core set and the unbounded-wait /
+    swallowed-exception scopes, with ZERO findings of ANY rule and
+    ZERO baseline entries or inline suppressions — the loop
     holds migration-budget slots across waves, where a swallowed
     exception or an unbounded wait leaks budget every drain storm
     then fights."""
@@ -1934,13 +1926,6 @@ def test_defrag_module_raw_clean_and_in_every_scope():
     assert "nomad_tpu/defrag/" in CORE_DIRS
     assert "/defrag/" in WAIT_SCOPE_MARKERS
     assert "/defrag/" in SWALLOW_SCOPE_MARKERS
-    # bench.py imports heavy deps at module load; read the gate dir
-    # tuples textually instead (they are module-level literals).
-    bench_src = open(os.path.join(REPO, "bench.py")).read()
-    assert '"defrag"' in bench_src.split(
-        "PURITY_GATE_DIRS")[1].split(")")[0]
-    assert '"nomad_tpu/defrag/"' in bench_src.split(
-        "CONCURRENCY_GATE_DIRS")[1].split(")")[0]
     offenders = [f for f in _tree_findings()
                  if f.path.startswith("nomad_tpu/defrag/")]
     assert offenders == [], "\n".join(f.render() for f in offenders)
@@ -1955,10 +1940,10 @@ def test_defrag_module_raw_clean_and_in_every_scope():
 def test_gang_module_raw_clean_and_in_every_scope():
     """Gang-PR acceptance (the ISSUE's ntalint satellite):
     nomad_tpu/gang/ (all-or-nothing multi-node placement) is in the
-    baseline-free core set, the unbounded-wait / swallowed-exception /
-    device-residency scopes, and both bench gates' dir sets, with ZERO
-    findings of ANY rule and ZERO baseline entries or inline
-    suppressions — gang staging runs inside scheduler attempts where a
+    baseline-free core set and the unbounded-wait /
+    swallowed-exception / device-residency scopes, with ZERO findings
+    of ANY rule and ZERO baseline entries or inline suppressions —
+    gang staging runs inside scheduler attempts where a
     swallowed exception would leave a HALF-STAGED gang on the plan,
     the one state this subsystem exists to make unrepresentable. The
     raft-funnel sweep covers it too: gang terminals only ever stamp
@@ -1975,13 +1960,6 @@ def test_gang_module_raw_clean_and_in_every_scope():
     assert "/gang/" in WAIT_SCOPE_MARKERS
     assert "/gang/" in SWALLOW_SCOPE_MARKERS
     assert "/gang/" in RESIDENCY_SCOPE_MARKERS
-    # bench.py imports heavy deps at module load; read the gate dir
-    # tuples textually instead (they are module-level literals).
-    bench_src = open(os.path.join(REPO, "bench.py")).read()
-    assert '"gang"' in bench_src.split(
-        "PURITY_GATE_DIRS")[1].split(")")[0]
-    assert '"nomad_tpu/gang/"' in bench_src.split(
-        "CONCURRENCY_GATE_DIRS")[1].split(")")[0]
     offenders = [f for f in _tree_findings()
                  if f.path.startswith("nomad_tpu/gang/")
                  or f.path.endswith(("models/topology.py", "ops/gang.py"))]
@@ -1994,36 +1972,6 @@ def test_gang_module_raw_clean_and_in_every_scope():
                 "ops/gang.py"):
         src = open(os.path.join(REPO, "nomad_tpu", rel)).read()
         assert "nta: disable" not in src, rel
-
-
-def test_executive_module_manifests_and_raw_clean():
-    """The scheduler executive's self-check (PR 12): the module
-    declares the extended NTA_DISPATCHER_ENTRYPOINTS (the cohort drain
-    is the never-blocking clock) and NTA_RECORD_PATH (the drain-cut
-    stats stamp) manifests, lives inside the unbounded-wait and
-    swallowed-exception scopes (server/), and the real tree shows ZERO
-    findings of ANY rule in it — no baseline entries, no inline
-    suppressions: the hottest new path in the repo carries no recorded
-    debt."""
-    from nomad_tpu.analysis.robustness import (
-        SWALLOW_SCOPE_MARKERS,
-        WAIT_SCOPE_MARKERS,
-    )
-    from nomad_tpu.server import executive as exec_mod
-
-    assert exec_mod.NTA_DISPATCHER_ENTRYPOINTS == (
-        "SchedulerExecutive._drain",)
-    assert exec_mod.NTA_RECORD_PATH == ("SchedulerExecutive._note_drain",)
-    assert "/server/" in WAIT_SCOPE_MARKERS
-    assert "/server/" in SWALLOW_SCOPE_MARKERS
-    offenders = [f for f in _tree_findings()
-                 if f.path.endswith("server/executive.py")]
-    assert offenders == [], "\n".join(f.render() for f in offenders)
-    assert [e for e in load_baseline()
-            if e["path"].endswith("server/executive.py")] == []
-    src = open(os.path.join(
-        REPO, "nomad_tpu", "server", "executive.py")).read()
-    assert "nta: disable" not in src
 
 
 def test_readplane_manifests_and_raw_clean():

@@ -37,7 +37,7 @@ def test_timers_fire_in_deadline_order():
 def test_slow_callback_does_not_delay_others():
     """One blocked callback (a raft apply during leader loss) must not
     make other timers fire late — the round-2 wheel serialized all
-    callbacks on the firing thread (ADVICE r2 #1)."""
+    callbacks on the firing thread (an advisor finding of round 2)."""
     wheel = TimerWheel(name="t-slow")
     release = threading.Event()
     fast_fired = threading.Event()
