@@ -97,21 +97,32 @@ class PipelineSession(EvalSession):
     instead of replanning alone."""
 
     def __init__(self, pipeline: "DispatchPipeline", entry: _Pending,
-                 announced: bool = False):
+                 cohort=None):
         # EvalSession only needs `.server` and `._wait_for_index` from
         # its worker — the pipeline provides both.
         super().__init__(pipeline, entry.eval, entry.token)
         self.pipeline = pipeline
         self.entry = entry
-        # True while this eval is counted in the batcher's announced
-        # cohort (add_cohort); consumed at place() time or repaid on
-        # host fallback (scheduler/tpu.py) / eval completion
-        # (_repay_unconsumed).
-        self.announced_cohort = announced
+        # This eval's unit of its batch's cohort (scheduler/batcher.py
+        # CohortUnit, opened by the launch prologue), None when the
+        # batch goes to the host or the eval never meets the batcher.
+        # The dense scheduler hands it to place(), which marks it
+        # arrived; every other way the eval can end goes through
+        # settle_cohort.
+        self.cohort = cohort
         # Evals created this attempt (blocked / rolling follow-ups):
         # once any exist, aborting the attempt would re-create them on
         # the requeued run — fall back to the inline retry instead.
         self.created_evals = 0
+
+    def settle_cohort(self) -> None:
+        """This eval's place() is not coming (a host route inside the
+        dense scheduler, no placements, a failure) or has come: its
+        batch-mates' dispatch must not wait for it. Idempotent; the
+        dense scheduler calls it as early as it knows, _process_entry
+        when the scheduler returns, whichever way."""
+        if self.cohort is not None:
+            self.cohort.settle()
 
     def submit_plan(self, plan: Plan) -> Tuple[PlanResult, Optional[object]]:
         start = time.monotonic()
@@ -457,13 +468,13 @@ class DispatchPipeline:
         # exactly once and releases the slot via `remaining`. A
         # partial-fan-out cleanup here would double-finish entries the
         # pool still runs.
-        snapshot, route_host = prologue
+        snapshot, route_host, units = prologue
         profile.event("launch", "stage", a=len(batch), b=int(route_host))
         remaining = [len(batch)]
-        for entry in batch:
+        for entry, unit in zip(batch, units):
             self.server.eval_pool.submit(
                 self._process_entry, entry, snapshot, route_host,
-                remaining, t_fan)
+                remaining, t_fan, unit)
 
     def _drop_expired(self, batch: List[_Pending],
                       t_launch: float) -> List[_Pending]:
@@ -531,9 +542,12 @@ class DispatchPipeline:
                 self._cond.notify_all()
 
     def _launch_prologue(self, batch: List[_Pending]):
-        """(snapshot, route_host) for a launchable batch, None when the
-        FSM never caught up to the batch's snapshot index. Returned,
-        not stored: concurrent launches each carry their own."""
+        """(snapshot, route_host, units) for a launchable batch, None
+        when the FSM never caught up to the batch's snapshot index.
+        `units` holds, entry for entry, each eval's unit of the batch's
+        cohort at the batcher (None for an eval that never meets it).
+        Returned, not stored: concurrent launches each carry their
+        own."""
         if chaos.enabled:
             # 'error' = the launch prologue dies (snapshot/catch-up
             # failure): _launch aborts the batch, every eval nacks and
@@ -549,7 +563,7 @@ class DispatchPipeline:
             # Device-path circuit breaker (admission/breaker.py): an
             # OPEN breaker inside its cool-down routes the whole batch
             # to the host factories up front — no matrix build against
-            # a sick device path, no cohort announcement to repay.
+            # a sick device path, no cohort to open.
             # This is the NON-consuming hint: once the cool-down
             # elapses it goes quiet and the dense path's acquire() gate
             # (scheduler/tpu.py) sends exactly one half-open probe.
@@ -575,26 +589,29 @@ class DispatchPipeline:
         if not self._wait_for_index(max_index, WAIT_INDEX_TIMEOUT):
             return None
         snapshot = self.server.fsm.state.snapshot()
+        units = [None] * len(batch)
         if not route_host:
-            # Announce the fan-out to the batcher: its dispatch window
-            # then waits for this whole batch's place() calls (their
-            # matrix builds stagger under the GIL) instead of shipping
-            # fragmented, third-full device dispatches. System-dense
-            # evals are excluded — DenseSystemScheduler's vectorized
-            # pass never touches the batcher, so announcing them would
-            # only stretch the window (the hint self-heals either way,
-            # COHORT_WAIT_MAX). Generic dense evals that fall back to
-            # the host path repay their announcement in
-            # scheduler/tpu.py.
-            announce = sum(
-                1 for e in batch
-                if e.eval.type != consts.JOB_TYPE_SYSTEM)
-            if announce:
-                from ..scheduler.batcher import get_batcher
-
-                get_batcher().add_cohort(announce)
             self._prefetch_bases(batch, snapshot)
-        return snapshot, route_host
+            # Announce the fan-out to the batcher: this batch is cut and
+            # counted, so a dispatch that holds its place() calls (their
+            # matrix builds stagger under the GIL) closes when the last
+            # of the batch has arrived or been settled, not on a timed
+            # window. Opened last, after everything that can raise and
+            # after the prefetch: a prologue that fails opens nothing,
+            # and the other batch in flight, which waits for an open
+            # cohort, never waits on this prologue. System-dense evals
+            # are excluded — DenseSystemScheduler's vectorized pass
+            # never touches the batcher. A generic dense eval that
+            # takes a host path, places nothing or fails settles its
+            # unit (PipelineSession.settle_cohort).
+            from ..scheduler.batcher import get_batcher
+
+            members = [i for i, e in enumerate(batch)
+                       if e.eval.type != consts.JOB_TYPE_SYSTEM]
+            for i, unit in zip(
+                    members, get_batcher().open_cohort(len(members))):
+                units[i] = unit
+        return snapshot, route_host, units
 
     def _prefetch_bases(self, batch: List[_Pending], snapshot) -> None:
         """Async double-buffering, host side: make this batch's cluster
@@ -618,8 +635,7 @@ class DispatchPipeline:
             if entry.eval.type == consts.JOB_TYPE_SYSTEM:
                 # DenseSystemScheduler builds its matrix over explicit
                 # pinned nodes (a different cache family) and never
-                # touches the batcher — same exclusion as the cohort
-                # announce above.
+                # touches the batcher — same exclusion as the cohort's.
                 continue
             job = snapshot.job_by_id(entry.eval.job_id)
             if job is None:
@@ -656,7 +672,8 @@ class DispatchPipeline:
     # ---------------------------------------------------------- stages
 
     def _process_entry(self, entry: _Pending, snapshot, route_host: bool,
-                       remaining: List[int], t_fan: float) -> None:
+                       remaining: List[int], t_fan: float,
+                       unit=None) -> None:
         ev, token = entry.eval, entry.token
         start = time.monotonic()
         trace.record_span(ev.id, trace.STAGE_DISPATCH_POOL_WAIT, t_fan,
@@ -666,30 +683,16 @@ class DispatchPipeline:
         # scheduler invoke lands on the span so a slow scheduler.process
         # can be read as "blocked on locks" vs "actually computing".
         wait0 = profile.thread_wait_ms()
-        session = PipelineSession(
-            self, entry,
-            announced=(not route_host
-                       and ev.type != consts.JOB_TYPE_SYSTEM))
+        session = PipelineSession(self, entry, cohort=unit)
         try:
-            if chaos.enabled:
-                # 'delay' = a stalled stage consumer (a wedged
-                # scheduler thread): the eval sits in process, the e2e
-                # p99 inflates, and the pressure monitor must see it —
-                # the overload soak forces consumer stalls through this
-                # site. 'error' = the consumer dies; the eval nacks and
-                # redelivers via the except path below.
-                chaos.fire("admission.slow_consumer", eval_id=ev.id)
-            factory = self.server.config.factory_for(ev.type)
-            if route_host:
-                from ..server.worker import host_factory
-
-                factory = host_factory(factory)
-            # Independent PRNG per eval (see worker.py: correlated
-            # tie-break streams spike plan conflicts).
-            rng = random.Random(int.from_bytes(os.urandom(8), "little"))
-            sched = new_scheduler(
-                factory, self.logger, snapshot, session, rng=rng)
-            sched.process_eval(ev)
+            try:
+                self._schedule(session, snapshot, route_host)
+            finally:
+                # The net under every way out of the scheduler (no
+                # placements, a raise, a requeue, or a path that
+                # settled already): no unit of the cohort stays open
+                # past its eval.
+                session.settle_cohort()
         except _RequeueConflict:
             with self._lock:
                 self.requeues += 1
@@ -697,7 +700,6 @@ class DispatchPipeline:
                               ann={"path": "pipeline", "requeued": True},
                               trace_id=ev.trace_id)
             metrics.incr_counter(("dispatch", "requeue"))
-            self._repay_unconsumed(session)
             # Back into the ACCUMULATING batch; the broker token stays
             # outstanding, so per-job serialization still holds.
             entry.requeues += 1
@@ -709,7 +711,6 @@ class DispatchPipeline:
             trace.record_span(ev.id, trace.STAGE_SCHED_PROCESS, start,
                               ann={"path": "pipeline", "failed": True},
                               trace_id=ev.trace_id)
-            self._repay_unconsumed(session)
             self._finish(entry, acked=False)
             self._release_slot(remaining)
             return
@@ -719,23 +720,32 @@ class DispatchPipeline:
                  "lock_wait_ms": round(
                      profile.thread_wait_ms() - wait0, 3)},
             trace_id=ev.trace_id)
-        self._repay_unconsumed(session)
         self._finish(entry, acked=True)
         self._release_slot(remaining)
 
-    def _repay_unconsumed(self, session: PipelineSession) -> None:
-        """Repay a cohort unit this eval announced but never consumed:
-        placement-less evals (job stop, scale-down, in-place-only
-        update) and failed schedulers never reach the batcher, and an
-        unrepaid announcement stretches every subsequent partial
-        dispatch toward COHORT_WAIT_MAX. The dense scheduler flips
-        announced_cohort off right before its place() call, so a
-        consumed announcement is never repaid twice."""
-        if session.announced_cohort:
-            session.announced_cohort = False
-            from ..scheduler.batcher import get_batcher
+    def _schedule(self, session: PipelineSession, snapshot,
+                  route_host: bool) -> None:
+        """Run one eval's scheduler against the batch's snapshot."""
+        ev = session.eval
+        if chaos.enabled:
+            # 'delay' = a stalled stage consumer (a wedged
+            # scheduler thread): the eval sits in process, the e2e
+            # p99 inflates, and the pressure monitor must see it —
+            # the overload soak forces consumer stalls through this
+            # site. 'error' = the consumer dies; the eval nacks and
+            # redelivers via _process_entry's except path.
+            chaos.fire("admission.slow_consumer", eval_id=ev.id)
+        factory = self.server.config.factory_for(ev.type)
+        if route_host:
+            from ..server.worker import host_factory
 
-            get_batcher().cohort_cancel(1)
+            factory = host_factory(factory)
+        # Independent PRNG per eval (see worker.py: correlated
+        # tie-break streams spike plan conflicts).
+        rng = random.Random(int.from_bytes(os.urandom(8), "little"))
+        sched = new_scheduler(
+            factory, self.logger, snapshot, session, rng=rng)
+        sched.process_eval(ev)
 
     def _finish(self, entry: _Pending, acked: bool) -> None:
         if chaos.enabled and chaos.fire(

@@ -21,11 +21,15 @@ recompiles). Within a batch there are two device paths:
 - mixed bases: the full states stack along the batch axis
   (batched_placement_program).
 
-The window is adaptive: while a device dispatch is in flight, new
-requests simply accumulate and the follow-up dispatch takes everything
-queued (up to MAX_BATCH) with no added wait; only a first request on an
-idle batcher waits a short fixed window for concurrent workers to pile
-on.
+A dispatch closes on what its requests carry. Requests of a pipeline
+batch carry that batch's cohort (open_cohort: the launch prologue has
+already counted them), and their dispatch is released the moment every
+cohort in the queue, and any other that is open, is complete, never by
+the clock. Requests without
+one (a worker's lone dense eval, direct callers) have nobody upstream
+to count them: a first request on an idle batcher waits a short window
+for concurrent workers to pile on, and while a device dispatch is in
+flight new requests simply accumulate for the follow-up dispatch.
 """
 
 from __future__ import annotations
@@ -47,19 +51,19 @@ MAX_BATCH = 64
 # matrices are too small to beat the collective the sharded argmax
 # inserts. Single-device runs never shard.
 SHARD_MIN_NODES = 2048
-# Idle-batcher accumulation window. Sized for the drain-to-batch storm:
-# a drained group's place() calls arrive staggered by the GIL-serialized
-# host phases (~2-4ms each), so a too-small window ships a near-empty
-# first dispatch. Interactive evals never wait this — latency-aware
-# routing sends lone evals to the host factory (server/worker.py).
-# ADAPTIVE: when the measured dispatch round-trip is large, waiting a
-# fraction of it fills batches further — the wall-clock is then
-# RTT-bound, so fewer, fuller dispatches win; a short round-trip keeps
-# the small floor. WINDOW_S/WINDOW_MAX_S have not been re-measured on
-# an attached chip.
+# Idle-batcher accumulation window, for requests that carry NO cohort:
+# a worker's one-eval dense run (eval_batch_size <= 1) and direct
+# callers, whose concurrent place() calls nobody upstream has counted.
+# Their calls arrive staggered by the GIL-serialized host phases
+# (~2-4ms each), so a too-small window ships a near-empty first
+# dispatch. ADAPTIVE: when the measured dispatch round-trip is large,
+# waiting a fraction of it fills batches further. No served path of the
+# dispatch pipeline reaches it: every request of a pipeline batch
+# carries its cohort and closes on that (_accumulate). WINDOW_S and
+# WINDOW_MAX_S have not been re-measured on an attached chip.
 WINDOW_S = 0.02
 WINDOW_MAX_S = 0.12
-RESPAWN_WINDOW_S = 0.005  # post-dispatch window: catch GIL stragglers
+RESPAWN_WINDOW_S = 0.005  # post-dispatch window, same requests: GIL stragglers
 # Cluster bases kept on device. Sized for the live storm's token churn:
 # ~4 workers' wave snapshots plus the delta parents they derive from —
 # evicting a parent forces the next delta into a full re-upload.
@@ -74,12 +78,14 @@ MAX_INFLIGHT = 3
 # re-checks are noise (the window + device call usually complete in
 # one slice), short enough that a dead dispatcher is noticed fast.
 REQUEST_WAIT_SLICE_S = 0.1
-# Hard ceiling on cohort-extended accumulation (add_cohort): the
-# window stretches while ANNOUNCED requests are still on their way —
-# their matrix builds are GIL-serialized host work the RTT-driven
-# window cannot see — but an announced eval that never places (host
-# fallback, no-op plan) must not wedge the dispatcher. On expiry the
-# outstanding count zeroes (the hint lied; self-heal).
+# The one bound on a cohort member that never comes, per cohort, from
+# the cohort's first arrival. Every exit of an announced eval settles
+# its unit (PipelineSession.settle_cohort, with a finally as the net),
+# so a dispatch reaches this only through a fault in that hand-over or
+# a member stuck for a second before its place(); it must still not
+# wedge its batch-mates. On expiry that cohort alone is closed: its
+# dispatch goes (counted closed_by_cap) and late members dispatch on
+# arrival.
 COHORT_WAIT_MAX = 1.0
 
 
@@ -92,13 +98,63 @@ COHORT_WAIT_MAX = 1.0
 NTA_REBUILD_ENTRYPOINTS = ("PlacementBatcher._build_device_base",)
 
 
+class _Cohort:
+    """One pipeline batch's announcement (open_cohort): how many of its
+    units have neither arrived in place() nor been settled. Guarded by
+    the lock of the batcher that opened it."""
+
+    __slots__ = ("pending", "deadline", "arrived", "capped")
+
+    def __init__(self, n: int):
+        self.pending = n
+        # COHORT_WAIT_MAX from when it was opened, then from its first
+        # arrival.
+        self.deadline = time.monotonic() + COHORT_WAIT_MAX
+        self.arrived = False
+        self.capped = False  # closed by the cap, a member still out
+
+
+class CohortUnit:
+    """One eval's place in its batch's cohort. Handed to place() as
+    `cohort=`, which marks it arrived; an eval that will not place
+    calls settle(). Either happens once: whatever comes after finds
+    the unit closed and takes nobody else's."""
+
+    __slots__ = ("batcher", "cohort", "open", "closed_by")
+
+    def __init__(self, batcher: "PlacementBatcher", cohort: _Cohort):
+        self.batcher = batcher
+        self.cohort = cohort
+        self.open = True  # guarded by the batcher's lock
+        # What released the dispatch this unit's request rode:
+        # "cohort", "full" or "cap" (the device.dispatch span's
+        # annotation); None until it has ridden one.
+        self.closed_by: Optional[str] = None
+
+    def take(self) -> bool:
+        """Close this unit (the batcher's lock is held). True when
+        that completed its cohort."""
+        if not self.open:
+            return False
+        self.open = False
+        if self.cohort.pending <= 0:
+            return False  # the cap closed the cohort before
+        self.cohort.pending -= 1
+        return self.cohort.pending == 0
+
+    def settle(self) -> None:
+        """This eval's place() is not coming: its batch-mates'
+        dispatch must not wait for it. Idempotent."""
+        self.batcher.settle(self)
+
+
 class _Request:
     __slots__ = ("token", "base", "overlay", "compact", "asks", "key",
                  "delta", "event", "choices", "scores", "error", "span",
-                 "ready_at", "arrived_at")
+                 "ready_at", "arrived_at", "unit")
 
     def __init__(self, token, base, overlay, asks, key, delta=None,
-                 compact=None, span=None):
+                 compact=None, span=None, unit=None):
         self.token = token  # cluster-base identity, None = unshared
         self.base = base  # (capacity, sched_capacity, util, bw_avail,
         #                    bw_used, ports_free, node_ok, class_ids)
@@ -112,6 +168,7 @@ class _Request:
         self.key = key
         self.delta = delta  # (parent_token, changed_rows) or None
         self.span = span  # (eval_id, trace_id) for the device.solve span
+        self.unit: Optional[CohortUnit] = unit
         # When the request reached the batcher: the earliest of a
         # batch's is where the device's idle gap stops being "no work"
         # and starts being "batch wait" (_idle_parts).
@@ -238,17 +295,14 @@ class PlacementBatcher:
         self.bytes_upload = 0.0  # guarded-by: _lock (upload payload)
         # EMA of the dispatch round-trip, drives the adaptive window.
         self._sync_ema = 0.0  # guarded-by: _lock
-        # Requests ANNOUNCED but not yet arrived (add_cohort): the
-        # central dispatch pipeline fans a known batch out and tells
-        # the batcher how many place() calls are coming, so dispatch
-        # accumulation waits for the stragglers instead of shipping
-        # 1/3-full lanes (measured r05: 9.4/64). _cohort_gen bumps on
-        # every cohort mutation: an expiring dispatcher only zeroes a
-        # cohort that has been completely INERT through its whole wait
-        # — zeroing an active counter would clobber a fresh batch's
-        # announcement and re-fragment its dispatch.
-        self._cohort = 0  # guarded-by: _lock
-        self._cohort_gen = 0  # guarded-by: _lock
+        # Cohorts opened (open_cohort) with a unit still out: empty
+        # whenever no pipeline batch is between its launch prologue and
+        # its last member's place() or settle() (the gauge
+        # open_cohorts).
+        self._cohorts: set = set()  # guarded-by: _lock
+        # Dispatches by what released them (_accumulate).
+        self._closed_by = {"cohort": 0, "window": 0,  # guarded-by: _lock
+                           "full": 0, "cap": 0}
         # The device's idle time by cause (_issue): programs between
         # issue and results on the host, over every shape key, and the
         # end of the last such interval (0.0: none yet). A gap is only
@@ -259,30 +313,37 @@ class PlacementBatcher:
         self._busy_until = 0.0  # guarded-by: _lock
         self._issued = 0  # guarded-by: _lock (nomad.dispatch ordinal)
 
-    def add_cohort(self, n: int) -> None:
-        """Announce that `n` place() calls are on their way (the
-        dispatch pipeline calls this as it fans a batch out). Dispatch
-        accumulation extends past its RTT-driven window while announced
-        requests are outstanding — bounded by COHORT_WAIT_MAX."""
+    def open_cohort(self, n: int) -> List[CohortUnit]:
+        """Announce a batch of `n` place() calls (the dispatch pipeline's
+        launch prologue, which has cut the batch and so has counted it):
+        the `n` units of one new cohort, one for each eval to hand to
+        its place(). A dispatch that holds any of them is released when
+        all `n` have arrived or been settled (and so have those of
+        every other cohort open then: _accumulate), not by the clock —
+        bounded by COHORT_WAIT_MAX."""
         if n <= 0:
-            return
-        with self._full:
-            self._cohort += n
-            self._cohort_gen += 1
-            self._full.notify_all()
+            return []
+        cohort = _Cohort(n)
+        with self._lock:
+            self._cohorts.add(cohort)
+        return [CohortUnit(self, cohort) for _ in range(n)]
 
-    def cohort_cancel(self, n: int = 1) -> None:
-        """Repay an announced place() that will never arrive (an
-        announced eval fell back to the host path). Floor at zero: a
-        double repayment only un-stretches the window, never wedges."""
+    def settle(self, unit: CohortUnit) -> None:
+        """CohortUnit.settle: a unit whose place() will not come."""
         with self._full:
-            self._cohort = max(0, self._cohort - n)
-            self._cohort_gen += 1
-            self._full.notify_all()
+            if unit.take():
+                self._cohorts.discard(unit.cohort)
+                self._full.notify_all()
 
-    def place(self, state, asks, rng_key, config, span=None):
+    def place(self, state, asks, rng_key, config, span=None, cohort=None):
         """Submit one eval's placement; blocks until its batch's device
         dispatch returns. Returns (choices, scores) for THIS request.
+
+        `cohort` is this eval's CohortUnit when a pipeline batch
+        announced it (open_cohort): the call marks it arrived, and the
+        request's dispatch closes on its cohort instead of the timed
+        window. A second place() with the same unit (an inline replan)
+        finds it closed, and its cohort complete.
 
         `state` is anything exposing the NodeState field names
         (ops/binpack.NodeState itself, or models/matrix.ClusterMatrix —
@@ -324,14 +385,19 @@ class PlacementBatcher:
         )
         req = _Request(token, base, overlay, asks, rng_key,
                        delta=getattr(state, "base_delta", None),
-                       compact=compact, span=span)
+                       compact=compact, span=span, unit=cohort)
         run_dispatch = False
         with self._lock:
-            if self._cohort > 0:
-                self._cohort -= 1
-                self._cohort_gen += 1
             q = self._queues.setdefault(shape_key, [])
             q.append(req)
+            unit = req.unit
+            if unit is not None:
+                if not unit.cohort.arrived:
+                    unit.cohort.arrived = True
+                    unit.cohort.deadline = req.arrived_at + COHORT_WAIT_MAX
+                if unit.take():
+                    self._cohorts.discard(unit.cohort)
+                    self._full.notify_all()
             if len(q) >= self.max_batch:
                 self._full.notify_all()
             if self._dispatchers.get(shape_key, 0) == 0:
@@ -857,46 +923,74 @@ class PlacementBatcher:
                     req.span[0], trace.STAGE_DEVICE_SOLVE, t1, t3,
                     ann=ann, trace_id=req.span[1])
 
-    def _accumulate(self, shape_key, window: float) -> None:
-        """Wait up to `window` for requests to pile on — but a FULL
-        batch dispatches immediately: once max_batch requests are
-        queued nothing more can join this dispatch. Sleeps on a
-        condition place() signals at max_batch —
-        no lock-polling on the scheduler hot path.
+    def _accumulate(self, shape_key, window: float) -> str:
+        """Hold the shape's queue open until it may dispatch, and say
+        what released it. Sleeps on the condition that place() and
+        settle() signal — no lock-polling on the scheduler hot path.
 
-        A live cohort (add_cohort: announced requests still on their
-        way, typically mid-matrix-build under the GIL) extends the
-        wait past the RTT-driven window, bounded by COHORT_WAIT_MAX —
-        shipping a third-full dispatch while the rest of the batch is
-        provably coming wastes a full round-trip per fragment."""
-        import time as _time
-
-        start = _time.monotonic()
-        deadline = start + window
-        hard = start + COHORT_WAIT_MAX
-        gen_seen = None  # cohort generation when we began extending
+        - "full": max_batch requests are queued, nothing more can join.
+        - "cohort": the queue holds requests of pipeline batches and
+          every cohort among them is complete (all units arrived or
+          settled), and so is every other cohort open at that moment.
+          Never the clock: the pipeline has counted these requests, and
+          shipping a fragment while the rest is provably coming wastes
+          a round-trip, as waiting on after the last has come wastes
+          the wait. A batch spread over several shape queues is waited
+          for as a whole by each: a member's shape is not known before
+          it arrives. The OTHER batch in flight is waited for because
+          the in-batch pre-resolution sees only its own dispatch: the
+          pipeline keeps two batches in flight, and two that go
+          together either share a snapshot and ride one dispatch, or
+          at least commit together, so that the next two plan on both.
+          Going as soon as the own batch was complete was measured
+          (PERF.md section 6, PR 31): at saturation the batches fall
+          out of step, every batch then overlaps its predecessor's
+          uncommitted plans AND its successor's, and plan conflicts
+          double. An open cohort is one whose prologue is done: nobody
+          waits on a prefetch.
+        - "cap": as "cohort", but a request among them waited
+          COHORT_WAIT_MAX out for a member that did not come, and its
+          cohort was closed without it.
+        - "window": no request in the queue carries a cohort, and
+          `window` seconds have passed."""
+        deadline = time.monotonic() + window
+        expired = False  # this wait ran into a cohort's cap
         with self._full:
-            while len(self._queues.get(shape_key, ())) < self.max_batch:
-                now = _time.monotonic()
-                if now >= deadline:
-                    if self._cohort <= 0:
-                        return
-                    if gen_seen is None:
-                        gen_seen = self._cohort_gen
-                    if now >= hard:
-                        # This dispatcher waited the cap out. Zero the
-                        # hint only if it was INERT the whole time — an
-                        # active counter belongs to some other batch
-                        # whose announcements arrived/changed during
-                        # our wait, and clobbering it would re-fragment
-                        # that batch's dispatch.
-                        if self._cohort_gen == gen_seen:
-                            self._cohort = 0
-                            self._cohort_gen += 1
-                        return
-                    self._full.wait(min(0.002, hard - now))
-                    continue
-                self._full.wait(deadline - now)
+            while True:
+                q = self._queues.get(shape_key, ())
+                if len(q) >= self.max_batch:
+                    return "full"
+                now = time.monotonic()
+                cohorts = {r.unit.cohort for r in q if r.unit is not None}
+                if cohorts:
+                    cohorts |= self._cohorts
+                waiting = [c for c in cohorts if c.pending > 0]
+                if waiting:
+                    until = min(c.deadline for c in waiting)
+                    if now >= until:
+                        expired = True
+                        for c in waiting:
+                            if now >= c.deadline:
+                                c.pending = 0
+                                c.capped = True
+                                self._cohorts.discard(c)
+                        # Its other shape queues wait on it too.
+                        self._full.notify_all()
+                        continue
+                elif cohorts:
+                    # "cap" for a request that waited a capped cohort
+                    # out, in whichever of its queues; a member that
+                    # comes after the cap finds its cohort complete.
+                    waited_out = expired or any(
+                        r.unit.cohort.capped
+                        and r.arrived_at < r.unit.cohort.deadline
+                        for r in q if r.unit is not None)
+                    return "cap" if waited_out else "cohort"
+                elif now >= deadline:
+                    return "window"
+                else:
+                    until = deadline
+                self._full.wait(until - now)
 
     def _spawn_dispatcher(self, shape_key, config) -> None:
         t = threading.Thread(
@@ -931,32 +1025,27 @@ class PlacementBatcher:
         batch: List[_Request] = []
         popped = False
         try:
-            import time as _time
-
             with self._lock:
                 sync_ema = self._sync_ema
-            if wait_window and self.window > 0:
-                # Idle batcher: give concurrent workers a moment to
-                # pile on. Post-dispatch respawns use a shorter window —
-                # most of their batch accumulated during the in-flight
-                # device call (the adaptive part); the short wait only
-                # catches stragglers mid-host-phase. The window grows
-                # with the measured round-trip (see WINDOW_S note) —
-                # but a FULL batch dispatches immediately: once
-                # max_batch requests are queued nothing more can join
-                # this dispatch.
-                self._accumulate(shape_key, min(
-                    WINDOW_MAX_S, max(self.window, sync_ema * 0.5)))
-            elif not wait_window and RESPAWN_WINDOW_S > 0:
-                # Respawn window is adaptive too: when the round-trip
-                # is long (sync_ema ~100ms+) a 5ms straggler window
-                # ships near-empty follow-up dispatches — each ragged
-                # size is its own XLA program, so tiny respawn batches
-                # pay compiles AND round-trips. The floor stays small
-                # when the round-trip is short.
-                self._accumulate(shape_key, max(
-                    RESPAWN_WINDOW_S,
-                    min(WINDOW_MAX_S, sync_ema * 0.5)))
+            # The timed window, for a queue whose requests carry no
+            # cohort (_accumulate; one that does closes on its cohorts
+            # and never reads it). Idle batcher: give concurrent
+            # workers a moment to pile on; the window grows with the
+            # measured round-trip (see WINDOW_S note). Post-dispatch
+            # respawns use a shorter one — most of their batch
+            # accumulated during the in-flight device call, the short
+            # wait only catches stragglers mid-host-phase — but not a
+            # fixed one: when the round-trip is long (sync_ema ~100ms+)
+            # a 5ms straggler window ships near-empty follow-up
+            # dispatches, and each ragged size is its own XLA program.
+            if not wait_window:
+                window = max(RESPAWN_WINDOW_S,
+                             min(WINDOW_MAX_S, sync_ema * 0.5))
+            elif self.window > 0:
+                window = min(WINDOW_MAX_S, max(self.window, sync_ema * 0.5))
+            else:
+                window = 0.0
+            closed_by = self._accumulate(shape_key, window)
             with self._lock:
                 waiting = self._queues.pop(shape_key, [])
                 batch = waiting[: self.max_batch]
@@ -966,6 +1055,11 @@ class PlacementBatcher:
                     # would wedge those workers in event.wait().
                     self._queues[shape_key] = leftover
                 popped = True
+                if batch:
+                    self._closed_by[closed_by] += 1
+                    for req in batch:
+                        if req.unit is not None:
+                            req.unit.closed_by = closed_by
                 # Overlap: if work is already waiting, start the next
                 # dispatcher NOW so its accumulation + transfer hides
                 # behind our device round-trip.
@@ -1060,6 +1154,14 @@ class PlacementBatcher:
                 # climb under load is a recompile storm (the benchmark's
                 # window_compiles reads it).
                 "jit_cache_size": jit_programs,
+                # How each dispatch was released (_accumulate), and the
+                # cohorts with a unit still out: a served request always
+                # has a cohort, so closed_by_window counts only lone
+                # dense evals and direct callers, and closed_by_cap a
+                # hand-over that lost a unit.
+                **{f"closed_by_{k}": v
+                   for k, v in self._closed_by.items()},
+                "open_cohorts": len(self._cohorts),
             }
 
 

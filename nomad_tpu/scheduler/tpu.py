@@ -165,7 +165,7 @@ class BatchedTPUScheduler(GenericScheduler):
             self._place_gang_dense(tg, tuples)
         if not place:
             if gang_sets:
-                self._repay_cohort()
+                self._settle_cohort()
             return
         # Sticky-disk placements keep the host path (they pin to one node).
         sticky: List[AllocTuple] = []
@@ -188,7 +188,7 @@ class BatchedTPUScheduler(GenericScheduler):
                 remaining.append(missing)
         bulk = remaining
         if not bulk:
-            self._repay_cohort()
+            self._settle_cohort()
             return
         from ..migrate import preemption_eligible
         from ..utils import metrics
@@ -211,7 +211,7 @@ class BatchedTPUScheduler(GenericScheduler):
             metrics.incr_counter(
                 ("scheduler", "small_route_host"), len(bulk))
             metrics.incr_counter(("scheduler", "small_route_host_evals"))
-            self._repay_cohort()
+            self._settle_cohort()
             super()._compute_placements(bulk)
             return
 
@@ -230,7 +230,7 @@ class BatchedTPUScheduler(GenericScheduler):
 
         breaker = get_breaker()
         if not breaker.acquire():
-            self._repay_cohort()
+            self._settle_cohort()
             metrics.incr_counter(
                 ("scheduler", "breaker_rejected"), len(bulk))
             trace.record_span(
@@ -311,16 +311,15 @@ class BatchedTPUScheduler(GenericScheduler):
         # for stacking.
         key = host_prng_key(self.rng.getrandbits(31))
 
-        # The announced place() call is about to arrive: mark the
-        # cohort unit consumed so the pipeline doesn't also repay it
-        # (place() itself decrements the batcher's counter).
-        if getattr(self.planner, "announced_cohort", False):
-            self.planner.announced_cohort = False
         # The drain-to-batch shim (BASELINE north star): concurrent
         # workers' same-shaped placement programs coalesce into one
         # vmapped device dispatch instead of N serial calls, and evals
         # sharing a cluster base ride one cached device upload.
         _t0 = time.monotonic()
+        # This eval's unit of its pipeline batch's cohort (a Planner
+        # outside the pipeline has none): place() marks it arrived, and
+        # its dispatch closes on the cohort, not on the timed window.
+        unit = getattr(self.planner, "cohort", None)
         try:
             if chaos.enabled:
                 # 'error' = an injected device fault AT the breaker's
@@ -339,7 +338,7 @@ class BatchedTPUScheduler(GenericScheduler):
                 get_batcher().prefetch_base(matrix)
             choices, scores = get_batcher().place(
                 matrix, asks, key, config,
-                span=(self.eval.id, self.eval.trace_id))
+                span=(self.eval.id, self.eval.trace_id), cohort=unit)
         except Exception:
             # Device dispatch failed (runtime fault, OOM on device,
             # chaos binpack.device / device.breaker_trip): the host
@@ -350,6 +349,9 @@ class BatchedTPUScheduler(GenericScheduler):
             # bulk set takes the host path; the plan applier
             # re-verifies either way. The breaker counts the failure:
             # K consecutive ones trip the dense path out of the way.
+            # A fault BEFORE place() left the unit open: batch-mates
+            # must not wait out the host placement below.
+            self._settle_cohort()
             breaker.record_failure()
             self.logger.warning(
                 "device placement dispatch failed; falling back to the "
@@ -363,8 +365,11 @@ class BatchedTPUScheduler(GenericScheduler):
         breaker.record_success((time.monotonic() - _t0) * 1000.0)
         choices = np.asarray(choices)
         scores = np.asarray(scores)
-        trace.record_span(self.eval.id, trace.STAGE_DEVICE_DISPATCH, _t0,
-                          trace_id=self.eval.trace_id)
+        trace.record_span(
+            self.eval.id, trace.STAGE_DEVICE_DISPATCH, _t0,
+            ann=({"closed_by": unit.closed_by} if unit is not None
+                 else None),
+            trace_id=self.eval.trace_id)
 
         # Host-side exact port assignment per chosen node, incremental.
         net_indexes: Dict[str, NetworkIndex] = {}
@@ -728,17 +733,15 @@ class BatchedTPUScheduler(GenericScheduler):
         note_quality(self.logger, self.job, kernel, matrix, ask_res,
                      committed)
 
-    def _repay_cohort(self) -> None:
-        """Un-announce this eval's place() call: the dispatch pipeline
-        told the batcher a dispatch was coming (add_cohort), but this
-        eval took a host path instead — without the repayment the
-        batcher's window would stretch COHORT_WAIT_MAX for a request
-        that never arrives."""
-        if getattr(self.planner, "announced_cohort", False):
-            from .batcher import get_batcher
-
-            self.planner.announced_cohort = False
-            get_batcher().cohort_cancel(1)
+    def _settle_cohort(self) -> None:
+        """This eval takes a host path: the dispatch pipeline announced
+        its place() to the batcher (a unit of its batch's cohort), and
+        its batch-mates' dispatch must not wait out a host placement
+        for a request that never arrives. A Planner outside the
+        pipeline announced nothing."""
+        settle = getattr(self.planner, "settle_cohort", None)
+        if settle is not None:
+            settle()
 
     # ------------------------------------------------------------------
 

@@ -819,3 +819,160 @@ def test_batching_off_worker_runs_dense_eval_on_its_dense_factory():
         assert stats["batches"] == 0 and stats["routed_host"] == 0, stats
     finally:
         server.shutdown()
+
+
+# ---------------------------------------------------------------------
+# the batch's cohort at the batcher: every way an announced eval can end
+# settles its unit, so no batch-mate's dispatch waits for a request that
+# is not coming and nothing is left for the cap to release
+
+
+def _run_batch_by_hand(server, pipe, jobs, extra=()):
+    """Register `jobs` (no worker runs: num_schedulers=0), take their
+    evals from the broker and run them as ONE batch of the UNSTARTED
+    pipeline `pipe`: the launch prologue, then the stage function for
+    every entry on a thread of its own, as the pool would. `extra`
+    entries (a requeued one) join the batch first. Returns the entries
+    and their units."""
+    import threading
+
+    from nomad_tpu.dispatch.pipeline import _Pending
+
+    for job in jobs:
+        server.job_register(job)
+    assert wait_until(
+        lambda: server.broker.ready_count() >= len(jobs), 15.0)
+    got = server.eval_dequeue_many(pipe.types, len(jobs))
+    assert len(got) == len(jobs)
+    batch = list(extra) + [_Pending(ev, token) for ev, token in got]
+    with pipe._cond:
+        pipe._inflight += 1  # the slot _accumulate would have taken
+    snapshot, route_host, units = pipe._launch_prologue(batch)
+    assert not route_host and len(units) == len(batch)
+    remaining = [len(batch)]
+    threads = [
+        threading.Thread(
+            target=pipe._process_entry, daemon=True,
+            args=(entry, snapshot, route_host, remaining,
+                  time.monotonic(), unit))
+        for entry, unit in zip(batch, units)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120.0)
+    assert not any(t.is_alive() for t in threads), "a stage thread hangs"
+    assert pipe.stats()["in_flight"] == 0
+    return batch, units
+
+
+@pytest.mark.parametrize("way", [
+    "host_fallback", "breaker_rejected", "no_placements",
+    "scheduler_raises", "requeue"])
+def test_an_announced_eval_that_never_places_settles_its_unit(
+        way, monkeypatch):
+    from nomad_tpu.admission import get_breaker
+    from nomad_tpu.chaos import FaultSpec, chaos
+    from nomad_tpu.dispatch import DispatchPipeline
+    from nomad_tpu.dispatch import pipeline as pipeline_mod
+    from nomad_tpu.scheduler.batcher import get_batcher
+
+    server = make_server(num_schedulers=0)
+    breaker = get_breaker()
+    try:
+        seed_nodes(server, 8)
+        pipe = DispatchPipeline(server)  # not started: driven by hand
+        before = get_batcher().stats()
+        fallbacks = _host_fallbacks()
+        # Three evals: a dense batch (dense_min_batch is 2). The first
+        # job is the one that never reaches place().
+        jobs = [_sized_job(f"{way}-{i}",
+                           count=2 if (way, i) == ("requeue", 0) else 5)
+                for i in range(3)]
+        if way == "no_placements":
+            # Registered again unchanged: its second eval has nothing
+            # to place, and the dense scheduler never computes any.
+            standing = [_sized_job(f"{way}-standing-{i}") for i in range(2)]
+            _run_batch_by_hand(server, pipe, [jobs[0]] + standing)
+            assert len(_live(server, jobs[0].id)) == 5
+            before = get_batcher().stats()
+        if way == "host_fallback":
+            # A fault at the breaker's gate, BEFORE place(): the dense
+            # scheduler's except path, then the host iterators.
+            chaos.arm(7, [FaultSpec("device.breaker_trip", "error",
+                                    count=1)])
+        elif way == "breaker_rejected":
+            verdicts = iter([False])
+            real_acquire = breaker.acquire
+            monkeypatch.setattr(
+                breaker, "acquire",
+                lambda: next(verdicts, None) is None and real_acquire())
+        elif way == "scheduler_raises":
+            chaos.arm(7, [FaultSpec("admission.slow_consumer", "error",
+                                    count=1)])
+        elif way == "requeue":
+            # Two asks go to the host iterators inside the dense
+            # scheduler; the plan then meets a conflict.
+            real_submit = pipeline_mod.PipelineSession.submit_plan
+            conflicts = iter([True])
+
+            def submit_plan(session, plan):
+                if (session.eval.job_id == jobs[0].id
+                        and next(conflicts, False)):
+                    raise pipeline_mod._RequeueConflict()
+                return real_submit(session, plan)
+
+            monkeypatch.setattr(pipeline_mod.PipelineSession,
+                                "submit_plan", submit_plan)
+
+        batch, units = _run_batch_by_hand(server, pipe, jobs)
+        chaos.disarm()
+        assert all(u is not None and not u.open for u in units)
+        assert units[0].cohort is units[1].cohort is units[2].cohort
+
+        def settled():
+            after = get_batcher().stats()
+            assert after["open_cohorts"] == before["open_cohorts"], after
+            assert after["closed_by_cap"] == before["closed_by_cap"], after
+            assert (after["closed_by_window"]
+                    == before["closed_by_window"]), after
+
+        settled()
+        stats = pipe.stats()
+        placed = {j.id: len(_live(server, j.id)) for j in jobs}
+        if way == "host_fallback":
+            assert _host_fallbacks() - fallbacks >= 1
+            assert placed == {j.id: 5 for j in jobs} and stats["acked"] == 3
+        elif way == "breaker_rejected":
+            assert placed == {j.id: 5 for j in jobs} and stats["acked"] == 3
+        elif way == "no_placements":
+            assert placed == {j.id: 5 for j in jobs} and stats["acked"] == 6
+            after = get_batcher().stats()
+            assert (after["batched_requests"] - before["batched_requests"]
+                    == 2), after
+        elif way == "scheduler_raises":
+            assert sorted(placed.values()) == [0, 5, 5]
+            assert stats["acked"] == 2 and stats["nacked"] == 1
+        else:
+            assert placed == {jobs[0].id: 0, jobs[1].id: 5, jobs[2].id: 5}
+            assert stats["requeues"] == 1 and stats["acked"] == 2
+            # The requeued eval is back in the accumulator; it joins the
+            # NEXT batch's cohort with a unit of its own.
+            with pipe._cond:
+                (requeued,) = pipe._pending
+                pipe._pending.clear()
+            assert requeued is batch[0] and requeued.requeues == 1
+            more = [_sized_job(f"{way}-more-{i}") for i in range(2)]
+            _batch2, units2 = _run_batch_by_hand(
+                server, pipe, more, extra=[requeued])
+            assert units2[0] is not units[0]
+            assert units2[0].cohort is units2[1].cohort
+            assert units2[0].cohort is not units[0].cohort
+            settled()
+            assert len(_live(server, jobs[0].id)) == 2
+            assert all(len(_live(server, j.id)) == 5 for j in more)
+            assert pipe.stats()["acked"] == 5
+    finally:
+        chaos.disarm()
+        breaker.reset()
+        breaker.configure_defaults()
+        server.shutdown()
