@@ -4,6 +4,27 @@ raft log, and the job templates the load generator registers.
 
 Everything comes from the configuration's JSON and `--seed`; a new
 deployment is a new file, not new code here.
+
+Two keys a deployment with racks and gangs may add, both optional (a
+file without them loads and registers exactly what it did before):
+
+- A class's `topology`: `{"rack": {"nodes_per_group": 24, "prefix":
+  "a"}}`, and `ici` likewise (the levels `models/topology.py` reads from
+  a node's `meta`). The class's i-th node, counted from 0 in load order,
+  gets `meta.rack = "<prefix><i // nodes_per_group>"`; `prefix` defaults
+  to the level's name, and the class's last group is short where its
+  count is no multiple. `ici` alone is numbered the same way. With both,
+  the rack's `nodes_per_group` has to be a multiple of the `ici` one and
+  `meta.ici = "<the node's rack>-<ici prefix><(i % rack size) // ici
+  size>"`: an `ici` group lies inside one rack and its name says which.
+  The node's `meta` is the class's `meta` plus these keys, and its
+  computed class is taken anew (the rack is part of it). The rule draws
+  nothing from the seed. Every class counts from 0, so two classes that
+  state one prefix share group names: rack `a0` then holds the first
+  `nodes_per_group` nodes of each of them, a mixed rack.
+- A job shape's `gang`: `{"slice": "rack"}` (or `affinity`, `spread`;
+  `rack` or `ici`; `{}` for no topology policy) becomes the task group's
+  gang stanza: its `count` members are placed all or none.
 """
 
 from __future__ import annotations
@@ -78,6 +99,27 @@ def _node_template(shape: dict, datacenter: str):
                 reserved_ports=[Port(f"r{p}", p) for p in res["ports"]])]))
 
 
+def _topology_meta(topology: dict, i: int) -> dict:
+    """The meta keys a class's `topology` rule gives its i-th node (the
+    module's docstring says how)."""
+    unknown = set(topology) - {"rack", "ici"}
+    if unknown:
+        raise ValueError(f"no topology level {sorted(unknown)}")
+    meta = {}
+    for level, rule in topology.items():
+        meta[level] = (f"{rule.get('prefix', level)}"
+                       f"{i // rule['nodes_per_group']}")
+    if len(topology) == 2:
+        rack, ici = (topology[level]["nodes_per_group"]
+                     for level in ("rack", "ici"))
+        if rack % ici:
+            raise ValueError(f"a rack of {rack} nodes does not hold whole "
+                             f"ici groups of {ici}")
+        meta["ici"] = (f"{meta['rack']}-{topology['ici'].get('prefix', 'ici')}"
+                       f"{i % rack // ici}")
+    return meta
+
+
 def _filler_job(datacenter: str, rule: dict):
     """A rule that states a priority gets a filler job of its own
     (`filler-p<priority>`), so that a fleet can hold several tiers."""
@@ -119,10 +161,14 @@ def load_fleet(server, config: dict, seed: int) -> dict:
         for rule in rules:
             job = _filler_job(dc, rule)
             tiers.append((rule, filler_jobs.setdefault(job.id, job)))
-        for _ in range(cls["count"]):
+        topology = cls.get("topology")
+        for i in range(cls["count"]):
             node = template.copy()
             node.id = seeded_uuid(rng)
             node.secret_id = seeded_uuid(rng)
+            if topology:
+                node.meta.update(_topology_meta(topology, i))
+                node.compute_class()
             server.log.apply("node_register", {"node": node})
             n_nodes += 1
             for rule, filler_job in tiers:
@@ -150,12 +196,13 @@ def load_fleet(server, config: dict, seed: int) -> dict:
 def job_template(spec: dict) -> dict:
     """One job shape (an entry of `job_specs`) as the JSON body's `job`;
     the generator fills in `id` and `name`."""
-    from nomad_tpu.structs import (Constraint, EphemeralDisk, Job,
+    from nomad_tpu.structs import (Constraint, EphemeralDisk, Gang, Job,
                                    NetworkResource, Port, Resources,
                                    RestartPolicy, Task, TaskGroup)
     from nomad_tpu.utils.codec import to_dict
 
     task = spec["task"]
+    gang = spec.get("gang")     # {} is a gang with no topology policy
     networks = []
     if task["mbits"] or task["dynamic_ports"]:
         networks = [NetworkResource(
@@ -178,6 +225,7 @@ def job_template(spec: dict) -> dict:
             name=spec["group"], count=spec["count"],
             constraints=group_constraints, restart_policy=restart,
             ephemeral_disk=EphemeralDisk(size_mb=spec["ephemeral_disk_mb"]),
+            gang=Gang(**gang) if gang is not None else None,
             tasks=[Task(
                 name=task["name"], driver=task["driver"],
                 config={"command": "/bin/date"},

@@ -61,6 +61,11 @@ import tracereduce  # noqa: E402
 GENERATOR_MAX_LIFE_S = 400.0
 READ_BACK_ALLOWANCE_S = 60.0
 WARMUP_POLL_S = 0.5
+# A run whose warm-up ends with nothing served by the device has no
+# window worth measuring: it says so and leaves with this code, and a
+# teardown that will not end is cut after LEAVE_S.
+EXIT_HOPELESS = 4
+LEAVE_S = 20.0
 
 
 def process_start() -> float:
@@ -171,6 +176,7 @@ class Run:
     def __init__(self, rehearse: bool, mark: str = ""):
         self.prefix = ("REHEARSAL " if rehearse else "") + mark
         self.checks: list = []
+        self.no_result = ""     # why the run ended without one
 
     def say(self, msg: str) -> None:
         print(self.prefix + msg, flush=True)
@@ -197,7 +203,25 @@ class Run:
             print(f"{self.prefix}{name} {row['value']} limit "
                   f"{row['limit']}{'' if row['ok'] else ' FAIL'}",
                   file=sys.stderr)
+        if self.no_result:
+            print(self.prefix + self.no_result, file=sys.stderr)
         sys.stderr.flush()
+
+
+class HopelessRun(Exception):
+    """Warm-up reached its bound and the device had served nothing."""
+
+
+def leave_within(seconds: float, code: int) -> None:
+    """From now the process has `seconds` to end by itself; then it is
+    ended, whatever thread of the server will not join."""
+    def cut():
+        time.sleep(seconds)
+        sys.stdout.flush()
+        sys.stderr.flush()
+        os._exit(code)
+
+    threading.Thread(target=cut, name="leave", daemon=True).start()
 
 
 def warm_up(run: Run, conn, rule: dict, child) -> dict:
@@ -206,9 +230,15 @@ def warm_up(run: Run, conn, rule: dict, child) -> dict:
     been still for `still_s` while `still_dispatches` device dispatches
     completed; no sooner than `min_s`, bounded by `max_s`. Counting work
     and not only seconds is for the run that compiles: while a program
-    compiles the count is still too, but nothing completes."""
+    compiles the count is still too, but nothing completes. A warm-up
+    that reaches its bound goes on into the window if the device served
+    anything in it (`bounded`), and is a HopelessRun if it served
+    nothing: the program cannot run this cell on the device, and a
+    window of it would take minutes to say so."""
     start = time.monotonic()
     last_size, last_change, dispatches_then = -1, start, 0
+    served_then = counters.read_counters(conn).get(
+        "batcher.batched_requests", 0)
     while True:
         time.sleep(WARMUP_POLL_S)
         if child.poll() is not None:
@@ -231,6 +261,12 @@ def warm_up(run: Run, conn, rule: dict, child) -> dict:
         if now - start >= rule["max_s"]:
             run.say(f"warm-up hit its bound of {rule['max_s']} s with "
                     f"{size} programs, still for {still:.1f} s")
+            if c.get("batcher.batched_requests", 0) <= served_then:
+                raise HopelessRun(
+                    f"warm-up reached its bound of {rule['max_s']} s and "
+                    f"the device served no request in it "
+                    f"(batcher.batched_requests still {served_then}, "
+                    f"{size} programs)")
             return {"seconds": now - start, "programs": size, "bounded": True}
 
 
@@ -382,7 +418,8 @@ def judge(run: Run, config: dict, registered: list, snapshot, before: dict,
     window_jobs = {
         s["job_id"]: {"count": shape["count"],
                       "distinct_hosts": shape["distinct_hosts"],
-                      "template": shape["name"], "priority": shape["priority"]}
+                      "template": shape["name"], "priority": shape["priority"],
+                      "gang": shape.get("gang")}
         for s, shape in ((s, shape_of(s)) for s in registered)}
     verdict = reference.judge(store, window_jobs, port_range)
     for name, value in sorted(verdict["counts"].items()):
@@ -493,7 +530,15 @@ def run_cell(args, run: Run) -> int:
         child = start_generator(http.addr, cell, config, traffic, args.seed,
                                 args.seconds)
         t0 = time.monotonic()
-        warm = warm_up(run, conn, traffic["warmup"], child)
+        try:
+            warm = warm_up(run, conn, traffic["warmup"], child)
+        except HopelessRun as e:
+            # no window, no drain: the generator is stopped below, and
+            # whatever of the server will not stop is cut
+            leave_within(LEAVE_S, EXIT_HOPELESS)
+            run.no_result = f"no result: {e}"
+            run.say(run.no_result)
+            return EXIT_HOPELESS
         warmup_s = time.monotonic() - t0
 
         # ---- the window

@@ -12,6 +12,8 @@ import numpy as np
 
 READY = "ready"
 LIVE_DESIRED = "run"
+# The node meta keys that name a topology group (models/topology.py)
+TOPOLOGY_KEYS = ("rack", "ici")
 DEAD_CLIENT = ("complete", "failed", "lost")
 
 
@@ -78,9 +80,10 @@ def dump_store(state) -> dict:
     job_ids, job_row = [], {}
     alloc_ids, alloc_eval = [], []
     alloc_node, alloc_job, usage, alloc_mbits = [], [], [], []
-    alloc_priority = []
+    alloc_priority, alloc_group, alloc_name = [], [], []
     port_alloc, port_value = [], []
-    gone = {"ids": [], "node": [], "job": [], "priority": [], "desired": []}
+    gone = {"ids": [], "node": [], "job": [], "priority": [], "desired": [],
+            "group": []}
     for alloc in state.allocs():
         if alloc.desired_status != LIVE_DESIRED:
             # stopped or evicted: kept apart for a deployment's own
@@ -90,6 +93,7 @@ def dump_store(state) -> dict:
             gone["job"].append(alloc.job_id)
             gone["priority"].append(_priority(alloc))
             gone["desired"].append(alloc.desired_status)
+            gone["group"].append(alloc.task_group)
             continue
         if alloc.client_status in DEAD_CLIENT:
             continue
@@ -104,6 +108,8 @@ def dump_store(state) -> dict:
         alloc_job.append(j)
         usage.append(_alloc_total(alloc))
         alloc_priority.append(_priority(alloc))
+        alloc_group.append(alloc.task_group)
+        alloc_name.append(alloc.name)
         bw = 0
         # The first network of each task is the one the reference's
         # NetworkIndex counts (network.go AddAllocs).
@@ -139,4 +145,13 @@ def dump_store(state) -> dict:
         "gone_priority": np.asarray(gone["priority"], np.int64),
         "gone_desired": gone["desired"],
         **_evals(state),
+        # for a deployment's own checks (racks, gangs): plain lists, one
+        # string a node and level ("" where the node states none), the
+        # task group and the name of each live allocation in the order of
+        # `alloc_ids`, the task group of each gone one
+        "node_meta": {key: [node.meta.get(key) or "" for node in nodes]
+                      for key in TOPOLOGY_KEYS},
+        "alloc_group": alloc_group,
+        "alloc_name": alloc_name,
+        "gone_group": gone["group"],
     }

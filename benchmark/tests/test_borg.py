@@ -174,7 +174,12 @@ def test_accepted_benchmark_files_are_byte_for_byte():
     """Every file the benchmark had before PR 28 (the digests of PR 27's
     commit) is still there and unchanged; BENCHMARK.json's accepted
     entries are unchanged but for the open-loop latency's list of
-    cells."""
+    cells. PR 32, a benchmark PR, edited five of them (`fleet.py`,
+    `store_dump.py`, `run.py`, `tests/test_data_driven.py`,
+    `tests/test_rehearsal.py`): their digests are that PR's, and what
+    the first three still do for the committed configurations is held by
+    `test_committed_configurations_load_as_the_parent_loaded_them` and
+    the pinned checks and metrics of `test_rehearsal.py`."""
     accepted = json.load(open(os.path.join(
         HERE, "data", "accepted_digests.json")))
     for name, digest in accepted.items():

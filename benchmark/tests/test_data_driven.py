@@ -9,6 +9,10 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
+import gang_racks_fixture
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -149,7 +153,7 @@ def test_a_tiered_deployment_is_files_and_entries_only(tmp_path):
     config["rehearsal"] = {"fleet_scale": 0.0256,
                            "job_count": {"prod": 4, "batch": 2}}
     json.dump(config, open(bench_dir / "configs" / "tiers-2shape.json", "w"))
-    os.mkdir(bench_dir / "checks")
+    os.makedirs(bench_dir / "checks", exist_ok=True)
     open(bench_dir / "checks" / "tiers.py", "w").write(CHECK_FILE)
     json.dump({"kind": "open",
                "arrivals": {"process": "bursts", "rate_evals_per_s": 16,
@@ -253,3 +257,181 @@ def test_filler_tiers_and_legacy_draws():
     assert sorted(store["alloc_priority"]) == [20] * 8 + [70] * 4
     assert {v[0] for v in allocs.values()} == {"filler-p20", "filler-p70"}
     assert {v[3] for v in allocs.values() if v[0] == "filler-p20"} == {"batch"}
+
+
+def rehearse(tmp_path, cell, seed, trace, script="benchmark/run.py"):
+    proc = subprocess.run(
+        [sys.executable, script, "--workload", cell, "--seed", str(seed),
+         "--seconds", "6", "--trace", str(trace), "--rehearse"],
+        cwd=tmp_path, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return proc, proc.stdout.strip().splitlines()
+
+
+GANG_COUNTS = ("gangs_split_across_groups", "gangs_partial",
+               "members_without_group", "no_gang_placed")
+
+
+def test_a_deployment_of_racks_and_gangs_is_files_and_entries_only(tmp_path):
+    """What the queue's gang deployment will need, at fixture size
+    (`gang_racks_fixture.py`): two server shapes in racks stated by
+    rule, fillers that leave room for a gang member in some racks only,
+    a plain shape and two `slice: rack` gang shapes by share, a check
+    that sees racks and task groups, a metric over `gang.select`: all of
+    it new files and entries."""
+    bench_dir = gang_racks_fixture.copy_benchmark(tmp_path)
+    before = digests(bench_dir)
+    gang_racks_fixture.install(tmp_path)
+
+    _proc, lines = rehearse(tmp_path, gang_racks_fixture.CELL, 2**31 + 32, 1)
+    result = json.loads(lines[-1][len("REHEARSAL "):])
+    assert result["correct"] is True, [l for l in lines if "FAIL" in l]
+    assert result["attempted"] == 48 and result["failed"] == 0  # 8/s x 6 s
+    for name in GANG_COUNTS:
+        assert result["compared"][f"gang_slices.{name}"] == {
+            "value": 0, "limit": 0, "ok": True}
+    # the gang pass ran on the device path and its span has samples
+    assert result["metrics"]["gang_select_p50_ms"]["value"] > 0
+    assert result["metrics"]["gang_select_p95_ms"]["value"] > 0
+    assert "place_due_p95_ms" in result["metrics"]
+    assert "preempt_select_p50_ms" not in result["metrics"]
+    fleet_line = next(l for l in lines if " fleet: " in l)
+    # 36 + 12 + 15 + 8 servers; fillers on the busy ones: 36 x 3 + 15 x 5
+    assert "'nodes': 71, 'filler_allocs': 183" in fleet_line, fleet_line
+
+    after = digests(bench_dir)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert set(after) - set(before) == gang_racks_fixture.ADDED
+
+
+ROTATED = '''"""The control of the fixture's check: the run as it is, the check handed
+a `node_meta` whose groups are rotated by one node."""
+import os
+import sys
+
+sys.path[:0] = [os.path.join(os.getcwd(), "benchmark"), os.getcwd()]
+import run
+import store_dump
+
+dump = store_dump.dump_store
+
+
+def rotated(state):
+    store = dump(state)
+    store["node_meta"] = {level: names[1:] + names[:1]
+                          for level, names in store["node_meta"].items()}
+    return store
+
+
+store_dump.dump_store = rotated
+sys.exit(run.main(mark="CONTROL rotated "))
+'''
+
+
+def test_racks_rotated_by_one_node_are_not_correct(tmp_path):
+    gang_racks_fixture.copy_benchmark(tmp_path)
+    gang_racks_fixture.install(tmp_path)
+    open(tmp_path / "rotated.py", "w").write(ROTATED)
+    _proc, lines = rehearse(tmp_path, gang_racks_fixture.CELL, 2**31 + 33, 0,
+                            script="rotated.py")
+    prefix = "REHEARSAL CONTROL rotated "
+    assert all(line.startswith(prefix) for line in lines)
+    result = json.loads(lines[-1][len(prefix):])
+    assert result["correct"] is False
+    split = result["compared"]["gang_slices.gangs_split_across_groups"]
+    assert split["value"] > 0 and split["ok"] is False
+    assert list(result["compared"])[0] == "gang_slices.gangs_split_across_groups"
+    # the placement itself was sound: nothing else failed
+    assert [name for name, row in result["compared"].items()
+            if not row["ok"]] == ["gang_slices.gangs_split_across_groups"]
+
+
+class _Log:
+    """Stands where `server.log` does: `load_fleet` applies entries and
+    reads nothing."""
+
+    def __init__(self):
+        self.entries = []
+        self.log = self
+
+    def apply(self, kind, payload):
+        self.entries.append((kind, payload))
+
+
+def fleet_golden():
+    """For each committed configuration: the job dictionaries of its
+    shapes, and the nodes and fillers of a 50-node slice of its fleet in
+    load order, each as a digest."""
+    import fleet
+
+    def sha(obj):
+        return hashlib.sha256(json.dumps(obj).encode()).hexdigest()
+
+    out = {}
+    for name in ("northstar-10k", "c1m-5k", "borg-12k"):
+        config = json.load(open(os.path.join(
+            ROOT, "benchmark", "configs", f"{name}.json")))
+        jobs = [fleet.job_template(spec) for spec in fleet.job_specs(config)]
+        classes = config["fleet"]["classes"]
+        per = -(-50 // len(classes))
+        config["fleet"]["classes"] = [dict(c, count=per) for c in classes]
+        server = _Log()
+        loaded = fleet.load_fleet(server, config, 2**31 + 7)
+        nodes, fillers = [], []
+        for kind, payload in server.entries:
+            if kind == "node_register":
+                n = payload["node"]
+                nodes.append([n.id, n.secret_id, n.node_class,
+                              n.computed_class, sorted(n.meta.items())])
+            else:
+                for a in payload["allocs"]:
+                    r = a.task_resources["web"]
+                    fillers.append([a.id, a.node_id, a.name, a.job_id,
+                                    a.job.priority, r.cpu, r.memory_mb])
+        out[name] = {"jobs": sha(jobs), "nodes": sha(nodes),
+                     "fillers": sha(fillers), "loaded": loaded}
+    return out
+
+
+def test_committed_configurations_load_as_the_parent_loaded_them():
+    """`data/fleet_golden.json` was written by PR 32 from the parent's
+    `fleet.py` (PR 31's commit), before `job_template` took a `gang` and
+    `load_fleet` a `topology`: a configuration with neither gives the same
+    job dictionaries, key for key, and the same fleet, id for id."""
+    golden = json.load(open(os.path.join(
+        ROOT, "benchmark", "tests", "data", "fleet_golden.json")))
+    assert fleet_golden() == golden
+
+
+def test_topology_rule_names_racks_and_nested_ici_groups():
+    import fleet
+
+    config = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "northstar-10k.json")))
+    cls = config["fleet"]["classes"][0]
+    cls["count"], cls["filler"] = 7, {"per_node": 0}
+
+    def metas(topology):
+        cls["topology"] = topology
+        server = _Log()
+        fleet.load_fleet(server, config, 5)
+        nodes = [payload["node"] for _kind, payload in server.entries]
+        # the class's own meta stays; the rack is part of the class
+        assert all(n.meta["pci-dss"] == "true" for n in nodes)
+        assert len({n.computed_class for n in nodes}) == len(
+            {tuple(sorted(n.meta.items())) for n in nodes})
+        return [(n.meta.get("rack"), n.meta.get("ici")) for n in nodes]
+
+    assert metas({"rack": {"nodes_per_group": 4, "prefix": "a"}}) == [
+        ("a0", None)] * 4 + [("a1", None)] * 3
+    assert metas({"ici": {"nodes_per_group": 3}}) == [
+        (None, "ici0")] * 3 + [(None, "ici1")] * 3 + [(None, "ici2")]
+    assert metas({"rack": {"nodes_per_group": 4, "prefix": "a"},
+                  "ici": {"nodes_per_group": 2, "prefix": "i"}}) == [
+        ("a0", "a0-i0"), ("a0", "a0-i0"), ("a0", "a0-i1"), ("a0", "a0-i1"),
+        ("a1", "a1-i0"), ("a1", "a1-i0"), ("a1", "a1-i1")]
+    with pytest.raises(ValueError):
+        metas({"rack": {"nodes_per_group": 4}, "ici": {"nodes_per_group": 3}})
+    with pytest.raises(ValueError):
+        metas({"row": {"nodes_per_group": 4}})

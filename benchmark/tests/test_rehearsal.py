@@ -1,11 +1,12 @@
 """Each cell of BENCHMARK.json end to end at rehearsal size, as the
 driver would start it (a process of its own), both with and without the
-trace; and the two ways a run has to end with no result."""
+trace; and the three ways a run has to end with no result."""
 
 import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -56,7 +57,8 @@ PINNED_METRICS = {
     ("c1m-5k.ramp", 0): {"placed_allocs_per_s", "setup_s"},
     ("c1m-5k.ramp", 1): PINNED_PER_LAYER,
 }
-ADDED_SINCE = {"plans_per_commit", "small_route_host_evals_per_eval"}
+ADDED_SINCE = {"plans_per_commit", "small_route_host_evals_per_eval",
+               "device_transfer_p50_ms"}
 
 
 def start(args, cwd=ROOT, script=None):
@@ -131,3 +133,85 @@ def test_no_program_no_result(tmp_path):
     assert proc.returncode == 2, proc.stderr[-2000:]
     assert "not beside the benchmark" in proc.stderr
     assert not any("correct" in line for line in proc.stdout.splitlines())
+
+
+def test_a_hopeless_run_ends_early_and_non_zero(tmp_path):
+    """Jobs that never reach the device: two allocations an eval, which
+    the dense scheduler hands to the host iterators, each larger than any
+    node. Warm-up reaches its bound with nothing served by the device;
+    the run says so in its last line and leaves with a code of its own,
+    well inside the bound plus 30 s: no window, no drain, no result."""
+    import gang_racks_fixture
+    import run
+
+    max_s = 8
+    gang_racks_fixture.copy_benchmark(tmp_path)
+    config = json.load(open(tmp_path / "benchmark" / "configs"
+                            / "northstar-10k.json"))
+    config["name"] = "too-big"
+    config["job"].update(count=2)
+    config["job"]["task"]["cpu"] = 100000
+    config["rehearsal"]["job_count"] = 2
+    json.dump(config, open(tmp_path / "benchmark" / "configs"
+                           / "too-big.json", "w"))
+    traffic = json.load(open(tmp_path / "benchmark" / "traffic"
+                             / "steady.json"))
+    traffic["rehearsal"]["warmup"]["max_s"] = max_s
+    json.dump(traffic, open(tmp_path / "benchmark" / "traffic"
+                            / "hopeless.json", "w"))
+    bench = dict(BENCH)
+    bench["configs"] = BENCH["configs"] + [{
+        "name": "too-big", "source": "test fixture",
+        "file": "benchmark/configs/too-big.json", "reduced": [],
+        "why": "fixture"}]
+    bench["workloads"] = BENCH["workloads"] + [{
+        "name": "too-big.hopeless", "config": "too-big",
+        "traffic": "hopeless", "chips": 1, "why": "fixture"}]
+    json.dump(bench, open(tmp_path / "BENCHMARK.json", "w"))
+
+    t0 = time.monotonic()
+    proc = start(["--workload", "too-big.hopeless", "--seed", str(2**31 + 9),
+                  "--seconds", "5", "--trace", "0", "--rehearse"],
+                 cwd=tmp_path, script=str(tmp_path / "benchmark" / "run.py"))
+    took = time.monotonic() - t0
+    assert proc.returncode == run.EXIT_HOPELESS, proc.stderr[-2000:]
+    assert took < max_s + 30, took
+    lines = proc.stdout.strip().splitlines()
+    assert lines[-1].startswith("REHEARSAL no result: warm-up reached its "
+                                f"bound of {max_s} s and the device served "
+                                "no request"), lines[-1]
+    assert not any("correct" in line for line in lines)
+    assert proc.stderr.strip().splitlines()[-1] == lines[-1]
+
+
+@pytest.mark.parametrize("served,hopeless", [(0, True), (7, False)])
+def test_a_bounded_warm_up_goes_on_only_with_work_served(monkeypatch, served,
+                                                         hopeless):
+    """A warm-up that reaches its bound because the program count never
+    stood still goes on into the window as it always did, if the device
+    served anything meanwhile; with nothing served it is a HopelessRun."""
+    import run
+
+    reads = []
+
+    def read_counters(_conn):
+        reads.append(None)
+        n = len(reads)
+        return {"batcher.jit_cache_size": n, "batcher.dispatches": n,
+                "batcher.batched_requests": 5 + (served if n > 1 else 0)}
+
+    class Child:
+        def poll(self):
+            return None
+
+    monkeypatch.setattr(run.counters, "read_counters", read_counters)
+    monkeypatch.setattr(run, "WARMUP_POLL_S", 0.01)
+    rule = {"min_s": 0, "still_s": 1, "still_dispatches": 1,
+            "min_requests": 1, "max_s": 0.05}
+    quiet = run.Run(rehearse=True)
+    if hopeless:
+        with pytest.raises(run.HopelessRun, match="served no request"):
+            run.warm_up(quiet, None, rule, Child())
+    else:
+        warm = run.warm_up(quiet, None, rule, Child())
+        assert warm["bounded"] is True and warm["programs"] > 1
