@@ -7,7 +7,12 @@ places its ``count`` members ATOMICALLY — all K or none:
 - the dense leg (ops/gang.py) runs the all-K feasibility pass over
   the device-resident cluster base: per-node member capacity ->
   topology-group cumulative capacity -> contiguous-slice selection ->
-  K-step member assignment, with all-K enforcement on device;
+  K-step member assignment, with all-K enforcement on device. A gang
+  is dispatched through the placement batcher (scheduler/batcher.py
+  place_gang) like any dense ask: it joins its pipeline batch's
+  cohort, reads the resident base and its deltas, and the gangs of one
+  dispatch are solved in one program that carries each gang's claims
+  to the next;
 - the host leg (gang/host.py) mirrors the semantics through the
   sequential iterator stack — parity target, oracle for the
   differential rig (kernels/differential.py ``judge_gang_plan``), and
@@ -30,7 +35,7 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from ..structs import Job, TaskGroup, consts
 
@@ -44,8 +49,10 @@ __all__ = [
     "gang_key",
     "gang_mode",
     "build_gang_config",
+    "build_gang_request",
     "build_gang_state",
     "gang_distinct_hosts",
+    "note_gang_dispatch",
     "note_gang_result",
     "gang_stats",
     "reset_gang_stats",
@@ -128,15 +135,25 @@ def build_gang_config(job: Job, tg: TaskGroup, topo_groups: int):
     )
 
 
-def build_gang_state(matrix, job: Job, tg: TaskGroup):
-    """(GangState, active [K_pad], ask (res, bw, ports), config) for
-    one gang dispatch against a ClusterMatrix. Reuses the matrix's
+class GangRequest(NamedTuple):
+    """One gang's dispatch against a ClusterMatrix: its own lane
+    (ops/gang.py GangLane), the topology column it reads with the key
+    the device's copy is kept under, and the static config."""
+
+    lane: object  # ops.gang.GangLane
+    topo_ids: object  # [N] int32 host column
+    topo_key: tuple
+    config: object  # ops.gang.GangConfig
+
+
+def build_gang_request(matrix, job: Job, tg: TaskGroup) -> GangRequest:
+    """The request one gang hands the batcher. Reuses the matrix's
     memoized feasibility mask and overlay counts — the gang pass adds
     no per-eval host recomputation beyond slicing them."""
     import numpy as np
 
     from ..models.matrix import ASK_BUCKETS, bucket_size
-    from ..ops.gang import GANG_MODE_SLICE, make_gang_state
+    from ..ops.gang import GANG_MODE_SLICE, make_gang_lane
 
     gi = next(i for i, g in enumerate(job.task_groups)
               if g.name == tg.name)
@@ -149,17 +166,16 @@ def build_gang_state(matrix, job: Job, tg: TaskGroup):
     # (one row; gang members are identical by construction).
     resources, bw, ports, _tgi, _act, _jdh, _tdh = \
         matrix.build_asks([gi])
-    ask_res, ask_bw, ask_ports = resources[0], bw[0], ports[0]
 
     mode, level = gang_mode(gang_spec(tg))
     topo = matrix.topology
-    if mode == GANG_MODE_SLICE:
+    singleton = mode != GANG_MODE_SLICE
+    if singleton:
+        topo_ids, topo_groups = topo.singleton_column(level)
+    else:
         topo_ids = topo.column(level)
         topo_groups = topo.counts[level]
-    else:
-        topo_ids, topo_groups = topo.singleton_column(level)
 
-    feas_row = matrix.feasible[:, gi] & matrix.node_ok
     dh = gang_distinct_hosts(job, tg)
     job_dh = any(c.operand == consts.CONSTRAINT_DISTINCT_HOSTS
                  for c in job.constraints)
@@ -169,12 +185,31 @@ def build_gang_state(matrix, job: Job, tg: TaskGroup):
     else:
         dh_presence = np.zeros(matrix.n, np.int32)
 
+    return GangRequest(
+        lane=make_gang_lane(matrix.feasible[:, gi], matrix.job_count,
+                            dh_presence, resources[0], bw[0], ports[0],
+                            active),
+        topo_ids=topo_ids,
+        topo_key=topo.device_key(level, singleton),
+        config=build_gang_config(job, tg, topo_groups))
+
+
+def build_gang_state(matrix, job: Job, tg: TaskGroup):
+    """(GangState, active [K_pad], ask (res, bw, ports), config) for
+    the single-gang program (ops/gang.py gang_placement_program, the
+    plain reference of one lane) against a ClusterMatrix: the same
+    request, with the node arrays from the matrix's host side."""
+    from ..ops.gang import make_gang_state
+
+    req = build_gang_request(matrix, job, tg)
+    lane = req.lane
     state = make_gang_state(
         matrix.capacity, matrix.sched_capacity, matrix.util,
         matrix.bw_avail, matrix.bw_used, matrix.ports_free,
-        feas_row, matrix.job_count, dh_presence, topo_ids)
-    config = build_gang_config(job, tg, topo_groups)
-    return state, active, (ask_res, ask_bw, ask_ports), config
+        lane.feas_row & matrix.node_ok, lane.job_count,
+        lane.dh_presence, req.topo_ids)
+    return (state, lane.active,
+            (lane.ask_res, lane.ask_bw, lane.ask_ports), req.config)
 
 
 # ---------------------------------------------------------------- stats
@@ -197,9 +232,25 @@ def note_gang_result(placed: bool, members: int, path: str) -> None:
         _stats[key] = _stats.get(key, 0) + 1
 
 
-def gang_stats() -> Dict[str, int]:
+def note_gang_dispatch(gangs: int, moved: int) -> None:
+    """Count one device dispatch of the gang program (the batcher's):
+    how many gangs rode it, and how many of them an earlier lane's
+    claims moved off the rack they would have taken alone."""
     with _stats_lock:
-        return dict(_stats)
+        _stats["dispatches"] = _stats.get("dispatches", 0) + 1
+        _stats["dispatched_gangs"] = (
+            _stats.get("dispatched_gangs", 0) + gangs)
+        _stats["moved_by_claims"] = _stats.get("moved_by_claims", 0) + moved
+
+
+def gang_stats() -> Dict[str, object]:
+    """Counters, and `gangs_per_dispatch` once a dispatch has gone."""
+    with _stats_lock:
+        out: Dict[str, object] = dict(_stats)
+    if out.get("dispatches"):
+        out["gangs_per_dispatch"] = round(
+            out["dispatched_gangs"] / out["dispatches"], 3)
+    return out
 
 
 def reset_gang_stats() -> None:

@@ -16,6 +16,13 @@ plane for that residency:
   rebuild of the node axis (the matrix is built over the full
   datacenter *universe*, not the ready subset, exactly so readiness
   is row state rather than matrix shape).
+- **the topology tensor** — the rack and ici id columns
+  (models/topology.py) are node-level like the node axis itself: every
+  delta clone of a base shares its parent's tensor, and the batcher
+  keeps each column a gang dispatch has read resident beside the base
+  under the tensor's own token (scheduler/batcher.py
+  ``_device_topology``), so a column is uploaded once for every rebuild
+  of the node set (``topo_uploads``), not once a base token.
 - **rebuild policy** — thresholds for when a delta stops being worth
   it (too many touched rows) or stops being *possible* (alloc
   deletions, node registrations, capacity edits), with counters that
@@ -194,4 +201,5 @@ def device_state_stats() -> Dict[str, object]:
     out["base_uploads"] = b["base_uploads"]
     out["base_delta_updates"] = b["base_delta_updates"]
     out["upload_bytes"] = b["upload_bytes"]
+    out["topo_uploads"] = b["topo_uploads"]
     return out

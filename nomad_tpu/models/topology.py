@@ -20,6 +20,12 @@ refuses the row delta) — breaks the delta family and re-anchors with a
 full rebuild, which re-derives the tensor. That is how register/
 deregister keeps the tensor current without a dedicated update path.
 
+On the device the same holds: a gang dispatch reads its id column
+resident beside the cluster base (scheduler/batcher.py keeps it under
+``device_key``, the index's own ``token`` and the level), so a column
+crosses host->device once for every rebuild of the tensor, however
+many base tokens the delta family goes through in between.
+
 Padding/missing conventions (shared with ops/gang.py):
 
 - rows past ``n_real`` (bucket padding) carry ``-1``;
@@ -64,11 +70,17 @@ class TopologyIndex:
     padded [n_pad] int32 column (-1 = missing/padding), ``names[level]``
     the interned group-name list (id -> name)."""
 
-    __slots__ = ("n_real", "n_pad", "ids", "names", "counts")
+    __slots__ = ("n_real", "n_pad", "ids", "names", "counts", "token",
+                 "_singletons")
 
     def __init__(self, nodes, n_pad: int):
         self.n_real = len(nodes)
         self.n_pad = n_pad
+        # Identity of this tensor, shared by every delta clone of the
+        # base that built it: what the device's copy of a column is
+        # kept under (device_key).
+        self.token = object()
+        self._singletons: Dict[str, Tuple[np.ndarray, int]] = {}
         self.ids: Dict[str, np.ndarray] = {}
         self.names: Dict[str, List[str]] = {}
         self.counts: Dict[str, int] = {}
@@ -103,12 +115,22 @@ class TopologyIndex:
         """The level's column with MISSING rows remapped to unique
         singleton group ids (spread/affinity semantics: a node without
         the meta key is its own group). Returns (column, group_count
-        including singletons); padding rows stay -1."""
-        col = self.ids[level].copy()
-        base = self.counts[level]
-        missing = np.flatnonzero(col[: self.n_real] < 0)
-        col[missing] = base + np.arange(len(missing), dtype=np.int32)
-        return col, base + len(missing)
+        including singletons); padding rows stay -1. Built once an
+        index (read-only by contract, like column())."""
+        hit = self._singletons.get(level)
+        if hit is None:
+            col = self.ids[level].copy()
+            base = self.counts[level]
+            missing = np.flatnonzero(col[: self.n_real] < 0)
+            col[missing] = base + np.arange(len(missing), dtype=np.int32)
+            hit = self._singletons[level] = (col, base + len(missing))
+        return hit
+
+    def device_key(self, level: str, singleton: bool) -> tuple:
+        """What the batcher keeps the device's copy of one column
+        under: requests with one key share the column, so they may
+        share a dispatch."""
+        return (self.token, level, singleton)
 
 
 def node_topology_summary(nodes) -> Dict[str, Dict[str, int]]:

@@ -679,6 +679,7 @@ NTA_JIT_ACCOUNTED = (
     "device_resident",
     "preempt_placement_program_jit",
     "gang_placement_program_jit",
+    "batched_gang_placement_program_jit",
     # parallel/shard.py program factories, accounted via
     # shard_cache_size() (one compile per (mesh, pad) build key).
     "sharded_base_delta",
@@ -695,7 +696,10 @@ def _jit_entry_points():
         # (ops/gang.py) are part of the placement path's compile
         # budget: jit_cache_size() must see their caches too, or a
         # preemption/gang shape leak would hide.
-        from .gang import gang_placement_program_jit
+        from .gang import (
+            batched_gang_placement_program_jit,
+            gang_placement_program_jit,
+        )
         from .preempt import preempt_placement_program_jit
 
         _JIT_ENTRY_POINTS = (
@@ -708,6 +712,7 @@ def _jit_entry_points():
             device_resident,
             preempt_placement_program_jit,
             gang_placement_program_jit,
+            batched_gang_placement_program_jit,
         )
     return _JIT_ENTRY_POINTS
 

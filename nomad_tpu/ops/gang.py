@@ -32,6 +32,16 @@ so the gang leg compiles once per (bucket, config) and steady-state
 ``jit_recompiles`` stays 0 (it joins the placement path's jit
 accounting in ops/binpack.py).
 
+A DISPATCH is B gangs (scheduler/batcher.py queues gang requests like
+plain ones and stacks them): ``batched_gang_placement_program`` scans
+the lanes in the batch's order and carries utilisation, bandwidth and
+ports from one gang to the next, so the gangs of a burst, which all
+plan on one snapshot and would all pick the same tightest rack, see
+each other's claims on device instead of colliding at the plan applier
+(the plain program's pre_resolve, for gangs). Inside a lane the pass is
+``gang_placement_program``, which stays the plain reference of one
+lane. The batch axis rides the batcher's BATCH_BUCKETS.
+
 The host twin lives in nomad_tpu/gang/host.py; the plan applier's
 per-node verification plus the ``Plan.gang_groups`` atomicity leg
 (server/plan_apply.py) make any device approximation cost a replan,
@@ -70,6 +80,12 @@ class GangConfig(NamedTuple):
     distinct_hosts: bool = False
     g_pad: int = 16
     noise_scale: float = 2.0
+
+    @property
+    def kernel(self) -> str:
+        """What a dispatch of this program is called where a plain one
+        names its placement kernel (the device.solve span)."""
+        return "gang"
 
 
 class GangState(NamedTuple):
@@ -147,6 +163,36 @@ def _group_capacity(units, topo_ids, g_pad):
         units, mode="drop")
 
 
+def _group_noise(key, g_pad: int):
+    """One uniform draw per topology group: the slice choice's
+    tie-break, from the caller's key."""
+    return jax.random.uniform(
+        jax.random.fold_in(key, 1), (g_pad,), minval=0.0, maxval=1.0)
+
+
+def _select_slice(group_cap, topo_ids, k_actual, group_noise,
+                  config: GangConfig):
+    """Slice selection: the tightest topology group whose capacity
+    covers all K, noise tie-broken. Returns (chosen group id [] int32,
+    -1 when the mode has no slice or no group covers the gang; [N] bool
+    mask of the nodes a member may take)."""
+    n = topo_ids.shape[0]
+    if config.mode != GANG_MODE_SLICE:
+        return jnp.int32(-1), jnp.ones(n, bool)
+    with jax.named_scope("gang_slice_select"):
+        covers = group_cap >= k_actual
+        # Smaller sufficient capacity scores higher; noise < 1 breaks
+        # exact-capacity ties without reordering distinct capacities.
+        gscore = jnp.where(covers, -group_cap + group_noise, NEG_INF)
+        best = jnp.argmax(gscore)
+        any_group = gscore[best] > NEG_INF / 2
+        chosen_group = jnp.where(any_group, best, -1).astype(jnp.int32)
+        # A -1 sentinel must match NOTHING: compare against g_pad + 1
+        # (no real id) when no group covers the gang.
+        match = jnp.where(any_group, best, config.g_pad + 1)
+        return chosen_group, topo_ids == match
+
+
 def gang_placement_program(state: GangState, ask_res, ask_bw, ask_ports,
                            active, key, config: GangConfig):
     """Place one gang of K uniform members. ``active`` is the [K]
@@ -164,27 +210,13 @@ def gang_placement_program(state: GangState, ask_res, ask_bw, ask_ports,
     # caller's host key (binpack.host_prng_key layout).
     noise = jax.random.uniform(
         key, (k, n), minval=0.0, maxval=config.noise_scale)
-    group_noise = jax.random.uniform(
-        jax.random.fold_in(key, 1), (g_pad,), minval=0.0, maxval=1.0)
+    group_noise = _group_noise(key, g_pad)
 
     units = _member_units(state, ask_res, ask_bw, ask_ports, config)
     group_cap = _group_capacity(units, state.topo_ids, g_pad)
 
-    # ---- slice selection: tightest covering group, noise tie-broken.
-    chosen_group = jnp.int32(-1)
-    slice_mask = jnp.ones(n, bool)
-    if config.mode == GANG_MODE_SLICE:
-        covers = group_cap >= k_actual
-        # Smaller sufficient capacity scores higher; noise < 1 breaks
-        # exact-capacity ties without reordering distinct capacities.
-        gscore = jnp.where(covers, -group_cap + group_noise, NEG_INF)
-        best = jnp.argmax(gscore)
-        any_group = gscore[best] > NEG_INF / 2
-        chosen_group = jnp.where(any_group, best, -1).astype(jnp.int32)
-        # A -1 sentinel must match NOTHING: compare against g_pad + 1
-        # (no real id) when no group covers the gang.
-        match = jnp.where(any_group, best, g_pad + 1)
-        slice_mask = state.topo_ids == match
+    chosen_group, slice_mask = _select_slice(
+        group_cap, state.topo_ids, k_actual, group_noise, config)
 
     # ---- spread cap: at most ceil(K / eligible groups) per group.
     spread_cap = jnp.float32(k)
@@ -248,8 +280,9 @@ def gang_placement_program(state: GangState, ask_res, ask_bw, ask_ports,
 
     carry0 = (state.util, state.bw_used, state.ports_free,
               jnp.zeros(n, jnp.int32), jnp.zeros(g_pad, jnp.float32))
-    _, (choices, scores) = jax.lax.scan(
-        body, carry0, (active, noise))
+    with jax.named_scope("gang_scan"):
+        _, (choices, scores) = jax.lax.scan(
+            body, carry0, (active, noise))
 
     # ---- all-K enforcement: a partial gang never leaves the device.
     all_placed = jnp.all(jnp.where(active, choices >= 0, True))
@@ -266,3 +299,108 @@ def gang_placement_program_jit(state: GangState, ask_res, ask_bw,
                                config: GangConfig):
     return gang_placement_program(state, ask_res, ask_bw, ask_ports,
                                   active, key, config)
+
+
+class GangBase(NamedTuple):
+    """What the lanes of one dispatch share: the cluster base's node
+    arrays (device-resident per base token, scheduler/batcher.py) and
+    the topology id column resident beside it."""
+
+    capacity: jnp.ndarray  # [N, 4]
+    sched_capacity: jnp.ndarray  # [N, 4]
+    util: jnp.ndarray  # [N, 4]
+    bw_avail: jnp.ndarray  # [N]
+    bw_used: jnp.ndarray  # [N]
+    ports_free: jnp.ndarray  # [N]
+    node_ok: jnp.ndarray  # [N] bool
+    topo_ids: jnp.ndarray  # [N] topology group id (-1 = excluded)
+
+
+class GangLane(NamedTuple):
+    """One gang's own inputs; a dispatch stacks B of them along a
+    leading axis. HOST-side numpy, like GangState."""
+
+    feas_row: jnp.ndarray  # [N] bool: the gang TG's feasibility
+    job_count: jnp.ndarray  # [N] int32
+    dh_presence: jnp.ndarray  # [N] int32
+    ask_res: jnp.ndarray  # [4]
+    ask_bw: jnp.ndarray  # []
+    ask_ports: jnp.ndarray  # []
+    active: jnp.ndarray  # [K] bool member mask; all False = padding
+
+
+def make_gang_lane(feas_row, job_count, dh_presence, ask_res, ask_bw,
+                   ask_ports, active) -> GangLane:
+    return GangLane(
+        feas_row=_np.asarray(feas_row, bool),
+        job_count=_np.asarray(job_count, _np.int32),
+        dh_presence=_np.asarray(dh_presence, _np.int32),
+        ask_res=_np.asarray(ask_res, _np.float32),
+        ask_bw=_np.asarray(ask_bw, _np.float32),
+        ask_ports=_np.asarray(ask_ports, _np.float32),
+        active=_np.asarray(active, bool),
+    )
+
+
+def batched_gang_placement_program(base: GangBase, lanes: GangLane,
+                                   keys, config: GangConfig):
+    """Place the B gangs of one dispatch, in the batch's order, each
+    seeing the claims of those before it: an outer scan over the lanes
+    carries utilisation, bandwidth and free ports; inside a lane the
+    pass is gang_placement_program on the carried state. A lane whose
+    gang was rejected whole claims nothing, nor does a padding lane.
+
+    Returns (choices [B, K] int32, scores [B, K] f32, info [B, 2]
+    int32): info[:, 0] is the lane's slice group, info[:, 1] is 1 where
+    an earlier lane's claims moved the gang off the group it would have
+    taken on the unclaimed base (slice mode only)."""
+    n = base.util.shape[0]
+
+    def lane_state(util, bw_used, ports_free, lane: GangLane):
+        return GangState(
+            capacity=base.capacity, sched_capacity=base.sched_capacity,
+            util=util, bw_avail=base.bw_avail, bw_used=bw_used,
+            ports_free=ports_free,
+            feas_row=lane.feas_row & base.node_ok,
+            job_count=lane.job_count, dh_presence=lane.dh_presence,
+            topo_ids=base.topo_ids)
+
+    def body(carry, xs):
+        util, bw_used, ports_free = carry
+        lane, key = xs
+        state = lane_state(util, bw_used, ports_free, lane)
+        choices, scores, group = gang_placement_program(
+            state, lane.ask_res, lane.ask_bw, lane.ask_ports,
+            lane.active, key, config)
+        moved = jnp.int32(0)
+        if config.mode == GANG_MODE_SLICE:
+            # The group this gang would have taken had it been solved
+            # alone on the snapshot, under the same tie-break noise.
+            alone = lane_state(base.util, base.bw_used, base.ports_free,
+                               lane)
+            units = _member_units(alone, lane.ask_res, lane.ask_bw,
+                                  lane.ask_ports, config)
+            alone_group, _mask = _select_slice(
+                _group_capacity(units, base.topo_ids, config.g_pad),
+                base.topo_ids,
+                jnp.sum(lane.active.astype(jnp.float32)),
+                _group_noise(key, config.g_pad), config)
+            moved = ((group >= 0) & (alone_group != group)).astype(
+                jnp.int32)
+        safe = jnp.where(choices >= 0, choices, n)
+        carry = (
+            util.at[safe].add(lane.ask_res[None, :], mode="drop"),
+            bw_used.at[safe].add(lane.ask_bw, mode="drop"),
+            ports_free.at[safe].add(-lane.ask_ports, mode="drop"),
+        )
+        return carry, (choices, scores, jnp.stack([group, moved]))
+
+    _, (choices, scores, info) = jax.lax.scan(
+        body, (base.util, base.bw_used, base.ports_free), (lanes, keys))
+    return choices, scores, info
+
+
+@functools.partial(jax.jit, static_argnames=("config",))
+def batched_gang_placement_program_jit(base: GangBase, lanes: GangLane,
+                                       keys, config: GangConfig):
+    return batched_gang_placement_program(base, lanes, keys, config)

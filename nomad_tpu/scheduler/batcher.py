@@ -21,6 +21,15 @@ recompiles). Within a batch there are two device paths:
 - mixed bases: the full states stack along the batch axis
   (batched_placement_program).
 
+A gang (nomad_tpu/gang) is a request in the same queues: place_gang
+keys it by its static GangConfig as place() keys a plain one by its
+PlacementConfig, it carries its pipeline batch's cohort, is counted in
+`batched_requests` and `dispatches`, reads the same resident base and
+its deltas (the topology id column resident beside it, _device_topology)
+and the gangs of one dispatch are one program (ops/gang.py
+batched_gang_placement_program) that carries each gang's claims to the
+next.
+
 A dispatch closes on what its requests carry. Requests of a pipeline
 batch carry that batch's cohort (open_cohort: the launch prologue has
 already counted them), and their dispatch is released the moment every
@@ -68,6 +77,11 @@ RESPAWN_WINDOW_S = 0.005  # post-dispatch window, same requests: GIL stragglers
 # ~4 workers' wave snapshots plus the delta parents they derive from —
 # evicting a parent forces the next delta into a full re-upload.
 DEVICE_BASE_CACHE = 8
+# Topology id columns kept on device (models/topology.py device_key):
+# one per (tensor, level, singleton form) a gang dispatch has read. A
+# tensor is rebuilt only when the node set changes, so a handful covers
+# the live family and the one before it.
+DEVICE_TOPO_CACHE = 8
 # In-flight dispatches allowed per shape: overlapping device calls
 # hides the per-dispatch round-trip behind the next batch's
 # accumulation. XLA serializes the programs on-device; overlap buys
@@ -90,12 +104,15 @@ COHORT_WAIT_MAX = 1.0
 
 
 # ntalint residency manifest (analysis/residency.py): the ONE function
-# allowed to ship a full cluster base host->device. Everything else on
+# allowed to ship a full cluster base host->device, and the one that
+# ships a topology id column beside it (once a rebuild of the node
+# set's tensor, models/topology.py). Everything else on
 # the dispatch/scheduler steady state must ride the delta/cached paths
 # — a full-matrix device_put creeping back into a hot path is exactly
 # the per-batch re-ship the device-resident design removed, and it
 # regresses silently (the code still works, just 10-100x the bytes).
-NTA_REBUILD_ENTRYPOINTS = ("PlacementBatcher._build_device_base",)
+NTA_REBUILD_ENTRYPOINTS = ("PlacementBatcher._build_device_base",
+                           "PlacementBatcher._device_topology")
 
 
 class _Cohort:
@@ -151,10 +168,10 @@ class CohortUnit:
 class _Request:
     __slots__ = ("token", "base", "overlay", "compact", "asks", "key",
                  "delta", "event", "choices", "scores", "error", "span",
-                 "ready_at", "arrived_at", "unit")
+                 "ready_at", "arrived_at", "unit", "topo", "info")
 
     def __init__(self, token, base, overlay, asks, key, delta=None,
-                 compact=None, span=None, unit=None):
+                 compact=None, span=None, unit=None, topo=None):
         self.token = token  # cluster-base identity, None = unshared
         self.base = base  # (capacity, sched_capacity, util, bw_avail,
         #                    bw_used, ports_free, node_ok, class_ids)
@@ -164,8 +181,13 @@ class _Request:
         # KB cross host->device per eval and the dense overlays are
         # rebuilt on device.
         self.compact = compact
-        self.asks = asks
+        self.asks = asks  # ops/binpack.py Asks; a gang's GangLane
         self.key = key
+        # A gang request's topology column, (device key, host [N]
+        # int32); None on a plain request.
+        self.topo = topo
+        # A gang's (slice group, moved by an earlier lane's claims).
+        self.info = None
         self.delta = delta  # (parent_token, changed_rows) or None
         self.span = span  # (eval_id, trace_id) for the device.solve span
         self.unit: Optional[CohortUnit] = unit
@@ -205,7 +227,8 @@ BATCH_BUCKETS = (4, 16, 64)
 # ARE this module's bucket functions (hand-rolled ladders over the
 # tuples above, with a deliberate pow2 overflow fallback), so shapes
 # they produce are sanctioned the same as matrix.py bucket_size.
-NTA_BUCKET_FNS = ("_pad_rows", "_pad_batch")
+NTA_BUCKET_FNS = ("_pad_rows", "_pad_batch", "_pad_gang_batch",
+                  "_pad_gang_members")
 
 
 def _pad_rows(rows) -> np.ndarray:
@@ -229,6 +252,40 @@ def _pad_batch(n: int, max_batch: int) -> int:
         if n <= b <= max_batch:
             return b
     return max_batch
+
+
+def _node_base(state) -> tuple:
+    """The job-independent node arrays of `state` in the order the
+    device keeps a base (_build_device_base). Plain NodeState callers
+    (tests) have no class index: the compact path is off for them
+    anyway."""
+    class_ids = getattr(state, "class_ids", None)
+    if class_ids is None:
+        class_ids = np.full(np.shape(state.node_ok), -1, np.int32)
+    return (state.capacity, state.sched_capacity, state.util,
+            state.bw_avail, state.bw_used, state.ports_free,
+            state.node_ok, class_ids)
+
+
+def _pad_gang_batch(n: int, max_batch: int) -> int:
+    """The batch bucket of a gang dispatch: the ladder without its
+    smallest step. A gang dispatch's member axis is its largest gang's
+    bucket (_pad_gang_members), so every batch bucket is a program for
+    EACH member bucket the traffic holds, and a lane with no member
+    active costs the device next to nothing: a straggler's dispatch of
+    one or two lanes runs the program its burst compiled."""
+    return _pad_batch(max(n, BATCH_BUCKETS[1]), max_batch)
+
+
+def _pad_gang_members(k: int) -> int:
+    """The member axis of a gang dispatch: its largest gang's size on
+    every other step of the ask ladder (8, 32, 128, 512, 1,024). Which
+    gang is a batch's largest changes from batch to batch, and each
+    step is a program for every batch bucket: half the steps is half
+    the programs a warm-up has to meet, for a few member steps more."""
+    from ..models.matrix import ASK_BUCKETS, bucket_size
+
+    return bucket_size(k, ASK_BUCKETS[::2] + ASK_BUCKETS[-1:])
 
 
 def _idle_parts(last_end: float, first_arrival: float, closed: float,
@@ -271,6 +328,8 @@ class PlacementBatcher:
         # overlapped dispatchers on one token must not each pay the
         # transfer this cache exists to avoid.
         self._base_pending: Dict[object, threading.Event] = {}  # guarded-by: _lock
+        self._device_topos: "OrderedDict[tuple, object]" = OrderedDict()  # guarded-by: _lock
+        self.topo_uploads = 0  # guarded-by: _lock (host->device columns)
         self._mesh = None  # guarded-by: _lock (lazy; False = 1 device)
         # Bases made device-resident SHARDED across the mesh — full
         # uploads and delta-derivations from a sharded parent alike.
@@ -353,14 +412,7 @@ class PlacementBatcher:
         eval covering the jitted solve itself (issue + device sync,
         kernel-annotated) — the part of `device.dispatch` that is the
         kernel, separated from batch-wait and stacking."""
-        class_ids = getattr(state, "class_ids", None)
-        if class_ids is None:
-            # Plain NodeState callers (tests): no class index —
-            # the compact path is off for them anyway.
-            class_ids = np.full(np.shape(state.node_ok), -1, np.int32)
-        base = (state.capacity, state.sched_capacity, state.util,
-                state.bw_avail, state.bw_used, state.ports_free,
-                state.node_ok, class_ids)
+        base = _node_base(state)
         overlay = (state.job_count, state.tg_count, state.feasible)
         compact = getattr(state, "compact_overlay", None)
         token = getattr(state, "base_token", None)
@@ -386,6 +438,47 @@ class PlacementBatcher:
         req = _Request(token, base, overlay, asks, rng_key,
                        delta=getattr(state, "base_delta", None),
                        compact=compact, span=span, unit=cohort)
+        self._submit(req, shape_key, config)
+        return req.choices, req.scores
+
+    def place_gang(self, state, gang, rng_key, span=None, cohort=None):
+        """Submit one gang (nomad_tpu/gang build_gang_request against
+        `state`, a ClusterMatrix); blocks until its batch's device
+        dispatch returns. Returns (choices [K], scores [K], slice group,
+        moved) for THIS gang: choices all >= 0 or all -1; `moved` says
+        an earlier gang of the dispatch claimed the rack this one would
+        have taken alone.
+
+        The request queues, counts, closes on its cohort and reads the
+        resident base exactly as place()'s do; gangs that share a base
+        token, a topology column and a GangConfig share a dispatch,
+        whatever their sizes, in which each sees the claims of those
+        before it. A state without
+        a base token (a plan that already stops or places something)
+        has nothing resident to share and dispatches alone."""
+        base = _node_base(state)
+        token = getattr(state, "base_token", None)
+        # No token, nothing to share: a key of its own.
+        share = token if token is not None else object()
+        # The gang's size is no part of the key: gangs of every size
+        # share a dispatch (so each sees every other's claims), whose
+        # member axis is its largest gang's bucket (_run_gang_batch).
+        shape_key = (
+            "gang", np.shape(state.capacity), gang.config, share,
+            gang.topo_key,
+        )
+        req = _Request(token, base, None, gang.lane, rng_key,
+                       delta=getattr(state, "base_delta", None),
+                       span=span, unit=cohort,
+                       topo=(gang.topo_key, gang.topo_ids))
+        self._submit(req, shape_key, gang.config)
+        group, moved = req.info
+        return req.choices, req.scores, group, moved
+
+    def _submit(self, req: _Request, shape_key, config) -> None:
+        """Queue `req` under its shape key, dispatch or park until its
+        batch's dispatch has set its results, and raise what the
+        dispatch raised."""
         run_dispatch = False
         with self._lock:
             q = self._queues.setdefault(shape_key, [])
@@ -464,7 +557,6 @@ class PlacementBatcher:
                 "batch_park", (time.monotonic() - req.ready_at) * 1000.0)
         if req.error is not None:
             raise req.error
-        return req.choices, req.scores
 
     # ------------------------------------------------------------------
 
@@ -517,12 +609,7 @@ class PlacementBatcher:
         with self._lock:
             if token in self._device_bases:
                 return 0
-        class_ids = getattr(state, "class_ids", None)
-        if class_ids is None:
-            class_ids = np.full(np.shape(state.node_ok), -1, np.int32)
-        base = (state.capacity, state.sched_capacity, state.util,
-                state.bw_avail, state.bw_used, state.ports_free,
-                state.node_ok, class_ids)
+        base = _node_base(state)
         # Bytes come back from THIS call's build (0 on a lost
         # build race): a global counter-diff here would attribute
         # concurrent uploads of other tokens to this prefetch.
@@ -731,6 +818,10 @@ class PlacementBatcher:
         # dense schedulers fall back to the host path per eval.
         check_device_chaos()
 
+        if batch[0].topo is not None:
+            self._run_gang_batch(batch, config, closed)
+            return
+
         if len(batch) == 1 and batch[0].token is None:
             # Unshared lone request: nothing cacheable, dispatch as-is.
             # Token-carrying lone requests fall through to the overlay
@@ -843,6 +934,14 @@ class PlacementBatcher:
             choices, scores, times = self._issue(
                 batch, config, closed, batched_placement_program,
                 per_eval, asks, keys, config)
+        self._count_dispatch(times, payload, compact, shared)
+        for i, req in enumerate(batch):
+            req.choices = choices[i]
+            req.scores = scores[i]
+
+    def _count_dispatch(self, times, payload: int, compact: bool,
+                        shared: bool) -> None:
+        """One dispatch's cost into the cumulative breakdown."""
         t1, t2, t3 = times
         with self._lock:
             self.t_issue += t2 - t1
@@ -856,9 +955,104 @@ class PlacementBatcher:
             sync = t3 - t2
             self._sync_ema = (sync if self._sync_ema == 0.0
                               else 0.7 * self._sync_ema + 0.3 * sync)
+
+    def _device_topology(self, key, column):
+        """The device's copy of one topology id column
+        (models/topology.py device_key), LRU-cached: it crosses
+        host->device once a rebuild of the tensor, not once a base
+        token. Two dispatchers that miss at once both upload ([N]
+        int32); the second insert wins."""
+        with self._lock:
+            dev = self._device_topos.get(key)
+            if dev is not None:
+                self._device_topos.move_to_end(key)
+                return dev
+        import jax
+
+        column = np.asarray(column, np.int32)
+        dev = jax.device_put(column)
+        with self._lock:
+            self.topo_uploads += 1
+            self.bytes_upload += column.nbytes
+            while len(self._device_topos) >= DEVICE_TOPO_CACHE:
+                self._device_topos.popitem(last=False)
+            self._device_topos[key] = dev
+        return dev
+
+    def _run_gang_batch(self, batch: List[_Request], config,
+                        closed: float) -> None:
+        """One dispatch of the gangs in `batch`, in the queue's order
+        (ops/gang.py batched_gang_placement_program): the lanes stack
+        along the batch axis, each member mask padded to the largest
+        gang's bucket (_pad_gang_members), the batch padded up a bucket
+        (_pad_gang_batch) with lanes that have no member active (they
+        place and claim nothing); the
+        node arrays are the resident base of the batch's token with the
+        topology column beside it, so only the lanes cross host->device.
+        A tokenless request came alone (place_gang) and its node arrays
+        ride the call."""
+        import jax
+
+        from ..gang import note_gang_dispatch
+        from ..ops.gang import GangBase, batched_gang_placement_program_jit
+
+        n_live = len(batch)
+        pad_to = _pad_gang_batch(n_live, self.max_batch)
+        first = batch[0]
+        k_pad = _pad_gang_members(max(len(r.asks.active) for r in batch))
+
+        def stacked(*xs):
+            return np.stack(xs)
+
+        def members(active):
+            out = np.zeros(k_pad, bool)
+            out[:len(active)] = active
+            return out
+
+        with trace.annotation("nomad.stack", lanes=n_live):
+            idle = first.asks._replace(active=np.zeros(k_pad, bool))
+            lanes = jax.tree.map(
+                stacked, *([r.asks._replace(active=members(r.asks.active))
+                            for r in batch] + [idle] * (pad_to - n_live)))
+            keys = np.stack([r.key for r in batch]
+                            + [first.key] * (pad_to - n_live))
+        payload = sum(x.nbytes for x in lanes) + keys.nbytes
+        topo_key, topo_ids = first.topo
+        if first.token is None:
+            f32 = np.float32
+            node = tuple(np.asarray(x, f32) for x in first.base[:6]) \
+                + (np.asarray(first.base[6], bool),)
+            topo = np.asarray(topo_ids, np.int32)
+            payload += sum(x.nbytes for x in node) + topo.nbytes
+        else:
+            dev, _ = self._device_base(first.token, first.base, first.delta)
+            node = dev[:7]
+            # Beside a base sharded over a mesh the column rides the
+            # call uncommitted: jit lays it out with the base.
+            topo = (np.asarray(topo_ids, np.int32)
+                    if len(dev[0].sharding.device_set) > 1
+                    else self._device_topology(topo_key, topo_ids))
+        issued = []
+        with trace.annotation("nomad.gang", gangs=n_live,
+                              mode=config.mode):
+            choices, scores, times = self._issue(
+                batch, config, closed, batched_gang_placement_program_jit,
+                GangBase(*node, topo), lanes, keys, config,
+                on_issued=issued.append)
+            info = np.asarray(issued[0][2])
+        t_info = time.monotonic()
+        self._count_dispatch(times, payload, False, first.token is not None)
+        note_gang_dispatch(n_live, int(info[:n_live, 1].sum()))
         for i, req in enumerate(batch):
             req.choices = choices[i]
             req.scores = scores[i]
+            req.info = (int(info[i, 0]), bool(info[i, 1]))
+            if req.span:
+                # Issue to the gang's slice and claim readings on the
+                # host: device.solve, then the pull of `info`.
+                trace.record_span(
+                    req.span[0], trace.STAGE_GANG_SOLVE, times[0], t_info,
+                    ann={"gangs": n_live}, trace_id=req.span[1])
 
     def _issue(self, batch: List[_Request], config, closed: float, program,
                *args, on_issued=None):
@@ -1149,6 +1343,10 @@ class PlacementBatcher:
                 "sync_us": int(self.t_sync * 1e6),
                 "payload_bytes": int(self.bytes_overlay),
                 "upload_bytes": int(self.bytes_upload),
+                # Topology id columns made resident beside a base
+                # (_device_topology): rises only when the node set
+                # changed under a gang dispatch.
+                "topo_uploads": self.topo_uploads,
                 # Compiled XLA programs this process holds (all the
                 # placement entry points): steady state is FLAT — a
                 # climb under load is a recompile storm (the benchmark's

@@ -158,8 +158,9 @@ class BatchedTPUScheduler(GenericScheduler):
         from .batcher import get_batcher
 
         # Gang task groups (nomad_tpu/gang) take the dense all-K pass:
-        # one gang = one dispatch of ops/gang.py's program, atomically
-        # staged on the plan's gang leg.
+        # one gang = one lane of ops/gang.py's batched program, through
+        # the batcher like the plain asks below, atomically staged on
+        # the plan's gang leg.
         gang_sets, place = self._split_gang_placements(place)
         for tg, tuples in gang_sets:
             self._place_gang_dense(tg, tuples)
@@ -269,17 +270,7 @@ class BatchedTPUScheduler(GenericScheduler):
         trace.record_span(self.eval.id, trace.STAGE_MATRIX_BUILD, _t0,
                           ann={"placements": len(bulk)},
                           trace_id=self.eval.trace_id)
-        # Attribution for the device-resident path: how this eval's
-        # base came to be (cache hit / incremental delta / full
-        # rebuild) and how many node rows the delta touched — the
-        # resident design's win IS this span staying "hit"/"delta"
-        # with small row counts under steady load (models/resident.py).
-        kind = getattr(matrix, "build_kind", None)
-        if kind is not None:
-            trace.record_span(
-                self.eval.id, trace.STAGE_MATRIX_UPDATE, _t0, _t_base,
-                ann={"kind": kind, "rows": matrix.delta_rows},
-                trace_id=self.eval.trace_id)
+        self._record_matrix_update(matrix, _t0, _t_base)
         # Compression-plane attribution (models/classes.py): how far
         # the fleet interned — C classes over N nodes. Zero-duration
         # marker span (the interning rides the base build above); its
@@ -438,21 +429,25 @@ class BatchedTPUScheduler(GenericScheduler):
                 trace_id=self.eval.trace_id)
 
     def _place_gang_dense(self, tg, tuples: List[AllocTuple]) -> None:
-        """One gang's all-K dispatch (ops/gang.py): per-node fit mask
-        -> topology-group cumulative capacity -> contiguous-slice
-        selection -> K-step member assignment, one compiled program
-        over the device-resident base arrays. Members stage through
-        the plan's gang leg (Plan.append_gang_alloc) — the applier
-        verifies per node and rejects the WHOLE gang on any member's
-        under-fit. Device faults and an open breaker fall back to the
-        host gang stack with identical atomicity semantics."""
+        """One gang's all-K pass (ops/gang.py): per-node fit mask ->
+        topology-group cumulative capacity -> contiguous-slice selection
+        -> K-step member assignment. The gang goes to the device as
+        place() sends plain asks: a request built from the snapshot's
+        cached cluster base and handed to the batcher with this eval's
+        cohort unit (scheduler/batcher.py place_gang), so the gangs of
+        one pipeline batch ride one dispatch over the resident base,
+        each seeing the claims of those before it. Members stage
+        through the plan's gang leg (Plan.append_gang_alloc) — the
+        applier verifies per node and rejects the WHOLE gang on any
+        member's under-fit. Device faults and an open breaker fall back
+        to the host gang stack with identical atomicity semantics."""
         from ..admission import get_breaker
         from ..chaos import chaos
-        from ..gang import build_gang_state, gang_key, note_gang_result
+        from ..gang import build_gang_request
         from ..models.matrix import ClusterMatrix
-        from ..ops.binpack import check_device_chaos, host_prng_key
-        from ..ops.gang import gang_placement_program_jit
+        from ..ops.binpack import host_prng_key
         from ..utils import metrics as _metrics
+        from .batcher import get_batcher
 
         name = tg.name
         if self.failed_tg_allocs and name in self.failed_tg_allocs:
@@ -461,28 +456,41 @@ class BatchedTPUScheduler(GenericScheduler):
 
         breaker = get_breaker()
         if not breaker.acquire():
+            self._settle_cohort()
             _metrics.incr_counter(
                 ("scheduler", "gang_breaker_rejected"), len(tuples))
             self._place_gang_host(tg, tuples)
             return
 
         _t0 = time.monotonic()
-        # The matrix includes this plan's earlier staged legs (gang
-        # replacement stops free their capacity through the proposed-
-        # alloc overlay) — the all-K pass must see the room the
-        # survivors' stops open up.
-        matrix = ClusterMatrix(self.state, self.job, self.plan)
-        state, active, (ask_res, ask_bw, ask_ports), config = \
-            build_gang_state(matrix, self.job, tg)
+        # The cached base of the snapshot; where this plan already
+        # stages something (gang replacement stops free their capacity)
+        # only the rows it touches are derived again — the all-K pass
+        # must see the room the survivors' stops open up. Such a matrix
+        # carries no base token and its gang dispatches alone.
+        matrix = ClusterMatrix(self.state, self.job, self.plan,
+                               plan_overlay=True)
+        _t_base = time.monotonic()
+        gang = build_gang_request(matrix, self.job, tg)
+        config = gang.config
         key = host_prng_key(self.rng.getrandbits(31))
+        trace.record_span(self.eval.id, trace.STAGE_MATRIX_BUILD, _t0,
+                          ann={"placements": len(tuples), "gang": True},
+                          trace_id=self.eval.trace_id)
+        self._record_matrix_update(matrix, _t0, _t_base)
+        trace.record_span(self.eval.id, trace.STAGE_GANG_BUILD, _t0,
+                          trace_id=self.eval.trace_id)
         _t_solve = time.monotonic()
+        unit = getattr(self.planner, "cohort", None)
         try:
             if chaos.enabled:
                 chaos.fire("device.breaker_trip", eval_id=self.eval.id)
-            check_device_chaos()
-            choices, scores, slice_group = gang_placement_program_jit(
-                state, ask_res, ask_bw, ask_ports, active, key, config)
+            choices, scores, slice_gid, moved = get_batcher().place_gang(
+                matrix, gang, key,
+                span=(self.eval.id, self.eval.trace_id), cohort=unit)
         except Exception:
+            # A fault before place_gang() left the unit open.
+            self._settle_cohort()
             breaker.record_failure()
             self.logger.warning(
                 "gang device dispatch failed; falling back to the host "
@@ -497,15 +505,31 @@ class BatchedTPUScheduler(GenericScheduler):
             self._place_gang_host(tg, tuples)
             return
         breaker.record_success((time.monotonic() - _t_solve) * 1000.0)
-        choices = np.asarray(choices)
-        scores = np.asarray(scores)
-        slice_gid = int(np.asarray(slice_group))
+        ann = {"lanes": len(tuples)}
+        if unit is not None:
+            ann["closed_by"] = unit.closed_by
         trace.record_span(
-            self.eval.id, trace.STAGE_GANG_SELECT, _t0,
-            ann={"members": len(tuples), "mode": config.mode,
-                 "slice_group": slice_gid},
+            self.eval.id, trace.STAGE_DEVICE_DISPATCH, _t_solve, ann=ann,
             trace_id=self.eval.trace_id)
+        try:
+            self._stage_gang(tg, tuples, matrix, gang, choices, scores)
+        finally:
+            trace.record_span(
+                self.eval.id, trace.STAGE_GANG_SELECT, _t0,
+                ann={"members": len(tuples), "mode": config.mode,
+                     "slice_group": slice_gid, "moved": moved},
+                trace_id=self.eval.trace_id)
 
+    def _stage_gang(self, tg, tuples: List[AllocTuple], matrix, gang,
+                    choices, scores) -> None:
+        """What the device chose for one gang onto the plan: all K
+        members on the gang leg, or the one failure of a gang rejected
+        whole."""
+        from ..gang import gang_key, note_gang_result
+        from ..utils import metrics as _metrics
+
+        name = tg.name
+        ask_res = gang.lane.ask_res
         if int(choices[0]) < 0:
             # Whole-gang reject on device (no slice fits all K, or a
             # member found no node): ONE failure for the TG, with
@@ -728,6 +752,19 @@ class BatchedTPUScheduler(GenericScheduler):
             placed_total += 1
         note_preemption(staged_total, placed_total)
         return n_candidates
+
+    def _record_matrix_update(self, matrix, t0: float, t1: float) -> None:
+        """Attribution for the device-resident path: how this eval's
+        base came to be (cache hit / incremental delta / full rebuild)
+        and how many node rows the delta touched — the resident
+        design's win IS this span staying "hit"/"delta" with small row
+        counts under steady load (models/resident.py)."""
+        kind = getattr(matrix, "build_kind", None)
+        if kind is not None:
+            trace.record_span(
+                self.eval.id, trace.STAGE_MATRIX_UPDATE, t0, t1,
+                ann={"kind": kind, "rows": matrix.delta_rows},
+                trace_id=self.eval.trace_id)
 
     def _note_quality(self, kernel, matrix, ask_res, committed) -> None:
         note_quality(self.logger, self.job, kernel, matrix, ask_res,

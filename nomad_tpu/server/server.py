@@ -1443,8 +1443,10 @@ class Server:
             # cost split cold-vs-warm, and the compiled-program count.
             "defrag": self.defrag.stats(),
             # Gang scheduling (nomad_tpu/gang): gangs placed/rejected
-            # per path; the applier-side whole-gang rejections live in
-            # plan_applier stats ("gangs_rejected").
+            # per path, device dispatches of the gang program, gangs a
+            # dispatch, and gangs an earlier lane's claim moved to
+            # another rack; the applier-side whole-gang rejections live
+            # in plan_applier stats ("gangs_rejected").
             "gang": _gang_stats(),
             # Read plane (nomad_tpu/readplane): parked continuations,
             # wake/spurious/served/timeout/write-error counters, and

@@ -35,7 +35,9 @@ from .span import (  # noqa: F401
     STAGE_DISPATCH_POOL_WAIT,
     STAGE_EVAL_UNCOVERED,
     STAGE_EVAL_UPDATE,
+    STAGE_GANG_BUILD,
     STAGE_GANG_SELECT,
+    STAGE_GANG_SOLVE,
     STAGE_IDLE_BATCH_WAIT,
     STAGE_IDLE_NO_WORK,
     STAGE_IDLE_STACK,

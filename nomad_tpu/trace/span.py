@@ -52,10 +52,20 @@ STAGE_PREEMPT_VICTIMS = "preempt.victims"  # inside preempt.select: the
 #   victim candidates looked up in the base's table (or built)
 STAGE_PREEMPT_SOLVE = "preempt.solve"      # inside preempt.select: the
 #   preemption program, issue to results on the host (ends in a sync)
-STAGE_GANG_SELECT = "gang.select"          # all-K gang slice selection
-#   + member assignment (ops/gang.py; ann: members, mode,
-#   slice group, host_fallback) — one span per gang dispatch
-#   (nomad_tpu/gang)
+STAGE_GANG_SELECT = "gang.select"          # one gang's all-K pass,
+#   whole: request, dispatch through the batcher, plan staged
+#   (nomad_tpu/gang, ops/gang.py; ann: members, mode, slice group,
+#   moved, host_fallback). Its children are gang.build and the gang's
+#   device.dispatch; what they leave is `gang.select.self`: port
+#   offers and staging the members on the plan's gang leg
+STAGE_GANG_BUILD = "gang.build"            # inside gang.select: the
+#   request's host side (the matrix from the cached base, matrix.build
+#   inside it, and the gang's lane: gang/__init__.py build_gang_request)
+STAGE_GANG_SOLVE = "gang.solve"            # inside the gang's
+#   device.dispatch: the batched gang program, issue to every result
+#   on the host (ends in a sync; ann: gangs in the dispatch). Wraps
+#   the dispatch's device.solve, which ends when the placements are
+#   back; the slice and claim readings follow
 STAGE_DEFRAG_SOLVE = "defrag.solve"        # one defrag-loop round's
 #   warm-started global relaxation solve + move extraction
 #   (nomad_tpu/defrag; ann: movable, moves, gain, warm, solve_ms) —
@@ -108,6 +118,8 @@ ALL_STAGES = (
     STAGE_PREEMPT_VICTIMS,
     STAGE_PREEMPT_SOLVE,
     STAGE_GANG_SELECT,
+    STAGE_GANG_BUILD,
+    STAGE_GANG_SOLVE,
     STAGE_DEFRAG_SOLVE,
     STAGE_PLAN_SUBMIT,
     STAGE_PLAN_QUEUE_WAIT,

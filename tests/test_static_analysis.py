@@ -583,9 +583,12 @@ def test_reship_manifest_globally_unique():
     """The ONE sanctioned full-upload path stays unique: across every
     module in the residency scope, the union of declared
     NTA_REBUILD_ENTRYPOINTS manifests is exactly the batcher's rebuild
-    entry point. A second manifest anywhere (e.g. a class-expansion
-    helper sanctioning its own device_put) widens the steady-state
-    upload surface and must be a deliberate, reviewed change here."""
+    entry point, and beside it the upload of a topology id column
+    (once a rebuild of the node set's tensor: the gang dispatch reads
+    it resident beside the base). A further manifest anywhere (e.g. a
+    class-expansion helper sanctioning its own device_put) widens the
+    steady-state upload surface and must be a deliberate, reviewed
+    change here."""
     from nomad_tpu.analysis.core import Module
     from nomad_tpu.analysis.residency import _in_scope, manifest_entries
 
@@ -602,9 +605,10 @@ def test_reship_manifest_globally_unique():
                 mod = Module(path, rel, fh.read())
             for ent in manifest_entries(mod):
                 entries.setdefault(ent, []).append(rel)
-    assert set(entries) == {"PlacementBatcher._build_device_base"}, entries
-    assert entries["PlacementBatcher._build_device_base"] == [
-        "nomad_tpu/scheduler/batcher.py"]
+    assert set(entries) == {"PlacementBatcher._build_device_base",
+                            "PlacementBatcher._device_topology"}, entries
+    for sites in entries.values():
+        assert sites == ["nomad_tpu/scheduler/batcher.py"]
 
 
 # ---------------------------------------------------------------------
