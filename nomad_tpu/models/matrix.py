@@ -600,28 +600,30 @@ def _victim_candidates(allocs, job_id=None, max_priority=None):
 class _VictimTable:
     """Per node, the V lowest-priority live allocations in victim
     order with their footprints: what ops/preempt.py's VictimState
-    holds, for every job and every priority at once. Which of a row's
-    entries a given eval may evict is a prefix of it (priorities
-    ascend), so an eval masks `ok` by its own priority and patches only
-    the rows its own job or plan touches (ClusterMatrix.build_victims).
+    holds, for every job and every priority at once, and in its
+    layout: the NODE axis last, so that a pass hands the arrays to the
+    device as they are. Which of a node's entries a given eval may
+    evict is a prefix of them (priorities ascend), so an eval masks
+    `ok` by its own priority and patches only the nodes its own job or
+    plan touches (ClusterMatrix.build_victims).
     Immutable once built: evals of one batch share it."""
 
     __slots__ = ("res", "bw", "ports", "prio", "ok", "lists")
 
     def __init__(self, res, bw, ports, prio, ok, lists: List):
-        self.res, self.bw, self.ports = res, bw, ports  # [N,V,4], [N,V] x2
-        self.prio, self.ok = prio, ok  # [N,V]; padding: +inf, False
+        self.res, self.bw, self.ports = res, bw, ports  # [4,V,N], [V,N] x2
+        self.prio, self.ok = prio, ok  # [V,N]; padding: +inf, False
         self.lists = lists  # row -> ordered Allocations, or None
 
     @classmethod
     def build(cls, n: int, nodes, state) -> "_VictimTable":
         from ..ops.preempt import PREEMPT_MAX_VICTIMS as V
 
-        table = cls(np.zeros((n, V, 4), np.float32),
-                    np.zeros((n, V), np.float32),
-                    np.zeros((n, V), np.float32),
-                    np.full((n, V), np.inf, np.float32),
-                    np.zeros((n, V), bool), [None] * len(nodes))
+        table = cls(np.zeros((4, V, n), np.float32),
+                    np.zeros((V, n), np.float32),
+                    np.zeros((V, n), np.float32),
+                    np.full((V, n), np.inf, np.float32),
+                    np.zeros((V, n), bool), [None] * len(nodes))
         table._set_rows({
             i: _victim_candidates(
                 state.allocs_by_node_terminal(node.id, False))
@@ -653,20 +655,20 @@ class _VictimTable:
             slots.extend(range(len(cands)))
             flat.extend(cands)
         touched = np.fromiter(cands_by_row, np.intp, len(cands_by_row))
-        self.res[touched] = 0.0
-        self.bw[touched] = 0.0
-        self.ports[touched] = 0.0
-        self.prio[touched] = np.inf
-        self.ok[touched] = False
+        self.res[..., touched] = 0.0
+        self.bw[:, touched] = 0.0
+        self.ports[:, touched] = 0.0
+        self.prio[:, touched] = np.inf
+        self.ok[:, touched] = False
         if not flat:
             return
         r, v = np.asarray(rows, np.intp), np.asarray(slots, np.intp)
         usage = np.asarray([_alloc_usage(a) for a in flat], np.float32)
-        self.res[r, v] = usage[:, :4]
-        self.bw[r, v] = usage[:, 4]
-        self.ports[r, v] = usage[:, 5]
-        self.prio[r, v] = [victim_priority(a) for a in flat]
-        self.ok[r, v] = True
+        self.res[:, v, r] = usage[:, :4].T
+        self.bw[v, r] = usage[:, 4]
+        self.ports[v, r] = usage[:, 5]
+        self.prio[v, r] = [victim_priority(a) for a in flat]
+        self.ok[v, r] = True
 
 
 def compute_class_index(nodes) -> Tuple[np.ndarray, List[int]]:
@@ -1488,10 +1490,11 @@ class ClusterMatrix:
         places something are derived again from the store, exactly.
 
         Returns (victim_arrays, victims_of, total): victim_arrays feed
-        make_victim_state, victims_of(row) is the row's ordered
-        Allocation list (the commit loop maps the kernel's victim COUNT
-        back to its next unconsumed entries), total the number of
-        candidates; rows beyond n_real are padding."""
+        make_victim_state (node axis last: [4,V,N] and [V,N]),
+        victims_of(row) is the row's ordered Allocation list (the
+        commit loop maps the kernel's victim COUNT back to its next
+        unconsumed entries), total the number of candidates; nodes
+        beyond n_real are padding."""
         base = self._base
         table = base.victim_table(self.nodes, self.state)
         res, bw, ports, prio = table.res, table.bw, table.ports, table.prio

@@ -612,6 +612,7 @@ class BatchedTPUScheduler(GenericScheduler):
         from ..ops.preempt import (
             make_victim_state,
             preempt_placement_program_jit,
+            unpack_result,
         )
         from .stack import (
             BATCH_JOB_ANTI_AFFINITY_PENALTY,
@@ -675,12 +676,10 @@ class BatchedTPUScheduler(GenericScheduler):
         _t_solve = time.monotonic()
         try:
             with trace.annotation("nomad.preempt", asks=len(pending)):
-                choices, scores, counts = preempt_placement_program_jit(
-                    state, victims, asks, key,
-                    np.float32(self.eval.priority), config)
-                choices = np.asarray(choices)
-                scores = np.asarray(scores)
-                counts = np.asarray(counts)
+                choices, scores, counts = unpack_result(
+                    preempt_placement_program_jit(
+                        state, victims, asks, key,
+                        np.float32(self.eval.priority), config))
         except Exception:  # noqa: BLE001 - degrade to plain failure
             # The device path is sick: these asks simply stay failed/blocked — the
             # no-preemption outcome, never a half-staged eviction. The

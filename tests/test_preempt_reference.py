@@ -27,6 +27,7 @@ from nomad_tpu.ops.preempt import (
     PREEMPT_MAX_VICTIMS,
     make_victim_state,
     preempt_placement_program_jit,
+    unpack_result,
 )
 from nomad_tpu.server import Server, ServerConfig
 from nomad_tpu.structs import Allocation, Resources, consts
@@ -44,13 +45,15 @@ BANDS = (10, 30, 70)            # free, middle, production
 ASK_SIZES = ((500, 2051, 20, 2), (1000, 4099, 50, 2))
 
 
-def seeded_fleet(seed, k_small=5, k_large=3):
+def seeded_fleet(seed, k_small=5, k_large=3, shapes=SHAPES, k_pad=0):
     """A full fleet as arrays: every node's free memory is under the
     smaller ask, so no ask fits anywhere without an eviction; victim
     sizes are odd numbers of MB, so that a sum in bfloat16 (8 bits: a
-    step of 16 at 2,048) is a different sum."""
+    step of 16 at 2,048) is a different sum. With `k_pad` the asks are
+    followed by inactive ones up to that many, as a K bucket pads them.
+    """
     rng = np.random.default_rng(seed)
-    n = sum(count for _c, _m, count in SHAPES)
+    n = sum(count for _c, _m, count in shapes)
     capacity = np.zeros((n, 4))
     util = np.zeros((n, 4))
     res = np.zeros((n, V, 4))
@@ -59,7 +62,7 @@ def seeded_fleet(seed, k_small=5, k_large=3):
     prio = np.full((n, V), np.inf)
     ok = np.zeros((n, V), bool)
     i = 0
-    for cpu, mem, count in SHAPES:
+    for cpu, mem, count in shapes:
         for _ in range(count):
             capacity[i] = (cpu, mem, 100000, 1000)
             sizes, left = [], mem - 256
@@ -102,17 +105,37 @@ def seeded_fleet(seed, k_small=5, k_large=3):
         bw=sizes[:, 2], ports=sizes[:, 3],
         tg_index=kinds.astype(np.int32), active=np.ones(k, bool),
         job_dh=False, tg_dh=np.array([True, False]))
-    return node, victims, asks
+    return node, victims, padded(asks, max(k, k_pad))
+
+
+def padded(asks, k):
+    """`asks` followed by inactive rows of zeros up to `k` of them."""
+    more = k - len(asks["active"])
+    if not more:
+        return asks
+    grow = {name: np.concatenate([asks[name],
+                                  np.zeros((more,) + asks[name].shape[1:],
+                                           asks[name].dtype)])
+            for name in ("resources", "bw", "ports", "tg_index", "active")}
+    return dict(asks, **grow)
+
+
+def program_inputs(node, victims, asks):
+    """The reference's dictionaries as the program takes them: the
+    victims with the node axis last ([N, V, 4] to [4, V, N], [N, V] to
+    [V, N])."""
+    return (make_node_state(**node),
+            make_victim_state(**{name: np.asarray(a).T
+                                 for name, a in victims.items()}),
+            make_asks(asks["resources"], asks["bw"], asks["ports"],
+                      asks["tg_index"], asks["active"], asks["job_dh"],
+                      asks["tg_dh"]))
 
 
 def run_program(node, victims, asks, key, priority, config=CFG):
-    out = preempt_placement_program_jit(
-        make_node_state(**node), make_victim_state(**victims),
-        make_asks(asks["resources"], asks["bw"], asks["ports"],
-                  asks["tg_index"], asks["active"], asks["job_dh"],
-                  asks["tg_dh"]),
-        key, np.float32(priority), config)
-    return tuple(np.asarray(x) for x in out)
+    return unpack_result(preempt_placement_program_jit(
+        *program_inputs(node, victims, asks), key, np.float32(priority),
+        config))
 
 
 def noise_of(key, k, n, config=CFG):
@@ -176,6 +199,184 @@ def test_headroom_wins_over_eviction(seed):
         node, victims, asks, seed)
     assert choices == w_choices and counts == w_counts, seed
     assert choices[0] == roomy and counts[0] == 0
+
+
+# What the node-minor program with carried prefix tables can get wrong
+# and the parent's could not: each case against the reference.
+
+# one BUCKETS step, and two sizes no multiple of the chip's 128 lanes
+FLEET_SIZES = {
+    "n128": ((8000, 32768, 64), (8000, 16384, 40), (16000, 65536, 24)),
+    "n200": ((8000, 32768, 100), (8000, 16384, 60), (16000, 65536, 40)),
+    "n131": ((8000, 32768, 66), (8000, 16384, 41), (16000, 65536, 24)),
+}
+
+
+@pytest.mark.parametrize("size", sorted(FLEET_SIZES))
+def test_program_equals_reference_whatever_the_node_count(size):
+    seed = 2840 + len(size) + int(size[1:])
+    node, victims, asks = seeded_fleet(seed, shapes=FLEET_SIZES[size])
+    assert len(node["node_ok"]) == int(size[1:])
+    got, want = compare(node, victims, asks, seed)
+    assert got[0] == want[0] and got[2] == want[2], size
+    np.testing.assert_allclose(got[1], want[1], atol=1e-4)
+    assert any(c >= 0 for c in got[0])
+
+
+@pytest.mark.parametrize("k_bucket,k_small,k_large", [
+    (8, 3, 2), (16, 7, 4), (32, 12, 7)])
+def test_program_equals_reference_with_trailing_inactive_asks(
+        k_bucket, k_small, k_large):
+    seed = 2850 + k_bucket
+    node, victims, asks = seeded_fleet(seed, k_small, k_large,
+                                       k_pad=k_bucket)
+    assert len(asks["active"]) == k_bucket
+    assert int(asks["active"].sum()) == k_small + k_large < k_bucket
+    got, want = compare(node, victims, asks, seed)
+    assert got[0] == want[0] and got[2] == want[2], k_bucket
+    np.testing.assert_allclose(got[1], want[1], atol=1e-4)
+    live = k_small + k_large
+    assert any(c >= 0 for c in got[0][:live])
+    assert got[0][live:] == [-1] * (k_bucket - live)
+    assert got[2][live:] == [0] * (k_bucket - live)
+
+
+def crafted(utils, slots, ask_sizes, feasible=None):
+    """A cell of len(utils) nodes of capacity 100 in every dimension,
+    node i used to utils[i]; `slots[i]` is node i's victim row, slot by
+    slot: (size, priority), or None for a padding slot (ok false,
+    priority +inf, nothing held), wherever in the row it stands. One
+    ask a size, all of one task group, no distinct_hosts."""
+    n, k = len(utils), len(ask_sizes)
+    res = np.zeros((n, V, 4))
+    prio = np.full((n, V), np.inf)
+    ok = np.zeros((n, V), bool)
+    for i, row in slots.items():
+        for slot, entry in enumerate(row):
+            if entry is not None:
+                res[i, slot], prio[i, slot] = entry
+                ok[i, slot] = True
+    capacity = np.full((n, 4), 100.0)
+    node = dict(
+        capacity=capacity, sched_capacity=capacity,
+        util=np.repeat(np.asarray(utils, np.float64)[:, None], 4, axis=1),
+        bw_avail=np.full(n, 1000.0), bw_used=np.zeros(n),
+        ports_free=np.full(n, 20.0), job_count=np.zeros(n, np.int32),
+        tg_count=np.zeros((n, 1), np.int32),
+        feasible=(np.ones((n, 1), bool) if feasible is None
+                  else np.asarray(feasible, bool)[:, None]),
+        node_ok=np.ones(n, bool))
+    victims = dict(res=res, bw=np.zeros((n, V)), ports=np.zeros((n, V)),
+                   prio=prio, ok=ok)
+    asks = dict(
+        resources=np.repeat(np.asarray(ask_sizes, np.float64)[:, None], 4,
+                            axis=1),
+        bw=np.zeros(k), ports=np.zeros(k), tg_index=np.zeros(k, np.int32),
+        active=np.ones(k, bool), job_dh=False, tg_dh=np.array([False]))
+    return node, victims, padded(asks, 8)
+
+
+ONLY_NODE_0 = [True, False, False, False]
+CRAFTED = {
+    # four asks of two victims each use node 0's eight slots up; the
+    # fifth finds every slot consumed and no other node to evict on
+    "all_eight_slots_consumed": (
+        crafted([100] * 4, {0: [(10.0, p) for p in range(10, 18)]},
+                [20, 20, 20, 20, 20], ONLY_NODE_0),
+        [0, 0, 0, 0, -1], [2, 2, 2, 2, 0]),
+    # the second ask's prefix starts after the first's victim
+    "two_asks_evict_on_one_node": (
+        crafted([100] * 4, {0: [(30.0, 10), (30.0, 20), (30.0, 30)]},
+                [25, 25], ONLY_NODE_0),
+        [0, 0], [1, 1]),
+    # node 1 has room for the small ask alone: it goes there without a
+    # victim between two asks that evict on node 0
+    "a_fit_between_two_evictions": (
+        crafted([100, 92, 100, 100], {0: [(30.0, 10), (30.0, 20)]},
+                [25, 5, 25]),
+        [0, 1, 0], [1, 0, 1]),
+    # padding slots in the middle of the row neither count nor break a
+    # prefix: 55 takes the first two live ones across a hole, then 25
+    # the next across two more
+    "padding_slots_inside_a_row": (
+        crafted([100] * 4,
+                {0: [(30.0, 10), None, (30.0, 20), None, None, (30.0, 30),
+                     None, (30.0, 40)]},
+                [55, 25, 25], ONLY_NODE_0),
+        [0, 0, 0], [2, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CRAFTED))
+def test_program_equals_reference_on_a_crafted_cell(case):
+    (node, victims, asks), choices, counts = CRAFTED[case]
+    got, want = compare(node, victims, asks, seed=2860, priority=50.0)
+    live = len(choices)
+    assert want[0][:live] == choices and want[2][:live] == counts, case
+    assert got[0] == want[0] and got[2] == want[2], case
+    np.testing.assert_allclose(got[1], want[1], atol=1e-4)
+
+
+def carried_tables_are_fresh(node, victims, asks, seed, priority):
+    """Step the program's own scan by hand: after every ask the carried
+    prefix tables are, number for number, the tables computed afresh
+    from the carried live mask. Returns how many asks evicted."""
+    from nomad_tpu.ops.preempt import _prefix_tables, _scan_parts
+
+    state, vstate, asks_ = program_inputs(node, victims, asks)
+    body, carry, xs = _scan_parts(
+        state, vstate, asks_, host_prng_key(seed), np.float32(priority), CFG)
+    step = jax.jit(body)
+    tables = jax.jit(_prefix_tables)
+    evicted = 0
+    for j in range(len(asks["active"])):
+        before = np.asarray(carry.vok)
+        carry, out = step(carry, jax.tree_util.tree_map(lambda x: x[j], xs))
+        fresh = tables(vstate, carry.vok, np.float32(priority))
+        for name, got, want in zip(fresh._fields, carry.prefix, fresh):
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want), err_msg=f"{name}, ask {j}")
+        gone = before & ~np.asarray(carry.vok)
+        assert not (~before & np.asarray(carry.vok)).any()
+        assert int(gone.sum()) == int(out[2]), j
+        if int(out[2]):
+            evicted += 1
+            assert gone[:, int(out[0])].sum() == int(out[2])
+    return evicted
+
+
+@pytest.mark.parametrize("seed", range(2870, 2876))
+def test_carried_prefix_tables_equal_fresh_ones_after_every_ask(seed):
+    """On random full fleets, with one roomy node so that asks that fit
+    without a victim stand among those that evict."""
+    node, victims, asks = seeded_fleet(seed, k_small=6, k_large=4,
+                                       k_pad=16)
+    rows = np.flatnonzero(node["node_ok"] & node["feasible"].all(axis=1))
+    node["util"][int(rows[seed % len(rows)]), 1] -= 3 * 2140
+    priority = 80.0 if seed % 2 else 31.0
+    assert carried_tables_are_fresh(node, victims, asks, seed, priority) >= 3
+
+
+@pytest.mark.parametrize("case", sorted(CRAFTED))
+def test_carried_prefix_tables_equal_fresh_ones_on_a_crafted_cell(case):
+    (node, victims, asks), _choices, counts = CRAFTED[case]
+    assert carried_tables_are_fresh(node, victims, asks, 2860, 50.0) \
+        == sum(1 for c in counts if c)
+
+
+def test_a_stale_column_does_not_pass_unseen(monkeypatch):
+    """The check above on a program that forgets to take the evicted
+    prefix out of the carried tables."""
+    from nomad_tpu.ops import preempt
+
+    consume = preempt._consume
+    monkeypatch.setattr(
+        preempt, "_consume",
+        lambda prefix, vok, hit, k_star, star:
+            (prefix, consume(prefix, vok, hit, k_star, star)[1]))
+    (node, victims, asks), _c, _n = CRAFTED["two_asks_evict_on_one_node"]
+    with pytest.raises(AssertionError):
+        carried_tables_are_fresh(node, victims, asks, 2860, 50.0)
 
 
 def doctored(fn):

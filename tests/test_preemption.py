@@ -31,6 +31,7 @@ from nomad_tpu.ops.preempt import (
     PREEMPT_MAX_VICTIMS,
     make_victim_state,
     preempt_placement_program_jit,
+    unpack_result,
 )
 from nomad_tpu.scheduler.testing import Harness
 from nomad_tpu.server import Server, ServerConfig
@@ -93,7 +94,8 @@ def _victims(n, entries):
             res[row, v] = r
             prio[row, v] = p
             ok[row, v] = True
-    return make_victim_state(res, bw, ports, prio, ok)
+    # the program takes the node axis last: [4, V, N] and [V, N]
+    return make_victim_state(res.T, bw.T, ports.T, prio.T, ok.T)
 
 
 CFG = PlacementConfig(anti_affinity_penalty=10.0)
@@ -103,13 +105,13 @@ def test_kernel_selects_lowest_priority_prefix():
     state = _kernel_state()
     victims = _victims(4, {0: [(30.0, 10), (30.0, 20)]})
     asks = _kernel_asks(2, 25.0)
-    choices, _s, counts = preempt_placement_program_jit(
-        state, victims, asks, host_prng_key(7), np.float32(50.0), CFG)
+    choices, _s, counts = unpack_result(preempt_placement_program_jit(
+        state, victims, asks, host_prng_key(7), np.float32(50.0), CFG))
     # Both asks land on node 0, each consuming ONE victim in sorted
     # order; the scan carries consumption so the second ask needs the
     # second victim.
-    assert list(np.asarray(choices)) == [0, 0]
-    assert list(np.asarray(counts)) == [1, 1]
+    assert list(choices) == [0, 0]
+    assert list(counts) == [1, 1]
 
 
 def test_kernel_prefers_normal_fit_over_preemption():
@@ -118,22 +120,22 @@ def test_kernel_prefers_normal_fit_over_preemption():
     state.util[2, :] = 10.0
     victims = _victims(4, {0: [(60.0, 10)], 1: [(60.0, 10)]})
     asks = _kernel_asks(1, 25.0)
-    choices, _s, counts = preempt_placement_program_jit(
-        state, victims, asks, host_prng_key(3), np.float32(50.0), CFG)
-    assert int(np.asarray(choices)[0]) == 2
-    assert int(np.asarray(counts)[0]) == 0  # no eviction needed
+    choices, _s, counts = unpack_result(preempt_placement_program_jit(
+        state, victims, asks, host_prng_key(3), np.float32(50.0), CFG))
+    assert int(choices[0]) == 2
+    assert int(counts[0]) == 0  # no eviction needed
 
 
 def test_kernel_never_evicts_equal_or_higher_priority():
     state = _kernel_state()
     victims = _victims(4, {0: [(60.0, 50)], 1: [(60.0, 80)]})
     asks = _kernel_asks(1, 25.0)
-    choices, _s, counts = preempt_placement_program_jit(
-        state, victims, asks, host_prng_key(5), np.float32(50.0), CFG)
+    choices, _s, counts = unpack_result(preempt_placement_program_jit(
+        state, victims, asks, host_prng_key(5), np.float32(50.0), CFG))
     # eval priority 50: neither the prio-50 nor the prio-80 victim is
     # outrankable -> no placement at all
-    assert int(np.asarray(choices)[0]) == -1
-    assert int(np.asarray(counts)[0]) == 0
+    assert int(choices[0]) == -1
+    assert int(counts[0]) == 0
 
 
 def test_kernel_prefix_stops_at_first_fit():
@@ -142,10 +144,10 @@ def test_kernel_prefix_stops_at_first_fit():
     # the prio-30 second victim must survive
     victims = _victims(4, {1: [(40.0, 5), (40.0, 30)]})
     asks = _kernel_asks(1, 25.0)
-    choices, _s, counts = preempt_placement_program_jit(
-        state, victims, asks, host_prng_key(9), np.float32(50.0), CFG)
-    assert int(np.asarray(choices)[0]) == 1
-    assert int(np.asarray(counts)[0]) == 1
+    choices, _s, counts = unpack_result(preempt_placement_program_jit(
+        state, victims, asks, host_prng_key(9), np.float32(50.0), CFG))
+    assert int(choices[0]) == 1
+    assert int(counts[0]) == 1
 
 
 # ---------------------------------------------------------------------
@@ -411,6 +413,76 @@ def test_preemption_leg_jit_cache_is_stable():
     h2.process("service-tpu", new_eval(h2.state.job_by_id(high2.id),
                                        consts.EVAL_TRIGGER_JOB_REGISTER))
     assert jit_cache_size() == warm
+
+
+def _scan_body_text(text, carried):
+    """Of a lowered module's text, the body of the one `stablehlo.while`
+    that carries a `carried` tensor, with every function it calls,
+    however deep."""
+    import re
+
+    lines = text.splitlines()
+    funcs, name = {}, None
+    for line in lines:
+        m = re.match(r"\s*func\.func \w+ @(\w+)\(", line)
+        if m:
+            name = m.group(1)
+        if name:
+            funcs.setdefault(name, []).append(line)
+    (at,) = [i for i, line in enumerate(lines)
+             if "stablehlo.while" in line and carried in line]
+    indent = len(lines[at]) - len(lines[at].lstrip())
+    stop = next(i for i in range(at + 1, len(lines))
+                if lines[i] == " " * indent + "}")
+    body, seen, todo = list(lines[at:stop]), set(), []
+    while True:
+        todo += [c for c in re.findall(r"@(\w+)", "\n".join(body))
+                 if c in funcs and c not in seen]
+        if not todo:
+            return "\n".join(body)
+        seen.add(todo[-1])
+        body += funcs[todo.pop()]
+
+
+def test_cell_shaped_program_keeps_nodes_minor_and_sums_nothing_an_ask():
+    """The program lowered for borg-12k's shape (N 16,384, V 8): the
+    scan over the asks carries the prefix tables node-minor, and its
+    body holds no reduce-window (a cumsum an ask, as the program had
+    three) and no array of victim granularity with the node axis
+    leading. The [N, 4] NodeState the scoring rule reads may stay."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from nomad_tpu.ops.binpack import Asks, NodeState
+    from nomad_tpu.ops.preempt import VictimState
+
+    n, g = 16384, 1
+    f32, i32, flag = jnp.float32, jnp.int32, jnp.bool_
+    shape = jax.ShapeDtypeStruct
+    state = NodeState(
+        shape((n, 4), f32), shape((n, 4), f32), shape((n, 4), f32),
+        shape((n,), f32), shape((n,), f32), shape((n,), f32),
+        shape((n,), i32), shape((n, g), i32), shape((n, g), flag),
+        shape((n,), flag))
+    victims = VictimState(
+        shape((4, V, n), f32), shape((V, n), f32), shape((V, n), f32),
+        shape((V, n), f32), shape((V, n), flag))
+    for k in (8, 16):
+        asks = Asks(shape((k, 4), f32), shape((k,), f32), shape((k,), f32),
+                    shape((k,), i32), shape((k,), flag), shape((), flag),
+                    shape((g,), flag))
+        text = preempt_placement_program_jit.lower(
+            state, victims, asks, shape((2,), jnp.uint32), shape((), f32),
+            CFG).as_text()
+        # the tables ride the carry, node axis last
+        body = _scan_body_text(text, f"tensor<4x{V}x{n}xf32>")
+        assert "stablehlo.select" in body and "@closed_call" in body, k
+        assert "reduce_window" not in body, k
+        assert "cumsum" not in body and "cumprod" not in body, k
+        assert not re.search(rf"tensor<{n}x{V}[x>]", body), k
+        assert f"tensor<{n}x4xf32>" in body  # the scoring rule's own
 
 
 @pytest.mark.parametrize("ask_floor,asks,k,job_rows", [
@@ -703,8 +775,9 @@ def _victims_by_walk(matrix, max_priority):
 def _assert_victims_equal_walk(matrix, max_priority):
     from nomad_tpu.models.matrix import _alloc_usage
 
-    (res, bw, ports, prio, ok), victims_of, total = matrix.build_victims(
-        max_priority)
+    arrays, victims_of, total = matrix.build_victims(max_priority)
+    # node axis last as handed to the program; read here node by node
+    res, bw, ports, prio, ok = (a.T for a in arrays)
     want = _victims_by_walk(matrix, max_priority)
     assert total == sum(len(c) for c in want.values())
     for i in range(matrix.n):
