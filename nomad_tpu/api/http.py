@@ -1047,6 +1047,13 @@ class HTTPServer:
         batcher_mod = sys.modules.get("nomad_tpu.scheduler.batcher")
         if batcher_mod is not None and batcher_mod._global is not None:
             out["placement_batcher"] = batcher_mod._global.stats()
+        # The fleet's class counts, from the newest cluster base (the
+        # `matrix.compress` span's annotation and the computed classes
+        # the compact overlay's class bucket holds).
+        matrix_mod = sys.modules.get("nomad_tpu.models.matrix")
+        compress = matrix_mod and matrix_mod.compress_stats()
+        if compress:
+            out["matrix_compress"] = compress
         # Central dispatch pipeline observability (occupancy, retries
         # per eval, batches in flight, stage latencies) — the lane-fill
         # telemetry the r05 verdict asked for.

@@ -123,6 +123,108 @@ def build_scenario(seed: int):
     return seed_state, job
 
 
+# Attribute values of the constraint rig's fleets, by target. Each
+# target also has values no node takes and operands that hold nowhere,
+# so that an empty mask is among the cases.
+_ATTR_VALUES = {
+    "${attr.kernel.version}": ["2.6.32", "3.2.0", "3.13.0-rc1", "4.4.0"],
+    "${attr.platform}": ["A", "B", "C"],
+    "${attr.cpu.arch}": ["amd64", "amd64", "amd64", "arm64"],
+    "${meta.ethernet}": ["1g", "1g", "10g"],
+    "${meta.disks}": ["1", "2", "4", "6", "8"],
+    "${node.class}": ["small", "large", "gpu"],
+}
+
+
+def random_constraint(rng: random.Random):
+    """One constraint over the rig's attributes, of one of the five
+    operand kinds of the reference (=, !=, a lexical order, version,
+    regexp)."""
+    from ..structs import Constraint
+
+    target = rng.choice(sorted(_ATTR_VALUES))
+    values = sorted(set(_ATTR_VALUES[target]))
+    kind = rng.choice(["=", "!=", "order", "version", "regexp"])
+    if kind == "version":
+        target = "${attr.kernel.version}"
+        rtarget = rng.choice([">= 3.2", "< 3.13.0", "~> 3.2", "> 2.6, < 4",
+                              ">= 3.13.0-rc1", "= 9.9"])
+    elif kind == "regexp":
+        rtarget = rng.choice([
+            "^(" + "|".join(rng.sample(values, min(2, len(values)))) + ")$",
+            "^" + values[0][0], values[-1][-1] + "$", "^nothing$"])
+    elif kind == "order":
+        kind = rng.choice(["<", "<=", ">", ">="])
+        rtarget = rng.choice(values)
+    else:
+        rtarget = rng.choice(values + ["absent"])
+    return Constraint(ltarget=target, operand=kind, rtarget=rtarget)
+
+
+def build_constraint_scenario(seed: int, min_classes: int = 0,
+                              escaped: bool = False,
+                              classless: bool = False):
+    """(seed_state_fn, job, nodes) for one case of the CONSTRAINT rig:
+    a fleet of random attributes, meta and node classes, and a job with
+    one to three random constraints of the five operand kinds on the
+    job, its task group or its task. `min_classes` puts every node in a
+    rack of its own kind until the fleet has more computed classes than
+    that (a rack is part of the class), `escaped` adds constraints on
+    `unique.` attributes, which never ride a class verdict, and
+    `classless` gives some nodes a value the class digest refuses."""
+    from .. import mock
+    from ..structs import Constraint
+
+    rng = random.Random(seed)
+    n_nodes = max(rng.choice([24, 40, 72]), min_classes + 8)
+    nodes = []
+    for i in range(n_nodes):
+        node = mock.node()
+        node.attributes["kernel.version"] = rng.choice(
+            _ATTR_VALUES["${attr.kernel.version}"])
+        node.attributes["platform"] = rng.choice(
+            _ATTR_VALUES["${attr.platform}"])
+        node.attributes["cpu.arch"] = rng.choice(
+            _ATTR_VALUES["${attr.cpu.arch}"])
+        node.attributes["unique.hostname"] = f"host-{i:04d}"
+        node.meta["ethernet"] = rng.choice(_ATTR_VALUES["${meta.ethernet}"])
+        node.meta["disks"] = rng.choice(_ATTR_VALUES["${meta.disks}"])
+        node.node_class = rng.choice(_ATTR_VALUES["${node.class}"])
+        if min_classes:
+            node.meta["rack"] = f"r{i % (min_classes + 3)}"
+        if classless and i % 7 == 3:
+            # A value with no stable digest: structs/node.py
+            # compute_class leaves such a node without a class.
+            node.meta["labels"] = ["dynamic"]
+        node.compute_class()
+        nodes.append(node)
+
+    job = mock.job()
+    job.type = rng.choice(["service", "batch"])
+    tg = job.task_groups[0]
+    tg.count = rng.choice([4, 6, 11])
+    task = tg.tasks[0]
+    task.resources.cpu = rng.choice([100, 333])
+    task.resources.memory_mb = rng.choice([64, 300])
+    task.resources.networks = []
+    scopes = [job.constraints, tg.constraints, task.constraints]
+    for _ in range(rng.choice([1, 2, 3])):
+        rng.choice(scopes).append(random_constraint(rng))
+    if escaped:
+        rng.choice(scopes).append(Constraint(
+            ltarget="${attr.unique.hostname}", operand="regexp",
+            rtarget=rng.choice(["[02468]$", "^host-00", "[1-5]$"])))
+
+    def seed_state(h, job):
+        from ..scheduler.testing import seed_harness_cluster
+
+        seed_harness_cluster(h, nodes=nodes, allocs=[], jobs=[job.copy()],
+                             drained=[])
+
+    return seed_state, job, nodes
+
+
+
 def _oracle_feasible(snap, job, tg, node) -> bool:
     """The HOST feasibility chain's verdict on one node for one task
     group: a fresh single-node iterator stack must yield it."""
@@ -138,13 +240,15 @@ def _oracle_feasible(snap, job, tg, node) -> bool:
     return option is not None
 
 
-def _check_case(kernel: str, seed: int) -> List[str]:
-    """Run one rig case; returns the list of violation strings."""
+def _check_case(kernel: str, seed: int, scenario=None) -> List[str]:
+    """Run one rig case; returns the list of violation strings.
+    `scenario(seed)` gives the case's (seed_state, job, ...); the
+    default is build_scenario."""
     from ..scheduler.testing import Harness
     from ..server.plan_apply import evaluate_node_plan
     from ..structs import allocs_fit, consts, new_eval, remove_allocs
 
-    seed_state, job = build_scenario(seed)
+    seed_state, job = (scenario or build_scenario)(seed)[:2]
     factory = f"{job.type}-{kernel}-tpu"
 
     h = Harness(seed=seed)
@@ -232,16 +336,19 @@ def _oracle_placed(seed: int) -> int:
 
 
 def run_differential(kernel: str, seeds=DEFAULT_SEEDS,
-                     with_oracle_counts: bool = False) -> Dict:
+                     with_oracle_counts: bool = False,
+                     scenario=None) -> Dict:
     """Run the rig for one kernel across `seeds`. Returns a report:
     {"kernel", "cases", "violations": [...], "green": bool,
-     "placed": {seed: (kernel_placed, oracle_placed)}? }."""
+     "placed": {seed: (kernel_placed, oracle_placed)}? }. `scenario`
+    names another case builder than build_scenario (the constraint
+    rig: build_constraint_scenario)."""
     from ..scheduler.testing import Harness  # noqa: F401 (fail fast on import)
 
     violations: List[str] = []
     placed: Dict[int, tuple] = {}
     for seed in seeds:
-        violations.extend(_check_case(kernel, seed))
+        violations.extend(_check_case(kernel, seed, scenario))
         if with_oracle_counts:
             from ..structs import consts, new_eval
 

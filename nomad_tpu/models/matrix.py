@@ -16,6 +16,7 @@ Bridges the object model (structs/state) to the array program
 from __future__ import annotations
 
 import bisect
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -41,8 +42,11 @@ BUCKETS = [128, 256, 512, 1024, 2048, 4096, 6144, 8192, 10240, 12288,
 ASK_BUCKETS = [8, 16, 32, 64, 128, 256, 512, 1024]
 # Compact-overlay padding buckets (each distinct size is one compile):
 # class count, feasibility-patch rows, job alloc positions. Overlays
-# larger than the top bucket fall back to the dense [N,G] overlay.
-CLASS_BUCKETS = [8, 32, 128]
+# larger than the top bucket fall back to the dense [N,G] overlay. A
+# rack is part of a node's computed class wherever it states one, so the
+# class ladder has to hold a fleet's rack count: 2,048 racks of 40 are
+# 80k nodes, and a lane's verdicts are then 2 KB x G.
+CLASS_BUCKETS = [8, 32, 128, 512, 2048]
 PATCH_BUCKETS = [16, 64, 256]
 JOBPOS_BUCKETS = [16, 64, 256, 1024]
 
@@ -82,7 +86,8 @@ def base_epoch() -> int:
 class _ClusterBase:
     __slots__ = ("n_real", "n", "capacity", "sched_capacity",
                  "util", "bw_avail", "bw_used", "ports_free", "node_ok",
-                 "alloc_groups", "token", "allocs_index", "table_len",
+                 "alloc_groups", "token", "nodes_token", "allocs_index",
+                 "table_len",
                  "nodes_index", "delta_parent", "class_ids", "class_reps",
                  "class_index", "topology", "_positions",
                  "_positions_lock", "_victims", "_victims_lock",
@@ -93,6 +98,13 @@ class _ClusterBase:
         # Identity token: evals whose matrices share one base can share
         # a single device upload (scheduler/batcher.py groups by it).
         self.token = next(_BASE_TOKENS)
+        # Identity of the NODE axis: a full build's own token, which its
+        # delta clones inherit with the class index, the topology tensor
+        # and the row order they share by reference. What depends on the
+        # nodes alone (the feasibility masks) is memoized under it and
+        # so outlives every commit; a rebuild of the node axis (a
+        # register, a class split) mints a new one.
+        self.nodes_token = self.token
         self.allocs_index = allocs_index  # -1 = not delta-updatable
         # Allocs-table size at build time: deletions (GC) are invisible
         # to the modify_index scan, so a shrinking table forces a full
@@ -453,6 +465,7 @@ class _ClusterBase:
             node_rows = [r for r in node_rows if r != lost]
         new = _ClusterBase.__new__(_ClusterBase)
         new.token = next(_BASE_TOKENS)
+        new.nodes_token = self.nodes_token
         new.allocs_index = new_allocs_index
         new.table_len = table_len
         new.nodes_index = max(base_nodes_index, new_nodes_index)
@@ -731,29 +744,63 @@ def ready_nodes_cached(state, datacenters):
     return out
 
 
-# Feasibility memo per (base token, job constraint signature): the
-# [N, G] mask depends only on the node set (pinned by the base token)
-# and the job's constraint/driver STRUCTURE — not its id. A placement
-# storm is N structurally identical jobs with distinct ids (one
-# service scaled out, the benchmark's storm), so every eval
-# of a drained batch was recomputing an identical mask under the GIL
-# while the batcher's cohort window ticked — the mask memo is to
-# node_feasibility what the base cache is to the [N, 4] build.
-_FEAS_CACHE: Dict[Tuple, Tuple[np.ndarray, Optional[np.ndarray]]] = {}
-_FEAS_MAX = 16
-# Compact overlay + zero job-count memo for jobs with NO live allocs
-# (every job of a placement storm, until its own plan commits): the
-# overlay is then a pure function of (base, constraint signature) and
-# its padded arrays are identical across the batch — per-eval numpy
-# materialization was the residual cohort-window stagger after the
-# mask memo. All cached arrays are read-only by contract (the batcher
-# stacks them; the kernel carries functional copies).
-_OVERLAY_CACHE: Dict[Tuple, Tuple] = {}
-_OVERLAY_MAX = 16
+# Feasibility memo per (node axis, job constraint signature): the
+# [N, G] mask depends only on the nodes (their computed classes and,
+# for escaped constraints and classless nodes, their own attributes)
+# and the job's constraint/driver STRUCTURE: not on its id, and not on
+# a single allocation. So it is keyed on the base's `nodes_token`, which
+# a delta clone inherits: a commit mints a new base token and leaves the
+# masks where they are, and the next eval of each signature finds its
+# mask instead of paying one ConstraintChecker pass per class per
+# constraint under the GIL and the expansion over N again. A rebuild of
+# the node axis (a register, a deregister, a meta edit that moves a
+# computed class) mints a new nodes_token and the masks are built anew.
+# A mask that reads per-node attributes (`per_node`) also pins the
+# nodes-table index it was built at: a node's unique attributes can
+# change under a row delta that keeps its class.
+# Sized for the signatures a deployment has in flight on one or two
+# node axes, not for one storm's single signature.
+_FEAS_CACHE: Dict[Tuple, "_Mask"] = {}
+_FEAS_MAX = 64
+
+
+class _Mask:
+    """One memoized feasibility mask: the padded [N, G] mask, and its
+    compact form for the device-side expansion (ops/binpack.py
+    CompactOverlay): the per-class verdicts padded to a class bucket
+    and the sparse patch for rows the class verdict cannot represent
+    (classless nodes, escaped constraints); `compact` is None where
+    there are no classes or a part overflows its top bucket. Read-only
+    once built: the evals of a batch share it."""
+
+    __slots__ = ("feasible", "compact", "nodes_index", "idle")
+
+    def __init__(self, feasible, compact, nodes_index):
+        self.feasible = feasible
+        self.compact = compact  # (verdicts, patch_rows, patch_vals)
+        self.nodes_index = nodes_index  # None: class verdicts alone
+        # The whole overlay of a job with NO live allocs (every job of
+        # a placement storm, until its own plan commits), by job-rows
+        # floor: (zero job_count, zero tg_count, compact overlay). It is
+        # then a pure function of the mask, and its padded arrays are
+        # identical across the batch: per-eval numpy materialization
+        # was the residual cohort-window stagger after the mask memo.
+        # Two evals that miss at once store equal values.
+        self.idle: Dict[int, Tuple] = {}
 
 
 def _constraint_sig(cons) -> Tuple:
     return tuple((c.ltarget, c.operand, c.rtarget) for c in cons)
+
+
+def _constraint_scopes(job, groups):
+    """The constraint lists node_feasibility reads: the job's, then
+    each task group's and its tasks'."""
+    yield job.constraints
+    for tg in groups:
+        yield tg.constraints
+        for task in tg.tasks:
+            yield task.constraints
 
 
 def feasibility_signature(job) -> Tuple:
@@ -1108,6 +1155,21 @@ def resolve_cluster_base(state, datacenters, nodes=None, explicit=False,
             done.set()
 
 
+def compress_stats() -> Optional[dict]:
+    """How far the newest cluster base's fleet interned: the
+    `matrix.compress` annotation (models/classes.py ClassIndex.stats)
+    and `computed_classes`, the count the compact overlay's class
+    bucket has to hold. None before any cacheable base was built.
+    `/v1/agent/self` carries it as `matrix_compress`."""
+    with _BASE_CACHE_LOCK:
+        base = max(_BASE_FAMILY.values(), key=lambda b: b.token,
+                   default=None)
+    if base is None:
+        return None
+    return dict(base.class_index.stats(),
+                computed_classes=len(base.class_reps))
+
+
 class _BaseView:
     """A _ClusterBase under the attribute names the batcher's
     device-residency entry points expect (ClusterMatrix's surface) —
@@ -1249,30 +1311,23 @@ class ClusterMatrix:
         # Job-specific overlay: this job's per-node alloc counts, from
         # the base's lazy positions index (O(this job's allocs)).
         positions = base.job_positions(self.job.id)
+        # Set by _build_feasibility where this eval really built a mask
+        # (a memo miss): (t0, t1, annotations) of the span
+        # `feasibility.build`.
+        self.feas_build = None
+        mask = self._build_feasibility(base)
+        self.feasible = mask.feasible
         if not positions and base.allocs_index >= 0:
-            # No live allocs (the storm shape): the whole overlay —
-            # zero counts, feasibility, compact form — is a function
-            # of (base, constraint signature); share one memo across
-            # the batch instead of re-materializing ~N-sized arrays
-            # per eval under the GIL.
-            okey = (base.token, feasibility_signature(self.job),
-                    self._job_rows_floor)
-            with _BASE_CACHE_LOCK:
-                hit = _OVERLAY_CACHE.get(okey)
-            if hit is not None:
-                (self.job_count, self.tg_count, self.feasible,
-                 self.compact_overlay) = hit
-                return
-            self.job_count = np.zeros(n, np.int32)
-            self.tg_count = np.zeros((n, g), np.int32)
-            self.feasible, verdicts = self._build_feasibility(base)
-            self._build_compact_overlay(base, verdicts)
-            with _BASE_CACHE_LOCK:
-                while len(_OVERLAY_CACHE) >= _OVERLAY_MAX:
-                    _OVERLAY_CACHE.pop(next(iter(_OVERLAY_CACHE)))
-                _OVERLAY_CACHE[okey] = (
-                    self.job_count, self.tg_count, self.feasible,
-                    self.compact_overlay)
+            # No live allocs (the storm shape): the whole overlay is
+            # the mask's, shared across the batch and across commits.
+            hit = mask.idle.get(self._job_rows_floor)
+            if hit is None:
+                self.job_count = np.zeros(n, np.int32)
+                self.tg_count = np.zeros((n, g), np.int32)
+                self._build_compact_overlay(mask, {})
+                hit = mask.idle[self._job_rows_floor] = (
+                    self.job_count, self.tg_count, self.compact_overlay)
+            self.job_count, self.tg_count, self.compact_overlay = hit
             return
         job_count = np.zeros(n, np.int32)
         tg_count = np.zeros((n, g), np.int32)
@@ -1284,8 +1339,7 @@ class ClusterMatrix:
                 np.add.at(tg_count[:, gi], rows, 1)
         self.job_count = job_count
         self.tg_count = tg_count
-        self.feasible, verdicts = self._build_feasibility(base)
-        self._build_compact_overlay(base, verdicts)
+        self._build_compact_overlay(mask, positions)
 
     def _plan_rows(self) -> Dict[int, str]:
         """{row: node id} of the nodes this matrix's plan stops,
@@ -1306,7 +1360,7 @@ class ClusterMatrix:
         proposed_allocs_for_node): each row moves by the difference of
         the two sums, as a base's delta adds a new allocation's usage.
         Every other row IS the cached base's. The arrays touched are
-        copied first: the base's and the overlay memo's are shared."""
+        copied first: the base's and the mask memo's are shared."""
         rows = self._plan_rows()
         if not rows:
             return
@@ -1339,35 +1393,23 @@ class ClusterMatrix:
                 if gi is not None:
                     self.tg_count[i, gi] += 1
 
-    def _build_compact_overlay(self, base, verdicts) -> None:
+    def _build_compact_overlay(self, mask: "_Mask", positions) -> None:
         """The pre-expansion overlay (ops/binpack.py CompactOverlay):
-        per-class verdicts + a sparse patch for rows the class verdict
-        can't represent, and this job's alloc row positions — a few KB
-        per eval instead of the ~100KB x G dense overlay at 10k nodes.
-        None (dense fallback) when the base isn't device-cacheable or
-        any component overflows its top padding bucket."""
+        the mask's compact form (per-class verdicts + a sparse patch,
+        memoized with the mask) and this job's alloc row positions — a
+        few KB per eval instead of the ~100KB x G dense overlay at 10k
+        nodes. None (dense fallback) when the base isn't
+        device-cacheable or any component overflows its top padding
+        bucket."""
         self.compact_overlay = None
-        if self.base_token is None or verdicts is None:
-            return
-        n_real, g = self.n_real, self.g
-        ids = base.class_ids[:n_real]
-        if len(base.class_reps) > CLASS_BUCKETS[-1]:
-            return
-        # Patch rows: wherever the real mask differs from the class
-        # expansion (classless nodes, escaped constraints).
-        expected = np.zeros((n_real, g), bool)
-        classed = ids >= 0
-        expected[classed] = verdicts[ids[classed]]
-        feas_real = self.feasible[:n_real]
-        patch_rows = np.flatnonzero((feas_real != expected).any(axis=1))
-        if len(patch_rows) > PATCH_BUCKETS[-1]:
+        if self.base_token is None or mask.compact is None:
             return
         # This job's alloc positions, flattened with their TG indices.
         gi_by_name = {tg.name: gi for gi, tg in enumerate(self.groups)}
         rows_parts: List[np.ndarray] = []
         tg_parts: List[np.ndarray] = []
         n_pos = 0
-        for task_group, rows in base.job_positions(self.job.id).items():
+        for task_group, rows in positions.items():
             gi = gi_by_name.get(task_group)
             if gi is None:
                 continue
@@ -1376,18 +1418,9 @@ class ClusterMatrix:
             n_pos += len(rows)
         if n_pos > JOBPOS_BUCKETS[-1]:
             return
-        c_pad = bucket_size(max(len(base.class_reps), 1), CLASS_BUCKETS)
-        p_pad = bucket_size(len(patch_rows), PATCH_BUCKETS) \
-            if len(patch_rows) else PATCH_BUCKETS[0]
         j_pad = max(bucket_size(n_pos, JOBPOS_BUCKETS),
                     self._job_rows_floor)
-        verd = np.zeros((c_pad, g), bool)
-        verd[: len(verdicts)] = verdicts
         # Pad with self.n: out of range, dropped by the device scatter.
-        p_rows = np.full(p_pad, self.n, np.int32)
-        p_rows[: len(patch_rows)] = patch_rows
-        p_vals = np.zeros((p_pad, g), bool)
-        p_vals[: len(patch_rows)] = feas_real[patch_rows]
         j_rows = np.full(j_pad, self.n, np.int32)
         j_tgs = np.zeros(j_pad, np.int32)
         if n_pos:
@@ -1395,35 +1428,76 @@ class ClusterMatrix:
             j_tgs[:n_pos] = np.concatenate(tg_parts)
         from ..ops.binpack import CompactOverlay
 
+        verd, p_rows, p_vals = mask.compact
         self.compact_overlay = CompactOverlay(
             verdicts=verd, patch_rows=p_rows, patch_vals=p_vals,
             job_rows=j_rows, job_tgs=j_tgs)
 
-    def _build_feasibility(self, base):
-        """([N, G] padded mask, per-class verdicts or None); see
-        node_feasibility. Memoized per (base token, job constraint
-        signature): a storm's structurally identical jobs share one
-        mask computation per base instead of one per eval (the memo'd
-        arrays are treated as immutable by every consumer)."""
-        key = None
+    def _compact_mask(self, base, feasible, verdicts):
+        """The compact form of one mask, or None: the class verdicts
+        padded to a class bucket, and as patch rows wherever the real
+        mask differs from their expansion (classless nodes, escaped
+        constraints)."""
+        if verdicts is None or len(base.class_reps) > CLASS_BUCKETS[-1]:
+            return None
+        n_real, g = self.n_real, self.g
+        ids = base.class_ids[:n_real]
+        expected = np.zeros((n_real, g), bool)
+        classed = ids >= 0
+        expected[classed] = verdicts[ids[classed]]
+        feas_real = feasible[:n_real]
+        patch_rows = np.flatnonzero((feas_real != expected).any(axis=1))
+        if len(patch_rows) > PATCH_BUCKETS[-1]:
+            return None
+        c_pad = bucket_size(max(len(base.class_reps), 1), CLASS_BUCKETS)
+        p_pad = bucket_size(len(patch_rows), PATCH_BUCKETS) \
+            if len(patch_rows) else PATCH_BUCKETS[0]
+        verd = np.zeros((c_pad, g), bool)
+        verd[: len(verdicts)] = verdicts
+        # Pad with self.n: out of range, dropped by the device scatter.
+        p_rows = np.full(p_pad, self.n, np.int32)
+        p_rows[: len(patch_rows)] = patch_rows
+        p_vals = np.zeros((p_pad, g), bool)
+        p_vals[: len(patch_rows)] = feas_real[patch_rows]
+        return verd, p_rows, p_vals
+
+    def _build_feasibility(self, base) -> "_Mask":
+        """The job's mask over this base's nodes; see node_feasibility.
+        Memoized per (node axis, job constraint signature): a storm's
+        structurally identical jobs share one mask computation per
+        rebuild of the node axis, whatever commits in between (the
+        memo'd arrays are treated as immutable by every consumer)."""
+        key = nodes_index = None
         if base.allocs_index >= 0:  # cacheable bases only
-            key = (base.token, feasibility_signature(self.job))
+            key = (base.nodes_token, feasibility_signature(self.job))
+            nodes_index = self.state.index("nodes")
             with _BASE_CACHE_LOCK:
                 hit = _FEAS_CACHE.get(key)
-            if hit is not None:
+            if hit is not None and hit.nodes_index in (None, nodes_index):
                 return hit
+        t0 = time.monotonic()
         feasible = np.zeros((self.n, self.g), bool)
+        ids = base.class_ids[: self.n_real]
         real, verdicts = node_feasibility(
-            self.state, self.job, self.groups, self.nodes,
-            base.class_ids[: self.n_real], base.class_reps,
-            return_verdicts=True)
+            self.state, self.job, self.groups, self.nodes, ids,
+            base.class_reps, return_verdicts=True)
         feasible[: self.n_real] = real
+        cons = [c for scope in _constraint_scopes(self.job, self.groups)
+                for c in scope]
+        escaped = len(escaped_constraints(cons))
+        per_node = bool(escaped) or bool((ids < 0).any())
+        mask = _Mask(feasible, self._compact_mask(base, feasible, verdicts),
+                     nodes_index if per_node else None)
+        self.feas_build = (t0, time.monotonic(), {
+            "classes": len(base.class_reps), "groups": self.g,
+            "constraints": len(cons), "escaped": escaped})
         if key is not None:
             with _BASE_CACHE_LOCK:
+                _FEAS_CACHE.pop(key, None)
                 while len(_FEAS_CACHE) >= _FEAS_MAX:
                     _FEAS_CACHE.pop(next(iter(_FEAS_CACHE)))
-                _FEAS_CACHE[key] = (feasible, verdicts)
-        return feasible, verdicts
+                _FEAS_CACHE[key] = mask
+        return mask
 
     # ------------------------------------------------------------------
 

@@ -97,8 +97,6 @@ class EvalContext:
         self.logger = logger or logging.getLogger("nomad_tpu.scheduler")
         self.metrics = AllocMetric()
         self.eligibility = EvalEligibility()
-        self.regexp_cache: Dict[str, object] = {}
-        self.constraint_cache: Dict[str, object] = {}
         self.rng = rng or random.Random()
 
     def reset(self) -> None:

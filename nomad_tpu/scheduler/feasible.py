@@ -8,6 +8,7 @@ FeasibilityWrapper:457.
 
 from __future__ import annotations
 
+import functools
 import re
 from typing import Dict, Iterable, List, Optional, Set
 
@@ -152,9 +153,9 @@ def check_constraint(ctx: EvalContext, operand: str, lval, rval) -> bool:
     if operand in ("<", "<=", ">", ">="):
         return _check_lexical(operand, lval, rval)
     if operand == consts.CONSTRAINT_VERSION:
-        return _check_version(ctx, lval, rval)
+        return _check_version(lval, rval)
     if operand == consts.CONSTRAINT_REGEX:
-        return _check_regexp(ctx, lval, rval)
+        return _check_regexp(lval, rval)
     return False
 
 
@@ -170,7 +171,24 @@ def _check_lexical(op: str, lval, rval) -> bool:
     return lval >= rval
 
 
-def _check_version(ctx: EvalContext, lval, rval) -> bool:
+# Parsed `version` constraints and compiled `regexp` operands by their
+# text. Process-wide, not on the EvalContext: the dense mask build makes
+# a context of its own for every build, and a job's operand text is the
+# same from eval to eval. None = the text does not parse.
+@functools.lru_cache(maxsize=1024)
+def _parsed_constraints(text: str):
+    return parse_constraints(text)
+
+
+@functools.lru_cache(maxsize=1024)
+def _compiled_regexp(text: str):
+    try:
+        return re.compile(text)
+    except re.error:
+        return None
+
+
+def _check_version(lval, rval) -> bool:
     if isinstance(lval, int):
         lval = str(lval)
     if not isinstance(lval, str) or not isinstance(rval, str):
@@ -178,26 +196,15 @@ def _check_version(ctx: EvalContext, lval, rval) -> bool:
     version = parse_version(lval)
     if version is None:
         return False
-    constraints = ctx.constraint_cache.get(rval)
-    if constraints is None:
-        constraints = parse_constraints(rval)
-        if constraints is None:
-            return False
-        ctx.constraint_cache[rval] = constraints
-    return constraints.check(version)
+    constraints = _parsed_constraints(rval)
+    return constraints is not None and constraints.check(version)
 
 
-def _check_regexp(ctx: EvalContext, lval, rval) -> bool:
+def _check_regexp(lval, rval) -> bool:
     if not isinstance(lval, str) or not isinstance(rval, str):
         return False
-    compiled = ctx.regexp_cache.get(rval)
-    if compiled is None:
-        try:
-            compiled = re.compile(rval)
-        except re.error:
-            return False
-        ctx.regexp_cache[rval] = compiled
-    return compiled.search(lval) is not None
+    compiled = _compiled_regexp(rval)
+    return compiled is not None and compiled.search(lval) is not None
 
 
 class ProposedAllocConstraintIterator:

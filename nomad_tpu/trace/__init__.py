@@ -35,6 +35,7 @@ from .span import (  # noqa: F401
     STAGE_DISPATCH_POOL_WAIT,
     STAGE_EVAL_UNCOVERED,
     STAGE_EVAL_UPDATE,
+    STAGE_FEASIBILITY_BUILD,
     STAGE_GANG_BUILD,
     STAGE_GANG_SELECT,
     STAGE_GANG_SOLVE,

@@ -36,6 +36,12 @@ STAGE_MATRIX_BUILD = "matrix.build"        # ClusterMatrix + ask construction
 STAGE_MATRIX_UPDATE = "matrix.update"      # incremental delta vs full rebuild
 STAGE_MATRIX_COMPRESS = "matrix.compress"  # signature-class interning
 #   (models/classes.py; ann: classes C, nodes N, escaped, ratio N/C)
+STAGE_FEASIBILITY_BUILD = "feasibility.build"  # inside matrix.build:
+#   one job's constraint mask really BUILT over the node axis
+#   (models/matrix.py _build_feasibility: a checker pass per computed
+#   class, the expansion over N, the compact form; ann: classes,
+#   groups, constraints, escaped). Recorded only on a miss of the
+#   mask memo, so its sample count is the memo's misses
 STAGE_DEVICE_TRANSFER = "device.transfer"  # base prefetch host->device
 STAGE_DEVICE_DISPATCH = "device.dispatch"  # batcher.place round-trip
 STAGE_DEVICE_SOLVE = "device.solve"        # the jitted placement-kernel
@@ -110,6 +116,7 @@ ALL_STAGES = (
     STAGE_MATRIX_BUILD,
     STAGE_MATRIX_UPDATE,
     STAGE_MATRIX_COMPRESS,
+    STAGE_FEASIBILITY_BUILD,
     STAGE_DEVICE_TRANSFER,
     STAGE_DEVICE_DISPATCH,
     STAGE_DEVICE_SOLVE,
