@@ -204,6 +204,9 @@ class DispatchPipeline:
         # would read the whole accumulation window as scheduling delay.
         self._notified_at = 0.0  # guarded-by: _lock
         self._drain_waiting = False  # guarded-by: _lock
+        # The forming batch's placeholder at the batcher
+        # (_announce_forming); the dispatcher thread's alone.
+        self._forming = None
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.drained = 0  # guarded-by: _lock (evals requeued by drain())
@@ -318,6 +321,9 @@ class DispatchPipeline:
     def _run(self) -> None:
         while not self._stop.is_set():
             batch = self._accumulate()
+            forming, self._forming = self._forming, None
+            if not batch and forming is not None:
+                forming.settle()
             if batch:
                 # The launch prologue BLOCKS — _wait_for_index
                 # sleep-polls the FSM for up to WAIT_INDEX_TIMEOUT and
@@ -330,7 +336,7 @@ class DispatchPipeline:
                 # _accumulate already took the in-flight slot, so the
                 # pipelining bound still holds while the launch is in
                 # hand-off.
-                self.server.eval_pool.submit(self._launch, batch)
+                self.server.eval_pool.submit(self._launch, batch, forming)
 
     def _accumulate(self) -> List[_Pending]:
         """Pack the next batch: wait for a seed eval, then top up with
@@ -389,7 +395,12 @@ class DispatchPipeline:
                 elif (self._inflight < self.max_inflight
                       and elapsed >= self.window):
                     break
-                self._cond.wait(DEQUEUE_TOPUP_SLICE)
+                announce = (self._forming is None
+                            and 0 < self._inflight < self.max_inflight)
+                if not announce:
+                    self._cond.wait(DEQUEUE_TOPUP_SLICE)
+            if announce:
+                self._announce_forming()
         # Wait for an in-flight slot; late arrivals keep joining the
         # pending list while we wait (that IS the adaptive window).
         with self._cond:
@@ -401,6 +412,7 @@ class DispatchPipeline:
             if not batch:
                 return []
             self._inflight += 1
+            others = self._inflight > 1
             self.batches += 1
             self.dispatched_evals += len(batch)
             self.largest_batch = max(self.largest_batch, len(batch))
@@ -409,10 +421,39 @@ class DispatchPipeline:
                     1 for entry in batch if entry.requeues)
             profile.event("accumulate_close", "dispatcher",
                           a=len(batch), b=self.batches)
+        if others and self._forming is None:
+            self._announce_forming()
         metrics.add_sample(("dispatch", "batch_size"), len(batch))
         return batch
 
-    def _launch(self, batch: List[_Pending]) -> None:
+    def _announce_forming(self) -> None:
+        """Tell the batcher that a batch is forming beside one in
+        flight and WILL launch within the window (a slot is free, or
+        it has just taken one): a cohort of one placeholder unit, which
+        _launch settles when the batch's prologue is through (its own
+        cohort is open by then) or has failed, and the prologue itself
+        before it waits for a lagging FSM. A dispatch that holds the
+        in-flight batch's requests waits for every open cohort
+        (scheduler/batcher.py _accumulate), so the two batches go
+        together whatever the prologue's length: it waits for at most
+        this batch's window and its base prefetch. While a prologue
+        took 40-80 ms they mostly met by themselves; since PR 39 it
+        takes 5-25, every batch went alone, overlapped both neighbours'
+        uncommitted plans, and the storm's conflicts rose from 0.16 an
+        eval to 0.45 (PERF.md section 6, PRs 31 and 39). Bounded by the
+        cohort's cap like any member that does not come."""
+        from ..scheduler.batcher import get_batcher
+
+        (self._forming,) = get_batcher().open_cohort(1)
+
+    def _launch(self, batch: List[_Pending], forming=None) -> None:
+        try:
+            self._launch_batch(batch, forming)
+        finally:
+            if forming is not None:
+                forming.settle()
+
+    def _launch_batch(self, batch: List[_Pending], forming) -> None:
         # Trace: the accumulate stage closes when the batch is cut.
         # Recorded HERE (stage thread) rather than in _accumulate so
         # the dispatcher thread carries zero extra work per batch.
@@ -441,7 +482,7 @@ class DispatchPipeline:
         try:
             with trace.annotation("nomad.launch_prologue",
                                   evals=len(batch)):
-                prologue = self._launch_prologue(batch)
+                prologue = self._launch_prologue(batch, forming)
         except Exception:
             self.logger.exception(
                 "batch launch failed; nacking %d evals", len(batch))
@@ -541,7 +582,7 @@ class DispatchPipeline:
                 self._inflight -= 1
                 self._cond.notify_all()
 
-    def _launch_prologue(self, batch: List[_Pending]):
+    def _launch_prologue(self, batch: List[_Pending], forming=None):
         """(snapshot, route_host, units) for a launchable batch, None
         when the FSM never caught up to the batch's snapshot index.
         `units` holds, entry for entry, each eval's unit of the batch's
@@ -586,6 +627,12 @@ class DispatchPipeline:
         # safe: the applier re-verifies every node.
         max_index = max(max(e.eval.modify_index, e.min_index)
                         for e in batch)
+        if (forming is not None
+                and self.server.fsm.state.latest_index() < max_index):
+            # The FSM lags (a follower behind its leader): nobody waits
+            # that out on this batch's placeholder; the batch in flight
+            # goes alone, as it did before the placeholder.
+            forming.settle()
         if not self._wait_for_index(max_index, WAIT_INDEX_TIMEOUT):
             return None
         snapshot = self.server.fsm.state.snapshot()
@@ -597,9 +644,11 @@ class DispatchPipeline:
             # matrix builds stagger under the GIL) closes when the last
             # of the batch has arrived or been settled, not on a timed
             # window. Opened last, after everything that can raise and
-            # after the prefetch: a prologue that fails opens nothing,
-            # and the other batch in flight, which waits for an open
-            # cohort, never waits on this prologue. System-dense evals
+            # after the prefetch: a prologue that fails opens nothing.
+            # (The other batch in flight does wait this prefetch out
+            # since PR 39, on the placeholder _announce_forming opened
+            # and _launch settles once this cohort is open; never an
+            # index wait.) System-dense evals
             # are excluded — DenseSystemScheduler's vectorized pass
             # never touches the batcher. A generic dense eval that
             # takes a host path, places nothing or fails settles its
@@ -647,6 +696,7 @@ class DispatchPipeline:
             t0 = time.monotonic()
             try:
                 view, kind = prefetch_cluster_base(snapshot, list(dcs))
+                t_host = time.monotonic()
                 nbytes = batcher.prefetch_base(view) if view else 0
             except Exception:
                 self.logger.warning(
@@ -660,6 +710,14 @@ class DispatchPipeline:
                 self.prefetch_bytes += nbytes
             metrics.incr_counter(("dispatch", "prefetch_bytes"), nbytes)
             profile.event("prefetch", "stage", a=int(nbytes))
+            if kind == "delta":
+                # The host half alone, once a derived delta (on the
+                # batch's first eval): what the delta held and how many
+                # jobs' positions it rewrote.
+                trace.record_span(
+                    entries[0].eval.id, trace.STAGE_BASE_DELTA, t0, t_host,
+                    ann=view.delta_stats,
+                    trace_id=entries[0].eval.trace_id)
             # One span per eval riding this base: stage attribution for
             # the new path (the bytes shipped are the batch's WHOLE
             # host->device traffic when the delta path holds).

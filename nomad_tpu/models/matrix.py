@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import bisect
 import time
+from collections import Counter
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -88,7 +89,8 @@ class _ClusterBase:
                  "util", "bw_avail", "bw_used", "ports_free", "node_ok",
                  "alloc_groups", "token", "nodes_token", "allocs_index",
                  "table_len",
-                 "nodes_index", "delta_parent", "class_ids", "class_reps",
+                 "nodes_index", "delta_parent", "delta_stats", "class_ids",
+                 "class_reps",
                  "class_index", "topology", "_positions",
                  "_positions_lock", "_victims", "_victims_lock",
                  "_row_of")
@@ -120,6 +122,10 @@ class _ClusterBase:
         # parent's device-cached arrays instead of re-uploading
         # (ops/binpack.py apply_base_delta).
         self.delta_parent = None
+        # What that delta held, the annotations of the span
+        # `base.delta`: rows written, allocations added to additive
+        # rows, rows refilled, jobs whose positions entry was rewritten.
+        self.delta_stats = None
         self.n_real = len(nodes)
         self.n = bucket_size(self.n_real)
         n = self.n
@@ -134,7 +140,8 @@ class _ClusterBase:
         # cheap per-job overlay counts
         self.alloc_groups: List[List[Tuple[str, str]]] = []
         self._init_class_index(nodes)
-        # job_id -> {tg: row indices}, built lazily
+        # The job-positions index, {job_id: _JobRows}: built lazily by
+        # a full build's first reader, carried on by delta clones
         from ..profile import ProfiledLock
 
         self._positions = None  # guarded-by: _positions_lock
@@ -174,11 +181,14 @@ class _ClusterBase:
 
     def job_positions(self, job_id: str) -> Dict[str, np.ndarray]:
         """{task_group: node-row indices (with repeats)} for one job's
-        live allocs. The index over alloc_groups builds lazily ONCE per
-        base (O(total allocs)) and every eval in a drained batch then
-        pays O(its own job's allocs) instead of an O(N x allocs) python
-        scan — the per-eval overlay cost that dominated the live dense
-        path at 10k nodes / 50k allocs."""
+        live allocs; the order of a task group's rows is no part of the
+        contract. The index over alloc_groups builds lazily ONCE per
+        chain of bases (O(total allocs), on a full build's first
+        reader); a delta clone carries it on at O(what the delta wrote)
+        (_patch_positions), and a reader pays O(its own job's allocs):
+        the lookup, and once a base the fold of the rows that came to
+        and went from THIS job since it was last read (_JobRows). No
+        other job's rows are looked at, here or in a delta."""
         with self._positions_lock:
             if self._positions is None:
                 positions: Dict[str, Dict[str, List[int]]] = {}
@@ -187,11 +197,12 @@ class _ClusterBase:
                         positions.setdefault(jid, {}).setdefault(
                             tg, []).append(i)
                 self._positions = {
-                    jid: {tg: np.asarray(rows, np.int64)
-                          for tg, rows in per.items()}
+                    jid: _JobRows({tg: np.asarray(rows, np.int64)
+                                   for tg, rows in per.items()})
                     for jid, per in positions.items()
                 }
-            return self._positions.get(job_id, {})
+            entry = self._positions.get(job_id)
+        return entry.rows() if entry is not None else {}
 
     def row_of(self, nodes) -> Dict[str, int]:
         """{node id: row} of this base's node set. Built once per
@@ -319,7 +330,13 @@ class _ClusterBase:
 
     def delta_update(self, nodes, state, new_allocs_index: int,
                      new_nodes_index: int = -1) -> Optional["_ClusterBase"]:
-        """A newer base for the same node set: only rows whose allocs
+        """A newer base for the same node set, at a cost that follows
+        what was written since our allocs_index and never what lives
+        on the rows it touched: an allocation created since is
+        scatter-added to its row and appended to its own job's
+        positions, a row with a changed pre-existing allocation is
+        refilled from its own entries, and no other job is looked at
+        (_patch_positions). Only rows whose allocs
         changed since our allocs_index are recomputed — and, when the
         NODES table advanced too, rows whose node object changed
         (up/down/drain flips) are refilled with node_ok re-derived, so
@@ -500,7 +517,6 @@ class _ClusterBase:
         new.ports_free = self.ports_free.copy()
         new.node_ok = self.node_ok.copy()
         new.alloc_groups = list(self.alloc_groups)
-        old_groups = {i: self.alloc_groups[i] for i in rows}
         for i in refill_rows:
             new._fill_row(
                 i, nodes[i],
@@ -520,75 +536,165 @@ class _ClusterBase:
                     or not np.array_equal(new.bw_avail[nr],
                                           self.bw_avail[nr])):
                 return None
-        get_tracker().count_delta(len(rows) - len(node_rows),
-                                  len(node_rows))
+        add_rows = [row_of[a.node_id] for a in adds]
         if adds:
             # Additive rows: one bulk scatter-add of the new allocs'
             # memoized usage — O(new allocs), not O(rows x allocs).
-            ridx = np.asarray([row_of[a.node_id] for a in adds], np.intp)
+            ridx = np.asarray(add_rows, np.intp)
             ua = np.asarray([_alloc_usage(a) for a in adds], np.float32)
             np.add.at(new.util, ridx, ua[:, :4])
             np.add.at(new.bw_used, ridx, ua[:, 4])
             np.subtract.at(new.ports_free, ridx, ua[:, 5])
-            for a in adds:
-                i = row_of[a.node_id]
+            for a, i in zip(adds, add_rows):
                 # Copy-on-write: the parent's row list stays untouched.
                 if new.alloc_groups[i] is self.alloc_groups[i]:
                     new.alloc_groups[i] = list(self.alloc_groups[i])
                 new.alloc_groups[i].append((a.job_id, a.task_group))
-        new._patch_positions(self, rows, old_groups)
+        patched = new._patch_positions(self, adds, add_rows, refill_rows)
         new._patch_victims(self, rows, nodes, state)
+        # Counted after the chaos drop above: what was really written.
+        new.delta_stats = {"rows": len(set(add_rows) | set(refill_rows)),
+                           "adds": len(adds),
+                           "refills": len(refill_rows),
+                           "patched_jobs": patched}
+        get_tracker().count_delta(len(rows) - len(node_rows),
+                                  len(node_rows), patched)
         return new
 
-    def _patch_positions(self, parent: "_ClusterBase", rows,
-                         old_groups) -> None:
-        """Carry the parent's job-positions index forward, re-deriving
-        only the jobs present in the changed rows — rebuilding the full
-        index is an O(total allocs) python scan per delta base, dozens
-        of times per live storm."""
+    def _patch_positions(self, parent: "_ClusterBase", adds, add_rows,
+                         refill_rows) -> int:
+        """Carry the parent's job-positions index forward at a cost of
+        O(entries this delta wrote): the allocations it adds plus the
+        entries of the rows it refills. Never O(jobs resident on the
+        touched rows), never O(touched rows x allocations a row) per
+        job, and no scan of the positions of a job that neither gains
+        nor loses an entry.
+
+        - An added allocation (`adds`, on the additive rows `add_rows`)
+          changes its own (job, task group) alone, by one appended row;
+          no other job on that row is looked at.
+        - A refilled row (`refill_rows`: a pre-existing allocation
+          changed, an eviction, a node flip) is read once, its old
+          entries against its new: what leaves and what comes, counted
+          per (job, task group), and only a pair whose count really
+          differs is written.
+        - What is written is an edit on the job's entry (_JobRows), not
+          a new array: a standing job that loses one row of 59,000 is
+          not scanned for it. Entries of jobs the delta did not write
+          are the parent's objects; the dict that holds them is copied
+          (26 us for 6,200 jobs: PERF.md section 6, PR 39).
+
+        Returns the number of jobs whose entry was rewritten (the
+        span's `patched_jobs`); 0 where the parent never built an index
+        (this base stays lazy too)."""
         with parent._positions_lock:
             base_positions = parent._positions
         if base_positions is None:
-            return  # parent never built one; stay lazy
-        affected = set()
-        for i in rows:
-            for jid, _tg in old_groups[i]:
-                affected.add(jid)
-            for jid, _tg in self.alloc_groups[i]:
-                affected.add(jid)
+            return 0
+        come: Dict[str, Dict[str, List[int]]] = {}  # job -> tg -> rows
+        gone: Dict[str, Dict[str, List[int]]] = {}
+        for a, i in zip(adds, add_rows):
+            come.setdefault(a.job_id, {}).setdefault(
+                a.task_group, []).append(i)
+        for i in refill_rows:
+            moved = Counter(self.alloc_groups[i])
+            moved.subtract(parent.alloc_groups[i])
+            for (jid, tg), k in moved.items():
+                if k:
+                    (come if k > 0 else gone).setdefault(
+                        jid, {}).setdefault(tg, []).extend([i] * abs(k))
         patched = dict(base_positions)
-        rowset = np.asarray(sorted(rows), np.int64)
-        for jid in affected:
-            per = {tg: arr for tg, arr in
-                   (base_positions.get(jid) or {}).items()}
-            # Strip the changed rows' old memberships...
-            for tg in list(per):
-                keep = per[tg][~np.isin(per[tg], rowset)]
-                if keep.size:
-                    per[tg] = keep
-                else:
-                    del per[tg]
-            # ... and add their current ones.
-            adds: Dict[str, List[int]] = {}
-            for i in rows:
-                for jid2, tg in self.alloc_groups[i]:
-                    if jid2 == jid:
-                        adds.setdefault(tg, []).append(i)
-            for tg, idxs in adds.items():
-                prev = per.get(tg)
-                arr = np.asarray(idxs, np.int64)
-                per[tg] = (np.concatenate([prev, arr])
-                           if prev is not None else arr)
-            if per:
-                patched[jid] = per
-            else:
-                patched.pop(jid, None)
+        written = come.keys() | gone.keys()
+        for jid in written:
+            patched[jid] = base_positions.get(jid, _NO_ROWS).edited(
+                come.get(jid, {}), gone.get(jid, {}))
         # Publish under the lock: `self` is freshly built and unshared
         # in the current delta path, but the guarded-by contract on
         # _positions is unconditional — a future caller patching a
         # LIVE base would otherwise race job_positions' lazy build.
         with self._positions_lock:
             self._positions = patched
+        return len(written)
+
+
+# A positions entry folds its pending edits in by itself once they are
+# more than this many plus a quarter of its arrays' length: the chain
+# of edits of a standing job nobody reads stays bounded, at an
+# amortised O(1) a written entry.
+_FOLD_AFTER = 64
+
+
+class _JobRows:
+    """One job's node rows by task group (with repeats), as the arrays
+    some ancestor base held and the edits of the deltas since. A delta
+    writes down the rows that came and went, O(what it wrote), and
+    never reads the arrays; the first reader folds them in (`rows`),
+    and a delta after that starts from the folded arrays. Immutable
+    but for that memo, so bases share entries freely."""
+
+    __slots__ = ("arrays", "edits", "pending", "_folded")
+
+    def __init__(self, arrays: Dict[str, np.ndarray], edits=None,
+                 pending: int = 0):
+        self.arrays = arrays    # shared with the ancestor, never written
+        self.edits = edits      # (come, gone, older edits) or None
+        self.pending = pending  # rows the edits hold
+        self._folded = arrays if edits is None else None
+
+    def edited(self, come: Dict[str, List[int]],
+               gone: Dict[str, List[int]]) -> "_JobRows":
+        """This job one delta on: `come` and `gone` are {tg: rows}."""
+        n = sum(map(len, come.values())) + sum(map(len, gone.values()))
+        folded = self._folded
+        if folded is not None:
+            new = _JobRows(folded, (come, gone, None), n)
+        else:
+            new = _JobRows(self.arrays, (come, gone, self.edits),
+                           self.pending + n)
+        if new.pending > _FOLD_AFTER + sum(
+                map(len, new.arrays.values())) // 4:
+            new.rows()
+        return new
+
+    def rows(self) -> Dict[str, np.ndarray]:
+        folded = self._folded
+        if folded is None:
+            # Two readers may both fold: they get equal arrays, and the
+            # memo is one store.
+            folded = self._folded = _fold(self.arrays, self.edits)
+        return folded
+
+
+_NO_ROWS = _JobRows({})
+
+
+def _fold(arrays: Dict[str, np.ndarray], edits) -> Dict[str, np.ndarray]:
+    """`arrays` with a chain of edits applied, as multisets of rows: a
+    task group left with no row is dropped."""
+    come: Dict[str, List[int]] = {}
+    gone: Dict[str, List[int]] = {}
+    while edits is not None:
+        plus, minus, edits = edits
+        for tg, rows in plus.items():
+            come.setdefault(tg, []).extend(rows)
+        for tg, rows in minus.items():
+            gone.setdefault(tg, []).extend(rows)
+    out = {}
+    for tg in list(arrays) + [tg for tg in come if tg not in arrays]:
+        rows = arrays.get(tg)
+        if tg in come:
+            plus = np.asarray(come[tg], np.int64)
+            rows = plus if rows is None else np.concatenate([rows, plus])
+        if tg in gone:
+            # Every row that went was there: the multiset difference.
+            vals, counts = np.unique(rows, return_counts=True)
+            gone_vals, gone_counts = np.unique(
+                np.asarray(gone[tg], np.int64), return_counts=True)
+            counts[np.searchsorted(vals, gone_vals)] -= gone_counts
+            rows = np.repeat(vals, counts)
+        if rows.size:
+            out[tg] = rows
+    return out
 
 
 def _victim_candidates(allocs, job_id=None, max_priority=None):
@@ -1175,13 +1281,14 @@ class _BaseView:
     device-residency entry points expect (ClusterMatrix's surface) —
     what prefetch_cluster_base hands to PlacementBatcher.prefetch_base."""
 
-    __slots__ = ("base_token", "base_delta", "capacity", "sched_capacity",
-                 "util", "bw_avail", "bw_used", "ports_free", "node_ok",
-                 "class_ids")
+    __slots__ = ("base_token", "base_delta", "delta_stats", "capacity",
+                 "sched_capacity", "util", "bw_avail", "bw_used",
+                 "ports_free", "node_ok", "class_ids")
 
     def __init__(self, base: "_ClusterBase"):
         self.base_token = base.token
         self.base_delta = base.delta_parent
+        self.delta_stats = base.delta_stats
         self.capacity = base.capacity
         self.sched_capacity = base.sched_capacity
         self.util = base.util
@@ -1262,12 +1369,19 @@ class ClusterMatrix:
     def _cached_base(self) -> "_ClusterBase":
         cacheable = (self._plan_overlay or self.plan is None
                      or self.plan.is_no_op())
+        t0 = time.monotonic()
         base, self.build_kind = resolve_cluster_base(
             self.state, self.job.datacenters, nodes=self.nodes,
             explicit=self._explicit_nodes,
             proposed_fn=(None if self._plan_overlay
                          else self._proposed_allocs),
             cacheable=cacheable)
+        # Set where this very build derived the delta (a replan on a
+        # snapshot no prologue prefetched): (t0, t1, annotations) of the
+        # span `base.delta`.
+        self.base_delta_span = (
+            (t0, time.monotonic(), base.delta_stats)
+            if self.build_kind == "delta" else None)
         self.delta_rows = (len(base.delta_parent[1])
                            if self.build_kind == "delta"
                            and base.delta_parent else 0)

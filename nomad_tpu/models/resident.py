@@ -82,6 +82,11 @@ class ResidentStateTracker:
         self.journal_deltas = 0  # guarded-by: _lock
         self.journal_misses = 0  # guarded-by: _lock
         self.journal_allocs = 0  # guarded-by: _lock
+        # Jobs whose entry of the positions index the deltas rewrote
+        # (models/matrix.py _patch_positions). Over delta_updates, the
+        # jobs a delta patched: the jobs of what it wrote, never the
+        # jobs that merely live on the rows it touched.
+        self.positions_patched_jobs = 0  # guarded-by: _lock
         self.stale_rebuilds = 0  # guarded-by: _lock (post-rejection)
         self.universe_rebuilds = 0  # guarded-by: _lock (node set changed)
         # Plan-apply rejection marked the resident chain suspect; the
@@ -137,10 +142,12 @@ class ResidentStateTracker:
         with self._lock:
             self.universe_rebuilds += 1
 
-    def count_delta(self, alloc_rows: int, node_rows: int) -> None:
+    def count_delta(self, alloc_rows: int, node_rows: int,
+                    patched_jobs: int) -> None:
         with self._lock:
             self.delta_updates += 1
             self.alloc_delta_rows += alloc_rows
+            self.positions_patched_jobs += patched_jobs
             if node_rows:
                 self.node_delta_updates += 1
                 self.node_delta_rows += node_rows
@@ -167,6 +174,7 @@ class ResidentStateTracker:
                 "journal_deltas": self.journal_deltas,
                 "journal_misses": self.journal_misses,
                 "journal_allocs": self.journal_allocs,
+                "positions_patched_jobs": self.positions_patched_jobs,
                 "stale_rebuilds": self.stale_rebuilds,
                 "universe_rebuilds": self.universe_rebuilds,
             }

@@ -1140,8 +1140,12 @@ class PlacementBatcher:
           (PERF.md section 6, PR 31): at saturation the batches fall
           out of step, every batch then overlaps its predecessor's
           uncommitted plans AND its successor's, and plan conflicts
-          double. An open cohort is one whose prologue is done: nobody
-          waits on a prefetch.
+          double. An open cohort is a batch whose prologue is done,
+          or the placeholder of one that forms beside a batch in
+          flight and is sure to launch within the pipeline's window
+          (dispatch/pipeline.py _announce_forming; PR 39): without it
+          the two met only while a prologue took as long as that
+          window.
         - "cap": as "cohort", but a request among them waited
           COHORT_WAIT_MAX out for a member that did not come, and its
           cohort was closed without it.

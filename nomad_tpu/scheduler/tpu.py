@@ -764,7 +764,7 @@ class BatchedTPUScheduler(GenericScheduler):
                 self.eval.id, trace.STAGE_MATRIX_UPDATE, t0, t1,
                 ann={"kind": kind, "rows": matrix.delta_rows},
                 trace_id=self.eval.trace_id)
-        _record_feasibility_build(self.eval, matrix)
+        _record_built_spans(self.eval, matrix)
 
     def _note_quality(self, kernel, matrix, ask_res, committed) -> None:
         note_quality(self.logger, self.job, kernel, matrix, ask_res,
@@ -801,14 +801,20 @@ class BatchedTPUScheduler(GenericScheduler):
                     bool(matrix.feasible[i, gi]), name, node.computed_class
                 )
 
-def _record_feasibility_build(ev, matrix) -> None:
-    """The span of the constraint mask, where this eval's matrix really
-    built one (a miss of the mask memo, models/matrix.py
-    _build_feasibility): its samples are the memo's misses."""
-    built = getattr(matrix, "feas_build", None)
-    if built is not None:
-        trace.record_span(ev.id, trace.STAGE_FEASIBILITY_BUILD, built[0],
-                          built[1], ann=built[2], trace_id=ev.trace_id)
+def _record_built_spans(ev, matrix) -> None:
+    """The spans of what this eval's matrix really built itself:
+    `feasibility.build`, the constraint mask (a miss of the mask memo,
+    models/matrix.py _build_feasibility: its samples are the memo's
+    misses), and `base.delta`, the cluster base derived inline from its
+    parent (a replan on a snapshot no prologue prefetched)."""
+    for stage, built in (
+            (trace.STAGE_FEASIBILITY_BUILD,
+             getattr(matrix, "feas_build", None)),
+            (trace.STAGE_BASE_DELTA,
+             getattr(matrix, "base_delta_span", None))):
+        if built is not None:
+            trace.record_span(ev.id, stage, built[0], built[1],
+                              ann=built[2], trace_id=ev.trace_id)
 
 
 def note_quality(logger, job, kernel, matrix, ask_res, committed) -> None:
@@ -995,7 +1001,7 @@ class DenseSystemScheduler(SystemScheduler):
         trace.record_span(self.eval.id, trace.STAGE_MATRIX_BUILD, _t0,
                           ann={"placements": len(place), "pinned": True},
                           trace_id=self.eval.trace_id)
-        _record_feasibility_build(self.eval, matrix)
+        _record_built_spans(self.eval, matrix)
 
         util = matrix.util.copy()
         bw_used = matrix.bw_used.copy()

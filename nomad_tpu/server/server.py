@@ -305,7 +305,8 @@ class Server:
                         ("device_state", "upload_bytes"),
                         ds["upload_bytes"])
                     for key in ("journal_deltas", "journal_misses",
-                                "journal_allocs"):
+                                "journal_allocs",
+                                "positions_patched_jobs"):
                         metrics.set_gauge(("device_state", key), ds[key])
                     # Placement-quality gauges (kernels/quality.py):
                     # the active kernel's committed-plan medians plus

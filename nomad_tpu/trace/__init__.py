@@ -25,6 +25,7 @@ from .span import (  # noqa: F401
     SELF_SUFFIX,
     STAGE_ALLOC_UPSERT,
     STAGE_API_REGISTER,
+    STAGE_BASE_DELTA,
     STAGE_BROKER_WAIT,
     STAGE_DEFRAG_SOLVE,
     STAGE_DEVICE_DISPATCH,

@@ -43,6 +43,12 @@ STAGE_FEASIBILITY_BUILD = "feasibility.build"  # inside matrix.build:
 #   groups, constraints, escaped). Recorded only on a miss of the
 #   mask memo, so its sample count is the memo's misses
 STAGE_DEVICE_TRANSFER = "device.transfer"  # base prefetch host->device
+STAGE_BASE_DELTA = "base.delta"            # the HOST half of that
+#   prefetch, or of an inline replan's matrix.update: a cluster base
+#   really derived from its parent by delta (models/matrix.py
+#   delta_update; ann: rows, adds, refills, patched_jobs). One sample a
+#   derived delta (on the batch's first eval, or the replanning one):
+#   never on a hit, a rekey or a full build
 STAGE_DEVICE_DISPATCH = "device.dispatch"  # batcher.place round-trip
 STAGE_DEVICE_SOLVE = "device.solve"        # the jitted placement-kernel
 #   solve inside the dispatch (issue + device sync, kernel-annotated) —
@@ -118,6 +124,7 @@ ALL_STAGES = (
     STAGE_MATRIX_COMPRESS,
     STAGE_FEASIBILITY_BUILD,
     STAGE_DEVICE_TRANSFER,
+    STAGE_BASE_DELTA,
     STAGE_DEVICE_DISPATCH,
     STAGE_DEVICE_SOLVE,
     STAGE_MIGRATE_PLACE,

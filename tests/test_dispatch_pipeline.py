@@ -976,3 +976,105 @@ def test_an_announced_eval_that_never_places_settles_its_unit(
         breaker.reset()
         breaker.configure_defaults()
         server.shutdown()
+
+
+# ---------------------------------------------------------------------
+# a batch that forms beside one in flight is announced to the batcher,
+# so that the in-flight batch's dispatch waits for it: the two go
+# together whatever the prologue's length
+
+
+@pytest.mark.parametrize("in_flight, announced", [
+    (0, False),   # nobody to go with: the idle grace, no placeholder
+    (1, True),    # a slot is free: announced while the window runs
+    (2, True),    # every slot busy: announced when it takes its slot
+])
+def test_a_batch_forming_beside_one_in_flight_is_announced(
+        in_flight, announced):
+    import threading
+
+    from nomad_tpu.dispatch import DispatchPipeline
+    from nomad_tpu.dispatch.pipeline import _Pending
+    from nomad_tpu.scheduler.batcher import get_batcher
+
+    server = make_server(num_schedulers=0)
+    try:
+        pipe = DispatchPipeline(server)  # not started: we drive it
+        assert pipe.max_inflight == 2
+        batcher = get_batcher()
+        opened = batcher.stats()["open_cohorts"]
+        with pipe._cond:
+            pipe._inflight = in_flight
+        got = []
+        t = threading.Thread(
+            target=lambda: got.append(pipe._accumulate()), daemon=True)
+        pipe._admit(_Pending(mock.eval(), "tok-0"))
+        t.start()
+        if in_flight == 1:
+            # inside the window, before the batch is cut
+            assert wait_until(lambda: pipe._forming is not None, 2.0)
+            assert not got
+        elif in_flight == 2:
+            time.sleep(0.2)
+            assert not got and pipe._forming is None
+            with pipe._cond:
+                pipe._inflight = 1  # one of the two finished
+                pipe._cond.notify_all()
+        t.join(timeout=5.0)
+        assert got and len(got[0]) == 1
+        forming = pipe._forming
+        assert (forming is not None) == announced
+        assert batcher.stats()["open_cohorts"] == opened + announced
+
+        # Whatever way the launch ends, the placeholder is settled: a
+        # prologue that fails aborts the batch and must leave no cohort
+        # for the cap to release.
+        def boom(batch, forming=None):
+            raise RuntimeError("prologue")
+
+        pipe._launch_prologue = boom
+        pipe._launch(got[0], forming)
+        assert batcher.stats()["open_cohorts"] == opened
+        assert pipe.stats()["in_flight"] == in_flight - (in_flight == 2)
+    finally:
+        server.shutdown()
+
+
+@pytest.mark.parametrize("lagging", [True, False])
+def test_nobody_waits_a_lagging_index_out_on_the_placeholder(lagging):
+    """The placeholder covers the forming batch's window and its base
+    prefetch, never _wait_for_index: where the FSM is behind the
+    batch's index (a follower that lags its leader), the prologue
+    settles it before it starts to wait, so the batch in flight goes
+    alone instead of sitting out COHORT_WAIT_MAX."""
+    from nomad_tpu.dispatch import DispatchPipeline
+    from nomad_tpu.dispatch.pipeline import _Pending
+    from nomad_tpu.scheduler.batcher import get_batcher
+
+    server = make_server(num_schedulers=0)
+    try:
+        pipe = DispatchPipeline(server)  # not started: we drive it
+        batcher = get_batcher()
+        opened = batcher.stats()["open_cohorts"]
+        pipe._announce_forming()
+        forming = pipe._forming
+        assert batcher.stats()["open_cohorts"] == opened + 1
+        ev = mock.eval()
+        ev.modify_index = server.fsm.state.latest_index() + (
+            5 if lagging else 0)
+        seen = []
+
+        def never(index, timeout):
+            seen.append((index, forming.open,
+                         batcher.stats()["open_cohorts"]))
+            return False  # the FSM never caught up: the batch aborts
+
+        pipe._wait_for_index = never
+        assert pipe._launch_prologue(
+            [_Pending(ev, "tok-0")], forming) is None
+        assert seen == [(ev.modify_index, not lagging,
+                         opened + (not lagging))]
+        forming.settle()  # what _launch's finally does
+        assert batcher.stats()["open_cohorts"] == opened
+    finally:
+        server.shutdown()

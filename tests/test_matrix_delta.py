@@ -4,6 +4,8 @@ table recomputes touched node rows instead of a full O(N x allocs)
 rebuild, and the delta result must be bit-identical to a fresh build —
 the live pipeline's per-apply snapshot churn rides this path."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -53,6 +55,13 @@ def assert_bases_equal(a, b):
         np.testing.assert_array_equal(
             getattr(a, f), getattr(b, f), err_msg=f)
     assert a.alloc_groups == b.alloc_groups
+
+
+def positions_of(base, jid):
+    """One job's rows by task group, as sorted lists: the order of a
+    task group's rows is no part of job_positions' contract."""
+    return {tg: sorted(rows.tolist())
+            for tg, rows in base.job_positions(jid).items()}
 
 
 def test_delta_update_matches_full_rebuild(cluster):
@@ -126,8 +135,8 @@ def test_many_changed_rows_falls_back_to_full_rebuild(cluster):
 
 def test_delta_patches_positions_index(cluster):
     """A delta base carries the parent's job-positions index forward,
-    patching only jobs in the changed rows; the result must equal a
-    from-scratch index (same multiset of rows per job/task-group)."""
+    writing only the jobs of what the delta wrote; the result must equal
+    a from-scratch index (same multiset of rows per job/task-group)."""
     store, job, nodes, allocs, index = cluster
     m1 = ClusterMatrix(store.snapshot(), job)
     parent = m1._cached_base()
@@ -153,11 +162,7 @@ def test_delta_patches_positions_index(cluster):
     oracle = _ClusterBase(
         m2.nodes, lambda nid: snap.allocs_by_node_terminal(nid, False))
     for jid in (job.id, other.id, "no-such-job"):
-        got = {tg: sorted(arr.tolist())
-               for tg, arr in base2.job_positions(jid).items()}
-        want = {tg: sorted(arr.tolist())
-                for tg, arr in oracle.job_positions(jid).items()}
-        assert got == want, jid
+        assert positions_of(base2, jid) == positions_of(oracle, jid), jid
 
 
 def test_gc_deletion_forces_full_rebuild(cluster):
@@ -370,11 +375,7 @@ def assert_same_base(base, snap, nodes):
         == [sorted(g) for g in oracle.alloc_groups]
     jobs = {jid for g in oracle.alloc_groups for jid, _tg in g}
     for jid in jobs | {"no-such-job"}:
-        got = {tg: sorted(rows.tolist())
-               for tg, rows in base.job_positions(jid).items()}
-        want = {tg: sorted(rows.tolist())
-                for tg, rows in oracle.job_positions(jid).items()}
-        assert got == want, jid
+        assert positions_of(base, jid) == positions_of(oracle, jid), jid
     if base._victims is not None:
         want = _VictimTable.build(base.n, nodes, snap)
         for f in ("res", "bw", "ports", "prio", "ok"):
@@ -539,3 +540,294 @@ def test_a_state_without_a_journal_gets_a_full_build(cluster):
     m = ClusterMatrix(Bare(snap), job)
     assert m.build_kind == "full"
     assert_same_base(m._base, snap, m.nodes)
+
+
+# ---------------------------------------------------------------------
+# The positions index is carried at a cost of what the delta wrote
+# (_patch_positions, _JobRows): an added allocation touches its own job
+# only, a refilled row the (job, task group) pairs whose count differs.
+# ---------------------------------------------------------------------
+
+
+def chain_job(i, groups=("web",)):
+    job = mock.job()
+    job.id = f"chain-{i}"
+    job.task_groups[0].tasks[0].resources.networks = []
+    job.task_groups[0].name = groups[0]
+    for name in groups[1:]:
+        tg = job.task_groups[0].copy()
+        tg.name = name
+        job.task_groups.append(tg)
+    return job
+
+
+def group_alloc(node, job, group, cpu=20):
+    alloc = make_alloc(node, job, cpu=cpu)
+    alloc.task_group = group
+    return alloc
+
+
+@pytest.mark.parametrize("fold_after", [64, 0])
+@pytest.mark.parametrize("seed", range(3900, 3908))
+def test_positions_of_every_job_equal_a_fresh_build_along_random_chains(
+        seed, fold_after, monkeypatch):
+    """Chains of deltas that mix additive rows, refilled rows, evictions,
+    a node flip and replans of jobs with live allocations, some jobs
+    read along the way and most never: after every link job_positions of
+    EVERY job the chain has seen (those left with nothing too) is the
+    fresh build's. With `_FOLD_AFTER` 0 every edit folds at once; with
+    64 the edits of an unread job pile up across links."""
+    import random
+
+    from nomad_tpu.models import matrix as matrix_mod
+
+    monkeypatch.setattr(matrix_mod, "_FOLD_AFTER", fold_after)
+    rng = random.Random(seed)
+    store = StateStore()
+    nodes = []
+    index = 0
+    for _ in range(24):
+        node = mock.node()
+        node.compute_class()
+        nodes.append(node)
+        index += 1
+        store.upsert_node(index, node)
+    standing = chain_job("standing")
+    jobs = [standing] + [chain_job(i, ("web", "db")) for i in range(6)]
+    # the standing job holds several allocations a row: repeats
+    live = [group_alloc(node, standing, "web") for node in nodes
+            for _ in range(3)]
+    live += [group_alloc(rng.choice(nodes), job, rng.choice(("web", "db")))
+             for job in jobs[1:] for _ in range(4)]
+    index += 1
+    store.upsert_allocs(index, live)
+    first = ClusterMatrix(store.snapshot(), standing)
+    first._base.job_positions(standing.id)   # the chain carries the index
+    seen = {job.id for job in jobs}
+    kinds = []
+    ops = ("adds", "adds", "replan", "stop", "evict", "update", "flip",
+           "mixed")
+    for step in range(30):
+        op = rng.choice(ops)
+        index += 1
+        if op == "adds":
+            # a window's arrivals: new jobs stacked on a few rows
+            fresh = []
+            for _ in range(rng.randrange(1, 4)):
+                job = chain_job(f"{seed}-{step}-{len(jobs)}", ("web", "db"))
+                jobs.append(job)
+                seen.add(job.id)
+                row = rng.randrange(len(nodes))
+                fresh += [group_alloc(nodes[(row + k) % 3], job,
+                                      rng.choice(("web", "db")))
+                          for k in range(rng.randrange(1, 5))]
+            live += fresh
+            store.upsert_allocs(index, fresh)
+        elif op == "replan":
+            # a job with live allocations is read, loses one and gains
+            job = rng.choice([j for j in jobs if j is not standing])
+            ClusterMatrix(store.snapshot(), job)
+            mine = [a for a in live if a.job_id == job.id]
+            writes = [group_alloc(rng.choice(nodes), job, "web")
+                      for _ in range(2)]
+            live += writes
+            if mine:
+                lost = rng.choice(mine)
+                lost.desired_status = consts.ALLOC_DESIRED_STOP
+                live.remove(lost)
+                writes.append(lost)
+            store.upsert_allocs(index, writes)
+        elif op in ("stop", "evict"):
+            a = live.pop(rng.randrange(len(live)))
+            a.desired_status = (consts.ALLOC_DESIRED_STOP if op == "stop"
+                                else consts.ALLOC_DESIRED_EVICT)
+            store.upsert_allocs(index, [a])
+        elif op == "update":
+            a = rng.choice(live)
+            for tr in a.task_resources.values():
+                tr.cpu = 10 + rng.randrange(30)
+            a.__dict__.pop("_dense_usage", None)
+            store.upsert_allocs(index, [a])
+        elif op == "flip":
+            node = rng.choice(nodes)
+            node.drain = not node.drain
+            store.upsert_node(index, node)
+        else:
+            # one commit that evicts from the standing job and places a
+            # new job on the same row and on another
+            victim = next(a for a in live if a.job_id == standing.id)
+            victim.desired_status = consts.ALLOC_DESIRED_EVICT
+            live.remove(victim)
+            job = chain_job(f"{seed}-{step}-m")
+            jobs.append(job)
+            seen.add(job.id)
+            placed = [make_alloc(n, job, cpu=15) for n in (
+                next(n for n in nodes if n.id == victim.node_id),
+                rng.choice(nodes))]
+            live += placed
+            store.upsert_allocs(index, [victim] + placed)
+        snap = store.snapshot()
+        m = ClusterMatrix(snap, rng.choice(jobs))
+        kinds.append(m.build_kind)
+        oracle = _ClusterBase(
+            m.nodes, lambda nid: snap.allocs_by_node_terminal(nid, False))
+        for jid in sorted(seen):
+            assert positions_of(m._base, jid) == positions_of(oracle, jid), \
+                (step, op, jid)
+        if m.build_kind == "full":
+            m._base.job_positions(standing.id)
+    assert kinds.count("delta") >= 20, kinds
+
+
+def crowded_rows():
+    """Four rows of a 32-node cell that hold 200 other jobs each, and a
+    standing job of 50,000 entries over the cell: what a window's end
+    looks like to a delta."""
+    import copy
+
+    store = StateStore()
+    nodes = []
+    index = 0
+    for _ in range(32):
+        node = mock.node()
+        node.compute_class()
+        nodes.append(node)
+        index += 1
+        store.upsert_node(index, node)
+    standing = chain_job("standing")
+    template = make_alloc(nodes[0], standing, cpu=0, mem=0)
+    allocs = []
+    for k in range(50_000):
+        a = copy.copy(template)
+        a.id = f"standing-{k}"
+        a.node_id = nodes[k % 32].id
+        allocs.append(a)
+    others = [chain_job(f"other-{i}") for i in range(200)]
+    for job in others:
+        allocs += [make_alloc(nodes[row], job, cpu=1, mem=1)
+                   for row in range(4)]
+    index += 1
+    store.upsert_allocs(index, allocs)
+    parent = ClusterMatrix(store.snapshot(), standing)._base
+    parent.job_positions(standing.id)
+    return store, nodes, standing, others, allocs, index, parent
+
+
+def test_an_adds_only_delta_rewrites_the_jobs_of_its_adds_and_no_other():
+    """The cost guard, by counting work and not time: `patched_jobs` is
+    the distinct jobs among the adds, and the entry of every job that
+    merely lives on the touched rows (the standing job's 50,000 entries
+    first) is the parent's object."""
+    store, nodes, standing, others, _allocs, index, parent = crowded_rows()
+    arrivals = [chain_job(f"arrival-{i}", ("web", "db")) for i in range(3)]
+    adds = [group_alloc(nodes[row], job, group, cpu=1)
+            for job in arrivals for row in range(4)
+            for group in ("web", "db")]
+    index += 1
+    store.upsert_allocs(index, adds)
+    snap = store.snapshot()
+    m = ClusterMatrix(snap, arrivals[0])
+    base = m._base
+    assert m.build_kind == "delta"
+    assert base.delta_stats == {"rows": 4, "adds": 24, "refills": 0,
+                                "patched_jobs": 3}
+    assert base.job_positions(standing.id)["web"] \
+        is parent.job_positions(standing.id)["web"]
+    for job in [standing] + others:
+        assert base._positions[job.id] \
+            is parent._positions[job.id], job.id
+    for job in arrivals:
+        assert positions_of(base, job.id) == {
+            "web": [0, 1, 2, 3], "db": [0, 1, 2, 3]}
+    assert len(base.job_positions(standing.id)["web"]) == 50_000
+
+
+def test_a_refill_writes_the_pairs_that_differ_and_scans_no_array():
+    """A standing job that loses one row of 50,000: one edit on its
+    entry, over the parent's arrays untouched; the 200 other jobs of
+    the refilled row keep the parent's entries; a reader folds the edit
+    in and gets the fresh build's rows."""
+    store, nodes, standing, others, allocs, index, parent = crowded_rows()
+    victim = allocs[1]            # the standing job's, on row 1
+    victim.desired_status = consts.ALLOC_DESIRED_EVICT
+    arrival = chain_job("arrival")
+    index += 1
+    store.upsert_allocs(index, [victim, make_alloc(nodes[1], arrival)])
+    snap = store.snapshot()
+    m = ClusterMatrix(snap, arrival)
+    base = m._base
+    assert m.build_kind == "delta"
+    assert base.delta_stats == {"rows": 1, "adds": 0, "refills": 1,
+                                "patched_jobs": 2}
+    entry = base._positions[standing.id]
+    assert entry.arrays is parent._positions[standing.id].arrays
+    assert entry.pending == 1 and entry._folded is None
+    for job in others:
+        assert base._positions[job.id] \
+            is parent._positions[job.id], job.id
+    oracle = _ClusterBase(
+        m.nodes, lambda nid: snap.allocs_by_node_terminal(nid, False))
+    assert positions_of(base, standing.id) == positions_of(oracle, standing.id)
+    assert len(base.job_positions(standing.id)["web"]) == 49_999
+    assert positions_of(base, arrival.id) == {"web": [1]}
+
+
+def test_a_chain_of_deltas_shares_the_entries_it_did_not_write(cluster):
+    """Down a chain of deltas the index stays one flat dict of every
+    job; an entry no delta wrote is the first base's object still."""
+    store, job, nodes, _allocs, index = cluster
+    many = [chain_job(f"resident-{i}") for i in range(64)]
+    index += 1
+    store.upsert_allocs(index, [make_alloc(nodes[i % 16], j, cpu=1)
+                                for i, j in enumerate(many)])
+    parent = ClusterMatrix(store.snapshot(), job)._base
+    parent.job_positions(job.id)
+    first = dict(parent._positions)
+    assert len(first) == 65
+    for step in range(6):
+        index += 1
+        store.upsert_allocs(index, [make_alloc(
+            nodes[step], chain_job(f"new-{step}"), cpu=1)])
+        base = ClusterMatrix(store.snapshot(), job)._base
+        assert base.delta_stats["patched_jobs"] == 1
+        assert len(base._positions) == 65 + step + 1
+        assert all(base._positions[jid] is entry
+                   for jid, entry in first.items())
+    assert parent._positions == first
+    assert positions_of(base, "chain-new-3") == {"web": [3]}
+    assert positions_of(base, many[5].id) == {"web": [5]}
+
+
+@pytest.mark.parametrize("kind", ["full", "hit", "rekey", "delta"])
+def test_the_span_of_a_base_delta_is_carried_only_by_a_derived_delta(
+        cluster, kind):
+    """ClusterMatrix.base_delta_span (what the scheduler records as the
+    span `base.delta` for an inline replan) is set where this very
+    build derived the delta, with the four annotations, and on no other
+    kind of build."""
+    store, job, nodes, _allocs, index = cluster
+    if kind != "full":
+        ClusterMatrix(store.snapshot(), job)
+    if kind == "delta":
+        index += 1
+        store.upsert_allocs(index, [make_alloc(nodes[2], job)])
+    elif kind == "rekey":
+        far = mock.node()
+        far.datacenter = "dc-elsewhere"
+        far.compute_class()
+        index += 1
+        store.upsert_node(index, far)
+        ClusterMatrix(store.snapshot(), job)
+        index += 1
+        store.upsert_allocs(index, [make_alloc(far, chain_job("far"))])
+    t0 = time.monotonic()
+    m = ClusterMatrix(store.snapshot(), job)
+    t1 = time.monotonic()
+    assert m.build_kind == kind
+    if kind != "delta":
+        assert m.base_delta_span is None
+        return
+    start, end, ann = m.base_delta_span
+    assert t0 <= start <= end <= t1
+    assert ann == {"rows": 1, "adds": 1, "refills": 0, "patched_jobs": 1}
+    assert ann is m._base.delta_stats

@@ -266,7 +266,8 @@ def test_device_state_stats_surface():
                 "node_delta_updates", "stale_rebuilds",
                 "universe_rebuilds", "jit_cache_size", "base_uploads",
                 "base_delta_updates", "upload_bytes", "journal_deltas",
-                "journal_misses", "journal_allocs"):
+                "journal_misses", "journal_allocs",
+                "positions_patched_jobs"):
         assert key in st, key
     assert st["enabled"] is True
 
@@ -397,6 +398,10 @@ def test_stale_delta_forces_rebuild_not_wrong_placement(resident_on):
         m2 = ClusterMatrix(snap, job)
         fired = chaos.firing_log()
     assert fired, "the stale-delta site never fired"
+    # The span's annotation counts what was written, not what was lost.
+    assert m2._base.delta_stats == {"rows": 0, "adds": 0, "refills": 0,
+                                    "patched_jobs": 0}
+    assert m2.delta_rows == 1  # the lost row is shipped un-recomputed
 
     # The resident state is now WRONG: the 800-cpu commit is invisible.
     oracle = _ClusterBase(

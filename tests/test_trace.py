@@ -374,6 +374,113 @@ def test_e2e_span_tree_per_eval_dense_pipeline(fresh_recorder):
         server.shutdown()
 
 
+def test_base_delta_span_once_per_derived_delta(fresh_recorder):
+    """`base.delta` is the host half of a base prefetch (or of an inline
+    replan's matrix.update) that really derived a delta: one sample a
+    derived delta, with its four annotations, inside a `device.transfer`
+    or `matrix.update` of kind "delta", and none on a hit, a rekey or a
+    full build. `device_state.positions_patched_jobs` counts the jobs
+    whose index entry the deltas rewrote: with pure creations, at most
+    the allocations they added."""
+    from nomad_tpu.models.resident import get_tracker
+    from nomad_tpu.trace import STAGE_BASE_DELTA
+
+    server = make_server()
+    try:
+        seed_nodes(server, 8)
+        before = get_tracker().stats()
+        eval_ids = run_dense_storm(server, n_jobs=4)   # the full build
+        eval_ids += run_dense_storm(server, n_jobs=4)  # a delta over it
+        rec = fresh_recorder
+        assert wait_until(lambda: all(
+            rec.trace_for(eid) is not None for eid in eval_ids), 10.0)
+        after = server.stats()["device_state"]
+        derived = after["delta_updates"] - before["delta_updates"]
+        assert derived >= 1
+        assert rec.stage_stats()[STAGE_BASE_DELTA]["count"] == derived
+        spans = 0
+        adds = patched = 0
+        for eid in eval_ids:
+            tr = rec.trace_for(eid)
+            for s in tr["spans"]:
+                if s["name"] != STAGE_BASE_DELTA:
+                    continue
+                spans += 1
+                ann = s["annotations"]
+                assert set(ann) == {"rows", "adds", "refills",
+                                    "patched_jobs"}
+                assert 1 <= ann["rows"] <= ann["adds"] + ann["refills"]
+                assert ann["patched_jobs"] <= ann["adds"] + ann["refills"]
+                adds += ann["adds"]
+                patched += ann["patched_jobs"]
+                around = [o for o in tr["spans"]
+                          if o["name"] in ("device.transfer",
+                                           "matrix.update")
+                          and o["annotations"]["kind"] == "delta"
+                          and o["start_ms"] <= s["start_ms"]
+                          and s["end_ms"] <= o["end_ms"] + 0.01]
+                assert around, tr["spans"]
+        assert spans == derived
+        assert after["positions_patched_jobs"] \
+            - before["positions_patched_jobs"] == patched
+        assert 1 <= patched <= adds
+    finally:
+        server.shutdown()
+
+
+def test_base_delta_metric_file_reads_the_span(fresh_recorder, monkeypatch):
+    """`benchmark/metrics/base_delta_p50_ms.json` is the entry
+    BENCHMARK.json lists for every cell, names a stage of the program,
+    and its reader takes a median from the recorder's histogram over a
+    window, and nothing from a program that never recorded the stage
+    (the parent's: the line then leaves the metric out)."""
+    import importlib.util
+    import json
+    import os
+    import sys
+
+    from nomad_tpu.trace import ALL_STAGES, STAGE_BASE_DELTA
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    spec = json.load(open(os.path.join(
+        root, "benchmark", "metrics", "base_delta_p50_ms.json")))
+    entry = bench["per_layer"][-1]
+    assert entry == {key: spec[key] for key in (
+        "name", "unit", "better", "source", "layer", "moves")} | {
+        "workloads": [w["name"] for w in bench["workloads"]]}
+    assert spec["args"] == {"stage": STAGE_BASE_DELTA, "q": 0.5}
+    assert STAGE_BASE_DELTA in ALL_STAGES
+
+    def load(name, path):
+        module_spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(module_spec)
+        module_spec.loader.exec_module(module)
+        return module
+
+    monkeypatch.setitem(sys.modules, "stats", load(
+        "stats", os.path.join(root, "benchmark", "stats.py")))
+    reader = load("benchmark_reader_span", os.path.join(
+        root, "benchmark", "readers", f"{spec['reader']}.py"))
+    rec = fresh_recorder
+
+    def snapshot():
+        return {stage: rec.stage_buckets(stage)
+                for stage in rec.stage_stats()}
+
+    before = snapshot()
+    assert reader.read(spec["args"], {
+        "spans_before": before, "spans_after": before}) is None
+    now = time.monotonic()
+    for ms in (1.0, 2.0, 30.0):
+        rec.record_span("ev-1", STAGE_BASE_DELTA, now, now + ms / 1000.0,
+                        ann={"rows": 4, "adds": 8, "refills": 0,
+                             "patched_jobs": 1})
+    value = reader.read(spec["args"], {
+        "spans_before": before, "spans_after": snapshot()})
+    assert 1.5 < value < 2.7
+
+
 def test_chaos_fault_annotation_lands_on_covering_span(fresh_recorder):
     """An armed chaos fault that fires inside a stage must show up as a
     (site, ordinal) annotation ON the span covering that stage."""
