@@ -30,6 +30,15 @@ from ..utils.codec import from_dict, to_dict
 MAX_BLOCKING_WAIT = 300.0  # rpc.go:34
 DEFAULT_BLOCKING_WAIT = 300.0
 
+# The two route families on every client's path, whose requests feed
+# the recorder's `http.<family>.*` rows (trace/span.py HTTP_STAGES):
+# (route tag, method) -> family. Other routes record nothing.
+_HTTP_FAMILY = {
+    ("jobs", "PUT"): "register", ("jobs", "POST"): "register",
+    ("job", "PUT"): "register", ("job", "POST"): "register",
+    ("evaluation", "GET"): "eval",
+}
+
 
 class HTTPError(Exception):
     def __init__(self, status: int, message: str):
@@ -212,6 +221,15 @@ class HTTPServer:
             def log_message(self, fmt, *args):
                 pass
 
+            def parse_request(self):
+                # handle_one_request calls this right after
+                # rfile.readline returned the request line: the first
+                # moment the handler thread has the request in its
+                # hands. `http.<family>.request` and `.cpu` start here.
+                self._nomad_line_at = time.monotonic()
+                self._nomad_cpu_at = time.thread_time()
+                return super().parse_request()
+
             def _dispatch(self):
                 _start = time.monotonic()
                 # Set by api.handle when a route matches; a single
@@ -220,8 +238,13 @@ class HTTPServer:
                 # histogram percentiles only mean something per
                 # (method, route).
                 self.nomad_route = "unmatched"
+                # Set by api.handle too, for a route of _HTTP_FAMILY:
+                # the family, and when its handler was entered.
+                self.nomad_family = None
+                returned = None
                 try:
                     body = api.handle(self)
+                    returned = time.monotonic()
                 except _ParkSignal as sig:
                     # The blocking query wants to park: hand the
                     # continuation to the read mux and detach the
@@ -259,6 +282,29 @@ class HTTPServer:
                 metrics.measure_since(
                     ("http", "request", self.command, self.nomad_route),
                     _start)
+                if self.nomad_family is not None:
+                    self._observe_request(returned)
+
+            def _observe_request(self, returned):
+                """The request's rows of the stage table, in one
+                recorder call (one stripe, one critical section).
+                `returned` is when the route's handler returned its
+                body, None where it raised (an error reply, or a park:
+                the request then ends at the hand-over to the mux and
+                has no reply row)."""
+                front, reply, request, cpu = trace.HTTP_STAGES[
+                    self.nomad_family]
+                line_at = self._nomad_line_at
+                end = time.monotonic()
+                rows = [
+                    (front, (self.nomad_entered_at - line_at) * 1000.0),
+                    (request, (end - line_at) * 1000.0),
+                    (cpu, (time.thread_time() - self._nomad_cpu_at)
+                     * 1000.0),
+                ]
+                if returned is not None:
+                    rows.append((reply, (end - returned) * 1000.0))
+                trace.get_recorder().observe_stages(rows)
 
             def _reply_body(self, body):
                 """200 reply with the right X-Nomad-Index: a
@@ -510,6 +556,10 @@ class HTTPServer:
                         # snapshot exists to serve from.
                         query["stale"] = ["true"]
                         degraded = True
+                family = _HTTP_FAMILY.get((req.nomad_route, method))
+                if family is not None:
+                    req.nomad_family = family
+                    req.nomad_entered_at = time.monotonic()
                 result = handler(method, query, body, **m.groupdict())
                 if degraded:
                     if not isinstance(result, JSONResponse):
@@ -622,7 +672,7 @@ class HTTPServer:
         keepalive = not handler.close_connection
         scopes = list(sig.items)
 
-        def serve(reason: str) -> None:
+        def serve(reason: str) -> float:
             try:
                 payload, status = sig.run(), 200
             except HTTPError as e:
@@ -678,13 +728,17 @@ class HTTPServer:
             except BaseException:
                 close_conn()
                 raise
+            # Handed back to the mux: `read.deliver` ends here, so the
+            # connection's hand-back below is in `read.serve` only.
+            written = time.monotonic()
             if keep:
                 try:
                     self._resume_connection(raw, conn, client_address)
-                    return
+                    return written
                 except Exception:  # noqa: BLE001
                     pass  # server torn down mid-serve: fall through
             close_conn()
+            return written
 
         parked = server.read_mux.park(
             scopes, sig.min_index, sig.deadline, serve)

@@ -12,7 +12,10 @@ from a percentile gap, becomes a histogram, not a guess.
 The sampler owns its histogram (single writer — the sampler thread;
 readers snapshot monotonic counters, benign mid-update reads). The
 sample loop is the only place in the profiler allowed to sleep; it is
-NOT on the record-path manifest.
+NOT on the record-path manifest. Each overshoot also goes to the flight
+recorder's stage table as ``runtime.gil_wait`` (one ``observe_stage`` a
+sample), which is where the benchmark reads it (``gil_wait_p50_ms``,
+``gil_wait_p95_ms``): with the observatory off the row has no samples.
 
 Complementing the sampler, per-worker *run-queue delay* is stamped at
 the two points where ready work waits for a thread to actually run
@@ -64,6 +67,11 @@ class GilSampler:
         return t is not None and t.is_alive()
 
     def _run(self) -> None:
+        # Here and not at the top: trace/recorder.py imports this
+        # package for its locks.
+        from ..trace import STAGE_RUNTIME_GIL_WAIT, get_recorder
+
+        observe_stage = get_recorder().observe_stage
         stop = self._stop
         while True:
             # Re-read per tick: configure(sampler_interval=...) on a
@@ -78,6 +86,7 @@ class GilSampler:
                 overshoot_ms = 0.0  # clock granularity can undershoot
             self.hist.observe(overshoot_ms)
             self.samples += 1
+            observe_stage(STAGE_RUNTIME_GIL_WAIT, overshoot_ms)
 
     def stats(self) -> dict:
         out = self.hist.stats()

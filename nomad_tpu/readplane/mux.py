@@ -28,7 +28,12 @@ read side:
 Counters (parked/wakes/spurious/served/timeouts/write_errors) surface
 as ``readplane.*`` gauges in /v1/metrics, and park→wake / serve
 durations land in the flight recorder's stage table as ``read.park`` /
-``read.serve``.
+``read.serve``. A woken query's way from the commit to the client's
+socket is three rows more (trace/README.md, "The client's path"):
+``read.notify_lag`` (a commit's notify to the wake loop taking that
+batch), ``read.serve_wait`` (hand-off to a pool worker starting) and
+``read.deliver`` (the notify to the response written); a timeout or
+the shutdown flush feeds none of the three.
 """
 
 from __future__ import annotations
@@ -39,7 +44,15 @@ import time
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ..profile import ProfiledCondition, ProfiledLock
-from ..trace import get_recorder
+from ..trace import (
+    STAGE_READ_DELIVER,
+    STAGE_READ_NOTIFY_LAG,
+    STAGE_READ_PARK,
+    STAGE_READ_SERVE,
+    STAGE_READ_SERVE_CPU,
+    STAGE_READ_SERVE_WAIT,
+    get_recorder,
+)
 from ..utils import metrics
 from ..utils.pool import WorkPool
 
@@ -67,7 +80,7 @@ class ParkedQuery:
                  "claimed", "seq")
 
     def __init__(self, scopes: List[Item], min_index: int, deadline: float,
-                 serve: Callable[[str], None], seq: int = 0):
+                 serve: Callable[[str], Optional[float]], seq: int = 0):
         self.scopes = list(scopes)
         self.min_index = min_index
         self.deadline = deadline
@@ -103,9 +116,9 @@ class ReadMux:
         self._pool = WorkPool(max(1, workers), name="read-serve")
         self._lock = ProfiledLock("readplane.mux")
         self._cond = ProfiledCondition(self._lock)
-        # (seq, items) notify batches awaiting the wake owner, plus the
-        # next batch number; guarded-by: _lock
-        self._pending: List[Tuple[int, List[Item]]] = []
+        # (seq, items, t_notify) notify batches awaiting the wake
+        # owner, plus the next batch number; guarded-by: _lock
+        self._pending: List[Tuple[int, List[Item], float]] = []
         self._seq = 0
         self._next_deadline: Optional[float] = None  # guarded-by: _lock
         self._stop = threading.Event()
@@ -146,9 +159,12 @@ class ReadMux:
     # ------------------------------------------------------- park side
 
     def park(self, scopes: List[Item], min_index: int, deadline: float,
-             serve: Callable[[str], None]) -> bool:
+             serve: Callable[[str], Optional[float]]) -> bool:
         """Register a continuation. Returns False (caller must fall
-        back to thread-parking) when the mux is stopped or full."""
+        back to thread-parking) when the mux is stopped or full.
+        `serve(reason)` may hand back the time.monotonic() at which its
+        response was written: `read.deliver` ends there, and at the
+        thunk's return where it hands back nothing."""
         if self._thread is None:
             return False
         with self._cond:
@@ -174,7 +190,8 @@ class ReadMux:
         if store is not None and store.scope_index(rec.scopes) > min_index:
             if self._claim(rec):
                 self._retire(rec)
-                self._submit_serve(rec, "wake")
+                # Nobody notified: delivery is timed from the park.
+                self._submit_serve(rec, "wake", rec.parked_at)
         return True
 
     def _stripe(self, scope: Item) -> _Stripe:
@@ -222,7 +239,7 @@ class ReadMux:
         only queues and signals — the scope checks happen on the wake
         owner."""
         with self._cond:
-            self._pending.append((self._seq, items))
+            self._pending.append((self._seq, items, time.monotonic()))
             self._seq += 1
             self._cond.notify()
 
@@ -243,17 +260,27 @@ class ReadMux:
             metrics.set_gauge(("readplane", "parked"), parked)
             if store is None:
                 continue
-            woken: Dict[Item, int] = {}
-            for seq, items in batch:
+            # scope item -> the (seq, t_notify) of the batches naming
+            # it, oldest first (batches are queued in seq order)
+            woken: Dict[Item, List[Tuple[int, float]]] = {}
+            for seq, items, t_notify in batch:
                 for it in items:
-                    if seq > woken.get(it, -1):
-                        woken[it] = seq
-            for scope, seq in woken.items():
+                    woken.setdefault(it, []).append((seq, t_notify))
+            if batch:
+                taken = time.monotonic()
+                get_recorder().observe_stages(
+                    [(STAGE_READ_NOTIFY_LAG, (taken - t_notify) * 1000.0)
+                     for _seq, _items, t_notify in batch])
+            for scope, notified in woken.items():
                 stripe = self._stripe(scope)
                 with stripe.lock:
                     candidates = list(stripe.by_scope.get(scope, ()))
                 for rec in candidates:
-                    if seq < rec.seq:
+                    # The first batch since this park: its notify is the
+                    # commit that `read.deliver` is timed from.
+                    t_notify = next((t for seq, t in notified
+                                     if seq >= rec.seq), None)
+                    if t_notify is None:
                         # Every batch here predates this park: old news,
                         # not a wake signal for it (any index movement
                         # in that window was caught by park()'s
@@ -263,7 +290,7 @@ class ReadMux:
                     if store.scope_index(rec.scopes) > rec.min_index:
                         if self._claim(rec):
                             self._retire(rec)
-                            self._submit_serve(rec, "wake")
+                            self._submit_serve(rec, "wake", t_notify)
                     else:
                         with self._cond:
                             self._spurious += 1
@@ -313,15 +340,24 @@ class ReadMux:
 
     # ------------------------------------------------------ serve side
 
-    def _submit_serve(self, rec: ParkedQuery, reason: str) -> None:
+    def _submit_serve(self, rec: ParkedQuery, reason: str,
+                      t_notify: Optional[float] = None) -> None:
+        """Hand a claimed continuation to the serve pool. `t_notify`
+        (woken queries only) is when its commit notified, or when it
+        parked where park()'s own recheck found it satisfied."""
+        submitted = time.monotonic()
         get_recorder().observe_stage(
-            "read.park", (time.monotonic() - rec.parked_at) * 1000.0)
-        self._pool.submit(self._run_serve, rec, reason)
+            STAGE_READ_PARK, (submitted - rec.parked_at) * 1000.0)
+        self._pool.submit(self._run_serve, rec, reason, t_notify, submitted)
 
-    def _run_serve(self, rec: ParkedQuery, reason: str) -> None:
+    def _run_serve(self, rec: ParkedQuery, reason: str,
+                   t_notify: Optional[float] = None,
+                   submitted: Optional[float] = None) -> None:
         t0 = time.monotonic()
+        cpu0 = time.thread_time()
+        written = None
         try:
-            rec.serve(reason)
+            written = rec.serve(reason) or time.monotonic()
             with self._cond:
                 self._served += 1
             metrics.incr_counter(("readplane", "served"))
@@ -334,8 +370,18 @@ class ReadMux:
             metrics.incr_counter(("readplane", "write_errors"))
             logger.debug("parked-query serve failed", exc_info=True)
         finally:
-            get_recorder().observe_stage(
-                "read.serve", (time.monotonic() - t0) * 1000.0)
+            rows = [
+                (STAGE_READ_SERVE, (time.monotonic() - t0) * 1000.0),
+                (STAGE_READ_SERVE_CPU, (time.thread_time() - cpu0) * 1000.0),
+            ]
+            if t_notify is not None:
+                # A woken query (never a timeout or the shutdown flush).
+                rows.append(
+                    (STAGE_READ_SERVE_WAIT, (t0 - submitted) * 1000.0))
+                if written is not None:  # nothing was delivered otherwise
+                    rows.append(
+                        (STAGE_READ_DELIVER, (written - t_notify) * 1000.0))
+            get_recorder().observe_stages(rows)
 
     # ---------------------------------------------------- observation
 

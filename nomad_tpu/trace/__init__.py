@@ -20,7 +20,9 @@ import sys
 from .recorder import FlightRecorder  # noqa: F401
 from .span import (  # noqa: F401
     ALL_STAGES,
+    CLIENT_PATH_STAGES,
     DEVICE_IDLE_STAGES,
+    HTTP_STAGES,
     LIFECYCLE_CORE_STAGES,
     SELF_SUFFIX,
     STAGE_ALLOC_UPSERT,
@@ -54,6 +56,13 @@ from .span import (  # noqa: F401
     STAGE_PREEMPT_SELECT,
     STAGE_PREEMPT_SOLVE,
     STAGE_PREEMPT_VICTIMS,
+    STAGE_READ_DELIVER,
+    STAGE_READ_NOTIFY_LAG,
+    STAGE_READ_PARK,
+    STAGE_READ_SERVE,
+    STAGE_READ_SERVE_CPU,
+    STAGE_READ_SERVE_WAIT,
+    STAGE_RUNTIME_GIL_WAIT,
     STAGE_SCHED_PROCESS,
 )
 

@@ -24,7 +24,11 @@ grow without bound:
   critical section that stores the span, so recording takes one lock,
   not two. (One global histogram lock was taken by every record call of
   every thread; in the storm cell on the chip's host its contended
-  waits were 16-25 ms at the median, PERF.md section 6, PR 25.)
+  waits were 16-25 ms at the median, PERF.md section 6, PR 25.) Rows
+  that belong to no eval (the device's idle gaps, the client's path:
+  README.md) are fed through observe_stage, which picks the stripe by
+  the stage's name, or observe_stages, which takes the calling
+  thread's stripe once for all of a request's rows.
 - the account of a finished trace (`<stage>.self`, `eval.uncovered`;
   _account) is computed at complete() from the tree that is built there
   anyway: bounded by SPAN_CAP, and fed to the stripe's stage table in
@@ -46,6 +50,7 @@ p99 survive long after the recent-ring has wrapped past them.
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Dict, List, Optional
 
@@ -71,9 +76,10 @@ SPAN_CAP = 96            # spans stored per trace (excess counted): an
 FAULT_CAP = 8            # chaos fault annotations stored per trace
 ACTIVE_PER_STRIPE = 256  # in-flight traces per stripe before eviction
 TAIL_MIN_SAMPLES = 64    # e2e samples before tail-keep engages
-MAX_STAGES = 64          # distinct stage histograms (instrumentation-
-#   bounded: 22 eval stages, each with a `.self` twin at worst, plus
-#   eval.uncovered, device.idle.* and read.* is 50)
+MAX_STAGES = 96          # distinct stage histograms a stripe holds
+#   (instrumentation-bounded: 28 eval stages, each with a `.self` twin
+#   at worst, plus eval.uncovered, device.idle.* and the 15 rows of the
+#   client's path, span.py CLIENT_PATH_STAGES, is 75)
 
 # ntalint record-path manifest (analysis/robustness.py
 # record-path-blocking): every function reachable from these — the
@@ -85,6 +91,8 @@ NTA_RECORD_PATH = (
     "FlightRecorder.record_since_mark",
     "FlightRecorder.annotate_fault",
     "FlightRecorder.complete",
+    "FlightRecorder.observe_stage",
+    "FlightRecorder.observe_stages",
 )
 
 
@@ -316,7 +324,7 @@ class FlightRecorder:
 
     def observe_stage(self, stage: str, ms: float) -> None:
         """Public per-stage histogram feed for non-eval pipelines (the
-        read plane's `read.park`/`read.serve` stages): lands in
+        device's idle rows, the sampler's `runtime.gil_wait`): lands in
         stage_stats() without opening a trace and without touching the
         e2e histogram — e2e_p99() feeds the admission pressure monitor
         and must keep measuring the eval lifecycle only."""
@@ -325,6 +333,24 @@ class FlightRecorder:
         stripe = self._stripes[hash(stage) % N_STRIPES]
         with stripe.lock:
             _observe(stripe.hists, stage, ms)
+
+    def observe_stages(self, rows) -> None:
+        """Several `(stage, ms)` rows of one request in ONE critical
+        section: observe_stage for call sites that many threads reach
+        at once with the same stage names (64 HTTP handler threads
+        feeding `http.register.front` would all meet on that name's
+        stripe). The stage table is merged over the stripes on read, so
+        a sample may land in any of them: the stripe is the calling
+        THREAD's, taken once for all of the rows."""
+        if not self.enabled:
+            return
+        # A thread's ident is an address (a multiple of the page size):
+        # reduce it by a prime before the stripe count.
+        stripe = self._stripes[threading.get_ident() % 1021 % N_STRIPES]
+        with stripe.lock:
+            hists = stripe.hists
+            for stage, ms in rows:
+                _observe(hists, stage, ms)
 
     def complete(self, eval_id: str, status: str = "complete") -> None:
         """Close the eval's trace: finalize the span tree, fold its e2e

@@ -112,6 +112,55 @@ DEVICE_IDLE_STAGES = (
     STAGE_IDLE_STACK,
 )
 
+# The client's path (PR 40): what the client is timed on and no eval's
+# tree holds. Rows of the stage table fed through observe_stages /
+# observe_stage, never spans of a trace, so `e2e` keeps its start and its
+# end. README.md, "The client's path", has each stamp's exact place.
+#
+# The request on the server's thread (api/http.py `_dispatch`), for the
+# route families `register` (PUT/POST /v1/jobs, /v1/job/<id>) and `eval`
+# (GET /v1/evaluation/<id>): `front` is the request line in the handler
+# thread's hands -> the route's handler entered; `reply` the handler
+# returned -> the response written (none for a request that parks);
+# `request` the first stamp to the last (for a parked one: to the
+# hand-over to the mux); `cpu` the thread's CPU time over `request`'s
+# interval, so that wall minus CPU is what the thread waited for: the
+# GIL, a lock, the socket.
+HTTP_STAGES = {
+    family: tuple(f"http.{family}.{part}"
+                  for part in ("front", "reply", "request", "cpu"))
+    for family in ("register", "eval")
+}
+# The terminal status from its commit to the client's socket
+# (readplane/mux.py).
+STAGE_READ_PARK = "read.park"              # park -> hand-off to the
+#   serve pool, by a wake or a timeout (the shutdown flush serves
+#   inline): mostly the eval's own run time, not a lag
+STAGE_READ_SERVE = "read.serve"            # the serve pool's re-run of
+#   the query, the write and the connection's hand-back
+STAGE_READ_SERVE_CPU = "read.serve.cpu"    # thread CPU time over it
+STAGE_READ_NOTIFY_LAG = "read.notify_lag"  # a commit's notify (FSM
+#   thread) -> the wake loop taking that batch; one sample a batch
+STAGE_READ_SERVE_WAIT = "read.serve_wait"  # hand-off -> a pool worker
+#   starting on it; woken queries only
+STAGE_READ_DELIVER = "read.deliver"        # the commit's notify (or
+#   the park, where park()'s own recheck found it satisfied) -> sendall
+#   returned; woken queries only, none for a timeout or a shutdown flush
+# The interpreter's scheduling delay (profile/sampler.py): how late a
+# thread that asked for a 5 ms sleep woke, 200 samples a second.
+STAGE_RUNTIME_GIL_WAIT = "runtime.gil_wait"
+CLIENT_PATH_STAGES = tuple(
+    stage for names in HTTP_STAGES.values() for stage in names
+) + (
+    STAGE_READ_PARK,
+    STAGE_READ_SERVE,
+    STAGE_READ_SERVE_CPU,
+    STAGE_READ_NOTIFY_LAG,
+    STAGE_READ_SERVE_WAIT,
+    STAGE_READ_DELIVER,
+    STAGE_RUNTIME_GIL_WAIT,
+)
+
 ALL_STAGES = (
     STAGE_API_REGISTER,
     STAGE_BROKER_WAIT,

@@ -288,3 +288,75 @@ def test_admission_yellow_rate_limits_writes_429(api):
         server.admission.force_level(None)
         server.admission._write.rate = 50.0
         server.admission._write.burst = 100.0
+
+
+# ------------------------------------------- the client's path (PR 40)
+
+
+def _http_rows(rec):
+    return {stage: row for stage, row in rec.stage_stats().items()
+            if stage.startswith("http.")}
+
+
+@pytest.mark.parametrize(
+    "case", ["register", "eval_inline", "eval_parked", "other"])
+def test_client_path_rows_of_a_request(api, case):
+    """The two route families on every client's path feed the
+    recorder's `http.<family>.*` rows, all of a request's in one call
+    after its reply: front + reply lie inside request, the thread's CPU
+    time cannot pass the wall time, a request that parks has no reply
+    row, and another route (or another method on the same route) feeds
+    nothing."""
+    from nomad_tpu.trace import HTTP_STAGES, get_recorder
+
+    client, server = api
+    rec = get_recorder()
+    rec.set_enabled(True)
+    job = mock.job()
+    job.task_groups[0].count = 1
+    if case != "register":
+        eval_id = client.jobs.register(job)
+        assert wait_until(
+            lambda: server.fsm.state.eval_by_id(eval_id).status
+            == consts.EVAL_STATUS_COMPLETE)
+        _ev, index = client.evaluations.info(eval_id)
+        # the rows are fed after the reply is on the wire
+        assert wait_until(
+            lambda: HTTP_STAGES["eval"][2] in rec.stage_stats())
+    rec.reset()
+
+    if case == "other":
+        client.jobs.info(job.id)      # the register route, by GET
+        client.jobs.list()
+        client.nodes.list()
+        client.evaluations.list()
+        time.sleep(0.1)
+        assert _http_rows(rec) == {}
+        return
+
+    family = "register" if case == "register" else "eval"
+    front, reply, request, cpu = HTTP_STAGES[family]
+    if case == "register":
+        client.jobs.register(job)
+    elif case == "eval_inline":
+        client.evaluations.info(eval_id)
+    else:
+        # Nothing writes the finished eval again: the read parks in the
+        # mux and its wait runs out.
+        ev, _ = client.evaluations.info(eval_id, index=index, wait=0.2)
+        assert ev.status == consts.EVAL_STATUS_COMPLETE
+    assert wait_until(lambda: request in rec.stage_stats())
+    rows = _http_rows(rec)
+    expected = {front, request, cpu} | (
+        set() if case == "eval_parked" else {reply})
+    assert set(rows) == expected
+    assert all(row["count"] == 1 for row in rows.values())
+    # stage_stats rounds to a microsecond
+    in_request = rows[front]["max_ms"] + (
+        rows[reply]["max_ms"] if reply in rows else 0.0)
+    assert in_request <= rows[request]["max_ms"] + 0.01
+    # ... and a thread clock may tick in jiffies
+    assert rows[cpu]["max_ms"] <= rows[request]["max_ms"] + 10.0
+    if case == "eval_parked":
+        assert wait_until(lambda: "read.serve" in rec.stage_stats())
+        assert "read.deliver" not in rec.stage_stats()

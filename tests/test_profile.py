@@ -399,6 +399,40 @@ def test_gil_sampler_measures_overshoot(prof):
     assert not prof.gil.running()
 
 
+@pytest.mark.parametrize("enabled", [True, False])
+def test_sampler_feeds_the_recorders_row(prof, enabled):
+    """Each overshoot also lands in the flight recorder's stage table
+    as `runtime.gil_wait`, where the benchmark reads it; with the
+    observatory off (`profile_enabled = false`) the sampler does not
+    run and the row has no sample."""
+    from nomad_tpu.profile.sampler import SAMPLE_INTERVAL_S
+    from nomad_tpu.trace import STAGE_RUNTIME_GIL_WAIT, get_recorder
+
+    rec = get_recorder()
+    rec.set_enabled(True)
+    prof.configure(enabled=False)       # a sampler an earlier test left
+    rec.reset()
+    try:
+        prof.configure(enabled=enabled, sampler_interval=0.002)
+        assert prof.gil.running() is enabled
+        deadline = time.monotonic() + 5.0
+        while enabled and time.monotonic() < deadline and (
+                rec.stage_buckets(STAGE_RUNTIME_GIL_WAIT) or (0,))[0] < 5:
+            time.sleep(0.01)
+        if not enabled:
+            time.sleep(0.05)
+    finally:
+        prof.gil.stop()
+        prof.gil.interval = SAMPLE_INTERVAL_S
+    row = rec.stage_buckets(STAGE_RUNTIME_GIL_WAIT)
+    if enabled:
+        assert row[0] >= 5
+        assert abs(row[0] - prof.gil.hist.count) <= 1  # its own stays
+    else:
+        assert row is None
+        assert prof.gil.hist.count == 0
+
+
 def test_runq_sites_fixed_vocabulary(prof):
     profile.record_runq("batch_park", 1.5)
     profile.record_runq("broker_drain", 2.5)

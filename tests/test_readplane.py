@@ -493,3 +493,77 @@ def test_mux_disabled_falls_back_to_thread_parking():
     finally:
         http.stop()
         server.shutdown()
+
+
+# --------------------------------- commit to the client's socket (PR 40)
+
+
+@pytest.mark.parametrize("how", ["wake", "recheck", "timeout", "shutdown"])
+def test_mux_delivery_rows(how):
+    """A query parked and woken by a commit yields exactly one
+    `read.deliver`, one `read.serve_wait` and a `read.notify_lag`
+    sample that is no longer than the delivery it is part of; one that
+    park()'s own recheck finds satisfied is timed from the park; a
+    timeout and the shutdown flush yield none of the three. `read.serve`
+    and its CPU time are fed whatever the reason, `read.park` at every
+    hand-off to the pool."""
+    from nomad_tpu.trace import (
+        STAGE_READ_DELIVER,
+        STAGE_READ_NOTIFY_LAG,
+        STAGE_READ_PARK,
+        STAGE_READ_SERVE,
+        STAGE_READ_SERVE_CPU,
+        STAGE_READ_SERVE_WAIT,
+        get_recorder,
+    )
+
+    rec = get_recorder()
+    rec.set_enabled(True)
+    rec.reset()
+    store = StateStore()
+    j = mock.job()
+    store.upsert_job(1, j)
+    mux = ReadMux(lambda: store, workers=1)
+    mux.start()
+    served = []
+    try:
+        # the wake loop subscribes to the store's notify on its first tick
+        assert wait_until(lambda: mux._subscribed_id == store.store_id)
+        if how == "recheck":
+            store.upsert_job(2, j)
+            assert wait_until(
+                lambda: STAGE_READ_NOTIFY_LAG in rec.stage_stats())
+        rec.reset()
+        scopes = [watch.job(j.id)]
+        deadline = time.monotonic() + (0.2 if how == "timeout" else 30.0)
+        assert mux.park(scopes, 1, deadline, served.append)
+        if how == "wake":
+            store.upsert_job(2, j)
+        elif how == "shutdown":
+            mux.stop()
+        reason = "wake" if how == "recheck" else how
+        assert wait_until(lambda: served == [reason])
+        assert wait_until(lambda: STAGE_READ_SERVE in rec.stage_stats())
+    finally:
+        mux.stop()
+    rows = rec.stage_stats()
+    for stage in (STAGE_READ_SERVE, STAGE_READ_SERVE_CPU):
+        assert rows[stage]["count"] == 1, stage
+    # the flush serves inline: there is no hand-off to the pool
+    assert (STAGE_READ_PARK in rows) is (how != "shutdown")
+    delivery = (STAGE_READ_DELIVER, STAGE_READ_SERVE_WAIT,
+                STAGE_READ_NOTIFY_LAG)
+    if how in ("timeout", "shutdown"):
+        assert not set(delivery) & set(rows)
+        return
+    assert rows[STAGE_READ_DELIVER]["count"] == 1
+    assert rows[STAGE_READ_SERVE_WAIT]["count"] == 1
+    assert (rows[STAGE_READ_SERVE_WAIT]["max_ms"]
+            <= rows[STAGE_READ_DELIVER]["max_ms"] + 0.01)
+    if how == "wake":
+        assert rows[STAGE_READ_NOTIFY_LAG]["count"] == 1
+        assert (rows[STAGE_READ_NOTIFY_LAG]["max_ms"]
+                <= rows[STAGE_READ_DELIVER]["max_ms"] + 0.01)
+    else:
+        # nobody notified after the park: the park's own recheck
+        assert STAGE_READ_NOTIFY_LAG not in rows

@@ -4,7 +4,11 @@ plane, chaos (site, ordinal) annotations landing on the covering span,
 tail-keep of past-p99 traces, and the HTTP surfaces
 (/v1/agent/trace, /v1/metrics Prometheus exposition)."""
 
+import importlib.util
+import json
+import os
 import re
+import sys
 import threading
 import time
 
@@ -428,47 +432,58 @@ def test_base_delta_span_once_per_derived_delta(fresh_recorder):
         server.shutdown()
 
 
-def test_base_delta_metric_file_reads_the_span(fresh_recorder, monkeypatch):
-    """`benchmark/metrics/base_delta_p50_ms.json` is the entry
-    BENCHMARK.json lists for every cell, names a stage of the program,
-    and its reader takes a median from the recorder's histogram over a
-    window, and nothing from a program that never recorded the stage
-    (the parent's: the line then leaves the metric out)."""
-    import importlib.util
-    import json
-    import os
-    import sys
+_ROOT = os.path.join(os.path.dirname(__file__), "..")
 
-    from nomad_tpu.trace import ALL_STAGES, STAGE_BASE_DELTA
 
-    root = os.path.join(os.path.dirname(__file__), "..")
-    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
+def _bench_entry(name):
+    """(BENCHMARK.json's per-layer entry of that name, its metric file,
+    every cell's name): the entry is found BY NAME, wherever later PRs
+    appended theirs."""
+    bench = json.load(open(os.path.join(_ROOT, "BENCHMARK.json")))
     spec = json.load(open(os.path.join(
-        root, "benchmark", "metrics", "base_delta_p50_ms.json")))
-    entry = bench["per_layer"][-1]
-    assert entry == {key: spec[key] for key in (
-        "name", "unit", "better", "source", "layer", "moves")} | {
-        "workloads": [w["name"] for w in bench["workloads"]]}
-    assert spec["args"] == {"stage": STAGE_BASE_DELTA, "q": 0.5}
-    assert STAGE_BASE_DELTA in ALL_STAGES
+        _ROOT, "benchmark", "metrics", f"{name}.json")))
+    entry = next(e for e in bench["per_layer"] if e["name"] == name)
+    return entry, spec, [w["name"] for w in bench["workloads"]]
 
-    def load(name, path):
-        module_spec = importlib.util.spec_from_file_location(name, path)
+
+def _bench_reader(monkeypatch, name):
+    """The benchmark's reader of that name, loaded as the harness loads
+    it (beside its `stats` module)."""
+    def load(module_name, path):
+        module_spec = importlib.util.spec_from_file_location(
+            module_name, path)
         module = importlib.util.module_from_spec(module_spec)
         module_spec.loader.exec_module(module)
         return module
 
     monkeypatch.setitem(sys.modules, "stats", load(
-        "stats", os.path.join(root, "benchmark", "stats.py")))
-    reader = load("benchmark_reader_span", os.path.join(
-        root, "benchmark", "readers", f"{spec['reader']}.py"))
+        "stats", os.path.join(_ROOT, "benchmark", "stats.py")))
+    return load(f"benchmark_reader_{name}", os.path.join(
+        _ROOT, "benchmark", "readers", f"{name}.py"))
+
+
+def _span_snapshot(rec):
+    """benchmark/run.py span_snapshot's reading."""
+    return {stage: rec.stage_buckets(stage) for stage in rec.stage_stats()}
+
+
+def test_base_delta_metric_file_reads_the_span(fresh_recorder, monkeypatch):
+    """`benchmark/metrics/base_delta_p50_ms.json` is an entry
+    BENCHMARK.json lists for every cell, names a stage of the program,
+    and its reader takes a median from the recorder's histogram over a
+    window, and nothing from a program that never recorded the stage
+    (the parent's: the line then leaves the metric out)."""
+    from nomad_tpu.trace import ALL_STAGES, STAGE_BASE_DELTA
+
+    entry, spec, cells = _bench_entry("base_delta_p50_ms")
+    assert entry == {key: spec[key] for key in (
+        "name", "unit", "better", "source", "layer", "moves")} | {
+        "workloads": cells}
+    assert spec["args"] == {"stage": STAGE_BASE_DELTA, "q": 0.5}
+    assert STAGE_BASE_DELTA in ALL_STAGES
+    reader = _bench_reader(monkeypatch, spec["reader"])
     rec = fresh_recorder
-
-    def snapshot():
-        return {stage: rec.stage_buckets(stage)
-                for stage in rec.stage_stats()}
-
-    before = snapshot()
+    before = _span_snapshot(rec)
     assert reader.read(spec["args"], {
         "spans_before": before, "spans_after": before}) is None
     now = time.monotonic()
@@ -477,8 +492,142 @@ def test_base_delta_metric_file_reads_the_span(fresh_recorder, monkeypatch):
                         ann={"rows": 4, "adds": 8, "refills": 0,
                              "patched_jobs": 1})
     value = reader.read(spec["args"], {
-        "spans_before": before, "spans_after": snapshot()})
+        "spans_before": before, "spans_after": _span_snapshot(rec)})
     assert 1.5 < value < 2.7
+
+
+# ---------------------------------------------------------------------
+# the client's path (PR 40): rows of the stage table, no eval's tree
+
+
+class _CountingLock:
+    def __init__(self, inner):
+        self.inner, self.holds = inner, 0
+
+    def __enter__(self):
+        self.inner.acquire()
+        self.holds += 1
+
+    def __exit__(self, *exc):
+        self.inner.release()
+
+
+def test_observe_stages_one_stripe_one_lock_hold():
+    """All of a request's rows land in ONE stripe, the calling thread's,
+    under one hold of its lock; another thread's may land elsewhere, and
+    the read side merges the stripes. No trace is opened and `e2e` is
+    not touched."""
+    from nomad_tpu.trace import HTTP_STAGES
+
+    rec = FlightRecorder()
+    for stripe in rec._stripes:
+        stripe.lock = _CountingLock(stripe.lock)
+    rows = [(stage, 1.0 + i)
+            for i, stage in enumerate(HTTP_STAGES["register"])]
+    rec.observe_stages(rows)
+    assert sum(stripe.lock.holds for stripe in rec._stripes) == 1
+    filled = [stripe for stripe in rec._stripes if stripe.hists]
+    assert len(filled) == 1 and filled[0].lock.holds == 1
+    assert set(filled[0].hists) == set(HTTP_STAGES["register"])
+
+    threads = [threading.Thread(target=rec.observe_stages, args=(rows,))
+               for _ in range(16)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    # 17 calls, 17 holds; the reads below take every stripe's lock
+    assert sum(stripe.lock.holds for stripe in rec._stripes) == 17
+    for i, stage in enumerate(HTTP_STAGES["register"]):
+        count, buckets = rec.stage_buckets(stage)
+        assert count == 17 and sum(buckets) == 17
+        assert rec.stage_stats()[stage]["max_ms"] == 1.0 + i
+    assert rec.stage_buckets("e2e") is None
+    assert rec.stats()["active"] == 0 and rec.traces() == []
+
+    rec.set_enabled(False)
+    rec.observe_stages(rows)
+    assert rec.stage_buckets(rows[0][0])[0] == 17
+
+
+def test_client_path_stages_fit_the_stage_table():
+    """Every eval stage with a `.self` twin, the derived rows, the
+    device's idle rows and the client's path fit one stripe's table (a
+    thread's rows may all land in one)."""
+    from nomad_tpu.trace import (
+        ALL_STAGES,
+        CLIENT_PATH_STAGES,
+        DEVICE_IDLE_STAGES,
+        HTTP_STAGES,
+    )
+    from nomad_tpu.trace.recorder import MAX_STAGES
+
+    assert len(set(CLIENT_PATH_STAGES)) == len(CLIENT_PATH_STAGES) == 15
+    assert not set(CLIENT_PATH_STAGES) & set(ALL_STAGES)
+    for names in HTTP_STAGES.values():
+        assert set(names) <= set(CLIENT_PATH_STAGES)
+    assert (2 * len(ALL_STAGES) + 1 + len(DEVICE_IDLE_STAGES)
+            + len(CLIENT_PATH_STAGES)) <= MAX_STAGES
+
+
+# metric -> (reader, what its window below must read)
+CLIENT_PATH_METRICS = {
+    "gil_wait_p50_ms": ("span", (1.6, 2.4)),
+    "gil_wait_p95_ms": ("span", (6.5, 9.6)),
+    "register_server_p50_ms": ("span", (1.6, 2.4)),
+    "register_front_p50_ms": ("span", (1.6, 2.4)),
+    "register_reply_p50_ms": ("span", (1.6, 2.4)),
+    "register_cpu_share": ("span_share", (0.42, 0.58)),
+    "eval_read_server_p50_ms": ("span", (1.6, 2.4)),
+    "eval_reads_per_eval": ("span_count", (2.0, 2.0)),
+    "read_deliver_p50_ms": ("span", (1.6, 2.4)),
+    "read_deliver_p95_ms": ("span", (6.5, 9.6)),
+    "read_notify_lag_p50_ms": ("span", (1.6, 2.4)),
+    "read_serve_wait_p50_ms": ("span", (1.6, 2.4)),
+    "read_serve_p50_ms": ("span", (1.6, 2.4)),
+    "read_serve_cpu_share": ("span_share", (0.42, 0.58)),
+    "read_wakes_per_eval": ("span_count", (2.0, 2.0)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CLIENT_PATH_METRICS))
+def test_client_path_metric_file(name, fresh_recorder, monkeypatch):
+    """Each of PR 40's metric files is an entry BENCHMARK.json lists for
+    every cell, names rows that `span.py` lists, and its reader (one of
+    those that were there) takes the WINDOW's difference from a recorder
+    that has the rows and nothing from one that has not (the parent's:
+    the line then leaves the metric out)."""
+    from nomad_tpu.trace import CLIENT_PATH_STAGES
+
+    reader_name, (low, high) = CLIENT_PATH_METRICS[name]
+    entry, spec, cells = _bench_entry(name)
+    assert entry == {key: spec[key] for key in (
+        "name", "unit", "better", "source", "layer", "moves")} | {
+        "workloads": cells}
+    assert spec["source"] == "program_span"
+    assert spec["moves"] == "placed_allocs_per_s"
+    assert spec["reader"] == reader_name
+    args = spec["args"]
+    num = args.get("num", [])
+    stages = ([args["stage"]] if "stage" in args    # span, span_count
+              else num + args["den"])                # span_share
+    assert stages and set(stages) <= set(CLIENT_PATH_STAGES)
+
+    reader = _bench_reader(monkeypatch, reader_name)
+    rec = fresh_recorder
+    empty = _span_snapshot(rec)
+    assert reader.read(args, {"spans_before": empty, "spans_after": empty,
+                              "evals_completed": 2}) is None
+    # before the window: samples a life-long reading would be moved by
+    rec.observe_stages([(stage, 100.0) for stage in stages] * 3)
+    before = _span_snapshot(rec)
+    # the window: four samples a stage, a share's numerator half as long
+    rec.observe_stages([(stage, ms / 2 if stage in num else ms)
+                        for stage in stages for ms in (1.0, 2.0, 4.0, 8.0)])
+    value = reader.read(args, {
+        "spans_before": before, "spans_after": _span_snapshot(rec),
+        "evals_completed": 2})
+    assert low <= value <= high
 
 
 def test_chaos_fault_annotation_lands_on_covering_span(fresh_recorder):

@@ -200,9 +200,10 @@ def test_new_stage_names_are_stages():
     from nomad_tpu.trace.recorder import MAX_STAGES
 
     # every eval stage with a `.self` twin, the derived and the
-    # observe_stage ones, still fit the table
+    # observe_stage ones (`read.park` and `read.serve` among the
+    # client's path since PR 40), still fit the table
     assert (2 * len(trace.ALL_STAGES) + 1 + len(trace.DEVICE_IDLE_STAGES)
-            + 2) <= MAX_STAGES
+            + len(trace.CLIENT_PATH_STAGES)) <= MAX_STAGES
 
 
 # ---------------------------------------------------------------------
