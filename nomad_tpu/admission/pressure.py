@@ -158,8 +158,11 @@ class PressureMonitor:
             in_flight = dispatch["in_flight"]
             pending = dispatch["pending"]
             max_batch = max(1, dispatch["max_batch"])
-            saturated = (in_flight >= self.server.dispatch.max_inflight
-                         and pending >= max_batch)
+            # `slots`: 1 while the pipeline sends batches through one
+            # at a time after a plan conflict (dispatch/pipeline.py).
+            slots = dispatch.get("slots",
+                                 self.server.dispatch.max_inflight)
+            saturated = in_flight >= slots and pending >= max_batch
             if saturated and pending >= 2 * max_batch:
                 bump(LEVEL_RED,
                      f"dispatch saturated: {in_flight} in flight, "

@@ -16,7 +16,10 @@ device dispatch:
   batch's evals build matrices and upload overlays WHILE the previous
   batch's device sync and plan submits are still in flight. Plan
   submission + ack runs on the stage/result threads, never on the
-  dispatcher.
+  dispatcher. The second slot is earned: a batch beside one in flight
+  plans without that one's uncommitted plans, and where that costs
+  plan conflicts the next batches go one at a time
+  (DispatchPipeline.__init__ has the rule).
 - **conflict requeue** — a plan the applier partially rejects
   (RefreshIndex) does not replan alone on a fresh snapshot (a 1-3
   alloc retry that pays a full round-trip, r05's retry tax); the eval
@@ -53,6 +56,9 @@ from ..utils.backoff import poll_until
 DEQUEUE_TOPUP_SLICE = 0.002  # cond-wait granularity while accumulating
 SLOT_WAIT_SLICE = 0.02  # cond-wait granularity while all slots busy
 WAIT_INDEX_TIMEOUT = 5.0
+# Most batches one plan conflict can send through the pipeline one at a
+# time (DispatchPipeline._note_conflict doubles up to here).
+ALONE_MAX = 64
 
 # ntalint lock-discipline manifest: functions reachable from these
 # entrypoints run on the dispatcher thread and must never block (the
@@ -207,6 +213,27 @@ class DispatchPipeline:
         # The forming batch's placeholder at the batcher
         # (_announce_forming); the dispatcher thread's alone.
         self._forming = None
+        # The second slot is EARNED (PR 41). A batch that launches
+        # beside one in flight plans on a snapshot without that one's
+        # uncommitted plans; where the two want the same nodes (a storm
+        # of like jobs on a bin-packed fleet) the applier rejects the
+        # later plans and a third of every eval is done again, where
+        # they do not (the ramp's thousand-allocation jobs) the overlap
+        # is pure gain. The pipeline cannot tell the two apart before
+        # the fact, so it reads the fact: a plan conflict in a stretch
+        # of shared slots (from the first batch cut beside another
+        # until the pipeline is empty again) sends the next
+        # `_alone_next` batches through ONE at a time (each snapshots
+        # after its predecessor's last commit), the one after them may
+        # take the second slot again, and every stretch that conflicts
+        # doubles the count (up to ALONE_MAX) until one ends without a
+        # conflict (_release_slot), which resets it. A conflict among
+        # one batch's own plans (nothing shared) costs nothing here.
+        self._alone = 0  # guarded-by: _lock (batches still to go alone)
+        self._alone_next = 1  # guarded-by: _lock (the next conflict's cost)
+        # plan_conflicts at the first batch cut beside another since the
+        # pipeline was last empty; None while no slots were shared.
+        self._shared_mark: Optional[int] = None  # guarded-by: _lock
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.drained = 0  # guarded-by: _lock (evals requeued by drain())
@@ -217,6 +244,7 @@ class DispatchPipeline:
         # ---- stats ----
         self.evals_in = 0  # guarded-by: _lock (handed off / requeued)
         self.batches = 0  # guarded-by: _lock (batches launched)
+        self.alone_batches = 0  # guarded-by: _lock (launched under _alone)
         self.dispatched_evals = 0  # guarded-by: _lock (sum batch sizes)
         self.largest_batch = 0  # guarded-by: _lock
         self.routed_host = 0  # guarded-by: _lock (sent to host factory)
@@ -389,14 +417,14 @@ class DispatchPipeline:
                 elapsed = time.monotonic() - start
                 if len(self._pending) >= self.max_batch:
                     break
+                slots = 1 if self._alone else self.max_inflight
                 if self._inflight == 0:
                     if elapsed >= self.idle_grace:
                         break
-                elif (self._inflight < self.max_inflight
-                      and elapsed >= self.window):
+                elif self._inflight < slots and elapsed >= self.window:
                     break
                 announce = (self._forming is None
-                            and 0 < self._inflight < self.max_inflight)
+                            and 0 < self._inflight < slots)
                 if not announce:
                     self._cond.wait(DEQUEUE_TOPUP_SLICE)
             if announce:
@@ -404,7 +432,8 @@ class DispatchPipeline:
         # Wait for an in-flight slot; late arrivals keep joining the
         # pending list while we wait (that IS the adaptive window).
         with self._cond:
-            while (self._inflight >= self.max_inflight
+            while (self._inflight >= (1 if self._alone
+                                      else self.max_inflight)
                    and not self._stop.is_set()):
                 self._cond.wait(SLOT_WAIT_SLICE)
             batch = self._pending[: self.max_batch]
@@ -413,6 +442,11 @@ class DispatchPipeline:
                 return []
             self._inflight += 1
             others = self._inflight > 1
+            if self._alone:
+                self._alone -= 1
+                self.alone_batches += 1
+            elif others and self._shared_mark is None:
+                self._shared_mark = self.plan_conflicts
             self.batches += 1
             self.dispatched_evals += len(batch)
             self.largest_batch = max(self.largest_batch, len(batch))
@@ -842,6 +876,13 @@ class DispatchPipeline:
             remaining[0] -= 1
             if remaining[0] == 0:
                 self._inflight -= 1
+                if self._inflight == 0 and self._shared_mark is not None:
+                    # A stretch of shared slots is over. If no plan
+                    # conflicted in it, sharing works here: the next
+                    # conflict costs one batch alone again.
+                    if self.plan_conflicts == self._shared_mark:
+                        self._alone_next = 1
+                    self._shared_mark = None
                 self._cond.notify_all()
 
     # ------------------------------------------------------- plumbing
@@ -861,6 +902,12 @@ class DispatchPipeline:
     def _note_conflict(self) -> None:
         with self._lock:
             self.plan_conflicts += 1
+            if self._shared_mark is not None and not self._alone:
+                # Slots are shared: the batches in flight finish, then
+                # `_alone_next` batches go one at a time (__init__ has
+                # the rule).
+                self._alone = self._alone_next
+                self._alone_next = min(2 * self._alone_next, ALONE_MAX)
         metrics.incr_counter(("dispatch", "plan_conflict"))
 
     def _note_inline_retry(self) -> None:
@@ -888,6 +935,10 @@ class DispatchPipeline:
                 ) if batches else 0.0,
                 "largest_batch": self.largest_batch,
                 "in_flight": self._inflight,
+                "slots": 1 if self._alone else self.max_inflight,
+                "alone": self._alone,
+                "alone_next": self._alone_next,
+                "alone_batches": self.alone_batches,
                 "pending": len(self._pending),
                 "evals_in": self.evals_in,
                 "acked": self.acked,

@@ -57,7 +57,9 @@ class ServerConfig:
     # accumulates during the in-flight device sync + plan submits),
     # and requeues plan-conflict retries into the ACCUMULATING batch.
     # Batches allowed in flight at once: overlap hides the device
-    # round-trip + plan-submit tail behind the next accumulation.
+    # round-trip + plan-submit tail behind the next accumulation. The
+    # most, not the number: after a plan conflict the pipeline sends
+    # batches through one at a time (dispatch/pipeline.py).
     dispatch_max_inflight: int = 2
     # Accumulation window while another batch is in flight (its
     # round-trip is the budget being amortized); the idle grace is all

@@ -173,6 +173,12 @@ TABLES = (
 )
 
 
+def _jobs_table(items) -> tuple:
+    """("jobs",) where a txn's watch items say that `_set_job_status`
+    rewrote a job, for the txn's one `_bump`."""
+    return ("jobs",) if watch.table("jobs") in items else ()
+
+
 class StateSnapshot:
     """Immutable point-in-time view with the scheduler's read interface
     (scheduler.State, reference scheduler/scheduler.go:55)."""
@@ -295,7 +301,60 @@ class StateSnapshot:
 class StateStore:
     """The authoritative replicated state. All writes come from the FSM
     applying log entries; every write bumps the per-table and global
-    index and fires scoped watches."""
+    index and fires scoped watches.
+
+    Who takes `_lock`: every write txn, `snapshot()` (and so every
+    read that spans rows: it goes through `__getattr__` to a fresh
+    snapshot), `persist` and `restore`. Who takes NO lock and no
+    snapshot: `latest_index()`, `index(table)`, `scope_index(items)`
+    and the four point reads `node_by_id`, `job_by_id`, `eval_by_id`,
+    `alloc_by_id`. Each of those is one read of an int or one
+    `dict.get` with str (or tuple-of-str) keys, which is atomic only
+    because the interpreter has a GIL: on a free-threaded build
+    (`sys._is_gil_enabled()` false) the writers' in-place dict writes
+    race with these reads and the lock has to come back. With the GIL,
+    the read mux's one wake loop, the HTTP handlers and the catch-up
+    polls never queue behind the FSM. Scheduler and dispatch
+    code is no party to this: it plans against `snapshot()` only
+    (analysis/snapshot.py's rule; `latest_index` is its one probe).
+
+    The orders the lock-free readers rely on, all inside one writer's
+    hold of `_lock` (writers are serialised, and raft applies indexes
+    in rising order):
+
+    1. A row is complete before it is inserted (copy, set fields,
+       then `table[id] = row`) and never mutated after, so a point
+       read sees a whole row, old or new.
+    2. A txn makes ALL its table writes before it moves ANY index:
+       `_bump` (table indexes, then the global one) and then `_stamp`
+       (scope indexes) come last, once a txn (`_set_job_status` moves
+       none: the txn's one bump names the jobs table, `_jobs_table`).
+       So a reader that reads an index FIRST and the data SECOND, by
+       id or through a snapshot, finds the rows of every txn up to
+       that index: for that order of reads, new index with old data
+       is not possible. (`park()`'s recheck and the wake loop read so:
+       index, then the serve re-runs the query.) The other order of
+       reads is not covered by this and never was by the lock:
+       `_blocking` and the mux's `serve` read the data first and
+       compute `X-Nomad-Index` after it, so a commit that lands
+       between the two goes out as the OLDER row under the NEWER
+       index, and that client sits its `wait` out before it sees the
+       row. Under the lock the gap was one lock hand-off wide, now it
+       is two dict reads; closing it means reading the index before
+       the data in api/http.py (PERF.md section 7). What a point read
+       CAN newly see is a row whose stamp has not landed; the reply
+       then carries new data under the older index and the client's
+       next poll returns at once, which loses nothing.
+    3. `notify` fires after the lock is released, so a watcher that
+       read the old index and parked is told afterwards (the mux's
+       park() rechecks after registering, for the commit in between).
+    4. `_stamp`'s pruning raises `_scope_floor` BEFORE it drops the
+       entries, and `scope_index` reads the entry before the floor:
+       no scope's index ever reads lower than it did.
+
+    What this does not give: two point reads are two moments (they
+    always were: each took a snapshot of its own), and whoever needs
+    rows that belong together takes `snapshot()`."""
 
     def __init__(self):
         self._lock = threading.RLock()
@@ -339,12 +398,10 @@ class StateStore:
             )
 
     def latest_index(self) -> int:
-        with self._lock:
-            return self._latest_index
+        return self._latest_index  # lock-free: class docstring
 
     def index(self, table: str) -> int:
-        with self._lock:
-            return self._table_indexes.get(table, 0)
+        return self._table_indexes.get(table, 0)  # lock-free
 
     def watch(self, items) -> "threading.Event":
         return self.notify.watch(items)
@@ -359,38 +416,51 @@ class StateStore:
         scope floor (0 on a fresh store; the restored latest index when
         the snapshot predates scope persistence, so correctness degrades
         to the old conservative global behavior, never to missed
-        wakes)."""
-        with self._lock:
-            best = 0
-            for item in items:
-                idx = self._scope_indexes.get(item)
-                if idx is None:
-                    kind, key = item
-                    if kind == "table":
-                        idx = self._table_indexes.get(key, 0)
-                    else:
-                        idx = self._scope_floor
-                if idx > best:
-                    best = idx
-            return best
+        wakes). Lock-free (class docstring): the entry is read BEFORE
+        the floor, and `_stamp` raises the floor before it drops an
+        entry, so no scope's index ever reads lower than it did."""
+        scopes = self._scope_indexes
+        best = 0
+        for item in items:
+            idx = scopes.get(item)
+            if idx is None:
+                kind, key = item
+                if kind == "table":
+                    idx = self._table_indexes.get(key, 0)
+                else:
+                    idx = self._scope_floor
+            if idx > best:
+                best = idx
+        return best
 
-    # Read API mirrors the snapshot's (reads go through a fresh snapshot
-    # so they are consistent).
+    # Point reads: ONE dict read of the live table, no lock and no
+    # snapshot, so no share() and no table copy in the next write txn
+    # (class docstring).
+    def node_by_id(self, node_id: str) -> Optional[Node]:
+        return self._tables["nodes"].data.get(node_id)
+
+    def job_by_id(self, job_id: str) -> Optional[Job]:
+        return self._tables["jobs"].data.get(job_id)
+
+    def eval_by_id(self, eval_id: str) -> Optional[Evaluation]:
+        return self._tables["evals"].data.get(eval_id)
+
+    def alloc_by_id(self, alloc_id: str) -> Optional[Allocation]:
+        return self._tables["allocs"].data.get(alloc_id)
+
+    # Every read that spans rows goes through a fresh snapshot, so it
+    # is one consistent view (and takes the lock to get it).
     def __getattr__(self, name):
         snap_methods = (
-            "node_by_id",
             "nodes",
-            "job_by_id",
             "jobs",
             "jobs_by_scheduler",
             "jobs_by_periodic",
             "job_summary_by_id",
             "periodic_launch_by_id",
             "periodic_launches",
-            "eval_by_id",
             "evals",
             "evals_by_job",
-            "alloc_by_id",
             "allocs",
             "alloc_count",
             "allocs_changed_since",
@@ -421,19 +491,23 @@ class StateStore:
 
     def _stamp(self, index: int, items) -> None:
         """Record `index` as the modify index of every touched scope.
-        Runs under self._lock, after the txn's table writes, so a
-        reader never sees new data with a pre-txn scope index."""
+        Runs under self._lock, after ALL of the txn's table writes, so
+        a reader that sees the stamp finds the txn's rows (the readers
+        of `scope_index` take no lock: class docstring)."""
         scopes = self._scope_indexes
         for item in items:
             scopes[item] = index
         if len(scopes) > self._SCOPE_CAP:
             by_age = sorted(scopes.items(), key=lambda kv: kv[1])
             cut = len(by_age) // 2
-            for item, idx in by_age[:cut]:
-                del scopes[item]
             if cut:
+                # The floor rises BEFORE the entries go: a lock-free
+                # reader that misses an entry then reads a floor at or
+                # above what the entry held, never below it.
                 self._scope_floor = max(self._scope_floor,
                                         by_age[cut - 1][1])
+            for item, _idx in by_age[:cut]:
+                del scopes[item]
 
     def upsert_node(self, index: int, node: Node) -> None:
         items = [watch.table("nodes"), watch.node(node.id)]
@@ -604,7 +678,7 @@ class StateStore:
                 if job is not None:
                     items.extend(self._set_job_status(index, job))
                     items.append(watch.job_summary(ev.job_id))
-            self._bump(index, "evals", "job_summary")
+            self._bump(index, "evals", "job_summary", *_jobs_table(items))
             self._stamp(index, items)
         self.notify.notify(items)
 
@@ -638,8 +712,9 @@ class StateStore:
             for job_id in touched_jobs:
                 job = self._tables["jobs"].data.get(job_id)
                 if job is not None:
-                    items.extend(self._set_job_status(index, job, eval_delete=True))
-            self._bump(index, "evals", "allocs")
+                    items.extend(self._set_job_status(
+                        index, job, eval_delete=True))
+            self._bump(index, "evals", "allocs", *_jobs_table(items))
             self._stamp(index, items)
         self.notify.notify(items)
 
@@ -691,7 +766,7 @@ class StateStore:
                 job = self._tables["jobs"].data.get(job_id)
                 if job is not None:
                     items.extend(self._set_job_status(index, job))
-            self._bump(index, "allocs", "job_summary")
+            self._bump(index, "allocs", "job_summary", *_jobs_table(items))
             self._stamp(index, items)
         self.notify.notify(items)
 
@@ -732,7 +807,7 @@ class StateStore:
                     ]
                 )
             self._alloc_journal.record(index, written)
-            self._bump(index, "allocs", "job_summary")
+            self._bump(index, "allocs", "job_summary", *_jobs_table(items))
             self._stamp(index, items)
         self.notify.notify(items)
 
@@ -823,7 +898,9 @@ class StateStore:
     def _set_job_status(self, index: int, job: Job, eval_delete: bool = False) -> list:
         """Recompute and store the derived job status (state_store.go:1417
         setJobStatus). Returns the watch items to notify (empty when the
-        status is unchanged); a change also bumps the jobs table index."""
+        status is unchanged). It moves NO index: the caller's txn bumps
+        the jobs table with its own, when its table writes are through
+        (`_jobs_table`; class docstring, order 2)."""
         status = self._get_job_status(job, eval_delete)
         stored = self._tables["jobs"].data.get(job.id)
         if stored is None or stored.status == status:
@@ -833,7 +910,6 @@ class StateStore:
         updated.status = status
         updated.modify_index = index
         jobs[job.id] = updated
-        self._bump(index, "jobs")
         return [watch.table("jobs"), watch.job(job.id)]
 
     # ------------------------------------------------------------------

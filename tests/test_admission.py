@@ -47,7 +47,7 @@ def _reset_global_breaker():
 def stub_server(cfg=None, ready=0, unacked=0, blocked=0, shed=0,
                 expired=0, in_flight=0, pending=0, max_batch=64,
                 max_inflight=2, dispatch_enabled=True,
-                ready_by_queue=None):
+                ready_by_queue=None, slots=None):
     cfg = cfg or ServerConfig()
     if ready_by_queue is None:
         # Default: all ready depth on the 'service' queue.
@@ -58,13 +58,12 @@ def stub_server(cfg=None, ready=0, unacked=0, blocked=0, shed=0,
         "total_blocked": blocked, "total_waiting": 0,
         "dead_lettered": 0, "shed": shed, "expired": expired,
     })
-    dispatch = SimpleNamespace(
-        stats=lambda: {
-            "enabled": dispatch_enabled, "in_flight": in_flight,
-            "pending": pending, "max_batch": max_batch,
-        },
-        max_inflight=max_inflight,
-    )
+    stats = {"enabled": dispatch_enabled, "in_flight": in_flight,
+             "pending": pending, "max_batch": max_batch}
+    if slots is not None:
+        stats["slots"] = slots
+    dispatch = SimpleNamespace(stats=lambda: dict(stats),
+                               max_inflight=max_inflight)
     return SimpleNamespace(config=cfg, broker=broker, dispatch=dispatch)
 
 
@@ -270,6 +269,19 @@ def test_pressure_dispatch_saturation():
         stub_server(cfg, in_flight=2, pending=128, max_batch=64,
                     max_inflight=2), cfg)
     assert mon.snapshot(refresh=True)["level"] == LEVEL_RED
+
+
+@pytest.mark.parametrize("slots, level", [(1, LEVEL_YELLOW),
+                                          (2, LEVEL_GREEN)])
+def test_pressure_dispatch_saturation_counts_the_slots_in_use(slots, level):
+    """While the pipeline sends batches through one at a time (after a
+    plan conflict), ONE batch in flight with a full batch pending is
+    all it can do: saturated, as two are when slots are shared."""
+    cfg = ServerConfig()
+    mon = PressureMonitor(
+        stub_server(cfg, in_flight=1, pending=64, max_batch=64,
+                    max_inflight=2, slots=slots), cfg)
+    assert mon.snapshot(refresh=True)["level"] == level
 
 
 def test_pressure_e2e_p99_input(monkeypatch):

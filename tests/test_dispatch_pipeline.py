@@ -1078,3 +1078,135 @@ def test_nobody_waits_a_lagging_index_out_on_the_placeholder(lagging):
         assert batcher.stats()["open_cohorts"] == opened
     finally:
         server.shutdown()
+
+
+# ---------------------------------------------------------------------
+# the second slot is earned: a plan conflict sends batches through alone
+
+
+def _unstarted_pipe(server):
+    from nomad_tpu.dispatch import DispatchPipeline
+
+    pipe = DispatchPipeline(server)  # not started: we drive it
+    assert pipe.enabled and pipe.max_inflight == 2
+    return pipe
+
+
+def _cut_in_thread(pipe):
+    import threading
+
+    got = []
+    t = threading.Thread(
+        target=lambda: got.append(pipe._accumulate()), daemon=True)
+    t.start()
+    return t, got
+
+
+@pytest.mark.parametrize("conflicted", [False, True])
+def test_a_batch_launches_beside_one_in_flight_unless_a_plan_conflicted(
+        conflicted):
+    """With a slot free a forming batch launches after the window,
+    beside the batch in flight. After a plan conflict the next batch
+    waits for the pipeline to be EMPTY (its snapshot then holds every
+    plan of its predecessor), and the one after it may share again."""
+    from nomad_tpu.dispatch.pipeline import _Pending
+
+    server = make_server(num_schedulers=0)
+    try:
+        pipe = _unstarted_pipe(server)
+        with pipe._cond:
+            pipe._inflight = 1
+        pipe._note_conflict()  # among one batch's own plans: no cost
+        assert pipe.stats()["alone"] == 0
+        if conflicted:
+            with pipe._lock:  # a batch was cut beside the one in flight
+                pipe._shared_mark = pipe.plan_conflicts
+            pipe._note_conflict()
+            assert pipe.stats()["alone"] == 1
+        pipe._admit(_Pending(mock.eval(), "tok-0"))
+        t, got = _cut_in_thread(pipe)
+        if conflicted:
+            time.sleep(4 * pipe.window)
+            assert not got, "launched beside a batch after a conflict"
+            assert pipe._forming is None  # nobody waits on a placeholder
+            pipe._release_slot([1])  # the batch in flight finished
+        t.join(timeout=5.0)
+        assert got and len(got[0]) == 1
+        stats = pipe.stats()
+        assert stats["in_flight"] == (1 if conflicted else 2)
+        assert stats["alone"] == 0
+        assert stats["alone_batches"] == (1 if conflicted else 0)
+        if conflicted:
+            # The penalty is spent: the next batch shares again.
+            pipe._admit(_Pending(mock.eval(), "tok-1"))
+            t, got = _cut_in_thread(pipe)
+            t.join(timeout=5.0)
+            assert got and pipe.stats()["in_flight"] == 2
+        for forming in (pipe._forming,):
+            if forming is not None:
+                forming.settle()
+    finally:
+        server.shutdown()
+
+
+@pytest.mark.parametrize("tries", [1, 2, 4, 9])
+def test_every_shared_stretch_that_conflicts_doubles_the_batches_alone(
+        tries):
+    """The first stretch of shared slots that conflicts costs one batch
+    alone, the next two, then four, up to ALONE_MAX; conflicts that
+    come while batches already go alone (the in-flight batches' other
+    plans) cost nothing more."""
+    from nomad_tpu.dispatch.pipeline import ALONE_MAX
+
+    server = make_server(num_schedulers=0)
+    try:
+        pipe = _unstarted_pipe(server)
+        for n in range(tries):
+            with pipe._lock:  # a batch is cut beside one in flight
+                pipe._shared_mark = pipe.plan_conflicts
+            pipe._note_conflict()
+            want = min(2 ** n, ALONE_MAX)
+            assert pipe.stats()["alone"] == want
+            pipe._note_conflict()  # the same stretch's next plan
+            pipe._note_conflict()
+            assert pipe.stats()["alone"] == want
+            assert pipe.stats()["alone_next"] == min(2 * want, ALONE_MAX)
+            with pipe._lock:
+                pipe._alone = 0  # those batches have gone
+        assert pipe.stats()["plan_conflicts"] == 3 * tries
+    finally:
+        server.shutdown()
+
+
+@pytest.mark.parametrize("conflicts", [0, 1])
+def test_a_stretch_of_shared_slots_without_a_conflict_resets_the_cost(
+        conflicts):
+    """The cost of the next conflict falls back to one batch when two
+    batches have shared the slots and nothing conflicted (a ramp of
+    jobs that do not want the same nodes keeps its overlap after a
+    storm has passed); a stretch with a conflict leaves it."""
+    from nomad_tpu.dispatch.pipeline import _Pending
+
+    server = make_server(num_schedulers=0)
+    try:
+        pipe = _unstarted_pipe(server)
+        with pipe._lock:
+            pipe._alone_next = 8  # what an earlier storm left
+            pipe._inflight = 1
+        pipe._admit(_Pending(mock.eval(), "tok-0"))
+        t, got = _cut_in_thread(pipe)
+        t.join(timeout=5.0)
+        assert got and pipe.stats()["in_flight"] == 2
+        if pipe._forming is not None:
+            pipe._forming.settle()
+        for _ in range(conflicts):
+            pipe._note_conflict()
+        pipe._release_slot([1])
+        assert pipe.stats()["alone_next"] == (16 if conflicts else 8)
+        pipe._release_slot([1])  # the pipeline is empty: stretch over
+        stats = pipe.stats()
+        assert stats["in_flight"] == 0
+        assert stats["alone_next"] == (16 if conflicts else 1)
+        assert stats["alone"] == (8 if conflicts else 0)
+    finally:
+        server.shutdown()

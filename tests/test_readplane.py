@@ -196,6 +196,47 @@ def test_mux_park_closes_check_then_park_race():
         mux.stop()
 
 
+def test_wake_loop_does_not_queue_behind_the_writers_lock():
+    """With the store's lock held by another thread (the FSM inside a
+    later txn), a commit's notify is still taken off `_pending`, its
+    scope index read and its continuation handed to the serve pool:
+    the ONE wake loop waits for no writer. (The serve itself may.)"""
+    store = StateStore()
+    j = mock.job()
+    store.upsert_job(1, j)
+    mux = ReadMux(lambda: store, workers=1)
+    mux.start()
+    committed, release = threading.Event(), threading.Event()
+
+    def fsm():
+        # An RLock: the commit runs, stamps and notifies inside the
+        # outer hold, which then stays taken as the next txn's would.
+        with store._lock:
+            store.upsert_job(2, j)
+            committed.set()
+            release.wait(10.0)
+
+    served = []
+    holder = threading.Thread(target=fsm, daemon=True)
+    try:
+        assert wait_until(lambda: mux._subscribed_id == store.store_id)
+        assert mux.park([watch.job(j.id)], 1, time.monotonic() + 30.0,
+                        served.append)
+        holder.start()
+        assert committed.wait(5.0)
+        assert wait_until(lambda: served == ["wake"], timeout=2.0), \
+            "the wake loop stood at the store's lock"
+        assert holder.is_alive()  # the lock was held all the while
+        with mux._cond:
+            assert mux._pending == []
+        assert mux.stats()["parked"] == 0
+    finally:
+        release.set()
+        holder.join(5.0)
+        mux.stop()
+    assert not holder.is_alive()
+
+
 # ----------------------------------------------------- HTTP long-polls
 
 

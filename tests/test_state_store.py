@@ -533,3 +533,171 @@ def test_alloc_journal_appends_are_amortised():
         assert len(j.ids) == len(j.indexes) <= _ALLOC_JOURNAL_CAP
     assert 4 <= replaced <= 6
     assert j.floor == j.indexes[0] - 1 or j.floor == j.indexes[0]
+
+
+# ----------------------------------- reads that take no lock (PR 41)
+
+
+def _seeded_store():
+    s = StateStore()
+    n, j, a = mock.node(), mock.job(), mock.alloc()
+    e = mock.eval()
+    a.job_id, a.node_id, a.eval_id, e.job_id = j.id, n.id, e.id, j.id
+    s.upsert_node(1, n)
+    s.upsert_job(2, j)
+    s.upsert_evals(3, [e])
+    s.upsert_allocs(4, [a])
+    return s, n, j, e, a
+
+
+_LOCK_FREE_READS = {
+    "latest_index": lambda s, n, j, e, a: s.latest_index() == 4,
+    "index": lambda s, n, j, e, a: s.index("evals") == 3,
+    "scope_index": lambda s, n, j, e, a: s.scope_index(
+        [watch.eval_item(e.id), watch.node(n.id)]) == 3,
+    "node_by_id": lambda s, n, j, e, a: s.node_by_id(n.id).id == n.id,
+    "job_by_id": lambda s, n, j, e, a: s.job_by_id(j.id).id == j.id,
+    "eval_by_id": lambda s, n, j, e, a: s.eval_by_id(e.id).id == e.id,
+    "alloc_by_id": lambda s, n, j, e, a: s.alloc_by_id(a.id).id == a.id,
+}
+
+
+@pytest.mark.parametrize("read", sorted(_LOCK_FREE_READS))
+def test_read_returns_while_a_writer_holds_the_lock(read):
+    """The index reads and the by-id reads queue behind nobody: with
+    the writers' lock held by another thread each returns at once."""
+    s, *rows = _seeded_store()
+    held, release = threading.Event(), threading.Event()
+
+    def hold():
+        with s._lock:
+            held.set()
+            release.wait(10.0)
+
+    holder = threading.Thread(target=hold, daemon=True)
+    holder.start()
+    assert held.wait(5.0)
+    got = []
+    reader = threading.Thread(
+        target=lambda: got.append(_LOCK_FREE_READS[read](s, *rows)),
+        daemon=True)
+    try:
+        reader.start()
+        reader.join(2.0)
+        assert not reader.is_alive(), f"{read} waited for the lock"
+        assert got == [True]
+    finally:
+        release.set()
+        holder.join(5.0)
+    assert not holder.is_alive()
+
+
+def test_indexes_never_decrease_for_a_lock_free_reader(monkeypatch):
+    """A writer commits rising indexes through several prunes of the
+    scope table while a reader, which takes no lock, watches a stamped
+    scope (pruned on the way: it falls back to the floor), a scope
+    never stamped (the floor alone) and the global index: none may
+    ever read lower than it did. The dict under the scope table also
+    checks the order that makes it so: the floor is at or above an
+    entry's index BEFORE the entry goes."""
+    import sys
+
+    monkeypatch.setattr(StateStore, "_SCOPE_CAP", 16)
+    s = StateStore()
+    floor_late = []
+
+    class Watched(dict):
+        def __delitem__(self, key):
+            if s._scope_floor < self[key]:
+                floor_late.append((key, self[key], s._scope_floor))
+            super().__delitem__(key)
+
+    s._scope_indexes = Watched()
+    fixed = mock.eval()
+    s.upsert_evals(1, [fixed])
+    stamped = [watch.eval_item(fixed.id)]
+    never = [watch.eval_item("never-written")]
+    stop = threading.Event()
+    went_down = []
+
+    def reader():
+        last = [0, 0, 0]
+        while not stop.is_set():
+            now = [s.scope_index(stamped), s.scope_index(never),
+                   s.latest_index()]
+            for k, (was, is_) in enumerate(zip(last, now)):
+                if is_ < was:
+                    went_down.append((k, was, is_))
+            last = now
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    t = threading.Thread(target=reader, daemon=True)
+    try:
+        t.start()
+        for index in range(2, 400):
+            s.upsert_evals(index, [mock.eval()])
+    finally:
+        stop.set()
+        t.join(10.0)
+        sys.setswitchinterval(interval)
+    assert not t.is_alive()
+    assert s._scope_floor > 1, "no prune took the fixed scope"
+    assert watch.eval_item(fixed.id) not in s._scope_indexes
+    assert floor_late == []
+    assert went_down == []
+    assert s.scope_index(stamped) == s._scope_floor
+
+
+def test_no_index_moves_before_a_txn_s_last_table_write():
+    """The lock-free readers rely on it: a txn writes ALL its rows
+    before it raises any index. The derived job status used to bump
+    the global and the jobs index in the middle of upsert_evals, once
+    a job whose status changed, with later evals still unwritten."""
+    s = StateStore()
+    j = mock.job()
+    e1, e2 = mock.eval(), mock.eval()
+    e1.job_id = e2.job_id = j.id
+    s.upsert_job(1, j)
+    s.upsert_evals(2, [e1])
+    seen = []
+
+    class Spy(dict):
+        def __setitem__(self, key, value):
+            seen.append((s.latest_index(), s.index("jobs")))
+            super().__setitem__(key, value)
+
+    table = s._tables["evals"]
+    table.data, table.shared = Spy(table.data), False
+    done = e1.copy()
+    done.status = e2.status = consts.EVAL_STATUS_COMPLETE
+    # e1 turning terminal flips the job to dead BEFORE e2 is written
+    s.upsert_evals(3, [done, e2])
+    assert s.job_by_id(j.id).status == consts.JOB_STATUS_DEAD
+    assert seen == [(2, 1), (2, 1)]
+    assert (s.latest_index(), s.index("jobs"), s.index("evals")) == (3, 3, 3)
+    assert s.scope_index([watch.job(j.id)]) == 3
+
+
+@pytest.mark.parametrize(
+    "read", ["node_by_id", "job_by_id", "eval_by_id", "alloc_by_id"])
+def test_by_id_read_shares_no_table(read):
+    """A point read on the store is one dict read of the live table:
+    it marks nothing shared, so the next write txn copies nothing. A
+    read that spans rows still takes a snapshot, which does."""
+    s, n, j, e, a = _seeded_store()
+    ids = {"node_by_id": n.id, "job_by_id": j.id, "eval_by_id": e.id,
+           "alloc_by_id": a.id}
+    for t in list(s._tables.values()) + list(s._indexes.values()):
+        t.shared = False
+    held = {name: t.data for name, t in s._tables.items()}
+    assert getattr(s, read)(ids[read]).id == ids[read]
+    assert getattr(s, read)("no-such-id") is None
+    assert not any(t.shared for t in s._tables.values())
+    assert not any(i.shared for i in s._indexes.values())
+    s.upsert_evals(5, [mock.eval()])
+    assert s._tables["evals"].data is held["evals"]  # written in place
+    assert [x.id for x in s.allocs_by_eval(e.id)] == [a.id]
+    assert all(t.shared for t in s._tables.values())
+    s.upsert_evals(6, [mock.eval()])
+    assert s._tables["evals"].data is not held["evals"]  # copied first
