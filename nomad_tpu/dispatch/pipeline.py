@@ -116,6 +116,11 @@ class PipelineSession(EvalSession):
         # arrived; every other way the eval can end goes through
         # settle_cohort.
         self.cohort = cohort
+        # This run replans a plan the applier partly rejected (the
+        # conflict requeue put the eval into this batch): the dense
+        # scheduler sends what is left of one to three asks to the host
+        # iterators, as it does an inline retry's, batch or no batch.
+        self.requeued = entry.requeues > 0
         # Evals created this attempt (blocked / rolling follow-ups):
         # once any exist, aborting the attempt would re-create them on
         # the requeued run — fall back to the inline retry instead.

@@ -53,7 +53,9 @@ __all__ = [
     "build_gang_state",
     "gang_distinct_hosts",
     "note_gang_dispatch",
+    "note_gang_rejected_whole",
     "note_gang_result",
+    "note_mixed_batch",
     "gang_stats",
     "reset_gang_stats",
     "spread_cap",
@@ -241,6 +243,22 @@ def note_gang_dispatch(gangs: int, moved: int) -> None:
         _stats["dispatched_gangs"] = (
             _stats.get("dispatched_gangs", 0) + gangs)
         _stats["moved_by_claims"] = _stats.get("moved_by_claims", 0) + moved
+
+
+def note_mixed_batch() -> None:
+    """Count one hand-over of a mixed pipeline batch: a plain dispatch
+    that started from the claims of its batch's gang dispatch
+    (scheduler/batcher.py _take_claims)."""
+    with _stats_lock:
+        _stats["mixed_batches"] = _stats.get("mixed_batches", 0) + 1
+
+
+def note_gang_rejected_whole(gangs: int) -> None:
+    """Count gangs the plan applier removed whole because a member's
+    node failed verification (server/plan_apply.py; one `gang.rejected`
+    span each)."""
+    with _stats_lock:
+        _stats["rejected_whole"] = _stats.get("rejected_whole", 0) + gangs
 
 
 def gang_stats() -> Dict[str, object]:

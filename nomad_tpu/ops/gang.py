@@ -40,7 +40,10 @@ plan on one snapshot and would all pick the same tightest rack, see
 each other's claims on device instead of colliding at the plan applier
 (the plain program's pre_resolve, for gangs). Inside a lane the pass is
 ``gang_placement_program``, which stays the plain reference of one
-lane. The batch axis rides the batcher's BATCH_BUCKETS.
+lane. The batch axis rides the batcher's BATCH_BUCKETS. The scan's final
+carry is an output, and its start may be another dispatch's carry: where
+the gangs' pipeline batch holds plain asks too, the plain lanes go first
+and the gangs start from what they claimed (scheduler/batcher.py).
 
 The host twin lives in nomad_tpu/gang/host.py; the plan applier's
 per-node verification plus the ``Plan.gang_groups`` atomicity leg
@@ -351,9 +354,13 @@ def batched_gang_placement_program(base: GangBase, lanes: GangLane,
     gang was rejected whole claims nothing, nor does a padding lane.
 
     Returns (choices [B, K] int32, scores [B, K] f32, info [B, 2]
-    int32): info[:, 0] is the lane's slice group, info[:, 1] is 1 where
-    an earlier lane's claims moved the gang off the group it would have
-    taken on the unclaimed base (slice mode only)."""
+    int32, util [N, 4], bw_used [N], ports_free [N]): info[:, 0] is the
+    lane's slice group, info[:, 1] is 1 where an earlier lane's claims
+    moved the gang off the group it would have taken on the unclaimed
+    base (slice mode only); the last three are the scan's final carry,
+    the base's columns after every lane's claims. They stay on the
+    device: the batcher starts the later dispatches on the same base
+    token from them (scheduler/batcher.py _publish_claims)."""
     n = base.util.shape[0]
 
     def lane_state(util, bw_used, ports_free, lane: GangLane):
@@ -395,9 +402,9 @@ def batched_gang_placement_program(base: GangBase, lanes: GangLane,
         )
         return carry, (choices, scores, jnp.stack([group, moved]))
 
-    _, (choices, scores, info) = jax.lax.scan(
+    carry, (choices, scores, info) = jax.lax.scan(
         body, (base.util, base.bw_used, base.ports_free), (lanes, keys))
-    return choices, scores, info
+    return (choices, scores, info, *carry)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))

@@ -30,6 +30,26 @@ and the gangs of one dispatch are one program (ops/gang.py
 batched_gang_placement_program) that carries each gang's claims to the
 next.
 
+A pipeline batch that holds gangs AND plain asks on one base token is
+two dispatches, ordered: PLAIN LANES FIRST. A plain program is short (an
+eval's few asks) and a gang program as long as its widest gang's member
+scan, and whoever goes second waits for the first on the device; with
+the claims carried no order loses a gang to the applier, so the order
+is the one that costs the batch's evals least (gangs first was built
+and measured first: PERF.md section 6, PR 42). The plain program's
+final carry (utilisation, bandwidth, free ports after every lane's
+claims; pre_resolve) stays on the device under the batch's token from
+the moment the program is issued (_publish_claims); a gang dispatch on
+that token waits for the plain dispatches queued or not yet issued
+ahead of it (_await_plain_ahead) and takes the carry as its starting
+state in place of the base's three columns (_take_claims), then
+publishes its own: no second copy of the base, no round trip of the
+claims through the host, and no wait for the first program's results
+either (the device runs the two in order). Every later dispatch on the
+token, of either kind, starts from the newest carry. A token no gang has
+touched is served as before: a batch of one kind is one dispatch on the
+base. The two programs stay two programs.
+
 A dispatch closes on what its requests carry. Requests of a pipeline
 batch carry that batch's cohort (open_cohort: the launch prologue has
 already counted them), and their dispatch is released the moment every
@@ -101,6 +121,13 @@ REQUEST_WAIT_SLICE_S = 0.1
 # dispatch goes (counted closed_by_cap) and late members dispatch on
 # arrival.
 COHORT_WAIT_MAX = 1.0
+# The bound on a gang dispatch's wait for the plain dispatches ahead of
+# it on its token (_await_plain_ahead): as long as a dispatcher waits for
+# another's upload of one base (_device_base), since the first plain
+# dispatch of a shape compiles inside its issue. On expiry the gang
+# dispatch goes on what has been published, blind to the plain lanes as
+# before PR 42, and is counted (claims_wait_expired).
+CLAIMS_WAIT_MAX = 30.0
 
 
 # ntalint residency manifest (analysis/residency.py): the ONE function
@@ -120,9 +147,10 @@ class _Cohort:
     units have neither arrived in place() nor been settled. Guarded by
     the lock of the batcher that opened it."""
 
-    __slots__ = ("pending", "deadline", "arrived", "capped")
+    __slots__ = ("size", "pending", "deadline", "arrived", "capped")
 
     def __init__(self, n: int):
+        self.size = n  # units announced: the evals of the batch
         self.pending = n
         # COHORT_WAIT_MAX from when it was opened, then from its first
         # arrival.
@@ -164,11 +192,36 @@ class CohortUnit:
         dispatch must not wait for it. Idempotent."""
         self.batcher.settle(self)
 
+    def batch_mates(self) -> int:
+        """The other evals of this unit's batch, while the unit has not
+        ridden a dispatch nor been settled; 0 after (an inline replan),
+        and for a batch of one."""
+        return self.batcher.batch_mates(self)
+
+
+class _Carry:
+    """What the dispatches on one base token have claimed so far: the
+    newest program's final carry, on the device. Guarded by the lock of
+    the batcher that keeps it."""
+
+    __slots__ = ("util", "bw_used", "ports_free", "kind", "lanes",
+                 "issued_at", "taken")
+
+    def __init__(self, carry, kind: str, lanes: int):
+        self.util, self.bw_used, self.ports_free = carry
+        self.kind = kind  # of the program: "plain" or "gang"
+        self.lanes = lanes
+        self.issued_at = time.monotonic()
+        # True once a dispatch of the OTHER kind has started from it
+        # (one batch.claims sample a hand-over).
+        self.taken = False
+
 
 class _Request:
     __slots__ = ("token", "base", "overlay", "compact", "asks", "key",
                  "delta", "event", "choices", "scores", "error", "span",
-                 "ready_at", "arrived_at", "unit", "topo", "info")
+                 "ready_at", "arrived_at", "unit", "topo", "info",
+                 "hand_over")
 
     def __init__(self, token, base, overlay, asks, key, delta=None,
                  compact=None, span=None, unit=None, topo=None):
@@ -188,6 +241,11 @@ class _Request:
         self.topo = topo
         # A gang's (slice group, moved by an earlier lane's claims).
         self.info = None
+        # Set at the pop on the first request of a batch that takes
+        # part in a hand-over of claims (_dispatch): every gang batch
+        # with a token, and a plain batch whose token gangs touch. It
+        # starts from its token's carry and publishes its own.
+        self.hand_over = False
         self.delta = delta  # (parent_token, changed_rows) or None
         self.span = span  # (eval_id, trace_id) for the device.solve span
         self.unit: Optional[CohortUnit] = unit
@@ -371,6 +429,17 @@ class PlacementBatcher:
         self._in_flight = 0  # guarded-by: _lock
         self._busy_until = 0.0  # guarded-by: _lock
         self._issued = 0  # guarded-by: _lock (nomad.dispatch ordinal)
+        # token -> what the dispatches on a base token that gangs
+        # touch have claimed so far. As many as bases are kept.
+        self._claims: "OrderedDict[object, _Carry]" = OrderedDict()  # guarded-by: _lock
+        # token -> the dispatches of a hand-over (id of the batch's
+        # first request) popped and not yet issued, by kind: a gang
+        # dispatch waits for the plain ones, and a plain dispatch popped
+        # while a gang one waits takes part.
+        self._plain_in_flight: Dict[object, set] = {}  # guarded-by: _lock
+        self._gangs_waiting: Dict[object, set] = {}  # guarded-by: _lock
+        self.mixed_batches = 0  # guarded-by: _lock (hand-overs taken)
+        self.claims_wait_expired = 0  # guarded-by: _lock
 
     def open_cohort(self, n: int) -> List[CohortUnit]:
         """Announce a batch of `n` place() calls (the dispatch pipeline's
@@ -393,6 +462,11 @@ class PlacementBatcher:
             if unit.take():
                 self._cohorts.discard(unit.cohort)
                 self._full.notify_all()
+
+    def batch_mates(self, unit: CohortUnit) -> int:
+        """CohortUnit.batch_mates."""
+        with self._lock:
+            return unit.cohort.size - 1 if unit.open else 0
 
     def place(self, state, asks, rng_key, config, span=None, cohort=None):
         """Submit one eval's placement; blocks until its batch's device
@@ -792,6 +866,69 @@ class PlacementBatcher:
             self._base_pending[token] = done
         return parent, rows, done
 
+    def _await_plain_ahead(self, token) -> None:
+        """The order of a mixed batch: a gang dispatch on `token` goes
+        after every plain dispatch on it that is queued or popped and
+        not yet issued (the cohort that released this dispatch released
+        those too), so that their claims are its starting state.
+        Bounded by CLAIMS_WAIT_MAX."""
+        deadline = time.monotonic() + CLAIMS_WAIT_MAX
+        with self._full:
+            while token in self._plain_in_flight or any(
+                    q and q[0].topo is None and q[0].token == token
+                    for q in self._queues.values()):
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    self.claims_wait_expired += 1
+                    return
+                self._full.wait(left)
+
+    def _publish_claims(self, first: _Request, carry, kind: str,
+                        lanes: int) -> None:
+        """The final carry of the program just ISSUED for the batch that
+        `first` heads, kept on the device under the batch's token for
+        the dispatches that follow on it. A gang dispatch that waits
+        behind a plain one goes on at once: what it starts from is this
+        program's output on the device, and the device runs the two in
+        order; nobody waits for these lanes' results to reach the
+        host."""
+        with self._full:
+            self._claims.pop(first.token, None)
+            while len(self._claims) >= DEVICE_BASE_CACHE:
+                self._claims.popitem(last=False)
+            self._claims[first.token] = _Carry(carry, kind, lanes)
+            self._off_the_list(self._plain_in_flight if first.topo is None
+                         else self._gangs_waiting, first)
+            self._full.notify_all()
+
+    @staticmethod
+    def _off_the_list(unissued: Dict[object, set],
+                      first: _Request) -> None:
+        """The dispatch that `first` heads holds nobody back any more:
+        issued, or failed before. `unissued` is the batcher's table of
+        its kind, handed over by a caller that holds the lock and
+        notifies after. Idempotent."""
+        ahead = unissued.get(first.token)
+        if ahead is not None:
+            ahead.discard(id(first))
+            if not ahead:
+                del unissued[first.token]
+
+    def _take_claims(self, token, kind: str):
+        """(what the dispatches before this one on `token` have claimed,
+        first) or None. `first`: the newest carry is of the other kind
+        and no dispatch of this kind has started from it yet (the
+        hand-over of one mixed batch, counted once)."""
+        with self._lock:
+            claims = self._claims.get(token)
+            if claims is None:
+                return None
+            first = claims.kind != kind and not claims.taken
+            if first:
+                claims.taken = True
+                self.mixed_batches += 1
+            return claims, first
+
     def _run_batch(self, batch: List[_Request], config) -> None:
         import jax
 
@@ -845,6 +982,19 @@ class PlacementBatcher:
         # Shared-base fast path: base cached on device, only the
         # per-eval payloads cross host->device this dispatch.
         shared = token is not None and all(r.token == token for r in batch)
+        # A token that gangs touch: these lanes start from what the
+        # dispatches before them on it claimed, and what they claim is
+        # where the gangs of their batch start (_dispatch set the mark
+        # at the pop).
+        hand_over = shared and batch[0].hand_over
+        claims = self._take_claims(token, "plain") if hand_over else None
+
+        def on_issued(out) -> None:
+            # The carry is the third output of the programs that
+            # resolve a batch's lanes in order; the vmapped ones have
+            # none to hand on.
+            if hand_over and config.pre_resolve:
+                self._publish_claims(batch[0], out[2], "plain", n_live)
         # Compact overlays: class verdicts + sparse patches + job
         # positions, expanded to the dense [B,N,G] masks ON DEVICE — a
         # few KB per eval instead of ~100KB x G.
@@ -855,7 +1005,16 @@ class PlacementBatcher:
 
         with trace.annotation("nomad.stack", lanes=n_live):
             keys = np.stack([r.key for r in padded])
-            asks = jax.tree.map(stacked, *[r.asks for r in padded])
+            lane_asks = [r.asks for r in padded]
+            if hand_over:
+                # The carry is handed on: a padding lane must claim
+                # nothing (a replica of the last request would place its
+                # asks again, after the real lanes, and the gangs would
+                # start from a fleet that much fuller than it is).
+                idle = batch[-1].asks._replace(
+                    active=np.zeros_like(batch[-1].asks.active))
+                lane_asks[n_live:] = [idle] * (pad_to - n_live)
+            asks = jax.tree.map(stacked, *lane_asks)
             if compact:
                 per_eval = jax.tree.map(
                     stacked, *[r.compact for r in padded])
@@ -868,7 +1027,11 @@ class PlacementBatcher:
         payload = (sum(x.nbytes for x in asks) + keys.nbytes
                    + sum(x.nbytes for x in per_eval))
         if compact:
-            fused = self._claim_fused_delta(token, batch[0].delta)
+            # The program that fuses the base's delta in returns the
+            # derived base, not its lanes' carry: a dispatch that hands
+            # claims on derives the base first.
+            fused = (None if hand_over
+                     else self._claim_fused_delta(token, batch[0].delta))
             if fused is not None:
                 # Base delta FUSED into this dispatch: the changed rows
                 # ride the call, the derived base comes back as device
@@ -912,15 +1075,13 @@ class PlacementBatcher:
                 finally:
                     publish()
             else:
-                dev, _ = self._device_base(
-                    token, batch[0].base, batch[0].delta)
+                dev = self._claimed_base(batch, claims)
                 choices, scores, times = self._issue(
                     batch, config, closed,
                     batched_placement_program_compact, *dev[:8],
-                    per_eval, asks, keys, config)
+                    per_eval, asks, keys, config, on_issued=on_issued)
         elif shared:
-            dev, _ = self._device_base(
-                token, batch[0].base, batch[0].delta)
+            dev = self._claimed_base(batch, claims)
             state = NodeState(
                 capacity=dev[0], sched_capacity=dev[1], util=dev[2],
                 bw_avail=dev[3], bw_used=dev[4], ports_free=dev[5],
@@ -929,15 +1090,49 @@ class PlacementBatcher:
             )
             choices, scores, times = self._issue(
                 batch, config, closed, batched_placement_program_overlay,
-                state, asks, keys, config)
+                state, asks, keys, config, on_issued=on_issued)
         else:
             choices, scores, times = self._issue(
                 batch, config, closed, batched_placement_program,
                 per_eval, asks, keys, config)
         self._count_dispatch(times, payload, compact, shared)
+        if claims is not None and claims[1]:
+            self._record_claims_carry(batch, claims[0], "plain", times[0])
         for i, req in enumerate(batch):
             req.choices = choices[i]
             req.scores = scores[i]
+
+    def _claimed_base(self, batch: List[_Request], claims) -> tuple:
+        """The resident base of the batch's token, its utilisation,
+        bandwidth and free-port columns replaced by what the dispatches
+        before this one on that token have claimed (_take_claims), if
+        any."""
+        first = batch[0]
+        dev, _ = self._device_base(first.token, first.base, first.delta)
+        if claims is None:
+            return dev
+        carry = claims[0]
+        return (dev[0], dev[1], carry.util, dev[3], carry.bw_used,
+                carry.ports_free, *dev[6:])
+
+    def _record_claims_carry(self, batch: List[_Request], claims: _Carry,
+                             kind: str, issued: float) -> None:
+        """The span batch.claims, one sample a hand-over, on the first
+        traced request of the dispatch that took the claims: from the
+        other kind's program's issue (or that request's arrival, if
+        later: the span lies inside its device.dispatch) to this
+        dispatch's issue."""
+        from ..gang import note_mixed_batch
+
+        note_mixed_batch()
+        req = next((r for r in batch if r.span), None)
+        if req is not None:
+            trace.record_span(
+                req.span[0], trace.STAGE_BATCH_CLAIMS,
+                min(max(claims.issued_at, req.arrived_at), issued), issued,
+                ann={f"{claims.kind}_lanes": claims.lanes,
+                     f"{kind}_lanes": len(batch)},
+                trace_id=req.span[1])
 
     def _count_dispatch(self, times, payload: int, compact: bool,
                         shared: bool) -> None:
@@ -1018,6 +1213,7 @@ class PlacementBatcher:
                             + [first.key] * (pad_to - n_live))
         payload = sum(x.nbytes for x in lanes) + keys.nbytes
         topo_key, topo_ids = first.topo
+        claims = None
         if first.token is None:
             f32 = np.float32
             node = tuple(np.asarray(x, f32) for x in first.base[:6]) \
@@ -1025,7 +1221,12 @@ class PlacementBatcher:
             topo = np.asarray(topo_ids, np.int32)
             payload += sum(x.nbytes for x in node) + topo.nbytes
         else:
-            dev, _ = self._device_base(first.token, first.base, first.delta)
+            # The plain lanes of this token go first, and what the
+            # dispatches before this one claimed is where these lanes
+            # start.
+            self._await_plain_ahead(first.token)
+            claims = self._take_claims(first.token, "gang")
+            dev = self._claimed_base(batch, claims)
             node = dev[:7]
             # Beside a base sharded over a mesh the column rides the
             # call uncommitted: jit lays it out with the base.
@@ -1033,15 +1234,23 @@ class PlacementBatcher:
                     if len(dev[0].sharding.device_set) > 1
                     else self._device_topology(topo_key, topo_ids))
         issued = []
+
+        def on_issued(out) -> None:
+            issued.append(out)
+            if first.token is not None:
+                self._publish_claims(first, out[3:6], "gang", n_live)
+
         with trace.annotation("nomad.gang", gangs=n_live,
                               mode=config.mode):
             choices, scores, times = self._issue(
                 batch, config, closed, batched_gang_placement_program_jit,
                 GangBase(*node, topo), lanes, keys, config,
-                on_issued=issued.append)
+                on_issued=on_issued)
             info = np.asarray(issued[0][2])
         t_info = time.monotonic()
         self._count_dispatch(times, payload, False, first.token is not None)
+        if claims is not None and claims[1]:
+            self._record_claims_carry(batch, claims[0], "gang", times[0])
         note_gang_dispatch(n_live, int(info[:n_live, 1].sum()))
         for i, req in enumerate(batch):
             req.choices = choices[i]
@@ -1222,6 +1431,7 @@ class PlacementBatcher:
         finally block counts us out and respawns if work remains."""
         batch: List[_Request] = []
         popped = False
+        handing = None  # the first request of a batch in a hand-over
         try:
             with self._lock:
                 sync_ema = self._sync_ema
@@ -1253,6 +1463,28 @@ class PlacementBatcher:
                     # would wedge those workers in event.wait().
                     self._queues[shape_key] = leftover
                 popped = True
+                if batch and batch[0].token is not None:
+                    first = batch[0]
+                    token = first.token
+                    if first.topo is not None:
+                        # A gang dispatch: the plain dispatches popped
+                        # from now to its issue take part.
+                        handing = first
+                        first.hand_over = True
+                        self._gangs_waiting.setdefault(
+                            token, set()).add(id(first))
+                    elif (token in self._claims
+                          or token in self._gangs_waiting
+                          or any(q and q[0].topo is not None
+                                 and q[0].token == token
+                                 for q in self._queues.values())):
+                        # A plain dispatch on a token that gangs touch
+                        # (claimed on, waiting, or queued: a mixed
+                        # batch's requests are all queued by now).
+                        handing = first
+                        first.hand_over = True
+                        self._plain_in_flight.setdefault(
+                            token, set()).add(id(first))
                 if batch:
                     self._closed_by[closed_by] += 1
                     for req in batch:
@@ -1295,7 +1527,14 @@ class PlacementBatcher:
             # dispatcher gets a fresh one. Zero-count keys are removed —
             # every new cluster-base token mints a new shape key, so a
             # long-running server would otherwise accrete dead entries.
-            with self._lock:
+            with self._full:
+                if handing is not None:
+                    # Had it failed before its issue, the dispatches
+                    # behind it would still be waiting.
+                    self._off_the_list(self._plain_in_flight
+                                 if handing.topo is None
+                                 else self._gangs_waiting, handing)
+                    self._full.notify_all()
                 remaining = self._dispatchers.get(shape_key, 1) - 1
                 spawn = bool(self._queues.get(shape_key)) and remaining == 0
                 if spawn:
@@ -1364,6 +1603,12 @@ class PlacementBatcher:
                 **{f"closed_by_{k}": v
                    for k, v in self._closed_by.items()},
                 "open_cohorts": len(self._cohorts),
+                # Mixed batches: dispatches that started from the
+                # claims of the other kind's dispatch, and gang
+                # dispatches that waited CLAIMS_WAIT_MAX out and went
+                # without.
+                "mixed_batches": self.mixed_batches,
+                "claims_wait_expired": self.claims_wait_expired,
             }
 
 

@@ -195,16 +195,30 @@ class BatchedTPUScheduler(GenericScheduler):
         from ..utils import metrics
 
         may_preempt = preemption_eligible(self.eval.priority)
-        if len(bulk) <= 3 and not may_preempt:
-            # Too few placements to amortize a dispatch — typical for
-            # the retry after a partially-rejected plan (1-3 conflicted
-            # allocs replanned on a FRESH snapshot, so the dense path
-            # would also pay a new matrix + base token). The host
+        # This eval's unit of its pipeline batch's cohort (a Planner
+        # outside the pipeline has none): place() marks it arrived, and
+        # its dispatch closes on the cohort, not on the timed window.
+        unit = getattr(self.planner, "cohort", None)
+        rides_batch = (unit is not None and unit.batch_mates() > 0
+                       and not getattr(self.planner, "requeued", False))
+        if len(bulk) <= 3 and not may_preempt and not rides_batch:
+            # One to three asks and no batch to ride: an eval that is
+            # alone (no cohort, or a cohort of one), or the retry after
+            # a partially-rejected plan (inline: its unit has ridden its
+            # dispatch; requeued by the pipeline into a later batch: its
+            # session says so; 1-3 conflicted allocs replanned on a
+            # FRESH snapshot, what the rule was written for). The host
             # iterators place a handful in low-ms with identical
-            # semantics. A preemption-eligible eval stays dense at ANY
-            # size: the host iterators cannot evict, and the retry
-            # after a partially-committed preemption plan is exactly a
-            # 1-3 ask replan that still needs the eviction leg.
+            # semantics. A first run of one to three asks whose batch
+            # holds other evals rides that batch's plain dispatch as a
+            # lane like any other: the dispatch goes anyway, and on the device it
+            # plans against its batch-mates' claims, the gangs' too
+            # (scheduler/batcher.py), where the host walk would plan on
+            # a snapshot none of them sees. A preemption-eligible eval
+            # stays dense at ANY size: the host iterators cannot evict,
+            # and the retry after a partially-committed preemption plan
+            # is exactly a 1-3 ask replan that still needs the eviction
+            # leg.
             # Counted: every route off the device is visible from
             # outside (/v1/metrics), like the fault and breaker routes.
             # In allocations and in evals: a rate per eval needs the
@@ -307,10 +321,6 @@ class BatchedTPUScheduler(GenericScheduler):
         # vmapped device dispatch instead of N serial calls, and evals
         # sharing a cluster base ride one cached device upload.
         _t0 = time.monotonic()
-        # This eval's unit of its pipeline batch's cohort (a Planner
-        # outside the pipeline has none): place() marks it arrived, and
-        # its dispatch closes on the cohort, not on the timed window.
-        unit = getattr(self.planner, "cohort", None)
         try:
             if chaos.enabled:
                 # 'error' = an injected device fault AT the breaker's

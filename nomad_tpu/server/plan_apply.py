@@ -489,6 +489,18 @@ class PlanApplier:
         if doomed:
             self.gangs_rejected += len(doomed)
             metrics.incr_counter(("plan", "gang_rejected"), len(doomed))
+            from ..gang import note_gang_rejected_whole
+
+            note_gang_rejected_whole(len(doomed))
+            now = time.monotonic()
+            for gk in doomed:
+                # One zero-length marker a gang rejected whole: how wide
+                # it was, and the first of its nodes that failed.
+                trace.record_span(
+                    plan.eval_id, trace.STAGE_GANG_REJECTED, now, now,
+                    ann={"width": len(plan.gang_groups.get(gk, ())),
+                         "node": min(gang_nodes[gk] & rejected_nodes)},
+                    create=False)
         if rejected:
             self.plans_rejected += 1
             self.nodes_rejected += rejected

@@ -28,6 +28,7 @@ from .span import (  # noqa: F401
     STAGE_ALLOC_UPSERT,
     STAGE_API_REGISTER,
     STAGE_BASE_DELTA,
+    STAGE_BATCH_CLAIMS,
     STAGE_BROKER_WAIT,
     STAGE_DEFRAG_SOLVE,
     STAGE_DEVICE_DISPATCH,
@@ -40,6 +41,7 @@ from .span import (  # noqa: F401
     STAGE_EVAL_UPDATE,
     STAGE_FEASIBILITY_BUILD,
     STAGE_GANG_BUILD,
+    STAGE_GANG_REJECTED,
     STAGE_GANG_SELECT,
     STAGE_GANG_SOLVE,
     STAGE_IDLE_BATCH_WAIT,

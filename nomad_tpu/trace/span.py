@@ -78,6 +78,19 @@ STAGE_GANG_SOLVE = "gang.solve"            # inside the gang's
 #   on the host (ends in a sync; ann: gangs in the dispatch). Wraps
 #   the dispatch's device.solve, which ends when the placements are
 #   back; the slice and claim readings follow
+STAGE_GANG_REJECTED = "gang.rejected"      # inside plan.evaluate: a
+#   zero-length marker, one a gang the applier removed WHOLE because a
+#   member's node failed verification (server/plan_apply.py; ann: width
+#   = the gang's members, node = the first of its nodes that failed).
+#   Its sample count is the gangs rejected whole
+STAGE_BATCH_CLAIMS = "batch.claims"        # inside the device.dispatch
+#   of an eval whose pipeline batch held gangs and plain asks: from the
+#   first program's issue (the plain lanes') to the second's (the
+#   gangs'), while the first's claims wait on the device to be the
+#   second's starting state (scheduler/batcher.py
+#   _record_claims_carry; ann: gang_lanes, plain_lanes). One sample a
+#   hand-over, on the taking dispatch's first traced request; the
+#   interval was device.dispatch.self before
 STAGE_DEFRAG_SOLVE = "defrag.solve"        # one defrag-loop round's
 #   warm-started global relaxation solve + move extraction
 #   (nomad_tpu/defrag; ann: movable, moves, gain, warm, solve_ms) —
@@ -183,10 +196,12 @@ ALL_STAGES = (
     STAGE_GANG_SELECT,
     STAGE_GANG_BUILD,
     STAGE_GANG_SOLVE,
+    STAGE_BATCH_CLAIMS,
     STAGE_DEFRAG_SOLVE,
     STAGE_PLAN_SUBMIT,
     STAGE_PLAN_QUEUE_WAIT,
     STAGE_PLAN_EVALUATE,
+    STAGE_GANG_REJECTED,
     STAGE_PLAN_COMMIT,
     STAGE_ALLOC_UPSERT,
     STAGE_EVAL_UPDATE,

@@ -64,12 +64,23 @@ class WorkPool:
     def submit(self, fn: Callable, *args) -> PoolFuture:
         fut = PoolFuture()
         self._queue.put((fn, args, fut))
+        self._spawn_if_stranded()
+        return fut
+
+    def _spawn_if_stranded(self) -> None:
+        """One more worker when queued work exceeds idle capacity (not
+        just idle==0: erring toward spawning is safe, the ceiling bounds
+        it). Called by submit() and by every worker that has just taken
+        an item: a worker between its get() and its idle decrement still
+        counts as idle, so a submit in that gap sees capacity that is
+        already spoken for, spawns nothing, and its item would sit
+        behind tasks that block. The dispatch pipeline's do: an eval of
+        a batch blocks in the batcher until its whole batch has arrived,
+        so one entry stranded here held its batch-mates for the
+        batcher's COHORT_WAIT_MAX, a second (PERF.md section 7:
+        `closed_by_cap` 1-2 a run; PR 42 found the gap). The worker's
+        own look, after its decrement, closes it."""
         with self._lock:
-            # Spawn when queued work exceeds idle capacity (not just
-            # idle==0: a worker between get() and its idle decrement
-            # would otherwise suppress a needed spawn and strand this
-            # item behind a long-blocking task). Erring toward spawning
-            # is safe — the ceiling bounds it.
             self._threads = [t for t in self._threads if t.is_alive()]
             if self._queue.qsize() > self._idle and len(self._threads) < self.size:
                 # Thread.start can fail under OS thread pressure —
@@ -103,7 +114,6 @@ class WorkPool:
                         # until the next is_alive() prune.
                         self._threads.append(t)
                         break
-        return fut
 
     def _work(self) -> None:
         while True:
@@ -114,6 +124,7 @@ class WorkPool:
             finally:
                 with self._lock:
                     self._idle -= 1
+            self._spawn_if_stranded()
             fn, args, fut = item
             try:
                 fut._result = fn(*args)

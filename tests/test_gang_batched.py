@@ -17,7 +17,8 @@ The contract under test:
   like any other: counted, closed by its cohort, served from the
   resident base, and traced (gang.select with its parts);
 - a pipeline batch of gang evals and plain evals places both, each
-  through its own program.
+  through its own program: the gangs' dispatch first, the plain lanes
+  from its carried claims (tests/test_mixed_batch.py has the rest).
 """
 
 import random
@@ -149,7 +150,9 @@ def dispatch(fa, lanes, keys, config):
     base = GangBase(**{f: fa[f] for f in GangBase._fields})
     out = batched_gang_placement_program_jit(
         base, stacked, np.stack(keys + [keys[0]] * extra), config)
-    return (lanes,) + tuple(np.asarray(x) for x in out)
+    # choices, scores, info; the carry after them is
+    # tests/test_mixed_batch.py's
+    return (lanes,) + tuple(np.asarray(x) for x in out[:3])
 
 
 def reference(fa, lanes, keys, config):
@@ -550,6 +553,7 @@ def test_a_mixed_batch_places_gangs_and_plain_jobs_each_by_its_program():
         plain = [filler_job(f"mix-p{i}", 8) for i in range(3)]
         before = get_batcher().stats()
         reset_gang_stats()
+        trace.get_recorder().reset()
         run_as_one_batch(server, [gangs[0], plain[0], gangs[1], plain[1],
                                   gangs[2], plain[2]])
         after = get_batcher().stats()
@@ -562,6 +566,17 @@ def test_a_mixed_batch_places_gangs_and_plain_jobs_each_by_its_program():
         assert after["dispatches"] - before["dispatches"] == 2
         assert after["overlay_dispatches"] - before["overlay_dispatches"] == 2
         assert after["open_cohorts"] == 0
+        # in that order: the plain lanes started from the gangs' claims,
+        # which stayed on the device (one hand-over, one batch.claims)
+        assert after["mixed_batches"] - before["mixed_batches"] == 1
+        assert stats["mixed_batches"] == 1
+        spans = [s for t in trace.get_recorder().traces(limit=50)
+                 for s in t["spans"]]
+        carried = [s for s in spans if s["name"] == trace.STAGE_BATCH_CLAIMS]
+        assert [s["annotations"] for s in carried] == [
+            {"gang_lanes": 3, "plain_lanes": 3}]
+        assert carried[0]["parent"] == trace.STAGE_DEVICE_DISPATCH
+        assert server.plan_applier.stats()["gangs_rejected"] == 0
         by_id = {n.id: n for n in nodes}
         for job in gangs:
             live = live_allocs(server, job)

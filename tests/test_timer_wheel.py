@@ -242,3 +242,41 @@ def test_pool_cold_spawn_failure_leaves_item_queued(monkeypatch):
     second = pool.submit(order.append, "second")
     assert first.wait(5.0) and second.wait(5.0)
     assert order == ["first", "second"]
+
+
+def test_pool_item_submitted_in_a_workers_idle_gap_is_not_stranded():
+    """A worker between its get() and its idle decrement still counts
+    as idle: a submit in that gap sees capacity that is spoken for and
+    spawns nothing. The worker's own look after its decrement spawns the
+    worker the item needs; before PR 42 the item sat behind the first
+    task for as long as that blocked (in the dispatch pipeline: an eval
+    stranded while its batch-mates waited the batcher's cap out for
+    it)."""
+    import queue
+
+    in_gap, leave_gap = threading.Event(), threading.Event()
+
+    class GapQueue(queue.SimpleQueue):
+        def get(self, *a, **kw):
+            item = super().get(*a, **kw)
+            if item[1] == ("first",):
+                in_gap.set()            # taken, idle not yet decremented
+                leave_gap.wait(10.0)
+            return item
+
+    pool = WorkPool(4, name="p-gap")
+    pool._queue = GapQueue()
+    pool.submit(lambda: None).result(5.0)       # one worker, now idle
+    deadline = time.monotonic() + 5.0
+    while pool._idle != 1 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    release = threading.Event()
+    first = pool.submit(lambda tag: release.wait(10.0), "first")
+    assert in_gap.wait(5.0)
+    second = pool.submit(lambda tag: tag, "second")
+    assert pool.worker_count() == 1             # the gap hid the need
+    leave_gap.set()
+    assert second.result(5.0) == "second"       # while `first` blocks
+    assert not first.done()
+    release.set()
+    assert first.result(5.0) is True
