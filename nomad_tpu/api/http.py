@@ -1445,6 +1445,7 @@ class HTTPServer:
             "threads": len(threading.enumerate()),
             "gc_counts": gc.get_count(),
             "gc_objects": len(gc.get_objects()),
+            "gc_frozen": gc.get_freeze_count(),
             "max_rss_kb": ru.ru_maxrss,
             "user_cpu_s": ru.ru_utime,
             "system_cpu_s": ru.ru_stime,

@@ -20,6 +20,11 @@ Three instruments, one process-global Profiler:
   width and duration of thread pile-ups at the batch boundary — the
   specific pathology ROADMAP open item 1 names.
 
+Beside them, and no instrument only: collector.py, the program's policy
+for the interpreter's cyclic collector (a long pass that freed next to
+nothing has its survivors frozen); its hook counts every pass, the
+sampler's thread feeds them to the recorder and freezes.
+
 Exposure: ``server.stats()["profile"]``, ``/v1/agent/profile`` (with
 ``?lock=`` / ``?thread=`` drill-down), ``/v1/metrics`` (Prometheus
 histograms/gauges), lock-wait annotations on trace spans, and the
@@ -45,6 +50,7 @@ from ..utils.metrics import (
     hist_bucket_upper,
     hist_percentile,
 )
+from .collector import get_collector
 from .locks import (  # noqa: F401
     ProfiledCondition,
     ProfiledLock,
@@ -386,6 +392,7 @@ class Profiler:
             "enabled": self.enabled,
             "locks": self.lock_table(),
             "gil": self.gil.stats(),
+            "gc": get_collector().stats(),
             "runq": self.runq_table(),
             "convoys": self.convoy_table(),
             "timeline": self.timeline.stats(),

@@ -16,6 +16,10 @@ NOT on the record-path manifest. Each overshoot also goes to the flight
 recorder's stage table as ``runtime.gil_wait`` (one ``observe_stage`` a
 sample), which is where the benchmark reads it (``gil_wait_p50_ms``,
 ``gil_wait_p95_ms``): with the observatory off the row has no samples.
+The same thread serves the collector's policy (collector.py), whose hook
+may take no lock: each tick it drains the passes the hook queued into
+the rows ``runtime.gc_pause`` and ``runtime.gc_full_pause`` and performs
+the freeze the hook asked for.
 
 Complementing the sampler, per-worker *run-queue delay* is stamped at
 the two points where ready work waits for a thread to actually run
@@ -69,9 +73,16 @@ class GilSampler:
     def _run(self) -> None:
         # Here and not at the top: trace/recorder.py imports this
         # package for its locks.
-        from ..trace import STAGE_RUNTIME_GIL_WAIT, get_recorder
+        from ..trace import (
+            STAGE_RUNTIME_GC_FULL_PAUSE,
+            STAGE_RUNTIME_GC_PAUSE,
+            STAGE_RUNTIME_GIL_WAIT,
+            get_recorder,
+        )
+        from .collector import get_collector
 
         observe_stage = get_recorder().observe_stage
+        collector = get_collector()
         stop = self._stop
         while True:
             # Re-read per tick: configure(sampler_interval=...) on a
@@ -87,6 +98,13 @@ class GilSampler:
             self.hist.observe(overshoot_ms)
             self.samples += 1
             observe_stage(STAGE_RUNTIME_GIL_WAIT, overshoot_ms)
+            # The collector's hook may neither lock nor freeze
+            # (collector.py): this thread does both halves for it.
+            for generation, ms in collector.drain():
+                observe_stage(STAGE_RUNTIME_GC_PAUSE, ms)
+                if generation == 2:
+                    observe_stage(STAGE_RUNTIME_GC_FULL_PAUSE, ms)
+            collector.freeze_if_asked()
 
     def stats(self) -> dict:
         out = self.hist.stats()

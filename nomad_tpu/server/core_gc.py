@@ -5,6 +5,11 @@ core-job evals (leader.go GC timers); a worker dequeues them like any
 other eval and this scheduler reaps terminal evals/allocs, dead jobs,
 and down nodes older than their thresholds, using the TimeTable to map
 time thresholds to raft indexes.
+
+The eval-GC tick and the forced pass end with the interpreter's own
+collection of what was frozen (profile/collector.py `settle`): the records
+reaped here, and any cycle that died among frozen objects since the last
+tick, go then; that is the bound on a frozen dead cycle's life.
 """
 
 from __future__ import annotations
@@ -13,6 +18,7 @@ import logging
 import time
 from typing import List, Optional
 
+from ..profile.collector import get_collector
 from ..structs import Evaluation, consts
 
 
@@ -29,6 +35,7 @@ class CoreScheduler:
         kind = ev.job_id
         if kind == consts.CORE_JOB_EVAL_GC:
             self._eval_gc(force=False)
+            get_collector().settle()
         elif kind == consts.CORE_JOB_JOB_GC:
             self._job_gc(force=False)
         elif kind == consts.CORE_JOB_NODE_GC:
@@ -37,6 +44,7 @@ class CoreScheduler:
             self._eval_gc(force=True)
             self._job_gc(force=True)
             self._node_gc(force=True)
+            get_collector().settle()
         else:
             self.logger.error("core sched: unknown job %r", kind)
 

@@ -13,6 +13,7 @@ import time
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional
 
+from ..profile.collector import get_collector
 from ..state import PeriodicLaunch, StateStore
 from ..utils import metrics
 from ..structs import Allocation, Evaluation, Job, Node, consts
@@ -301,6 +302,9 @@ class FSM:
     def restore(self, data: dict) -> None:
         self.state = StateStore.restore(data)
         self.last_applied_index = self.state.latest_index()
+        # A restored snapshot is the fleet by another door: no pass
+        # will free it (profile/collector.py).
+        get_collector().settle()
 
 
 class DevLog:

@@ -37,6 +37,7 @@ from ..kernels.quality import get_board as _quality_board
 from ..migrate import churn_stats as _churn_stats
 from ..models.resident import device_state_stats as _device_state_stats
 from ..profile import get_profiler as _get_profiler
+from ..profile.collector import get_collector as _get_collector
 from .config import ServerConfig
 from .core_gc import CoreScheduler
 from .fsm import FSM, DevLog
@@ -168,6 +169,7 @@ class Server:
         )
         self._leader = False
         self._shutdown = False
+        self._collector_installed = False
         self._gc_threads: List[threading.Timer] = []
         # Multi-server mode (start_with_raft): consensus node + peer
         # registry for leader-routed operations (the reference forwards
@@ -240,6 +242,7 @@ class Server:
 
     def start(self) -> None:
         """Dev mode: single server, immediately leader."""
+        self._install_collector()
         for i in range(self.config.num_schedulers):
             worker = Worker(self, i)
             self.workers.append(worker)
@@ -250,6 +253,14 @@ class Server:
             self.read_mux.start()
         self.establish_leadership()
         self._start_telemetry()
+
+    def _install_collector(self) -> None:
+        """The interpreter's collector is the process's: its policy
+        (profile/collector.py) is installed by every server that starts
+        and removed when the last one shuts down."""
+        if not self._collector_installed:
+            self._collector_installed = True
+            _get_collector().install()
 
     def _start_telemetry(self) -> None:
         """Periodic broker/plan-queue/heartbeat gauges (the reference
@@ -263,6 +274,9 @@ class Server:
         def emit():
             while not self._telemetry_stop.wait(self.config.telemetry_interval):
                 try:
+                    # The sampler's thread does this 200 times a second
+                    # while the observatory is on; with it off, here.
+                    _get_collector().freeze_if_asked()
                     # Dispatch-pipeline gauges are per-server (the
                     # pipeline runs on followers too, forwarding plans
                     # to the leader), so they emit before the
@@ -389,6 +403,7 @@ class Server:
         compaction (reference: raft-boltdb + fsm.go snapshots)."""
         from .raft import RaftLog, RaftNode
 
+        self._install_collector()
         storage = None
         if data_dir:
             from .raft_storage import RaftStorage
@@ -547,6 +562,9 @@ class Server:
             w.stop()
         if self.vault is not None and hasattr(self.vault, "stop"):
             self.vault.stop()  # own-token renewal loop
+        if self._collector_installed:
+            self._collector_installed = False
+            _get_collector().uninstall()
 
     def is_leader(self) -> bool:
         return self._leader

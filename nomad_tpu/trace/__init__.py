@@ -64,6 +64,8 @@ from .span import (  # noqa: F401
     STAGE_READ_SERVE,
     STAGE_READ_SERVE_CPU,
     STAGE_READ_SERVE_WAIT,
+    STAGE_RUNTIME_GC_FULL_PAUSE,
+    STAGE_RUNTIME_GC_PAUSE,
     STAGE_RUNTIME_GIL_WAIT,
     STAGE_SCHED_PROCESS,
 )

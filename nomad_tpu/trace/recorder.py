@@ -78,8 +78,8 @@ ACTIVE_PER_STRIPE = 256  # in-flight traces per stripe before eviction
 TAIL_MIN_SAMPLES = 64    # e2e samples before tail-keep engages
 MAX_STAGES = 96          # distinct stage histograms a stripe holds
 #   (instrumentation-bounded: 28 eval stages, each with a `.self` twin
-#   at worst, plus eval.uncovered, device.idle.* and the 15 rows of the
-#   client's path, span.py CLIENT_PATH_STAGES, is 75)
+#   at worst, plus eval.uncovered, device.idle.* and the 17 rows of the
+#   client's path, span.py CLIENT_PATH_STAGES, is 77)
 
 # ntalint record-path manifest (analysis/robustness.py
 # record-path-blocking): every function reachable from these — the

@@ -162,6 +162,10 @@ STAGE_READ_DELIVER = "read.deliver"        # the commit's notify (or
 # The interpreter's scheduling delay (profile/sampler.py): how late a
 # thread that asked for a 5 ms sleep woke, 200 samples a second.
 STAGE_RUNTIME_GIL_WAIT = "runtime.gil_wait"
+# The collector's passes (profile/collector.py), every thread stopped for
+# their length: the hook queues them, the sampler's thread feeds them.
+STAGE_RUNTIME_GC_PAUSE = "runtime.gc_pause"            # every pass
+STAGE_RUNTIME_GC_FULL_PAUSE = "runtime.gc_full_pause"  # generation 2
 CLIENT_PATH_STAGES = tuple(
     stage for names in HTTP_STAGES.values() for stage in names
 ) + (
@@ -172,6 +176,8 @@ CLIENT_PATH_STAGES = tuple(
     STAGE_READ_SERVE_WAIT,
     STAGE_READ_DELIVER,
     STAGE_RUNTIME_GIL_WAIT,
+    STAGE_RUNTIME_GC_PAUSE,
+    STAGE_RUNTIME_GC_FULL_PAUSE,
 )
 
 ALL_STAGES = (
