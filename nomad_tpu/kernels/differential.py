@@ -677,6 +677,190 @@ def build_gang_scenario(seed: int):
     return seed_state, job
 
 
+# ---------------------------------------------------------------------
+# One snapshot, several dispatches: the hand-over of claims between the
+# plain dispatches of one base token (scheduler/batcher.py)
+
+HANDOVER_SEEDS = range(9400, 9408)
+
+
+def build_handover_scenario(seed: int):
+    """(state store, jobs) for one case of the hand-over rig: a fleet
+    of one-core slots in which a few HOT machines are nearly full (so
+    that BestFit ranks them far above the rest, whatever the tie-break
+    noise draws) beside cold ones that are nearly empty, and four jobs
+    whose asks fall into three ask rungs (8, 16, 32) and two
+    PlacementConfigs (three `batch` jobs and a `service` one): four
+    queues of the batcher on one snapshot. The jobs fit the fleet
+    together; each of them alone fits the hot machines' free slots or
+    takes them all, so dispatches that plan blind to each other MUST
+    put more on the hot machines than they hold. One job asks for a
+    dynamic port and bandwidth."""
+    from .. import mock
+    from ..structs import consts
+    from ..structs.resources import NetworkResource, Port
+
+    rng = random.Random(seed)
+    n_hot, n_cold = rng.choice([6, 8]), rng.choice([14, 16])
+    slot_cpu, slot_mem = 1000, 1024
+    nodes, fillers = [], []
+    filler = mock.job()
+    filler.id = f"handover-filler-{seed}"
+    for i in range(n_hot + n_cold):
+        node = mock.node()
+        node.reserved = None
+        node.resources.cpu = 10 * slot_cpu
+        node.resources.memory_mb = 10 * slot_mem
+        node.compute_class()
+        nodes.append(node)
+        # hot: 8 of 10 slots taken; cold: 1 of 10
+        for _ in range(8 if i < n_hot else 1):
+            a = mock.alloc()
+            a.node_id, a.job_id, a.job = node.id, filler.id, filler
+            a.desired_status = consts.ALLOC_DESIRED_RUN
+            a.client_status = consts.ALLOC_CLIENT_RUNNING
+            for tr in a.task_resources.values():
+                tr.cpu, tr.memory_mb, tr.networks = slot_cpu, slot_mem, []
+            a.resources = None
+            fillers.append(a)
+    rng.shuffle(nodes)
+
+    def job_of(name: str, kind: str, count: int, port: bool):
+        job = mock.job()
+        job.id = f"handover-{seed}-{name}"
+        job.type = kind
+        job.datacenters = [nodes[0].datacenter]
+        job.constraints = []
+        tg = job.task_groups[0]
+        tg.count = count
+        tg.constraints = []
+        tg.ephemeral_disk.size_mb = 0
+        task = tg.tasks[0]
+        task.resources.cpu, task.resources.memory_mb = slot_cpu, slot_mem
+        task.resources.disk_mb = 0
+        task.resources.networks = [NetworkResource(
+            mbits=100, dynamic_ports=[Port("http", 0)])] if port else []
+        return job
+
+    jobs = [job_of("few", "batch", rng.randint(4, 7), False),
+            job_of("some", "batch", rng.randint(10, 15), True),
+            job_of("many", "batch", rng.randint(24, 30), False),
+            job_of("app", "service", rng.randint(3, 5), False)]
+    rng.shuffle(jobs)
+    from ..scheduler.testing import Harness, seed_harness_cluster
+
+    h = Harness(seed=seed)
+    seed_harness_cluster(h, nodes=nodes, allocs=fillers, jobs=jobs)
+    store = h.state
+    return store, jobs
+
+
+def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False):
+    """[(job, matrix, choices)] of `jobs` placed through
+    PlacementBatcher.place on the snapshot `snap` as ONE pipeline batch
+    (one cohort, every lane from a thread of its own, as the dispatch
+    pipeline's workers call it), each with the PlacementConfig the dense
+    scheduler would build. `blind`: every job's dispatch on a batcher
+    of its own, so that none starts from another's claims (what two
+    queues of one token were to each other before the hand-over).
+    Returns the lanes in the order the batcher's rule dispatches them
+    (ask rung, shortest first), and the batcher (None where blind)."""
+    import threading
+
+    from ..models.matrix import ClusterMatrix
+    from ..ops.binpack import host_prng_key, make_asks
+    from ..scheduler.batcher import PlacementBatcher
+    from ..scheduler.tpu import build_placement_config
+
+    shared = None if blind else PlacementBatcher(window=0.0)
+    units = [None] * len(jobs) if blind else shared.open_cohort(len(jobs))
+    lanes, errors = [], []
+
+    def lane(i, job, unit):
+        try:
+            count = job.task_groups[0].count
+            matrix = ClusterMatrix(snap, job, rows_floor=count)
+            placements = [0] * count
+            arrays = matrix.build_asks(placements)
+            config = build_placement_config(
+                job.type == "batch", True, "greedy", placements, arrays)
+            batcher = shared or PlacementBatcher(window=0.0)
+            choices, _scores = batcher.place(
+                matrix, make_asks(*arrays), host_prng_key(seed * 31 + i),
+                config, cohort=unit)
+            lanes.append((job, matrix, [int(c) for c in choices[:count]]))
+        except BaseException as e:  # noqa: BLE001 - reported by the caller
+            errors.append(e)
+
+    threads = [threading.Thread(target=lane, args=(i, job, unit))
+               for i, (job, unit) in enumerate(zip(jobs, units))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120.0)
+    if errors:
+        raise errors[0]
+    lanes.sort(key=lambda row: row[0].task_groups[0].count)
+    return lanes, shared
+
+
+def judge_shared_snapshot(snap, lanes, seed=None) -> List[str]:
+    """Violations in the UNION of what several lanes chose on one
+    snapshot, by the host's own tools: on every node the lanes touched,
+    its live allocations plus every chosen instance must pass
+    `allocs_fit` (cpu, memory, disk, iops, bandwidth, port collisions),
+    each instance's network ask must get an offer from the node's
+    NetworkIndex given everything placed there before it (no port
+    twice, bandwidth within the device's), and an ask the device left
+    unplaced is a violation too: the scenario's jobs fit together."""
+    from ..structs import Allocation, NetworkIndex, Resources, allocs_fit, consts
+
+    tag = f"seed {seed}: " if seed is not None else ""
+    bad: List[str] = []
+    rng = random.Random(seed)
+    proposed: Dict[str, list] = {}
+    indexes: Dict[str, NetworkIndex] = {}
+    for job, matrix, choices in lanes:
+        tg = job.task_groups[0]
+        for k, choice in enumerate(choices):
+            if not 0 <= choice < matrix.n_real:
+                bad.append(f"{tag}{job.id}[{k}]: no node chosen")
+                continue
+            node = matrix.nodes[choice]
+            if node.id not in proposed:
+                proposed[node.id] = list(
+                    snap.allocs_by_node_terminal(node.id, False))
+                idx = NetworkIndex()
+                idx.set_node(node)
+                idx.add_allocs(proposed[node.id])
+                indexes[node.id] = idx
+            task_resources = {}
+            for task in tg.tasks:
+                res = task.resources.copy()
+                if res.networks:
+                    offer, _err = indexes[node.id].assign_network(
+                        res.networks[0], rng)
+                    if offer is None:
+                        bad.append(f"{tag}{job.id}[{k}]: no network offer "
+                                   f"left on {node.id}")
+                        continue
+                    indexes[node.id].add_reserved(offer)
+                    res.networks = [offer]
+                task_resources[task.name] = res
+            proposed[node.id].append(Allocation(
+                id=f"{job.id}[{k}]", node_id=node.id, job_id=job.id, job=job,
+                task_group=tg.name, task_resources=task_resources,
+                shared_resources=Resources(
+                    disk_mb=tg.ephemeral_disk.size_mb),
+                desired_status=consts.ALLOC_DESIRED_RUN,
+                client_status=consts.ALLOC_CLIENT_PENDING))
+    for node_id, allocs in sorted(proposed.items()):
+        fit, dim, _ = allocs_fit(snap.node_by_id(node_id), allocs)
+        if not fit:
+            bad.append(f"{tag}node {node_id} overcommitted: {dim}")
+    return bad
+
+
 GANG_SEEDS = range(9200, 9208)
 
 

@@ -40,7 +40,12 @@ from ..structs.resources import Resources
 # (+63% on every transfer and scan row).
 BUCKETS = [128, 256, 512, 1024, 2048, 4096, 6144, 8192, 10240, 12288,
            16384, 20480, 24576, 32768]
-ASK_BUCKETS = [8, 16, 32, 64, 128, 256, 512, 1024]
+# The ask ladder: the padded length of an eval's ask axis, one compiled
+# program a rung (and a batch bucket). 2,048 is a NAMED rung: past the
+# ladder's top bucket_size rounds up to the next multiple of the top,
+# each multiple a compile of its own, so a count the traffic holds
+# belongs on the ladder (a task of 2,000 instances: alibaba-colo-4k).
+ASK_BUCKETS = [8, 16, 32, 64, 128, 256, 512, 1024, 2048]
 # Compact-overlay padding buckets (each distinct size is one compile):
 # class count, feasibility-patch rows, job alloc positions. Overlays
 # larger than the top bucket fall back to the dense [N,G] overlay. A
@@ -49,7 +54,7 @@ ASK_BUCKETS = [8, 16, 32, 64, 128, 256, 512, 1024]
 # 80k nodes, and a lane's verdicts are then 2 KB x G.
 CLASS_BUCKETS = [8, 32, 128, 512, 2048]
 PATCH_BUCKETS = [16, 64, 256]
-JOBPOS_BUCKETS = [16, 64, 256, 1024]
+JOBPOS_BUCKETS = [16, 64, 256, 1024, 2048]
 
 # Job-independent cluster base, cached across evaluations: rebuilding
 # the [N,4] utilization matrices is O(N x allocs) host work per eval,
@@ -1315,7 +1320,8 @@ class ClusterMatrix:
 
     def __init__(self, state, job: Job, plan: Optional[Plan] = None,
                  nodes: Optional[List[Node]] = None,
-                 plan_overlay: bool = False, ask_floor: int = 0):
+                 plan_overlay: bool = False, ask_floor: int = 0,
+                 rows_floor: int = 0):
         """`plan_overlay`: where the plan already holds placements or
         stops, take the node state from the CACHED base of the snapshot
         and re-derive only the rows the plan touches, instead of a
@@ -1326,14 +1332,19 @@ class ClusterMatrix:
         `ask_floor`: pad the asks and the job's alloc positions as for
         that many asks at least. An eval that retries on the dense path
         gives its first attempt's count, so that the retry (fewer asks,
-        more positions) runs the programs the first attempt compiled."""
+        more positions) runs the programs the first attempt compiled.
+
+        `rows_floor`: pad the job's alloc positions alone as for that
+        many: the job's whole count, on the first attempt too, so that
+        the positions bucket is the job's and not the attempt's."""
         self.state = state
         self.job = job
         self.plan = plan
         self._plan_overlay = plan_overlay
         self._ask_floor = ask_floor
         self._job_rows_floor = bucket_size(
-            min(ask_floor, JOBPOS_BUCKETS[-1]), JOBPOS_BUCKETS)
+            min(max(ask_floor, rows_floor), JOBPOS_BUCKETS[-1]),
+            JOBPOS_BUCKETS)
         self._explicit_nodes = nodes is not None
         if nodes is None:
             from .resident import get_tracker

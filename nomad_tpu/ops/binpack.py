@@ -616,8 +616,11 @@ def batched_placement_program_compact_delta(
     changed rows ride this very call's arguments — deriving the child
     base costs zero extra round-trips. Returns the batch results plus
     the updated (util, bw_used, ports_free, node_ok) for the batcher to
-    cache under the child's token. Padding rows duplicate a real row
-    (same value, so the duplicate-index scatter is benign)."""
+    cache under the child's token and, under config.pre_resolve, the
+    lanes' final carry (util, bw_used, ports_free after every lane's
+    claims) after them, for the dispatches that follow on that token.
+    Padding rows duplicate a real row (same value, so the
+    duplicate-index scatter is benign)."""
     util2 = util.at[rows].set(util_rows)
     bw2 = bw_used.at[rows].set(bw_rows)
     ports2 = ports_free.at[rows].set(ports_rows)
@@ -625,7 +628,8 @@ def batched_placement_program_compact_delta(
     choices, scores, final = _compact_batch(
         capacity, sched_capacity, util2, bw_avail, bw2, ports2,
         ok2, class_ids, overlays, asks, keys, config)
-    return choices, scores, util2, bw2, ports2, ok2
+    derived = (choices, scores, util2, bw2, ports2, ok2)
+    return derived + tuple(final) if config.pre_resolve else derived
 
 
 @jax.jit

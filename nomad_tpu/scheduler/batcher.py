@@ -30,25 +30,47 @@ and the gangs of one dispatch are one program (ops/gang.py
 batched_gang_placement_program) that carries each gang's claims to the
 next.
 
-A pipeline batch that holds gangs AND plain asks on one base token is
-two dispatches, ordered: PLAIN LANES FIRST. A plain program is short (an
-eval's few asks) and a gang program as long as its widest gang's member
-scan, and whoever goes second waits for the first on the device; with
-the claims carried no order loses a gang to the applier, so the order
-is the one that costs the batch's evals least (gangs first was built
-and measured first: PERF.md section 6, PR 42). The plain program's
-final carry (utilisation, bandwidth, free ports after every lane's
-claims; pre_resolve) stays on the device under the batch's token from
-the moment the program is issued (_publish_claims); a gang dispatch on
-that token waits for the plain dispatches queued or not yet issued
-ahead of it (_await_plain_ahead) and takes the carry as its starting
-state in place of the base's three columns (_take_claims), then
-publishes its own: no second copy of the base, no round trip of the
-claims through the host, and no wait for the first program's results
-either (the device runs the two in order). Every later dispatch on the
-token, of either kind, starts from the newest carry. A token no gang has
-touched is served as before: a batch of one kind is one dispatch on the
-base. The two programs stay two programs.
+A pipeline batch whose requests fall into more than one queue on one
+base token (plain lanes of several ask rungs, a `service` job beside
+`batch` ones, gangs beside plain asks, or one queue's lanes past its
+dispatch's cap) is several dispatches on that token, and they go ONE
+AFTER ANOTHER, each from the claims of those before it. The order is
+total and read off what the requests carry (_rank): plain dispatches by
+the padded length of their ask axis, the ask rung, SHORTEST FIRST, then
+the gang dispatches; ties go by the order of the pops. A program is as
+long as its lanes' scans, and whoever goes later waits for the earlier
+on the device: with the claims carried no order loses a plan to the
+applier, so the order is the one that costs the batch's evals least (a
+burst's one-ask lanes do not wait out a 2,048-step scan; gangs first was
+built and measured first: PERF.md section 6, PRs 42 and 45). A
+program's final carry (utilisation, bandwidth, free ports after every
+lane's claims; pre_resolve) stays on the device under the token from
+the moment the program is issued (_publish_claims); a dispatch on that
+token waits for those ahead of it that are queued, or popped and not
+yet issued (_await_turn, bounded by CLAIMS_WAIT_MAX), takes the newest
+carry as its starting state in place of the base's three columns
+(_take_claims), and publishes its own: no second copy of the base, no
+round trip of the claims through the host, and no wait for an earlier
+program's results either (the device runs them in order). Between its
+turn and its issue a dispatch holds the token, so no two dispatches
+ever start from one carry, and no two ever wait for each other. A batch
+of ONE queue on a token nobody else touches is served as before: one
+dispatch on the base, the program that fuses the base's delta in where
+it ran. The programs stay the programs they were.
+
+Past the ask ladder's first rung a queue's lanes go BATCH_BUCKETS[0] to
+a dispatch (_lane_cap): there a lane is a scan of 16 to 2,048 steps, a
+padding lane costs what a real one does, and every batch bucket would
+be one more program for every rung; the chunks are chained by the carry
+like any two dispatches of a token. ON the first rung a dispatch that
+takes part in a hand-over pads its batch axis to BATCH_BUCKETS[1] at the
+least, as a gang dispatch does (_batch_bucket): how many of a burst's
+short lanes fall into one pipeline batch is the arrivals' luck, and the
+step of the batch ladder they take must not be. And a first-rung
+dispatch whose own step has never run takes the next one up that has,
+where there is one: a compiled program is worth more than twelve idle
+lanes of eight steps. So a token's short lanes run one program from the
+first batch that shares its token on.
 
 A dispatch closes on what its requests carry. Requests of a pipeline
 batch carry that batch's cohort (open_cohort: the launch prologue has
@@ -121,11 +143,11 @@ REQUEST_WAIT_SLICE_S = 0.1
 # dispatch goes (counted closed_by_cap) and late members dispatch on
 # arrival.
 COHORT_WAIT_MAX = 1.0
-# The bound on a gang dispatch's wait for the plain dispatches ahead of
-# it on its token (_await_plain_ahead): as long as a dispatcher waits for
-# another's upload of one base (_device_base), since the first plain
-# dispatch of a shape compiles inside its issue. On expiry the gang
-# dispatch goes on what has been published, blind to the plain lanes as
+# The bound on a dispatch's wait for the dispatches ahead of it on its
+# token (_await_turn): as long as a dispatcher waits for another's
+# upload of one base (_device_base), since the first dispatch of a shape
+# compiles inside its issue. On expiry the dispatch goes on what has
+# been published, blind to whoever it waited for as every dispatch was
 # before PR 42, and is counted (claims_wait_expired).
 CLAIMS_WAIT_MAX = 30.0
 
@@ -147,11 +169,16 @@ class _Cohort:
     units have neither arrived in place() nor been settled. Guarded by
     the lock of the batcher that opened it."""
 
-    __slots__ = ("size", "pending", "deadline", "arrived", "capped")
+    __slots__ = ("size", "pending", "deadline", "arrived", "capped",
+                 "queues", "opened")
 
     def __init__(self, n: int):
         self.size = n  # units announced: the evals of the batch
         self.pending = n
+        # The shape queues its requests went to (counted token_queues
+        # when the last unit has arrived or been settled).
+        self.queues: set = set()
+        self.opened = time.monotonic()
         # COHORT_WAIT_MAX from when it was opened, then from its first
         # arrival.
         self.deadline = time.monotonic() + COHORT_WAIT_MAX
@@ -204,13 +231,14 @@ class _Carry:
     newest program's final carry, on the device. Guarded by the lock of
     the batcher that keeps it."""
 
-    __slots__ = ("util", "bw_used", "ports_free", "kind", "lanes",
+    __slots__ = ("util", "bw_used", "ports_free", "kind", "lanes", "rung",
                  "issued_at", "taken")
 
-    def __init__(self, carry, kind: str, lanes: int):
+    def __init__(self, carry, kind: str, lanes: int, rung: int):
         self.util, self.bw_used, self.ports_free = carry
         self.kind = kind  # of the program: "plain" or "gang"
         self.lanes = lanes
+        self.rung = rung  # the program's ask (or member) axis
         self.issued_at = time.monotonic()
         # True once a dispatch of the OTHER kind has started from it
         # (one batch.claims sample a hand-over).
@@ -221,7 +249,7 @@ class _Request:
     __slots__ = ("token", "base", "overlay", "compact", "asks", "key",
                  "delta", "event", "choices", "scores", "error", "span",
                  "ready_at", "arrived_at", "unit", "topo", "info",
-                 "hand_over")
+                 "hand_over", "order")
 
     def __init__(self, token, base, overlay, asks, key, delta=None,
                  compact=None, span=None, unit=None, topo=None):
@@ -243,9 +271,12 @@ class _Request:
         self.info = None
         # Set at the pop on the first request of a batch that takes
         # part in a hand-over of claims (_dispatch): every gang batch
-        # with a token, and a plain batch whose token gangs touch. It
-        # starts from its token's carry and publishes its own.
+        # with a token, and a plain batch whose token another queue,
+        # or another dispatch of its own, touches. It starts from its
+        # token's carry and publishes its own, in the order of `order`
+        # (_rank and the pop's ordinal).
         self.hand_over = False
+        self.order: Optional[tuple] = None
         self.delta = delta  # (parent_token, changed_rows) or None
         self.span = span  # (eval_id, trace_id) for the device.solve span
         self.unit: Optional[CohortUnit] = unit
@@ -286,7 +317,7 @@ BATCH_BUCKETS = (4, 16, 64)
 # tuples above, with a deliberate pow2 overflow fallback), so shapes
 # they produce are sanctioned the same as matrix.py bucket_size.
 NTA_BUCKET_FNS = ("_pad_rows", "_pad_batch", "_pad_gang_batch",
-                  "_pad_gang_members")
+                  "_pad_gang_members", "_batch_bucket")
 
 
 def _pad_rows(rows) -> np.ndarray:
@@ -335,15 +366,47 @@ def _pad_gang_batch(n: int, max_batch: int) -> int:
     return _pad_batch(max(n, BATCH_BUCKETS[1]), max_batch)
 
 
+# The member axis of a gang dispatch (_pad_gang_members).
+GANG_MEMBER_BUCKETS = [8, 32, 128, 512, 1024]
+
+
 def _pad_gang_members(k: int) -> int:
     """The member axis of a gang dispatch: its largest gang's size on
-    every other step of the ask ladder (8, 32, 128, 512, 1,024). Which
-    gang is a batch's largest changes from batch to batch, and each
-    step is a program for every batch bucket: half the steps is half
-    the programs a warm-up has to meet, for a few member steps more."""
-    from ..models.matrix import ASK_BUCKETS, bucket_size
+    every other step of the ask ladder up to 1,024 (8, 32, 128, 512,
+    1,024). Which gang is a batch's largest changes from batch to batch,
+    and each step is a program for every batch bucket: half the steps is
+    half the programs a warm-up has to meet, for a few member steps
+    more."""
+    from ..models.matrix import bucket_size
 
-    return bucket_size(k, ASK_BUCKETS[::2] + ASK_BUCKETS[-1:])
+    return bucket_size(k, GANG_MEMBER_BUCKETS)
+
+
+def _rank(req: "_Request") -> Tuple[bool, int]:
+    """Where a request's dispatch stands among the dispatches of its
+    base token: plain before gang, and plain by the padded length of
+    the ask axis (the ask rung: the length of each lane's scan),
+    shortest first."""
+    if req.topo is not None:
+        return (True, 0)
+    return (False, int(np.shape(req.asks.active)[0]))
+
+
+def _lane_cap(req: "_Request", max_batch: int) -> int:
+    """The most lanes one dispatch of `req`'s queue takes. On the ask
+    ladder's first rung, and for gangs, the batcher's max_batch: an
+    eval is a handful of scan steps there, and the batch ladder up to
+    sixty-four lanes is three short programs. Past it the ladder's
+    first step: a lane is a scan of its own rung's length, which a
+    padding lane runs too, and every batch bucket would be a program
+    for every rung (nine rungs to 2,048); the rest of the queue rides
+    the next dispatches, each from the claims of the one before."""
+    from ..models.matrix import ASK_BUCKETS
+
+    gang, rung = _rank(req)
+    if gang or rung <= ASK_BUCKETS[0]:
+        return max_batch
+    return min(max_batch, BATCH_BUCKETS[0])
 
 
 def _idle_parts(last_end: float, first_arrival: float, closed: float,
@@ -429,16 +492,32 @@ class PlacementBatcher:
         self._in_flight = 0  # guarded-by: _lock
         self._busy_until = 0.0  # guarded-by: _lock
         self._issued = 0  # guarded-by: _lock (nomad.dispatch ordinal)
-        # token -> what the dispatches on a base token that gangs
-        # touch have claimed so far. As many as bases are kept.
+        # token -> what the dispatches on a base token that more than
+        # one dispatch touches have claimed so far. As many as bases are
+        # kept.
         self._claims: "OrderedDict[object, _Carry]" = OrderedDict()  # guarded-by: _lock
-        # token -> the dispatches of a hand-over (id of the batch's
-        # first request) popped and not yet issued, by kind: a gang
-        # dispatch waits for the plain ones, and a plain dispatch popped
-        # while a gang one waits takes part.
-        self._plain_in_flight: Dict[object, set] = {}  # guarded-by: _lock
-        self._gangs_waiting: Dict[object, set] = {}  # guarded-by: _lock
-        self.mixed_batches = 0  # guarded-by: _lock (hand-overs taken)
+        # token -> the dispatches of a hand-over popped and not yet
+        # issued, {id of the batch's first request: its order}: a
+        # dispatch waits for those of a lower order, and a dispatch
+        # popped while any is here takes part.
+        self._unissued: Dict[object, Dict[int, tuple]] = {}  # guarded-by: _lock
+        # token -> the one dispatch (id of its first request) between
+        # its turn and its issue: it has read the token's carry and not
+        # yet published its own.
+        self._going: Dict[object, int] = {}  # guarded-by: _lock
+        self._popped = 0  # guarded-by: _lock (ordinal of hand-over pops)
+        # program shape (a queue's key less its token) -> the steps of
+        # the batch ladder its first-rung dispatches have run
+        # (_batch_bucket).
+        self._buckets_run: Dict[tuple, set] = {}  # guarded-by: _lock
+        # Dispatches that started from the OTHER kind's carry (a plain
+        # and a gang dispatch crossing), plain dispatches that started
+        # from another plain dispatch's carry, the shape queues that
+        # pipeline batches' requests went to, and dispatches that
+        # waited CLAIMS_WAIT_MAX out.
+        self.mixed_batches = 0  # guarded-by: _lock
+        self.plain_handovers = 0  # guarded-by: _lock
+        self.token_queues = 0  # guarded-by: _lock
         self.claims_wait_expired = 0  # guarded-by: _lock
 
     def open_cohort(self, n: int) -> List[CohortUnit]:
@@ -458,10 +537,32 @@ class PlacementBatcher:
 
     def settle(self, unit: CohortUnit) -> None:
         """CohortUnit.settle: a unit whose place() will not come."""
+        done = None
         with self._full:
             if unit.take():
                 self._cohorts.discard(unit.cohort)
                 self._full.notify_all()
+                done = unit.cohort
+        if done is not None:
+            self._count_queues(done)
+
+    def _count_queues(self, cohort: _Cohort) -> None:
+        """A pipeline batch's last unit has arrived or been settled: the
+        shape queues its requests went to, counted (token_queues, beside
+        the pipeline's `batches`) and as that many samples of the row
+        batch.queues, each the time the batch took to gather. Called
+        without the lock."""
+        n = len(cohort.queues)
+        if not n:
+            return
+        from ..utils import metrics
+
+        with self._lock:
+            self.token_queues += n
+        metrics.incr_counter(("placement_batcher", "token_queues"), n)
+        ms = (time.monotonic() - cohort.opened) * 1000.0
+        trace.get_recorder().observe_stages(
+            [(trace.STAGE_BATCH_QUEUES, ms)] * n)
 
     def batch_mates(self, unit: CohortUnit) -> int:
         """CohortUnit.batch_mates."""
@@ -505,9 +606,11 @@ class PlacementBatcher:
             np.shape(compact.patch_rows)[0],
             np.shape(compact.job_rows)[0],
         )
+        # The token first: what follows it is the program's shape
+        # (_batch_bucket).
         shape_key = (
-            np.shape(state.capacity), np.shape(asks.resources),
-            np.shape(state.feasible)[-1], config, token, compact_key,
+            token, np.shape(state.capacity), np.shape(asks.resources),
+            np.shape(state.feasible)[-1], config, compact_key,
         )
         req = _Request(token, base, overlay, asks, rng_key,
                        delta=getattr(state, "base_delta", None),
@@ -554,17 +657,20 @@ class PlacementBatcher:
         batch's dispatch has set its results, and raise what the
         dispatch raised."""
         run_dispatch = False
+        done = None
         with self._lock:
             q = self._queues.setdefault(shape_key, [])
             q.append(req)
             unit = req.unit
             if unit is not None:
+                unit.cohort.queues.add(shape_key)
                 if not unit.cohort.arrived:
                     unit.cohort.arrived = True
                     unit.cohort.deadline = req.arrived_at + COHORT_WAIT_MAX
                 if unit.take():
                     self._cohorts.discard(unit.cohort)
                     self._full.notify_all()
+                    done = unit.cohort
             if len(q) >= self.max_batch:
                 self._full.notify_all()
             if self._dispatchers.get(shape_key, 0) == 0:
@@ -573,6 +679,8 @@ class PlacementBatcher:
                 # in flight, arrivals accumulate for their respawns.)
                 self._dispatchers[shape_key] = 1
                 run_dispatch = True
+        if done is not None:
+            self._count_queues(done)
         if run_dispatch:
             self._dispatch(shape_key, config, wait_window=True)
         # Bounded park (ntalint unbounded-wait): slices with an
@@ -866,70 +974,115 @@ class PlacementBatcher:
             self._base_pending[token] = done
         return parent, rows, done
 
-    def _await_plain_ahead(self, token) -> None:
-        """The order of a mixed batch: a gang dispatch on `token` goes
-        after every plain dispatch on it that is queued or popped and
-        not yet issued (the cohort that released this dispatch released
-        those too), so that their claims are its starting state.
-        Bounded by CLAIMS_WAIT_MAX."""
+    def _await_turn(self, first: _Request) -> None:
+        """The order of a token's dispatches: the one `first` heads goes
+        after every dispatch on its token that stands before it (_rank,
+        then the order of the pops) and is queued (the cohort that
+        released this dispatch released those too) or popped and not
+        yet issued, so that their claims are its starting state; and
+        after whichever dispatch holds the token between its own turn
+        and its issue. Then it holds the token itself until it has
+        published (_publish_claims) or failed (_release). The order is
+        total, so no two dispatches wait for each other. Bounded by
+        CLAIMS_WAIT_MAX: on expiry the dispatch goes on what has been
+        published."""
+        token, mine, me = first.token, first.order, id(first)
         deadline = time.monotonic() + CLAIMS_WAIT_MAX
         with self._full:
-            while token in self._plain_in_flight or any(
-                    q and q[0].topo is None and q[0].token == token
-                    for q in self._queues.values()):
+            while (token in self._going
+                   or any(order < mine for other, order
+                          in self._unissued.get(token, {}).items()
+                          if other != me)
+                   or any(q and q[0].token == token
+                          and _rank(q[0]) < mine[:2]
+                          for q in self._queues.values())):
                 left = deadline - time.monotonic()
                 if left <= 0:
                     self.claims_wait_expired += 1
-                    return
+                    break
                 self._full.wait(left)
+            self._going.setdefault(token, me)
 
     def _publish_claims(self, first: _Request, carry, kind: str,
-                        lanes: int) -> None:
+                        lanes: int, rung: int) -> None:
         """The final carry of the program just ISSUED for the batch that
         `first` heads, kept on the device under the batch's token for
-        the dispatches that follow on it. A gang dispatch that waits
-        behind a plain one goes on at once: what it starts from is this
-        program's output on the device, and the device runs the two in
-        order; nobody waits for these lanes' results to reach the
-        host."""
+        the dispatches that follow on it. A dispatch that waits behind
+        this one goes on at once: what it starts from is this program's
+        output on the device, and the device runs the two in order;
+        nobody waits for these lanes' results to reach the host."""
         with self._full:
             self._claims.pop(first.token, None)
             while len(self._claims) >= DEVICE_BASE_CACHE:
                 self._claims.popitem(last=False)
-            self._claims[first.token] = _Carry(carry, kind, lanes)
-            self._off_the_list(self._plain_in_flight if first.topo is None
-                         else self._gangs_waiting, first)
-            self._full.notify_all()
+            self._claims[first.token] = _Carry(carry, kind, lanes, rung)
+        self._release(first)
 
-    @staticmethod
-    def _off_the_list(unissued: Dict[object, set],
-                      first: _Request) -> None:
+    def _release(self, first: _Request) -> None:
         """The dispatch that `first` heads holds nobody back any more:
-        issued, or failed before. `unissued` is the batcher's table of
-        its kind, handed over by a caller that holds the lock and
-        notifies after. Idempotent."""
-        ahead = unissued.get(first.token)
-        if ahead is not None:
-            ahead.discard(id(first))
-            if not ahead:
-                del unissued[first.token]
+        issued, or failed before. Idempotent."""
+        with self._full:
+            ahead = self._unissued.get(first.token)
+            if ahead is not None:
+                ahead.pop(id(first), None)
+                if not ahead:
+                    del self._unissued[first.token]
+            if self._going.get(first.token) == id(first):
+                del self._going[first.token]
+            self._full.notify_all()
 
     def _take_claims(self, token, kind: str):
         """(what the dispatches before this one on `token` have claimed,
-        first) or None. `first`: the newest carry is of the other kind
-        and no dispatch of this kind has started from it yet (the
-        hand-over of one mixed batch, counted once)."""
+        crossing, handed) or None. `crossing`: the newest carry is of
+        the other kind and no dispatch of this kind has started from it
+        yet (the hand-over of one mixed batch, counted once).
+        `handed`: a plain dispatch starts from a plain dispatch's
+        carry."""
         with self._lock:
             claims = self._claims.get(token)
             if claims is None:
                 return None
-            first = claims.kind != kind and not claims.taken
-            if first:
+            crossing = claims.kind != kind and not claims.taken
+            if crossing:
                 claims.taken = True
                 self.mixed_batches += 1
-            return claims, first
+            handed = claims.kind == kind == "plain"
+            if handed:
+                self.plain_handovers += 1
+            return claims, crossing, handed
 
-    def _run_batch(self, batch: List[_Request], config) -> None:
+    def _batch_bucket(self, shape_key, first: _Request, n: int,
+                      hand_over: bool) -> int:
+        """The padded length of the batch axis for `n` plain lanes that
+        `first` heads, of the queue `shape_key` (place()). Past the ask
+        ladder's first rung, the ladder's step for `n` (_pad_batch;
+        _lane_cap holds `n` to the first).
+        On the first rung, two rules that keep the step from depending
+        on how a burst's lanes fell into pipeline batches:
+
+        - a dispatch of a hand-over (one of several on its token) pads
+          to BATCH_BUCKETS[1] at the least, as a gang dispatch does
+          (_pad_gang_batch): its padding lanes claim nothing and cost
+          eight scan steps each;
+        - a dispatch whose step this program shape has never run takes
+          the next step up to BATCH_BUCKETS[1] that it has run, if any:
+          the tighter program would be compiled for this one batch."""
+        from ..models.matrix import ASK_BUCKETS
+
+        step = _pad_batch(n, self.max_batch)
+        if _rank(first)[1] > ASK_BUCKETS[0]:
+            return step
+        if hand_over:
+            step = _pad_batch(max(n, BATCH_BUCKETS[1]), self.max_batch)
+        with self._lock:
+            run = self._buckets_run.setdefault(shape_key[1:], set())
+            if (step not in run and step < BATCH_BUCKETS[1]
+                    and BATCH_BUCKETS[1] in run):
+                step = BATCH_BUCKETS[1]
+            run.add(step)
+        return step
+
+    def _run_batch(self, batch: List[_Request], config, shape_key) -> None:
         import jax
 
         from ..chaos import chaos
@@ -976,25 +1129,31 @@ class PlacementBatcher:
         # pay a full compile. Padding rows replicate the last request;
         # their outputs are discarded.
         n_live = len(batch)
-        pad_to = _pad_batch(n_live, self.max_batch)
-        padded = batch + [batch[-1]] * (pad_to - n_live)
         token = batch[0].token
         # Shared-base fast path: base cached on device, only the
         # per-eval payloads cross host->device this dispatch.
         shared = token is not None and all(r.token == token for r in batch)
-        # A token that gangs touch: these lanes start from what the
-        # dispatches before them on it claimed, and what they claim is
-        # where the gangs of their batch start (_dispatch set the mark
+        # A token that several dispatches touch: these lanes start from
+        # what the dispatches before them on it claimed, and what they
+        # claim is where those after them start (_dispatch set the mark
         # at the pop).
         hand_over = shared and batch[0].hand_over
-        claims = self._take_claims(token, "plain") if hand_over else None
+        pad_to = self._batch_bucket(shape_key, batch[0], n_live, hand_over)
+        padded = batch + [batch[-1]] * (pad_to - n_live)
 
-        def on_issued(out) -> None:
-            # The carry is the third output of the programs that
-            # resolve a batch's lanes in order; the vmapped ones have
-            # none to hand on.
-            if hand_over and config.pre_resolve:
-                self._publish_claims(batch[0], out[2], "plain", n_live)
+        def handed_on(carry_of):
+            """on_issued for a program whose lanes' final carry is
+            `carry_of(outputs)`: the programs that resolve a batch's
+            lanes in order. The vmapped ones have none to hand on, and
+            only stop holding the token."""
+            def on_issued(out) -> None:
+                if config.pre_resolve:
+                    self._publish_claims(
+                        batch[0], carry_of(out), "plain", n_live,
+                        _rank(batch[0])[1])
+                else:
+                    self._release(batch[0])
+            return on_issued if hand_over else None
         # Compact overlays: class verdicts + sparse patches + job
         # positions, expanded to the dense [B,N,G] masks ON DEVICE — a
         # few KB per eval instead of ~100KB x G.
@@ -1026,11 +1185,17 @@ class PlacementBatcher:
                     stacked, *[r.full_state() for r in padded])
         payload = (sum(x.nbytes for x in asks) + keys.nbytes
                    + sum(x.nbytes for x in per_eval))
+        # Stacked: now the dispatch waits for its turn on the token and
+        # reads what those before it claimed.
+        claims = None
+        if hand_over:
+            self._await_turn(batch[0])
+            claims = self._take_claims(token, "plain")
         if compact:
-            # The program that fuses the base's delta in returns the
-            # derived base, not its lanes' carry: a dispatch that hands
-            # claims on derives the base first.
-            fused = (None if hand_over
+            # The program that fuses the base's delta in derives the
+            # base: it can only be the first dispatch on its token, and
+            # hands its lanes' carry on after the derived columns.
+            fused = (None if claims is not None
                      else self._claim_fused_delta(token, batch[0].delta))
             if fused is not None:
                 # Base delta FUSED into this dispatch: the changed rows
@@ -1047,6 +1212,8 @@ class PlacementBatcher:
                         self._base_pending.pop(token, None)
                     done.set()
 
+                hand_on = handed_on(lambda out: out[6:9])
+
                 def cache_derived(out) -> None:
                     # As soon as the program is issued, before its
                     # results are pulled: dispatchers waiting on this
@@ -1059,6 +1226,8 @@ class PlacementBatcher:
                             self._device_bases.popitem(last=False)
                         self._device_bases[token] = dev
                     publish()
+                    if hand_on is not None:
+                        hand_on(out)
 
                 try:
                     rows_p = _pad_rows(rows)
@@ -1079,7 +1248,7 @@ class PlacementBatcher:
                 choices, scores, times = self._issue(
                     batch, config, closed,
                     batched_placement_program_compact, *dev[:8],
-                    per_eval, asks, keys, config, on_issued=on_issued)
+                    per_eval, asks, keys, config, on_issued=handed_on(lambda out: out[2]))
         elif shared:
             dev = self._claimed_base(batch, claims)
             state = NodeState(
@@ -1090,14 +1259,15 @@ class PlacementBatcher:
             )
             choices, scores, times = self._issue(
                 batch, config, closed, batched_placement_program_overlay,
-                state, asks, keys, config, on_issued=on_issued)
+                state, asks, keys, config, on_issued=handed_on(lambda out: out[2]))
         else:
             choices, scores, times = self._issue(
                 batch, config, closed, batched_placement_program,
                 per_eval, asks, keys, config)
         self._count_dispatch(times, payload, compact, shared)
-        if claims is not None and claims[1]:
-            self._record_claims_carry(batch, claims[0], "plain", times[0])
+        if claims is not None:
+            self._record_claims_carry(batch, claims, "plain", times[0],
+                                      _rank(batch[0])[1])
         for i, req in enumerate(batch):
             req.choices = choices[i]
             req.scores = scores[i]
@@ -1115,24 +1285,41 @@ class PlacementBatcher:
         return (dev[0], dev[1], carry.util, dev[3], carry.bw_used,
                 carry.ports_free, *dev[6:])
 
-    def _record_claims_carry(self, batch: List[_Request], claims: _Carry,
-                             kind: str, issued: float) -> None:
-        """The span batch.claims, one sample a hand-over, on the first
-        traced request of the dispatch that took the claims: from the
-        other kind's program's issue (or that request's arrival, if
+    def _record_claims_carry(self, batch: List[_Request], taken,
+                             kind: str, issued: float, rung: int) -> None:
+        """One sample a hand-over (`taken` is _take_claims'), on the
+        first traced request of the dispatch that took the claims: from
+        the earlier program's issue (or that request's arrival, if
         later: the span lies inside its device.dispatch) to this
-        dispatch's issue."""
-        from ..gang import note_mixed_batch
+        dispatch's issue. The span batch.claims where a plain and a
+        gang dispatch cross, batch.handover where a plain dispatch
+        started from a plain one's carry; both say which kinds met and
+        on which rungs (`rung`: this dispatch's ask axis, a gang
+        dispatch's member axis)."""
+        claims, crossing, handed = taken
+        if crossing:
+            from ..gang import note_mixed_batch
 
-        note_mixed_batch()
+            note_mixed_batch()
+            stage = trace.STAGE_BATCH_CLAIMS
+            ann = {f"{claims.kind}_lanes": claims.lanes,
+                   f"{kind}_lanes": len(batch)}
+        elif handed:
+            from ..utils import metrics
+
+            metrics.incr_counter(("placement_batcher", "plain_handovers"))
+            stage = trace.STAGE_BATCH_HANDOVER
+            ann = {"from_lanes": claims.lanes, "lanes": len(batch)}
+        else:
+            return
         req = next((r for r in batch if r.span), None)
         if req is not None:
+            ann.update(kind=f"{claims.kind}>{kind}", from_rung=claims.rung,
+                       rung=rung)
             trace.record_span(
-                req.span[0], trace.STAGE_BATCH_CLAIMS,
+                req.span[0], stage,
                 min(max(claims.issued_at, req.arrived_at), issued), issued,
-                ann={f"{claims.kind}_lanes": claims.lanes,
-                     f"{kind}_lanes": len(batch)},
-                trace_id=req.span[1])
+                ann=ann, trace_id=req.span[1])
 
     def _count_dispatch(self, times, payload: int, compact: bool,
                         shared: bool) -> None:
@@ -1224,7 +1411,7 @@ class PlacementBatcher:
             # The plain lanes of this token go first, and what the
             # dispatches before this one claimed is where these lanes
             # start.
-            self._await_plain_ahead(first.token)
+            self._await_turn(first)
             claims = self._take_claims(first.token, "gang")
             dev = self._claimed_base(batch, claims)
             node = dev[:7]
@@ -1238,7 +1425,7 @@ class PlacementBatcher:
         def on_issued(out) -> None:
             issued.append(out)
             if first.token is not None:
-                self._publish_claims(first, out[3:6], "gang", n_live)
+                self._publish_claims(first, out[3:6], "gang", n_live, k_pad)
 
         with trace.annotation("nomad.gang", gangs=n_live,
                               mode=config.mode):
@@ -1249,8 +1436,9 @@ class PlacementBatcher:
             info = np.asarray(issued[0][2])
         t_info = time.monotonic()
         self._count_dispatch(times, payload, False, first.token is not None)
-        if claims is not None and claims[1]:
-            self._record_claims_carry(batch, claims[0], "gang", times[0])
+        if claims is not None:
+            self._record_claims_carry(batch, claims, "gang", times[0],
+                                      k_pad)
         note_gang_dispatch(n_live, int(info[:n_live, 1].sum()))
         for i, req in enumerate(batch):
             req.choices = choices[i]
@@ -1456,8 +1644,10 @@ class PlacementBatcher:
             closed_by = self._accumulate(shape_key, window)
             with self._lock:
                 waiting = self._queues.pop(shape_key, [])
-                batch = waiting[: self.max_batch]
-                leftover = waiting[self.max_batch:]
+                cap = (_lane_cap(waiting[0], self.max_batch)
+                       if waiting else self.max_batch)
+                batch = waiting[:cap]
+                leftover = waiting[cap:]
                 if leftover:
                     # Overflow rides the next dispatch; dropping it
                     # would wedge those workers in event.wait().
@@ -1466,25 +1656,25 @@ class PlacementBatcher:
                 if batch and batch[0].token is not None:
                     first = batch[0]
                     token = first.token
-                    if first.topo is not None:
-                        # A gang dispatch: the plain dispatches popped
-                        # from now to its issue take part.
+                    if (first.topo is not None or token in self._claims
+                            or token in self._unissued
+                            or any(q and q[0].token == token
+                                   for q in self._queues.values())):
+                        # A gang dispatch, or a plain one that is one
+                        # of several on its token: an earlier one has
+                        # published its claims, one is popped and not
+                        # yet issued, or requests on the token are
+                        # queued (another queue of the batch, whose
+                        # requests are all queued by now, or this
+                        # queue's own lanes past the cap). It takes its
+                        # place among them, and those popped from now
+                        # to its issue take part.
                         handing = first
                         first.hand_over = True
-                        self._gangs_waiting.setdefault(
-                            token, set()).add(id(first))
-                    elif (token in self._claims
-                          or token in self._gangs_waiting
-                          or any(q and q[0].topo is not None
-                                 and q[0].token == token
-                                 for q in self._queues.values())):
-                        # A plain dispatch on a token that gangs touch
-                        # (claimed on, waiting, or queued: a mixed
-                        # batch's requests are all queued by now).
-                        handing = first
-                        first.hand_over = True
-                        self._plain_in_flight.setdefault(
-                            token, set()).add(id(first))
+                        self._popped += 1
+                        first.order = _rank(first) + (self._popped,)
+                        self._unissued.setdefault(
+                            first.token, {})[id(first)] = first.order
                 if batch:
                     self._closed_by[closed_by] += 1
                     for req in batch:
@@ -1503,7 +1693,7 @@ class PlacementBatcher:
                 self._spawn_dispatcher(shape_key, config)
             if not batch:
                 return
-            self._run_batch(batch, config)
+            self._run_batch(batch, config, shape_key)
             with self._lock:
                 # Under the lock: dispatchers of different shape keys
                 # race these (+= is not atomic across a GIL switch).
@@ -1523,18 +1713,15 @@ class PlacementBatcher:
             for req in batch:
                 req.ready_at = ready
                 req.event.set()
+            if handing is not None:
+                # Had it failed before its issue, the dispatches behind
+                # it would still be waiting.
+                self._release(handing)
             # Count ourselves out; anything still queued with no live
             # dispatcher gets a fresh one. Zero-count keys are removed —
             # every new cluster-base token mints a new shape key, so a
             # long-running server would otherwise accrete dead entries.
             with self._full:
-                if handing is not None:
-                    # Had it failed before its issue, the dispatches
-                    # behind it would still be waiting.
-                    self._off_the_list(self._plain_in_flight
-                                 if handing.topo is None
-                                 else self._gangs_waiting, handing)
-                    self._full.notify_all()
                 remaining = self._dispatchers.get(shape_key, 1) - 1
                 spawn = bool(self._queues.get(shape_key)) and remaining == 0
                 if spawn:
@@ -1603,11 +1790,15 @@ class PlacementBatcher:
                 **{f"closed_by_{k}": v
                    for k, v in self._closed_by.items()},
                 "open_cohorts": len(self._cohorts),
-                # Mixed batches: dispatches that started from the
-                # claims of the other kind's dispatch, and gang
-                # dispatches that waited CLAIMS_WAIT_MAX out and went
-                # without.
+                # Hand-overs on a token: dispatches that started from
+                # the claims of the other kind's dispatch, plain
+                # dispatches that started from a plain one's, the shape
+                # queues pipeline batches' requests went to (beside the
+                # pipeline's `batches`), and dispatches that waited
+                # CLAIMS_WAIT_MAX out and went without.
                 "mixed_batches": self.mixed_batches,
+                "plain_handovers": self.plain_handovers,
+                "token_queues": self.token_queues,
                 "claims_wait_expired": self.claims_wait_expired,
             }
 

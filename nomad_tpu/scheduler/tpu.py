@@ -41,9 +41,11 @@ from .system import SystemScheduler
 from .util import AllocTuple
 
 # A preemption-eligible eval whose task groups ask for at most this
-# many allocations in all pads its asks to that count (see
-# BatchedTPUScheduler._compute_placements): the top of the ask ladder's
-# small steps (models/matrix.py ASK_BUCKETS 8/16/32/64).
+# many allocations in all pads its asks to that count on its FIRST
+# attempt already (see BatchedTPUScheduler._compute_placements): the top
+# of the ask ladder's small steps (models/matrix.py ASK_BUCKETS
+# 8/16/32/64). A replan pads to its first attempt's count whatever the
+# size.
 REPLAN_PAD_MAX_ASKS = 64
 
 
@@ -141,6 +143,9 @@ class BatchedTPUScheduler(GenericScheduler):
                  kernel: Optional[str] = None):
         super().__init__(logger, state, planner, batch=batch, rng=rng)
         self.kernel = kernel
+        # The dense asks of this scheduler's first attempt (None before
+        # it): an inline replan after a partly rejected plan pads to it.
+        self._first_asks: Optional[int] = None
 
     def _inplace_update(self, updates):
         """Batched host-side in-place routing (scheduler/util.py
@@ -256,25 +261,42 @@ class BatchedTPUScheduler(GenericScheduler):
             super()._compute_placements(bulk)
             return
 
+        # The compiled programs an eval runs are keyed by its ask rung
+        # and its job's positions bucket (models/matrix.py), and a
+        # REPLAN after a partly rejected plan comes with fewer asks and
+        # more positions of its own: with task groups of 1 to 2,000 in
+        # one window, rung x positions bucket is a set no warm-up
+        # closes by meeting each job shape in turn, and a compile is
+        # seconds of the eval's latency. So the set is closed here:
+        # - positions are padded as for the job's whole count on every
+        #   attempt, the first included (a few KB at the most): their
+        #   bucket is the job's, never the attempt's;
+        # - a replan (inline: this scheduler has planned before;
+        #   requeued: its session says so and the first attempt's count
+        #   is taken to be the task groups') pads its asks to the first
+        #   attempt's, and runs the program that attempt ran. A task
+        #   group scaling up by a few is a first attempt and scans its
+        #   few asks;
+        # - a preemption-eligible eval of small task groups pads its
+        #   first attempt too, as if none of its allocations were
+        #   placed: it is replanned by the preemption pass, which has
+        #   programs of its own to keep few (small task groups only:
+        #   that is where a replan changes bucket).
+        counts = {m.task_group.name: m.task_group.count for m in bulk}
+        job_asks = sum(counts.values())
         ask_floor = 0
-        if may_preempt:
-            # Such an eval is replanned HERE after a partially
-            # committed plan (requeued, so by a new scheduler), with
-            # fewer asks and more positions of its own. It pads both as
-            # if none of its task groups' allocations were placed yet:
-            # the replan then runs the programs the first attempt
-            # compiled and never mints one (on a full cell every
-            # production eval is such an eval, and a compile is seconds
-            # of its latency). Small task groups only: that is where a
-            # replan changes bucket, and a large one scaling up by a
-            # few must not scan its whole count.
-            counts = {m.task_group.name: m.task_group.count for m in bulk}
-            ask_floor = sum(counts.values())
-            if ask_floor > REPLAN_PAD_MAX_ASKS:
-                ask_floor = 0
+        if may_preempt and job_asks <= REPLAN_PAD_MAX_ASKS:
+            ask_floor = job_asks
+        inline_replan = self._first_asks is not None
+        if inline_replan:
+            ask_floor = max(ask_floor, self._first_asks)
+        elif getattr(self.planner, "requeued", False):
+            ask_floor = max(ask_floor, job_asks)
+        else:
+            self._first_asks = len(bulk)
         _t0 = time.monotonic()
         matrix = ClusterMatrix(self.state, self.job, self.plan,
-                               ask_floor=ask_floor)
+                               ask_floor=ask_floor, rows_floor=job_asks)
         _t_base = time.monotonic()
         tg_indices = {tg.name: i for i, tg in enumerate(self.job.task_groups)}
         placements = [tg_indices[m.task_group.name] for m in bulk]
@@ -329,7 +351,7 @@ class BatchedTPUScheduler(GenericScheduler):
                 # soak drives trip -> half-open -> reclose through
                 # this site).
                 chaos.fire("device.breaker_trip", eval_id=self.eval.id)
-            if may_preempt:
+            if may_preempt or inline_replan:
                 # The other half of the padding above: an inline replan
                 # plans on a snapshot no launch prologue prefetched.
                 # Its base is made resident first (a no-op wherever it
@@ -366,10 +388,11 @@ class BatchedTPUScheduler(GenericScheduler):
         breaker.record_success((time.monotonic() - _t0) * 1000.0)
         choices = np.asarray(choices)
         scores = np.asarray(scores)
+        ann = {"rung": int(np.shape(asks.active)[0])}
+        if unit is not None:
+            ann["closed_by"] = unit.closed_by
         trace.record_span(
-            self.eval.id, trace.STAGE_DEVICE_DISPATCH, _t0,
-            ann=({"closed_by": unit.closed_by} if unit is not None
-                 else None),
+            self.eval.id, trace.STAGE_DEVICE_DISPATCH, _t0, ann=ann,
             trace_id=self.eval.trace_id)
 
         # Host-side exact port assignment per chosen node, incremental.
@@ -515,7 +538,7 @@ class BatchedTPUScheduler(GenericScheduler):
             self._place_gang_host(tg, tuples)
             return
         breaker.record_success((time.monotonic() - _t_solve) * 1000.0)
-        ann = {"lanes": len(tuples)}
+        ann = {"lanes": len(tuples), "rung": len(gang.lane.active)}
         if unit is not None:
             ann["closed_by"] = unit.closed_by
         trace.record_span(

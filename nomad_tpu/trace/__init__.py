@@ -29,6 +29,8 @@ from .span import (  # noqa: F401
     STAGE_API_REGISTER,
     STAGE_BASE_DELTA,
     STAGE_BATCH_CLAIMS,
+    STAGE_BATCH_HANDOVER,
+    STAGE_BATCH_QUEUES,
     STAGE_BROKER_WAIT,
     STAGE_DEFRAG_SOLVE,
     STAGE_DEVICE_DISPATCH,

@@ -91,6 +91,16 @@ STAGE_BATCH_CLAIMS = "batch.claims"        # inside the device.dispatch
 #   _record_claims_carry; ann: gang_lanes, plain_lanes). One sample a
 #   hand-over, on the taking dispatch's first traced request; the
 #   interval was device.dispatch.self before
+STAGE_BATCH_HANDOVER = "batch.handover"    # inside the device.dispatch
+#   of an eval whose pipeline batch's plain asks went to more than one
+#   dispatch on their base token (several ask rungs, a service job
+#   beside batch ones, a queue past its dispatch's lane cap): from the
+#   earlier plain program's issue to this one's, which starts from its
+#   claims (scheduler/batcher.py _record_claims_carry; ann: kind,
+#   from_rung, rung, from_lanes, lanes). One sample a plain dispatch
+#   that started from a plain dispatch's carry, on its first traced
+#   request; batch.claims (which also carries kind and the rungs) keeps
+#   meaning a plain and a gang dispatch crossing
 STAGE_DEFRAG_SOLVE = "defrag.solve"        # one defrag-loop round's
 #   warm-started global relaxation solve + move extraction
 #   (nomad_tpu/defrag; ann: movable, moves, gain, warm, solve_ms) —
@@ -124,6 +134,14 @@ DEVICE_IDLE_STAGES = (
     STAGE_IDLE_BATCH_WAIT,
     STAGE_IDLE_STACK,
 )
+# The shape queues a pipeline batch's requests went to
+# (scheduler/batcher.py _count_queues): a row of the stage table fed
+# through observe_stages when the batch's last unit has arrived, one
+# sample a queue, each the time the batch took to gather. Its sample
+# count over the pipeline's `batches` is how many dispatches a batch
+# becomes at the least.
+STAGE_BATCH_QUEUES = "batch.queues"
+BATCHER_ROW_STAGES = (STAGE_BATCH_QUEUES,)
 
 # The client's path (PR 40): what the client is timed on and no eval's
 # tree holds. Rows of the stage table fed through observe_stages /
@@ -203,6 +221,7 @@ ALL_STAGES = (
     STAGE_GANG_BUILD,
     STAGE_GANG_SOLVE,
     STAGE_BATCH_CLAIMS,
+    STAGE_BATCH_HANDOVER,
     STAGE_DEFRAG_SOLVE,
     STAGE_PLAN_SUBMIT,
     STAGE_PLAN_QUEUE_WAIT,

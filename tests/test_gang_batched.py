@@ -519,7 +519,7 @@ def test_a_gang_dispatch_is_a_request_of_the_batcher():
         assert spans["gang.solve"]["parent"] == "device.dispatch"
         assert spans["device.solve"]["parent"] == "gang.solve"
         assert spans["device.dispatch"]["annotations"] == {
-            "lanes": 4, "closed_by": "cohort"}
+            "lanes": 4, "rung": 8, "closed_by": "cohort"}
         assert spans["gang.solve"]["annotations"] == {"gangs": 3}
         select = spans["gang.select"]["annotations"]
         assert select["members"] == 4 and select["mode"] == "slice"
@@ -574,7 +574,8 @@ def test_a_mixed_batch_places_gangs_and_plain_jobs_each_by_its_program():
                  for s in t["spans"]]
         carried = [s for s in spans if s["name"] == trace.STAGE_BATCH_CLAIMS]
         assert [s["annotations"] for s in carried] == [
-            {"gang_lanes": 3, "plain_lanes": 3}]
+            {"gang_lanes": 3, "plain_lanes": 3, "kind": "plain>gang",
+             "from_rung": 8, "rung": 8}]
         assert carried[0]["parent"] == trace.STAGE_DEVICE_DISPATCH
         assert server.plan_applier.stats()["gangs_rejected"] == 0
         by_id = {n.id: n for n in nodes}

@@ -310,7 +310,8 @@ def test_a_mixed_batch_places_every_gang_whole_with_no_rejection():
                    for s in t["spans"]
                    if s["name"] == trace.STAGE_BATCH_CLAIMS]
         assert [s["annotations"] for s in carried] == [
-            {"gang_lanes": 2, "plain_lanes": 34}]
+            {"gang_lanes": 2, "plain_lanes": 34, "kind": "plain>gang",
+             "from_rung": 8, "rung": 32}]
     finally:
         server.shutdown()
 
@@ -332,8 +333,8 @@ def test_without_the_hand_over_the_same_batch_conflicts(monkeypatch, cut):
     monkeypatch.setattr(PlacementBatcher, "_take_claims",
                         lambda self, token, kind: None)
     if cut == "as_the_parent":
-        monkeypatch.setattr(PlacementBatcher, "_await_plain_ahead",
-                            lambda self, token: None)
+        monkeypatch.setattr(PlacementBatcher, "_await_turn",
+                            lambda self, first: None)
         monkeypatch.setattr(CohortUnit, "batch_mates", lambda self: 0)
     server = dense_server()
     try:
@@ -595,20 +596,26 @@ def test_the_route_of_an_eval_of_one_to_three_asks(case):
 
 
 def test_a_gang_dispatch_waits_for_the_plain_dispatch_ahead_of_it():
-    from nomad_tpu.scheduler.batcher import _Request
+    import threading
+    from types import SimpleNamespace
+
+    from nomad_tpu.scheduler.batcher import _rank, _Request
 
     batcher = PlacementBatcher()
     token = object()
-    batcher._await_plain_ahead(token)       # nothing ahead: no wait
-    first = _Request(token, None, None, None, None)
-    with batcher._lock:
-        batcher._plain_in_flight[token] = {id(first)}
+    first = _Request(token, None, None,
+                     SimpleNamespace(active=np.zeros(8, bool)), None)
+    lead = _Request(token, None, None, None, None, topo=("k", None))
+    with batcher._lock:     # both popped, as _dispatch pops them
+        for seq, req in enumerate((lead, first)):
+            req.order = _rank(req) + (seq,)
+            batcher._unissued.setdefault(token, {})[id(req)] = req.order
+    batcher._await_turn(first)      # nothing ahead of the plain lanes
+    assert batcher._going == {token: id(first)}
     released = []
 
-    import threading
-
     def gang():
-        batcher._await_plain_ahead(token)
+        batcher._await_turn(lead)
         released.append(batcher._take_claims(token, "gang"))
 
     t = threading.Thread(target=gang)
@@ -619,23 +626,25 @@ def test_a_gang_dispatch_waits_for_the_plain_dispatch_ahead_of_it():
              np.ones(4, np.float32))
     # the plain program is issued: its carry is published, the gang
     # dispatch goes on
-    batcher._publish_claims(first, carry, "plain", 3)
+    batcher._publish_claims(first, carry, "plain", 3, 8)
     t.join(5.0)
-    (claims, handed), = released
-    assert (claims.kind, claims.lanes, handed) == ("plain", 3, True)
-    assert batcher._plain_in_flight == {}
-    batcher._off_the_list(batcher._plain_in_flight, first)  # idempotent
-    # the hand-over is counted once, and only across kinds: a later
-    # plain dispatch starts from the plain carry without taking it
-    assert batcher._take_claims(token, "gang")[1] is False
-    assert batcher._take_claims(token, "plain")[1] is False
-    assert batcher.stats()["mixed_batches"] == 1
+    (claims, crossing, handed), = released
+    assert (claims.kind, claims.lanes, claims.rung, crossing, handed) == (
+        "plain", 3, 8, True, False)
+    assert batcher._unissued == {token: {id(lead): lead.order}}
+    assert batcher._going == {token: id(lead)}
+    batcher._release(first)             # idempotent
+    # the crossing is counted once, and only across kinds: a later
+    # plain dispatch starts from the plain carry as a plain hand-over
+    assert batcher._take_claims(token, "gang")[1:] == (False, False)
+    assert batcher._take_claims(token, "plain")[1:] == (False, True)
+    stats = batcher.stats()
+    assert (stats["mixed_batches"], stats["plain_handovers"]) == (1, 1)
     assert batcher._take_claims(object(), "gang") is None
     # a gang dispatch is counted in while it waits, and out at its issue
-    lead = _Request(token, None, None, None, None, topo=("k", None))
-    with batcher._lock:
-        batcher._gangs_waiting[token] = {id(lead)}
-    batcher._publish_claims(lead, carry, "gang", 2)
-    assert batcher._gangs_waiting == {}
-    claims, handed = batcher._take_claims(token, "plain")
-    assert (claims.kind, claims.lanes, handed) == ("gang", 2, True)
+    batcher._publish_claims(lead, carry, "gang", 2, 32)
+    assert batcher._unissued == {} and batcher._going == {}
+    assert batcher.stats()["claims_wait_expired"] == 0
+    claims, crossing, handed = batcher._take_claims(token, "plain")
+    assert (claims.kind, claims.lanes, crossing, handed) == (
+        "gang", 2, True, False)
