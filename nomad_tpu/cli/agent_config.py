@@ -33,10 +33,8 @@ class ServerBlock:
     # finer-grained than the all-or-nothing -tpu flag.
     scheduler_factories: Dict[str, str] = field(default_factory=dict)
     # Batch tuning (server/config.py): max dense-factory evals the
-    # dispatch pipeline packs into one batch, and the size below which
-    # latency-aware routing sends evals to the host pipeline.
+    # dispatch pipeline packs into one batch.
     eval_batch_size: Optional[int] = None
-    dense_min_batch: Optional[int] = None
     # Central dispatch pipeline knobs (server/config.py dispatch_*):
     # batches in flight, and the device-side in-batch conflict
     # pre-resolution toggle.
@@ -231,7 +229,7 @@ _SCHEMA: Dict[str, Any] = {
     "server.num_schedulers": int, "server.enabled_schedulers": _str_list,
     "server.node_gc_threshold": str, "server.heartbeat_grace": str,
     "server.retry_join": _str_list, "server.start_join": _str_list,
-    "server.eval_batch_size": int, "server.dense_min_batch": int,
+    "server.eval_batch_size": int,
     "server.dispatch_max_inflight": int, "server.dense_pre_resolve": bool,
     "server.device_resident": bool, "server.resident_rebuild_rows": int,
     "server.placement_kernel": str,

@@ -658,8 +658,6 @@ def cmd_agent(args) -> int:
             server_cfg.node_gc_threshold = node_gc_threshold
         if cfg.server.eval_batch_size is not None:
             server_cfg.eval_batch_size = cfg.server.eval_batch_size
-        if cfg.server.dense_min_batch is not None:
-            server_cfg.dense_min_batch = cfg.server.dense_min_batch
         if cfg.server.dispatch_max_inflight is not None:
             server_cfg.dispatch_max_inflight = (
                 cfg.server.dispatch_max_inflight)

@@ -29,10 +29,11 @@ device dispatch:
   the cross-batch residue only.
 
 The pipeline preserves the worker path's contracts: per-job broker
-serialization (a drained batch is always over distinct jobs), the
-latency-aware host routing for sub-`dense_min_batch` batches, eval
+serialization (a drained batch is always over distinct jobs), eval
 ack/nack with the original broker token, and the nack-clock pause
-while a plan waits in the plan queue.
+while a plan waits in the plan queue. A batch of any size, one
+included, runs on the dense factories; only an open device-path
+breaker sends a batch to the host factories.
 """
 
 from __future__ import annotations
@@ -48,7 +49,7 @@ from .. import profile, trace
 from ..chaos import chaos
 from ..profile import ProfiledCondition, ProfiledLock
 from ..scheduler import new_scheduler
-from ..server.worker import EvalSession, routes_host
+from ..server.worker import EvalSession
 from ..structs import Evaluation, Plan, PlanResult, consts
 from ..utils import metrics
 from ..utils.backoff import poll_until
@@ -633,31 +634,23 @@ class DispatchPipeline:
             # failure): _launch aborts the batch, every eval nacks and
             # redelivers. 'delay' = a follower lagging the leader.
             chaos.fire("dispatch.launch", batch=len(batch))
-        cfg = self.server.config
-        # Latency-aware routing, centralized: a batch too small to
-        # amortize the device dispatch runs on the host factories with
-        # identical placement semantics (parity-tested).
-        route_host = routes_host((e.eval.priority for e in batch),
-                                 cfg.dense_min_batch)
-        if not route_host:
-            # Device-path circuit breaker (admission/breaker.py): an
-            # OPEN breaker inside its cool-down routes the whole batch
-            # to the host factories up front — no matrix build against
-            # a sick device path, no cohort to open.
-            # This is the NON-consuming hint: once the cool-down
-            # elapses it goes quiet and the dense path's acquire() gate
-            # (scheduler/tpu.py) sends exactly one half-open probe.
-            from ..admission import get_breaker
+        # Device-path circuit breaker (admission/breaker.py), the one
+        # route to the host factories (identical placement semantics,
+        # parity-tested): an OPEN breaker inside its cool-down routes
+        # the whole batch there up front — no matrix build against a
+        # sick device path, no cohort to open.
+        # This is the NON-consuming hint: once the cool-down elapses it
+        # goes quiet and the dense path's acquire() gate
+        # (scheduler/tpu.py) sends exactly one half-open probe.
+        from ..admission import get_breaker
 
-            if get_breaker().should_route_host():
-                route_host = True
-                with self._lock:
-                    self.breaker_routed += len(batch)
-                metrics.incr_counter(
-                    ("dispatch", "breaker_route_host"), len(batch))
+        route_host = get_breaker().should_route_host()
         if route_host:
             with self._lock:
+                self.breaker_routed += len(batch)
                 self.routed_host += len(batch)
+            metrics.incr_counter(
+                ("dispatch", "breaker_route_host"), len(batch))
             metrics.incr_counter(("dispatch", "route_host"), len(batch))
         # One MVCC snapshot for the whole batch: every member plans
         # against the same cluster state so their ClusterMatrix bases

@@ -39,17 +39,9 @@ class ServerConfig:
     # then runs a dense eval itself on the dense factory.
     # Default = the batcher's MAX_BATCH: a batch can fill one device
     # dispatch and no more. The value has not been re-measured on an
-    # attached chip. Lone/interactive evals never see this (the
-    # dense_min_batch router sends them to the host pipeline).
+    # attached chip. A lone eval is a batch of one: a dispatch of one
+    # lane.
     eval_batch_size: int = 64
-
-    # Latency-aware routing: a dense factory only pays off when the
-    # device dispatch amortizes over a batch; a lone interactive eval
-    # would eat the full batch-window + dispatch latency for nothing.
-    # Drained groups smaller than this run on the host (CPU iterator)
-    # factory instead — same placement semantics (CPU/TPU parity is a
-    # test invariant), millisecond latency. 1 forces dense always.
-    dense_min_batch: int = 2
 
     # Central dispatch pipeline (nomad_tpu/dispatch): dense-path evals
     # from EVERY worker flow into one leader-side accumulator that
@@ -64,7 +56,7 @@ class ServerConfig:
     # Accumulation window while another batch is in flight (its
     # round-trip is the budget being amortized); the idle grace is all
     # a batch waits when nothing is in flight — a lone interactive
-    # eval pays only this before routing to the host path.
+    # eval pays only this before its launch.
     dispatch_window: float = 0.05
     dispatch_idle_grace: float = 0.004
     # Conflict-rejected evals rejoin the accumulating batch at most

@@ -23,7 +23,6 @@ import time
 from typing import Optional, Tuple
 
 from ..scheduler import new_scheduler
-from ..migrate import preemption_eligible
 from ..utils import metrics
 from ..utils.backoff import poll_until
 from ..structs import Evaluation, Plan, PlanResult
@@ -43,18 +42,6 @@ def is_dense_factory(name: str) -> bool:
     return name.endswith("-tpu")
 
 
-def routes_host(priorities, dense_min_batch: int) -> bool:
-    """Latency-aware routing, the dispatch pipeline's one rule: a
-    batch too small to amortize a device dispatch runs on the host
-    factories (identical placement semantics, parity-tested), unless
-    one of its evals may preempt. The host iterators cannot evict, so
-    such an eval stays dense at any batch size (scheduler/tpu.py keeps
-    its few-ask retries on the dense path for the same reason)."""
-    priorities = list(priorities)
-    return len(priorities) < dense_min_batch and not any(
-        preemption_eligible(p) for p in priorities)
-
-
 def factory_kernel(name: str) -> Optional[str]:
     """The kernel a dense factory variant pins ("service-convex-tpu"
     -> "convex"; nomad_tpu/kernels lazy registry), None for plain
@@ -72,10 +59,11 @@ def factory_kernel(name: str) -> Optional[str]:
 
 def host_factory(name: str) -> str:
     """The host (CPU iterator) factory with identical placement
-    semantics — where latency-aware routing sends lone evals. Kernel-
-    pinned dense variants ("service-convex-tpu", nomad_tpu/kernels)
-    map to the same host factory as their plain siblings: the host
-    path has no kernels, the infix strips with the suffix."""
+    semantics — where the dispatch pipeline sends a batch while the
+    device-path breaker is open. Kernel-pinned dense variants
+    ("service-convex-tpu", nomad_tpu/kernels) map to the same host
+    factory as their plain siblings: the host path has no kernels, the
+    infix strips with the suffix."""
     if not is_dense_factory(name):
         return name
     kernel = factory_kernel(name)

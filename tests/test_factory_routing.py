@@ -1,8 +1,10 @@
-"""Latency-aware factory routing: with a dense (TPU) factory
-configured, a LONE eval runs on the host iterator pipeline
-(millisecond latency — it must not pay the batch window + device RTT),
-while a drained batch runs dense and coalesces into shared device
-dispatches. VERDICT r2 ask #8."""
+"""Factory routing: with a dense (TPU) factory configured every eval
+the pipeline launches runs on the dense factory, alone or in a batch;
+only the device-path circuit breaker sends a batch to the host
+factories. The ONE size rule left is the dense scheduler's own
+(scheduler/tpu.py `_compute_placements`): an eval of one to three asks
+with no batch to ride walks the host iterators, everything else is a
+lane of a device dispatch."""
 
 import time
 
@@ -11,11 +13,7 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.scheduler.batcher import get_batcher
 from nomad_tpu.server import Server, ServerConfig
-from nomad_tpu.server.worker import (
-    host_factory,
-    is_dense_factory,
-    routes_host,
-)
+from nomad_tpu.server.worker import host_factory, is_dense_factory
 
 
 def wait_until(fn, timeout=30.0, interval=0.02):
@@ -59,34 +57,75 @@ def test_host_factory_mapping():
     assert is_dense_factory("service-convex-tpu")
 
 
-# (priorities of the batch, dense_min_batch, preemption on) -> host?
-# Threshold 50: priority 70 may preempt, 50 may not (strictly above).
-ROUTES_HOST_TABLE = {
-    "below_min_batch": ([50], 2, False, True),
-    "at_min_batch": ([50, 50], 2, False, False),
-    "min_batch_one_forces_dense": ([50], 1, False, False),
-    "empty_batch_below_min": ([], 2, False, True),
-    "none_eligible": ([50], 2, True, True),
-    "one_eligible_stays_dense": ([50, 70, 50], 4, True, False),
-    "all_eligible_stay_dense": ([70], 2, True, False),
-    "eligible_priority_but_preemption_off": ([70], 2, False, True),
-}
+def counted(suffix):
+    from nomad_tpu.utils.metrics import get_metrics
+
+    life = get_metrics().inmem._life.counters
+    return sum(c[1] for name, c in list(life.items())
+               if name.endswith(suffix))
 
 
-@pytest.mark.parametrize("case", sorted(ROUTES_HOST_TABLE))
-def test_routes_host_truth_table(case):
-    """The dispatch pipeline's one routing rule: a batch under
-    dense_min_batch goes to the host factories unless one of its evals
-    may preempt (the host iterators cannot evict)."""
+# The one size rule that is left, scheduler/tpu.py _compute_placements:
+# (asks of the eval, what it finds at the batcher) -> host walk?
+# Priority 70 may preempt under a threshold of 50 and then stays dense
+# at any size (the host iterators cannot evict).
+SITUATIONS = ("no_cohort", "cohort_of_one", "batch_mates", "replan",
+              "requeued", "preemption_eligible")
+SMALL_ASK_TABLE = {
+    f"{asks}_asks_{situation}":
+        (asks, situation,
+         asks <= 3 and situation not in ("batch_mates",
+                                         "preemption_eligible"))
+    for asks in (1, 3, 4) for situation in SITUATIONS}
+
+
+@pytest.mark.parametrize("case", sorted(SMALL_ASK_TABLE))
+def test_small_ask_rule_truth_table(case):
+    """One to three asks walk the host iterators only when the eval has
+    no batch to ride (no cohort, a cohort of one, a unit that has
+    ridden its dispatch as an inline replan's has, a run the pipeline
+    requeued after a conflict) and may not preempt; four asks are a
+    lane whatever the eval finds."""
     from nomad_tpu import migrate
+    from nomad_tpu.scheduler.testing import Harness
+    from nomad_tpu.structs import consts, new_eval
 
-    priorities, min_batch, preempt_on, want = ROUTES_HOST_TABLE[case]
+    asks, situation, want_host = SMALL_ASK_TABLE[case]
     before = migrate.preempt_stats()
-    migrate.configure(preemption_enabled=preempt_on,
-                      preempt_priority_threshold=50)
+    migrate.configure(
+        preemption_enabled=situation == "preemption_eligible",
+        preempt_priority_threshold=50)
+    batcher = get_batcher()
     try:
-        # a generator, as the pipeline passes it
-        assert routes_host((p for p in priorities), min_batch) is want
+        h = Harness(seed=93)
+        for _ in range(6):
+            h.state.upsert_node(h.next_index(), mock.node())
+        job = mock.job()
+        job.task_groups[0].count = asks
+        if situation == "preemption_eligible":
+            job.priority = 70
+        h.state.upsert_job(h.next_index(), job)
+        units = []
+        if situation != "no_cohort":
+            units = batcher.open_cohort(
+                1 if situation in ("cohort_of_one",
+                                   "preemption_eligible") else 2)
+            for mate in units[1:]:
+                mate.settle()
+            if situation == "replan":
+                units[0].settle()  # as after its first dispatch
+            h.cohort = units[0]
+            h.settle_cohort = units[0].settle
+        h.requeued = situation == "requeued"
+        small = counted("scheduler.small_route_host_evals")
+        served = batcher.stats()["batched_requests"]
+        h.process("service-tpu",
+                  new_eval(job, consts.EVAL_TRIGGER_JOB_REGISTER))
+        assert len(h.state.allocs_by_job(job.id)) == asks
+        on_host = counted("scheduler.small_route_host_evals") - small
+        on_device = batcher.stats()["batched_requests"] - served
+        assert (on_host, on_device) == ((1, 0) if want_host else (0, 1))
+        assert batcher.stats()["open_cohorts"] == 0
     finally:
         migrate.configure(
             preemption_enabled=before["enabled"],
@@ -196,21 +235,25 @@ def test_placement_kernel_knob_reaches_stats_surface():
         configure(before)
 
 
-def test_lone_eval_routes_to_host_path():
-    """One job registered on an idle broker: placements must NOT go
-    through the device batcher."""
+def test_lone_small_eval_walks_the_host_iterators():
+    """One job of three asks registered on an idle broker: the pipeline
+    launches it dense, a cohort of one, and the dense scheduler's
+    small-ask rule hands it to the host iterators: no batcher traffic,
+    and the pipeline routed nothing."""
     server = make_server()
     try:
         seed_nodes(server)
         batcher = get_batcher()
         before = batcher.batched_requests
+        small = counted("scheduler.small_route_host_evals")
         job = mock.job()
         job.task_groups[0].count = 3
         server.job_register(job)
         assert wait_until(
             lambda: len(server.fsm.state.allocs_by_job(job.id)) == 3)
-        # Placed by the host pipeline: zero new batcher traffic.
         assert batcher.batched_requests == before
+        assert counted("scheduler.small_route_host_evals") == small + 1
+        assert server.dispatch.stats()["routed_host"] == 0
     finally:
         server.shutdown()
 
@@ -245,20 +288,25 @@ def test_eval_storm_routes_to_dense_path():
         server.shutdown()
 
 
-def test_dense_min_batch_one_forces_dense():
-    """Operators can force the dense path for every eval."""
-    server = make_server(dense_min_batch=1)
+def test_lone_eval_is_served_by_the_batcher():
+    """A lone eval of six asks on an idle pipeline is a dispatch of one
+    lane: the batcher serves it, the pipeline routes nothing to the
+    host."""
+    server = make_server()
     try:
         seed_nodes(server)
         batcher = get_batcher()
         before = batcher.batched_requests
         job = mock.job()
-        job.task_groups[0].count = 6  # >3: small-K host fallback skipped
+        job.task_groups[0].count = 6  # >3: past the small-ask rule
         server.job_register(job)
         assert wait_until(
             lambda: len(server.fsm.state.allocs_by_job(job.id)) == 6,
             timeout=60.0,
         )
-        assert batcher.batched_requests > before
+        assert batcher.batched_requests == before + 1
+        stats = server.dispatch.stats()
+        assert stats["routed_host"] == 0 and stats["batches"] >= 1, stats
+        assert batcher.stats()["open_cohorts"] == 0
     finally:
         server.shutdown()

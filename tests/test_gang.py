@@ -870,9 +870,7 @@ def test_pipeline_places_gang_atomically():
                 w.parked() for w in server.workers):
             _time.sleep(0.02)
         reset_gang_stats()
-        # a gang job AND plain jobs: the batch clears dense_min_batch,
-        # so the gang rides the dense path (a singleton batch
-        # short-circuits to the host route)
+        # a gang job AND plain jobs in one batch
         job = gang_job(k=4, slice="rack")
         ev, _ = server.job_register(job)
         evals = [ev]

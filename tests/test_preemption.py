@@ -629,7 +629,6 @@ def test_server_preemption_soak_with_victim_lost_chaos():
     server = Server(ServerConfig(
         num_schedulers=2,
         scheduler_factories={"service": "service-tpu"},
-        dense_min_batch=1,
         eval_nack_timeout=2.0,
         eval_delivery_limit=8,
         preemption_enabled=True,

@@ -320,7 +320,7 @@ def storm(tmp_path_factory):
     batcher_mod.get_batcher()._busy_until = 0.0
     server = Server(ServerConfig(
         num_schedulers=2, scheduler_factories={"service": "service-tpu"},
-        eval_batch_size=16, eval_nack_timeout=60.0, dense_min_batch=2))
+        eval_batch_size=16, eval_nack_timeout=60.0))
     server.start()
     http = HTTPServer(server)
     http.start()
