@@ -253,6 +253,13 @@ class DispatchPipeline:
         self.alone_batches = 0  # guarded-by: _lock (launched under _alone)
         self.dispatched_evals = 0  # guarded-by: _lock (sum batch sizes)
         self.largest_batch = 0  # guarded-by: _lock
+        # Batches cut with nothing in flight; of them, those cut on the
+        # first look because no register was on the way, and those that
+        # waited for one up to the cap (idle_grace). The rest waited
+        # until the last register had returned.
+        self.idle_closes = 0  # guarded-by: _lock
+        self.idle_closes_at_once = 0  # guarded-by: _lock
+        self.idle_closes_at_cap = 0  # guarded-by: _lock
         self.routed_host = 0  # guarded-by: _lock (sent to host factory)
         self.acked = 0  # guarded-by: _lock
         self.nacked = 0  # guarded-by: _lock
@@ -336,6 +343,14 @@ class DispatchPipeline:
                 self._notified_at = time.monotonic()
             self._cond.notify_all()
 
+    def arrivals_settled(self) -> None:
+        """The last register on the way has returned
+        (Server._registering): its eval is on the broker or was never
+        made, so an idle accumulator that held its batch for it takes
+        one more pass and cuts, now and not a slice later."""
+        with self._cond:
+            self._cond.notify_all()
+
     def pending_count(self) -> int:
         with self._lock:
             return len(self._pending)
@@ -375,9 +390,13 @@ class DispatchPipeline:
     def _accumulate(self) -> List[_Pending]:
         """Pack the next batch: wait for a seed eval, then top up with
         one central broker drain per pass. Close rules: a FULL batch
-        closes immediately; an idle pipeline closes after `idle_grace`
-        (a lone interactive eval must not marinate); while batches are
-        in flight the accumulator keeps filling for `window` — the
+        closes immediately; an idle pipeline (nothing in flight) closes
+        after the pass's drain as soon as no register is on the way
+        (Server.registers_on_the_way: nobody can join, and a lone
+        interactive eval must not marinate), and while one is it waits
+        for the last of them to return (arrivals_settled wakes it) or
+        for `idle_grace`, the cap, whichever is first; while batches
+        are in flight the accumulator keeps filling for `window` — the
         in-flight round-trip is exactly the budget this wait amortizes
         — and when every slot is busy it simply keeps accumulating
         until one frees."""
@@ -404,6 +423,7 @@ class DispatchPipeline:
             profile.event("accumulate_open", "dispatcher",
                           a=len(self._pending))
         start = time.monotonic()
+        held = False  # an idle pass waited for a register on the way
         while not self._stop.is_set():
             with self._lock:
                 room = self.max_batch - len(self._pending)
@@ -425,8 +445,15 @@ class DispatchPipeline:
                     break
                 slots = 1 if self._alone else self.max_inflight
                 if self._inflight == 0:
-                    if elapsed >= self.idle_grace:
+                    on_the_way = self.server.registers_on_the_way()
+                    if not on_the_way or elapsed >= self.idle_grace:
+                        self.idle_closes += 1
+                        if on_the_way:
+                            self.idle_closes_at_cap += 1
+                        elif not held:
+                            self.idle_closes_at_once += 1
                         break
+                    held = True
                 elif self._inflight < slots and elapsed >= self.window:
                     break
                 announce = (self._forming is None
@@ -933,6 +960,10 @@ class DispatchPipeline:
                 ) if batches else 0.0,
                 "largest_batch": self.largest_batch,
                 "in_flight": self._inflight,
+                "registers_on_the_way": self.server.registers_on_the_way(),
+                "idle_closes": self.idle_closes,
+                "idle_closes_at_once": self.idle_closes_at_once,
+                "idle_closes_at_cap": self.idle_closes_at_cap,
                 "slots": 1 if self._alone else self.max_inflight,
                 "alone": self._alone,
                 "alone_next": self._alone_next,

@@ -54,9 +54,12 @@ class ServerConfig:
     # batches through one at a time (dispatch/pipeline.py).
     dispatch_max_inflight: int = 2
     # Accumulation window while another batch is in flight (its
-    # round-trip is the budget being amortized); the idle grace is all
-    # a batch waits when nothing is in flight — a lone interactive
-    # eval pays only this before its launch.
+    # round-trip is the budget being amortized). With nothing in
+    # flight a batch waits only for arrivals that are known to be on
+    # the way (a register between its entry and its return,
+    # Server.registers_on_the_way): the idle grace is the CAP on that
+    # wait. A lone interactive eval, with nobody on the way, is cut at
+    # once and pays none of it.
     dispatch_window: float = 0.05
     dispatch_idle_grace: float = 0.004
     # Conflict-rejected evals rejoin the accumulating batch at most

@@ -10,6 +10,7 @@ apply() interface.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -178,6 +179,13 @@ class Server:
         self.cluster: Optional[Dict[str, "Server"]] = None
         self.node_id = self.config.node_name or "server-0"
         self._leadership_lock = threading.Lock()
+        # Calls that end in a NEW pending eval on the broker and are
+        # between their entry and their return on this server
+        # (_registering). The dispatch pipeline's idle close reads it:
+        # with nothing in flight and nobody on the way no batch-mate
+        # can come, and a lone eval is cut at once.
+        self._registers_lock = threading.Lock()
+        self._registers_on_the_way = 0  # guarded-by: _registers_lock
         # Gossip membership (serf.go): peers is all known servers keyed
         # by region, local_peers the same-region subset — mirroring
         # server.go:100-104 peers/localPeers.
@@ -904,20 +912,46 @@ class Server:
         if enforce_index:
             payload["enforce_index"] = True
             payload["job_modify_index"] = job_modify_index
-        index = self.log.apply(fsm_msgs.JOB_REGISTER, payload)
-        if enforce_index:
-            self._wait_applied(index)
-            err = self.fsm.outcome(index)
-            if err is not None:
-                raise ValueError(str(err))
+        with self._registering():
+            index = self.log.apply(fsm_msgs.JOB_REGISTER, payload)
+            if enforce_index:
+                self._wait_applied(index)
+                err = self.fsm.outcome(index)
+                if err is not None:
+                    raise ValueError(str(err))
 
-        if job.is_periodic():
-            return "", index
+            if job.is_periodic():
+                return "", index
 
-        stored = self.fsm.state.job_by_id(job.id)
-        ev = new_eval(stored, triggered_by)
-        self.eval_update([ev])
-        return ev.id, index
+            stored = self.fsm.state.job_by_id(job.id)
+            ev = new_eval(stored, triggered_by)
+            self.eval_update([ev])
+            return ev.id, index
+
+    @contextlib.contextmanager
+    def _registering(self):
+        """Brackets a call that ends in `eval_update` of NEW pending
+        evals (a register, a deregister, a forced evaluation, a node's
+        evals): while it runs, an eval is on its way to the broker that
+        the dispatch pipeline cannot see yet. The return that leaves
+        nobody on the way wakes the accumulator, so a burst's batch is
+        cut when its last register has returned. A call that raises or
+        creates no eval after all (a periodic parent) leaves the same
+        way."""
+        with self._registers_lock:
+            self._registers_on_the_way += 1
+        try:
+            yield
+        finally:
+            with self._registers_lock:
+                self._registers_on_the_way -= 1
+                last = self._registers_on_the_way == 0
+            if last:
+                self.dispatch.arrivals_settled()
+
+    def registers_on_the_way(self) -> int:
+        with self._registers_lock:
+            return self._registers_on_the_way
 
     def _wait_applied(self, index: int, timeout: float = 5.0) -> None:
         """Wait until the local FSM has applied `index` (a follower's
@@ -930,20 +964,21 @@ class Server:
 
     def job_deregister(self, job_id: str, create_eval: bool = True) -> Optional[str]:
         job = self.fsm.state.job_by_id(job_id)
-        self.log.apply(fsm_msgs.JOB_DEREGISTER, {"job_id": job_id})
-        if not create_eval or job is None or job.is_periodic():
-            return None
-        ev = Evaluation(
-            id=generate_uuid(),
-            priority=job.priority,
-            type=job.type,
-            triggered_by=consts.EVAL_TRIGGER_JOB_DEREGISTER,
-            job_id=job_id,
-            job_modify_index=job.job_modify_index,
-            status=consts.EVAL_STATUS_PENDING,
-        )
-        self.eval_update([ev])
-        return ev.id
+        with self._registering():
+            self.log.apply(fsm_msgs.JOB_DEREGISTER, {"job_id": job_id})
+            if not create_eval or job is None or job.is_periodic():
+                return None
+            ev = Evaluation(
+                id=generate_uuid(),
+                priority=job.priority,
+                type=job.type,
+                triggered_by=consts.EVAL_TRIGGER_JOB_DEREGISTER,
+                job_id=job_id,
+                job_modify_index=job.job_modify_index,
+                status=consts.EVAL_STATUS_PENDING,
+            )
+            self.eval_update([ev])
+            return ev.id
 
     def job_evaluate(self, job_id: str) -> str:
         """Job.Evaluate: force a new evaluation (job_endpoint.go:236)."""
@@ -953,7 +988,8 @@ class Server:
         if job.is_periodic():
             raise ValueError("can't evaluate periodic job")
         ev = new_eval(job, consts.EVAL_TRIGGER_JOB_REGISTER)
-        self.eval_update([ev])
+        with self._registering():
+            self.eval_update([ev])
         return ev.id
 
     def job_plan(self, job: Job, diff: bool = False, contextual: bool = False) -> dict:
@@ -1209,7 +1245,8 @@ class Server:
                 )
             )
         if evals:
-            self.eval_update(evals)
+            with self._registering():
+                self.eval_update(evals)
         return [e.id for e in evals]
 
     # ----------------------------------------------------------- evals

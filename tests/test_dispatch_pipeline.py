@@ -645,6 +645,9 @@ def test_dispatcher_keeps_accumulating_while_launch_blocks():
         def eval_dequeue_many(self, types, max_n):
             return []
 
+        def registers_on_the_way(self):
+            return 0
+
         def eval_ack(self, eval_id, token):
             pass
 
@@ -1330,4 +1333,220 @@ def test_a_stretch_of_shared_slots_without_a_conflict_resets_the_cost(
         assert stats["alone_next"] == (16 if conflicts else 1)
         assert stats["alone"] == (8 if conflicts else 0)
     finally:
+        server.shutdown()
+
+
+# ---------------------------------------------------------------------
+# the idle close reads whether a register is on the way: an idle
+# pipeline cuts a lone eval at once, and `dispatch_idle_grace` is the
+# cap on waiting for arrivals that are known to be coming
+
+
+def _idle_pipe(grace):
+    """A server without workers whose pipeline is an unstarted one we
+    drive: the server's own wake (the last register's return) reaches
+    it."""
+    server = make_server(num_schedulers=0, dispatch_idle_grace=grace)
+    server.dispatch.stop()
+    server.dispatch = _unstarted_pipe(server)
+    return server, server.dispatch
+
+
+def _idle_counts(pipe):
+    stats = pipe.stats()
+    return (stats["idle_closes"], stats["idle_closes_at_once"],
+            stats["idle_closes_at_cap"])
+
+
+def test_an_idle_pipeline_cuts_a_lone_eval_at_once():
+    from nomad_tpu.dispatch.pipeline import _Pending
+
+    server, pipe = _idle_pipe(0.5)
+    try:
+        assert pipe.stats()["registers_on_the_way"] == 0
+        pipe._admit(_Pending(mock.eval(), "tok-0"))
+        t0 = time.monotonic()
+        batch = pipe._accumulate()
+        assert time.monotonic() - t0 < 0.1, "waited for nobody"
+        assert len(batch) == 1
+        assert pipe._forming is None
+        assert _idle_counts(pipe) == (1, 1, 0)
+    finally:
+        server.shutdown()
+
+
+def test_a_register_on_the_way_holds_the_batch_until_it_has_returned(
+        monkeypatch):
+    """With a register on the way the accumulator holds the eval, a
+    second eval admitted meanwhile rides the same batch, and the batch
+    is cut when the last register returns: woken by that return (the
+    slice is out of the way here), well before the cap."""
+    import contextlib
+
+    from nomad_tpu.dispatch import pipeline as pipeline_mod
+    from nomad_tpu.dispatch.pipeline import _Pending
+
+    monkeypatch.setattr(pipeline_mod, "DEQUEUE_TOPUP_SLICE", 5.0)
+    server, pipe = _idle_pipe(30.0)
+    try:
+        on_the_way = contextlib.ExitStack()
+        on_the_way.enter_context(server._registering())
+        assert pipe.stats()["registers_on_the_way"] == 1
+        pipe._admit(_Pending(mock.eval(), "tok-0"))
+        t, got = _cut_in_thread(pipe)
+        time.sleep(0.1)
+        assert not got, "cut while a register was on the way"
+        pipe._admit(_Pending(mock.eval(), "tok-1"))
+        time.sleep(0.1)
+        assert not got
+        t0 = time.monotonic()
+        on_the_way.close()
+        t.join(timeout=5.0)
+        assert time.monotonic() - t0 < 1.0, "the return woke nobody"
+        assert got and len(got[0]) == 2
+        assert pipe.stats()["registers_on_the_way"] == 0
+        assert _idle_counts(pipe) == (1, 0, 0)
+    finally:
+        server.shutdown()
+
+
+def test_a_register_that_never_returns_is_waited_for_up_to_the_cap():
+    from nomad_tpu.dispatch.pipeline import _Pending
+
+    server, pipe = _idle_pipe(0.1)
+    try:
+        with server._registering():
+            pipe._admit(_Pending(mock.eval(), "tok-0"))
+            t0 = time.monotonic()
+            batch = pipe._accumulate()
+            waited = time.monotonic() - t0
+            assert len(batch) == 1
+            assert 0.1 <= waited < 1.0, waited
+            assert _idle_counts(pipe) == (1, 0, 1)
+    finally:
+        server.shutdown()
+
+
+def _count_at_eval_update(server):
+    """The count as each `eval_update` sees it, from here on."""
+    seen = []
+    eval_update = server.eval_update
+
+    def watched(evals, token=""):
+        seen.append(server.registers_on_the_way())
+        return eval_update(evals, token)
+
+    server.eval_update = watched
+    return seen
+
+
+def _registered_job(server):
+    job = _sized_job("held")
+    server.job_register(job)
+    return job
+
+
+@pytest.mark.parametrize("call", [
+    lambda server, job: server.job_register(_sized_job("fresh")),
+    lambda server, job: server.job_deregister(job.id),
+    lambda server, job: server.job_evaluate(job.id),
+    lambda server, job: server.node_update_drain(
+        seed_nodes(server, 1)[0].id, True),
+], ids=["register", "deregister", "evaluate", "node-drain"])
+def test_every_call_that_creates_an_eval_is_on_the_way_until_it_returns(
+        call):
+    server = make_server(num_schedulers=0)
+    try:
+        seed_nodes(server, 2)
+        job = _registered_job(server)
+        server.job_register(mock.system_job())  # what a drain evaluates
+        seen = _count_at_eval_update(server)
+        call(server, job)
+        assert seen == [1], "the eval went on the broker uncounted"
+        assert server.registers_on_the_way() == 0
+        assert server.dispatch.stats()["registers_on_the_way"] == 0
+    finally:
+        server.shutdown()
+
+
+def _invalid_job(server):
+    job = _sized_job("invalid")
+    job.task_groups = []
+    return job, {}
+
+
+def _stale_index(server):
+    job = _registered_job(server)
+    return job, {"enforce_index": True,
+                 "job_modify_index": job.job_modify_index + 7}
+
+
+def _apply_raises(server):
+    def boom(*args, **kwargs):
+        raise RuntimeError("raft apply")
+
+    server.log.apply = boom
+    return _sized_job("lost"), {}
+
+
+@pytest.mark.parametrize("refused, error", [
+    (_invalid_job, ValueError),
+    (_stale_index, ValueError),
+    (_apply_raises, RuntimeError),
+], ids=["invalid-job", "enforce-index", "apply-raises"])
+def test_a_register_that_raises_leaves_nobody_on_the_way(refused, error):
+    server = make_server(num_schedulers=0)
+    try:
+        job, kwargs = refused(server)
+        with pytest.raises(error):
+            server.job_register(job, **kwargs)
+        assert server.registers_on_the_way() == 0
+    finally:
+        server.shutdown()
+
+
+def test_a_lone_registration_is_cut_at_once_on_a_live_server():
+    """Through the whole path (register, broker, worker, accumulator)
+    with a grace no test would sit out: the register has returned when
+    its eval reaches the accumulator, so nothing is waited for."""
+    server = make_server(dispatch_idle_grace=30.0)
+    try:
+        seed_nodes(server, 4)
+        job = _sized_job("lone")
+        server.job_register(job)
+        assert wait_until(
+            lambda: len(server.fsm.state.allocs_by_job(job.id)) == 5,
+            timeout=120.0), server.dispatch.stats()
+        stats = server.dispatch.stats()
+        assert stats["batches"] == stats["idle_closes"] == 1, stats
+        assert stats["idle_closes_at_cap"] == 0, stats
+    finally:
+        server.shutdown()
+
+
+def test_agent_self_serves_the_count_and_the_idle_closes():
+    """A register over HTTP is on the way while its eval goes to the
+    broker and not after its reply, a refused body leaves nobody on
+    the way, and `/v1/agent/self` serves the count and the idle
+    closes."""
+    from nomad_tpu.api import Client, HTTPServer
+
+    server = make_server(num_schedulers=0)
+    http = HTTPServer(server)
+    http.start()
+    try:
+        seen = _count_at_eval_update(server)
+        client = Client(http.addr, timeout=10.0)
+        client.jobs.register(_sized_job("over-http"))
+        assert seen == [1]
+        assert server.registers_on_the_way() == 0
+        with pytest.raises(Exception):
+            client.jobs.register(_invalid_job(server)[0])
+        assert server.registers_on_the_way() == 0
+        pipe = client.agent.self()["stats"]["dispatch_pipeline"]
+        assert pipe["registers_on_the_way"] == 0
+        assert {"idle_closes", "idle_closes_at_once",
+                "idle_closes_at_cap"} <= set(pipe)
+    finally:
+        http.stop()
         server.shutdown()
