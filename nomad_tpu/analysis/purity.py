@@ -29,7 +29,7 @@ Rules inside traced code:
   depends on traced values (concretization error / silent recompile
   per shape). Tests over STATIC parameters (``static_argnames``),
   shape/dtype queries (``x.shape``, ``len()``, ``np.shape``), module
-  globals, and constants are fine and common (``if config.pre_resolve``).
+  globals, and constants are fine and common (``if config.uniform_dh``).
 
 Call-site rule (applies everywhere, not just traced code):
 

@@ -256,7 +256,7 @@ class HTTPServer:
                         else:
                             self._reply_body(api._blocking_threadpark(
                                 sig.items, sig.min_index, sig.deadline,
-                                sig.run, sig.headers, True))
+                                sig.run, sig.headers))
                     except HTTPError as e:
                         self._reply(e.status, {"error": e.message})
                     except Exception as e:  # noqa: BLE001
@@ -582,7 +582,7 @@ class HTTPServer:
 
         Queries that must park go to the read mux (_ParkSignal) so no
         HTTP thread waits; the thread-parking loop remains as the
-        mux-full / global-index-arm fallback."""
+        mux-full fallback."""
         min_index = int(query.get("index", ["0"])[0])
         requested = float(query.get("wait", [DEFAULT_BLOCKING_WAIT])[0])
         wait = min(requested, MAX_BLOCKING_WAIT)
@@ -595,7 +595,6 @@ class HTTPServer:
             headers["X-Nomad-Effective-Wait"] = f"{wait:.3f}"
         server = self.server
         state = server.fsm.state
-        scoped = getattr(server.config, "read_scoped_index", True)
         stale = _qflag(query, "stale")
         consistent = _qflag(query, "consistent")
         if stale and consistent:
@@ -612,35 +611,27 @@ class HTTPServer:
                 raise HTTPError(
                     504, f"consistent read barrier timed out: {e}")
 
-        def cur_index() -> int:
-            return (state.scope_index(items) if scoped
-                    else state.latest_index())
-
-        if min_index <= 0 or cur_index() > min_index:
-            return JSONResponse(run(), index=max(cur_index(), 1),
-                                headers=headers)
+        if min_index <= 0 or state.scope_index(items) > min_index:
+            return JSONResponse(
+                run(), index=max(state.scope_index(items), 1),
+                headers=headers)
         deadline = time.monotonic() + wait
-        mux = getattr(server, "read_mux", None)
-        if scoped and mux is not None:
+        if getattr(server, "read_mux", None) is not None:
             raise _ParkSignal(items, min_index, deadline, run, headers)
         return self._blocking_threadpark(
-            items, min_index, deadline, run, headers, scoped)
+            items, min_index, deadline, run, headers)
 
     def _blocking_threadpark(self, items, min_index: int, deadline: float,
-                             run, headers, scoped: bool) -> "JSONResponse":
+                             run, headers) -> "JSONResponse":
         """The pre-mux blocking loop: park THIS handler thread on the
         watch until satisfied or expired. What runs with
-        `read_mux_enabled=false` / `read_scoped_index=false`, and the
-        overflow path when the mux is full."""
+        `read_mux_enabled=false`, and the overflow path when the mux
+        is full."""
         state = self.server.fsm.state
-
-        def cur_index() -> int:
-            return (state.scope_index(items) if scoped
-                    else state.latest_index())
 
         while True:
             ev = state.watch(items)
-            if cur_index() > min_index:
+            if state.scope_index(items) > min_index:
                 state.stop_watch(items, ev)
                 break
             remaining = deadline - time.monotonic()
@@ -649,7 +640,7 @@ class HTTPServer:
                 break
             ev.wait(min(remaining, 1.0))
             state.stop_watch(items, ev)
-        return JSONResponse(run(), index=max(cur_index(), 1),
+        return JSONResponse(run(), index=max(state.scope_index(items), 1),
                             headers=headers)
 
     def _park_handler(self, handler, sig: "_ParkSignal") -> bool:
@@ -680,9 +671,7 @@ class HTTPServer:
             except Exception as e:  # noqa: BLE001
                 payload, status = {"error": str(e)}, 500
             state = server.fsm.state
-            scoped = getattr(server.config, "read_scoped_index", True)
-            index = (state.scope_index(scopes) if scoped
-                     else state.latest_index())
+            index = state.scope_index(scopes)
             headers = dict(sig.headers)
             if "X-Nomad-LastContact" in headers:
                 # Staleness is measured at SERVE time, not park time.

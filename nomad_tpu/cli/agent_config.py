@@ -35,15 +35,9 @@ class ServerBlock:
     # Batch tuning (server/config.py): max dense-factory evals the
     # dispatch pipeline packs into one batch.
     eval_batch_size: Optional[int] = None
-    # Central dispatch pipeline knobs (server/config.py dispatch_*):
-    # batches in flight, and the device-side in-batch conflict
-    # pre-resolution toggle.
+    # Central dispatch pipeline (server/config.py dispatch_*): batches
+    # in flight.
     dispatch_max_inflight: Optional[int] = None
-    dense_pre_resolve: Optional[bool] = None
-    # Device-resident node state (models/resident.py): enable knob +
-    # the delta-vs-rebuild row threshold (0 = auto).
-    device_resident: Optional[bool] = None
-    resident_rebuild_rows: Optional[int] = None
     # Placement kernel (nomad_tpu/kernels): the dense solve the *-tpu
     # factories run ("greedy" / "convex" / a plugin's); validated at
     # server init.
@@ -71,10 +65,9 @@ class ServerBlock:
     breaker_failure_threshold: Optional[int] = None
     breaker_cooldown: Optional[float] = None
     # Contention observatory (nomad_tpu/profile; server/config.py):
-    # recording + GIL sampler switch, sampler cadence, and the
-    # pressure-monitor lock-wait p99 thresholds (ms; 0 disables).
+    # recording + GIL sampler switch, and the pressure-monitor
+    # lock-wait p99 thresholds (ms; 0 disables).
     profile_enabled: Optional[bool] = None
-    gil_sampler_interval: Optional[float] = None
     admission_lock_wait_yellow_ms: Optional[float] = None
     admission_lock_wait_red_ms: Optional[float] = None
 
@@ -230,8 +223,7 @@ _SCHEMA: Dict[str, Any] = {
     "server.node_gc_threshold": str, "server.heartbeat_grace": str,
     "server.retry_join": _str_list, "server.start_join": _str_list,
     "server.eval_batch_size": int,
-    "server.dispatch_max_inflight": int, "server.dense_pre_resolve": bool,
-    "server.device_resident": bool, "server.resident_rebuild_rows": int,
+    "server.dispatch_max_inflight": int,
     "server.placement_kernel": str,
     "server.migrate_max_parallel": int,
     "server.preemption_enabled": bool,
@@ -245,7 +237,6 @@ _SCHEMA: Dict[str, Any] = {
     "server.breaker_failure_threshold": int,
     "server.breaker_cooldown": float,
     "server.profile_enabled": bool,
-    "server.gil_sampler_interval": float,
     "server.admission_lock_wait_yellow_ms": float,
     "server.admission_lock_wait_red_ms": float,
     "client.enabled": bool, "client.state_dir": str,

@@ -661,14 +661,6 @@ def cmd_agent(args) -> int:
         if cfg.server.dispatch_max_inflight is not None:
             server_cfg.dispatch_max_inflight = (
                 cfg.server.dispatch_max_inflight)
-        if cfg.server.dense_pre_resolve is not None:
-            server_cfg.dense_pre_resolve = cfg.server.dense_pre_resolve
-        # Device-resident node state (models/resident.py).
-        if cfg.server.device_resident is not None:
-            server_cfg.device_resident = cfg.server.device_resident
-        if cfg.server.resident_rebuild_rows is not None:
-            server_cfg.resident_rebuild_rows = (
-                cfg.server.resident_rebuild_rows)
         # Placement kernel (nomad_tpu/kernels); Server init validates,
         # so a typo'd name aborts agent startup with the known list.
         if cfg.server.placement_kernel is not None:
@@ -717,9 +709,6 @@ def cmd_agent(args) -> int:
         # Contention observatory (nomad_tpu/profile).
         if cfg.server.profile_enabled is not None:
             server_cfg.profile_enabled = cfg.server.profile_enabled
-        if cfg.server.gil_sampler_interval is not None:
-            server_cfg.gil_sampler_interval = (
-                cfg.server.gil_sampler_interval)
         if cfg.server.admission_lock_wait_yellow_ms is not None:
             server_cfg.admission_lock_wait_yellow_ms = (
                 cfg.server.admission_lock_wait_yellow_ms)

@@ -24,9 +24,9 @@ device dispatch:
   (RefreshIndex) does not replan alone on a fresh snapshot (a 1-3
   alloc retry that pays a full round-trip, r05's retry tax); the eval
   is folded back into the ACCUMULATING batch and replans with the next
-  full dispatch. In-batch collisions are already pre-resolved on
-  device (ops/binpack.py PlacementConfig.pre_resolve), so requeues are
-  the cross-batch residue only.
+  full dispatch. In-batch collisions are already resolved on device
+  (a dispatch's lanes plan in order on one carry, ops/binpack.py), so
+  requeues are the cross-batch residue only.
 
 The pipeline preserves the worker path's contracts: per-job broker
 serialization (a drained batch is always over distinct jobs), eval
@@ -57,6 +57,9 @@ from ..utils.backoff import poll_until
 DEQUEUE_TOPUP_SLICE = 0.002  # cond-wait granularity while accumulating
 SLOT_WAIT_SLICE = 0.02  # cond-wait granularity while all slots busy
 WAIT_INDEX_TIMEOUT = 5.0
+# Plan-conflict requeues one eval may take before it retries inline on
+# a fresh snapshot (PipelineSession.submit_plan).
+MAX_REQUEUES = 3
 # Most batches one plan conflict can send through the pipeline one at a
 # time (DispatchPipeline._note_conflict doubles up to here).
 ALONE_MAX = 64
@@ -96,12 +99,11 @@ class _Pending:
 
 class PipelineSession(EvalSession):
     """Per-eval Planner for pipeline-processed evals. Inherits the
-    whole Planner contract (pause-nack framing, eval updates, reblock,
-    pre_resolve wiring) from server/worker.py EvalSession — one
-    implementation to keep in sync — and overrides only the
-    plan-conflict handling: refreshes raise _RequeueConflict (bounded,
-    side-effect-guarded) so the retry rides the ACCUMULATING batch
-    instead of replanning alone."""
+    whole Planner contract (pause-nack framing, eval updates, reblock)
+    from server/worker.py EvalSession — one implementation to keep in
+    sync — and overrides only the plan-conflict handling: refreshes
+    raise _RequeueConflict (bounded, side-effect-guarded) so the retry
+    rides the ACCUMULATING batch instead of replanning alone."""
 
     def __init__(self, pipeline: "DispatchPipeline", entry: _Pending,
                  cohort=None):
@@ -161,7 +163,7 @@ class PipelineSession(EvalSession):
         if result.refresh_index:
             self.pipeline._note_conflict()
             if (self.created_evals == 0
-                    and self.entry.requeues < self.pipeline.max_requeues):
+                    and self.entry.requeues < MAX_REQUEUES):
                 # Replan as part of the next packed batch — which must
                 # snapshot at or past this plan's partial commit.
                 self.entry.min_index = max(self.entry.min_index,
@@ -189,8 +191,6 @@ class DispatchPipeline:
         self.max_inflight = max(1, cfg.dispatch_max_inflight)
         self.window = cfg.dispatch_window
         self.idle_grace = cfg.dispatch_idle_grace
-        self.max_requeues = cfg.dispatch_max_requeues
-        self.pre_resolve = cfg.dense_pre_resolve
         # The eval types whose factories are dense — what the central
         # drain pulls from the broker.
         from ..server.worker import is_dense_factory
@@ -681,9 +681,9 @@ class DispatchPipeline:
             metrics.incr_counter(("dispatch", "route_host"), len(batch))
         # One MVCC snapshot for the whole batch: every member plans
         # against the same cluster state so their ClusterMatrix bases
-        # share one token, one device upload, and (pre_resolve) one
-        # serialized claim scan. Optimistic concurrency keeps it
-        # safe: the applier re-verifies every node.
+        # share one token, one device upload, and one serialized
+        # claim scan. Optimistic concurrency keeps it safe: the applier
+        # re-verifies every node.
         max_index = max(max(e.eval.modify_index, e.min_index)
                         for e in batch)
         if (forming is not None
@@ -733,11 +733,8 @@ class DispatchPipeline:
         jobs each resolve one base. Failures are non-fatal: place()
         falls back to uploading synchronously, exactly as before."""
         from ..models.matrix import prefetch_cluster_base
-        from ..models.resident import get_tracker
         from ..scheduler.batcher import get_batcher
 
-        if not get_tracker().is_enabled():
-            return
         dc_sets = {}
         for entry in batch:
             if entry.eval.type == consts.JOB_TYPE_SYSTEM:

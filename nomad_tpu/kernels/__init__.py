@@ -10,7 +10,7 @@ exact signature —
 `placement_program` dispatches to the registered kernel named by
 ``PlacementConfig.kernel`` (a static/compile-time field, so every
 kernel gets its own cached XLA program and rides the batcher's
-overlay / compact / pre-resolve / fused-delta paths unchanged — the
+overlay / compact / fused-delta paths unchanged — the
 kernel swaps only HOW the solve is computed, never how batches form,
 how bases become device-resident, or how plans commit).
 

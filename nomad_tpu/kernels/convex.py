@@ -42,7 +42,7 @@ This kernel solves the JOINT problem first, then rounds:
    against the CPU oracle).
 
 Pure and transform-safe: vmap-able over the batch axis, scan-able
-under pre_resolve, exactly like the greedy program — the batcher's
+over the lanes' carry, exactly like the greedy program — the batcher's
 overlay/compact/fused-delta paths ride unchanged.
 """
 

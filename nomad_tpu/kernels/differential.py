@@ -783,7 +783,7 @@ def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False):
             placements = [0] * count
             arrays = matrix.build_asks(placements)
             config = build_placement_config(
-                job.type == "batch", True, "greedy", placements, arrays)
+                job.type == "batch", "greedy", placements, arrays)
             batcher = shared or PlacementBatcher(window=0.0)
             choices, _scores = batcher.place(
                 matrix, make_asks(*arrays), host_prng_key(seed * 31 + i),

@@ -820,41 +820,6 @@ def compute_class_index(nodes) -> Tuple[np.ndarray, List[int]]:
 _CLASS_INDEX_CACHE: Dict[Tuple, Tuple[np.ndarray, List[int]]] = {}
 _CLASS_INDEX_MAX = 4
 
-# Ready-node LIST cached per (snapshot nodes-index, dc set): the
-# central dispatch pipeline fans a full 64-eval batch out against one
-# snapshot, and each eval's ClusterMatrix would otherwise re-walk all
-# N node objects (ready_nodes_in_dcs is an O(N) python scan — 64 x 10k
-# attribute reads per batch, all under the GIL while the batcher's
-# accumulation window is ticking). Readiness depends only on the nodes
-# table, so the nodes index keys it exactly. Callers treat the cached
-# (nodes, by_dc) pair as immutable.
-_READY_NODES_CACHE: Dict[Tuple, Tuple[List[Node], Dict[str, int]]] = {}
-_READY_NODES_MAX = 4
-
-
-def ready_nodes_cached(state, datacenters):
-    """ready_nodes_in_dcs with a per-snapshot memo (see note above).
-    Falls through to the plain scan for stateless snapshots (tests,
-    shadow stores)."""
-    key = None
-    if hasattr(state, "index") and getattr(state, "store_id", ""):
-        key = (state.store_id, state.index("nodes"),
-               tuple(sorted(datacenters or [])))
-        with _BASE_CACHE_LOCK:
-            hit = _READY_NODES_CACHE.get(key)
-        if hit is not None:
-            return hit
-    from ..scheduler.util import ready_nodes_in_dcs
-
-    out = ready_nodes_in_dcs(state, datacenters)
-    if key is not None:
-        with _BASE_CACHE_LOCK:
-            while len(_READY_NODES_CACHE) >= _READY_NODES_MAX:
-                _READY_NODES_CACHE.pop(next(iter(_READY_NODES_CACHE)))
-            _READY_NODES_CACHE[key] = out
-    return out
-
-
 # Feasibility memo per (node axis, job constraint signature): the
 # [N, G] mask depends only on the nodes (their computed classes and,
 # for escaped constraints and classless nodes, their own attributes)
@@ -944,7 +909,9 @@ _UNIVERSE_MAX = 4
 
 def universe_nodes_cached(state, datacenters):
     """(nodes, ready_by_dc, ids_sig) over the full dc node universe;
-    memoized per snapshot nodes-index like ready_nodes_cached."""
+    memoized per snapshot nodes-index: the dispatch pipeline fans a
+    whole batch out against one snapshot, and each eval's ClusterMatrix
+    would otherwise re-walk all N node objects under the GIL."""
     key = None
     if hasattr(state, "index") and getattr(state, "store_id", ""):
         key = (state.store_id, state.index("nodes"),
@@ -1133,25 +1100,22 @@ def resolve_cluster_base(state, datacenters, nodes=None, explicit=False,
     Module-level (job-free) on purpose: the dispatch pipeline prefetches
     batch k+1's base under batch k's in-flight compute with no job in
     hand (dispatch/pipeline.py), and ClusterMatrix delegates here for
-    its own build. With `nodes=None` the node list derives from the
-    resident universe (or the ready set when resident state is off).
+    its own build. With `nodes=None` the node list is the resident
+    universe; `explicit` marks a caller's own list (the system path's
+    pinned subsets), which keeps a family of its own.
 
     Returns (base, kind) with kind in "hit" | "rekey" | "delta" |
-    "full". Family keying is the residency core: with device-resident
-    state enabled the family keys on the node-SET identity instead of
-    the nodes-table index, so node up/down/drain transitions (which
-    bump the index but keep the set) delta against the previous base
-    instead of starting a new family — the delta chain only breaks when
-    nodes register/deregister (the universe signature moves)."""
+    "full". Family keying is the residency core: the universe's family
+    keys on the node-SET identity instead of the nodes-table index, so
+    node up/down/drain transitions (which bump the index but keep the
+    set) delta against the previous base instead of starting a new
+    family — the delta chain only breaks when nodes register/deregister
+    (the universe signature moves)."""
     from .resident import get_tracker
 
     tracker = get_tracker()
-    resident = tracker.is_enabled() and not explicit
     if nodes is None:
-        if resident:
-            nodes, _by_dc, _sig = universe_nodes_cached(state, datacenters)
-        else:
-            nodes, _by_dc = ready_nodes_cached(state, datacenters)
+        nodes, _by_dc, _sig = universe_nodes_cached(state, datacenters)
     if proposed_fn is None:
         from ..scheduler.util import proposed_allocs_for_node
 
@@ -1166,20 +1130,20 @@ def resolve_cluster_base(state, datacenters, nodes=None, explicit=False,
         # Caller-provided node lists (the system path's pinned
         # subsets) need their identity in the key: two different
         # subsets of equal size on one snapshot must not collide.
-        # The derived full-ready-set is determined by (nodes index,
-        # dcs), so a constant marker suffices there.
+        # The universe is determined by (nodes index, dcs), so a
+        # constant marker suffices there.
         nodes_sig = (hash(tuple(n.id for n in nodes)) if explicit else 0)
         nodes_idx = state.index("nodes")
         allocs_idx = state.index("allocs")
         key = (state.store_id, nodes_idx, allocs_idx, dcs,
                len(nodes), nodes_sig)
-        if resident:
+        if explicit:
+            family = (state.store_id, nodes_idx, dcs,
+                      len(nodes), nodes_sig)
+        else:
             _unodes, _by_dc, usig = universe_nodes_cached(
                 state, datacenters)
             family = (state.store_id, "resident", dcs, usig)
-        else:
-            family = (state.store_id, nodes_idx, dcs,
-                      len(nodes), nodes_sig)
         if tracker.consume_stale():
             # A plan-apply rejection marked the resident chain suspect:
             # whatever matrix the scheduler planned against disagreed
@@ -1215,7 +1179,7 @@ def resolve_cluster_base(state, datacenters, nodes=None, explicit=False,
             if prev is not None and 0 <= prev.allocs_index <= allocs_idx:
                 base = prev.delta_update(
                     nodes, state, allocs_idx,
-                    new_nodes_index=nodes_idx if resident else -1)
+                    new_nodes_index=-1 if explicit else nodes_idx)
                 if base is prev:
                     kind = "rekey"
                 elif base is not None:
@@ -1228,12 +1192,12 @@ def resolve_cluster_base(state, datacenters, nodes=None, explicit=False,
                     nodes, proposed_fn,
                     allocs_index=allocs_idx if key is not None else -1,
                     table_len=table_len,
-                    nodes_index=nodes_idx if (key is not None and resident)
-                    else -1)
+                    nodes_index=-1 if (key is None or explicit)
+                    else nodes_idx)
                 kind = "full"
                 if key is not None:
                     tracker.count_full()
-                    if resident and prev is None:
+                    if not explicit and prev is None:
                         # No family base to delta from: first build, or
                         # the node SET itself changed (register/
                         # deregister) — the one transition that must
@@ -1347,17 +1311,12 @@ class ClusterMatrix:
             JOBPOS_BUCKETS)
         self._explicit_nodes = nodes is not None
         if nodes is None:
-            from .resident import get_tracker
-
-            if get_tracker().is_enabled():
-                # Resident universe: ALL dc nodes, readiness as the
-                # node_ok row bit — up/down/drain flips become deltas
-                # against the device-resident base instead of changing
-                # the matrix shape (models/resident.py).
-                nodes, by_dc, _sig = universe_nodes_cached(
-                    state, job.datacenters)
-            else:
-                nodes, by_dc = ready_nodes_cached(state, job.datacenters)
+            # Resident universe: ALL dc nodes, readiness as the
+            # node_ok row bit — up/down/drain flips become deltas
+            # against the device-resident base instead of changing
+            # the matrix shape (models/resident.py).
+            nodes, by_dc, _sig = universe_nodes_cached(
+                state, job.datacenters)
             self.nodes_by_dc = by_dc
         else:
             self.nodes_by_dc = {}

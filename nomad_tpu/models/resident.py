@@ -23,8 +23,8 @@ plane for that residency:
   under the tensor's own token (scheduler/batcher.py
   ``_device_topology``), so a column is uploaded once for every rebuild
   of the node set (``topo_uploads``), not once a base token.
-- **rebuild policy** — thresholds for when a delta stops being worth
-  it (too many touched rows) or stops being *possible* (alloc
+- **rebuild policy** — when a delta stops being worth it (too many
+  touched rows: ``max_refill_rows``) or stops being *possible* (alloc
   deletions, node registrations, capacity edits), with counters that
   tell the two cases apart.
 - **staleness safety net** — the plan applier re-verifies every node
@@ -51,18 +51,12 @@ from __future__ import annotations
 import threading
 from typing import Dict, Optional
 
-# Default max refilled rows before a full rebuild is the better deal;
-# mirrors the historical inline policy in _ClusterBase.delta_update.
-AUTO_REBUILD_ROWS = 0  # 0 = max(64, n_real // 4)
-
 
 class ResidentStateTracker:
     """Counters + policy for the device-resident node matrix."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self.enabled = True  # guarded-by: _lock (universe + node deltas)
-        self.rebuild_rows = AUTO_REBUILD_ROWS  # guarded-by: _lock
         # Build-mode counters. full_rebuilds counts every from-scratch
         # _ClusterBase on the cacheable path; the *_reason counters
         # attribute why the delta path was skipped.
@@ -95,22 +89,11 @@ class ResidentStateTracker:
 
     # ------------------------------------------------------------ policy
 
-    def configure(self, enabled: Optional[bool] = None,
-                  rebuild_rows: Optional[int] = None) -> None:
-        with self._lock:
-            if enabled is not None:
-                self.enabled = bool(enabled)
-            if rebuild_rows is not None:
-                self.rebuild_rows = int(rebuild_rows)
-
-    def is_enabled(self) -> bool:
-        with self._lock:
-            return self.enabled
-
-    def max_refill_rows(self, n_real: int) -> int:
-        with self._lock:
-            limit = self.rebuild_rows
-        return limit if limit > 0 else max(64, n_real // 4)
+    @staticmethod
+    def max_refill_rows(n_real: int) -> int:
+        """Most refilled rows a delta may carry before a full rebuild
+        is the better deal."""
+        return max(64, n_real // 4)
 
     # --------------------------------------------------------- staleness
 
@@ -165,7 +148,6 @@ class ResidentStateTracker:
     def stats(self) -> Dict[str, object]:
         with self._lock:
             return {
-                "enabled": self.enabled,
                 "full_rebuilds": self.full_rebuilds,
                 "delta_updates": self.delta_updates,
                 "node_delta_updates": self.node_delta_updates,
@@ -185,11 +167,6 @@ _tracker = ResidentStateTracker()
 
 def get_tracker() -> ResidentStateTracker:
     return _tracker
-
-
-def configure(enabled: Optional[bool] = None,
-              rebuild_rows: Optional[int] = None) -> None:
-    _tracker.configure(enabled=enabled, rebuild_rows=rebuild_rows)
 
 
 def note_rejection() -> None:

@@ -47,18 +47,6 @@ class PlacementConfig(NamedTuple):
     """Static (compile-time) knobs."""
 
     anti_affinity_penalty: float  # 10 service / 5 batch (stack.go:14-18)
-    # In-batch conflict pre-resolution: serialize the EVAL axis of a
-    # shared-base batch on device (lax.scan instead of vmap) so eval
-    # i+1 plans against the capacity/bandwidth/ports that evals 0..i
-    # already claimed — the in-batch analog of the plan applier's
-    # serialization (plan_apply.go:194). Without it, B evals planning
-    # against one snapshot argmax toward the same headroom and the
-    # applier rejects the collisions, each rejection costing a full
-    # dispatch round-trip to replan. Per-JOB state (job_count/tg_count)
-    # stays per-eval — distinct jobs never share anti-affinity. Only
-    # the shared-base paths honor this; the mixed-base stacked path has
-    # no shared capacity to carry.
-    pre_resolve: bool = False
     # Per-eval tie-break noise, in FITNESS units. This is the dense
     # analog of the reference's shuffled power-of-two-choices
     # (stack.go:120-132 LimitIterator): concurrent evals planning
@@ -379,7 +367,7 @@ def placement_program(
     kernel registry (nomad_tpu/kernels) and runs in this program's
     place — same signature, same validity mask, different solve. The
     branch is on a STATIC config field, so it happens at trace time
-    and every batcher path (overlay/compact/pre-resolve/fused-delta)
+    and every batcher path (overlay/compact/fused-delta)
     carries any kernel unchanged."""
     if config.kernel != "greedy":
         from ..kernels import kernel_program
@@ -442,32 +430,28 @@ def batched_placement_program(states: NodeState, asks: Asks, keys, config: Place
     )(states, asks, keys)
 
 
-# vmap axes for the overlay path: the job-independent cluster base
-# (capacity/util/bandwidth/ports/node_ok) is SHARED across the eval
-# batch (in_axes=None — one device copy, no per-eval transfer), while
-# the per-job overlay (this job's alloc counts + constraint mask) and
-# the asks carry the batch axis.
-_OVERLAY_STATE_AXES = NodeState(
-    capacity=None, sched_capacity=None, util=None, bw_avail=None,
-    bw_used=None, ports_free=None, job_count=0, tg_count=0,
-    feasible=0, node_ok=None,
-)
-_OVERLAY_ASKS_AXES = Asks(
-    resources=0, bw=0, ports=0, tg_index=0, active=0,
-    job_distinct_hosts=0, tg_distinct_hosts=0,
-)
+@functools.partial(jax.jit, static_argnames=("config",))
+def batched_placement_program_overlay(
+    state: NodeState, asks: Asks, keys, config: PlacementConfig
+):
+    """Batched evals of DIFFERENT jobs against one shared snapshot: the
+    heavy [N,4] base matrices are unbatched (uploaded once per
+    snapshot, cached on device by the batcher), while job_count [B,N],
+    tg_count/feasible [B,N,G], asks, and keys carry the batch axis.
+    This is what makes live broker-drain batches cheap: per dispatch
+    only the small per-job overlays move host->device.
 
-
-def _overlay_seq(state: NodeState, asks: Asks, keys,
-                 config: PlacementConfig):
-    """Pre-resolving variant of the overlay batch: a lax.scan over the
-    EVAL axis whose carry is the shared mutable cluster state (util,
-    bw_used, ports_free), so each eval's placements see every earlier
-    eval's claims — conflicts are resolved inside the dispatch instead
-    of by plan-applier rejection + replan round-trips. The per-job
-    overlay fields (job_count/tg_count/feasible) stay per-eval: they
-    describe the eval's OWN job. Batch-padding rows scan AFTER the real
-    rows, so their phantom claims never affect a real output."""
+    The eval axis is a lax.scan whose carry is the shared mutable
+    cluster state (util, bw_used, ports_free), so eval i+1 plans
+    against what evals 0..i claimed — the in-batch analog of the plan
+    applier's serialization (plan_apply.go:194). B evals planning
+    independently against one snapshot argmax toward the same headroom
+    and the applier rejects the collisions, each rejection a full
+    dispatch round-trip to replan. The per-job overlay fields
+    (job_count/tg_count/feasible) stay per-eval: they describe the
+    eval's OWN job. Batch-padding rows scan AFTER the real rows, so
+    their phantom claims never affect a real output. Returns the
+    lanes' final carry after the choices and scores."""
 
     def body(carry, xs):
         util, bw_used, ports_free = carry
@@ -484,28 +468,6 @@ def _overlay_seq(state: NodeState, asks: Asks, keys,
     xs = ((state.job_count, state.tg_count, state.feasible), asks, keys)
     carry, (choices, scores) = jax.lax.scan(body, carry0, xs)
     return choices, scores, carry
-
-
-@functools.partial(jax.jit, static_argnames=("config",))
-def batched_placement_program_overlay(
-    state: NodeState, asks: Asks, keys, config: PlacementConfig
-):
-    """Batched evals of DIFFERENT jobs against one shared snapshot: the
-    heavy [N,4] base matrices are unbatched (uploaded once per
-    snapshot, cached on device by the batcher), while job_count [B,N],
-    tg_count/feasible [B,N,G], asks, and keys carry the batch axis.
-    This is what makes live broker-drain batches cheap: per dispatch
-    only the small per-job overlays move host->device.
-
-    With config.pre_resolve the eval axis runs as a sequential scan
-    carrying claimed capacity (see _overlay_seq) instead of a vmap —
-    the in-batch analog of the plan applier's serialization."""
-    if config.pre_resolve:
-        return _overlay_seq(state, asks, keys, config)
-    return jax.vmap(
-        lambda s, a, k: placement_program(s, a, k, config),
-        in_axes=(_OVERLAY_STATE_AXES, _OVERLAY_ASKS_AXES, 0),
-    )(state, asks, keys)
 
 
 class CompactOverlay(NamedTuple):
@@ -549,44 +511,30 @@ def _expand_overlay(class_ids, ov: CompactOverlay, n: int, g: int):
 def _compact_batch(capacity, sched_capacity, util, bw_avail, bw_used,
                    ports_free, node_ok, class_ids, overlays, asks, keys,
                    config):
+    """The eval axis of a compact batch: the scan of
+    batched_placement_program_overlay, each lane's overlay expanded on
+    the device inside its step."""
     n = util.shape[0]
     g = overlays.verdicts.shape[-1]
 
-    if config.pre_resolve:
-        # Sequential eval axis carrying claimed capacity (the compact
-        # twin of _overlay_seq); overlays still expand on device.
-        def body(carry, xs):
-            u, bw, pf = carry
-            ov, a, k = xs
-            feasible, job_count, tg_count = _expand_overlay(
-                class_ids, ov, n, g)
-            s = NodeState(
-                capacity=capacity, sched_capacity=sched_capacity, util=u,
-                bw_avail=bw_avail, bw_used=bw, ports_free=pf,
-                job_count=job_count, tg_count=tg_count, feasible=feasible,
-                node_ok=node_ok,
-            )
-            choices, scores, final = placement_program(s, a, k, config)
-            return ((final.util, final.bw_used, final.ports_free),
-                    (choices, scores))
-
-        carry, (choices, scores) = jax.lax.scan(
-            body, (util, bw_used, ports_free), (overlays, asks, keys))
-        return choices, scores, carry
-
-    def one(ov, a, k):
-        feasible, job_count, tg_count = _expand_overlay(class_ids, ov, n, g)
+    def body(carry, xs):
+        u, bw, pf = carry
+        ov, a, k = xs
+        feasible, job_count, tg_count = _expand_overlay(
+            class_ids, ov, n, g)
         s = NodeState(
-            capacity=capacity, sched_capacity=sched_capacity, util=util,
-            bw_avail=bw_avail, bw_used=bw_used, ports_free=ports_free,
+            capacity=capacity, sched_capacity=sched_capacity, util=u,
+            bw_avail=bw_avail, bw_used=bw, ports_free=pf,
             job_count=job_count, tg_count=tg_count, feasible=feasible,
             node_ok=node_ok,
         )
-        return placement_program(s, a, k, config)
+        choices, scores, final = placement_program(s, a, k, config)
+        return ((final.util, final.bw_used, final.ports_free),
+                (choices, scores))
 
-    return jax.vmap(
-        one, in_axes=(0, _OVERLAY_ASKS_AXES, 0),
-    )(overlays, asks, keys)
+    carry, (choices, scores) = jax.lax.scan(
+        body, (util, bw_used, ports_free), (overlays, asks, keys))
+    return choices, scores, carry
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -616,9 +564,9 @@ def batched_placement_program_compact_delta(
     changed rows ride this very call's arguments — deriving the child
     base costs zero extra round-trips. Returns the batch results plus
     the updated (util, bw_used, ports_free, node_ok) for the batcher to
-    cache under the child's token and, under config.pre_resolve, the
-    lanes' final carry (util, bw_used, ports_free after every lane's
-    claims) after them, for the dispatches that follow on that token.
+    cache under the child's token and, after them, the lanes' final
+    carry (util, bw_used, ports_free after every lane's claims) for the
+    dispatches that follow on that token.
     Padding rows duplicate a real row (same value, so the
     duplicate-index scatter is benign)."""
     util2 = util.at[rows].set(util_rows)
@@ -628,8 +576,7 @@ def batched_placement_program_compact_delta(
     choices, scores, final = _compact_batch(
         capacity, sched_capacity, util2, bw_avail, bw2, ports2,
         ok2, class_ids, overlays, asks, keys, config)
-    derived = (choices, scores, util2, bw2, ports2, ok2)
-    return derived + tuple(final) if config.pre_resolve else derived
+    return (choices, scores, util2, bw2, ports2, ok2) + tuple(final)
 
 
 @jax.jit

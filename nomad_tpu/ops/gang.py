@@ -38,7 +38,7 @@ the lanes in the batch's order and carries utilisation, bandwidth and
 ports from one gang to the next, so the gangs of a burst, which all
 plan on one snapshot and would all pick the same tightest rack, see
 each other's claims on device instead of colliding at the plan applier
-(the plain program's pre_resolve, for gangs). Inside a lane the pass is
+(as the plain lanes of the shared-base programs do). Inside a lane the pass is
 ``gang_placement_program``, which stays the plain reference of one
 lane. The batch axis rides the batcher's BATCH_BUCKETS. The scan's final
 carry is an output, and its start may be another dispatch's carry: where

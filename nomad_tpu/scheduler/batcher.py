@@ -3,7 +3,7 @@ dispatch.
 
 The north star (BASELINE.json, SURVEY.md §5): evals drained from the
 broker batch into a single device program — N workers' placement
-requests with the same bucketed shapes ride one vmapped dispatch
+requests with the same bucketed shapes ride one dispatch
 instead of N serial dispatches. Per-dispatch overhead (Python→XLA
 call, transfer, device RTT) is paid once per batch.
 
@@ -16,10 +16,12 @@ recompiles). Within a batch there are two device paths:
   the base is uploaded once and LRU-cached on device; the dispatch
   moves only the small per-job overlays (alloc counts + feasibility),
   asks, and PRNG keys (ops/binpack.py
-  batched_placement_program_overlay). This is the live broker-drain
-  fast path — many evals of different jobs against one snapshot.
-- mixed bases: the full states stack along the batch axis
-  (batched_placement_program).
+  batched_placement_program_overlay), and the lanes plan in order on
+  one carry of the base's claimed columns. This is the live
+  broker-drain fast path — many evals of different jobs against one
+  snapshot.
+- mixed bases: the full states stack along the batch axis and plan
+  independently (batched_placement_program): nothing shared to carry.
 
 A gang (nomad_tpu/gang) is a request in the same queues: place_gang
 keys it by its static GangConfig as place() keys a plain one by its
@@ -44,7 +46,7 @@ applier, so the order is the one that costs the batch's evals least (a
 burst's one-ask lanes do not wait out a 2,048-step scan; gangs first was
 built and measured first: PERF.md section 6, PRs 42 and 45). A
 program's final carry (utilisation, bandwidth, free ports after every
-lane's claims; pre_resolve) stays on the device under the token from
+lane's claims) stays on the device under the token from
 the moment the program is issued (_publish_claims); a dispatch on that
 token waits for those ahead of it that are queued, or popped and not
 yet issued (_await_turn, bounded by CLAIMS_WAIT_MAX), takes the newest
@@ -1142,17 +1144,12 @@ class PlacementBatcher:
         padded = batch + [batch[-1]] * (pad_to - n_live)
 
         def handed_on(carry_of):
-            """on_issued for a program whose lanes' final carry is
-            `carry_of(outputs)`: the programs that resolve a batch's
-            lanes in order. The vmapped ones have none to hand on, and
-            only stop holding the token."""
+            """on_issued for a shared-base program, whose lanes' final
+            carry is `carry_of(outputs)`."""
             def on_issued(out) -> None:
-                if config.pre_resolve:
-                    self._publish_claims(
-                        batch[0], carry_of(out), "plain", n_live,
-                        _rank(batch[0])[1])
-                else:
-                    self._release(batch[0])
+                self._publish_claims(
+                    batch[0], carry_of(out), "plain", n_live,
+                    _rank(batch[0])[1])
             return on_issued if hand_over else None
         # Compact overlays: class verdicts + sparse patches + job
         # positions, expanded to the dense [B,N,G] masks ON DEVICE — a

@@ -76,11 +76,10 @@ def _offer_networks(rng, missing: AllocTuple, node, net_indexes, matrix):
     return task_resources
 
 
-def build_placement_config(batch: bool, pre_resolve: bool, kernel,
-                           placements, ask_arrays):
+def build_placement_config(batch: bool, kernel, placements, ask_arrays):
     """The PlacementConfig BatchedTPUScheduler hands the batcher. One
     factory for the STATIC fields that key compiled device programs
-    (penalty, pre_resolve, uniform_dh, kernel): a second way to build
+    (penalty, uniform_dh, kernel): a second way to build
     them would mint a second program per shape bucket (a recompile
     storm; analysis/compile_surface.py sanctions this factory)."""
     from ..kernels import active_kernel
@@ -95,7 +94,6 @@ def build_placement_config(batch: bool, pre_resolve: bool, kernel,
         anti_affinity_penalty=(
             BATCH_JOB_ANTI_AFFINITY_PENALTY if batch
             else SERVICE_JOB_ANTI_AFFINITY_PENALTY),
-        pre_resolve=pre_resolve,
         # Uniform distinct-hosts fast path: one TG scaled to count=K
         # under distinct-hosts (the storm shape) collapses the K-step
         # scan to one scoring pass + top_k (ops/binpack.py). Static, so
@@ -317,21 +315,13 @@ class BatchedTPUScheduler(GenericScheduler):
                 self.eval.id, trace.STAGE_MATRIX_COMPRESS, _t_base,
                 _t_base, ann=cidx.stats(),
                 trace_id=self.eval.trace_id)
-        # In-batch conflict pre-resolution rides the Planner (worker /
-        # dispatch-pipeline sessions set it from server config): batch
-        # members of one shared-snapshot dispatch then see each other's
-        # capacity claims on device instead of colliding at the plan
-        # applier. Harness/test planners without the attr stay on the
-        # independent (vmapped) path.
         # Placement kernel (nomad_tpu/kernels): instance pin from the
         # factory variant, else the process-global active kernel inside
         # build_placement_config. The name is a static PlacementConfig
         # field — it joins the batcher's shape key, so kernels never
         # share a dispatch.
         config = build_placement_config(
-            self.batch,
-            bool(getattr(self.planner, "pre_resolve", False)),
-            self.kernel, placements, ask_arrays)
+            self.batch, self.kernel, placements, ask_arrays)
         kernel = config.kernel
         # Host-side key: a device PRNGKey here would cost a device
         # round-trip per eval and force the batcher to pull keys back
@@ -340,7 +330,7 @@ class BatchedTPUScheduler(GenericScheduler):
 
         # The drain-to-batch shim (BASELINE north star): concurrent
         # workers' same-shaped placement programs coalesce into one
-        # vmapped device dispatch instead of N serial calls, and evals
+        # device dispatch instead of N serial calls, and evals
         # sharing a cluster base ride one cached device upload.
         _t0 = time.monotonic()
         try:

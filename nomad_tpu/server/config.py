@@ -62,17 +62,6 @@ class ServerConfig:
     # once and pays none of it.
     dispatch_window: float = 0.05
     dispatch_idle_grace: float = 0.004
-    # Conflict-rejected evals rejoin the accumulating batch at most
-    # this many times before falling back to the scheduler's own
-    # inline retry loop (bounded like MAX_SERVICE_SCHEDULE_ATTEMPTS).
-    dispatch_max_requeues: int = 3
-
-    # In-batch conflict pre-resolution: serialize the eval axis of a
-    # shared-base device dispatch so batch members see each other's
-    # capacity claims (ops/binpack.py PlacementConfig.pre_resolve) —
-    # cuts plan-applier rejections, each of which costs a replan +
-    # dispatch round-trip. False = independent (vmapped) evals.
-    dense_pre_resolve: bool = True
 
     # ---- Placement kernel (nomad_tpu/kernels) ----
     # Which dense placement kernel the *-tpu factories run: "greedy"
@@ -85,16 +74,6 @@ class ServerConfig:
     # type pins are also available through scheduler_factories (e.g.
     # {"service": "service-convex-tpu"}).
     placement_kernel: Optional[str] = None
-
-    # ---- Device-resident node state (models/resident.py) ----
-    # The dense path's [N, R] node matrix lives on device; plan commits
-    # and node up/down/drain transitions apply as small scatter deltas
-    # keyed on raft index instead of re-shipping the full matrix per
-    # batch. False reverts to per-snapshot rebuild + re-upload.
-    device_resident: bool = True
-    # Max delta-refilled rows before a full rebuild is the better deal;
-    # 0 = auto (max(64, N/4)).
-    resident_rebuild_rows: int = 0
 
     # ---- Churn control (nomad_tpu/migrate) ----
     # In-flight migration budget: how many drain-displaced allocs may
@@ -143,17 +122,9 @@ class ServerConfig:
     # on scope notifications. False reverts to thread-parking long
     # polls.
     read_mux_enabled: bool = True
-    # Serve-pool threads re-running satisfied/expired queries.
-    read_mux_workers: int = 4
     # Continuations parked at once before new blocking queries fall
     # back to thread-parking (bounds mux memory under a watcher storm).
     read_mux_max_parked: int = 4096
-    # Scoped modify-index tracking: blocking queries wake on — and
-    # X-Nomad-Index reports — their watch scope's index instead of the
-    # global raft index. False restores global-index wakes (the
-    # spurious-wakeup A/B arm); the mux requires scoped tracking, so
-    # False also implies thread-parking long polls.
-    read_scoped_index: bool = True
 
     # ---- Overload protection (nomad_tpu/admission) ----
     # Bounded broker ready queues: default per-scheduler-type depth cap
@@ -201,11 +172,6 @@ class ServerConfig:
     # detector. False disables recording and stops the sampler; the
     # lock wrappers stay in place either way.
     profile_enabled: bool = True
-    # GIL sampler sleep-request interval in seconds (~200 wakes/s at
-    # the default; the overshoot distribution is the measurement).
-    # Values <= 0 are ignored (a zero interval would spin); to stop
-    # the sampler, disable the observatory via profile_enabled.
-    gil_sampler_interval: float = 0.005
     # Pressure-monitor thresholds on the WORST per-site contended
     # lock-wait p99 in ms (0 disables the input — like the e2e p99
     # thresholds, absolute bars are deployment-specific). When set,

@@ -39,6 +39,7 @@ from ..migrate import churn_stats as _churn_stats
 from ..models.resident import device_state_stats as _device_state_stats
 from ..profile import get_profiler as _get_profiler
 from ..profile.collector import get_collector as _get_collector
+from ..profile.sampler import SAMPLE_INTERVAL_S
 from .config import ServerConfig
 from .core_gc import CoreScheduler
 from .fsm import FSM, DevLog
@@ -116,16 +117,7 @@ class Server:
         # sampler without dropping lock registrations.
         _get_profiler().configure(
             enabled=self.config.profile_enabled,
-            sampler_interval=self.config.gil_sampler_interval,
-        )
-        # Device-resident node state (models/resident.py): process-
-        # global like the breaker and the batcher's device cache it
-        # fronts; configure() updates policy without dropping counters.
-        from ..models.resident import configure as configure_resident
-
-        configure_resident(
-            enabled=self.config.device_resident,
-            rebuild_rows=self.config.resident_rebuild_rows,
+            sampler_interval=SAMPLE_INTERVAL_S,
         )
         # Placement kernel (nomad_tpu/kernels): validate HERE, not at
         # first eval — a typo'd placement_kernel must fail server init
@@ -165,7 +157,6 @@ class Server:
 
         self.read_mux = ReadMux(
             lambda: self.fsm.state,
-            workers=self.config.read_mux_workers,
             max_parked=self.config.read_mux_max_parked,
         )
         self._leader = False

@@ -83,10 +83,6 @@ class EvalSession:
         self.server = worker.server
         self.eval = ev
         self.token = token
-        # The dense kernel's in-batch conflict pre-resolution flag
-        # (scheduler/tpu.py reads it off its Planner): a pipeline
-        # batch's members share one snapshot.
-        self.pre_resolve = worker.server.config.dense_pre_resolve
 
     def submit_plan(self, plan: Plan) -> Tuple[PlanResult, Optional[object]]:
         start = time.monotonic()

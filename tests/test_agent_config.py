@@ -273,12 +273,17 @@ server {
     "executive_threads = 6",
     "dispatch_pipeline = false",
     "dense_min_batch = 2",
+    "dense_pre_resolve = false",
+    "device_resident = false",
+    "resident_rebuild_rows = 512",
+    "gil_sampler_interval = 0.01",
 ])
 def test_removed_dense_driver_keys_are_refused(tmp_path, line):
-    """There is one dense driver and it has no batch-size route to the
-    host (PR 46): the switches of the other two drivers and
-    `dense_min_batch` are unknown keys like any typo, not silently
-    accepted."""
+    """There is one dense driver, it has no batch-size route to the
+    host (PR 46) and its device path has one configuration (PR 49):
+    the switches of the other two drivers, `dense_min_batch`, the
+    in-batch and resident-base switches and the sampler's cadence are
+    unknown keys like any typo, not silently accepted."""
     p = tmp_path / "a.hcl"
     p.write_text("server {\n  enabled = true\n  %s\n}\n" % line)
     key = "server." + line.split()[0]

@@ -4,6 +4,7 @@ per snapshot, overlay-only dispatches), including LONE requests — the
 live trickle regime — and the base cache must be true LRU."""
 
 import threading
+import time
 
 import jax
 import numpy as np
@@ -97,30 +98,46 @@ def test_lone_dispatch_uses_overlay_path_and_matches_direct():
 def test_batch_then_lone_no_base_reupload():
     """A concurrent batch followed by a lone trickle request on the
     same snapshot pays exactly one base upload total."""
+    from serial_reference import serial_placement
+
     b = PlacementBatcher(window=0.25)
     asks = build_asks()
     results = {}
+    # One cohort: the four ride one dispatch, and each is queued before
+    # the next starts, so the lanes' order is known.
+    units = b.open_cohort(4)
 
     def worker(i):
         s = build_state(token=5, job_seed=i)
-        results[i] = (s, jax.random.PRNGKey(i), b.place(s, asks, jax.random.PRNGKey(i), CONFIG))
+        key = jax.random.PRNGKey(i)
+        results[i] = (s, key, b.place(s, asks, key, CONFIG,
+                                      cohort=units[i]))
 
     threads = [threading.Thread(target=worker, args=(i,)) for i in range(4)]
-    for t in threads:
+    for t, unit in zip(threads, units):
         t.start()
+        while unit.open and t.is_alive():
+            time.sleep(0.001)
     for t in threads:
         t.join(timeout=60)
     assert len(results) == 4
     assert b.base_uploads == 1
+    assert b.dispatches == 1
     # Lone follow-up on the same snapshot: still one upload.
     s = build_state(token=5, job_seed=9)
     key = jax.random.PRNGKey(99)
     choices, _ = b.place(s, asks, key, CONFIG)
     assert b.base_uploads == 1
     np.testing.assert_array_equal(choices, direct(s, asks, key)[0])
-    # Every batched result matches the full-state oracle.
-    for i, (si, ki, (ci, _)) in results.items():
-        np.testing.assert_array_equal(ci, direct(si, build_asks(), ki)[0])
+    # The batch's lanes match serial placement that carries each
+    # lane's claims to the next.
+    lanes = [results[i] for i in range(4)]
+    want, _ = serial_placement(
+        lanes[0][0],
+        [(s.job_count, s.tg_count, s.feasible, asks, k)
+         for s, k, _ in lanes], CONFIG)
+    for i, (_s, _k, (ci, _)) in enumerate(lanes):
+        np.testing.assert_array_equal(ci, want[i])
 
 
 def test_mixed_tokens_fall_back_to_full_state_path():

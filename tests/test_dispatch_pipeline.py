@@ -317,13 +317,12 @@ def test_pre_resolve_parity_vs_serial_placement(shape):
     at a time while carrying the shared capacity state host-side — the
     exact serialization the plan applier would impose."""
     import jax
+    from serial_reference import serial_placement
 
     from nomad_tpu.ops.binpack import (
-        NodeState,
         PlacementConfig,
         batched_placement_program_overlay,
         host_prng_key,
-        placement_program_jit,
         uniform_dh_flag,
     )
 
@@ -333,7 +332,7 @@ def test_pre_resolve_parity_vs_serial_placement(shape):
     state, asks = _shared_batch_inputs(n, k, g, b, **spec)
     keys = np.stack([host_prng_key(i) for i in range(b)])
     cfg = PlacementConfig(
-        anti_affinity_penalty=penalty, pre_resolve=True,
+        anti_affinity_penalty=penalty,
         uniform_dh=uniform_dh_flag(
             [0] * k, spec.get("distinct_hosts", False), [False]))
 
@@ -342,37 +341,28 @@ def test_pre_resolve_parity_vs_serial_placement(shape):
     choices, scores = np.asarray(choices), np.asarray(scores)
     assert (choices >= 0).all()  # every shape fits its cluster
 
-    util, bw, pf = state.util, state.bw_used, state.ports_free
-    serial_choices, serial_scores = [], []
-    for i in range(b):
-        s = NodeState(
-            capacity=state.capacity, sched_capacity=state.sched_capacity,
-            util=util, bw_avail=state.bw_avail, bw_used=bw,
-            ports_free=pf, job_count=state.job_count[i],
-            tg_count=state.tg_count[i], feasible=state.feasible[i],
-            node_ok=state.node_ok)
-        a = jax.tree.map(lambda x: x[i], asks)
-        c, sc, fin = placement_program_jit(s, a, keys[i], cfg)
-        util = np.asarray(fin.util)
-        bw = np.asarray(fin.bw_used)
-        pf = np.asarray(fin.ports_free)
-        serial_choices.append(np.asarray(c))
-        serial_scores.append(np.asarray(sc))
-    assert (choices == np.stack(serial_choices)).all()
-    assert np.allclose(scores, np.stack(serial_scores))
+    serial_choices, serial_scores = serial_placement(
+        state,
+        [(state.job_count[i], state.tg_count[i], state.feasible[i],
+          jax.tree.map(lambda x: x[i], asks), keys[i]) for i in range(b)],
+        cfg)
+    assert (choices == serial_choices).all()
+    assert np.allclose(scores, serial_scores)
     if spec.get("distinct_hosts"):
         for row in choices:
             assert len(set(row.tolist())) == k
 
 
 def test_pre_resolve_eliminates_in_batch_overcommit():
-    """A/B at the kernel: vmapped (independent) evals over a tight
-    cluster overcommit node capacity — every overcommit is a plan the
-    applier would reject, i.e. a retry round-trip. The pre-resolving
-    scan produces claims that ALL verify, so in-batch retries drop to
-    zero."""
+    """A/B at the kernel: independent evals (the unshared program, on
+    the lanes' full states) over a tight cluster overcommit node
+    capacity — every overcommit is a plan the applier would reject,
+    i.e. a retry round-trip. The shared-base program's scan over the
+    eval axis produces claims that ALL verify, so in-batch retries
+    drop to zero."""
     from nomad_tpu.ops.binpack import (
         PlacementConfig,
+        batched_placement_program,
         batched_placement_program_overlay,
         host_prng_key,
     )
@@ -387,11 +377,16 @@ def test_pre_resolve_eliminates_in_batch_overcommit():
     state, asks = _shared_batch_inputs(n, k, g, b, node_cpu=node_cpu,
                                        ask_cpu=ask_cpu)
     keys = np.stack([host_prng_key(100 + i) for i in range(b)])
+    cfg = PlacementConfig(anti_affinity_penalty=10.0)
+    # The lanes' full states: the shared columns once a lane.
+    full = state._replace(**{
+        f: np.broadcast_to(getattr(state, f),
+                           (b,) + getattr(state, f).shape)
+        for f in ("capacity", "sched_capacity", "util", "bw_avail",
+                  "bw_used", "ports_free", "node_ok")})
 
-    def overcommits(cfg):
-        choices, _, _ = batched_placement_program_overlay(
-            state, asks, keys, cfg)
-        choices = np.asarray(choices)
+    def overcommits(program, lanes):
+        choices = np.asarray(program(lanes, asks, keys, cfg)[0])
         claimed = np.zeros(n)
         rejected = 0
         for i in range(b):
@@ -408,14 +403,13 @@ def test_pre_resolve_eliminates_in_batch_overcommit():
             rejected += bad
         return rejected
 
-    off = overcommits(PlacementConfig(anti_affinity_penalty=10.0))
-    on = overcommits(
-        PlacementConfig(anti_affinity_penalty=10.0, pre_resolve=True))
+    off = overcommits(batched_placement_program, full)
+    on = overcommits(batched_placement_program_overlay, state)
     # BestFit steers independent evals to the same packed nodes: the
-    # vmapped batch must show the collision pathology for the A/B to
-    # mean anything.
-    assert off > 0, "expected in-batch overcommit with pre_resolve off"
-    assert on == 0, f"pre-resolve left {on} in-batch overcommits"
+    # independent batch must show the collision pathology for the A/B
+    # to mean anything.
+    assert off > 0, "expected overcommit between independent lanes"
+    assert on == 0, f"the carry left {on} in-batch overcommits"
 
 
 # ---------------------------------------------------------------------
@@ -471,15 +465,18 @@ def test_requeue_joins_accumulating_batch():
 
 def test_plan_conflicts_requeue_and_resolve_live():
     """Live conflict path: 4 single-node-sized jobs racing over 2 nodes
-    in ONE batch (pre-resolve off) must produce plan-applier rejections
+    in TWO batches in flight on different snapshots (the first batch's
+    plans are held before the plan queue while the second launches
+    beside it, blind to them) must produce plan-applier rejections
     whose retries are requeued through the pipeline — and the cluster
     still converges (2 jobs placed, 2 blocked)."""
-    server = make_server(dense_pre_resolve=False)
-    try:
-        seed_nodes(server, 2, cpu=500, mem=4096)
-        quiesce(server)
-        jobs = []
-        for _ in range(4):
+    from nomad_tpu.chaos import FaultSpec, chaos
+
+    server = make_server()
+    jobs = []
+
+    def register_pair():
+        for _ in range(2):
             job = mock.job()
             tg = job.task_groups[0]
             tg.count = 4
@@ -488,9 +485,24 @@ def test_plan_conflicts_requeue_and_resolve_live():
             tg.tasks[0].resources.networks = []
             server.job_register(job)
             jobs.append(job)
-        assert wait_until(lambda: server.broker.ready_count() >= 4, 10.0)
+
+    try:
+        seed_nodes(server, 2, cpu=500, mem=4096)
+        quiesce(server)
+        register_pair()
+        assert wait_until(lambda: server.broker.ready_count() >= 2, 10.0)
+        # The first two submits, the first batch's, wait before the
+        # plan queue: a slow plan queue, as the site defines 'delay'.
+        held = FaultSpec("dispatch.submit", "delay", count=2, delay=3.0)
+        chaos.arm(7, [held])
         for w in server.workers:
             w.set_pause(False)
+        # Both of the first batch's plans are made (registered sooner,
+        # the second pair would ride the first's device dispatch and be
+        # resolved in it). The second batch snapshots without them,
+        # wants the same two nodes and commits first.
+        assert wait_until(lambda: held.fired >= 2, 60.0)
+        register_pair()
 
         def placed_jobs():
             return sum(
@@ -505,23 +517,24 @@ def test_plan_conflicts_requeue_and_resolve_live():
             timeout=60.0)
         stats = server.dispatch.stats()
         applier = server.plan_applier.stats()
-        # 4 plans over 2 one-job nodes in one batch: the applier MUST
-        # have rejected some, and those retries must have ridden the
-        # pipeline's requeue (or, past the bound, its inline path).
+        # 4 plans over 2 one-job nodes from two snapshots: the applier
+        # MUST have rejected some, and those retries must have ridden
+        # the pipeline's requeue (or, past the bound, its inline path).
         assert applier["plans_rejected"] >= 1, (stats, applier)
         assert stats["plan_conflicts"] >= 1, stats
         assert stats["requeues"] + stats["inline_retries"] >= 1, stats
         assert stats["retries_per_eval"] > 0.0, stats
         assert placed_jobs() == 2
     finally:
+        chaos.disarm()
         server.shutdown()
 
 
 def test_pre_resolve_cuts_live_conflicts():
-    """Same race with pre-resolve ON: the in-batch serialization should
-    keep applier rejections at (near) zero — the A/B twin of the
-    kernel-level test, through the REAL control plane."""
-    server = make_server(dense_pre_resolve=True)
+    """Four jobs that fit together in ONE batch: the in-batch
+    serialization should keep applier rejections at (near) zero — the
+    twin of the kernel-level test, through the REAL control plane."""
+    server = make_server()
     try:
         seed_nodes(server, 4, cpu=500, mem=4096)
         quiesce(server)

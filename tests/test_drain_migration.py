@@ -97,7 +97,6 @@ def test_drain_flip_rides_resident_node_delta():
     arrive as a node-axis DELTA (node_ok row flip), not a rebuild —
     and the flipped bit must actually be False."""
     from nomad_tpu.models.matrix import ClusterMatrix
-    from nomad_tpu.models.resident import get_tracker
 
     h = Harness()
     nodes = []
@@ -111,7 +110,6 @@ def test_drain_flip_rides_resident_node_delta():
     h.state.upsert_job(h.next_index(), job)
     sjob = h.state.job_by_id(job.id)
 
-    assert get_tracker().is_enabled()
     m1 = ClusterMatrix(h.state.snapshot(), sjob)
     row = m1.nodes.index(next(n for n in m1.nodes if n.id == nodes[3].id))
     assert bool(m1.node_ok[row])

@@ -37,7 +37,7 @@ from nomad_tpu.scheduler.feasible import (
 from nomad_tpu.state import StateStore
 from nomad_tpu.structs import Constraint, Plan, consts
 
-CONFIG = PlacementConfig(anti_affinity_penalty=10.0, pre_resolve=True)
+CONFIG = PlacementConfig(anti_affinity_penalty=10.0)
 
 
 def host_mask(snap, job, nodes) -> np.ndarray:

@@ -166,7 +166,7 @@ def test_the_two_dispatches_equal_the_single_lane_programs_in_order(seed):
     rng = np.random.default_rng(seed + 1)
     ask = np.asarray([SLOT_CPU, SLOT_MEM, 1000, 0], np.float32)
     gang_cfg = GangConfig(anti_affinity_penalty=5.0, g_pad=256)
-    plain_cfg = PlacementConfig(anti_affinity_penalty=5.0, pre_resolve=True)
+    plain_cfg = PlacementConfig(anti_affinity_penalty=5.0)
     sizes = [int(k) for k in rng.choice([2, 5, 8], 3)]
     lanes, gkeys = [], []
     for i, k in enumerate(sizes):

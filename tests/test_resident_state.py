@@ -32,14 +32,12 @@ BASE_FIELDS = ("capacity", "sched_capacity", "util", "bw_avail",
 
 
 @pytest.fixture(autouse=True)
-def resident_on():
-    """The tracker is process-global: pin it enabled with default
-    policy and a clean staleness flag for every test here."""
+def clean_staleness():
+    """The tracker is process-global: a clean staleness flag for every
+    test here."""
     tracker = resident.get_tracker()
-    tracker.configure(enabled=True, rebuild_rows=0)
     tracker.consume_stale()
     yield tracker
-    tracker.configure(enabled=True, rebuild_rows=0)
     tracker.consume_stale()
 
 
@@ -232,28 +230,6 @@ def test_down_nodes_masked_not_dropped():
     assert base2.delta_parent[0] == m1.base_token
 
 
-def test_resident_off_reverts_to_ready_subset():
-    """The A/B knob: disabled, the matrix is built over READY nodes
-    only (the pre-resident shape) and node flips change the shape."""
-    resident.configure(enabled=False)
-    store = StateStore()
-    job = mock.job()
-    job.task_groups[0].tasks[0].resources.networks = []
-    nodes = []
-    index = 0
-    for _ in range(6):
-        node = mock.node()
-        node.compute_class()
-        nodes.append(node)
-        index += 1
-        store.upsert_node(index, node)
-    nodes[0].status = consts.NODE_STATUS_DOWN
-    index += 1
-    store.upsert_node(index, nodes[0])
-    m = ClusterMatrix(store.snapshot(), job)
-    assert m.n_real == 5
-
-
 def test_device_state_stats_surface():
     """server.stats()["device_state"] carries the resident counters +
     the batcher's jit compile-cache size, so recompile storms and
@@ -262,18 +238,17 @@ def test_device_state_stats_surface():
     from nomad_tpu.server import Server, ServerConfig
 
     st = Server(ServerConfig()).stats()["device_state"]
-    for key in ("enabled", "full_rebuilds", "delta_updates",
+    for key in ("full_rebuilds", "delta_updates",
                 "node_delta_updates", "stale_rebuilds",
                 "universe_rebuilds", "jit_cache_size", "base_uploads",
                 "base_delta_updates", "upload_bytes", "journal_deltas",
                 "journal_misses", "journal_allocs",
                 "positions_patched_jobs"):
         assert key in st, key
-    assert st["enabled"] is True
 
 
 def test_journal_counters_say_how_the_delta_learnt_what_changed(
-        resident_on, monkeypatch):
+        clean_staleness, monkeypatch):
     """journal_deltas: deltas the store's journal of allocation writes
     served; journal_allocs: the changed allocations it handed over (so
     allocs / deltas is the allocations a commit); journal_misses: deltas
@@ -297,12 +272,12 @@ def test_journal_counters_say_how_the_delta_learnt_what_changed(
     ClusterMatrix(store.snapshot(), job)
 
     def moved(then):
-        now = resident_on.stats()
+        now = clean_staleness.stats()
         return tuple(now[k] - then[k] for k in (
             "journal_deltas", "journal_allocs", "journal_misses",
             "full_rebuilds"))
 
-    then = resident_on.stats()
+    then = clean_staleness.stats()
     for commit in (3, 5):     # two commits, two deltas
         index += 1
         store.upsert_allocs(
@@ -374,7 +349,7 @@ def run_applier(fsm, log, plans):
         applier.stop()
 
 
-def test_stale_delta_forces_rebuild_not_wrong_placement(resident_on):
+def test_stale_delta_forces_rebuild_not_wrong_placement(clean_staleness):
     """End to end through the REAL plan applier: a chaos-dropped delta
     record leaves the resident matrix believing a nearly-full node is
     empty; the placement that belief produces is REJECTED by exact
@@ -417,7 +392,7 @@ def test_stale_delta_forces_rebuild_not_wrong_placement(resident_on):
 
     # The rejection forced a re-anchor: the next build (same snapshot —
     # the rejected plan committed nothing) full-rebuilds and matches.
-    tracker = resident_on
+    tracker = clean_staleness
     stale_before = tracker.stats()["stale_rebuilds"]
     snap3 = fsm.state.snapshot()
     m3 = ClusterMatrix(snap3, job)

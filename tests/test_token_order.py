@@ -66,7 +66,7 @@ from nomad_tpu.structs.plan import PlanResult
 from test_gang_batched import dense_server, live_allocs, run_as_one_batch
 from test_mixed_batch import gpu_nodes, slot_job
 
-CONFIG = PlacementConfig(anti_affinity_penalty=5.0, pre_resolve=True)
+CONFIG = PlacementConfig(anti_affinity_penalty=5.0)
 
 
 # ---------------------------------------------------------------------
