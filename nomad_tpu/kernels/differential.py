@@ -37,6 +37,7 @@ tests/test_kernels.py sweeps ``run_differential`` property-style.
 from __future__ import annotations
 
 import random
+import time
 from typing import Dict, List, Optional
 
 DEFAULT_SEEDS = range(7000, 7012)
@@ -459,7 +460,11 @@ def judge_migration_plan(snap, plan, seed=None) -> List[str]:
         existing = snap.allocs_by_node_terminal(node_id, False)
         updates = (plan.node_update.get(node_id, [])
                    + plan.node_preemptions.get(node_id, []))
-        proposed = remove_allocs(existing, updates) + placed
+        # a placement overrides by id: an allocation rewritten in place
+        # is on its node once (scheduler/util.py proposed_allocs_for_node)
+        by_id = {a.id: a for a in remove_allocs(existing, updates)}
+        by_id.update((a.id, a) for a in placed)
+        proposed = list(by_id.values())
         for a in proposed:
             if a.job is None:
                 a.job = plan.job
@@ -755,7 +760,8 @@ def build_handover_scenario(seed: int):
     return store, jobs
 
 
-def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False):
+def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False,
+                          plans=None):
     """[(job, matrix, choices)] of `jobs` placed through
     PlacementBatcher.place on the snapshot `snap` as ONE pipeline batch
     (one cohort, every lane from a thread of its own, as the dispatch
@@ -763,6 +769,11 @@ def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False):
     scheduler would build. `blind`: every job's dispatch on a batcher
     of its own, so that none starts from another's claims (what two
     queues of one token were to each other before the hand-over).
+    `plans`: {job id: Plan} for the lanes whose eval already stops or
+    has placed something (an update of a running job); such a lane
+    asks for the placements its plan still lacks, and its request
+    reaches the batcher before any other lane's (the lanes of a
+    dispatch scan in the order they arrived).
     Returns the lanes in the order the batcher's rule dispatches them
     (ask rung, shortest first), and the batcher (None where blind)."""
     import threading
@@ -772,6 +783,7 @@ def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False):
     from ..scheduler.batcher import PlacementBatcher
     from ..scheduler.tpu import build_placement_config
 
+    plans = plans or {}
     shared = None if blind else PlacementBatcher(window=0.0)
     units = [None] * len(jobs) if blind else shared.open_cohort(len(jobs))
     lanes, errors = [], []
@@ -779,7 +791,10 @@ def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False):
     def lane(i, job, unit):
         try:
             count = job.task_groups[0].count
-            matrix = ClusterMatrix(snap, job, rows_floor=count)
+            plan = plans.get(job.id)
+            matrix = ClusterMatrix(snap, job, plan, rows_floor=count)
+            if plan is not None:
+                count -= sum(len(v) for v in plan.node_allocation.values())
             placements = [0] * count
             arrays = matrix.build_asks(placements)
             config = build_placement_config(
@@ -794,17 +809,28 @@ def place_on_one_snapshot(snap, jobs, seed: int, blind: bool = False):
 
     threads = [threading.Thread(target=lane, args=(i, job, unit))
                for i, (job, unit) in enumerate(zip(jobs, units))]
-    for t in threads:
+    # the lanes under a plan first, each queued before the next starts
+    first = [t for t, job in zip(threads, jobs) if job.id in plans]
+    for k, t in enumerate(first):
         t.start()
+        deadline = time.monotonic() + 30.0
+        while (shared is not None and not errors
+               and time.monotonic() < deadline
+               and sum(len(q) for q in list(
+                   shared._queues.values())) <= k):
+            time.sleep(0.002)
+    for t in threads:
+        if t not in first:
+            t.start()
     for t in threads:
         t.join(120.0)
     if errors:
         raise errors[0]
-    lanes.sort(key=lambda row: row[0].task_groups[0].count)
+    lanes.sort(key=lambda row: len(row[2]))
     return lanes, shared
 
 
-def judge_shared_snapshot(snap, lanes, seed=None) -> List[str]:
+def judge_shared_snapshot(snap, lanes, seed=None, stops=()) -> List[str]:
     """Violations in the UNION of what several lanes chose on one
     snapshot, by the host's own tools: on every node the lanes touched,
     its live allocations plus every chosen instance must pass
@@ -812,9 +838,12 @@ def judge_shared_snapshot(snap, lanes, seed=None) -> List[str]:
     each instance's network ask must get an offer from the node's
     NetworkIndex given everything placed there before it (no port
     twice, bandwidth within the device's), and an ask the device left
-    unplaced is a violation too: the scenario's jobs fit together."""
+    unplaced is a violation too: the scenario's jobs fit together.
+    `stops`: allocations a lane's plan stops, taken off their nodes'
+    live sets first (the state once every lane's plan has committed)."""
     from ..structs import Allocation, NetworkIndex, Resources, allocs_fit, consts
 
+    gone = {a.id for a in stops}
     tag = f"seed {seed}: " if seed is not None else ""
     bad: List[str] = []
     rng = random.Random(seed)
@@ -828,8 +857,9 @@ def judge_shared_snapshot(snap, lanes, seed=None) -> List[str]:
                 continue
             node = matrix.nodes[choice]
             if node.id not in proposed:
-                proposed[node.id] = list(
-                    snap.allocs_by_node_terminal(node.id, False))
+                proposed[node.id] = [
+                    a for a in snap.allocs_by_node_terminal(node.id, False)
+                    if a.id not in gone]
                 idx = NetworkIndex()
                 idx.set_node(node)
                 idx.add_allocs(proposed[node.id])
@@ -859,6 +889,260 @@ def judge_shared_snapshot(snap, lanes, seed=None) -> List[str]:
         if not fit:
             bad.append(f"{tag}node {node_id} overcommitted: {dim}")
     return bad
+
+
+# ------------------------------------------------- updates of running jobs
+#
+# An eval whose plan stops something and places something (a new version
+# of a running job) is a lane of its batch's dispatch: its matrix keeps
+# the snapshot's base token and states the plan as a patch on the rows
+# it touches (models/matrix.py _build_plan_patch). The rig: a fleet in
+# which standing services run, each re-registered as one of the three
+# kinds of update, beside arrivals; the host GenericScheduler is the
+# plain reference for what an update stops, rewrites and places, and the
+# walk over every node for what a matrix under a plan has to hold.
+
+UPDATE_SEEDS = range(9600, 9608)
+
+
+class RecordPlans:
+    """A Harness planner that accepts every plan whole and writes
+    nothing: two schedulers can then plan on one store."""
+
+    def __init__(self, harness):
+        self.harness = harness
+
+    def submit_plan(self, plan):
+        from ..structs import PlanResult
+
+        return PlanResult(
+            node_update=plan.node_update,
+            node_allocation=plan.node_allocation,
+            node_preemptions=plan.node_preemptions,
+            alloc_index=self.harness.next_index()), None
+
+    def update_eval(self, ev) -> None:
+        pass
+
+    def create_eval(self, ev) -> None:
+        pass
+
+    def reblock_eval(self, ev) -> None:
+        pass
+
+
+def build_update_scenario(seed: int):
+    """(harness, updates, arrivals, tight) for one case of the update
+    rig. A fleet of one-core slots, ten a machine: HOT machines with
+    four slots free and cold ones nearly empty (BestFit ranks the hot
+    ones far above the rest), and TIGHT machines that are full and hold
+    one allocation of the service `push` each: they fit a slot-sized ask
+    only once that allocation is stopped. Three standing services of
+    one count (`push`, `scale`, `touch`; distinct_hosts, a dynamic port
+    and bandwidth) run on the fleet under their first version; the
+    store then holds their second: `push` asks for a little less memory
+    and leaves the pool `old`, which half of the tight machines are of
+    (destructive: every allocation stopped and placed anew; the new
+    ones fit the room the stops leave on the other half, and the room
+    on the `old` half stays free until the plan commits), `scale` two
+    instances more (in place, and two placed), `touch` the same body
+    again (in place).
+    `updates` maps the kind to its registered job; `arrivals` are two
+    new services of the same count and one of eight more (the next ask
+    rung: a second queue on the token); `tight` the tight machines'
+    ids. The harness's planner records plans and writes none."""
+    from .. import mock
+    from ..scheduler.testing import Harness, seed_harness_cluster
+    from ..structs import Constraint, consts
+    from ..structs.resources import NetworkResource, Port
+
+    rng = random.Random(seed)
+    count = rng.choice([4, 5, 6])
+    n_hot, n_cold = rng.choice([5, 6]), rng.choice([16, 18])
+    slot_cpu, slot_mem = 1000, 1024
+    filler = mock.job()
+    filler.id = f"update-filler-{seed}"
+
+    def service(name: str, n: int, memory: int = slot_mem):
+        job = mock.job()
+        job.id = job.name = f"update-{seed}-{name}"
+        job.type = "service"
+        job.constraints = []
+        tg = job.task_groups[0]
+        tg.count = n
+        tg.constraints = [Constraint(
+            operand=consts.CONSTRAINT_DISTINCT_HOSTS)]
+        tg.ephemeral_disk.size_mb = 0
+        task = tg.tasks[0]
+        task.resources.cpu, task.resources.memory_mb = slot_cpu, memory
+        task.resources.disk_mb = 0
+        task.resources.networks = [NetworkResource(
+            mbits=50, dynamic_ports=[Port("http", 0)])]
+        return job
+
+    def held(node, job, name):
+        a = mock.alloc()
+        a.node_id, a.job_id, a.job = node.id, job.id, job
+        a.name = name
+        a.task_group = job.task_groups[0].name
+        a.desired_status = consts.ALLOC_DESIRED_RUN
+        a.client_status = consts.ALLOC_CLIENT_RUNNING
+        for tr in a.task_resources.values():
+            tr.cpu, tr.memory_mb, tr.networks = slot_cpu, slot_mem, []
+        a.resources = None
+        return a
+
+    standing = {kind: service(kind, count)
+                for kind in ("push", "scale", "touch")}
+    nodes, allocs, tight = [], [], []
+    for i in range(count + n_hot + n_cold):
+        node = mock.node()
+        node.reserved = None
+        node.resources.cpu = 10 * slot_cpu
+        node.resources.memory_mb = 10 * slot_mem
+        node.compute_class()
+        nodes.append(node)
+        # The first half of the tight machines are of the pool the new
+        # version of `push` leaves: nobody may place there, and what
+        # its stops free there is free only once its plan commits.
+        node.meta["pool"] = "old" if i < count // 2 else "new"
+        if i < count:
+            tight.append(node.id)
+            taken = 9       # and the tenth is `push`'s
+        else:
+            taken = 6 if i < count + n_hot else 1
+        allocs.extend(held(node, filler, f"filler[{len(allocs) + k}]")
+                      for k in range(taken))
+    for job in standing.values():
+        job.datacenters = [nodes[0].datacenter]
+    rng.shuffle(nodes)
+
+    h = Harness(seed=seed)
+    seed_harness_cluster(h, nodes=nodes, allocs=allocs,
+                         jobs=list(standing.values()))
+    # `push` runs one to a tight machine; the two others are placed by
+    # the host scheduler
+    push = h.state.job_by_id(standing["push"].id)
+    tg = push.task_groups[0]
+    running = []
+    for k, node_id in enumerate(tight):
+        a = held(h.state.node_by_id(node_id), push,
+                 f"{push.name}.{tg.name}[{k}]")
+        a.task_resources = {tg.tasks[0].name: tg.tasks[0].resources.copy()}
+        running.append(a)
+    seed_harness_cluster(h, allocs=running)
+    from ..structs.eval import new_eval
+
+    for kind in ("scale", "touch"):
+        h.process("service", new_eval(
+            h.state.job_by_id(standing[kind].id),
+            consts.EVAL_TRIGGER_JOB_REGISTER))
+    # the second versions
+    updates = {"push": service("push", count, slot_mem - 24),
+               "scale": service("scale", count + 2),
+               "touch": service("touch", count)}
+    updates["push"].constraints = [Constraint(
+        ltarget="${meta.pool}", rtarget="old", operand="!=")]
+    arrivals = [service("new-a", count), service("new-b", count),
+                service("new-wide", count + 8)]
+    for job in list(updates.values()) + arrivals:
+        job.datacenters = [nodes[0].datacenter]
+    seed_harness_cluster(h, jobs=list(updates.values()) + arrivals)
+    updates = {kind: h.state.job_by_id(job.id)
+               for kind, job in updates.items()}
+    arrivals = [h.state.job_by_id(job.id) for job in arrivals]
+    h.plans.clear()
+    h.evals.clear()
+    h.planner = RecordPlans(h)
+    return h, updates, arrivals, tight
+
+
+def plan_summary(snap, plan) -> Dict[str, object]:
+    """What one plan does to its job, by allocation NAME (ids are the
+    scheduler's own draws): the names stopped, the names rewritten in
+    place (an allocation of the plan whose id the store already holds)
+    and how many are placed anew."""
+    staged = [a for v in plan.node_allocation.values() for a in v]
+    inplace = [a for a in staged if snap.alloc_by_id(a.id) is not None]
+    return {"stops": sorted(a.name for v in plan.node_update.values()
+                            for a in v),
+            "inplace": sorted(a.name for a in inplace),
+            "placed": len(staged) - len(inplace)}
+
+
+def judge_update_plan(snap, plan, job, seed=None) -> List[str]:
+    """Violations in the plan of an update: its legs by
+    judge_migration_plan (every stop is a live allocation on the node
+    named, every node's proposed set passes allocs_fit and plan-apply
+    verification: capacity, bandwidth, ports, after the stops), and
+    distinct_hosts over the proposed state of every node the plan
+    touches."""
+    from ..scheduler.util import proposed_allocs_for_node
+    from ..structs import consts
+
+    tag = f"seed {seed}: " if seed is not None else ""
+    bad = judge_migration_plan(snap, plan, seed)
+    distinct = any(c.operand == consts.CONSTRAINT_DISTINCT_HOSTS
+                   for tg in job.task_groups
+                   for c in list(job.constraints) + list(tg.constraints))
+    if distinct:
+        for node_id in set(plan.node_update) | set(plan.node_allocation):
+            mine = [a for a in proposed_allocs_for_node(snap, plan, node_id)
+                    if a.job_id == job.id]
+            if len(mine) > 1:
+                bad.append(f"{tag}{len(mine)} allocations of {job.id} "
+                           f"on {node_id}")
+    return bad
+
+
+def walked_view(snap, job, plan) -> Dict[str, object]:
+    """The plain reference for a matrix under a plan: a cluster base
+    built by the walk over EVERY node of the job's datacenters and
+    every allocation proposed on it (live, less the plan's stops and
+    victims, plus its placements), with the job's counts taken from
+    that walk. What the program built for such an eval before a plan
+    was a lane's patch; the program itself no longer reaches it."""
+    import numpy as np
+
+    from ..models.matrix import _ClusterBase, universe_nodes_cached
+    from ..scheduler.util import proposed_allocs_for_node
+
+    nodes, _by_dc, _sig = universe_nodes_cached(snap, job.datacenters)
+    base = _ClusterBase(
+        nodes, lambda nid: proposed_allocs_for_node(snap, plan, nid))
+    g = {tg.name: gi for gi, tg in enumerate(job.task_groups)}
+    job_count = np.zeros(base.n, np.int32)
+    tg_count = np.zeros((base.n, len(g)), np.int32)
+    for i, groups in enumerate(base.alloc_groups):
+        for job_id, group in groups:
+            if job_id == job.id:
+                job_count[i] += 1
+                if group in g:
+                    tg_count[i, g[group]] += 1
+    return {"util": base.util, "bw_used": base.bw_used,
+            "ports_free": base.ports_free, "job_count": job_count,
+            "tg_count": tg_count}
+
+
+def patched_view(matrix) -> Dict[str, object]:
+    """What a lane of `matrix` plans on: the cached base's columns with
+    the matrix's plan patch put in (ClusterMatrix.proposed_columns, the
+    host's copy of what the shared-base programs do on the device), and
+    the job's counts expanded from the compact overlay's positions
+    where it has one."""
+    import numpy as np
+
+    util, bw_used, ports_free = matrix.proposed_columns()
+    job_count, tg_count = matrix.job_count, matrix.tg_count
+    if matrix.compact_overlay is not None:
+        ov = matrix.compact_overlay
+        live = ov.job_rows < matrix.n
+        job_count = np.zeros(matrix.n, np.int32)
+        tg_count = np.zeros((matrix.n, matrix.g), np.int32)
+        np.add.at(job_count, ov.job_rows[live], 1)
+        np.add.at(tg_count, (ov.job_rows[live], ov.job_tgs[live]), 1)
+    return {"util": util, "bw_used": bw_used, "ports_free": ports_free,
+            "job_count": job_count, "tg_count": tg_count}
 
 
 GANG_SEEDS = range(9200, 9208)

@@ -55,6 +55,13 @@ ASK_BUCKETS = [8, 16, 32, 64, 128, 256, 512, 1024, 2048]
 CLASS_BUCKETS = [8, 32, 128, 512, 2048]
 PATCH_BUCKETS = [16, 64, 256]
 JOBPOS_BUCKETS = [16, 64, 256, 1024, 2048]
+# The rows of a lane's plan patch (ClusterMatrix.plan_patch): a ladder
+# of its own, because every lane of every dispatch carries a patch and
+# an arrival's is empty: on the positions ladder, whose floor is the
+# job's whole count, a task of 2,000 instances would ship 57 KB of
+# padding a lane. A plan that touches more rows than the top holds
+# takes a dense state of its own (_build_plan_patch).
+PLAN_BUCKETS = [16, 64, 256, 1024]
 
 # Job-independent cluster base, cached across evaluations: rebuilding
 # the [N,4] utilization matrices is O(N x allocs) host work per eval,
@@ -1044,6 +1051,13 @@ def bucket_size(n: int, buckets: List[int] = BUCKETS) -> int:
     return buckets[i]
 
 
+def empty_plan_patch(n: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The plan patch of a lane whose plan touches nothing, over a node
+    axis of `n`: the ladder's first rung, every row out of range."""
+    return (np.full(PLAN_BUCKETS[0], n, np.int32),
+            np.zeros((PLAN_BUCKETS[0], 6), np.float32))
+
+
 def _alloc_usage(alloc: Allocation) -> Tuple[float, float, float, float, float, int]:
     """(cpu, mem, disk, iops, mbits, dyn_ports_in_range) consumed by one
     alloc — same accounting as AllocsFit (structs/funcs.go:72-94).
@@ -1086,8 +1100,7 @@ def _alloc_usage(alloc: Allocation) -> Tuple[float, float, float, float, float, 
     return usage
 
 
-def resolve_cluster_base(state, datacenters, nodes=None, explicit=False,
-                         proposed_fn=None, cacheable=True):
+def resolve_cluster_base(state, datacenters, nodes=None, explicit=False):
     """Resolve the job-independent cluster base for one (snapshot, dc
     set): exact-key cache hit, family delta-update, or full rebuild —
     single-flighted, since a drained batch's evals all build matrices
@@ -1116,16 +1129,14 @@ def resolve_cluster_base(state, datacenters, nodes=None, explicit=False,
     tracker = get_tracker()
     if nodes is None:
         nodes, _by_dc, _sig = universe_nodes_cached(state, datacenters)
-    if proposed_fn is None:
-        from ..scheduler.util import proposed_allocs_for_node
+    from ..scheduler.util import proposed_allocs_for_node
 
-        def proposed_fn(node_id, _state=state):
-            return proposed_allocs_for_node(_state, None, node_id)
+    def proposed_fn(node_id, _state=state):
+        return proposed_allocs_for_node(_state, None, node_id)
 
     key = family = prev = done = None
     allocs_idx = nodes_idx = -1
-    if (cacheable and hasattr(state, "index")
-            and getattr(state, "store_id", "")):
+    if hasattr(state, "index") and getattr(state, "store_id", ""):
         dcs = tuple(sorted(datacenters or []))
         # Caller-provided node lists (the system path's pinned
         # subsets) need their identity in the key: two different
@@ -1286,12 +1297,22 @@ class ClusterMatrix:
                  nodes: Optional[List[Node]] = None,
                  plan_overlay: bool = False, ask_floor: int = 0,
                  rows_floor: int = 0):
-        """`plan_overlay`: where the plan already holds placements or
-        stops, take the node state from the CACHED base of the snapshot
-        and re-derive only the rows the plan touches, instead of a
-        fresh uncacheable base over every node (the preemption pass:
-        a handful of rows against 12k). Such a matrix carries no base
-        token: what it holds is no cached base's content.
+        """`plan`: where it already stops, evicts or has placed
+        something, the node state is still the CACHED base of the
+        snapshot, under its token, and what the plan changes is the
+        matrix's `plan_patch`: the rows the plan touches and, a row,
+        the proposed allocations' usage less the live ones' (scheduler/
+        util.py proposed_allocs_for_node) on the six usage columns. The
+        shared-base programs apply it to this lane's view alone
+        (ops/binpack.py); the job's positions and counts are the
+        proposed state's. No plan walks every node.
+
+        `plan_overlay`: write that patch into copies of the host arrays
+        instead, for a program that takes a dense state of its own (the
+        preemption and gang passes; a caller's own node list, which the
+        system path reads on the host, is written the same way). Such a
+        matrix carries no base token: what it holds is no cached base's
+        content.
 
         `ask_floor`: pad the asks and the job's alloc positions as for
         that many asks at least. An eval that retries on the dense path
@@ -1326,8 +1347,6 @@ class ClusterMatrix:
         self.groups = job.task_groups
         self.g = len(self.groups)
         self._build()
-        if plan_overlay:
-            self._overlay_plan()
 
     # ------------------------------------------------------------------
 
@@ -1337,15 +1356,10 @@ class ClusterMatrix:
         return proposed_allocs_for_node(self.state, self.plan, node_id)
 
     def _cached_base(self) -> "_ClusterBase":
-        cacheable = (self._plan_overlay or self.plan is None
-                     or self.plan.is_no_op())
         t0 = time.monotonic()
         base, self.build_kind = resolve_cluster_base(
             self.state, self.job.datacenters, nodes=self.nodes,
-            explicit=self._explicit_nodes,
-            proposed_fn=(None if self._plan_overlay
-                         else self._proposed_allocs),
-            cacheable=cacheable)
+            explicit=self._explicit_nodes)
         # Set where this very build derived the delta (a replan on a
         # snapshot no prologue prefetched): (t0, t1, annotations) of the
         # span `base.delta`.
@@ -1393,15 +1407,18 @@ class ClusterMatrix:
         self.topology = base.topology
 
         # Job-specific overlay: this job's per-node alloc counts, from
-        # the base's lazy positions index (O(this job's allocs)).
+        # the base's lazy positions index (O(this job's allocs)), moved
+        # to the proposed state where the plan touches them.
         positions = base.job_positions(self.job.id)
+        positions = self._build_plan_patch(positions)
         # Set by _build_feasibility where this eval really built a mask
         # (a memo miss): (t0, t1, annotations) of the span
         # `feasibility.build`.
         self.feas_build = None
         mask = self._build_feasibility(base)
         self.feasible = mask.feasible
-        if not positions and base.allocs_index >= 0:
+        if (not positions and base.allocs_index >= 0
+                and self.base_token is not None):
             # No live allocs (the storm shape): the whole overlay is
             # the mask's, shared across the batch and across commits.
             hit = mask.idle.get(self._job_rows_floor)
@@ -1437,45 +1454,82 @@ class ClusterMatrix:
                             | set(plan.node_preemptions))
                 if nid in row_of}
 
-    def _overlay_plan(self) -> None:
-        """Bring the rows this plan touches from the snapshot's state
-        to the proposed one (live allocations less the plan's stops and
-        victims plus its placements, scheduler/util.py
-        proposed_allocs_for_node): each row moves by the difference of
-        the two sums, as a base's delta adds a new allocation's usage.
-        Every other row IS the cached base's. The arrays touched are
-        copied first: the base's and the mask memo's are shared."""
+    def _build_plan_patch(self, positions):
+        """What this matrix's plan changes, stated against the cached
+        base: each row the plan touches moves by the proposed
+        allocations' usage less the live ones' (live allocations less
+        the plan's stops and victims plus its placements, scheduler/
+        util.py proposed_allocs_for_node), as a base's delta adds a new
+        allocation's usage; every other row IS the cached base's.
+        Sets `plan_patch`, (rows [P] int32 padded with n, values [P, 6]
+        float32: cpu, memory, disk, iops, bandwidth, dynamic ports), the
+        empty one for a plan that touches nothing; `plan_patch_span` is
+        (t0, t1, annotations) of the span `matrix.plan_patch` where it
+        touches something. Returns the job's positions in the proposed
+        state: a stopped allocation no longer counts on its node."""
+        self.plan_patch_span = None
         rows = self._plan_rows()
         if not rows:
-            return
-        self.base_token = self.base_delta = self.compact_overlay = None
-        self.util = self.util.copy()
-        self.bw_used = self.bw_used.copy()
-        self.ports_free = self.ports_free.copy()
-        self.job_count = self.job_count.copy()
-        self.tg_count = self.tg_count.copy()
-        gi_by_name = {tg.name: gi for gi, tg in enumerate(self.groups)}
+            self.plan_patch = empty_plan_patch(self.n)
+            return positions
+        t0 = time.monotonic()
 
         def total(allocs) -> np.ndarray:
-            if not allocs:
-                return np.zeros(6, np.float32)
             return np.asarray([_alloc_usage(a) for a in allocs],
-                              np.float32).sum(axis=0)
+                              np.float64).reshape(-1, 6).sum(axis=0)
 
-        for i, nid in rows.items():
+        idx = np.fromiter(rows, np.int64, len(rows))
+        moved = np.zeros((len(rows), 6), np.float32)
+        mine: Dict[str, List[int]] = {}
+        for k, (i, nid) in enumerate(rows.items()):
             proposed = self._proposed_allocs(nid)
-            moved = total(proposed) - total(
+            moved[k] = total(proposed) - total(
                 self.state.allocs_by_node_terminal(nid, False))
-            self.util[i] += moved[:4]
-            self.bw_used[i] += moved[4]
-            self.ports_free[i] -= moved[5]
-            mine = [a for a in proposed if a.job_id == self.job.id]
-            self.job_count[i] = len(mine)
-            self.tg_count[i] = 0
-            for a in mine:
-                gi = gi_by_name.get(a.task_group)
-                if gi is not None:
-                    self.tg_count[i, gi] += 1
+            for a in proposed:
+                if a.job_id == self.job.id:
+                    mine.setdefault(a.task_group, []).append(i)
+        proposed_positions = {}
+        for tg in set(positions) | set(mine):
+            kept = positions.get(tg, np.zeros(0, np.int64))
+            kept = np.concatenate([
+                kept[~np.isin(kept, idx)],
+                np.asarray(mine.get(tg, ()), np.int64)])
+            if len(kept):
+                proposed_positions[tg] = kept
+        bucket = bucket_size(len(idx), PLAN_BUCKETS)
+        # Pad with self.n: out of range, dropped by the device scatter.
+        p_rows = np.full(bucket, self.n, np.int32)
+        p_rows[: len(idx)] = idx
+        p_vals = np.zeros((bucket, 6), np.float32)
+        p_vals[: len(idx)] = moved
+        self.plan_patch = (p_rows, p_vals)
+        if (self._plan_overlay or self._explicit_nodes
+                or len(idx) > PLAN_BUCKETS[-1]):
+            # A dense state of this matrix's own: the patch goes into
+            # copies of the columns (the base's are shared).
+            self.util, self.bw_used, self.ports_free = \
+                self.proposed_columns()
+            self.base_token = self.base_delta = self.plan_patch = None
+            bucket = 0
+        self.plan_patch_span = (t0, time.monotonic(),
+                                {"rows": len(idx), "bucket": bucket})
+        return proposed_positions
+
+    def proposed_columns(self) -> Tuple[np.ndarray, ...]:
+        """(util [N, 4], bw_used [N], ports_free [N]) as this matrix's
+        plan leaves them: copies of the base's columns with the patch
+        put in, as the shared-base programs put it into a lane's view
+        (ops/binpack.py _patched)."""
+        util = np.array(self.util)
+        bw_used = np.array(self.bw_used)
+        ports_free = np.array(self.ports_free)
+        if self.plan_patch is not None:
+            rows, vals = self.plan_patch
+            live = rows < self.n
+            util[rows[live]] += vals[live, :4]
+            bw_used[rows[live]] += vals[live, 4]
+            ports_free[rows[live]] -= vals[live, 5]
+        return util, bw_used, ports_free
 
     def _build_compact_overlay(self, mask: "_Mask", positions) -> None:
         """The pre-expansion overlay (ops/binpack.py CompactOverlay):

@@ -430,9 +430,26 @@ def batched_placement_program(states: NodeState, asks: Asks, keys, config: Place
     )(states, asks, keys)
 
 
+def _patched(carry, patch, sign: float):
+    """The carry (util, bw_used, ports_free) with one lane's plan patch
+    put in (`sign` 1) or taken out again (-1). `patch` is (rows [P],
+    values [P, 6]: cpu, memory, disk, iops, bandwidth, dynamic ports;
+    models/matrix.py ClusterMatrix.plan_patch): what the lane's plan
+    stops and has placed, as the difference it makes on the rows it
+    touches. Padding rows are N, out of range, and dropped. The sums are
+    integers under 2^24, exact in float32: in and out again is the
+    carry it was, plus the lane's claims."""
+    util, bw_used, ports_free = carry
+    rows, vals = patch
+    vals = vals * sign
+    return (util.at[rows].add(vals[:, :NUM_RESOURCES], mode="drop"),
+            bw_used.at[rows].add(vals[:, 4], mode="drop"),
+            ports_free.at[rows].add(-vals[:, 5], mode="drop"))
+
+
 @functools.partial(jax.jit, static_argnames=("config",))
 def batched_placement_program_overlay(
-    state: NodeState, asks: Asks, keys, config: PlacementConfig
+    state: NodeState, asks: Asks, keys, config: PlacementConfig, patches
 ):
     """Batched evals of DIFFERENT jobs against one shared snapshot: the
     heavy [N,4] base matrices are unbatched (uploaded once per
@@ -451,21 +468,28 @@ def batched_placement_program_overlay(
     (job_count/tg_count/feasible) stay per-eval: they describe the
     eval's OWN job. Batch-padding rows scan AFTER the real rows, so
     their phantom claims never affect a real output. Returns the
-    lanes' final carry after the choices and scores."""
+    lanes' final carry after the choices and scores.
+
+    `patches` (rows [B, P], values [B, P, 6]) are the lanes' plan
+    patches (_patched): a lane plans on the carry with its own patch
+    put in, and the patch is taken out again before the carry goes on,
+    so capacity that a lane's stops would free is that lane's alone to
+    place on, and the carry handed on holds placements only."""
 
     def body(carry, xs):
-        util, bw_used, ports_free = carry
-        (job_count, tg_count, feasible), a, k = xs
+        (job_count, tg_count, feasible), a, k, patch = xs
+        util, bw_used, ports_free = _patched(carry, patch, 1.0)
         s = state._replace(
             util=util, bw_used=bw_used, ports_free=ports_free,
             job_count=job_count, tg_count=tg_count, feasible=feasible,
         )
         choices, scores, final = placement_program(s, a, k, config)
-        return ((final.util, final.bw_used, final.ports_free),
-                (choices, scores))
+        return (_patched((final.util, final.bw_used, final.ports_free),
+                         patch, -1.0), (choices, scores))
 
     carry0 = (state.util, state.bw_used, state.ports_free)
-    xs = ((state.job_count, state.tg_count, state.feasible), asks, keys)
+    xs = ((state.job_count, state.tg_count, state.feasible), asks, keys,
+          patches)
     carry, (choices, scores) = jax.lax.scan(body, carry0, xs)
     return choices, scores, carry
 
@@ -509,17 +533,18 @@ def _expand_overlay(class_ids, ov: CompactOverlay, n: int, g: int):
 
 
 def _compact_batch(capacity, sched_capacity, util, bw_avail, bw_used,
-                   ports_free, node_ok, class_ids, overlays, asks, keys,
-                   config):
+                   ports_free, node_ok, class_ids, overlays, patches, asks,
+                   keys, config):
     """The eval axis of a compact batch: the scan of
     batched_placement_program_overlay, each lane's overlay expanded on
-    the device inside its step."""
+    the device inside its step and its plan patch put in for that step
+    alone."""
     n = util.shape[0]
     g = overlays.verdicts.shape[-1]
 
     def body(carry, xs):
-        u, bw, pf = carry
-        ov, a, k = xs
+        ov, patch, a, k = xs
+        u, bw, pf = _patched(carry, patch, 1.0)
         feasible, job_count, tg_count = _expand_overlay(
             class_ids, ov, n, g)
         s = NodeState(
@@ -529,34 +554,34 @@ def _compact_batch(capacity, sched_capacity, util, bw_avail, bw_used,
             node_ok=node_ok,
         )
         choices, scores, final = placement_program(s, a, k, config)
-        return ((final.util, final.bw_used, final.ports_free),
-                (choices, scores))
+        return (_patched((final.util, final.bw_used, final.ports_free),
+                         patch, -1.0), (choices, scores))
 
     carry, (choices, scores) = jax.lax.scan(
-        body, (util, bw_used, ports_free), (overlays, asks, keys))
+        body, (util, bw_used, ports_free), (overlays, patches, asks, keys))
     return choices, scores, carry
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
 def batched_placement_program_compact(
     capacity, sched_capacity, util, bw_avail, bw_used, ports_free,
-    node_ok, class_ids, overlays: CompactOverlay, asks: Asks, keys,
-    config: PlacementConfig
+    node_ok, class_ids, overlays: CompactOverlay, patches, asks: Asks,
+    keys, config: PlacementConfig
 ):
     """The overlay path with device-side overlay expansion: the seven
     base arrays and class_ids are the device-cached cluster base
-    (unbatched); `overlays` carries the batch axis on every field and
-    the dense per-eval masks/counts are rebuilt on device."""
+    (unbatched); `overlays` and `patches` carry the batch axis on every
+    field and the dense per-eval masks/counts are rebuilt on device."""
     return _compact_batch(capacity, sched_capacity, util, bw_avail,
                           bw_used, ports_free, node_ok, class_ids,
-                          overlays, asks, keys, config)
+                          overlays, patches, asks, keys, config)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
 def batched_placement_program_compact_delta(
     capacity, sched_capacity, util, bw_avail, bw_used, ports_free,
     node_ok, class_ids, rows, util_rows, bw_rows, ports_rows, ok_rows,
-    overlays: CompactOverlay, asks: Asks, keys,
+    overlays: CompactOverlay, patches, asks: Asks, keys,
     config: PlacementConfig
 ):
     """Compact dispatch FUSED with a base delta-update: the mutable
@@ -575,7 +600,7 @@ def batched_placement_program_compact_delta(
     ok2 = node_ok.at[rows].set(ok_rows)
     choices, scores, final = _compact_batch(
         capacity, sched_capacity, util2, bw_avail, bw2, ports2,
-        ok2, class_ids, overlays, asks, keys, config)
+        ok2, class_ids, overlays, patches, asks, keys, config)
     return (choices, scores, util2, bw2, ports2, ok2) + tuple(final)
 
 

@@ -74,6 +74,20 @@ where there is one: a compiled program is worth more than twelve idle
 lanes of eight steps. So a token's short lanes run one program from the
 first batch that shares its token on.
 
+An eval whose plan already stops or has placed something (an update of
+a running job: stops and placements in one plan) is a lane like any
+other. Its matrix keeps the snapshot's base token and states the plan as
+a PATCH (models/matrix.py ClusterMatrix.plan_patch): the rows the plan
+touches and what it changes on them. The shared-base programs put a
+lane's patch into that lane's view of the carry and take it out again
+before the carry goes on, so what the lane's stops would free is its
+alone to place on (another eval's plan could reach the applier first),
+and the carry a token hands on holds placements only. An arrival carries
+the empty patch of the ladder's first rung; the patch's bucket is part
+of the queue key as the compact overlay's are, and stands nowhere in the
+order of a token's dispatches (_rank): two queues that differ only in it
+go by the order of their pops.
+
 A dispatch closes on what its requests carry. Requests of a pipeline
 batch carry that batch's cohort (open_cohort: the launch prologue has
 already counted them), and their dispatch is released the moment every
@@ -248,13 +262,14 @@ class _Carry:
 
 
 class _Request:
-    __slots__ = ("token", "base", "overlay", "compact", "asks", "key",
-                 "delta", "event", "choices", "scores", "error", "span",
-                 "ready_at", "arrived_at", "unit", "topo", "info",
+    __slots__ = ("token", "base", "overlay", "compact", "patch", "asks",
+                 "key", "delta", "event", "choices", "scores", "error",
+                 "span", "ready_at", "arrived_at", "unit", "topo", "info",
                  "hand_over", "order")
 
     def __init__(self, token, base, overlay, asks, key, delta=None,
-                 compact=None, span=None, unit=None, topo=None):
+                 compact=None, patch=None, span=None, unit=None,
+                 topo=None):
         self.token = token  # cluster-base identity, None = unshared
         self.base = base  # (capacity, sched_capacity, util, bw_avail,
         #                    bw_used, ports_free, node_ok, class_ids)
@@ -264,6 +279,11 @@ class _Request:
         # KB cross host->device per eval and the dense overlays are
         # rebuilt on device.
         self.compact = compact
+        # The lane's plan patch (models/matrix.py
+        # ClusterMatrix.plan_patch): what its plan stops and has placed,
+        # put into this lane's view of the shared base alone. Empty for
+        # an arrival; None on a request without a token.
+        self.patch = patch
         self.asks = asks  # ops/binpack.py Asks; a gang's GangLane
         self.key = key
         # A gang request's topology column, (device key, host [N]
@@ -608,15 +628,28 @@ class PlacementBatcher:
             np.shape(compact.patch_rows)[0],
             np.shape(compact.job_rows)[0],
         )
+        # The lane's plan patch, and its bucket in the key for the same
+        # reason: an update eval of a handful of stops carries the first
+        # rung, as every arrival does (the empty patch), and rides their
+        # dispatch.
+        patch = None
+        if token is not None:
+            patch = getattr(state, "plan_patch", None)
+            if patch is None:
+                from ..models.matrix import empty_plan_patch
+
+                patch = empty_plan_patch(np.shape(state.node_ok)[0])
         # The token first: what follows it is the program's shape
         # (_batch_bucket).
         shape_key = (
             token, np.shape(state.capacity), np.shape(asks.resources),
             np.shape(state.feasible)[-1], config, compact_key,
+            None if patch is None else np.shape(patch[0])[0],
         )
         req = _Request(token, base, overlay, asks, rng_key,
                        delta=getattr(state, "base_delta", None),
-                       compact=compact, span=span, unit=cohort)
+                       compact=compact, patch=patch, span=span,
+                       unit=cohort)
         self._submit(req, shape_key, config)
         return req.choices, req.scores
 
@@ -1171,6 +1204,9 @@ class PlacementBatcher:
                     active=np.zeros_like(batch[-1].asks.active))
                 lane_asks[n_live:] = [idle] * (pad_to - n_live)
             asks = jax.tree.map(stacked, *lane_asks)
+            if shared:
+                patches = jax.tree.map(
+                    stacked, *[r.patch for r in padded])
             if compact:
                 per_eval = jax.tree.map(
                     stacked, *[r.compact for r in padded])
@@ -1182,6 +1218,8 @@ class PlacementBatcher:
                     stacked, *[r.full_state() for r in padded])
         payload = (sum(x.nbytes for x in asks) + keys.nbytes
                    + sum(x.nbytes for x in per_eval))
+        if shared:
+            payload += sum(x.nbytes for x in patches)
         # Stacked: now the dispatch waits for its turn on the token and
         # reads what those before it claimed.
         claims = None
@@ -1237,7 +1275,8 @@ class PlacementBatcher:
                         batch, config, closed,
                         batched_placement_program_compact_delta,
                         *parent[:8], rows_p, *row_payload, per_eval,
-                        asks, keys, config, on_issued=cache_derived)
+                        patches, asks, keys, config,
+                        on_issued=cache_derived)
                 finally:
                     publish()
             else:
@@ -1245,7 +1284,8 @@ class PlacementBatcher:
                 choices, scores, times = self._issue(
                     batch, config, closed,
                     batched_placement_program_compact, *dev[:8],
-                    per_eval, asks, keys, config, on_issued=handed_on(lambda out: out[2]))
+                    per_eval, patches, asks, keys, config,
+                    on_issued=handed_on(lambda out: out[2]))
         elif shared:
             dev = self._claimed_base(batch, claims)
             state = NodeState(
@@ -1256,7 +1296,8 @@ class PlacementBatcher:
             )
             choices, scores, times = self._issue(
                 batch, config, closed, batched_placement_program_overlay,
-                state, asks, keys, config, on_issued=handed_on(lambda out: out[2]))
+                state, asks, keys, config, patches,
+                on_issued=handed_on(lambda out: out[2]))
         else:
             choices, scores, times = self._issue(
                 batch, config, closed, batched_placement_program,

@@ -13,6 +13,7 @@ import random
 import time
 from typing import Dict, List, Optional
 
+from .. import trace
 from ..structs import (
     AllocMetric,
     Allocation,
@@ -462,6 +463,7 @@ class GenericScheduler:
 
         allocs, terminal_allocs = self._filter_complete_allocs(allocs)
 
+        _t_reconcile = time.monotonic()
         diff = diff_allocs(self.job, tainted, groups, allocs, terminal_allocs)
 
         # Continuous defragmentation (nomad_tpu/defrag): a defrag eval
@@ -517,7 +519,6 @@ class GenericScheduler:
         # waves instead of thundering-herding the plan queue.
         migrate_now = diff.migrate
         if migrate_now:
-            from .. import trace
             from ..migrate import check_migration_chaos, get_governor
 
             check_migration_chaos(self.eval.id)
@@ -563,6 +564,14 @@ class GenericScheduler:
         self.limit_reached = self.limit_reached or mark_lost_and_place(
             self.ctx, diff, diff.lost, ALLOC_LOST, limit
         )
+        # The reconcile on the record: what this eval found of its job
+        # against the registered version, before anything is placed.
+        trace.record_span(
+            self.eval.id, trace.STAGE_SCHED_RECONCILE, _t_reconcile,
+            ann={"stop": sum(len(v) for v in self.plan.node_update.values()),
+                 "inplace": len(inplace), "place": len(diff.place),
+                 "ignore": len(diff.ignore)},
+            trace_id=self.eval.trace_id)
 
         if not diff.place:
             if self.job is not None:

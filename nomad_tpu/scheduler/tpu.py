@@ -488,9 +488,11 @@ class BatchedTPUScheduler(GenericScheduler):
         _t0 = time.monotonic()
         # The cached base of the snapshot; where this plan already
         # stages something (gang replacement stops free their capacity)
-        # only the rows it touches are derived again — the all-K pass
-        # must see the room the survivors' stops open up. Such a matrix
-        # carries no base token and its gang dispatches alone.
+        # the rows it touches are written into a dense state of this
+        # matrix's own — the all-K pass must see the room the
+        # survivors' stops open up. Such a matrix carries no base token
+        # and its gang dispatches alone (the plain lanes' patch is not
+        # taken by the gang program: ROADMAP.md D13).
         matrix = ClusterMatrix(self.state, self.job, self.plan,
                                plan_overlay=True)
         _t_base = time.monotonic()
@@ -656,8 +658,8 @@ class BatchedTPUScheduler(GenericScheduler):
                                                tg_indices)
 
         # The node state is the snapshot's CACHED base (the one the
-        # normal pass just used) with only the rows this very plan
-        # touches derived again: the preemption pass must not claim
+        # normal pass just used) with the rows this very plan touches
+        # written into a copy: the preemption pass must not claim
         # headroom an earlier ask of this same eval just took, and its
         # victim lists must exclude allocs the plan already stops.
         pm = ClusterMatrix(self.state, self.job, self.plan,
@@ -829,12 +831,16 @@ def _record_built_spans(ev, matrix) -> None:
     `feasibility.build`, the constraint mask (a miss of the mask memo,
     models/matrix.py _build_feasibility: its samples are the memo's
     misses), and `base.delta`, the cluster base derived inline from its
-    parent (a replan on a snapshot no prologue prefetched)."""
+    parent (a replan on a snapshot no prologue prefetched), and
+    `matrix.plan_patch`, what a plan that is not a no-op changes on the
+    rows it touches (_build_plan_patch)."""
     for stage, built in (
             (trace.STAGE_FEASIBILITY_BUILD,
              getattr(matrix, "feas_build", None)),
             (trace.STAGE_BASE_DELTA,
-             getattr(matrix, "base_delta_span", None))):
+             getattr(matrix, "base_delta_span", None)),
+            (trace.STAGE_MATRIX_PLAN_PATCH,
+             getattr(matrix, "plan_patch_span", None))):
         if built is not None:
             trace.record_span(ev.id, stage, built[0], built[1],
                               ann=built[2], trace_id=ev.trace_id)
@@ -852,7 +858,7 @@ def note_quality(logger, job, kernel, matrix, ask_res, committed) -> None:
     try:
         if not get_board().should_sample(kernel):
             return
-        util = np.asarray(matrix.util).copy()
+        util = matrix.proposed_columns()[0]
         if committed:
             js = np.asarray([j for j, _r in committed])
             rows = np.asarray([r for _j, r in committed])

@@ -739,6 +739,16 @@ class StateStore:
                     if alloc.client_status != consts.ALLOC_CLIENT_LOST:
                         alloc.client_status = existing.client_status
                         alloc.client_description = existing.client_description
+                    # The eval index is by the field's value (memdb): an
+                    # allocation updated in place comes back under the
+                    # eval that updated it (scheduler/util.py
+                    # inplace_update) and is listed there, no longer
+                    # under the eval that placed it.
+                    if existing.eval_id != alloc.eval_id:
+                        by_eval = self._indexes["allocs_by_eval"]
+                        by_eval.remove(existing.eval_id, alloc.id)
+                        by_eval.add(alloc.eval_id, alloc.id)
+                        items.append(watch.alloc_eval(existing.eval_id))
                 else:
                     alloc.create_index = index
                     if not alloc.client_status:

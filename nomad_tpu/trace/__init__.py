@@ -51,6 +51,7 @@ from .span import (  # noqa: F401
     STAGE_IDLE_STACK,
     STAGE_MATRIX_BUILD,
     STAGE_MATRIX_COMPRESS,
+    STAGE_MATRIX_PLAN_PATCH,
     STAGE_MATRIX_UPDATE,
     STAGE_MIGRATE_PLACE,
     STAGE_PLAN_COMMIT,
@@ -70,6 +71,7 @@ from .span import (  # noqa: F401
     STAGE_RUNTIME_GC_PAUSE,
     STAGE_RUNTIME_GIL_WAIT,
     STAGE_SCHED_PROCESS,
+    STAGE_SCHED_RECONCILE,
 )
 
 # The process-wide recorder every instrumentation site uses. Module

@@ -32,6 +32,11 @@ STAGE_DISPATCH_POOL_WAIT = "dispatch.pool_wait"  # launch fan-out ->
 #   the eval's stage thread running (the pipeline's hand-off to
 #   its stage pool)
 STAGE_SCHED_PROCESS = "scheduler.process"  # scheduler invoke, end to end
+STAGE_SCHED_RECONCILE = "scheduler.reconcile"  # inside scheduler.process:
+#   the job's allocations against its registered version, diff_allocs
+#   through evict_and_place (scheduler/generic.py _compute_job_allocs;
+#   ann: stop, inplace, place, ignore): what an update of a running job
+#   costs before anything is placed
 STAGE_MATRIX_BUILD = "matrix.build"        # ClusterMatrix + ask construction
 STAGE_MATRIX_UPDATE = "matrix.update"      # incremental delta vs full rebuild
 STAGE_MATRIX_COMPRESS = "matrix.compress"  # signature-class interning
@@ -42,6 +47,13 @@ STAGE_FEASIBILITY_BUILD = "feasibility.build"  # inside matrix.build:
 #   class, the expansion over N, the compact form; ann: classes,
 #   groups, constraints, escaped). Recorded only on a miss of the
 #   mask memo, so its sample count is the memo's misses
+STAGE_MATRIX_PLAN_PATCH = "matrix.plan_patch"  # inside matrix.build:
+#   what a plan that already stops or has placed something changes on
+#   the rows it touches, stated against the cached base (models/
+#   matrix.py _build_plan_patch; ann: rows, bucket: the padded rows a
+#   lane ships, 0 where they went into a dense state of the matrix's
+#   own). One sample a matrix whose plan touches a row; none for an
+#   arrival
 STAGE_DEVICE_TRANSFER = "device.transfer"  # base prefetch host->device
 STAGE_BASE_DELTA = "base.delta"            # the HOST half of that
 #   prefetch, or of an inline replan's matrix.update: a cluster base
@@ -205,10 +217,12 @@ ALL_STAGES = (
     STAGE_DISPATCH_LAUNCH,
     STAGE_DISPATCH_POOL_WAIT,
     STAGE_SCHED_PROCESS,
+    STAGE_SCHED_RECONCILE,
     STAGE_MATRIX_BUILD,
     STAGE_MATRIX_UPDATE,
     STAGE_MATRIX_COMPRESS,
     STAGE_FEASIBILITY_BUILD,
+    STAGE_MATRIX_PLAN_PATCH,
     STAGE_DEVICE_TRANSFER,
     STAGE_BASE_DELTA,
     STAGE_DEVICE_DISPATCH,

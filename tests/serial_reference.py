@@ -29,3 +29,10 @@ def serial_placement(base, lanes, config):
         choices.append(np.asarray(c))
         scores.append(np.asarray(s))
     return np.stack(choices), np.stack(scores)
+
+
+def no_patches(b: int, n: int):
+    """The `patches` argument of the shared-base programs for `b` lanes
+    over `n` nodes none of whose plans touches a row (ops/binpack.py
+    _patched: a row of n is out of range and dropped)."""
+    return (np.full((b, 1), n, np.int32), np.zeros((b, 1, 6), np.float32))

@@ -317,7 +317,7 @@ def test_pre_resolve_parity_vs_serial_placement(shape):
     at a time while carrying the shared capacity state host-side — the
     exact serialization the plan applier would impose."""
     import jax
-    from serial_reference import serial_placement
+    from serial_reference import no_patches, serial_placement
 
     from nomad_tpu.ops.binpack import (
         PlacementConfig,
@@ -337,7 +337,7 @@ def test_pre_resolve_parity_vs_serial_placement(shape):
             [0] * k, spec.get("distinct_hosts", False), [False]))
 
     choices, scores, _ = batched_placement_program_overlay(
-        state, asks, keys, cfg)
+        state, asks, keys, cfg, no_patches(b, n))
     choices, scores = np.asarray(choices), np.asarray(scores)
     assert (choices >= 0).all()  # every shape fits its cluster
 
@@ -366,6 +366,7 @@ def test_pre_resolve_eliminates_in_batch_overcommit():
         batched_placement_program_overlay,
         host_prng_key,
     )
+    from serial_reference import no_patches
 
     # 8 evals x 2 asks x 400 cpu over 8 nodes of 800: demand exactly
     # equals capacity (16 asks, 16 slots), so a PERFECT serialization
@@ -385,8 +386,8 @@ def test_pre_resolve_eliminates_in_batch_overcommit():
         for f in ("capacity", "sched_capacity", "util", "bw_avail",
                   "bw_used", "ports_free", "node_ok")})
 
-    def overcommits(program, lanes):
-        choices = np.asarray(program(lanes, asks, keys, cfg)[0])
+    def overcommits(program, lanes, *patches):
+        choices = np.asarray(program(lanes, asks, keys, cfg, *patches)[0])
         claimed = np.zeros(n)
         rejected = 0
         for i in range(b):
@@ -404,7 +405,8 @@ def test_pre_resolve_eliminates_in_batch_overcommit():
         return rejected
 
     off = overcommits(batched_placement_program, full)
-    on = overcommits(batched_placement_program_overlay, state)
+    on = overcommits(batched_placement_program_overlay, state,
+                     no_patches(b, n))
     # BestFit steers independent evals to the same packed nodes: the
     # independent batch must show the collision pathology for the A/B
     # to mean anything.

@@ -13,7 +13,8 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.scheduler.batcher import get_batcher
 from nomad_tpu.server import Server, ServerConfig
-from nomad_tpu.server.worker import host_factory, is_dense_factory
+from nomad_tpu.server.worker import (
+    DEQUEUE_TIMEOUT, host_factory, is_dense_factory)
 
 
 def wait_until(fn, timeout=30.0, interval=0.02):
@@ -268,6 +269,11 @@ def test_eval_storm_routes_to_dense_path():
         before_req = batcher.batched_requests
         for w in server.workers:
             w.set_pause(True)
+        # a worker inside its dequeue long-poll takes one more eval
+        # before it parks: wait for the ack, not a fixed sleep
+        assert wait_until(
+            lambda: all(w.parked() for w in server.workers),
+            timeout=4 * DEQUEUE_TIMEOUT + 30.0)
         jobs = []
         for _ in range(6):
             job = mock.job()

@@ -270,3 +270,67 @@ def test_inplace_env_update_rerenders_running_task(tmp_path):
         agent.shutdown(destroy_allocs=True)
         http.stop()
         server.shutdown()
+
+
+def test_http_lists_an_alloc_updated_in_place_under_its_new_eval():
+    """`GET /v1/evaluation/<id>/allocations` after the same job body is
+    registered again: every allocation is rewritten in place under the
+    new evaluation, is listed there, and is no longer listed under the
+    evaluation that placed it (state/store.py upsert_allocs moves the
+    eval index's entry). Nothing is stopped and the ids survive."""
+    import json
+    import time
+    import urllib.request
+
+    from nomad_tpu.api.http import HTTPServer
+    from nomad_tpu.server import Server, ServerConfig
+    from nomad_tpu.utils.codec import to_dict
+
+    server = Server(ServerConfig(
+        num_schedulers=1, scheduler_factories={"service": "service-tpu"}))
+    server.start()
+    http = HTTPServer(server, host="127.0.0.1", port=0)
+    http.start()
+
+    def call(method, path, body=None):
+        req = urllib.request.Request(
+            http.addr + path, method=method,
+            data=None if body is None else json.dumps(body).encode())
+        with urllib.request.urlopen(req, timeout=30) as resp:
+            return json.loads(resp.read())
+
+    def registered(job):
+        eval_id = call("PUT", "/v1/jobs", {"job": to_dict(job)})["eval_id"]
+        deadline = time.monotonic() + 60.0
+        while time.monotonic() < deadline:
+            if call("GET", f"/v1/evaluation/{eval_id}")["status"] \
+                    == "complete":
+                return eval_id
+            time.sleep(0.02)
+        raise AssertionError(f"evaluation {eval_id} did not complete")
+
+    def listed(eval_id):
+        return {a["id"]: a["desired_status"]
+                for a in call("GET", f"/v1/evaluation/{eval_id}/allocations")}
+
+    try:
+        for _ in range(8):
+            node = mock.node()
+            node.compute_class()
+            server.node_register(node)
+        job = mock.job()
+        job.task_groups[0].count = 5
+        first = registered(job)
+        placed = listed(first)
+        assert len(placed) == 5 and set(placed.values()) == {"run"}
+        second = registered(job)
+        assert second != first
+        assert listed(second) == placed
+        assert listed(first) == {}
+        live = [a for a in server.fsm.state.allocs_by_job(job.id)
+                if not a.terminal_status()]
+        assert {a.id for a in live} == set(placed)
+        assert {a.eval_id for a in live} == {second}
+    finally:
+        http.stop()
+        server.shutdown()

@@ -53,6 +53,7 @@ from nomad_tpu.structs import Constraint, Gang, consts
 from nomad_tpu.structs.eval import new_eval
 from nomad_tpu.utils.metrics import get_metrics
 
+from serial_reference import no_patches
 from test_gang_batched import (
     dense_server,
     live_allocs,
@@ -207,7 +208,7 @@ def test_the_two_dispatches_equal_the_single_lane_programs_in_order(seed):
         tg_count=np.zeros((b, N_PAD, 1), np.int32), feasible=feasible,
         node_ok=fs["node_ok"])
     plain_out = batched_placement_program_overlay(
-        state, asks, pkeys, plain_cfg)
+        state, asks, pkeys, plain_cfg, no_patches(b, N_PAD))
     plain_choices = np.asarray(plain_out[0])
 
     # the reference: one lane at a time on one carried state

@@ -869,14 +869,28 @@ def test_plan_overlay_equals_a_fresh_matrix(seed):
         placed.job, placed.job_id = job, job.id
         placed.task_group = job.task_groups[0].name
         plan.append_alloc(placed)
-    fresh = ClusterMatrix(snap, job, plan)
+    # the plain reference: the walk over every node through the plan
+    # (kernels/differential.py walked_view); a matrix without
+    # plan_overlay keeps the base's token and carries the same plan as
+    # a lane's patch
+    from nomad_tpu.kernels.differential import patched_view, walked_view
+
+    walk = walked_view(snap, job, plan)
+    lane = ClusterMatrix(snap, job, plan)
+    assert lane.base_token is not None
     over = ClusterMatrix(snap, job, plan, plan_overlay=True)
     assert over.build_kind in ("hit", "delta", "full", "rekey")
     assert over.base_token is None and over.compact_overlay is None
-    for name in ("capacity", "sched_capacity", "util", "bw_avail", "bw_used",
-                 "ports_free", "node_ok", "job_count", "tg_count", "feasible"):
+    for name in ("capacity", "sched_capacity", "bw_avail", "node_ok",
+                 "feasible"):
         np.testing.assert_array_equal(
-            getattr(over, name), getattr(fresh, name), err_msg=name)
+            getattr(over, name), getattr(lane, name), err_msg=name)
+    patched = patched_view(lane)
+    for name in ("util", "bw_used", "ports_free", "job_count", "tg_count"):
+        np.testing.assert_array_equal(
+            getattr(over, name), walk[name], err_msg=name)
+        np.testing.assert_array_equal(
+            patched[name], walk[name], err_msg=f"lane {name}")
     _assert_victims_equal_walk(over, 70)
     # the cached base itself was not written to
     again = ClusterMatrix(snap, job, None)

@@ -117,6 +117,40 @@ def test_upsert_allocs_and_queries():
     assert s.job_by_id(j.id).status == consts.JOB_STATUS_RUNNING
 
 
+def test_an_alloc_updated_in_place_moves_to_its_new_eval():
+    """The eval index is by the field's value (memdb): an allocation
+    that comes back under another eval (an in-place update,
+    scheduler/util.py _stage_inplace_alloc) is listed there and no
+    longer under the eval that placed it; snapshots taken before keep
+    what they saw."""
+    s = StateStore()
+    j = mock.job()
+    s.upsert_job(5, j)
+    a, other = mock.alloc(), mock.alloc()
+    for x in (a, other):
+        x.job, x.job_id, x.eval_id = j, j.id, "eval-one"
+    s.upsert_allocs(10, [a, other])
+    before = s.snapshot()
+    woke = s.notify.watch([watch.alloc_eval("eval-one")])
+    updated = a.copy()
+    updated.eval_id = "eval-two"
+    s.upsert_allocs(11, [updated])
+    assert [x.id for x in s.allocs_by_eval("eval-two")] == [a.id]
+    assert [x.id for x in s.allocs_by_eval("eval-one")] == [other.id]
+    assert s.alloc_by_id(a.id).create_index == 10
+    assert {x.id for x in before.allocs_by_eval("eval-one")} == {
+        a.id, other.id}
+    assert before.allocs_by_eval("eval-two") == []
+    assert woke.is_set()    # a reader parked on the old eval's list
+    # the same eval again: nothing moves
+    s.upsert_allocs(12, [updated.copy()])
+    assert [x.id for x in s.allocs_by_eval("eval-two")] == [a.id]
+    # a restored store indexes what it reads
+    s2 = StateStore.restore(s.persist())
+    assert [x.id for x in s2.allocs_by_eval("eval-two")] == [a.id]
+    assert [x.id for x in s2.allocs_by_eval("eval-one")] == [other.id]
+
+
 def test_upsert_allocs_copies_shared_metrics():
     """The TPU pinned-placement path shares ONE AllocMetric across a
     plan's successful allocs (scheduler/tpu.py); the store's upsert
