@@ -239,6 +239,10 @@ class FSM:
             allocs.extend(part["allocs"])
         t0 = time.monotonic()
         self.state.upsert_allocs(index, allocs)
+        # What the txn copied before it could write (tops, buckets,
+        # index sets, by entry) and what it wrote: state/store.py.
+        copied, written = self.state.last_write
+        ann = {"index": index, "copied": copied, "written": written}
         # Trace: the state-store write is the lifecycle's last
         # side-effecting stage; one span per eval whose allocs landed
         # in this apply (a plan's allocs share one eval). create=False:
@@ -247,7 +251,7 @@ class FSM:
         # live) lifecycle records here.
         for eval_id in {a.eval_id for a in allocs if a.eval_id}:
             trace.record_span(eval_id, trace.STAGE_ALLOC_UPSERT, t0,
-                              ann={"index": index}, create=False)
+                              ann=ann, create=False)
         return None
 
     def _apply_alloc_client_update(self, index: int, payload: dict):

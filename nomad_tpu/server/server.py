@@ -1499,6 +1499,11 @@ class Server:
             # wake/spurious/served/timeout/write-error counters, and
             # the serve-pool depth.
             "read_mux": self.read_mux.stats(),
+            # State store (nomad_tpu/state/store.py): write txns, and
+            # what they copied of the tables' shared structure before
+            # they could write (entries, buckets, the largest copy of
+            # one txn): O(keys written x bucket), never a table.
+            "state_store": self.fsm.state.write_stats(),
         }
         if self.raft is not None:
             # Term/commit/membership for operators (the reference's

@@ -4,18 +4,39 @@ Reference: nomad/state/state_store.go:35 (StateStore over go-memdb's
 immutable radix trees) and nomad/state/schema.go:18-40 (tables: nodes,
 jobs, job_summary, periodic_launch, evals, allocs, index).
 
-Design: tables are plain dicts treated as immutable-after-snapshot.
-`snapshot()` marks every table shared and returns views in O(1); the
-next write to a shared table copies it first (copy-on-write at table
-granularity). Records are never mutated in place once inserted — writers
-insert fresh copies — so snapshots are stable without locking, which is
-what lets N scheduling workers read while the FSM writes (the
-reference's lock-free MVCC property, SURVEY.md section 2.3).
+Design: a table (and a secondary index) is two levels, a top of
+`_FANOUT` buckets chosen by the key's hash over plain dicts, treated
+as immutable-after-snapshot. `snapshot()` marks every table shared and
+returns views that hold the tops by reference, in O(1); the first
+write after it copies the top (a thousand references) and the bucket
+the key lives in, and every later write to a bucket copied since goes
+in place: copy-on-write at the granularity of what is written, so a
+write transaction copies O(keys written x bucket), never O(table)
+(go-memdb copies a path of its radix tree; two levels are what a
+Python dict makes cheap). Records are never mutated in place once
+inserted (writers insert fresh copies), so snapshots are stable
+without locking, which is what lets N scheduling workers read while
+the FSM writes (the reference's lock-free MVCC property, SURVEY.md
+section 2.3).
+
+Iteration order: a bucketed table lists its rows bucket by bucket,
+which is no order a caller may rely on (the index sets never had one).
+The `nodes` table alone is ONE bucket and so keeps insertion order:
+`nodes()` fixes the row order of the cluster base (models/matrix.py
+`universe_nodes_cached`). A write to it after a snapshot copies it
+whole, as every table's did; nodes are written by registrations and
+status changes, not by plans.
+
+What a write transaction copied and wrote is counted (`_WriteStats`:
+`StateStore.write_stats()`, the `state_store` block of
+`server.stats()`; `StateStore.last_write`, the `copied` / `written`
+annotations of the span `fsm.alloc_upsert`).
 """
 
 from __future__ import annotations
 
 import bisect
+import contextlib
 import copy as _copy
 import threading
 from dataclasses import dataclass, field
@@ -41,80 +62,177 @@ class PeriodicLaunch:
     modify_index: int = 0
 
 
-class _Table:
-    __slots__ = ("data", "shared")
+# Buckets a table or an index spreads its keys over (a power of two:
+# the bucket is the hash's low bits). 213,000 allocations are some 200
+# a bucket; a write after a snapshot copies the top once and one
+# bucket a key.
+_FANOUT = 1024
+
+# Every bucket nobody has written: shared by all tops and never
+# written (a writer copies a bucket it does not own first).
+_EMPTY: Dict[str, object] = {}
+
+
+class _WriteStats:
+    """What the store's write transactions copied and wrote, counted
+    by the tables themselves (plain ints under the store's lock)."""
+
+    __slots__ = ("write_txns", "entries_copied", "buckets_copied",
+                 "entries_written", "largest_txn_copy")
 
     def __init__(self):
-        self.data: Dict[str, object] = {}
+        self.write_txns = 0
+        # Entries copied before a write could go in place: a top's
+        # references, a bucket's entries, an index set's members.
+        self.entries_copied = 0
+        self.buckets_copied = 0
+        self.entries_written = 0
+        self.largest_txn_copy = 0
+
+
+class _Buckets:
+    """The read side of a table or an index, and what a snapshot holds
+    of one: the top by reference and the count at that moment."""
+
+    __slots__ = ("top", "mask", "count")
+
+    def __init__(self, top: List[Dict[str, object]], count: int):
+        self.top = top
+        self.mask = len(top) - 1
+        self.count = count
+
+    def get(self, key: str, default=None):
+        return self.top[hash(key) & self.mask].get(key, default)
+
+    def __contains__(self, key: str) -> bool:
+        return key in self.top[hash(key) & self.mask]
+
+    def __len__(self) -> int:
+        return self.count
+
+    def rows(self, keys: Iterable[str]) -> List[object]:
+        """The rows under `keys`, each of which the table holds."""
+        top, mask = self.top, self.mask
+        return [top[hash(k) & mask][k] for k in keys]
+
+    def values(self) -> List[object]:
+        out: List[object] = []
+        if not self.count:
+            return out  # node registrations list an empty jobs table
+        for bucket in self.top:
+            if bucket:
+                out.extend(bucket.values())
+        return out
+
+
+class _Table(_Buckets):
+    """A table the store writes. `_own` names the buckets created or
+    copied since the last `share()`: no snapshot can hold those, so
+    they are written in place."""
+
+    __slots__ = ("shared", "_own", "_stats")
+
+    def __init__(self, stats: _WriteStats, fanout: int):
+        super().__init__([_EMPTY] * fanout, 0)
         self.shared = False
+        self._own: Set[int] = set()
+        self._stats = stats
 
-    def for_write(self) -> Dict[str, object]:
+    def _bucket(self, key: str) -> Dict[str, object]:
+        """The bucket `key` lives in, the writer's own: after a
+        `share()` the top is copied once, and a bucket on its first
+        write since."""
+        n = hash(key) & self.mask
+        stats = self._stats
         if self.shared:
-            self.data = dict(self.data)
+            # The new top is published whole: a lock-free point read
+            # sees the old top or the new, both complete.
+            self.top = list(self.top)
+            self._own = set()
             self.shared = False
-        return self.data
+            stats.entries_copied += len(self.top)
+        elif n in self._own:
+            return self.top[n]
+        held = self.top[n]
+        bucket = self.top[n] = dict(held)
+        self._own.add(n)
+        if held:
+            stats.entries_copied += len(held)
+            stats.buckets_copied += 1
+        return bucket
 
-    def share(self) -> Dict[str, object]:
+    def put(self, key: str, value) -> None:
+        bucket = self._bucket(key)
+        if key not in bucket:
+            self.count += 1
+        bucket[key] = value
+        self._stats.entries_written += 1
+
+    def pop(self, key: str):
+        """Remove and return the row under `key`, None where there is
+        none (and then nothing is copied)."""
+        if key not in self:
+            return None
+        self.count -= 1
+        self._stats.entries_written += 1
+        return self._bucket(key).pop(key)
+
+    def share(self) -> _Buckets:
         self.shared = True
-        return self.data
+        return _Buckets(self.top, self.count)
 
 
-class _Index:
+class _Index(_Table):
     """Secondary index: key -> frozenset-ish of ids, copy-on-write.
 
-    COW granularity is per-SET, not per-dict: `_fresh` names the keys
-    whose set was created or copied since the last `share()` — no
-    snapshot can hold those, so they mutate in place. Without this,
+    Below the bucket the granularity is per-SET: `_fresh` names the
+    keys whose set was created or copied since the last `share()` (no
+    snapshot can hold those, so they mutate in place). Without this,
     every `add` under one hot key (500k allocs of one job) copies the
     whole growing set and a bulk load goes quadratic.
     """
 
-    __slots__ = ("data", "shared", "_fresh")
+    __slots__ = ("_fresh",)
 
-    def __init__(self):
-        self.data: Dict[str, Set[str]] = {}
-        self.shared = False
+    def __init__(self, stats: _WriteStats):
+        super().__init__(stats, _FANOUT)
         self._fresh: Set[str] = set()
 
-    def _for_write(self) -> Dict[str, Set[str]]:
-        if self.shared:
-            self.data = {k: v for k, v in self.data.items()}
-            self.shared = False
-        return self.data
-
     def add(self, key: str, id_: str) -> None:
-        data = self._for_write()
-        cur = data.get(key)
+        bucket = self._bucket(key)
+        self._stats.entries_written += 1
+        cur = bucket.get(key)
         if cur is None:
-            data[key] = {id_}
-            self._fresh.add(key)
+            bucket[key] = {id_}
+            self.count += 1
         elif key in self._fresh:
             cur.add(id_)  # private since last share(): mutate in place
+            return
         else:
-            data[key] = cur | {id_}  # copy: snapshots may hold cur
-            self._fresh.add(key)
+            bucket[key] = cur | {id_}  # copy: snapshots may hold cur
+            self._stats.entries_copied += len(cur)
+        self._fresh.add(key)
 
     def remove(self, key: str, id_: str) -> None:
-        data = self._for_write()
-        cur = data.get(key)
-        if cur and id_ in cur:
-            if key in self._fresh:
-                cur.discard(id_)
-                if not cur:
-                    del data[key]
-                    self._fresh.discard(key)
-            else:
-                nxt = cur - {id_}
-                if nxt:
-                    data[key] = nxt
-                    self._fresh.add(key)
-                else:
-                    del data[key]
+        cur = self.get(key)
+        if not cur or id_ not in cur:
+            return
+        bucket = self._bucket(key)
+        self._stats.entries_written += 1
+        if key in self._fresh:
+            cur.discard(id_)
+        else:
+            self._stats.entries_copied += len(cur)
+            cur = bucket[key] = cur - {id_}  # copy: snapshots may hold cur
+            self._fresh.add(key)
+        if not cur:
+            del bucket[key]
+            self.count -= 1
+            self._fresh.discard(key)
 
-    def share(self) -> Dict[str, Set[str]]:
-        self.shared = True
+    def share(self) -> _Buckets:
         self._fresh.clear()
-        return self.data
+        return super().share()
 
 
 # Allocation writes the journal keeps before it drops its older half.
@@ -207,14 +325,14 @@ class StateSnapshot:
         return self._t["nodes"].get(node_id)
 
     def nodes(self) -> List[Node]:
-        return list(self._t["nodes"].values())
+        return self._t["nodes"].values()
 
     # -- jobs --
     def job_by_id(self, job_id: str) -> Optional[Job]:
         return self._t["jobs"].get(job_id)
 
     def jobs(self) -> List[Job]:
-        return list(self._t["jobs"].values())
+        return self._t["jobs"].values()
 
     def jobs_by_scheduler(self, scheduler_type: str) -> List[Job]:
         return [j for j in self._t["jobs"].values() if j.type == scheduler_type]
@@ -230,25 +348,25 @@ class StateSnapshot:
         return self._t["periodic_launch"].get(job_id)
 
     def periodic_launches(self) -> List[PeriodicLaunch]:
-        return list(self._t["periodic_launch"].values())
+        return self._t["periodic_launch"].values()
 
     # -- evals --
     def eval_by_id(self, eval_id: str) -> Optional[Evaluation]:
         return self._t["evals"].get(eval_id)
 
     def evals(self) -> List[Evaluation]:
-        return list(self._t["evals"].values())
+        return self._t["evals"].values()
 
     def evals_by_job(self, job_id: str) -> List[Evaluation]:
         ids = self._i["evals_by_job"].get(job_id, ())
-        return [self._t["evals"][i] for i in ids]
+        return self._t["evals"].rows(ids)
 
     # -- allocs --
     def alloc_by_id(self, alloc_id: str) -> Optional[Allocation]:
         return self._t["allocs"].get(alloc_id)
 
     def allocs(self) -> List[Allocation]:
-        return list(self._t["allocs"].values())
+        return self._t["allocs"].values()
 
     def alloc_count(self) -> int:
         """O(1) allocs-table size (delta caches detect GC deletions by
@@ -273,11 +391,11 @@ class StateSnapshot:
 
     def allocs_by_job(self, job_id: str) -> List[Allocation]:
         ids = self._i["allocs_by_job"].get(job_id, ())
-        return [self._t["allocs"][i] for i in ids]
+        return self._t["allocs"].rows(ids)
 
     def allocs_by_node(self, node_id: str) -> List[Allocation]:
         ids = self._i["allocs_by_node"].get(node_id, ())
-        return [self._t["allocs"][i] for i in ids]
+        return self._t["allocs"].rows(ids)
 
     def allocs_by_node_terminal(self, node_id: str, terminal: bool) -> List[Allocation]:
         return [
@@ -285,7 +403,7 @@ class StateSnapshot:
         ]
 
     def vault_accessors(self) -> List[object]:
-        return list(self._t["vault_accessors"].values())
+        return self._t["vault_accessors"].values()
 
     def vault_accessors_by_alloc(self, alloc_id: str) -> List[object]:
         return [
@@ -295,7 +413,7 @@ class StateSnapshot:
 
     def allocs_by_eval(self, eval_id: str) -> List[Allocation]:
         ids = self._i["allocs_by_eval"].get(eval_id, ())
-        return [self._t["allocs"][i] for i in ids]
+        return self._t["allocs"].rows(ids)
 
 
 class StateStore:
@@ -309,8 +427,11 @@ class StateStore:
     snapshot: `latest_index()`, `index(table)`, `scope_index(items)`
     and the four point reads `node_by_id`, `job_by_id`, `eval_by_id`,
     `alloc_by_id`. Each of those is one read of an int or one
-    `dict.get` with str (or tuple-of-str) keys, which is atomic only
-    because the interpreter has a GIL: on a free-threaded build
+    `dict.get` with str (or tuple-of-str) keys (a point read: of the
+    bucket the table's top holds for the key; a writer publishes a
+    copied top whole and a copied bucket whole before it writes
+    either), which is atomic only because the interpreter has a GIL:
+    on a free-threaded build
     (`sys._is_gil_enabled()` false) the writers' in-place dict writes
     race with these reads and the lock has to come back. With the GIL,
     the read mux's one wake loop, the HTTP handlers and the catch-up
@@ -358,12 +479,20 @@ class StateStore:
 
     def __init__(self):
         self._lock = threading.RLock()
-        self._tables: Dict[str, _Table] = {name: _Table() for name in TABLES}
+        self._writes = _WriteStats()
+        # (copied, written) of the newest write txn, for its caller:
+        # the FSM, the one writer, annotates `fsm.alloc_upsert` with it.
+        self.last_write: Tuple[int, int] = (0, 0)
+        # `nodes` is one bucket: it keeps insertion order, the row
+        # order of the cluster base (module docstring).
+        self._tables: Dict[str, _Table] = {
+            name: _Table(self._writes, 1 if name == "nodes" else _FANOUT)
+            for name in TABLES
+        }
         self._indexes = {
-            "evals_by_job": _Index(),
-            "allocs_by_job": _Index(),
-            "allocs_by_node": _Index(),
-            "allocs_by_eval": _Index(),
+            name: _Index(self._writes)
+            for name in ("evals_by_job", "allocs_by_job", "allocs_by_node",
+                         "allocs_by_eval")
         }
         self._table_indexes: Dict[str, int] = {}
         self._latest_index = 0
@@ -433,20 +562,21 @@ class StateStore:
                 best = idx
         return best
 
-    # Point reads: ONE dict read of the live table, no lock and no
-    # snapshot, so no share() and no table copy in the next write txn
-    # (class docstring).
+    # Point reads: ONE bucket read of the live table (its top as it
+    # stands, then the key's bucket), no lock and no snapshot, so no
+    # share() and nothing for the next write txn to copy (class
+    # docstring).
     def node_by_id(self, node_id: str) -> Optional[Node]:
-        return self._tables["nodes"].data.get(node_id)
+        return self._tables["nodes"].get(node_id)
 
     def job_by_id(self, job_id: str) -> Optional[Job]:
-        return self._tables["jobs"].data.get(job_id)
+        return self._tables["jobs"].get(job_id)
 
     def eval_by_id(self, eval_id: str) -> Optional[Evaluation]:
-        return self._tables["evals"].data.get(eval_id)
+        return self._tables["evals"].get(eval_id)
 
     def alloc_by_id(self, alloc_id: str) -> Optional[Allocation]:
-        return self._tables["allocs"].data.get(alloc_id)
+        return self._tables["allocs"].get(alloc_id)
 
     # Every read that spans rows goes through a fresh snapshot, so it
     # is one consistent view (and takes the lock to get it).
@@ -484,6 +614,29 @@ class StateStore:
     # index (conservative, not lossy).
     _SCOPE_CAP = 262144
 
+    @contextlib.contextmanager
+    def _write_txn(self):
+        """One write transaction: the lock, and the account of what
+        its tables copied and wrote (`last_write`, `write_stats`)."""
+        with self._lock:
+            stats = self._writes
+            copied, written = stats.entries_copied, stats.entries_written
+            try:
+                yield
+            finally:
+                copied = stats.entries_copied - copied
+                stats.write_txns += 1
+                if copied > stats.largest_txn_copy:
+                    stats.largest_txn_copy = copied
+                self.last_write = (copied, stats.entries_written - written)
+
+    def write_stats(self) -> Dict[str, int]:
+        """What the write txns copied before they could write, tops,
+        buckets and index sets counted by entry (the `state_store`
+        block of `server.stats()`). Lock-free: plain ints."""
+        stats = self._writes
+        return {name: getattr(stats, name) for name in stats.__slots__}
+
     def _bump(self, index: int, *tables: str) -> None:
         for t in tables:
             self._table_indexes[t] = index
@@ -511,8 +664,8 @@ class StateStore:
 
     def upsert_node(self, index: int, node: Node) -> None:
         items = [watch.table("nodes"), watch.node(node.id)]
-        with self._lock:
-            table = self._tables["nodes"].for_write()
+        with self._write_txn():
+            table = self._tables["nodes"]
             existing = table.get(node.id)
             node = node.copy()
             if existing is not None:
@@ -523,26 +676,26 @@ class StateStore:
             # Always recompute: a re-registering node may carry a stale
             # class alongside changed attributes.
             node.compute_class()
-            table[node.id] = node
+            table.put(node.id, node)
             self._bump(index, "nodes")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def delete_node(self, index: int, node_id: str) -> None:
         items = [watch.table("nodes"), watch.node(node_id)]
-        with self._lock:
-            table = self._tables["nodes"].for_write()
+        with self._write_txn():
+            table = self._tables["nodes"]
             if node_id not in table:
                 return
-            del table[node_id]
+            table.pop(node_id)
             self._bump(index, "nodes")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def update_node_status(self, index: int, node_id: str, status: str) -> None:
         items = [watch.table("nodes"), watch.node(node_id)]
-        with self._lock:
-            table = self._tables["nodes"].for_write()
+        with self._write_txn():
+            table = self._tables["nodes"]
             existing = table.get(node_id)
             if existing is None:
                 raise KeyError(f"node {node_id} not found")
@@ -552,30 +705,30 @@ class StateStore:
             import time as _time
 
             node.status_updated_at = _time.time()
-            table[node_id] = node
+            table.put(node_id, node)
             self._bump(index, "nodes")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def update_node_drain(self, index: int, node_id: str, drain: bool) -> None:
         items = [watch.table("nodes"), watch.node(node_id)]
-        with self._lock:
-            table = self._tables["nodes"].for_write()
+        with self._write_txn():
+            table = self._tables["nodes"]
             existing = table.get(node_id)
             if existing is None:
                 raise KeyError(f"node {node_id} not found")
             node = existing.copy()
             node.drain = drain
             node.modify_index = index
-            table[node_id] = node
+            table.put(node_id, node)
             self._bump(index, "nodes")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def upsert_job(self, index: int, job: Job) -> None:
         items = [watch.table("jobs"), watch.job(job.id), watch.job_summary(job.id)]
-        with self._lock:
-            table = self._tables["jobs"].for_write()
+        with self._write_txn():
+            table = self._tables["jobs"]
             existing = table.get(job.id)
             job = job.copy()
             if existing is not None:
@@ -585,7 +738,7 @@ class StateStore:
                 job.create_index = index
                 job.job_modify_index = index
             job.modify_index = index
-            table[job.id] = job
+            table.put(job.id, job)
             self._ensure_job_summary(index, job)
             items.extend(self._set_job_status(index, job))
             self._bump(index, "jobs", "job_summary")
@@ -594,23 +747,23 @@ class StateStore:
 
     def delete_job(self, index: int, job_id: str) -> None:
         items = [watch.table("jobs"), watch.job(job_id), watch.job_summary(job_id)]
-        with self._lock:
-            table = self._tables["jobs"].for_write()
+        with self._write_txn():
+            table = self._tables["jobs"]
             if job_id not in table:
                 return
-            del table[job_id]
-            summary = self._tables["job_summary"].for_write()
-            summary.pop(job_id, None)
-            launches = self._tables["periodic_launch"].for_write()
-            launches.pop(job_id, None)
+            table.pop(job_id)
+            summary = self._tables["job_summary"]
+            summary.pop(job_id)
+            launches = self._tables["periodic_launch"]
+            launches.pop(job_id)
             self._bump(index, "jobs", "job_summary", "periodic_launch")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def upsert_periodic_launch(self, index: int, launch: PeriodicLaunch) -> None:
         items = [watch.table("periodic_launch")]
-        with self._lock:
-            table = self._tables["periodic_launch"].for_write()
+        with self._write_txn():
+            table = self._tables["periodic_launch"]
             existing = table.get(launch.id)
             rec = PeriodicLaunch(
                 id=launch.id,
@@ -618,16 +771,16 @@ class StateStore:
                 create_index=existing.create_index if existing else index,
                 modify_index=index,
             )
-            table[launch.id] = rec
+            table.put(launch.id, rec)
             self._bump(index, "periodic_launch")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def delete_periodic_launch(self, index: int, job_id: str) -> None:
         items = [watch.table("periodic_launch")]
-        with self._lock:
-            table = self._tables["periodic_launch"].for_write()
-            table.pop(job_id, None)
+        with self._write_txn():
+            table = self._tables["periodic_launch"]
+            table.pop(job_id)
             self._bump(index, "periodic_launch")
             self._stamp(index, items)
         self.notify.notify(items)
@@ -636,29 +789,29 @@ class StateStore:
         """Track derived vault tokens (state_store.go vault_accessors
         table; schema.go:18-40)."""
         items = [watch.table("vault_accessors")]
-        with self._lock:
-            table = self._tables["vault_accessors"].for_write()
+        with self._write_txn():
+            table = self._tables["vault_accessors"]
             for acc in accessors:
                 acc.create_index = index
-                table[acc.accessor] = acc
+                table.put(acc.accessor, acc)
             self._bump(index, "vault_accessors")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def delete_vault_accessors(self, index: int, accessors: List[str]) -> None:
         items = [watch.table("vault_accessors")]
-        with self._lock:
-            table = self._tables["vault_accessors"].for_write()
+        with self._write_txn():
+            table = self._tables["vault_accessors"]
             for acc in accessors:
-                table.pop(acc, None)
+                table.pop(acc)
             self._bump(index, "vault_accessors")
             self._stamp(index, items)
         self.notify.notify(items)
 
     def upsert_evals(self, index: int, evals: List[Evaluation]) -> None:
         items = [watch.table("evals")]
-        with self._lock:
-            table = self._tables["evals"].for_write()
+        with self._write_txn():
+            table = self._tables["evals"]
             for ev in evals:
                 items.append(watch.eval_item(ev.id))
                 existing = table.get(ev.id)
@@ -669,12 +822,12 @@ class StateStore:
                     ev.create_index = index
                     self._indexes["evals_by_job"].add(ev.job_id, ev.id)
                 ev.modify_index = index
-                table[ev.id] = ev
+                table.put(ev.id, ev)
                 # Propagate queued-alloc counts into the job summary
                 # (state_store.go UpsertEvals -> updateSummaryWithEval).
                 if ev.queued_allocations:
                     self._update_summary_queued(index, ev)
-                job = self._tables["jobs"].data.get(ev.job_id)
+                job = self._tables["jobs"].get(ev.job_id)
                 if job is not None:
                     items.extend(self._set_job_status(index, job))
                     items.append(watch.job_summary(ev.job_id))
@@ -685,17 +838,17 @@ class StateStore:
     def delete_evals(self, index: int, eval_ids: List[str], alloc_ids: List[str]) -> None:
         items = [watch.table("evals"), watch.table("allocs")]
         touched_jobs: Set[str] = set()
-        with self._lock:
-            evals = self._tables["evals"].for_write()
+        with self._write_txn():
+            evals = self._tables["evals"]
             for eid in eval_ids:
-                ev = evals.pop(eid, None)
+                ev = evals.pop(eid)
                 if ev is not None:
                     self._indexes["evals_by_job"].remove(ev.job_id, eid)
                     items.append(watch.eval_item(eid))
                     touched_jobs.add(ev.job_id)
-            allocs = self._tables["allocs"].for_write()
+            allocs = self._tables["allocs"]
             for aid in alloc_ids:
-                alloc = allocs.pop(aid, None)
+                alloc = allocs.pop(aid)
                 if alloc is not None:
                     self._indexes["allocs_by_job"].remove(alloc.job_id, aid)
                     self._indexes["allocs_by_node"].remove(alloc.node_id, aid)
@@ -710,7 +863,7 @@ class StateStore:
                         ]
                     )
             for job_id in touched_jobs:
-                job = self._tables["jobs"].data.get(job_id)
+                job = self._tables["jobs"].get(job_id)
                 if job is not None:
                     items.extend(self._set_job_status(
                         index, job, eval_delete=True))
@@ -722,8 +875,8 @@ class StateStore:
         """Scheduler/plan-apply driven alloc writes (state_store.go:922).
         Client-reported status on existing allocs is preserved."""
         items = [watch.table("allocs")]
-        with self._lock:
-            table = self._tables["allocs"].for_write()
+        with self._write_txn():
+            table = self._tables["allocs"]
             for alloc in allocs:
                 existing = table.get(alloc.id)
                 alloc = alloc.copy()
@@ -758,7 +911,7 @@ class StateStore:
                     self._indexes["allocs_by_eval"].add(alloc.eval_id, alloc.id)
                 alloc.modify_index = index
                 alloc.alloc_modify_index = index
-                table[alloc.id] = alloc
+                table.put(alloc.id, alloc)
                 self._update_summary_with_alloc(index, alloc, existing)
                 items.extend(
                     [
@@ -773,7 +926,7 @@ class StateStore:
             # Derived job status recomputes once per touched job, not
             # once per alloc (a system job upserts one alloc per node).
             for job_id in {a.job_id for a in allocs}:
-                job = self._tables["jobs"].data.get(job_id)
+                job = self._tables["jobs"].get(job_id)
                 if job is not None:
                     items.extend(self._set_job_status(index, job))
             self._bump(index, "allocs", "job_summary", *_jobs_table(items))
@@ -785,8 +938,8 @@ class StateStore:
         fields change; alloc_modify_index is NOT bumped so the client's
         long-poll diff (keyed on it) ignores its own writes."""
         items = [watch.table("allocs")]
-        with self._lock:
-            table = self._tables["allocs"].for_write()
+        with self._write_txn():
+            table = self._tables["allocs"]
             written: List[str] = []
             for update in allocs:
                 existing = table.get(update.id)
@@ -802,9 +955,9 @@ class StateStore:
                     k: _copy.deepcopy(v) for k, v in update.task_states.items()
                 }
                 alloc.modify_index = index
-                table[alloc.id] = alloc
+                table.put(alloc.id, alloc)
                 self._update_summary_with_alloc(index, alloc, existing)
-                job = self._tables["jobs"].data.get(alloc.job_id)
+                job = self._tables["jobs"].get(alloc.job_id)
                 if job is not None:
                     items.extend(self._set_job_status(index, job))
                 items.extend(
@@ -826,16 +979,16 @@ class StateStore:
     # ------------------------------------------------------------------
 
     def _ensure_job_summary(self, index: int, job: Job) -> None:
-        summaries = self._tables["job_summary"].for_write()
+        summaries = self._tables["job_summary"]
         existing = summaries.get(job.id)
         summary = existing.copy() if existing else JobSummary(job_id=job.id, create_index=index)
         for tg in job.task_groups:
             summary.summary.setdefault(tg.name, TaskGroupSummary())
         summary.modify_index = index
-        summaries[job.id] = summary
+        summaries.put(job.id, summary)
 
     def _update_summary_queued(self, index: int, ev: Evaluation) -> None:
-        summaries = self._tables["job_summary"].for_write()
+        summaries = self._tables["job_summary"]
         existing = summaries.get(ev.job_id)
         if existing is None:
             return
@@ -844,14 +997,14 @@ class StateStore:
             tgs = summary.summary.setdefault(tg, TaskGroupSummary())
             tgs.queued = queued
         summary.modify_index = index
-        summaries[ev.job_id] = summary
+        summaries.put(ev.job_id, summary)
 
     def _update_summary_with_alloc(
         self, index: int, alloc: Allocation, existing: Optional[Allocation]
     ) -> None:
         """Maintain per-task-group client-status counts
         (state_store.go:1552 updateSummaryWithAlloc)."""
-        summaries = self._tables["job_summary"].for_write()
+        summaries = self._tables["job_summary"]
         cur = summaries.get(alloc.job_id)
         if cur is None:
             cur = JobSummary(job_id=alloc.job_id, create_index=index)
@@ -875,7 +1028,7 @@ class StateStore:
         if new:
             setattr(tgs, new, getattr(tgs, new) + 1)
         summary.modify_index = index
-        summaries[alloc.job_id] = summary
+        summaries.put(alloc.job_id, summary)
 
     def _get_job_status(self, job: Job, eval_delete: bool) -> str:
         """Derive job status (state_store.go:1457 getJobStatus): running if
@@ -883,16 +1036,16 @@ class StateStore:
         everything outstanding is terminal (or evals were GC'd); a brand-new
         job with nothing outstanding is pending (running if periodic)."""
         has_alloc = False
-        for aid in self._indexes["allocs_by_job"].data.get(job.id, ()):
-            alloc = self._tables["allocs"].data.get(aid)
+        for aid in self._indexes["allocs_by_job"].get(job.id, ()):
+            alloc = self._tables["allocs"].get(aid)
             if alloc is None:
                 continue
             has_alloc = True
             if not alloc.terminal_status():
                 return consts.JOB_STATUS_RUNNING
         has_eval = False
-        for eid in self._indexes["evals_by_job"].data.get(job.id, ()):
-            ev = self._tables["evals"].data.get(eid)
+        for eid in self._indexes["evals_by_job"].get(job.id, ()):
+            ev = self._tables["evals"].get(eid)
             if ev is None:
                 continue
             has_eval = True
@@ -912,14 +1065,14 @@ class StateStore:
         the jobs table with its own, when its table writes are through
         (`_jobs_table`; class docstring, order 2)."""
         status = self._get_job_status(job, eval_delete)
-        stored = self._tables["jobs"].data.get(job.id)
+        stored = self._tables["jobs"].get(job.id)
         if stored is None or stored.status == status:
-            return []  # avoid the jobs-table copy-on-write when unchanged
-        jobs = self._tables["jobs"].for_write()
-        updated = jobs[job.id].copy()
+            return []  # unchanged: no write, so no bucket copied
+        jobs = self._tables["jobs"]
+        updated = stored.copy()
         updated.status = status
         updated.modify_index = index
-        jobs[job.id] = updated
+        jobs.put(job.id, updated)
         return [watch.table("jobs"), watch.job(job.id)]
 
     # ------------------------------------------------------------------
@@ -931,19 +1084,19 @@ class StateStore:
 
         with self._lock:
             return {
-                "nodes": [to_dict(n) for n in self._tables["nodes"].data.values()],
-                "jobs": [to_dict(j) for j in self._tables["jobs"].data.values()],
+                "nodes": [to_dict(n) for n in self._tables["nodes"].values()],
+                "jobs": [to_dict(j) for j in self._tables["jobs"].values()],
                 "job_summary": [
-                    to_dict(s) for s in self._tables["job_summary"].data.values()
+                    to_dict(s) for s in self._tables["job_summary"].values()
                 ],
                 "periodic_launch": [
-                    to_dict(p) for p in self._tables["periodic_launch"].data.values()
+                    to_dict(p) for p in self._tables["periodic_launch"].values()
                 ],
-                "evals": [to_dict(e) for e in self._tables["evals"].data.values()],
-                "allocs": [to_dict(a) for a in self._tables["allocs"].data.values()],
+                "evals": [to_dict(e) for e in self._tables["evals"].values()],
+                "allocs": [to_dict(a) for a in self._tables["allocs"].values()],
                 "vault_accessors": [
                     to_dict(v)
-                    for v in self._tables["vault_accessors"].data.values()
+                    for v in self._tables["vault_accessors"].values()
                 ],
                 "table_indexes": dict(self._table_indexes),
                 "latest_index": self._latest_index,
@@ -962,23 +1115,23 @@ class StateStore:
         with store._lock:
             for raw in data.get("nodes", []):
                 n = from_dict(Node, raw)
-                store._tables["nodes"].data[n.id] = n
+                store._tables["nodes"].put(n.id, n)
             for raw in data.get("jobs", []):
                 j = from_dict(Job, raw)
-                store._tables["jobs"].data[j.id] = j
+                store._tables["jobs"].put(j.id, j)
             for raw in data.get("job_summary", []):
                 s = from_dict(JobSummary, raw)
-                store._tables["job_summary"].data[s.job_id] = s
+                store._tables["job_summary"].put(s.job_id, s)
             for raw in data.get("periodic_launch", []):
                 p = from_dict(PeriodicLaunch, raw)
-                store._tables["periodic_launch"].data[p.id] = p
+                store._tables["periodic_launch"].put(p.id, p)
             for raw in data.get("evals", []):
                 e = from_dict(Evaluation, raw)
-                store._tables["evals"].data[e.id] = e
+                store._tables["evals"].put(e.id, e)
                 store._indexes["evals_by_job"].add(e.job_id, e.id)
             for raw in data.get("allocs", []):
                 a = from_dict(Allocation, raw)
-                store._tables["allocs"].data[a.id] = a
+                store._tables["allocs"].put(a.id, a)
                 store._indexes["allocs_by_job"].add(a.job_id, a.id)
                 store._indexes["allocs_by_node"].add(a.node_id, a.id)
                 store._indexes["allocs_by_eval"].add(a.eval_id, a.id)
@@ -986,7 +1139,7 @@ class StateStore:
 
             for raw in data.get("vault_accessors", []):
                 v = from_dict(VaultAccessor, raw)
-                store._tables["vault_accessors"].data[v.accessor] = v
+                store._tables["vault_accessors"].put(v.accessor, v)
             store._table_indexes = dict(data.get("table_indexes", {}))
             store._latest_index = data.get("latest_index", 0)
             # The journal is derived state and was not persisted: what

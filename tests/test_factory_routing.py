@@ -209,6 +209,10 @@ def test_placement_kernel_knob_reaches_stats_surface():
         seed_nodes(server)
         for w in server.workers:
             w.set_pause(True)
+        # as below: the ack, so no dequeue in flight steals an eval
+        assert wait_until(
+            lambda: all(w.parked() for w in server.workers),
+            timeout=4 * DEQUEUE_TIMEOUT + 30.0)
         jobs = []
         for _ in range(4):
             job = mock.job()

@@ -683,7 +683,7 @@ def test_indexes_never_decrease_for_a_lock_free_reader(monkeypatch):
     assert s.scope_index(stamped) == s._scope_floor
 
 
-def test_no_index_moves_before_a_txn_s_last_table_write():
+def test_no_index_moves_before_a_txn_s_last_table_write(monkeypatch):
     """The lock-free readers rely on it: a txn writes ALL its rows
     before it raises any index. The derived job status used to bump
     the global and the jobs index in the middle of upsert_evals, once
@@ -696,13 +696,15 @@ def test_no_index_moves_before_a_txn_s_last_table_write():
     s.upsert_evals(2, [e1])
     seen = []
 
-    class Spy(dict):
-        def __setitem__(self, key, value):
-            seen.append((s.latest_index(), s.index("jobs")))
-            super().__setitem__(key, value)
-
     table = s._tables["evals"]
-    table.data, table.shared = Spy(table.data), False
+    put = type(table).put
+
+    def spy(self, key, value):
+        if self is table:
+            seen.append((s.latest_index(), s.index("jobs")))
+        put(self, key, value)
+
+    monkeypatch.setattr(type(table), "put", spy)
     done = e1.copy()
     done.status = e2.status = consts.EVAL_STATUS_COMPLETE
     # e1 turning terminal flips the job to dead BEFORE e2 is written
@@ -716,22 +718,457 @@ def test_no_index_moves_before_a_txn_s_last_table_write():
 @pytest.mark.parametrize(
     "read", ["node_by_id", "job_by_id", "eval_by_id", "alloc_by_id"])
 def test_by_id_read_shares_no_table(read):
-    """A point read on the store is one dict read of the live table:
+    """A point read on the store is one bucket read of the live table:
     it marks nothing shared, so the next write txn copies nothing. A
-    read that spans rows still takes a snapshot, which does."""
+    read that spans rows still takes a snapshot, and the write after
+    it copies the top and the bucket it writes, no other."""
     s, n, j, e, a = _seeded_store()
     ids = {"node_by_id": n.id, "job_by_id": j.id, "eval_by_id": e.id,
            "alloc_by_id": a.id}
     for t in list(s._tables.values()) + list(s._indexes.values()):
         t.shared = False
-    held = {name: t.data for name, t in s._tables.items()}
+    held = s._tables["evals"].top
     assert getattr(s, read)(ids[read]).id == ids[read]
     assert getattr(s, read)("no-such-id") is None
     assert not any(t.shared for t in s._tables.values())
     assert not any(i.shared for i in s._indexes.values())
     s.upsert_evals(5, [mock.eval()])
-    assert s._tables["evals"].data is held["evals"]  # written in place
+    assert s._tables["evals"].top is held  # written in place
     assert [x.id for x in s.allocs_by_eval(e.id)] == [a.id]
     assert all(t.shared for t in s._tables.values())
-    s.upsert_evals(6, [mock.eval()])
-    assert s._tables["evals"].data is not held["evals"]  # copied first
+    before = list(held)
+    new = mock.eval()
+    s.upsert_evals(6, [new])
+    top = s._tables["evals"].top
+    assert top is not held and held == before  # the top copied first
+    fresh = [n for n, (x, y) in enumerate(zip(top, held)) if x is not y]
+    assert fresh == [hash(new.id) & s._tables["evals"].mask]
+
+
+# ---------------------------------------------------------------------
+# The tables share structure between snapshots (PR 52): a plain-dict
+# model of the store, and every snapshot held to its model for ever.
+# ---------------------------------------------------------------------
+
+
+class _Model:
+    """What the store holds, as plain dicts and lists."""
+
+    def __init__(self):
+        self.index = 0
+        self.nodes = []    # ids, in insertion order
+        self.jobs = set()
+        self.evals = {}    # id -> job id
+        self.allocs = {}   # id -> (modify_index, node, job, eval)
+        self.journal = []  # (index, alloc id), as written
+
+    def frozen(self):
+        m = _Model()
+        m.index, m.nodes, m.jobs = self.index, list(self.nodes), set(self.jobs)
+        m.evals, m.allocs = dict(self.evals), dict(self.allocs)
+        m.journal = list(self.journal)
+        return m
+
+    def ids_where(self, field, value):
+        return {i for i, row in self.allocs.items() if row[field] == value}
+
+    def changed_since(self, index):
+        ids = dict.fromkeys(i for at, i in self.journal if at > index)
+        return [i for i in ids if i in self.allocs]
+
+
+def _assert_reads(view, model, ever, floor=0):
+    """`view` (a snapshot, or a store) returns exactly `model`; `ever`
+    holds every node, job, eval and alloc id the run has used, `floor`
+    is the index its journal of allocation writes reaches back to."""
+    rows = {a.id: (a.modify_index, a.node_id, a.job_id, a.eval_id)
+            for a in view.allocs()}
+    assert rows == model.allocs
+    assert view.alloc_count() == len(model.allocs)
+    for aid in ever["allocs"]:
+        got = view.alloc_by_id(aid)
+        assert (got and got.id) == (aid if aid in model.allocs else None)
+    for field, by, ids in ((1, view.allocs_by_node, ever["nodes"]),
+                           (2, view.allocs_by_job, ever["jobs"]),
+                           (3, view.allocs_by_eval, ever["evals"])):
+        for key in list(ids) + ["no-such-key"]:
+            got = [a.id for a in by(key)]
+            assert len(got) == len(set(got))
+            assert set(got) == model.ids_where(field, key), (field, key)
+    assert {e.id: e.job_id for e in view.evals()} == model.evals
+    for eid in ever["evals"]:
+        got = view.eval_by_id(eid)
+        assert (got and got.id) == (eid if eid in model.evals else None)
+    for jid in ever["jobs"]:
+        assert ({e.id for e in view.evals_by_job(jid)}
+                == {e for e, j in model.evals.items() if j == jid})
+        assert (view.job_by_id(jid) is not None) == (jid in model.jobs)
+    assert {j.id for j in view.jobs()} == model.jobs
+    assert [n.id for n in view.nodes()] == model.nodes
+    for nid in ever["nodes"]:
+        assert (view.node_by_id(nid) is not None) == (nid in model.nodes)
+    assert view.latest_index() == model.index
+    for index in {0, model.index // 2, model.index - 1, model.index}:
+        got = view.allocs_changed_since(index)
+        if index < floor:
+            assert got is None
+        else:
+            assert [a.id for a in got] == model.changed_since(index), index
+
+
+class _Run:
+    """A store and its model under one stream of random write txns."""
+
+    def __init__(self, seed):
+        import random
+
+        self.rng = random.Random(seed)
+        self.store, self.model = StateStore(), _Model()
+        self.ever = {"nodes": [], "jobs": [], "evals": [], "allocs": []}
+        for _ in range(3):
+            self.add_node()
+
+    def _next(self):
+        self.model.index += 1
+        return self.model.index
+
+    def add_node(self):
+        n = mock.node()
+        self.store.upsert_node(self._next(), n)
+        self.model.nodes.append(n.id)
+        self.ever["nodes"].append(n.id)
+
+    def touch_node(self):
+        nid = self.rng.choice(self.model.nodes)
+        self.store.update_node_status(
+            self._next(), nid, self.rng.choice(
+                [consts.NODE_STATUS_READY, consts.NODE_STATUS_DOWN]))
+
+    def drop_node(self):
+        if len(self.model.nodes) > 2:
+            nid = self.model.nodes.pop(
+                self.rng.randrange(len(self.model.nodes)))
+            self.store.delete_node(self._next(), nid)
+
+    def _eval(self, job_id):
+        e = mock.eval()
+        e.job_id = job_id
+        self.store.upsert_evals(self._next(), [e])
+        self.model.evals[e.id] = job_id
+        self.ever["evals"].append(e.id)
+        return e.id
+
+    def place(self):
+        """A new job (or one more eval of a standing one) places."""
+        if self.model.jobs and self.rng.random() < 0.4:
+            job = self.store.job_by_id(
+                self.rng.choice(sorted(self.model.jobs)))
+        else:
+            job = mock.job()
+            self.store.upsert_job(self._next(), job)
+            self.model.jobs.add(job.id)
+            self.ever["jobs"].append(job.id)
+        eid = self._eval(job.id)
+        new = []
+        for _ in range(self.rng.randint(1, 6)):
+            a = mock.alloc()
+            a.job_id, a.job, a.eval_id = job.id, job, eid
+            a.node_id = self.rng.choice(self.ever["nodes"])
+            new.append(a)
+        index = self._next()
+        self.store.upsert_allocs(index, new)
+        for a in new:
+            self.model.allocs[a.id] = (index, a.node_id, a.job_id, eid)
+            self.model.journal.append((index, a.id))
+            self.ever["allocs"].append(a.id)
+
+    def _some_allocs(self):
+        ids = sorted(self.model.allocs)
+        return self.rng.sample(ids, min(len(ids), self.rng.randint(1, 5)))
+
+    def inplace(self):
+        """Standing allocations come back under the eval that updated
+        them (the store moves them in `allocs_by_eval`)."""
+        ids = self._some_allocs()
+        if not ids:
+            return
+        job_id = self.model.allocs[ids[0]][2]
+        ids = [i for i in ids if self.model.allocs[i][2] == job_id]
+        eid = self._eval(job_id)
+        updated = []
+        for i in ids:
+            a = self.store.alloc_by_id(i).copy()
+            a.eval_id = eid
+            updated.append(a)
+        index = self._next()
+        self.store.upsert_allocs(index, updated)
+        for a in updated:
+            self.model.allocs[a.id] = (index, a.node_id, a.job_id, eid)
+            self.model.journal.append((index, a.id))
+
+    def client(self):
+        """A client's status sync, one id of it unknown to the store."""
+        updates = []
+        for i in self._some_allocs():
+            a = self.store.alloc_by_id(i).copy()
+            a.client_status = consts.ALLOC_CLIENT_RUNNING
+            updates.append(a)
+        updates.append(mock.alloc())
+        index = self._next()
+        self.store.update_allocs_from_client(index, updates)
+        for a in updates[:-1]:
+            _, node, job, ev = self.model.allocs[a.id]
+            self.model.allocs[a.id] = (index, node, job, ev)
+            self.model.journal.append((index, a.id))
+
+    def reap(self):
+        """The eval GC: evals go with their allocations."""
+        evals = sorted(self.model.evals)
+        if not evals:
+            return
+        gone = self.rng.sample(evals, min(len(evals), self.rng.randint(1, 3)))
+        allocs = [i for i, row in self.model.allocs.items() if row[3] in gone]
+        self.store.delete_evals(self._next(), gone + ["no-such-eval"],
+                                allocs + ["no-such-alloc"])
+        for e in gone:
+            del self.model.evals[e]
+        for i in allocs:
+            del self.model.allocs[i]
+
+    def deregister(self):
+        if self.model.jobs:
+            jid = self.rng.choice(sorted(self.model.jobs))
+            self.store.delete_job(self._next(), jid)
+            self.model.jobs.discard(jid)
+
+    def step(self):
+        op = self.rng.choices(
+            [self.place, self.inplace, self.client, self.reap,
+             self.deregister, self.add_node, self.touch_node,
+             self.drop_node],
+            weights=[8, 4, 3, 2, 1, 1, 1, 1])[0]
+        op()
+
+
+def _fanout(monkeypatch, fanout):
+    """Few buckets make keys share one; 1,024 is what the store runs."""
+    from nomad_tpu.state import store as store_mod
+
+    monkeypatch.setattr(store_mod, "_FANOUT", fanout)
+
+
+_FANOUTS = [2, 16, 1024]
+
+
+@pytest.mark.parametrize("fanout", _FANOUTS)
+@pytest.mark.parametrize("seed", range(4))
+def test_every_snapshot_keeps_returning_its_model(seed, fanout, monkeypatch):
+    """Random interleavings of placements, in-place updates, client
+    syncs, eval GC, deregisters, node writes, snapshots and reads:
+    every snapshot taken returns exactly what the store held when it
+    was taken, after any number of later writes; the store itself
+    reads as the model at every check."""
+    _fanout(monkeypatch, fanout)
+    run = _Run(seed)
+    held = []
+    for step in range(90):
+        run.step()
+        if run.rng.random() < 0.35:
+            held.append((run.store.snapshot(), run.model.frozen()))
+        if step % 15 == 14:
+            for snap, model in held:
+                _assert_reads(snap, model, run.ever)
+            _assert_reads(run.store, run.model, run.ever)
+    assert len(held) > 10
+    for snap, model in held:
+        _assert_reads(snap, model, run.ever)
+
+
+@pytest.mark.parametrize("fanout", _FANOUTS)
+def test_persist_restore_round_trips_the_bucketed_tables(fanout, monkeypatch):
+    import json as _json
+
+    _fanout(monkeypatch, fanout)
+    run = _Run(seed=11)
+    for _ in range(60):
+        run.step()
+    snap = run.store.snapshot()  # the persisted store is a shared one
+    restored = StateStore.restore(_json.loads(_json.dumps(run.store.persist())))
+    # The journal is derived state, not persisted: a restored store
+    # answers None below its floor and lists nothing above it.
+    floor = restored.index("allocs")
+    assert 0 < floor <= run.model.index
+    model = run.model.frozen()
+    model.journal = []
+    _assert_reads(restored, model, run.ever, floor)
+    # and goes on as a store: a write after the restore, a snapshot
+    # before it untouched
+    before = restored.snapshot()
+    a = mock.alloc()
+    a.node_id = run.ever["nodes"][0]
+    restored.upsert_allocs(model.index + 1, [a])
+    _assert_reads(before, model, run.ever, floor)
+    assert restored.alloc_count() == len(model.allocs) + 1
+    _assert_reads(snap, run.model, run.ever)
+
+
+@pytest.mark.parametrize("fanout", _FANOUTS)
+def test_a_reader_iterates_a_snapshot_while_a_writer_commits(
+        fanout, monkeypatch):
+    """A thread reads one snapshot over and over, whole tables and by
+    every index, while another commits: it finds the snapshot's model
+    every time (switch interval shortened so the threads interleave
+    inside the table writes)."""
+    import sys
+
+    _fanout(monkeypatch, fanout)
+    run = _Run(seed=5)
+    for _ in range(40):
+        run.step()
+    snap, model = run.store.snapshot(), run.model.frozen()
+    ever = {k: list(v) for k, v in run.ever.items()}
+    stop, errors, passes = threading.Event(), [], []
+
+    def reader():
+        try:
+            while not stop.is_set():
+                _assert_reads(snap, model, ever)
+                passes.append(1)
+        except BaseException as exc:  # the assert is the finding
+            errors.append(exc)
+            raise
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    thread = threading.Thread(target=reader, daemon=True)
+    thread.start()
+    try:
+        for step in range(150):
+            run.step()
+            if step % 10 == 0:
+                run.store.snapshot()  # every table shared again
+        while not passes and not errors:
+            stop.wait(0.01)
+    finally:
+        stop.set()
+        thread.join(30.0)
+        sys.setswitchinterval(interval)
+    assert not thread.is_alive()
+    assert errors == []
+    assert passes
+    _assert_reads(run.store, run.model, run.ever)
+
+
+# -- the property PR 52 is about: a commit copies what it writes --------
+
+
+@pytest.fixture(scope="module")
+def big_store():
+    """200,000 allocations of 2,000 jobs on 12,000 nodes, loaded with
+    no snapshot taken."""
+    from nomad_tpu.structs import Allocation
+
+    s = StateStore()
+    allocs = [
+        Allocation(id=f"alloc-{i:06d}", node_id=f"node-{i % 12000:05d}",
+                   job_id=f"job-{i % 2000:04d}", eval_id=f"eval-{i % 2000:04d}",
+                   task_group="web")
+        for i in range(200_000)
+    ]
+    for at in range(0, len(allocs), 10_000):
+        s.upsert_allocs(at // 10_000 + 1, allocs[at:at + 10_000])
+    return s
+
+
+def _sixteen(tag):
+    from nomad_tpu.structs import Allocation
+
+    return [
+        Allocation(id=f"new-{tag}-{i:02d}", node_id=f"node-{i:05d}",
+                   job_id=f"job-{tag}", eval_id=f"eval-{tag}",
+                   task_group="web")
+        for i in range(16)
+    ]
+
+
+def test_a_write_after_a_snapshot_copies_buckets_not_the_table(big_store):
+    from nomad_tpu.state.store import _FANOUT
+
+    s = big_store
+    assert s.write_stats()["entries_copied"] == 0  # the bulk load
+    count = s.alloc_count()  # a snapshot: every table is shared
+    index = s.latest_index()
+
+    def largest(table):
+        return max(len(bucket) for bucket in table.top)
+
+    # Each of the five tables and indexes the txn writes copies its
+    # top once; allocs copies a bucket a row (some 200 entries each),
+    # allocs_by_node a bucket and a node's set a row, the three keyed
+    # by the new job and eval one bucket.
+    by_node = s._indexes["allocs_by_node"]
+    bound = 5 * _FANOUT + 16 * largest(s._tables["allocs"]) + 16 * (
+        largest(by_node) + max(len(ids) for ids in by_node.values())) + sum(
+        largest(t) for t in (s._indexes["allocs_by_job"],
+                             s._indexes["allocs_by_eval"],
+                             s._tables["job_summary"]))
+    assert largest(s._tables["allocs"]) < 1.5 * count / _FANOUT
+    s.upsert_allocs(index + 1, _sixteen("a"))
+    copied, written = s.last_write
+    assert 0 < copied <= bound < count // 10
+    assert written == 16 * 5
+    stats = s.write_stats()
+    assert stats["largest_txn_copy"] == stats["entries_copied"] == copied
+    assert 2 <= stats["buckets_copied"] <= 2 * 16 + 3
+    # the same write again with no snapshot between: its buckets are
+    # the writer's own since the first, nothing is copied
+    s.upsert_allocs(index + 2, _sixteen("a"))
+    assert s.last_write == (0, 16 * 2)
+    assert s.write_stats()["entries_copied"] == copied
+    # a snapshot held from before either write still reads 200,000
+    assert s.alloc_count() == count + 16
+
+
+def test_a_write_with_no_snapshot_between_copies_nothing():
+    s, n, j, e, a = _seeded_store()
+    for index in range(5, 9):
+        s.upsert_allocs(index, _sixteen(f"t{index}"))
+        assert s.last_write[0] == 0
+    assert s.write_stats()["entries_copied"] == 0
+    assert s.write_stats()["write_txns"] == 8
+
+
+def test_nodes_lists_in_insertion_order_across_snapshots():
+    """`nodes()` fixes the row order of the cluster base: the order is
+    the parent's, a dict's insertion order (an update keeps a node's
+    place, a node deleted and registered again goes last), whatever
+    was snapshotted in between."""
+    s = StateStore()
+    model = {}
+    nodes = [mock.node() for _ in range(300)]
+    index = 0
+    for n in nodes:
+        index += 1
+        s.upsert_node(index, n)
+        model[n.id] = None
+    first = s.snapshot()
+    order = list(model)
+    for n in nodes[10:200:7]:
+        index += 1
+        s.update_node_status(index, n.id, consts.NODE_STATUS_DOWN)
+        s.snapshot()
+        index += 1
+        s.update_node_drain(index, n.id, True)
+    for n in nodes[5:100:9]:
+        index += 1
+        s.delete_node(index, n.id)
+        del model[n.id]
+        s.snapshot()
+        index += 1
+        s.upsert_node(index, n)
+        model[n.id] = None
+    assert [n.id for n in s.nodes()] == list(model)
+    assert list(model) != order and sorted(model) == sorted(order)
+    assert [n.id for n in first.nodes()] == order
+    restored = StateStore.restore(s.persist())
+    assert [n.id for n in restored.nodes()] == list(model)
