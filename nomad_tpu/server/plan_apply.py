@@ -22,11 +22,21 @@ from __future__ import annotations
 import logging
 import threading
 import time
+from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
 from .. import trace
-from ..structs import Allocation, Plan, PlanResult, allocs_fit, consts, remove_allocs
+from ..structs import (
+    Allocation,
+    NetworkResource,
+    Node,
+    Plan,
+    PlanResult,
+    allocs_fit,
+    consts,
+    usage_fits,
+)
 from ..utils import metrics
 from .fsm import ALLOC_UPDATE
 from .plan_queue import PendingPlan, PlanQueue
@@ -62,7 +72,13 @@ def evaluate_node_preemptions(snapshot, plan: Plan, node_id: str) -> bool:
 
 def evaluate_node_plan(snapshot, plan: Plan, node_id: str) -> bool:
     """Whether the plan's changes to one node can be applied against the
-    given state (plan_apply.go:318 evaluateNodePlan)."""
+    given state (plan_apply.go:318 evaluateNodePlan), by the full list:
+    every allocation the view holds on the node is read and added up.
+    The applier itself verifies from a NodeSummary
+    (PlanApplier._verify_node); this is the rule that is held equal to,
+    plan for plan (tests/test_plan_apply_summary.py), what a node the
+    store no longer holds falls back on, and what kernels/differential.py
+    judges placements by."""
     if not evaluate_node_preemptions(snapshot, plan, node_id):
         return False
     if not plan.node_allocation.get(node_id):
@@ -81,15 +97,375 @@ def evaluate_node_plan(snapshot, plan: Plan, node_id: str) -> bool:
     return fit
 
 
+# What one allocation adds to its node's sums: cpu, memory, disk, iops
+# and the networks NetworkIndex.add_allocs would reserve for it.
+Share = Tuple[int, int, int, int, Tuple[NetworkResource, ...]]
+
+
+def _share(alloc: Allocation) -> Share:
+    """The accounting of allocs_fit and NetworkIndex.add_allocs for one
+    allocation: its combined resources where it carries them, else the
+    shared ask plus each task's; of each task the first network."""
+    tasks = alloc.task_resources
+    res = alloc.resources
+    nets: Tuple[NetworkResource, ...] = ()
+    if res is not None:
+        cpu, mem, disk, iops = res.cpu, res.memory_mb, res.disk_mb, res.iops
+        for res in tasks.values():
+            if res.networks:
+                nets += (res.networks[0],)
+    elif tasks:
+        res = alloc.shared_resources
+        if res is not None:
+            cpu, mem, disk, iops = (res.cpu, res.memory_mb, res.disk_mb,
+                                    res.iops)
+        else:
+            cpu = mem = disk = iops = 0
+        for res in tasks.values():
+            cpu += res.cpu
+            mem += res.memory_mb
+            disk += res.disk_mb
+            iops += res.iops
+            if res.networks:
+                nets += (res.networks[0],)
+    else:
+        raise ValueError(f"allocation {alloc.id!r} has no resources set")
+    return cpu, mem, disk, iops, nets
+
+
+class _Over:
+    """Writes over a dict that must stay as it is: a plan's ports are
+    tried on a node's counts without touching them."""
+
+    __slots__ = ("base", "own")
+
+    def __init__(self, base: dict):
+        self.base = base
+        self.own: dict = {}
+
+    def get(self, key, default):
+        value = self.own.get(key)
+        return self.base.get(key, default) if value is None else value
+
+    def __setitem__(self, key, value) -> None:
+        self.own[key] = value
+
+    def __delitem__(self, key) -> None:
+        self.own[key] = 0
+
+
+def _reserve(ports, bandwidth, net: NetworkResource, sign: int) -> int:
+    """NetworkIndex.add_reserved as counts, so that it can be taken
+    back: `ports` counts the holders of each (ip, port), `bandwidth`
+    sums by device. Returns the change in the node's collisions, which
+    are every holder of a port beyond its first and every port out of
+    range; at such a port the walk ends with the bandwidth not added,
+    as add_reserved's does."""
+    collisions = 0
+    ip = net.ip
+    for port in (*net.reserved_ports, *net.dynamic_ports):
+        value = port.value
+        if value < 0 or value >= consts.MAX_VALID_PORT:
+            return collisions + sign
+        key = (ip, value)
+        held = ports.get(key, 0)
+        if sign > 0:
+            if held:
+                collisions += 1
+            ports[key] = held + 1
+        elif held > 1:
+            collisions -= 1
+            ports[key] = held - 1
+        elif held:
+            del ports[key]
+    bandwidth[net.device] = bandwidth.get(net.device, 0) + sign * net.mbits
+    return collisions
+
+
+class NodeSummary:
+    """What allocs_fit derives from the allocations standing on one
+    node, kept so that a plan is verified against it and not against
+    the allocations themselves: the four sums (the node's reserved
+    included), bandwidth used by device, the holders of every port by
+    IP, and each covered allocation's share by id, so that a stop, a
+    preemption or an in-place update takes exactly that share out.
+    Integers throughout: a restatement of the list, not an estimate.
+    Good for one node ROW (the store replaces a node's row on every
+    write to it) and for the allocations NodeSummaries vouches for."""
+
+    __slots__ = ("node", "shares", "cpu", "memory_mb", "disk_mb", "iops",
+                 "avail", "bandwidth", "ports", "collisions")
+
+    def __init__(self, node: Node, standing: List[Allocation]):
+        self.node = node
+        self.shares: Dict[str, Share] = {}
+        self.cpu = self.memory_mb = self.disk_mb = self.iops = 0
+        self.avail = {n.device: n.mbits for n in node.resources.networks
+                      if n.device}
+        self.bandwidth: Dict[str, int] = {}
+        self.ports: Dict[Tuple[str, int], int] = {}
+        self.collisions = 0
+        reserved = node.reserved
+        if reserved:
+            self.cpu = reserved.cpu
+            self.memory_mb = reserved.memory_mb
+            self.disk_mb = reserved.disk_mb
+            self.iops = reserved.iops
+            for net in reserved.networks:
+                self.collisions += _reserve(self.ports, self.bandwidth,
+                                            net, 1)
+        # The view lists an allocation once: no share to give back.
+        shares = self.shares
+        cpu = mem = disk = iops = 0
+        for alloc in standing:
+            share = shares[alloc.id] = _share(alloc)
+            cpu += share[0]
+            mem += share[1]
+            disk += share[2]
+            iops += share[3]
+            for net in share[4]:
+                self.collisions += _reserve(self.ports, self.bandwidth,
+                                            net, 1)
+        self.cpu += cpu
+        self.memory_mb += mem
+        self.disk_mb += disk
+        self.iops += iops
+
+    def take(self, alloc: Allocation) -> None:
+        """Count one allocation in; one the summary covers under the
+        same id (an in-place update) gives its share back first."""
+        share = _share(alloc)
+        if alloc.id in self.shares:
+            self.drop(alloc.id)
+        self.shares[alloc.id] = share
+        cpu, mem, disk, iops, nets = share
+        self.cpu += cpu
+        self.memory_mb += mem
+        self.disk_mb += disk
+        self.iops += iops
+        for net in nets:
+            self.collisions += _reserve(self.ports, self.bandwidth, net, 1)
+
+    def drop(self, alloc_id: str) -> None:
+        share = self.shares.pop(alloc_id, None)
+        if share is None:
+            return
+        cpu, mem, disk, iops, nets = share
+        self.cpu -= cpu
+        self.memory_mb -= mem
+        self.disk_mb -= disk
+        self.iops -= iops
+        for net in nets:
+            self.collisions += _reserve(self.ports, self.bandwidth, net, -1)
+
+    def fits(self, removed: List[Allocation],
+             placed: List[Allocation]) -> Tuple[bool, str]:
+        """allocs_fit over what stands, less `removed` (the plan's
+        stops and victims here), with `placed` over it by id: the
+        verdict and the dimension exhausted. Changes nothing."""
+        shares = self.shares
+        leaving = {a.id: shares[a.id] for a in removed if a.id in shares}
+        if len(placed) > 1:
+            placed = list({a.id: a for a in placed}.values())
+        for alloc in placed:
+            if alloc.id in shares:
+                leaving[alloc.id] = shares[alloc.id]
+        cpu, mem, disk, iops = (self.cpu, self.memory_mb, self.disk_mb,
+                                self.iops)
+        moves: List[Tuple[NetworkResource, int]] = []
+        for c, m, d, i, nets in leaving.values():
+            cpu -= c
+            mem -= m
+            disk -= d
+            iops -= i
+            moves.extend((net, -1) for net in nets)
+        for alloc in placed:
+            c, m, d, i, nets = _share(alloc)
+            cpu += c
+            mem += m
+            disk += d
+            iops += i
+            moves.extend((net, 1) for net in nets)
+        collisions, bandwidth = self.collisions, self.bandwidth
+        if moves:
+            ports, bandwidth = _Over(self.ports), dict(bandwidth)
+            for net, sign in moves:
+                collisions += _reserve(ports, bandwidth, net, sign)
+        return usage_fits(self.node.resources, cpu, mem, disk, iops,
+                          collisions > 0, bandwidth, self.avail)
+
+
+# The most nodes the applier keeps a summary of; the one used longest
+# ago goes first. Above every cell's fleet (12,583 machines the
+# largest), so there the bound never acts; a node without a summary is
+# read from the store as every node was before. A constant: what it
+# bounds is memory (an entry an allocation standing), nobody's tuning.
+MAX_SUMMARIES = 1 << 14
+
+
+class NodeSummaries:
+    """The applier's node summaries, carried from plan to plan and from
+    group to group: node id -> NodeSummary, each the equal of what the
+    applier's view (OptimisticSnapshot: the base plus what was accepted
+    and has not landed) lists on that node.
+
+    Three things keep that true. What the applier accepts goes in and
+    out as it is accepted (`accept`). What anybody ELSE wrote is found
+    when the view moves to a new base (`rebase`), in the store's
+    journal of allocation writes (state/store.py allocs_changed_since,
+    the one models/matrix.py delta_update reads): the summary of every
+    node named by a changed allocation that no commit of the applier
+    wrote last is dropped; a journal that does not reach back to the
+    last base, another store, or a table whose size the journal does
+    not explain (a collected allocation leaves no entry) drops them
+    all; a placement of the applier's own that the new base lacks
+    (created and collected between two bases) drops its node. And a
+    node's own row is compared when its summary is asked for (`of`). A
+    failed commit drops them all as well: they held what never landed.
+    Touched on the applier's thread alone."""
+
+    def __init__(self):
+        self._by_node: "OrderedDict[str, NodeSummary]" = OrderedDict()
+        # The base they are good for: whose store, its allocs-table
+        # index and the table's size there.
+        self._store_id = ""
+        self._index = 0
+        self._count = 0
+        # Raft indexes of the applier's own commits since that base.
+        self._own: set = set()
+        # Nodes whose summary the next base outdates: see `accept`.
+        self._outdated: set = set()
+        # Placements handed to a commit since that base, id -> node:
+        # the next base must hold every one of them (`rebase`).
+        self._committing: Dict[str, str] = {}
+        self.hits = 0  # verifications served from a summary carried
+        self.builds = 0  # summaries built from the store's index
+        self.dropped = 0  # summaries thrown away, whatever the cause
+        self.standing = 0  # allocations read to build them
+
+    def __len__(self) -> int:
+        return len(self._by_node)
+
+    def clear(self) -> None:
+        self.dropped += len(self._by_node)
+        self._by_node.clear()
+        self._outdated.clear()
+        self._committing.clear()
+
+    def _drop(self, node_id: str) -> None:
+        if self._by_node.pop(node_id, None) is not None:
+            self.dropped += 1
+
+    def seal(self, results: List[PlanResult]) -> None:
+        """These accepted results go into a raft entry now."""
+        for result in results:
+            for node_id, allocs in result.node_allocation.items():
+                for alloc in allocs:
+                    self._committing[alloc.id] = node_id
+
+    def note_commit(self, index: int) -> None:
+        self._own.add(index)
+
+    def rebase(self, base) -> None:
+        """Move to a new base: keep what the journal vouches for."""
+        index, count = base.index("allocs"), base.alloc_count()
+        if self._by_node:
+            changed = (base.allocs_changed_since(self._index)
+                       if base.store_id == self._store_id else None)
+            if changed is None or count != self._count + sum(
+                    1 for a in changed if a.create_index > self._index):
+                self.clear()
+            else:
+                for node_id in self._outdated:
+                    self._drop(node_id)
+                # The table's size cannot show an allocation created
+                # AND collected since the last base, and the journal
+                # skips it; the summaries can hold one only if the
+                # applier placed it, and every commit sealed since has
+                # landed by now.
+                for alloc_id, node_id in self._committing.items():
+                    if base.alloc_by_id(alloc_id) is None:
+                        self._drop(node_id)
+                for alloc in changed:
+                    summary = self._by_node.get(alloc.node_id)
+                    if summary is None:
+                        continue
+                    # The applier's own write was counted when it was
+                    # accepted, and the store kept its resources. What
+                    # the store may have kept besides is the client's
+                    # status (upsert_allocs): an allocation the client
+                    # had finished stays finished under an in-place
+                    # update, so the row must be live where the summary
+                    # covers it and nowhere else.
+                    if (alloc.modify_index in self._own
+                            and (alloc.id in summary.shares)
+                            == (not alloc.terminal_status())):
+                        continue
+                    self._drop(alloc.node_id)
+        self._store_id, self._index, self._count = (base.store_id, index,
+                                                    count)
+        self._own.clear()
+        self._outdated.clear()
+        self._committing.clear()
+
+    def of(self, view: "OptimisticSnapshot", node: Node) -> NodeSummary:
+        """The node's summary: the one carried if it is of this row of
+        the node, else built from everything the view lists there."""
+        summary = self._by_node.get(node.id)
+        if summary is not None:
+            if summary.node is node:
+                self.hits += 1
+                self._by_node.move_to_end(node.id)
+                return summary
+            self._drop(node.id)
+        standing = view.allocs_by_node_terminal(node.id, False)
+        summary = self._by_node[node.id] = NodeSummary(node, standing)
+        self.builds += 1
+        self.standing += len(standing)
+        if len(self._by_node) > MAX_SUMMARIES:
+            self._by_node.popitem(last=False)
+            self.dropped += 1
+        return summary
+
+    def accept(self, result: PlanResult,
+               extra_by_node: Dict[str, Dict[str, Allocation]]) -> None:
+        """An accepted result's stops and victims go out of its nodes'
+        summaries and its placements in: O(what the result holds).
+        `extra_by_node` is the view's unlanded placements BEFORE this
+        result's: the view keeps listing one of those after a later
+        plan stops it, until the next base (where the stop hides it),
+        so its share stays and the node's summary ends with this
+        base."""
+        for stops in (result.node_update, result.node_preemptions):
+            for node_id, allocs in stops.items():
+                summary = self._by_node.get(node_id)
+                extra = extra_by_node.get(node_id) or ()
+                for alloc in allocs:
+                    if alloc.id in extra:
+                        # Also where the node has no summary yet: one
+                        # built on this base would count it too.
+                        self._outdated.add(node_id)
+                    elif summary is not None:
+                        summary.drop(alloc.id)
+        for node_id, allocs in result.node_allocation.items():
+            summary = self._by_node.get(node_id)
+            if summary is not None:
+                for alloc in allocs:
+                    summary.take(alloc)
+
+
 class OptimisticSnapshot:
     """Base snapshot + accepted allocations of plans that have not
     landed — the read view for verifying a plan behind its group-mates
     and behind the group whose commit is still in flight
     (plan_apply.go:155-161 optimistic snap.UpsertAllocs). Exposes
-    exactly what evaluate_node_plan reads."""
+    exactly what evaluate_node_plan reads, and the node summaries that
+    restate it (`summaries`: the applier's, carried across views; a
+    view made without them keeps its own)."""
 
-    def __init__(self, base):
+    def __init__(self, base, summaries: Optional[NodeSummaries] = None):
         self.base = base
+        self.summaries = summaries if summaries is not None else NodeSummaries()
         self._extra_by_node = {}  # node_id -> {alloc_id: alloc}
         self._evicted = set()  # alloc ids stopped by in-flight plans
         # Raft entries the view runs ahead of its base by: those sealed
@@ -98,9 +474,14 @@ class OptimisticSnapshot:
         self._sealed = 0
         self._open = False
 
-    def add_result(self, result: PlanResult) -> None:
+    def add_result(self, result: PlanResult, summarised: bool = False) -> None:
+        """Take an accepted result into the view, and into the
+        summaries unless they hold it already (`summarised`: a result
+        carried over to a new base)."""
         if result.is_no_op():
             return
+        if not summarised:
+            self.summaries.accept(result, self._extra_by_node)
         for node_id, allocs in result.node_allocation.items():
             d = self._extra_by_node.setdefault(node_id, {})
             for alloc in allocs:
@@ -142,6 +523,14 @@ class OptimisticSnapshot:
         if not terminal:
             live.update(self._extra_by_node.get(node_id, {}))
         return list(live.values())
+
+    def live_alloc(self, node_id: str, alloc_id: str) -> Optional[Allocation]:
+        """One allocation the view lists on the node, as that list
+        would hold it: the unlanded placement before the stored row."""
+        extra = self._extra_by_node.get(node_id)
+        if extra and alloc_id in extra:
+            return extra[alloc_id]
+        return self.base.alloc_by_id(alloc_id)
 
 
 # The most allocations one group carries into its one raft entry, and
@@ -206,6 +595,9 @@ class PlanApplier:
         self.commits = 0
         self.plans_committed = 0
         self.largest_group = 0
+        # Per-node standing usage, carried between plans and groups.
+        # The applier thread's alone (NodeSummaries).
+        self._summaries = NodeSummaries()
 
     def start(self) -> None:
         with self._lifecycle:
@@ -267,54 +659,74 @@ class PlanApplier:
         stop = stop if stop is not None else self._stop
         inflight = None  # future of the in-flight group commit
         overlay: Optional[OptimisticSnapshot] = None
+        # A generation of the loop starts from nothing: what a
+        # predecessor accepted last may never have landed.
+        self._summaries.clear()
         while not stop.is_set():
             group = self.plan_queue.dequeue_group(
                 MAX_GROUP_ALLOCS, timeout=0.02 if inflight else 0.25)
-            if not group:
-                if inflight is not None:
-                    self._wait_commit(inflight)
-                    inflight = None
-                overlay = None  # queue drained: next gets fresh state
-                continue
-            if inflight is None:
-                # Nothing outstanding: the group verifies against fresh
-                # state. The overlay only ever spans ONE in-flight
-                # commit and the group being verified — a rejected or
-                # no-op group must not pin the next one to a stale base.
-                overlay = OptimisticSnapshot(self.fsm.state.snapshot())
-            # Verified against the optimistic view WHILE the previous
-            # group's raft commit is still in flight — the reference's
-            # verify-(N+1)-during-commit-(N) overlap.
-            verified = self._verify_group(overlay, group, queued=True)
-            if inflight is not None:
-                ok = self._wait_commit(inflight)
-                inflight = None
-                # Rebase on committed state either way: staleness is
-                # bounded to one commit's duration, and node
-                # drains/client updates applied meanwhile are seen.
-                overlay = OptimisticSnapshot(self.fsm.state.snapshot())
-                if ok:
-                    for _pending, result in verified:
-                        overlay.add_result(result)
-                else:
-                    # The old view contained allocs that never landed:
-                    # this group's verification must be redone.
-                    verified = self._verify_group(
-                        overlay, [pending for pending, _ in verified])
-            accepted = []
-            for pending, result in verified:
-                if result.is_no_op():
-                    pending.respond(result, None)
-                else:
-                    accepted.append((pending, result))
-            if accepted:
-                overlay.seal_entry()
-                # The waiters are answered by the commit thread the
-                # INSTANT the entry has applied, not when this loop
-                # next wakes.
-                inflight = self._commit_pool.submit(self._commit, accepted)
+            inflight, overlay = self._turn(group, inflight, overlay)
         if inflight is not None:
             self._wait_commit(inflight)
+
+    def _turn(self, group: List[PendingPlan], inflight,
+              overlay: Optional[OptimisticSnapshot]):
+        """One turn of the loop: the group the queue gave (none when it
+        stood empty) behind the commit in flight and the view that
+        holds it; returns the commit in flight and the view after."""
+        if not group:
+            if inflight is not None:
+                self._wait_commit(inflight)
+            return None, None  # queue drained: next gets fresh state
+        if inflight is None:
+            # Nothing outstanding: the group verifies against fresh
+            # state. The overlay only ever spans ONE in-flight
+            # commit and the group being verified — a rejected or
+            # no-op group must not pin the next one to a stale base.
+            overlay = self._fresh_overlay()
+        # Verified against the optimistic view WHILE the previous
+        # group's raft commit is still in flight — the reference's
+        # verify-(N+1)-during-commit-(N) overlap.
+        verified = self._verify_group(overlay, group, queued=True)
+        if inflight is not None:
+            ok = self._wait_commit(inflight)
+            inflight = None
+            # Rebase on committed state either way: staleness is
+            # bounded to one commit's duration, and node
+            # drains/client updates applied meanwhile are seen.
+            overlay = self._fresh_overlay()
+            if ok:
+                # The summaries took these in when they were
+                # verified; the new view has yet to.
+                for _pending, result in verified:
+                    overlay.add_result(result, summarised=True)
+            else:
+                # The old view contained allocs that never landed:
+                # this group's verification must be redone.
+                verified = self._verify_group(
+                    overlay, [pending for pending, _ in verified])
+        accepted = []
+        for pending, result in verified:
+            if result.is_no_op():
+                pending.respond(result, None)
+            else:
+                accepted.append((pending, result))
+        if accepted:
+            overlay.seal_entry()
+            self._summaries.seal([result for _pending, result in accepted])
+            # The waiters are answered by the commit thread the
+            # INSTANT the entry has applied, not when this loop
+            # next wakes.
+            inflight = self._commit_pool.submit(self._commit, accepted)
+        return inflight, overlay
+
+    def _fresh_overlay(self) -> OptimisticSnapshot:
+        """A view of the store as it stands, and the summaries moved
+        to it: those a write of anybody else's touched since the last
+        base are gone before the first plan is verified on this one."""
+        base = self.fsm.state.snapshot()
+        self._summaries.rebase(base)
+        return OptimisticSnapshot(base, self._summaries)
 
     def _verify_group(self, overlay: "OptimisticSnapshot",
                       group: List[PendingPlan],
@@ -357,10 +769,15 @@ class PlanApplier:
         still-running commit would let it land after the pipeline moved
         on (double-commit on retry)."""
         try:
-            inflight.result()
+            # Its index names the applier's own allocations in the next
+            # base's journal: the summaries hold those already.
+            self._summaries.note_commit(inflight.result())
             return True
         except Exception:  # noqa: BLE001 - logged; waiters already told
             self.logger.exception("plan commit failed")
+            # The summaries hold what the failed group wrote, and what
+            # was verified on top of it: none of it is in the store.
+            self._summaries.clear()
             return False
 
     def _note_stale_state(self) -> None:
@@ -396,10 +813,64 @@ class PlanApplier:
         return any(a.modify_index > plan.matrix_index
                    for a in base.allocs_by_node(node_id))
 
+    @staticmethod
+    def _verify_node(view: OptimisticSnapshot, plan: Plan,
+                     node_id: str) -> bool:
+        """evaluate_node_plan from the node's summary: the same legs in
+        the same order, in proportion to what the plan writes there and
+        not to what stands there."""
+        victims = plan.node_preemptions.get(node_id)
+        placed = plan.node_allocation.get(node_id)
+        if not victims and not placed:
+            return True  # evictions only: always safe
+        node = view.node_by_id(node_id)
+        if node is None:
+            # Nothing to summarise for: the rule over the full list.
+            return evaluate_node_plan(view, plan, node_id)
+        summary = None
+        if victims:
+            from ..migrate import victim_priority
+
+            # As evaluate_node_preemptions: every victim live in the
+            # view (the summary covers exactly those) and strictly
+            # below the plan.
+            summary = view.summaries.of(view, node)
+            for victim in victims:
+                if victim.id not in summary.shares:
+                    return False
+                stored = view.live_alloc(node_id, victim.id)
+                if stored is None or victim_priority(stored) >= plan.priority:
+                    return False
+            if not placed:
+                return True
+        if node.status != consts.NODE_STATUS_READY or node.drain:
+            return False
+        if summary is None:
+            summary = view.summaries.of(view, node)
+        removed = plan.node_update.get(node_id) or ()
+        if victims:
+            removed = [*removed, *victims]
+        return summary.fits(removed, placed)[0]
+
     def _evaluate_plan(self, snapshot, plan: Plan) -> PlanResult:
         """Per-node verification with partial commit
         (plan_apply.go:194 evaluatePlan)."""
         _t0 = time.monotonic()
+        if not isinstance(snapshot, OptimisticSnapshot):
+            # A bare snapshot: a view over it with summaries of its
+            # own, so nothing is carried and every node is read.
+            snapshot = OptimisticSnapshot(snapshot)
+        summaries = snapshot.summaries
+        built0, standing0 = summaries.builds, summaries.standing
+
+        def cost() -> dict:
+            # nodes: those the plan touches; built: the summaries made
+            # from the store for it; standing: the allocations read to
+            # make them (0 and 0 where every node's was carried).
+            return {"nodes": len(node_ids),
+                    "built": summaries.builds - built0,
+                    "standing": summaries.standing - standing0}
+
         result = PlanResult(
             node_update=dict(plan.node_update),
             node_allocation=dict(plan.node_allocation),
@@ -413,7 +884,7 @@ class PlanApplier:
         suspect = False
         rejected_nodes = set()
         for node_id in node_ids:
-            if evaluate_node_plan(snapshot, plan, node_id):
+            if self._verify_node(snapshot, plan, node_id):
                 continue
             # This node's changes don't fit anymore.
             rejected += 1
@@ -433,7 +904,7 @@ class PlanApplier:
                     self._note_stale_state()
                 trace.record_span(
                     plan.eval_id, trace.STAGE_PLAN_EVALUATE, _t0,
-                    ann={"nodes_rejected": rejected, "gang": True},
+                    ann={**cost(), "nodes_rejected": rejected, "gang": True},
                     create=False)
                 return result
             rejected_nodes.add(node_id)
@@ -509,9 +980,9 @@ class PlanApplier:
         # create=False: the applier serves remote (follower-worker)
         # plans too — their lifecycle trace lives in the follower's
         # process, not this one.
-        ann = None
+        ann = cost()
         if rejected or doomed:
-            ann = {"nodes_rejected": rejected}
+            ann["nodes_rejected"] = rejected
             if doomed:
                 ann["gangs_rejected"] = len(doomed)
         trace.record_span(
@@ -524,7 +995,11 @@ class PlanApplier:
         verifications (each rejection is a replan round-trip somewhere
         upstream — the dispatch pipeline's A/B measures these); and
         whether groups form: plans_committed over commits is the plans
-        a raft entry carries."""
+        a raft entry carries; and how the node summaries serve:
+        verifications from one carried (`summary_hits`), summaries
+        built from the store (`summary_builds`) and thrown away
+        (`summary_dropped`)."""
+        summaries = self._summaries
         return {
             "plans_evaluated": self.plans_evaluated,
             "plans_rejected": self.plans_rejected,
@@ -533,6 +1008,9 @@ class PlanApplier:
             "commits": self.commits,
             "plans_committed": self.plans_committed,
             "largest_group": self.largest_group,
+            "summary_hits": summaries.hits,
+            "summary_builds": summaries.builds,
+            "summary_dropped": summaries.dropped,
         }
 
     def _commit(self, accepted: List[Verified]) -> int:

@@ -13,7 +13,7 @@ from .alloc import (
 )
 from .bitmap import Bitmap
 from .eval import Evaluation, new_eval
-from .funcs import allocs_fit, score_fit
+from .funcs import allocs_fit, score_fit, usage_fits
 from .job import (
     Constraint,
     DispatchPayloadConfig,
@@ -60,6 +60,7 @@ __all__ = [
     "new_eval",
     "allocs_fit",
     "score_fit",
+    "usage_fits",
     "Constraint",
     "DispatchPayloadConfig",
     "EphemeralDisk",

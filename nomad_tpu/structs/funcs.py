@@ -7,7 +7,7 @@ Google BestFit-v3).
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from .alloc import Allocation
 from .network import NetworkIndex
@@ -51,6 +51,38 @@ def allocs_fit(
         return False, "bandwidth exceeded", used
 
     return True, "", used
+
+
+def usage_fits(
+    capacity: Resources,
+    cpu: int,
+    memory_mb: int,
+    disk_mb: int,
+    iops: int,
+    port_collision: bool,
+    used_bandwidth: Dict[str, int],
+    avail_bandwidth: Dict[str, int],
+) -> Tuple[bool, str]:
+    """allocs_fit's rule over the sums it would have made: the node's
+    reserved included in the four, a port held twice or out of range
+    as `port_collision`, bandwidth by device. The same three verdicts
+    in the same order, for a caller that carries the sums forward
+    instead of adding every allocation up again
+    (server/plan_apply.py NodeSummary)."""
+    if capacity.cpu < cpu:
+        return False, "cpu"
+    if capacity.memory_mb < memory_mb:
+        return False, "memory"
+    if capacity.disk_mb < disk_mb:
+        return False, "disk"
+    if capacity.iops < iops:
+        return False, "iops"
+    if port_collision:
+        return False, "reserved port collision"
+    for device, used in used_bandwidth.items():
+        if used > avail_bandwidth.get(device, 0):
+            return False, "bandwidth exceeded"
+    return True, ""
 
 
 def score_fit(node: Node, util: Resources) -> float:
